@@ -755,6 +755,9 @@ def _summarize(report: dict) -> str:
 def main(argv=None) -> int:
     import os
 
+    from dnet_tpu.config import configure_compile_cache
+
+    configure_compile_cache()
     # honest attribution needs the obs fences; the bench opts in for its
     # own process (a remote target keeps its own setting)
     os.environ.setdefault("DNET_OBS_ENABLED", "1")

@@ -39,10 +39,6 @@ from dnet_tpu.analysis.core import (
 #: rel-path -> why raw DNET_* reads are sanctioned there
 DL006_ALLOWLIST: Dict[str, str] = {
     "dnet_tpu/config.py": "the settings layer — THE sanctioned env reader",
-    "bench.py": (
-        "bench driver <-> inner-process coordination (DNET_BENCH_*) runs "
-        "before dnet_tpu.config can be imported in the probed interpreter"
-    ),
 }
 
 _BROAD = {"Exception", "BaseException"}

@@ -713,6 +713,15 @@ class ApiHTTPServer:
         # visible here and through the federation scrape at a glance
         if self.cluster_manager is not None:
             body["epoch"] = getattr(self.cluster_manager, "epoch", 0)
+        else:
+            # local mode computes in this process: name the device it runs
+            # on and what each kernel dispatcher resolved to.  (A ring-mode
+            # API node owns no chip and must not open one to answer this;
+            # its shards report their own.)
+            from dnet_tpu.ops.kernel_select import SELECTIONS, device_report
+
+            body["device"] = device_report()
+            body["kernels"] = SELECTIONS.snapshot()
         monitor = self.inference.failure_monitor
         quarantine = getattr(monitor, "quarantine", None)
         if quarantine is not None:

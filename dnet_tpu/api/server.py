@@ -180,6 +180,12 @@ async def serve_async(args) -> None:
             "(attach more replicas via FleetManager.add_replica)",
             s.fleet.fleet,
         )
+    if cluster_manager is None:
+        # local mode computes in this process: refuse a kernel override that
+        # must not reach the chip now, not at the first traced request
+        from dnet_tpu.ops.kernel_select import kernel_backend
+
+        kernel_backend()
     http = ApiHTTPServer(inference, model_manager, cluster_manager, fleet=fleet)
     await http.start(args.host, args.http_port)
 
@@ -188,6 +194,13 @@ async def serve_async(args) -> None:
         try:
             await model_manager.load_model(preload)
         except Exception:
+            if cluster_manager is None:
+                # local mode: this process IS the engine, and the operator
+                # asked for this model — a server that stays up without it
+                # (and would later exit 0) hides the failure
+                log.exception("preload of %s failed", preload)
+                await http.stop()
+                raise SystemExit(1)
             # ring mode has no topology until the operator prepares one; a
             # failed preload must not kill the server
             log.exception("preload of %s failed; continuing without a model", preload)

@@ -21,7 +21,7 @@ import json
 import sys
 import time
 
-from dnet_tpu.config import get_settings
+from dnet_tpu.config import configure_compile_cache, get_settings
 from dnet_tpu.utils.logger import setup_logger
 
 
@@ -58,6 +58,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> int:
+    configure_compile_cache()
     args = build_parser().parse_args(argv)
     setup_logger("api")
     s = get_settings()

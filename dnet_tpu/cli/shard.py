@@ -8,7 +8,7 @@ from __future__ import annotations
 import argparse
 import sys
 
-from dnet_tpu.config import get_settings
+from dnet_tpu.config import configure_compile_cache, get_settings
 from dnet_tpu.utils.logger import setup_logger
 
 
@@ -34,8 +34,10 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv: list[str] | None = None) -> int:
+    cache_dir = configure_compile_cache()
     args = build_parser().parse_args(argv)
     log = setup_logger(role="shard")
+    log.info("compile cache: %s", cache_dir)
     log.info(
         "dnet-shard %s starting on %s:%d (grpc %d)",
         args.shard_name or "<unnamed>",

@@ -1,4 +1,4 @@
-"""Column sparsification ops (Pallas on TPU, jnp fallback elsewhere).
+"""Column sparsification ops (Pallas on TPU, jnp elsewhere).
 
 Reference: src/dnet/compression/ops.py:104-190 (`column_sparsify_tensor`
 dispatching hand-written Metal kernels) — the op zeroes the k columns with
@@ -12,11 +12,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from dnet_tpu.utils.logger import get_logger
-
-log = get_logger()
+from dnet_tpu.ops.kernel_select import SELECTIONS, on_tpu
 
 _LANE = 128
 
@@ -39,12 +36,15 @@ def _norms_kernel(x_ref, out_ref):
     out_ref[:] += partial
 
 
-def _column_sq_norms_pallas(x: jnp.ndarray, row_tile: int = 256) -> jnp.ndarray:
+def _column_sq_norms_pallas(
+    x: jnp.ndarray, row_tile: int = 256, interpret: bool = False
+) -> jnp.ndarray:
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     R, C = x.shape
-    assert R % row_tile == 0, "caller guards exact tiling"
+    if R % row_tile:
+        raise ValueError(f"row_tile {row_tile} must divide {R} rows exactly")
     grid = (R // row_tile,)
     return pl.pallas_call(
         _norms_kernel,
@@ -54,6 +54,8 @@ def _column_sq_norms_pallas(x: jnp.ndarray, row_tile: int = 256) -> jnp.ndarray:
         ],
         out_specs=pl.BlockSpec((1, C), lambda i: (0, 0), memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((1, C), jnp.float32),
+        interpret=interpret,
+        name="column_norms",
     )(x)[0]
 
 
@@ -61,19 +63,17 @@ def column_l2_norms(x: jnp.ndarray) -> jnp.ndarray:
     """Squared L2 norm per column of a 2D tensor [R, C] -> [C] f32.
 
     Pallas kernel on TPU when the shape tiles cleanly; jnp otherwise
-    (XLA fuses the fallback fine — the kernel exists for the DCN egress
-    hot path where activations are large and lane-aligned).
+    (XLA fuses that fine — the kernel exists for the DCN egress hot path
+    where activations are large and lane-aligned).
     """
     R, C = x.shape
-    on_tpu = jax.devices()[0].platform == "tpu"
     row_tile = R if R <= 256 else 256
     # tail row-blocks would be silently skipped by the grid: only use the
     # kernel when the tiling divides exactly
-    if on_tpu and C % _LANE == 0 and R % 8 == 0 and R % row_tile == 0:
-        try:
-            return _column_sq_norms_pallas(x, row_tile=row_tile)
-        except Exception as exc:  # pallas unavailable/mosaic error: fall back
-            log.debug("pallas column_sq_norms fell back to jnp: %s", exc)
+    if on_tpu() and C % _LANE == 0 and R % 8 == 0 and R % row_tile == 0:
+        SELECTIONS.record("column_norms", "pallas")
+        return _column_sq_norms_pallas(x, row_tile=row_tile)
+    SELECTIONS.record("column_norms", "dense", (x.shape,))
     xf = x.astype(jnp.float32)
     return jnp.sum(xf * xf, axis=0)
 
@@ -89,11 +89,14 @@ def _matmul_kernel(a_ref, b_ref, o_ref):
     def _():
         o_ref[:] = jnp.zeros_like(o_ref)
 
+    # HIGHEST: b is a one-hot selection, so the product must reproduce a's
+    # values exactly — one bf16 MXU pass would round f32 activations
     o_ref[:] += jax.lax.dot_general(
         a_ref[:].astype(jnp.float32),
         b_ref[:].astype(jnp.float32),
         (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 
@@ -106,7 +109,7 @@ def _tile(n: int, candidates) -> int:
     return 0
 
 
-def _pallas_matmul(a: jnp.ndarray, b: jnp.ndarray):
+def _pallas_matmul(a: jnp.ndarray, b: jnp.ndarray, interpret: bool = False):
     """a [R, D] @ b [D, K] on the MXU via Pallas (gather/scatter engine:
     b is a one-hot selection matrix, reference kernels.py k_gather_cols /
     k_scatter_from_compact)."""
@@ -118,7 +121,8 @@ def _pallas_matmul(a: jnp.ndarray, b: jnp.ndarray):
     tr = _tile(R, (256, 128, 64, 32, 16, 8))
     td = _tile(D, (512, 256, 128))
     tk = _tile(K, (256, 128))
-    assert tr and td and tk, "caller guards exact tiling"
+    if not (tr and td and tk):
+        raise ValueError(f"[{R},{D}] @ [{D},{K}] does not tile exactly")
     grid = (R // tr, K // tk, D // td)
     out = pl.pallas_call(
         _matmul_kernel,
@@ -131,17 +135,24 @@ def _pallas_matmul(a: jnp.ndarray, b: jnp.ndarray):
             (tr, tk), lambda i, k, d: (i, k), memory_space=pltpu.VMEM
         ),
         out_shape=jax.ShapeDtypeStruct((R, K), jnp.float32),
+        interpret=interpret,
+        name="column_select",
     )(a, b)
     return out
 
 
 def _pallas_selectable(rows: int, contraction: int, out: int) -> bool:
-    return (
-        jax.devices()[0].platform == "tpu"
+    ok = (
+        on_tpu()
         and _tile(rows, (256, 128, 64, 32, 16, 8)) > 0
         and _tile(contraction, (512, 256, 128)) > 0
         and _tile(out, (256, 128)) > 0
     )
+    SELECTIONS.record(
+        "column_select", "pallas" if ok else "dense",
+        ((rows, contraction), (contraction, out)),
+    )
+    return ok
 
 
 def gather_columns(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
@@ -152,10 +163,7 @@ def gather_columns(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     K = idx.shape[0]
     if _pallas_selectable(R, D, K):
         onehot = (jnp.arange(D)[:, None] == idx[None, :]).astype(jnp.float32)
-        try:
-            return _pallas_matmul(x, onehot).astype(x.dtype)
-        except Exception as exc:  # pallas/mosaic unavailable: fall back
-            log.debug("pallas gather_columns fell back to jnp: %s", exc)
+        return _pallas_matmul(x, onehot).astype(x.dtype)
     return jnp.take(x, idx, axis=1)
 
 
@@ -165,10 +173,7 @@ def scatter_columns(kept: jnp.ndarray, idx: jnp.ndarray, D: int) -> jnp.ndarray:
     R, K = kept.shape
     if _pallas_selectable(R, K, D):
         onehot = (idx[:, None] == jnp.arange(D)[None, :]).astype(jnp.float32)
-        try:
-            return _pallas_matmul(kept, onehot).astype(kept.dtype)
-        except Exception as exc:  # pallas/mosaic unavailable: fall back
-            log.debug("pallas scatter_columns fell back to jnp: %s", exc)
+        return _pallas_matmul(kept, onehot).astype(kept.dtype)
     return jnp.zeros((R, D), dtype=kept.dtype).at[:, idx].set(kept)
 
 

@@ -641,7 +641,6 @@ class MeshSettings(_EnvGroup):
     tp: int = 1
     dp: int = 1
     sp: int = 1
-    backend: str = ""  # "" = jax default
     # multi-host pods: when set, jax.distributed.initialize() runs before
     # the first backend use so jax.devices() spans every host of the slice
     # and the mesh engines build over the GLOBAL device set (DCN-connected
@@ -718,6 +717,26 @@ def env_flag(name: str, default: bool = False) -> bool:
         return _parse_bool(raw)
     except ValueError:
         return default
+
+
+def configure_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; returns its directory.
+
+    Called first thing by every entry point (the three CLIs and the two
+    benchmarks), so a server start finds the programs the previous start
+    compiled.  ``JAX_COMPILATION_CACHE_DIR`` wins and nothing is touched
+    (JAX reads the variable itself); otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache`` — never a temp name, because the directory is
+    part of what a later process must find again.  No other code sets the
+    option."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    import jax
+
+    path = str(Path(__file__).resolve().parent.parent / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 @functools.lru_cache(maxsize=1)

@@ -29,34 +29,38 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
-from dnet_tpu.utils.jax_compat import SDS_HAS_VMA, pcast_varying
+from dnet_tpu.ops.kernel_select import SELECTIONS, kernel_backend
 
 NEG_INF = -1e30
 
 
 def _flash_kernel(pos_ref, sink_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-                  acc_ref, *, bq: int, bk: int, scale: float, n_s: int):
-    """One (batch, head, q-tile, kv-tile) step of the online softmax.
+                  acc_ref, *, bq: int, bk: int, scale: float, n_s: int,
+                  KVH: int, G: int, Hd: int, Vd: int):
+    """One (batch, q-tile, kv-tile) step of the online softmax, every head.
 
-    q_ref/k_ref [.., Hd]; v_ref/o_ref [.., Vd] (MLA: Vd may differ) —
-    blocks of the NATIVE [B, T/S, heads, dim] layouts (no transposed copies
-    of the cache); scratch m/l [bq, 1] f32, acc [bq, Vd] f32; pos SMEM [1];
-    sink_ref SMEM [H] per-head sink logits (GPT-OSS: a virtual key that
-    absorbs probability mass but contributes no value; NEG_INF = no sink,
-    exp underflows to an exact no-op)."""
+    Mosaic tiles the last two dims of a block, so a block can take a head
+    out of [.., heads, dim] only whole.  The operands therefore arrive with
+    heads merged into the lane dim — q_ref [1, bq, H*Hd], k_ref
+    [1, bk, KVH*Hd], v_ref [1, bk, KVH*Vd], o_ref [1, bq, H*Vd] — and a
+    head is a static lane slice (MLA: Vd may differ from Hd).  Scratch per
+    head: m/l [H, bq, 1] f32, acc [H, bq, Vd] f32; pos SMEM [1]; sink_ref
+    SMEM [H] per-head sink logits (GPT-OSS: a virtual key that absorbs
+    probability mass but contributes no value; NEG_INF = no sink, exp
+    underflows to an exact no-op)."""
     import jax.experimental.pallas as pl
 
-    h = pl.program_id(1)
-    tq = pl.program_id(2)
-    s = pl.program_id(3)
+    tq = pl.program_id(1)
+    s = pl.program_id(2)
     pos = pos_ref[0]
 
     @pl.when(s == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # this q-tile's LAST row attends keys <= pos + tq*bq + bq - 1; a kv
     # tile starting past that is fully masked for the whole tile -> skip
@@ -64,41 +68,45 @@ def _flash_kernel(pos_ref, sink_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 
     @pl.when(s * bk <= q_hi)
     def _fold():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale  # [bq, Hd]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # [bk, Hd]
-        # v may have a different head dim (MLA caches qk_head_dim keys but
-        # v_head_dim values); acc is sized [bq, Vd]
-        scores = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [bq, bk]
-        q_pos = pos + tq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        k_pos = s * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        scores = jnp.where(k_pos <= q_pos, scores, NEG_INF)
-
-        m_prev = m_ref[:]  # [bq, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
-        p = jnp.exp(scores - m_new)  # [bq, bk]
-        corr = jnp.exp(m_prev - m_new)  # [bq, 1]
-        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v_ref[0, :, 0, :].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [bq, Vd]
-        acc_ref[:] = acc_ref[:] * corr + pv
-        m_ref[:] = m_new
+        q_pos = pos + tq * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        k_pos = s * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        keep = k_pos <= q_pos
+        for kh in range(KVH):
+            k = k_ref[0, :, kh * Hd:(kh + 1) * Hd].astype(jnp.float32)
+            v = v_ref[0, :, kh * Vd:(kh + 1) * Vd].astype(jnp.float32)
+            for h in range(kh * G, (kh + 1) * G):
+                q = q_ref[0, :, h * Hd:(h + 1) * Hd].astype(jnp.float32) * scale
+                scores = lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # [bq, bk]
+                scores = jnp.where(keep, scores, NEG_INF)
+                m_prev = m_ref[h]  # [bq, 1]
+                m_new = jnp.maximum(
+                    m_prev, jnp.max(scores, axis=1, keepdims=True)
+                )
+                p = jnp.exp(scores - m_new)  # [bq, bk]
+                corr = jnp.exp(m_prev - m_new)  # [bq, 1]
+                l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+                pv = lax.dot_general(
+                    p, v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # [bq, Vd]
+                acc_ref[h] = acc_ref[h] * corr + pv
+                m_ref[h] = m_new
 
     @pl.when(s == n_s - 1)
     def _emit():
         # fold the sink into the global softmax denominator exactly once
         # (same algebra as the dense op's virtual-key column)
-        sink = sink_ref[h]
-        m_fin = jnp.maximum(m_ref[:], sink)
-        corr = jnp.exp(m_ref[:] - m_fin)
-        l_fin = l_ref[:] * corr + jnp.exp(sink - m_fin)
-        o_ref[0, :, 0, :] = (
-            acc_ref[:] * corr / jnp.maximum(l_fin, 1e-30)
-        ).astype(o_ref.dtype)
+        for h in range(KVH * G):
+            sink = sink_ref[h]
+            m_fin = jnp.maximum(m_ref[h], sink)
+            corr = jnp.exp(m_ref[h] - m_fin)
+            l_fin = l_ref[h] * corr + jnp.exp(sink - m_fin)
+            o_ref[0, :, h * Vd:(h + 1) * Vd] = (
+                acc_ref[h] * corr / jnp.maximum(l_fin, 1e-30)
+            ).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -110,42 +118,44 @@ def _flash_pallas(q, k, v, pos, sinks, *, G: int, scale: float, bq: int,
     from jax.experimental.pallas import tpu as pltpu
 
     B, T, H, Hd = q.shape
-    S = k.shape[1]
+    S, KVH = k.shape[1], k.shape[2]
     Vd = v.shape[-1]
     n_s = S // bk
-    # inside shard_map the output is device-varying over the inputs' mesh
-    # axes; check_vma requires the declaration (vma=() outside shard_map)
-    kw = {"vma": frozenset(vma)} if (vma and SDS_HAS_VMA) else {}
 
-    # grid (batch, head, q-tile, kv-tile); kv-tile LAST so the scratch
+    # grid (batch, q-tile, kv-tile); kv-tile LAST so the scratch
     # accumulator carries across its (sequential) iterations
-    grid = (B, H, T // bq, n_s)
     kernel = functools.partial(
-        _flash_kernel, bq=bq, bk=bk, scale=scale, n_s=n_s
+        _flash_kernel, bq=bq, bk=bk, scale=scale, n_s=n_s, KVH=KVH, G=G,
+        Hd=Hd, Vd=Vd,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(B, T // bq, n_s),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # pos [1]
             pl.BlockSpec(memory_space=pltpu.SMEM),  # sinks [H]
-            pl.BlockSpec((1, bq, 1, Hd), lambda b, h, tq, s: (b, tq, h, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, 1, Hd), lambda b, h, tq, s: (b, s, h // G, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, 1, Vd), lambda b, h, tq, s: (b, s, h // G, 0),
-                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, bq, H * Hd), lambda b, tq, s: (b, tq, 0)),
+            pl.BlockSpec((1, bk, KVH * Hd), lambda b, tq, s: (b, s, 0)),
+            pl.BlockSpec((1, bk, KVH * Vd), lambda b, tq, s: (b, s, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, Vd), lambda b, h, tq, s: (b, tq, h, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((B, T, H, Vd), q.dtype, **kw),
+        out_specs=pl.BlockSpec((1, bq, H * Vd), lambda b, tq, s: (b, tq, 0)),
+        # inside shard_map the output is device-varying over the inputs'
+        # mesh axes; check_vma requires the declaration
+        out_shape=jax.ShapeDtypeStruct(
+            (B, T, H * Vd), q.dtype, vma=frozenset(vma)
+        ),
         scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, Vd), jnp.float32),
+            pltpu.VMEM((H, bq, 1), jnp.float32),
+            pltpu.VMEM((H, bq, 1), jnp.float32),
+            pltpu.VMEM((H, bq, Vd), jnp.float32),
         ],
         interpret=interpret,
-    )(pos, sinks, q, k, v)
+        name="flash_prefill",
+    )(
+        pos, sinks, q.reshape(B, T, H * Hd), k.reshape(B, S, KVH * Hd),
+        v.reshape(B, S, KVH * Vd),
+    )
+    return out.reshape(B, T, H, Vd)
 
 
 def _flash_emulate(q, k, v, pos, sinks, *, scale: float, bk: int):
@@ -160,8 +170,6 @@ def _flash_emulate(q, k, v, pos, sinks, *, scale: float, bk: int):
     so m is finite after the first fold and a fully-masked later tile
     contributes exp(NEG_INF - m) == 0.0 to l/acc and leaves m unchanged —
     a bitwise no-op in f32."""
-    from jax import lax
-
     B, T, H, Hd = q.shape
     S, KVH = k.shape[1], k.shape[2]
     G = H // KVH
@@ -191,11 +199,9 @@ def _flash_emulate(q, k, v, pos, sinks, *, scale: float, bk: int):
     )
     # the fold's outputs are varying over the inputs' mesh axes; the scan
     # carry must enter with the same vma (fresh zeros are invariant)
-    axes = _vma_union(q, k, v, pos) or frozenset()
+    axes = _vma_union(q, k, v, pos)
     if axes:
-        init = tuple(
-            pcast_varying(x, tuple(sorted(axes))) for x in init
-        )
+        init = lax.pcast(init, tuple(sorted(axes)), to="varying")
     (m, l, acc), _ = lax.scan(fold, init, jnp.arange(n_s))
     sink = sinks.astype(jnp.float32).reshape(KVH, G)[None, :, :, None, None]
     m_fin = jnp.maximum(m, sink)
@@ -212,92 +218,27 @@ def _pick_tile(n: int, target: int) -> int:
     return 0
 
 
-def _interpret() -> bool:
-    from dnet_tpu.config import env_flag
+def _under_manual_mesh() -> bool:
+    """True when tracing inside shard_map (mesh ring / mesh-shard programs).
 
-    return env_flag("DNET_FLASH_INTERPRET")
-
-
-_PROBE_WARNED = False
-
-
-def _under_manual_mesh():
-    """True when tracing inside shard_map (mesh ring / mesh-shard programs),
-    False outside, None when the probe itself fails.
-
-    Inside shard_map the kernels still run (r5): pallas_call outputs carry
+    Inside shard_map the kernels still run: pallas_call outputs carry
     explicit vma declarations derived from the inputs' varying axes
     (`_vma_union`), and interpret mode — where pallas under shard_map is
     fundamentally broken (discharged-jaxpr constants stay vma-invariant) —
-    runs the plain-jnp tile-fold emulation instead.  None makes callers
-    fail CLOSED to the dense ops with ONE logged warning (the probe API is
-    private-ish; a silent False after a jax upgrade would be an invisible
-    perf cliff, a silent True a permanent kernel blackout)."""
-    global _PROBE_WARNED
-    try:
-        return bool(jax.sharding.get_abstract_mesh().manual_axes)
-    except AttributeError:
-        # jax 0.4.x: no abstract-mesh API; inside shard_map the axis env
-        # is non-empty (and empty under plain jit/eager), which is the
-        # same True/False this probe needs
-        try:
-            from jax.core import nonempty_axis_env_DO_NOT_USE
-
-            return bool(nonempty_axis_env_DO_NOT_USE())
-        except Exception as exc:
-            return _probe_failed(exc)
-    except Exception as exc:
-        return _probe_failed(exc)
+    runs the plain-jnp tile-fold emulation instead."""
+    return bool(jax.sharding.get_abstract_mesh().manual_axes)
 
 
-def _probe_failed(exc) -> None:
-    global _PROBE_WARNED
-    if not _PROBE_WARNED:
-        _PROBE_WARNED = True
-        # lazy: this module must import without dragging in the logging
-        # setup (kernel code is imported from bare jax scripts too)
-        from dnet_tpu.utils.logger import get_logger
-
-        get_logger().warning(
-            "manual-mesh probe failed (%s: %s); flash kernels disabled "
-            "— dense attention serves everywhere", type(exc).__name__, exc
-        )
-    return None
-
-
-def _vma_union(*xs):
+def _vma_union(*xs) -> frozenset:
     """Union of the inputs' varying mesh axes (shard_map vma) — what a
-    pallas_call's outputs must declare under check_vma.  On jax without
-    the vma type system, falls back to ALL manual axes of the current
-    trace (conservative but exact for shard_map bodies, where every value
-    is per-device); None only if the probe API itself is unavailable
-    (callers fall back to dense)."""
-    if not hasattr(jax, "typeof"):
-        from dnet_tpu.utils.jax_compat import manual_axis_names
-
-        return manual_axis_names()
-    out = frozenset()
-    try:
-        for x in xs:
-            out |= frozenset(
-                getattr(jax.typeof(jnp.asarray(x)), "vma", frozenset())
-            )
-    except Exception:
-        return None
+    pallas_call's outputs must declare under check_vma."""
+    out: frozenset = frozenset()
+    for x in xs:
+        out |= jax.typeof(jnp.asarray(x)).vma
     return out
 
 
-def flash_eligible(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray) -> bool:
-    """Kernel preconditions: GQA-divisible heads, tileable T/S, and a TPU
-    backend (or the test override forcing interpret mode).  V's head dim
-    may differ from Q/K's (MLA).  Inside shard_map the kernel runs with
-    explicit output vma (or the jnp emulation under interpret); only a
-    broken mesh/vma probe falls back to dense (warned once)."""
-    if not _interpret() and jax.default_backend() != "tpu":
-        return False
-    um = _under_manual_mesh()
-    if um is None or (um and _vma_union(q, k, v) is None):
-        return False
+def _shape_ok(q: jnp.ndarray, k: jnp.ndarray) -> bool:
     T, H = q.shape[1], q.shape[2]
     S, KVH = k.shape[1], k.shape[2]
     return (
@@ -306,6 +247,14 @@ def flash_eligible(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray) -> bool:
         and _pick_tile(T, 128) > 0
         and _pick_tile(S, 128) > 0
     )
+
+
+def flash_eligible(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray) -> bool:
+    """Kernel preconditions: GQA-divisible heads, tileable T/S, and a TPU
+    backend (or the test override forcing interpret mode).  V's head dim
+    may differ from Q/K's (MLA).  Inside shard_map the kernel runs with
+    explicit output vma (or the jnp emulation under interpret)."""
+    return kernel_backend() is not None and _shape_ok(q, k)
 
 
 def flash_attend_causal(
@@ -328,6 +277,13 @@ def flash_attend_causal(
     B, T, H, Hd = q.shape
     S, KVH = k.shape[1], k.shape[2]
     scale = Hd**-0.5 if scale is None else scale
+
+    def dense():
+        from dnet_tpu.ops.attention import attend, causal_mask
+
+        return attend(q, k, v, mask=causal_mask(T, S, pos), scale=scale,
+                      sinks=sinks)
+
     if T == 1:
         # decode: one query row against the (preallocated) cache — the
         # split-K sibling kernel streams only the LIVE tiles
@@ -338,37 +294,28 @@ def flash_attend_causal(
 
         if flash_decode_eligible(q, k):
             return flash_decode_attend(q, k, v, pos, scale=scale, sinks=sinks)
+        return dense()  # booked under flash_decode by its eligibility check
     if not flash_eligible(q, k, v):
-        from dnet_tpu.ops.attention import attend, causal_mask
-
-        return attend(q, k, v, mask=causal_mask(T, S, pos), scale=scale,
-                      sinks=sinks)
+        SELECTIONS.record("flash_prefill", "dense", (q.shape, k.shape))
+        return dense()
+    backend = kernel_backend()
     sink_arr = (
         jnp.full((H,), NEG_INF, dtype=jnp.float32)
         if sinks is None
         else sinks.astype(jnp.float32)
     )
-    if _under_manual_mesh():
-        if _interpret():
-            # CPU mesh tests: pallas-in-shard_map interpret is broken, the
-            # jnp emulation executes the identical fold
-            return _flash_emulate(
-                q, k, v, pos, sink_arr, scale=float(scale),
-                bk=_pick_tile(S, 128),
-            )
-        vset = _vma_union(q, k, v, pos, sink_arr) or frozenset()
-        return _flash_pallas(
-            q, k, v, jnp.asarray([pos], dtype=jnp.int32), sink_arr,
-            G=H // KVH, scale=float(scale),
-            bq=_pick_tile(T, 128), bk=_pick_tile(S, 128),
-            interpret=False, vma=tuple(sorted(vset)),
+    manual = _under_manual_mesh()
+    if manual and backend == "interpret":
+        # CPU mesh tests: pallas-in-shard_map interpret is broken, the
+        # jnp emulation executes the identical fold
+        SELECTIONS.record("flash_prefill", "emulate")
+        return _flash_emulate(
+            q, k, v, pos, sink_arr, scale=float(scale), bk=_pick_tile(S, 128),
         )
-    # native layouts throughout: BlockSpec index maps pick head h's KV row
-    # h // G directly, so neither the query nor the (much larger) cache is
-    # copied/transposed in HBM
+    SELECTIONS.record("flash_prefill", backend)
+    vma = _vma_union(q, k, v, pos, sink_arr) if manual else frozenset()
     return _flash_pallas(
         q, k, v, jnp.asarray([pos], dtype=jnp.int32), sink_arr, G=H // KVH,
-        scale=float(scale),
-        bq=_pick_tile(T, 128), bk=_pick_tile(S, 128),
-        interpret=_interpret(),
+        scale=float(scale), bq=_pick_tile(T, 128), bk=_pick_tile(S, 128),
+        interpret=backend == "interpret", vma=tuple(sorted(vma)),
     )

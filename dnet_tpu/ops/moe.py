@@ -39,7 +39,6 @@ from typing import Callable
 import jax.numpy as jnp
 from jax import lax
 
-from dnet_tpu.utils.jax_compat import axis_size
 
 
 def expert_capacity(n_tokens: int, n_experts: int, k: int, factor: float) -> int:
@@ -171,7 +170,7 @@ def moe_apply(
     per-rank partial sum the caller must psum over tp_axis (the Megatron
     seam both models join their other residual terms at).
     """
-    ranks = 1 if tp_axis is None else axis_size(tp_axis)
+    ranks = 1 if tp_axis is None else lax.axis_size(tp_axis)
     n_experts = n_local * ranks  # tp ranks shard the expert dim
     impl = resolve_moe_impl(impl, flat.shape[0], n_experts, ranks)
     if impl == "a2a" and tp_axis is not None:
@@ -211,7 +210,7 @@ def moe_a2a_replicated(
     Returns the full [N, D] combined output, replicated over `axis`.
     """
     N, D = flat.shape
-    R = axis_size(axis)
+    R = lax.axis_size(axis)
     n = -(-N // R)
     pad = n * R - N
     if pad:

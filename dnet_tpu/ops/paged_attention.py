@@ -27,13 +27,13 @@ exactly like flash_decode's sink logits.
 
 Three implementations behind one dispatcher (`paged_attend`):
 
-- ``pallas``     — the real kernel (TPU).
+- ``pallas``     — the real kernel, and the only choice on a TPU backend.
 - ``interpret``  — the same kernel under pl.pallas_call(interpret=True),
   so CPU tier-1 executes the actual kernel logic incl. the index-map
   clamping (DNET_FLASH_INTERPRET=1, the flash_decode convention).
-- ``emulate``    — a plain-jnp twin for backends where interpret mode is
-  too slow to serve: gather the table's blocks (already width-bounded by
-  the caller's pow2 bucket), write the new row at `pos`, and run the
+- ``emulate``    — a plain-jnp twin for CPU backends, where interpret mode
+  is too slow to serve: gather the table's blocks (already width-bounded
+  by the caller's pow2 bucket), write the new row at `pos`, and run the
   shared dense `attend` — the same operation order as the dense-gather
   path, so greedy streams stay byte-identical, fused into the step
   program with no separate gather dispatch and NO scatter at all.
@@ -51,8 +51,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
-from dnet_tpu.ops.flash_attention import _interpret
+from dnet_tpu.ops.kernel_select import SELECTIONS, kernel_backend
 
 NEG_INF = -1e30
 
@@ -63,13 +64,9 @@ PAGED_IMPLS = ("pallas", "interpret", "emulate")
 def paged_attend_impl() -> str:
     """Resolve the implementation for this process: the real kernel on
     TPU, the interpret-mode kernel under the DNET_FLASH_INTERPRET test
-    override (CPU tier-1 executes the true kernel logic), the jnp twin
-    everywhere else (fast enough to SERVE on CPU fallback)."""
-    if _interpret():
-        return "interpret"
-    if jax.default_backend() == "tpu":
-        return "pallas"
-    return "emulate"
+    override (CPU tier-1 executes the true kernel logic), the jnp twin on
+    any other CPU backend (fast enough to SERVE there)."""
+    return kernel_backend() or "emulate"
 
 
 def ragged_refusal(model, kv_quant_bits: int) -> Optional[str]:
@@ -91,69 +88,71 @@ def ragged_refusal(model, kv_quant_bits: int) -> Optional[str]:
 
 def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref,
                   o_ref, m_ref, l_ref, acc_ref, *, bt: int, scale: float,
-                  nb: int):
-    """One (slot, kv-head, logical-block) fold of the online softmax.
+                  nb: int, KVH: int, Hd: int, Vd: int):
+    """One (slot, logical-block) fold of the online softmax, every kv head.
 
     tbl_ref SMEM [slots, nb] page table, pos_ref SMEM [slots] live pool
     rows per slot (the new token's row arrives via kn/vn, folded at emit).
-    q [G, Hd] is the slot's whole GQA group for this kv head — one block
-    read amortizes over all G query heads sharing it."""
+    Mosaic tiles a block's last two dims, so the pool block arrives with
+    heads merged into the lane dim — k_ref [1, bt, KVH*Hd], v_ref
+    [1, bt, KVH*Vd] — and a kv head is a static lane slice; q_ref
+    [1, KVH, G, Hd] holds each head's whole GQA group, so one block read
+    amortizes over all G query heads sharing it."""
     import jax.experimental.pallas as pl
 
     b = pl.program_id(0)
-    i = pl.program_id(2)
+    i = pl.program_id(1)
     live = pos_ref[b]
 
     @pl.when(i == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
     @pl.when(i * bt < live)
     def _fold():
-        q = q_ref[0, 0, :, :].astype(jnp.float32) * scale  # [G, Hd]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # [bt, Hd]
-        scores = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [G, bt]
         # mid-block ragged edge: the last live block is only partially
         # full — rows at absolute positions >= live are stale pool content
         # (or a clamped repeat of an earlier block) and must not score
-        slot = i * bt + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
-        scores = jnp.where(slot < live, scores, NEG_INF)
-
-        m_prev = m_ref[:]  # [G, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
-        p = jnp.exp(scores - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v_ref[0, :, 0, :].astype(jnp.float32),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [G, Vd]
-        acc_ref[:] = acc_ref[:] * corr + pv
-        m_ref[:] = m_new
+        slot = i * bt + lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+        valid = slot < live
+        for kh in range(KVH):
+            q = q_ref[0, kh].astype(jnp.float32) * scale  # [G, Hd]
+            k = k_ref[0, :, kh * Hd:(kh + 1) * Hd].astype(jnp.float32)
+            scores = lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [G, bt]
+            scores = jnp.where(valid, scores, NEG_INF)
+            m_prev = m_ref[kh]  # [G, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+            p = jnp.exp(scores - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[kh] = l_ref[kh] * corr + jnp.sum(p, axis=1, keepdims=True)
+            pv = lax.dot_general(
+                p, v_ref[0, :, kh * Vd:(kh + 1) * Vd].astype(jnp.float32),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [G, Vd]
+            acc_ref[kh] = acc_ref[kh] * corr + pv
+            m_ref[kh] = m_new
 
     @pl.when(i == nb - 1)
     def _emit():
         # fold the CURRENT token's row (position == live, always attended
         # under the causal predicate) analytically — it reaches the pool
         # only after the launch, via the kv_append program
-        q = q_ref[0, 0, :, :].astype(jnp.float32) * scale  # [G, Hd]
-        kn = kn_ref[0, 0, 0, :].astype(jnp.float32)  # [Hd]
-        vn = vn_ref[0, 0, 0, :].astype(jnp.float32)  # [Vd]
-        s_new = jnp.sum(q * kn[None, :], axis=1, keepdims=True)  # [G, 1]
-        m_fin = jnp.maximum(m_ref[:], s_new)
-        corr = jnp.exp(m_ref[:] - m_fin)
-        p_new = jnp.exp(s_new - m_fin)  # [G, 1]
-        l_fin = l_ref[:] * corr + p_new
-        acc_fin = acc_ref[:] * corr + p_new * vn[None, :]
-        o_ref[0, 0, :, :] = (
-            acc_fin / jnp.maximum(l_fin, 1e-30)
-        ).astype(o_ref.dtype)
+        q = q_ref[0].astype(jnp.float32) * scale  # [KVH, G, Hd]
+        kn = kn_ref[0].astype(jnp.float32)  # [KVH, 1, Hd]
+        vn = vn_ref[0].astype(jnp.float32)  # [KVH, 1, Vd]
+        s_new = jnp.sum(q * kn, axis=2, keepdims=True)  # [KVH, G, 1]
+        m_fin = jnp.maximum(m_ref[...], s_new)
+        corr = jnp.exp(m_ref[...] - m_fin)
+        p_new = jnp.exp(s_new - m_fin)
+        l_fin = l_ref[...] * corr + p_new
+        acc_fin = acc_ref[...] * corr + p_new * vn
+        o_ref[0] = (acc_fin / jnp.maximum(l_fin, 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -167,47 +166,53 @@ def _paged_pallas(q, k_pool, v_pool, tables, pos, k_new, v_new, *, G: int,
 
     B, T, H, Hd = q.shape
     KVH = H // G
+    N = k_pool.shape[0]
     Vd = v_pool.shape[-1]
     nb = tables.shape[1]
-    qg = q.reshape(B, KVH, G, Hd)
-    kn = k_new.reshape(B, KVH, 1, Hd)
-    vn = v_new.reshape(B, KVH, 1, Vd)
 
-    def live_block(b, tbl, pos):
+    def live_block(b, pos):
         """Last logical block holding any live row for slot b; dead grid
         steps clamp here so the pipeline re-fetches (elides) one block
         instead of streaming unallocated table entries."""
         return jnp.clip((pos[b] - 1) // bt, 0, nb - 1)
 
-    def kv_map(b, kh, i, tbl, pos):
-        return (tbl[b, jnp.minimum(i, live_block(b, tbl, pos))], 0, kh, 0)
+    def kv_map(b, i, tbl, pos):
+        return (tbl[b, jnp.minimum(i, live_block(b, pos))], 0, 0)
+
+    def whole4(b, i, tbl, pos):
+        return (b, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KVH, nb),
+        grid=(B, nb),
         in_specs=[
-            pl.BlockSpec((1, 1, G, Hd), lambda b, kh, i, tbl, pos: (b, kh, 0, 0)),
-            pl.BlockSpec((1, bt, 1, Hd), kv_map),
-            pl.BlockSpec((1, bt, 1, Vd), kv_map),
-            pl.BlockSpec((1, 1, 1, Hd), lambda b, kh, i, tbl, pos: (b, kh, 0, 0)),
-            pl.BlockSpec((1, 1, 1, Vd), lambda b, kh, i, tbl, pos: (b, kh, 0, 0)),
+            pl.BlockSpec((1, KVH, G, Hd), whole4),
+            pl.BlockSpec((1, bt, KVH * Hd), kv_map),
+            pl.BlockSpec((1, bt, KVH * Vd), kv_map),
+            pl.BlockSpec((1, KVH, 1, Hd), whole4),
+            pl.BlockSpec((1, KVH, 1, Vd), whole4),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, G, Vd), lambda b, kh, i, tbl, pos: (b, kh, 0, 0)
-        ),
+        out_specs=pl.BlockSpec((1, KVH, G, Vd), whole4),
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, Vd), jnp.float32),
+            pltpu.VMEM((KVH, G, 1), jnp.float32),
+            pltpu.VMEM((KVH, G, 1), jnp.float32),
+            pltpu.VMEM((KVH, G, Vd), jnp.float32),
         ],
     )
-    kernel = functools.partial(_paged_kernel, bt=bt, scale=scale, nb=nb)
+    kernel = functools.partial(
+        _paged_kernel, bt=bt, scale=scale, nb=nb, KVH=KVH, Hd=Hd, Vd=Vd
+    )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, Vd), q.dtype),
         interpret=interpret,
-    )(tables, pos, qg, k_pool, v_pool, kn, vn)
+        name="paged_attend",
+    )(
+        tables, pos, q.reshape(B, KVH, G, Hd),
+        k_pool.reshape(N, bt, KVH * Hd), v_pool.reshape(N, bt, KVH * Vd),
+        k_new.reshape(B, KVH, 1, Hd), v_new.reshape(B, KVH, 1, Vd),
+    )
     return out.reshape(B, T, H, Vd)
 
 
@@ -218,7 +223,7 @@ def _paged_emulate(q, k_pool, v_pool, tables, pos, k_new, v_new,
     new row at `pos` exactly like the dense path's write_kv, and attend
     with the causal-at-pos mask through the SAME dense `attend` the
     gather path bottoms out in — one fused program, no separate gather
-    dispatch, no scatter.  Serving CPU fallbacks run this; interpret mode
+    dispatch, no scatter.  CPU backends serve through this; interpret mode
     and TPU run the kernel."""
     from dnet_tpu.ops.attention import attend
 
@@ -271,11 +276,12 @@ def paged_attend(
     scale = Hd**-0.5 if scale is None else float(scale)
     tables = tables.astype(jnp.int32)
     pos = pos.astype(jnp.int32)
+    if impl not in PAGED_IMPLS:
+        raise ValueError(f"paged_attend impl {impl!r} not in {PAGED_IMPLS}")
+    SELECTIONS.record("paged_attend", impl)
     if impl == "emulate":
         return _paged_emulate(q, k_pool, v_pool, tables, pos, k_new, v_new,
                               scale)
-    if impl not in PAGED_IMPLS:
-        raise ValueError(f"paged_attend impl {impl!r} not in {PAGED_IMPLS}")
     return _paged_pallas(
         q, k_pool, v_pool, tables, pos, k_new, v_new,
         G=G, scale=scale, bt=bt, interpret=(impl == "interpret"),

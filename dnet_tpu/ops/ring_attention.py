@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dnet_tpu.utils.jax_compat import pcast_varying
 
 NEG = -1e30
 
@@ -79,9 +78,9 @@ def ring_attend(
 
     # accumulators become device-varying over the axis once folded with the
     # rank-local KV; mark them so the fori carry types line up
-    m = pcast_varying(jnp.full((B, KVH, G, Tq), NEG, dtype=jnp.float32), axis_name)
-    l = pcast_varying(jnp.zeros((B, KVH, G, Tq), dtype=jnp.float32), axis_name)
-    o = pcast_varying(jnp.zeros((B, KVH, G, Tq, Hd), dtype=jnp.float32), axis_name)
+    m = lax.pcast(jnp.full((B, KVH, G, Tq), NEG, dtype=jnp.float32), axis_name, to="varying")
+    l = lax.pcast(jnp.zeros((B, KVH, G, Tq), dtype=jnp.float32), axis_name, to="varying")
+    o = lax.pcast(jnp.zeros((B, KVH, G, Tq, Hd), dtype=jnp.float32), axis_name, to="varying")
 
     perm = [(r, (r + 1) % SP) for r in range(SP)]
 

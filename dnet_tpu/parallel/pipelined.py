@@ -39,8 +39,6 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from dnet_tpu.utils.jax_compat import pcast_varying, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dnet_tpu.core.sampler import (
@@ -222,12 +220,12 @@ def make_rotation_fn(
             x_embed = model.embed(edge_params, tok_in[:, None])
             # tokens are dp-sharded, so the embedding is already dp-varying;
             # only the pp axis needs the explicit cast
-            x_embed = pcast_varying(x_embed, AXIS_PP)
+            x_embed = lax.pcast(x_embed, AXIS_PP, to="varying")
             x_in = jnp.where(take, x_embed, x)
             pos_entry = lax.dynamic_index_in_dim(pos_vec, n, keepdims=False)
             pos_in = jnp.where(take, pos_entry, pos_x)
             live_entry = lax.dynamic_index_in_dim(live_row, j, keepdims=False)
-            live_entry = pcast_varying(live_entry, AXIS_PP)
+            live_entry = lax.pcast(live_entry, AXIS_PP, to="varying")
             live_in = jnp.where(take, live_entry, live_x)
             phase_in = jnp.where(take, 0, phase_x)
             pos_vec = lax.dynamic_update_index_in_dim(
@@ -321,7 +319,7 @@ def make_rotation_fn(
         return (results, x[None], kv, tokens, pos_vec, pos_x[None, None],
                 live_x[None, None], phase_x[None, None], keys, counts)
 
-    fn = shard_map(spmd, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    fn = jax.shard_map(spmd, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
     jitted = jax.jit(fn, donate_argnums=(2, 3, 4, 5, 6, 7, 8, 15, 16))
     kinds_arr = (
         model.layer_kinds if has_kinds else jnp.zeros((), dtype=jnp.int32)
@@ -370,8 +368,7 @@ def make_slot_prefill_fn(model, mesh: Mesh, window_params, n_slots: int, batch: 
             lambda a: lax.dynamic_slice_in_dim(a, slot * B, B, axis=1), kv
         )
         x = model.embed(edge_params, tokens)
-        x = pcast_varying(x, AXIS_PP)
-        x = pcast_varying(x, AXIS_DP)
+        x = lax.pcast(x, (AXIS_PP, AXIS_DP), to="varying")
 
         def stage_iter(i, carry):
             x, kv_slot = carry
@@ -404,7 +401,7 @@ def make_slot_prefill_fn(model, mesh: Mesh, window_params, n_slots: int, batch: 
         logits = lax.psum(jnp.where(mine, logits, jnp.zeros_like(logits)), AXIS_DP)
         return logits[:, 0], kv
 
-    fn = shard_map(spmd, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    fn = jax.shard_map(spmd, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
     jitted = jax.jit(fn, donate_argnums=(3,))
     kinds_arr = (
         model.layer_kinds if has_kinds else jnp.zeros((), dtype=jnp.int32)

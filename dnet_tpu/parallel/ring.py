@@ -23,8 +23,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from dnet_tpu.utils.jax_compat import pcast_varying, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dnet_tpu.parallel.mesh import (
@@ -77,7 +75,7 @@ def _ring_spmd(model, mesh: Mesh, window_params, full_logits: bool = False,
         # x becomes device-varying over pp once layer-sharded params touch
         # it (over tp it stays value-invariant thanks to the psum seams);
         # mark the loop carry so the carry types line up.
-        x = pcast_varying(x, AXIS_PP)
+        x = lax.pcast(x, AXIS_PP, to="varying")
 
         def stage_iter(i, carry):
             x, kv = carry
@@ -117,7 +115,7 @@ def _ring_spmd(model, mesh: Mesh, window_params, full_logits: bool = False,
         logits = _bcast_from_rank0(logits, AXIS_PP)
         return logits[:, 0], kv
 
-    fn = shard_map(spmd, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    fn = jax.shard_map(spmd, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
     kinds_arr = model.layer_kinds if has_kinds else jnp.zeros((), dtype=jnp.int32)
     return fn, kinds_arr
 

@@ -32,6 +32,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from dnet_tpu.core.engine import LocalEngine, Session
@@ -45,7 +46,6 @@ from dnet_tpu.parallel.mesh import (
     kv_spec,
     window_param_specs,
 )
-from dnet_tpu.utils.jax_compat import pcast_varying, shard_map
 from dnet_tpu.utils.logger import get_logger
 
 log = get_logger()
@@ -277,7 +277,7 @@ class MeshShardEngine(LocalEngine):
             # x becomes device-varying over the size-1 certify axes once the
             # sharded params/kv touch it; mark it up front so the layer
             # scan's carry types line up.
-            x = pcast_varying(x, certify)
+            x = lax.pcast(x, certify, to="varying")
             x, kv = model.apply_window(
                 wp, x, kv, pos,
                 layer_kinds=kinds if has_kinds else None,
@@ -288,7 +288,7 @@ class MeshShardEngine(LocalEngine):
             x = jax.lax.psum(x, certify)
             return x, kv
 
-        core = shard_map(
+        core = jax.shard_map(
             window_core, mesh=mesh, in_specs=in_specs, out_specs=out_specs
         )
 
@@ -312,7 +312,7 @@ class MeshShardEngine(LocalEngine):
                 key = jax.tree.structure(window_params)
                 fn = progs.get(key)
                 if fn is None:
-                    seg_core = shard_map(
+                    seg_core = jax.shard_map(
                         window_core, mesh=mesh,
                         in_specs=(
                             self._window_specs_of(window_params),
@@ -457,7 +457,7 @@ class MeshShardEngine(LocalEngine):
         def window_lanes(wp, x, kv, pos, active, kinds):
             def one(x_row, kv_row, p, a):
                 kv1 = jax.tree.map(lambda t: t[:, None], kv_row)
-                xo = pcast_varying(x_row[None], certify)
+                xo = lax.pcast(x_row[None], certify, to="varying")
                 xo, kv1 = model.apply_window(
                     wp, xo, kv1, p,
                     layer_kinds=kinds if has_kinds else None,
@@ -470,7 +470,7 @@ class MeshShardEngine(LocalEngine):
                 one, in_axes=(0, kv_axes, 0, 0), out_axes=(0, kv_axes)
             )(x, kv, pos, active)
 
-        core = shard_map(
+        core = jax.shard_map(
             window_lanes, mesh=mesh,
             in_specs=(self._window_specs, P(), kvs, P(), P(), P()),
             out_specs=(P(), kvs),

@@ -255,7 +255,9 @@ class TpEngine(MeshShardEngine):
         return None
 
     def _certify_axes(self):
-        return (AXIS_BATCH,)
+        # nothing is sharded over the size-1 batch axis, so x never turns
+        # varying over it and there is nothing to certify back
+        return ()
 
     def _window_specs_of(self, tree):
         return tp_window_specs(tree)

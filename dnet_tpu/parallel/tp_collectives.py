@@ -33,7 +33,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dnet_tpu.utils.jax_compat import axis_size as _axis_size
+# Varying -> Invariant gather: every chip ends up holding the same gathered
+# value, and shard_map's vma check must be able to see that, because the
+# callers' out_specs promise replication over the tp axis exactly as they do
+# after lax.psum.  lax.all_gather types its result varying; jax 0.9.0 ships
+# the invariant form but has not yet exported it from jax.lax.
+from jax._src.lax.parallel import all_gather_invariant
 
 MODE_LOSSLESS = "lossless"
 MODE_Q8 = "q8"
@@ -136,7 +141,7 @@ def _q8_all_reduce(x: jnp.ndarray, axis: str, gs: int) -> jnp.ndarray:
     collects every chip's chunk.  Two quant passes total, independent of
     tp — not a per-hop requant chain.
     """
-    tp = _axis_size(axis)
+    tp = lax.axis_size(axis)
     if tp == 1:
         return x
     shape = x.shape
@@ -153,9 +158,9 @@ def _q8_all_reduce(x: jnp.ndarray, axis: str, gs: int) -> jnp.ndarray:
     bias = lax.all_to_all(bias, axis, split_axis=0, concat_axis=0)
     reduced = jnp.sum(_q8_dequant(codes, scale, bias, gs), axis=0)  # [chunk]
     codes1, scale1, bias1 = _q8_quant_chunks(reduced[None], gs)
-    codes1 = lax.all_gather(codes1, axis)  # [tp, 1, chunk]
-    scale1 = lax.all_gather(scale1, axis)
-    bias1 = lax.all_gather(bias1, axis)
+    codes1 = all_gather_invariant(codes1, axis)  # [tp, 1, chunk]
+    scale1 = all_gather_invariant(scale1, axis)
+    bias1 = all_gather_invariant(bias1, axis)
     full = _q8_dequant(codes1[:, 0], scale1[:, 0], bias1[:, 0], gs)
     return full.reshape(tp * chunk)[:S].reshape(shape).astype(orig_dtype)
 
@@ -164,7 +169,7 @@ def _q8_all_gather(x: jnp.ndarray, axis: str, gs: int) -> jnp.ndarray:
     """Grouped-int8 all-gather: quantize the local payload once, gather
     codes + scales, dequantize every chip's copy.  Stacks a new leading
     tp axis like ``lax.all_gather``."""
-    tp = _axis_size(axis)
+    tp = lax.axis_size(axis)
     if tp == 1:
         return x[None]
     shape = x.shape
@@ -174,9 +179,9 @@ def _q8_all_gather(x: jnp.ndarray, axis: str, gs: int) -> jnp.ndarray:
     K = -(-S // gs) * gs
     flat = jnp.pad(flat, (0, K - S))
     codes, scale, bias = _q8_quant_chunks(flat[None], gs)
-    codes = lax.all_gather(codes, axis)  # [tp, 1, K]
-    scale = lax.all_gather(scale, axis)
-    bias = lax.all_gather(bias, axis)
+    codes = all_gather_invariant(codes, axis)  # [tp, 1, K]
+    scale = all_gather_invariant(scale, axis)
+    bias = all_gather_invariant(bias, axis)
     full = _q8_dequant(codes[:, 0], scale[:, 0], bias[:, 0], gs)  # [tp, K]
     return full[:, :S].reshape((tp,) + shape).astype(orig_dtype)
 
@@ -200,12 +205,13 @@ def tp_all_gather(x: jnp.ndarray, axis) -> jnp.ndarray:
     """Collect per-chip shards over the tp axis (new leading axis).
 
     Lossless for plain string axes; grouped-int8 payloads for a
-    :class:`TpAxis` tagged ``q8``."""
+    :class:`TpAxis` tagged ``q8``.  The result is replicated over the axis
+    (typed invariant), like :func:`tp_all_reduce`'s."""
     if axis is None:
         return x[None]
     if isinstance(axis, TpAxis) and axis.mode == MODE_Q8:
         return _q8_all_gather(x, str(axis), axis.group_size)
-    return lax.all_gather(x, axis)
+    return all_gather_invariant(x, axis)
 
 
 # ---- host-side byte accounting + latency probe ----------------------------
@@ -272,7 +278,6 @@ def probe_collective_ms(
 
     from dnet_tpu.obs import metric
     from dnet_tpu.obs.jit import instrument_jit
-    from dnet_tpu.utils.jax_compat import pcast_varying, shard_map
 
     from jax.sharding import PartitionSpec as P
 
@@ -280,22 +285,22 @@ def probe_collective_ms(
 
     def reduce_body(v):
         # mark the replicated probe tensor varying so the reduction is
-        # legal under the vma checker (identity on 0.4.x)
-        return tp_all_reduce(pcast_varying(v, str(tp_axis)), tp_axis)
+        # legal under the vma checker
+        return tp_all_reduce(lax.pcast(v, str(tp_axis), to="varying"), tp_axis)
 
     def gather_body(v):
-        return tp_all_gather(pcast_varying(v, str(tp_axis)), tp_axis)
+        return tp_all_gather(lax.pcast(v, str(tp_axis), to="varying"), tp_axis)
 
     spec = P()
     fns = {
         "all_reduce": instrument_jit(
-            jax.jit(shard_map(
+            jax.jit(jax.shard_map(
                 reduce_body, mesh=mesh, in_specs=(spec,), out_specs=spec,
             )),
             "tp_collective",
         ),
         "all_gather": instrument_jit(
-            jax.jit(shard_map(
+            jax.jit(jax.shard_map(
                 gather_body, mesh=mesh, in_specs=(spec,),
                 out_specs=P(None),
             )),

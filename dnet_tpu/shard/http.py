@@ -194,10 +194,14 @@ class ShardHTTPServer:
         chaos = armed_summary()
         if chaos is not None:
             mesh["chaos"] = chaos
+        from dnet_tpu.ops.kernel_select import SELECTIONS, device_report
+
         return web.json_response(
             {
                 "status": "ok",
                 "role": "shard",
+                "device": device_report(),
+                "kernels": SELECTIONS.snapshot(),
                 "shard_id": rt.shard_id,
                 "model": rt.model_path or None,
                 "layers": list(compute.layers) if compute else [],
@@ -391,11 +395,9 @@ class ShardHTTPServer:
         return web.json_response({"status": "ok", "stage_time_s": stage_s})
 
     async def profile(self, request: web.Request) -> web.Response:
-        """Device microbenchmark: subprocess-isolated when the accelerator
-        allows a second client, in-process otherwise (reference
-        utils/profile_subproc.py pattern)."""
-        from dnet_tpu.parallel.profiler import profile_device_subprocess
+        """Device microbenchmark, in this process: it owns the chips."""
+        from dnet_tpu.parallel.profiler import profile_device_quick
 
         loop = asyncio.get_running_loop()
-        result = await loop.run_in_executor(None, profile_device_subprocess)
+        result = await loop.run_in_executor(None, profile_device_quick)
         return web.json_response({"status": "ok", "profile": result})

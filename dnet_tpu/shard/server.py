@@ -155,6 +155,10 @@ async def serve_async(args) -> None:
     from dnet_tpu.resilience.chaos import validate_startup
 
     validate_startup(role="shard")
+    # likewise refuse, now, a kernel override that must not reach the chip
+    from dnet_tpu.ops.kernel_select import kernel_backend
+
+    kernel_backend()
     shard_id = args.shard_name or f"shard-{socket.gethostname()}-{args.grpc_port}"
     runtime = ShardRuntime(shard_id, queue_size=args.queue_size)
     adapter = RingAdapter(

@@ -88,6 +88,7 @@ def _get_lib():
     if _lib is None and not _lib_failed:
         try:
             _lib = _load()
+            log.info("native host store serves checkpoint IO: %s", _LIB)
         except Exception as exc:  # missing toolchain / unsupported platform
             _lib_failed = True
             log.warning("native host store unavailable, using python IO: %s", exc)

@@ -3,6 +3,15 @@
 # scripts/run_two_shards_one_api.sh — manual topology split across shards).
 #
 # Usage: scripts/run_two_shards_one_api.sh /path/to/model [layer_split]
+#
+# One process for each chip.  A process that has touched JAX owns every chip
+# it can see, so three processes on one TPU host must not all see them all:
+# the API node computes nothing in ring mode and runs on the CPU backend, and
+# each shard is pinned to its own chip through libtpu's TPU_VISIBLE_CHIPS
+# (with the process bounds that make one chip a whole topology).  Override
+# SHARD0_CHIPS / SHARD1_CHIPS (e.g. "0,1" and "2,3" with
+# SHARD_CHIP_BOUNDS=1,2,1) to give a shard more than one.  Off a TPU host the
+# variables are inert; export JAX_PLATFORMS=cpu there as usual.
 set -euo pipefail
 
 MODEL="${1:?usage: $0 /path/to/model [split_layer]}"
@@ -31,10 +40,15 @@ EOF
 cleanup() { kill 0 2>/dev/null || true; }
 trap cleanup EXIT
 
+export TPU_PROCESS_BOUNDS=1,1,1
+export TPU_CHIPS_PER_PROCESS_BOUNDS="${SHARD_CHIP_BOUNDS:-1,1,1}"
+TPU_VISIBLE_CHIPS="${SHARD0_CHIPS:-0}" \
 python -m dnet_tpu.cli.shard --host 127.0.0.1 --http-port $S0_HTTP --grpc-port $S0_GRPC \
     --shard-name s0 --discovery none &
+TPU_VISIBLE_CHIPS="${SHARD1_CHIPS:-1}" \
 python -m dnet_tpu.cli.shard --host 127.0.0.1 --http-port $S1_HTTP --grpc-port $S1_GRPC \
     --shard-name s1 --discovery none &
+JAX_PLATFORMS=cpu \
 python -m dnet_tpu.cli.api --host 127.0.0.1 --http-port $API_HTTP --grpc-port $API_GRPC \
     --hostfile "$HOSTFILE" &
 

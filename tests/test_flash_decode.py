@@ -188,10 +188,39 @@ def test_sp_partials_merge_matches_dense(rng, pos):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("bits", [8, 4])
+def test_packed_int4_cache_stays_off_the_kernel(rng):
+    """Packed-int4 tiles (uint8 nibbles interleaved along the lane dim) have
+    no Mosaic lowering: eligibility refuses them IN CODE, so cached_attend
+    dequantizes through read_kv and the kernel streams f32 tiles."""
+    import jax.numpy as jnp
+
+    from dnet_tpu.core.kvcache import KVConfig, init_cache, read_kv
+    from dnet_tpu.ops.attention import attend, cached_attend, causal_mask
+    from dnet_tpu.ops.flash_decode import flash_decode_eligible
+
+    B, S, H, KVH, Hd = 1, 32, 4, 2, 16
+    cfg = KVConfig(
+        n_layers=1, batch=B, max_seq=S, n_kv_heads=KVH, head_dim=Hd,
+        quant_bits=4,
+    )
+    kvs = {k: v[0] for k, v in init_cache(cfg).items()}
+    q = jnp.asarray(rng.normal(size=(B, 1, H, Hd)), jnp.float32)
+    assert not flash_decode_eligible(q, kvs["k"])
+    for t in range(10):
+        k_new = jnp.asarray(rng.normal(size=(B, 1, KVH, Hd)), jnp.float32)
+        v_new = jnp.asarray(rng.normal(size=(B, 1, KVH, Hd)), jnp.float32)
+        got, kvs = cached_attend(
+            q, k_new, v_new, kvs, jnp.int32(t), None, causal=True
+        )
+    kc, vc = read_kv(kvs)
+    want = attend(q, kc, vc, mask=causal_mask(1, S, 9))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("bits", [8])
 def test_quantized_cache_matches_dense(rng, bits):
-    """Fused in-kernel dequant (int8 / packed int4 + per-slot scales) ==
-    dense attend over the read_kv-dequantized cache."""
+    """Fused in-kernel dequant (int8 + per-slot scales) == dense attend
+    over the read_kv-dequantized cache."""
     import jax.numpy as jnp
 
     from dnet_tpu.core.kvcache import KVConfig, init_cache, read_kv, write_kv
@@ -220,7 +249,7 @@ def test_quantized_cache_matches_dense(rng, bits):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("bits", [8, 4])
+@pytest.mark.parametrize("bits", [8])
 def test_rotating_quantized_matches_dense(rng, bits):
     """Quantized SWA ring buffer (the gpt_oss sliding layer's layout):
     per-slot scale rotation + in-kernel dequant + in-kernel ring-position

@@ -16,7 +16,7 @@ Two levels of evidence, neither needing TPU hardware:
 import numpy as np
 import pytest
 
-from dnet_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 pytestmark = [pytest.mark.core, pytest.mark.parallel]
 

@@ -2,7 +2,7 @@
 
 import jax
 
-from dnet_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 import numpy as np
 import pytest

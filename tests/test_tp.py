@@ -24,7 +24,7 @@ from dnet_tpu.parallel.tp_collectives import (  # noqa: E402
     tp_all_gather,
     tp_all_reduce,
 )
-from dnet_tpu.utils.jax_compat import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 
 
 @pytest.fixture(scope="module")
