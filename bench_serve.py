@@ -758,8 +758,9 @@ def main(argv=None) -> int:
     from dnet_tpu.config import configure_compile_cache
 
     configure_compile_cache()
-    # honest attribution needs the obs fences; the bench opts in for its
-    # own process (a remote target keeps its own setting)
+    # the tick-record ring and the [PROFILE] lines are gated on obs; the
+    # bench opts in for its own process (a remote target keeps its own
+    # setting).  The span table (dnet_span_ms) is always on.
     os.environ.setdefault("DNET_OBS_ENABLED", "1")
     args = build_parser().parse_args(argv)
     if not args.base_url and not args.model:

@@ -22,7 +22,8 @@ Pass catalog (the original scripts/check_metrics_names.py passes 1-8):
   enums, both directions
 - DL016 membership    — stale-epoch kinds / recovery outcomes <-> declared
   enums, both directions
-- DL017 attribution   — step phases / jit fns / device-mem kinds <->
+- DL017 attribution   — host spans (series AND call sites) / decode
+  dispatch widths / token sources / jit fns / device-mem kinds <->
   declared enums, both directions
 - DL018 sanitizer     — dsan check codes / zombie-thread kinds <->
   declared enums, both directions (pass 9)
@@ -173,9 +174,14 @@ _REQUIRED_FAMILIES = (
     "dnet_recovery_duration_seconds",
     "dnet_shard_rejoins_total",
     # performance attribution (obs/phases.py, obs/jit.py) — the loadgen
-    # report's phase/JIT/memory sections and the p99 cross-check (pass 8)
-    # depend on these
-    "dnet_step_phase_ms",
+    # report's span/JIT/memory sections, the benchmark's per-layer readers
+    # and the label cross-check (pass 8) depend on these
+    "dnet_span_ms",
+    "dnet_decode_dispatch_total",
+    "dnet_decode_slot_steps_total",
+    "dnet_decode_lane_steps_total",
+    "dnet_decode_tokens_total",
+    "dnet_decode_buffer_dropped_total",
     "dnet_jit_compiles_total",
     "dnet_jit_compile_ms",
     "dnet_device_mem_bytes",
@@ -189,6 +195,10 @@ _REQUIRED_FAMILIES = (
     # dashboards and the label cross-check (pass 10) depend on these
     "dnet_sched_tick_ms",
     "dnet_sched_batch_tokens",
+    "dnet_sched_queue_wait_ms",
+    "dnet_sched_prefill_wall_ms",
+    "dnet_sched_prefill_ticks",
+    "dnet_sched_deliver_wait_ms",
     "dnet_sched_preemptions_total",
     "dnet_sched_queue_depth",
     # overlapped wire pipeline (transport/wire_pipeline.py) — the per-hop
@@ -443,28 +453,86 @@ def check_attribution_labels(errors: list) -> int:
     """Pass 8: the performance-attribution families must agree with the
     declared enums (dnet_tpu/obs/phases.py) both ways.  Histogram families
     expose per-label `_bucket`/`_sum`/`_count` series, so presence is
-    checked on `_count` and strays on any exposition suffix."""
+    checked on `_count` and strays on any exposition suffix.  Also: every
+    `span("...")` / `observe_span("...")` literal in the tree names a
+    declared host span and every declared span has a call site (by
+    constant or literal), and BatchedEngine.CHUNK_BUCKETS is exactly the
+    declared dispatch widths above 1."""
     from dnet_tpu.obs import get_registry
-    from dnet_tpu.obs.phases import DEVICE_MEM_KINDS, JIT_FNS, STEP_PHASES
+    from dnet_tpu.obs import phases
+    from dnet_tpu.obs.phases import (
+        DECODE_CHUNK_WIDTHS,
+        DECODE_TOKEN_SOURCES,
+        DEVICE_MEM_KINDS,
+        HOST_SPANS,
+        JIT_FNS,
+    )
 
     text = get_registry().expose()
     n = 0
-    for phase in STEP_PHASES:
+    for name in HOST_SPANS:
         n += 1
-        if f'dnet_step_phase_ms_count{{phase="{phase}"}}' not in text:
+        if f'dnet_span_ms_count{{span="{name}"}}' not in text:
             errors.append(
-                f"attribution: obs.phases.STEP_PHASES value {phase!r} has "
-                f"no dnet_step_phase_ms series (pre-touch it in "
+                f"attribution: obs.phases.HOST_SPANS value {name!r} has "
+                f"no dnet_span_ms series (pre-touch it in "
                 f"dnet_tpu.obs._register_core)"
             )
     for m in re.finditer(
-        r'dnet_step_phase_ms(?:_bucket|_sum|_count)\{phase="([^"]+)"', text
+        r'dnet_span_ms(?:_bucket|_sum|_count)\{span="([^"]+)"', text
     ):
-        if m.group(1) not in STEP_PHASES:
+        if m.group(1) not in HOST_SPANS:
             errors.append(
-                f"attribution: exposed dnet_step_phase_ms phase label "
-                f"{m.group(1)!r} is not declared in obs.phases.STEP_PHASES"
+                f"attribution: exposed dnet_span_ms span label "
+                f"{m.group(1)!r} is not declared in obs.phases.HOST_SPANS"
             )
+    # call sites: span(SPAN_X, ...) by constant, or by literal
+    const_of = {
+        k: v for k, v in vars(phases).items()
+        if k.startswith("SPAN_") and isinstance(v, str)
+    }
+    used = set()
+    site_re = re.compile(
+        r"""\b(?:span|observe_span)\(\s*(?:(?P<c>SPAN_[A-Z_]+)|"""
+        r"""(?P<q>['"])(?P<lit>dnet\.[^'"]+)(?P=q))"""
+    )
+    for path in _scan_paths():
+        if not path.is_file() or path.name == "metrics_checks.py":
+            continue
+        for m in site_re.finditer(path.read_text()):
+            name = const_of.get(m.group("c")) if m.group("c") else m.group("lit")
+            n += 1
+            if name not in HOST_SPANS:
+                errors.append(
+                    f"attribution: {path.relative_to(REPO)} opens span "
+                    f"{m.group(0)!r}, not declared in obs.phases.HOST_SPANS"
+                )
+            else:
+                used.add(name)
+    for name in HOST_SPANS:
+        if name not in used:
+            errors.append(
+                f"attribution: obs.phases.HOST_SPANS declares {name!r} but "
+                f"no span()/observe_span() call site opens it"
+            )
+    n += _cross_check_labels(
+        errors, text, "dnet_decode_dispatch_total", "r",
+        tuple(str(w) for w in DECODE_CHUNK_WIDTHS),
+        "obs.phases.DECODE_CHUNK_WIDTHS",
+    )
+    n += _cross_check_labels(
+        errors, text, "dnet_decode_tokens_total", "source",
+        DECODE_TOKEN_SOURCES, "obs.phases.DECODE_TOKEN_SOURCES",
+    )
+    from dnet_tpu.core.batch import BatchedEngine
+
+    n += 1
+    if set(BatchedEngine.CHUNK_BUCKETS) | {1} != set(DECODE_CHUNK_WIDTHS):
+        errors.append(
+            f"attribution: BatchedEngine.CHUNK_BUCKETS "
+            f"{BatchedEngine.CHUNK_BUCKETS!r} + 1 != "
+            f"obs.phases.DECODE_CHUNK_WIDTHS {DECODE_CHUNK_WIDTHS!r}"
+        )
     n += _cross_check_labels(
         errors, text, "dnet_jit_compiles_total", "fn",
         JIT_FNS, "obs.phases.JIT_FNS",

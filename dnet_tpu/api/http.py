@@ -216,15 +216,17 @@ class ApiHTTPServer:
                 # segment (obs/critical_path.py): the one leg of a
                 # request's story that happens after the driver hands a
                 # chunk back
-                from dnet_tpu.obs import get_recorder
+                from dnet_tpu.obs import get_recorder, observe_span
+                from dnet_tpu.obs.phases import SPAN_SSE_FLUSH
 
                 t_w = time.perf_counter()
                 for payload in reshape(chunk):
                     await resp.write(f"data: {payload}\n\n".encode())
-                get_recorder().span(
-                    chunk.id, "sse_flush",
-                    (time.perf_counter() - t_w) * 1000.0,
-                )
+                flush_ms = (time.perf_counter() - t_w) * 1000.0
+                get_recorder().span(chunk.id, "sse_flush", flush_ms)
+                # histogram only: this coroutine awaits, and a profiler
+                # annotation must open and close without yielding its thread
+                observe_span(SPAN_SSE_FLUSH, flush_ms)
 
             try:
                 if first is not None:

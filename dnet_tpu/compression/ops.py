@@ -195,11 +195,13 @@ def _topk_column_mask(norms: jnp.ndarray, keep: int) -> jnp.ndarray:
 # bitmask convention decompress relies on).
 
 
+@jax.named_scope("wire_encode")
 def _wire_cast_impl(x2, wire_np_dtype):
     """Lossless hop codec: cast to the wire dtype on device."""
     return x2.astype(wire_np_dtype)
 
 
+@jax.named_scope("wire_encode")
 def _wire_sparse_impl(x2, keep):
     """sparse_v1 device half: (mask bool[D], kept [R, keep]) — top-k
     column selection by L2 norm, gathered in ascending column order."""
@@ -240,6 +242,7 @@ def quantize_q8(kept: jnp.ndarray, gs: int):
     return codes.reshape(R, G * gs)[:, :K], scale, mn
 
 
+@jax.named_scope("wire_encode")
 def _wire_q8_impl(x2, keep, gs, wire_np_dtype):
     """qsparse8_v1 device half: (mask, codes u8, scale f32, bias f32) —
     top-k column selection + the shared quantize_q8 math.

@@ -54,20 +54,27 @@ from dnet_tpu.kv import (
     ragged_enabled,
 )
 from dnet_tpu.kv.store import _bucket_pow2
-from dnet_tpu.obs import get_recorder, metric, obs_enabled
+from dnet_tpu.obs import get_recorder, metric, span
 from dnet_tpu.obs.jit import instrument_jit
 from dnet_tpu.obs.phases import (
-    PHASE_COMPUTE,
-    PHASE_KV_GATHER,
-    PHASE_KV_SCATTER,
-    PHASE_SAMPLE,
+    DECODE_CHUNK_WIDTHS,
+    SPAN_DECODE_KV_GATHER,
+    SPAN_DECODE_KV_SCATTER,
+    SPAN_DECODE_LAUNCH,
+    SPAN_DECODE_PREPARE,
+    SPAN_DECODE_READBACK,
+    SPAN_DECODE_UNPACK,
 )
 from dnet_tpu.utils.logger import get_logger
 
 log = get_logger()
 
-_PHASE_MS = metric("dnet_step_phase_ms")
 _DECODE_STEP_MS = metric("dnet_decode_step_ms")
+_DECODE_DISPATCHES = metric("dnet_decode_dispatch_total")
+_DECODE_SLOT_STEPS = metric("dnet_decode_slot_steps_total")
+_DECODE_LANE_STEPS = metric("dnet_decode_lane_steps_total")
+_DECODE_TOKENS = metric("dnet_decode_tokens_total")
+_DECODE_BUFFER_DROPPED = metric("dnet_decode_buffer_dropped_total")
 
 
 class BatchedEngine:
@@ -206,7 +213,7 @@ class BatchedEngine:
                 )
         # ragged paged attention (DNET_KV_RAGGED=1): decode attends the
         # pool in place through the page tables; the dense gather/scatter
-        # round trip — and its kv_gather/kv_scatter phases — stop existing.
+        # round trip — and its kv_gather/kv_scatter spans — stop existing.
         # Dense-gather stays the fallback for everything the kernel
         # refuses (quantized caches, non-llama attention stacks), on top
         # of the session layouts BlockStore itself already refused.
@@ -247,6 +254,9 @@ class BatchedEngine:
         # fused-chunk results not yet handed to the driver (nonce -> FIFO);
         # dropped with the session like the pipelined engine's buffers
         self._buffer: Dict[str, List[SampleResult]] = {}
+        # (R, lanes) of the dispatch the last decode_batch call sent to the
+        # device; (0, 0) when every lane was answered from the buffer
+        self.last_dispatch: Tuple[int, int] = (0, 0)
         # per-nonce [blocks, emitted] acceptance stats (adaptive spec gate)
         self._spec_stats: Dict[str, List[int]] = {}
         self.hist = (
@@ -260,6 +270,7 @@ class BatchedEngine:
     def _build(self) -> None:
         model = self.eng.model
 
+        @jax.named_scope("batched_step")
         def one(wp, ep, token, kv, pos, active, sp, key, counts):
             """Single-example decode+sample; vmapped over the slot axis.
             kv leaves arrive batch-axis-stripped [L, S, ...]: re-add B=1."""
@@ -305,6 +316,7 @@ class BatchedEngine:
         if L > 0:
             from dnet_tpu.core.spec import accept_drafts, ngram_draft
 
+            @jax.named_scope("batched_spec")
             def one_spec(wp, ep, token, hist, kv, pos, active):
                 """One per-lane verify block (vmapped): commit the fed
                 token, draft L by prompt-lookup against THIS lane's history,
@@ -375,6 +387,7 @@ class BatchedEngine:
 
         vsample = jax.vmap(one_sample, in_axes=(0, 0, sp_axes, 0, 0))
 
+        @jax.named_scope("paged_attend")
         def ragged_step(wp, ep, token, pool, tables, pos, active, sp, keys,
                         counts):
             """One batched decode step against the pool (READ-ONLY here):
@@ -415,6 +428,7 @@ class BatchedEngine:
             step = self._ragged_step_fn
             bt = self._kv_cfg.block_tokens
 
+            @jax.named_scope("paged_attend")
             def chunk(wp, ep, token, pool, tables, pos, active, sp, keys,
                       counts):
                 def body(carry, _):
@@ -453,14 +467,17 @@ class BatchedEngine:
         return fn
 
     # chunk widths tried largest-first (bounded compiled-program set, same
-    # discipline as LocalEngine.DECODE_CHUNK_BUCKETS)
-    CHUNK_BUCKETS = (16, 8, 4, 2)
+    # discipline as LocalEngine.DECODE_CHUNK_BUCKETS); declared in
+    # obs/phases.py so dnet_decode_dispatch_total{r=} carries exactly these
+    CHUNK_BUCKETS = tuple(sorted((w for w in DECODE_CHUNK_WIDTHS if w > 1),
+                                 reverse=True))
 
     def _chunk_fn(self, R: int):
         fn = self._chunks.get(R)
         if fn is None:
             vstep = self._vmapped
 
+            @jax.named_scope("batched_chunk")
             def chunk(wp, ep, token, kv, pos, active, sp, keys, counts):
                 def body(carry, _):
                     token, kv, pos, keys, counts = carry
@@ -498,7 +515,11 @@ class BatchedEngine:
         return slot
 
     def free_slot(self, nonce: str) -> None:
-        self._buffer.pop(nonce, None)
+        dropped = self._buffer.pop(nonce, None)
+        if dropped:
+            # computed on the device, never delivered (warm-up's throwaway
+            # session pops its own buffer and is not counted)
+            _DECODE_BUFFER_DROPPED.inc(len(dropped))
         self._spec_stats.pop(nonce, None)
         stash = self._adopt.pop(nonce, None)
         if stash is not None and self.kv_pool is not None:
@@ -801,53 +822,179 @@ class BatchedEngine:
         dispatch + one packed read per R tokens per lane (the same contract
         as LocalEngine.decode_chunk / the pipelined engine's rotations).
         The active set is FIXED across a chunk, so the stream is
-        bit-identical to R serial steps with the same request set."""
+        bit-identical to R serial steps with the same request set.
+
+        Host spans (obs/phases.py): prepare, then — only when some lane's
+        buffer is empty — [kv_gather] launch [kv_scatter] readback unpack.
+        Nothing is fenced: launch is an enqueue, readback is the host
+        blocked on the device.  `last_dispatch` says what this call sent
+        to the device: (R, lanes), (0, 0) when every lane was answered
+        from the buffer."""
         errors: Dict[str, str] = {}
+        self.last_dispatch = (0, 0)
         if not requests:
             return {}, errors
-        # buffered tokens from an earlier fused chunk resolve first
+        t_parent = time.perf_counter()
+        plan = None
+        with span(SPAN_DECODE_PREPARE):
+            # buffered tokens from an earlier fused chunk resolve first
+            out_buf, requests = self._pop_buffered(requests)
+            # per-lane speculation: greedy lanes with budget to spare verify
+            # a drafted block instead of stepping once; they advance by
+            # their OWN acceptance count (buffered), while the remaining
+            # lanes take the plain batched step below — the two programs
+            # touch disjoint lanes
+            spec_reqs = self._pick_spec_lanes(requests, budgets)
+            if requests and not spec_reqs:
+                plan = self._plan_dispatch(requests, budgets, errors)
+        if spec_reqs:
+            spec_out = self._decode_spec_lanes(spec_reqs)
+            _DECODE_TOKENS.labels(source="spec").inc(len(spec_out))
+            out_buf.update(spec_out)
+            requests = {n: r for n, r in requests.items() if n not in spec_reqs}
+            if requests:
+                with span(SPAN_DECODE_PREPARE):
+                    plan = self._plan_dispatch(requests, budgets, errors)
+        if plan is None:
+            return out_buf, errors
+        order, R, dev, table_ids = plan
+        lanes = len(order)
+        paged = self.kv_pool is not None
+        if paged and self.kv_ragged:
+            # ragged paged attention: the pool is attended IN PLACE through
+            # the page tables and the new rows block-append — the gather/
+            # scatter round trip (and its two spans) does not exist here
+            with span(SPAN_DECODE_LAUNCH, R=R, lanes=lanes):
+                src = self._dispatch_ragged(order, R, dev, table_ids)
+        else:
+            if paged:
+                with span(SPAN_DECODE_KV_GATHER):
+                    kv_in = self.kv_store.gather(table_ids)
+            else:
+                kv_in = self.kv
+            token_d, pos_d, active_d, sp = dev
+            args = (
+                self.eng.window_params,
+                self.eng.edge_params,
+                token_d,
+                kv_in,
+                pos_d,
+                active_d,
+                sp,
+                self.keys,
+                self.counts,
+            )
+            with span(SPAN_DECODE_LAUNCH, R=R, lanes=lanes):
+                if R > 1:
+                    src, kv_out, self.counts, self.keys = self._chunk_fn(R)(*args)
+                else:
+                    src, kv_out, self.counts, self.keys = self._step(*args)
+            if paged:
+                # persist ONLY the blocks this step wrote (block-append
+                # write); the contiguous view kv_out is scratch and dies here
+                with span(SPAN_DECODE_KV_SCATTER):
+                    bt = self._kv_cfg.block_tokens
+                    triples = []
+                    for _nonce, slot in order.items():
+                        p0 = int(self.pos[slot])
+                        tbl = self._tables[slot]
+                        triples.extend(
+                            (slot, b, tbl.blocks[b])
+                            for b in range(p0 // bt, (p0 + R - 1) // bt + 1)
+                        )
+                    self.kv_store.scatter(kv_out, triples)
+            else:
+                self.kv = kv_out
+        # ONE packed device->host read per field per dispatch (the
+        # pipelined engine's drain pattern), then host-side slicing —
+        # per-element device gathers would reintroduce the dispatch
+        # overhead the fused chunk exists to remove.  The first read blocks
+        # until the device has finished the dispatch.
+        with span(SPAN_DECODE_READBACK):
+            toks = np.asarray(src.token)
+            lps = np.asarray(src.logprob)
+            tts = np.asarray(src.top_tokens)
+            tlps = np.asarray(src.top_logprobs)
+        with span(SPAN_DECODE_UNPACK):
+            now = time.time()
+            out: Dict[str, SampleResult] = dict(out_buf)
+            for nonce, slot in order.items():
+                self.pos[slot] += R
+                self.last_used[slot] = now
+                if R > 1:
+                    rows = [
+                        SampleResult(toks[k, slot], lps[k, slot],
+                                     tts[k, slot], tlps[k, slot])
+                        for k in range(R)
+                    ]
+                    out[nonce] = rows[0]
+                    self._buffer.setdefault(nonce, []).extend(rows[1:])
+                else:
+                    out[nonce] = SampleResult(
+                        token=toks[slot], logprob=lps[slot],
+                        top_tokens=tts[slot], top_logprobs=tlps[slot],
+                    )
+        # what the fused-chunk path did: the device computed R steps for
+        # every slot, lanes asked for R x lanes of them, and the driver
+        # received one token per lane now (the rest wait in the buffer)
+        self.last_dispatch = (R, lanes)
+        _DECODE_DISPATCHES.labels(r=str(R)).inc()
+        _DECODE_SLOT_STEPS.inc(R * self.slots)
+        _DECODE_LANE_STEPS.inc(R * lanes)
+        _DECODE_TOKENS.labels(source="dispatch").inc(lanes)
+        # per-token share, observed tokens-served times: the family's
+        # count stays == tokens across the local / chunked / speculative /
+        # batched paths (LocalEngine's amortization convention), and the
+        # sum stays == this call's wall time, prepare through unpack
+        n_tok = R * lanes
+        per_tok_ms = (time.perf_counter() - t_parent) * 1000.0 / n_tok
+        _DECODE_STEP_MS.observe_n(per_tok_ms, n_tok)
+        return out, errors
+
+    def _pop_buffered(self, requests):
+        """Answer every lane that still holds rows of an earlier fused
+        dispatch with the next one; returns (results, remaining requests)."""
         out_buf: Dict[str, SampleResult] = {}
         now = time.time()
-        for nonce in list(requests):
+        for nonce in requests:
             buf = self._buffer.get(nonce)
             if buf:
                 out_buf[nonce] = buf.pop(0)
                 slot = self.slot_of.get(nonce)
                 if slot is not None:
                     self.last_used[slot] = now
-        requests = {n: r for n, r in requests.items() if n not in out_buf}
-        if not requests:
-            return out_buf, errors
+        if out_buf:
+            _DECODE_TOKENS.labels(source="buffer").inc(len(out_buf))
+            requests = {n: r for n, r in requests.items() if n not in out_buf}
+        return out_buf, requests
 
-        # per-lane speculation: greedy lanes with budget to spare verify a
-        # drafted block instead of stepping once; they advance by their OWN
-        # acceptance count (buffered), while the remaining lanes take the
-        # plain batched step below — the two programs touch disjoint lanes
-        spec_out: Dict[str, SampleResult] = {}
-        if self.spec_lookahead > 0 and budgets:
-            spec_reqs = {}
-            for nonce, (tok, dec) in requests.items():
-                slot = self.slot_of.get(nonce)
-                budget = budgets.get(nonce) or 1
-                if (
-                    slot is not None
-                    and dec.temperature == 0.0
-                    and not dec.logprobs
-                    and dec.repetition_penalty == 1.0
-                    and not dec.logit_bias  # verify argmaxes are unbiased
-                    and budget > 1
-                    and self.pos[slot] + self.spec_lookahead + 1 <= self.max_seq
-                    and self._spec_worthwhile(nonce)
-                ):
-                    spec_reqs[nonce] = (tok, slot, budget)
-            if spec_reqs:
-                spec_out = self._decode_spec_lanes(spec_reqs)
-                requests = {
-                    n: r for n, r in requests.items() if n not in spec_reqs
-                }
-        out_buf = {**out_buf, **spec_out}
-        if not requests:
-            return out_buf, errors
+    def _pick_spec_lanes(self, requests, budgets) -> Dict[str, Tuple[int, int, int]]:
+        """Lanes that verify a drafted block this call: nonce -> (token,
+        slot, budget)."""
+        spec_reqs: Dict[str, Tuple[int, int, int]] = {}
+        if self.spec_lookahead <= 0 or not budgets:
+            return spec_reqs
+        for nonce, (tok, dec) in requests.items():
+            slot = self.slot_of.get(nonce)
+            budget = budgets.get(nonce) or 1
+            if (
+                slot is not None
+                and dec.temperature == 0.0
+                and not dec.logprobs
+                and dec.repetition_penalty == 1.0
+                and not dec.logit_bias  # verify argmaxes are unbiased
+                and budget > 1
+                and self.pos[slot] + self.spec_lookahead + 1 <= self.max_seq
+                and self._spec_worthwhile(nonce)
+            ):
+                spec_reqs[nonce] = (tok, slot, budget)
+        return spec_reqs
+
+    def _plan_dispatch(self, requests, budgets, errors):
+        """Everything the host prepares for one dispatch: per-slot numpy
+        parameter rows, the chunk width, page-table extension, and the
+        uploads.  Returns (order, R, device arrays, table ids) or None when
+        no lane is left to step."""
         token = np.zeros((self.slots, 1), dtype=np.int32)
         active = np.zeros(self.slots, dtype=bool)
         pos = np.zeros(self.slots, dtype=np.int32)
@@ -882,7 +1029,7 @@ class BatchedEngine:
             b_ids[slot], b_vals[slot] = encode_logit_bias(dec.logit_bias)
             order[nonce] = slot
         if not order:
-            return out_buf, errors
+            return None
 
         sp = SampleParams(
             temperature=jnp.asarray(temp),
@@ -901,177 +1048,53 @@ class BatchedEngine:
             cap = min((budgets.get(n) or 1) for n in order)
             cap = min(cap, *(int(self.max_seq - self.pos[s]) for s in order.values()))
             R = next((r for r in self.CHUNK_BUCKETS if r <= cap), 1)
-        # performance attribution (obs/phases.py): when obs is enabled the
-        # phase boundaries are FENCED (block_until_ready) so kv_gather /
-        # compute / kv_scatter / sample carry honest device time instead of
-        # async-dispatch noise — the device-sync gating contract from
-        # dnet_tpu.obs.  The parent dnet_decode_step_ms observation always
-        # records (the step ends in a synchronous host readback anyway).
-        attribute = obs_enabled()
-        t_parent = time.perf_counter()
+        table_ids = None
         if self.kv_pool is not None:
             # block-table extension is admission: a lane the pool cannot
             # cover fails ALONE with the typed backpressure message
             R = self._paged_extend(order, errors, active, R)
             if not order:
-                return out_buf, errors
-        paged = self.kv_pool is not None
-        if paged and self.kv_ragged:
-            # ragged paged attention: the pool is attended IN PLACE through
-            # the page tables and the new rows block-append — the gather/
-            # scatter round trip (and its two phases) does not exist here
-            src = self._dispatch_ragged(order, active, R, token, pos, sp,
-                                        attribute)
-        else:
-            if paged:
-                t0 = time.perf_counter()
-                kv_in = self.kv_store.gather(
-                    self._table_ids(order if R == 1 else None)
-                )
-                if attribute:
-                    jax.block_until_ready(kv_in)
-                    self._observe_phase(PHASE_KV_GATHER, t0, order, R)
-            else:
-                kv_in = self.kv
-            args = (
-                self.eng.window_params,
-                self.eng.edge_params,
-                jnp.asarray(token),
-                kv_in,
-                jnp.asarray(pos),
-                jnp.asarray(active),
-                sp,
-                self.keys,
-                self.counts,
-            )
-            t0 = time.perf_counter()
-            if R > 1:
-                stacked, kv_out, self.counts, self.keys = self._chunk_fn(R)(*args)
-                src = stacked
-            else:
-                res, kv_out, self.counts, self.keys = self._step(*args)
-                src = res
-            if attribute:
-                jax.block_until_ready((src, kv_out))
-                self._observe_phase(PHASE_COMPUTE, t0, order, R)
-            if paged:
-                # persist ONLY the blocks this step wrote (block-append
-                # write); the contiguous view kv_out is scratch and dies here
-                bt = self._kv_cfg.block_tokens
-                triples = []
-                for _nonce, slot in order.items():
-                    p0 = int(self.pos[slot])
-                    tbl = self._tables[slot]
-                    triples.extend(
-                        (slot, b, tbl.blocks[b])
-                        for b in range(p0 // bt, (p0 + R - 1) // bt + 1)
-                    )
-                t0 = time.perf_counter()
-                self.kv_store.scatter(kv_out, triples)
-                if attribute:
-                    jax.block_until_ready(self.kv_store.kv)
-                    self._observe_phase(PHASE_KV_SCATTER, t0, order, R)
-            else:
-                self.kv = kv_out
-        now = time.time()
-        out: Dict[str, SampleResult] = dict(out_buf)
-        # ONE packed device->host read per field per dispatch (the
-        # pipelined engine's drain pattern), then host-side slicing —
-        # per-element device gathers would reintroduce the dispatch
-        # overhead the fused chunk exists to remove
-        t0 = time.perf_counter()
-        toks = np.asarray(src.token)
-        lps = np.asarray(src.logprob)
-        tts = np.asarray(src.top_tokens)
-        tlps = np.asarray(src.top_logprobs)
-        if attribute:
-            self._observe_phase(PHASE_SAMPLE, t0, order, R)
-        for nonce, slot in order.items():
-            self.pos[slot] += R
-            self.last_used[slot] = now
-            if R > 1:
-                rows = [
-                    SampleResult(toks[k, slot], lps[k, slot],
-                                 tts[k, slot], tlps[k, slot])
-                    for k in range(R)
-                ]
-                out[nonce] = rows[0]
-                self._buffer.setdefault(nonce, []).extend(rows[1:])
-            else:
-                out[nonce] = SampleResult(
-                    token=toks[slot], logprob=lps[slot],
-                    top_tokens=tts[slot], top_logprobs=tlps[slot],
-                )
-        # per-token share, observed tokens-served times: the family's
-        # count stays == tokens across the local / chunked / speculative /
-        # batched paths (LocalEngine's amortization convention), and the
-        # sum stays == dispatch wall so the phase sums still account for it
-        n_tok = R * len(order)
-        per_tok_ms = (time.perf_counter() - t_parent) * 1000.0 / n_tok
-        _DECODE_STEP_MS.observe_n(per_tok_ms, n_tok)
-        return out, errors
+                return None
+            table_ids = self._table_ids(order if R == 1 else None)
+            if self.kv_ragged:
+                table_ids = jnp.asarray(table_ids)
+        dev = (jnp.asarray(token), jnp.asarray(pos), jnp.asarray(active), sp)
+        return order, R, dev, table_ids
 
-    def _dispatch_ragged(
-        self,
-        order: Dict[str, int],
-        active: np.ndarray,
-        R: int,
-        token: np.ndarray,
-        pos: np.ndarray,
-        sp: SampleParams,
-        attribute: bool,
-    ):
+    def _dispatch_ragged(self, order: Dict[str, int], R: int, dev, tables):
         """One ragged decode dispatch (R == 1: the read-only paged_attend
         program + the jitted kv_append block-append; R > 1: the fused
-        chunk carrying the donated pool).  Everything here is the compute
-        phase — kv_gather/kv_scatter stop existing on this path."""
-        tables = jnp.asarray(self._table_ids(order if R == 1 else None))
+        chunk carrying the donated pool).  All of it is the launch span —
+        no gather, no scatter on this path."""
+        token_d, pos_d, active_d, sp = dev
         args = (
             self.eng.window_params,
             self.eng.edge_params,
-            jnp.asarray(token),
+            token_d,
             self.kv_store.kv,
             tables,
-            jnp.asarray(pos, dtype=jnp.int32),
-            jnp.asarray(active),
+            pos_d,
+            active_d,
             sp,
             self.keys,
             self.counts,
         )
-        t0 = time.perf_counter()
         if R > 1:
             stacked, pool, self.counts, self.keys = self._ragged_chunk_fn(R)(*args)
             self.kv_store.kv = pool
-            src = stacked
-        else:
-            res, rows, self.counts, self.keys = self._ragged_step(*args)
-            bt = self._kv_cfg.block_tokens
-            # inactive-lane sentinel: past the block axis, never negative
-            # (see BlockStore append)
-            phys = np.full(self.slots, self._kv_cfg.pool_blocks, dtype=np.int32)
-            off = np.zeros(self.slots, dtype=np.int32)
-            for _nonce, slot in order.items():
-                p0 = int(self.pos[slot])
-                phys[slot] = self._tables[slot].blocks[p0 // bt]
-                off[slot] = p0 % bt
-            self.kv_store.append_rows(rows, phys, off)
-            src = res
-        if attribute:
-            jax.block_until_ready((src, self.kv_store.kv))
-            self._observe_phase(PHASE_COMPUTE, t0, order, R)
-        return src
-
-    def _observe_phase(
-        self, phase: str, t0: float, order: Dict[str, int], R: int
-    ) -> None:
-        """One histogram observation per dispatch, plus a recorder span on
-        every participating request's timeline (the recorder applies its
-        own trace sampling)."""
-        dur_ms = (time.perf_counter() - t0) * 1000.0
-        _PHASE_MS.labels(phase=phase).observe(dur_ms)
-        rec = get_recorder()
-        for nonce in order:
-            rec.span(nonce, phase, dur_ms, batch=len(order), chunk=R)
+            return stacked
+        res, rows, self.counts, self.keys = self._ragged_step(*args)
+        bt = self._kv_cfg.block_tokens
+        # inactive-lane sentinel: past the block axis, never negative
+        # (see BlockStore append)
+        phys = np.full(self.slots, self._kv_cfg.pool_blocks, dtype=np.int32)
+        off = np.zeros(self.slots, dtype=np.int32)
+        for _nonce, slot in order.items():
+            p0 = int(self.pos[slot])
+            phys[slot] = self._tables[slot].blocks[p0 // bt]
+            off[slot] = p0 % bt
+        self.kv_store.append_rows(rows, phys, off)
+        return res
 
     # adaptive spec gate, same thresholds/semantics as LocalEngine's
     SPEC_WARMUP_BLOCKS = LocalEngine.SPEC_WARMUP_BLOCKS

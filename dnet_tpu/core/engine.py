@@ -439,10 +439,15 @@ class LocalEngine:
         # donate kv (arg 3): each step reuses the cache buffers in place
         # (instrumented: dnet_jit_compiles_total{fn=} separates warmup
         # compiles from steady state in load reports)
+        @jax.named_scope("local_prefill")
+        def prefill_logits(window_params, edge_params, tokens, kv, pos, last_idx):
+            return full_logits(window_params, edge_params, tokens, kv, pos, last_idx)
+
         self._forward = instrument_jit(
-            jax.jit(full_logits, donate_argnums=(3,)), "local_prefill"
+            jax.jit(prefill_logits, donate_argnums=(3,)), "local_prefill"
         )
 
+        @jax.named_scope("local_decode")
         def decode_and_sample(window_params, edge_params, token, kv, pos, sp, key, counts,
                               plan=None):
             logits, kv = full_logits(window_params, edge_params, token, kv, pos, 0)
@@ -456,6 +461,7 @@ class LocalEngine:
             "local_decode",
         )
 
+        @jax.named_scope("local_decode_chunk")
         def decode_chunk_fn(window_params, edge_params, token, kv, pos, sp, key, counts,
                             n_steps, plan=None):
             """n_steps decode iterations fused into ONE XLA program: the

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from dnet_tpu.core.types import DecodingParams
+from dnet_tpu.obs.phases import SCOPE_SAMPLE
 
 MAX_TOP_LOGPROBS = 20  # static upper bound (OpenAI API max); request slices host-side
 # static per-request logit_bias capacity — the full OpenAI API cap (300
@@ -141,6 +142,7 @@ def pack_chunk_results(results: SampleResult, with_logprobs: bool) -> jnp.ndarra
     return results.token[..., None].astype(jnp.float32)
 
 
+@jax.named_scope(SCOPE_SAMPLE)
 def sample(
     logits: jnp.ndarray,
     params: SampleParams,

@@ -95,6 +95,7 @@ class BlockStore:
                     )
         bt = self.block_tokens
 
+        @jax.named_scope("kv_gather")
         def gather(pool, ids):
             """ids [slots, nb] int32 -> dense [L, slots, nb*bt, ...]."""
 
@@ -105,6 +106,7 @@ class BlockStore:
 
             return jax.tree.map(one, pool)
 
+        @jax.named_scope("kv_scatter")
         def scatter(pool, dense, slot_idx, block_idx, phys):
             """Write dense blocks (slot_idx[k], block_idx[k]) -> pool[phys[k]]."""
 
@@ -117,6 +119,7 @@ class BlockStore:
 
             return jax.tree.map(one, pool, dense)
 
+        @jax.named_scope("kv_append")
         def append(pool, rows, phys, off):
             """Write one new token row per slot straight into its physical
             block: rows leaves [L, slots, KVH, Hd] -> pool[:, phys[s],

@@ -26,7 +26,11 @@ from typing import Dict, Iterable, List, Optional, Sequence
 
 from dnet_tpu.loadgen.client import RequestOutcome
 from dnet_tpu.loadgen.workload import WorkloadSpec
-from dnet_tpu.obs.phases import DEVICE_MEM_KINDS, REQUEST_SEGMENTS, STEP_PHASES
+from dnet_tpu.obs.phases import (
+    DECODE_CHILD_SPANS,
+    DEVICE_MEM_KINDS,
+    REQUEST_SEGMENTS,
+)
 from dnet_tpu.obs.slo import nearest_rank
 
 # one Prometheus v0.0.4 sample line: name{labels} value  (labels optional)
@@ -82,20 +86,19 @@ def _latency_summary(values: List[float]) -> dict:
 def _phase_summary(
     after: Dict[str, float], before: Optional[Dict[str, float]]
 ) -> dict:
-    """dnet_step_phase_ms + parent dnet_decode_step_ms deltas over the run:
-    where a decode step's time went, and how much of the parent the four
-    phases account for (`coverage`)."""
+    """The batched decode dispatch's host spans (dnet_span_ms, the children
+    of dnet.tick.decode: obs/phases.py DECODE_CHILD_SPANS) + the parent
+    dnet_decode_step_ms as deltas over the run: what the host did around a
+    decode dispatch, and how much of the parent the spans account for
+    (`coverage`).  Nothing is fenced: `launch` is an enqueue, `readback`
+    is the host blocked until the device finished the dispatch."""
     phases = {}
     phase_sum = 0.0
-    for ph in STEP_PHASES:
-        s = metric_delta(
-            after, before, f'dnet_step_phase_ms_sum{{phase="{ph}"}}'
-        )
-        n = metric_delta(
-            after, before, f'dnet_step_phase_ms_count{{phase="{ph}"}}'
-        )
+    for name in DECODE_CHILD_SPANS:
+        s = metric_delta(after, before, f'dnet_span_ms_sum{{span="{name}"}}')
+        n = metric_delta(after, before, f'dnet_span_ms_count{{span="{name}"}}')
         phase_sum += s
-        phases[ph] = {
+        phases[name] = {
             "sum_ms": round(s, 3),
             "count": int(n),
             "mean_ms": round(s / n, 3) if n else 0.0,
@@ -105,13 +108,14 @@ def _phase_summary(
     return {
         "phases": phases,
         # count is TOKENS served (the family's per-token amortization
-        # convention); the phases' counts are dispatches
+        # convention); the spans' counts are decode_batch calls
         "decode_step": {
             "sum_ms": round(parent_sum, 3),
             "count": int(parent_n),
         },
-        # fraction of the parent decode-step wall the phases explain; 0
-        # when phases were not recorded (dense path / obs disabled)
+        # fraction of the dispatches' wall time the spans explain; above 1
+        # by the prepare time of calls answered from the fused-chunk
+        # buffer alone, 0 off the batched path
         "coverage": round(phase_sum / parent_sum, 4) if parent_sum else 0.0,
     }
 

@@ -24,10 +24,12 @@ import abc
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from dnet_tpu.core.kvcache import KVConfig
+from dnet_tpu.obs.phases import SCOPE_LM_HEAD
 from dnet_tpu.ops.quant import QUANTIZABLE
 
 
@@ -168,6 +170,7 @@ class RingModel(abc.ABC):
     def normalize(self, edge_params: dict, x: jnp.ndarray) -> jnp.ndarray:
         """Final norm before the LM head."""
 
+    @jax.named_scope(SCOPE_LM_HEAD)
     def lm_project(self, edge_params: dict, x: jnp.ndarray) -> jnp.ndarray:
         """hidden [B, T, D] -> logits [B, T, V].
 

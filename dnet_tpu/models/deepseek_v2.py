@@ -36,6 +36,7 @@ from jax import lax
 
 from dnet_tpu.core.kvcache import KVConfig
 from dnet_tpu.models.base import ModelConfig, RingModel
+from dnet_tpu.obs.phases import SCOPE_ATTN, SCOPE_MOE
 from dnet_tpu.models.segments import TwoSegmentStackMixin
 from dnet_tpu.parallel.tp_collectives import tp_all_reduce
 from dnet_tpu.ops.attention import cached_attend
@@ -114,6 +115,7 @@ class DeepseekV2RingModel(TwoSegmentStackMixin, RingModel):
         )
 
     # ---- pure compute -------------------------------------------------
+    @jax.named_scope(SCOPE_ATTN)
     def _attention(
         self, p, x, kvs, pos, mask, tp_axis=None, kv_commit=None, sp_axis=None
     ):
@@ -170,6 +172,7 @@ class DeepseekV2RingModel(TwoSegmentStackMixin, RingModel):
         up = h @ dq(p_prefix["w_up"])
         return (jax.nn.silu(gate) * up) @ dq(p_prefix["w_down"])
 
+    @jax.named_scope(SCOPE_MOE)
     def _moe(self, p, x, tp_axis=None):
         B, T, D = x.shape
         h = rms_norm(x, p["mlp_norm"], self.config.rms_norm_eps)

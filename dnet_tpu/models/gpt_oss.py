@@ -31,6 +31,7 @@ from jax import lax
 from dnet_tpu.parallel.tp_collectives import tp_all_reduce
 
 from dnet_tpu.models.base import ModelConfig, RingModel
+from dnet_tpu.obs.phases import SCOPE_ATTN, SCOPE_MOE
 from dnet_tpu.ops.attention import (
     cached_attend,
     causal_mask,
@@ -74,6 +75,7 @@ class GptOssRingModel(RingModel):
                 self.pair_kinds = (a[0], b[0])
 
     # ---- pure compute -------------------------------------------------
+    @jax.named_scope(SCOPE_ATTN)
     def _attention(self, p, x, kvs, pos, mask, tp_axis, kv_commit, sp_axis=None,
                    rotating_window: int = 0, t_real=None, causal: bool = False):
         cfg = self.config
@@ -107,6 +109,7 @@ class GptOssRingModel(RingModel):
         out = out + p["bo"]  # bias replicated: add once, after the psum
         return x + out, kvs
 
+    @jax.named_scope(SCOPE_MOE)
     def _moe(self, p, x, tp_axis):
         from dnet_tpu.ops.moe import moe_apply
 

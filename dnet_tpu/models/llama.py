@@ -18,6 +18,7 @@ import numpy as np
 from jax import lax
 
 from dnet_tpu.models.base import ModelConfig, RingModel
+from dnet_tpu.obs.phases import SCOPE_ATTN
 from dnet_tpu.ops.attention import cached_attend
 from dnet_tpu.parallel.tp_collectives import tp_all_reduce
 from dnet_tpu.ops.norms import rms_norm
@@ -63,39 +64,40 @@ class LlamaRingModel(RingModel):
         H = out_dim(p["wq"]) // Hd  # local heads (== cfg heads / tp)
         KVH = out_dim(p["wk"]) // Hd
 
-        h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-        # qkv biases are present only for families that ship them (qwen2);
-        # the per-family param dict is homogeneous so `in p` is static
-        q = h @ dq(p["wq"])
-        k = h @ dq(p["wk"])
-        v = h @ dq(p["wv"])
-        if "bq" in p:
-            q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-        q = q.reshape(B, T, H, Hd)
-        k = k.reshape(B, T, KVH, Hd)
-        v = v.reshape(B, T, KVH, Hd)
-        q, k = self._qk_transform(p, q, k)  # subclass hook (qwen3 q/k norms)
-        positions = pos + jnp.arange(T)
-        q = apply_rope(q, positions, self.inv_freq, self.rope_scale)
-        k = apply_rope(k, positions, self.inv_freq, self.rope_scale)
-        if attend_fn is not None:
-            # ragged paged attention (ops/paged_attention.py): the caller
-            # owns both the cache write (block append) and the attention
-            # read; kvs is this layer's pool slice dict, passed through so
-            # the hook can read it and return what the scan should stack
-            attn, kvs = attend_fn(q, k, v, kvs)
-        else:
-            attn, kvs = cached_attend(
-                q, k, v, kvs, pos, mask, kv_commit=kv_commit, sp_axis=sp_axis,
-                causal=mask is None,
-            )
-        attn_out = attn.reshape(B, T, H * Hd) @ dq(p["wo"])
-        if tp_axis is not None:
-            # out-proj all-reduce: THE first of the two per-layer TP
-            # collectives, routed through the quantizable seam (exact
-            # psum for plain string axes, parallel/tp_collectives.py)
-            attn_out = tp_all_reduce(attn_out, tp_axis)
-        x = x + attn_out
+        with jax.named_scope(SCOPE_ATTN):
+            h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+            # qkv biases are present only for families that ship them (qwen2);
+            # the per-family param dict is homogeneous so `in p` is static
+            q = h @ dq(p["wq"])
+            k = h @ dq(p["wk"])
+            v = h @ dq(p["wv"])
+            if "bq" in p:
+                q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
+            q = q.reshape(B, T, H, Hd)
+            k = k.reshape(B, T, KVH, Hd)
+            v = v.reshape(B, T, KVH, Hd)
+            q, k = self._qk_transform(p, q, k)  # subclass hook (qwen3 q/k norms)
+            positions = pos + jnp.arange(T)
+            q = apply_rope(q, positions, self.inv_freq, self.rope_scale)
+            k = apply_rope(k, positions, self.inv_freq, self.rope_scale)
+            if attend_fn is not None:
+                # ragged paged attention (ops/paged_attention.py): the caller
+                # owns both the cache write (block append) and the attention
+                # read; kvs is this layer's pool slice dict, passed through so
+                # the hook can read it and return what the scan should stack
+                attn, kvs = attend_fn(q, k, v, kvs)
+            else:
+                attn, kvs = cached_attend(
+                    q, k, v, kvs, pos, mask, kv_commit=kv_commit, sp_axis=sp_axis,
+                    causal=mask is None,
+                )
+            attn_out = attn.reshape(B, T, H * Hd) @ dq(p["wo"])
+            if tp_axis is not None:
+                # out-proj all-reduce: THE first of the two per-layer TP
+                # collectives, routed through the quantizable seam (exact
+                # psum for plain string axes, parallel/tp_collectives.py)
+                attn_out = tp_all_reduce(attn_out, tp_axis)
+            x = x + attn_out
 
         x = self._mlp_block(p, x, tp_axis)
         return x, kvs

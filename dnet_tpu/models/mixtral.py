@@ -29,6 +29,7 @@ from jax import lax
 
 from dnet_tpu.parallel.tp_collectives import tp_all_reduce
 from dnet_tpu.models.llama import LlamaRingModel
+from dnet_tpu.obs.phases import SCOPE_MOE
 from dnet_tpu.ops.norms import rms_norm
 
 
@@ -41,6 +42,7 @@ class MixtralRingModel(LlamaRingModel):
     # for qwen3_moe ("only diff with mixtral sparse moe block" per HF)
     norm_topk_prob = True
 
+    @jax.named_scope(SCOPE_MOE)
     def _mlp_block(self, p: dict, x: jnp.ndarray, tp_axis=None) -> jnp.ndarray:
         B, T, D = x.shape
         h = rms_norm(x, p["mlp_norm"], self.config.rms_norm_eps)

@@ -8,17 +8,24 @@ canonical family set below is registered on first access so `/metrics`
 exposes every series — zero-valued — from process start, and so a typo'd
 name fails loudly at import instead of silently creating a parallel series.
 
+`span(name, **args)` is the ONE host-span primitive: a
+`jax.profiler.TraceAnnotation` (next to free while no profiler session
+runs; in the same `.xplane.pb` as the device ops, on the same clock, while
+one does) plus one always-on `dnet_span_ms{span=}` observation.  Names are
+declared in obs/phases.py HOST_SPANS.  It fences nothing.
+
 `obs_enabled()` is the ONE truth for profile gating: the `[PROFILE]` log
 filter (utils/logger.py) and any sampling decisions both consult it, so the
 legacy `DNET_PROFILE` env and `DNET_OBS_ENABLED` (config.ObsSettings) can
 never disagree.  The registry and recorder themselves are always on —
 counters are near-free and the recorder is bounded — gating covers only the
-log-line firehose and the device-sync fences.
+log-line firehose, the tick-record ring and the per-layer sync fences.
 """
 
 from __future__ import annotations
 
 import threading
+import time
 
 from dnet_tpu.obs.metrics import (
     CONTENT_TYPE_LATEST,
@@ -41,7 +48,9 @@ __all__ = [
     "get_slo_tracker",
     "metric",
     "obs_enabled",
+    "observe_span",
     "reset_obs",
+    "span",
 ]
 
 _registry = MetricsRegistry()
@@ -54,16 +63,26 @@ COUNT_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0)
 
 _CACHE_KINDS = ("prefix", "snapshot")
 
+# request waits run to seconds under load: the default ms ladder tops out
+# too early to tell one prefill chunk from ten
+_WAIT_MS_BUCKETS = (1.0, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0, 500.0, 1000.0,
+                    2500.0, 5000.0, 10000.0, 30000.0)
+
 
 def _register_core(reg: MetricsRegistry) -> None:
     """The canonical family set, pre-registered (and labeled children
     pre-touched) so exposition carries them at zero before first use."""
     reg.histogram(
         "dnet_decode_step_ms",
-        "Per-token decode step wall time on the serving path (ms)",
+        "One decode dispatch's host wall time (enqueue through readback) "
+        "shared evenly over the tokens it produced, observed once per "
+        "token (ms)",
     )
     reg.histogram(
-        "dnet_prefill_ms", "Prompt prefill wall time per request (ms)"
+        "dnet_prefill_ms",
+        "Host time to ENQUEUE one prefill forward (a prompt, or one chunk "
+        "of it); the device runs it later, so this is not prefill "
+        "latency (ms)",
     )
     reg.histogram(
         "dnet_ttft_ms", "Time to first token per request (ms)"
@@ -310,20 +329,61 @@ def _register_core(reg: MetricsRegistry) -> None:
 
     for kind in SLO_KINDS:
         burning.labels(slo=kind)  # pre-touch: expose at 0 from the start
-    # performance attribution (obs/phases.py, obs/jit.py): decode-step
-    # sub-phase breakdown, jit compile tracking, device memory.  Phase /
-    # fn / kind label sets are DECLARED in obs/phases.py (a leaf module)
-    # and cross-checked both ways by the metrics lint (pass 8).
-    from dnet_tpu.obs.phases import DEVICE_MEM_KINDS, JIT_FNS, STEP_PHASES
-
-    phase_fam = reg.histogram(
-        "dnet_step_phase_ms",
-        "Batched decode-step sub-phase wall time (obs/phases.py; fenced "
-        "timings recorded when obs_enabled())",
-        labelnames=("phase",),
+    # performance attribution (obs/phases.py, obs/jit.py): host spans, the
+    # fused-chunk decode counters, jit compile tracking, device memory.
+    # Span / source / width / fn / kind label sets are DECLARED in
+    # obs/phases.py (a leaf module) and cross-checked both ways by the
+    # metrics lint (pass 8).
+    from dnet_tpu.obs.phases import (
+        DECODE_CHUNK_WIDTHS,
+        DECODE_TOKEN_SOURCES,
+        DEVICE_MEM_KINDS,
+        HOST_SPANS,
+        JIT_FNS,
     )
-    for phase in STEP_PHASES:
-        phase_fam.labels(phase=phase)  # pre-touch: the lint checks these
+
+    span_fam = reg.histogram(
+        "dnet_span_ms",
+        "Host-clock duration of one obs.span (obs/phases.py HOST_SPANS; "
+        "never fenced: a launch span is an enqueue, a readback span is the "
+        "host blocked on the device; self time = span less its children)",
+        labelnames=("span",),
+        buckets=(0.05, 0.25, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0,
+                 500.0, 1000.0, 2500.0, 5000.0),
+    )
+    for name in HOST_SPANS:
+        span_fam.labels(span=name)  # pre-touch: the lint checks these
+    dispatches = reg.counter(
+        "dnet_decode_dispatch_total",
+        "Batched decode dispatches that reached the device, by fused "
+        "chunk width R (obs/phases.py DECODE_CHUNK_WIDTHS)",
+        labelnames=("r",),
+    )
+    for width in DECODE_CHUNK_WIDTHS:
+        dispatches.labels(r=str(width))  # pre-touch: the lint checks these
+    reg.counter(
+        "dnet_decode_slot_steps_total",
+        "Slot-steps the device computed in batched decode dispatches "
+        "(R x slots per dispatch, inactive slots included)",
+    )
+    reg.counter(
+        "dnet_decode_lane_steps_total",
+        "Slot-steps active lanes asked for in batched decode dispatches "
+        "(R x dispatched lanes per dispatch)",
+    )
+    tokens_fam = reg.counter(
+        "dnet_decode_tokens_total",
+        "Tokens decode_batch handed to the driver, by where they came from "
+        "(obs/phases.py DECODE_TOKEN_SOURCES)",
+        labelnames=("source",),
+    )
+    for source in DECODE_TOKEN_SOURCES:
+        tokens_fam.labels(source=source)  # pre-touch: the lint checks these
+    reg.counter(
+        "dnet_decode_buffer_dropped_total",
+        "Buffered fused-chunk tokens thrown away when their session ended "
+        "(computed on the device, never delivered)",
+    )
     compiles = reg.counter(
         "dnet_jit_compiles_total",
         "Traced+compiled calls per instrumented jit entry point "
@@ -437,6 +497,33 @@ def _register_core(reg: MetricsRegistry) -> None:
         "dnet_sched_tick_ms",
         "One scheduler tick wall time: the mixed prefill+decode plan "
         "executed on the compute thread",
+    )
+    # where requests wait (sched/engine.py stamps on SchedRequest)
+    reg.histogram(
+        "dnet_sched_queue_wait_ms",
+        "Scheduler wait of a request: step-0 enqueue to the start of the "
+        "tick that runs its first prefill chunk (ms)",
+        buckets=_WAIT_MS_BUCKETS,
+    )
+    reg.histogram(
+        "dnet_sched_prefill_wall_ms",
+        "Wall time from the start of a request's first prefill chunk to "
+        "its first token resolved, the decode dispatches it shared ticks "
+        "with included (ms)",
+        buckets=_WAIT_MS_BUCKETS,
+    )
+    reg.histogram(
+        "dnet_sched_prefill_ticks",
+        "Prefill chunks (one per tick) a prompt took up to its first token",
+        buckets=COUNT_BUCKETS,
+    )
+    reg.histogram(
+        "dnet_sched_deliver_wait_ms",
+        "A decode token's wait from decode_batch returning on the compute "
+        "thread to its future resolved on the event loop (the tick's "
+        "prefill chunks lie in between unless the wire pipeline "
+        "dispatches early) (ms)",
+        buckets=_WAIT_MS_BUCKETS,
     )
     batch_fam = reg.histogram(
         "dnet_sched_batch_tokens",
@@ -601,6 +688,71 @@ def metric(name: str) -> MetricFamily:
         raise KeyError(f"metric {name!r} is not registered; add it to "
                        f"dnet_tpu.obs._register_core")
     return fam
+
+
+_trace_annotation = None
+
+
+def _annotation_cls():
+    """jax.profiler.TraceAnnotation, imported on first use: importing
+    dnet_tpu.obs stays light, and opening an annotation starts no backend."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation
+
+
+class span:
+    """Host span: `with span("dnet.tick", decode_lanes=3): ...`.
+
+    Opens a profiler TraceAnnotation (args become the event's stats) and,
+    on exit, observes the host-clock duration into dnet_span_ms{span=name}.
+    Always on, never fenced, never gated on obs_enabled().  Open and close
+    it on ONE thread and never across an `await` (annotations nest per
+    thread); code that awaits times itself and calls observe_span().
+    `name` must be declared in obs/phases.py HOST_SPANS."""
+
+    __slots__ = ("_child", "_ann", "_t0", "ms")
+
+    def __init__(self, name: str, **args) -> None:
+        self._child = _span_child(name)
+        self._ann = _annotation_cls()(name, **args)
+        self.ms = 0.0
+
+    def __enter__(self) -> "span":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.ms = (time.perf_counter() - self._t0) * 1000.0
+        self._ann.__exit__(*exc)
+        self._child.observe(self.ms)
+
+
+def observe_span(name: str, dur_ms: float) -> None:
+    """Histogram half of span() alone, for code that times itself across
+    an `await` (api/http.py write_chunk)."""
+    _span_child(name).observe(dur_ms)
+
+
+_span_children: dict = {}
+
+
+def _span_child(name: str):
+    child = _span_children.get(name)
+    if child is None:
+        from dnet_tpu.obs.phases import HOST_SPANS
+
+        if name not in HOST_SPANS:
+            raise ValueError(
+                f"span name {name!r} is not declared in "
+                f"dnet_tpu.obs.phases.HOST_SPANS"
+            )
+        child = _span_children[name] = metric("dnet_span_ms").labels(span=name)
+    return child
 
 
 def obs_enabled() -> bool:

@@ -17,9 +17,13 @@ The attribution rule is a priority sweep: spans are mapped to
 and each elementary slice goes to the most specific span covering it.
 `decode_step` (the API driver's per-token umbrella) is least specific;
 `hop_rtt` (send->resolve, which contains the remote shard's whole story)
-outranks it; shard compute / prefill outrank the hop; leaf work (sampling,
-codec encode, stream writes, SSE flushes) and queue waits outrank
-everything.  Because the slices partition the window, the per-request sums
+outranks it; shard compute / prefill outrank the hop; leaf work (codec
+encode, stream writes, SSE flushes) and queue waits outrank everything.
+Under DNET_SCHED=1 the scheduler's own stamps (sched/engine.py) emit
+`sched_queue` (enqueue to first prefill chunk) and a `prefill` span of the
+real wall time (first chunk to first token), so admission_wait +
+sched_queue + prefill_compute is the time to first token.  Because the
+slices partition the window, the per-request sums
 reconcile against measured E2E by construction — the reconciliation the
 ring acceptance test (tests/subsystems/) asserts end to end.
 
@@ -40,7 +44,6 @@ from dnet_tpu.obs.phases import (
     SEG_HOP_RTT,
     SEG_OTHER,
     SEG_PREFILL_COMPUTE,
-    SEG_SAMPLE,
     SEG_SCHED_QUEUE,
     SEG_SHARD_COMPUTE,
     SEG_SSE_FLUSH,
@@ -62,13 +65,6 @@ SPAN_SEGMENTS: Dict[str, Tuple[str, int]] = {
     "prefill": (SEG_PREFILL_COMPUTE, 3),
     "prefix_refill": (SEG_PREFILL_COMPUTE, 3),
     "shard_compute": (SEG_SHARD_COMPUTE, 3),
-    # batched decode sub-phases (core/batch.py, obs/phases.py STEP_PHASES):
-    # compute-side leaf work; on a shard node they re-map to shard_compute
-    # (see _segment_for) so the local-engine and ring stories agree
-    "kv_gather": (SEG_DECODE_COMPUTE, 4),
-    "compute": (SEG_DECODE_COMPUTE, 4),
-    "kv_scatter": (SEG_DECODE_COMPUTE, 4),
-    "sample": (SEG_SAMPLE, 4),
     "wire_encode": (SEG_WIRE_ENCODE, 4),
     # tx-stage leg rides under the egress wire_encode umbrella; tier 3 so
     # the encode leaf wins slices they share and only residual stage time

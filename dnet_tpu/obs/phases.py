@@ -3,23 +3,95 @@
 A LEAF module (like admission/reasons.py and membership/epoch.py): imported
 by `dnet_tpu.obs` for pre-touching and by the metrics lint (pass 8), which
 cross-checks the exposed label sets against these tuples BOTH directions —
-a new phase or instrumented jit entry point cannot ship without its series,
-and a renamed one cannot strand a stale label on dashboards.
+a new host span or instrumented jit entry point cannot ship without its
+series, and a renamed one cannot strand a stale label on dashboards.
 """
 
 from __future__ import annotations
 
-# Sub-phases of one batched decode dispatch (core/batch.py decode_batch):
-#   kv_gather  — page-table gather building the contiguous per-slot KV view
-#                (paged only; the copy the ragged-attention kernel removes)
-#   compute    — the jitted forward + on-device sampling program
-#   kv_scatter — block write-back of the rows the step touched (paged only)
-#   sample     — device->host readback of the sampled token fields
-PHASE_KV_GATHER = "kv_gather"
-PHASE_COMPUTE = "compute"
-PHASE_KV_SCATTER = "kv_scatter"
-PHASE_SAMPLE = "sample"
-STEP_PHASES = (PHASE_KV_GATHER, PHASE_COMPUTE, PHASE_KV_SCATTER, PHASE_SAMPLE)
+# dnet_span_ms{span=}: the host spans obs.span() opens (obs/__init__.py).
+# Each is a jax.profiler.TraceAnnotation (on the profiler's clock, beside the
+# device ops, while a profile is taken) and ONE always-on histogram
+# observation of its host-clock duration.  No fence: a span says what the
+# HOST did; device time is the device trace's to tell.  A span's self time is
+# its duration less its children's.  The tree (parent > child):
+#   dnet.tick                 sched/step.py execute_tick, compute thread
+#     dnet.tick.decode        around engine.decode_batch
+#       dnet.decode.prepare     buffer pops, numpy rows, uploads, table ids
+#       dnet.decode.kv_gather   dense-gather paged path only (enqueue)
+#       dnet.decode.launch      the jitted step/chunk call (+ kv_append):
+#                               an ENQUEUE (and a compile, if one happens)
+#       dnet.decode.kv_scatter  dense-gather paged path only (enqueue)
+#       dnet.decode.readback    the np.asarray reads: host blocked until the
+#                               device finishes the dispatch
+#       dnet.decode.unpack      SampleResult slicing
+#     dnet.tick.prefill       one per prefill chunk
+#       dnet.prefill.launch     engine.prefill_chunk: ENQUEUE only
+#       dnet.prefill.adopt      store_prefix + adopt_prefilled: the
+#                               first-token sample (eager dispatches,
+#                               enqueued) and the slot commit
+#   dnet.sched.plan           event loop, policy.plan
+#   dnet.sched.apply          event loop, SchedulerAdapter._apply
+#   dnet.api.sse_flush        api/http.py write_chunk (awaits: histogram
+#                             only, no annotation)
+SPAN_TICK = "dnet.tick"
+SPAN_TICK_DECODE = "dnet.tick.decode"
+SPAN_DECODE_PREPARE = "dnet.decode.prepare"
+SPAN_DECODE_KV_GATHER = "dnet.decode.kv_gather"
+SPAN_DECODE_LAUNCH = "dnet.decode.launch"
+SPAN_DECODE_KV_SCATTER = "dnet.decode.kv_scatter"
+SPAN_DECODE_READBACK = "dnet.decode.readback"
+SPAN_DECODE_UNPACK = "dnet.decode.unpack"
+SPAN_TICK_PREFILL = "dnet.tick.prefill"
+SPAN_PREFILL_LAUNCH = "dnet.prefill.launch"
+SPAN_PREFILL_ADOPT = "dnet.prefill.adopt"
+SPAN_SCHED_PLAN = "dnet.sched.plan"
+SPAN_SCHED_APPLY = "dnet.sched.apply"
+SPAN_SSE_FLUSH = "dnet.api.sse_flush"
+# the children of dnet.tick.decode, in dispatch order (loadgen/report.py's
+# decode table and the reconciliation tests sum these against the parent)
+DECODE_CHILD_SPANS = (
+    SPAN_DECODE_PREPARE,
+    SPAN_DECODE_KV_GATHER,
+    SPAN_DECODE_LAUNCH,
+    SPAN_DECODE_KV_SCATTER,
+    SPAN_DECODE_READBACK,
+    SPAN_DECODE_UNPACK,
+)
+HOST_SPANS = (
+    SPAN_TICK,
+    SPAN_TICK_DECODE,
+    *DECODE_CHILD_SPANS,
+    SPAN_TICK_PREFILL,
+    SPAN_PREFILL_LAUNCH,
+    SPAN_PREFILL_ADOPT,
+    SPAN_SCHED_PLAN,
+    SPAN_SCHED_APPLY,
+    SPAN_SSE_FLUSH,
+)
+
+# jax.named_scope names inside the traced programs, beside each jitted
+# entry's own JIT_FNS name: metadata only (no instruction changes), so a
+# device trace can be read by layer where the profiler carries op names
+SCOPE_SAMPLE = "dnet.sample"
+SCOPE_LM_HEAD = "dnet.lm_head"
+SCOPE_MOE = "dnet.moe"
+SCOPE_ATTN = "dnet.attn"
+DEVICE_SCOPES = (SCOPE_SAMPLE, SCOPE_LM_HEAD, SCOPE_MOE, SCOPE_ATTN)
+
+# dnet_decode_tokens_total{source=}: where a token decode_batch handed the
+# driver came from (core/batch.py)
+#   dispatch — first row of the dispatch this call made
+#   buffer   — a row an earlier fused R-step (or verify) dispatch left in
+#              the engine's buffer: no device work in this call
+#   spec     — first row of a per-lane speculative verify block
+DECODE_TOKEN_SOURCES = ("dispatch", "buffer", "spec")
+
+# dnet_decode_dispatch_total{r=}: the widths a batched decode dispatch may
+# take — one step, or a fused R-step chunk.  BatchedEngine.CHUNK_BUCKETS is
+# derived from this tuple, so the label set and the compiled-program set
+# cannot drift apart.
+DECODE_CHUNK_WIDTHS = (1, 2, 4, 8, 16)
 
 # Instrumented jitted entry points (obs/jit.py instrument_jit): the `fn`
 # label of dnet_jit_compiles_total.  Every instrument_jit call site must use
@@ -61,7 +133,6 @@ JIT_FNS = (
 #   wire_tx         — writing frames to outbound streams
 #   hop_rtt         — in-flight between nodes (send..ingress gap)
 #   shard_compute   — shard-side layer compute
-#   sample          — on-device sampling + token readback
 #   sse_flush       — serializing/flushing SSE chunks to the client
 #   other           — recorded wall clock no span claims (gaps)
 SEG_ADMISSION_WAIT = "admission_wait"
@@ -72,7 +143,6 @@ SEG_WIRE_ENCODE = "wire_encode"
 SEG_WIRE_TX = "wire_tx"
 SEG_HOP_RTT = "hop_rtt"
 SEG_SHARD_COMPUTE = "shard_compute"
-SEG_SAMPLE = "sample"
 SEG_SSE_FLUSH = "sse_flush"
 SEG_OTHER = "other"
 REQUEST_SEGMENTS = (
@@ -84,7 +154,6 @@ REQUEST_SEGMENTS = (
     SEG_WIRE_TX,
     SEG_HOP_RTT,
     SEG_SHARD_COMPUTE,
-    SEG_SAMPLE,
     SEG_SSE_FLUSH,
     SEG_OTHER,
 )

@@ -46,10 +46,6 @@ COMPUTE_SPANS = frozenset({
     "prefix_refill",
     "decode_sync_drain",
     "shard_compute",
-    "kv_gather",
-    "compute",
-    "kv_scatter",
-    "sample",
 })
 
 #: span names that render on the tx-stage thread track

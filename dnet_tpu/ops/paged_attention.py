@@ -4,8 +4,9 @@ The paged subsystem (kv/) gave admission and sharing block granularity, but
 PR 3 kept the compute dense: every decode tick gathers each slot's page
 table into a contiguous per-slot view (`pool[:, ids]` at full width),
 steps the existing attention programs over it, and scatters the touched
-blocks back.  PR 7's phase attribution prices that round trip exactly —
-`dnet_step_phase_ms{phase=kv_gather|kv_scatter}` — and "Ragged Paged
+blocks back.  The host spans of that round trip —
+`dnet_span_ms{span=dnet.decode.kv_gather|dnet.decode.kv_scatter}` — exist
+only on that path, and "Ragged Paged
 Attention" (PAPERS.md, arxiv 2604.15464) names the TPU-native fix this
 module implements: an attention program that consumes the pool-shaped
 `[N_blocks, bt, KVH, Hd]` arrays and the `[slots, nb]` int32 page tables

@@ -283,11 +283,13 @@ def probe_collective_ms(
 
     tp_axis = TpAxis(axis, mode=mode, group_size=group_size)
 
+    @jax.named_scope("tp_collective")
     def reduce_body(v):
         # mark the replicated probe tensor varying so the reduction is
         # legal under the vma checker
         return tp_all_reduce(lax.pcast(v, str(tp_axis), to="varying"), tp_axis)
 
+    @jax.named_scope("tp_collective")
     def gather_body(v):
         return tp_all_gather(lax.pcast(v, str(tp_axis), to="varying"), tp_axis)
 

@@ -33,8 +33,9 @@ from dnet_tpu.api.strategies import (
     _TokenFutures,
 )
 from dnet_tpu.core.types import DecodingParams, TokenResult
-from dnet_tpu.obs import metric, obs_enabled
+from dnet_tpu.obs import get_recorder, metric, obs_enabled, span
 from dnet_tpu.obs.events import log_event
+from dnet_tpu.obs.phases import SPAN_SCHED_APPLY, SPAN_SCHED_PLAN
 from dnet_tpu.sched.flight import get_tick_recorder
 from dnet_tpu.sched.kinds import QUEUE_STATES, STATE_DECODING
 from dnet_tpu.sched.policy import SchedulerPolicy, TickPlan
@@ -48,6 +49,10 @@ log = get_logger()
 _TICK_MS = metric("dnet_sched_tick_ms")
 _BATCH_TOKENS = metric("dnet_sched_batch_tokens")
 _PREEMPTIONS = metric("dnet_sched_preemptions_total")
+_QUEUE_WAIT_MS = metric("dnet_sched_queue_wait_ms")
+_PREFILL_WALL_MS = metric("dnet_sched_prefill_wall_ms")
+_PREFILL_TICKS = metric("dnet_sched_prefill_ticks")
+_DELIVER_WAIT_MS = metric("dnet_sched_deliver_wait_ms")
 
 
 def sched_enabled() -> bool:
@@ -248,10 +253,12 @@ class SchedulerAdapter(ApiAdapterBase):
             # loop would kill the task silently and wedge every current
             # and future request behind a kick event nobody waits on
             try:
-                plan = self.policy.plan(self.queue, self.engine)
+                with span(SPAN_SCHED_PLAN):
+                    plan = self.policy.plan(self.queue, self.engine)
                 if plan.empty():
                     continue
                 t0 = time.perf_counter()
+                self._stamp_chunks(plan, t0)
                 on_decode = None
                 if plan.prefills and wire_pipeline_enabled():
                     # wire-pipeline tick dispatch: decode results leave the
@@ -262,8 +269,12 @@ class SchedulerAdapter(ApiAdapterBase):
                     # is the sanctioned bridge (domains.BRIDGE_MODULES);
                     # FIFO loop ordering guarantees every early resolve
                     # runs before the executor future resumes _apply.
+                    # (the lambda runs on the compute thread the moment
+                    # decode_batch returns: its clock reading is where the
+                    # token's wait for its future starts)
                     on_decode = lambda nonce, sample: loop.call_soon_threadsafe(  # noqa: E731
-                        self._dispatch_decode, plan, nonce, sample
+                        self._dispatch_decode, plan, nonce, sample,
+                        time.perf_counter(),
                     )
                 result = await loop.run_in_executor(
                     self._executor, execute_tick, self.engine, plan, on_decode
@@ -276,7 +287,8 @@ class SchedulerAdapter(ApiAdapterBase):
                 _BATCH_TOKENS.labels(kind="decode").observe(
                     float(result.decode_lanes)
                 )
-                self._apply(plan, result)
+                with span(SPAN_SCHED_APPLY):
+                    self._apply(plan, result)
                 if obs_enabled():
                     self._record_tick(tick_ms, result)
                 if self.policy.has_work(self.queue, self.engine):
@@ -304,6 +316,8 @@ class SchedulerAdapter(ApiAdapterBase):
             budget_tokens=self.policy.token_budget,
             prefill_tokens=result.prefill_tokens,
             decode_lanes=result.decode_lanes,
+            dispatched_lanes=result.dispatched_lanes,
+            chunk_r=result.chunk_r,
             preempted=len(result.preempted),
             requeued=len(result.requeued),
             errors=len(result.errors),
@@ -316,7 +330,43 @@ class SchedulerAdapter(ApiAdapterBase):
             kv_pool_blocks=int(metric("dnet_kv_pool_blocks").value),
         )
 
-    def _dispatch_decode(self, plan: TickPlan, nonce: str, sample) -> None:
+    def _stamp_chunks(self, plan: TickPlan, t0: float) -> None:
+        """The tick starting at `t0` runs these prefill chunks: count them,
+        and for a request's FIRST chunk close its scheduler wait (enqueue
+        to here) into dnet_sched_queue_wait_ms and the recorder's
+        `sched_queue` span.  Forced like the other per-request summary
+        spans: the segment ledger of every request needs it."""
+        for chunk in plan.prefills:
+            req = self.queue.get(chunk.nonce)
+            if req is None:
+                continue
+            req.prefill_chunks += 1
+            if req.t_first_chunk is None:
+                req.t_first_chunk = t0
+                wait_ms = (t0 - req.t_enqueued) * 1000.0
+                _QUEUE_WAIT_MS.observe(wait_ms)
+                get_recorder().span(
+                    chunk.nonce, "sched_queue", wait_ms, force=True
+                )
+
+    def _stamp_first_token(self, req) -> None:
+        """A request's first token just resolved: its prefill's real wall
+        time (first chunk's tick start to here, the decode dispatches it
+        shared ticks with included) and the ticks it took."""
+        if req.t_first_token is not None or req.t_first_chunk is None:
+            return
+        req.t_first_token = time.perf_counter()
+        wall_ms = (req.t_first_token - req.t_first_chunk) * 1000.0
+        _PREFILL_WALL_MS.observe(wall_ms)
+        _PREFILL_TICKS.observe(float(req.prefill_chunks))
+        get_recorder().span(
+            req.nonce, "prefill", wall_ms, force=True,
+            tokens=req.prompt_len, chunks=req.prefill_chunks,
+        )
+
+    def _dispatch_decode(
+        self, plan: TickPlan, nonce: str, sample, t_done: float
+    ) -> None:
         """Early decode resolution (wire-pipeline tick dispatch): runs on
         the loop via call_soon_threadsafe while the tick's prefill chunks
         are still executing.  _apply later skips nonces listed in
@@ -325,6 +375,7 @@ class SchedulerAdapter(ApiAdapterBase):
         if step is None:
             return
         self._resolve_step(nonce, step, sample=sample)
+        _DELIVER_WAIT_MS.observe((time.perf_counter() - t_done) * 1000.0)
 
     def _fail_plan(self, plan: TickPlan, error: str) -> None:
         """A tick that died wholesale (executor torn down mid-flight):
@@ -386,6 +437,7 @@ class SchedulerAdapter(ApiAdapterBase):
             req.starved = 0
             step = req.pending_step if req.pending_step is not None else 0
             self._resolve_step(nonce, step, sample=sample)
+            self._stamp_first_token(req)
         dispatched = set(result.dispatched)
         for nonce, sample in result.decode_results.items():
             if nonce in dispatched:
@@ -394,6 +446,11 @@ class SchedulerAdapter(ApiAdapterBase):
             if step is None:
                 continue
             self._resolve_step(nonce, step, sample=sample)
+            # readback ended on the compute thread -> future resolved here:
+            # the tick's prefill chunks ran in between
+            _DELIVER_WAIT_MS.observe(
+                (time.perf_counter() - result.t_decode_done) * 1000.0
+            )
         for nonce, msg in result.errors.items():
             step = plan.steps.get(nonce)
             if step is None:

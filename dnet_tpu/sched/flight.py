@@ -41,7 +41,8 @@ class TickRecord:
     budget_used: int         # prefill tokens + decode lanes packed
     budget_wasted: int       # budget - used (0 on a saturated tick)
     prefill_tokens: int      # prompt tokens chunk-prefilled this tick
-    decode_lanes: int        # decode lanes stepped this tick
+    decode_lanes: int        # decode lanes answered this tick (from the
+                             # dispatch or the engine's fused-chunk buffer)
     preempted: int           # sequences evicted back to WAITING
     requeued: int            # starved prefills requeued
     errors: int              # per-nonce errors the tick surfaced
@@ -49,6 +50,8 @@ class TickRecord:
     kv_blocks_used: int = 0
     kv_blocks_free: int = 0
     kv_pool_blocks: int = 0
+    dispatched_lanes: int = 0  # lanes in the dispatch that reached the device
+    chunk_r: int = 0           # its fused width R; 0 = answered from the buffer
 
     def as_dict(self) -> dict:
         return asdict(self)
@@ -91,6 +94,8 @@ class TickFlightRecorder:
         kv_blocks_used: int = 0,
         kv_blocks_free: int = 0,
         kv_pool_blocks: int = 0,
+        dispatched_lanes: int = 0,
+        chunk_r: int = 0,
     ) -> Optional[TickRecord]:
         """Capture one tick; returns the record (None when capture is
         disabled via DNET_OBS_TICK_RECORDS=0)."""
@@ -114,6 +119,8 @@ class TickFlightRecorder:
             kv_blocks_used=int(kv_blocks_used),
             kv_blocks_free=int(kv_blocks_free),
             kv_pool_blocks=int(kv_pool_blocks),
+            dispatched_lanes=int(dispatched_lanes),
+            chunk_r=int(chunk_r),
         )
         with self._lock:
             rec.seq = self._seq

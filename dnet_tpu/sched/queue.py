@@ -19,6 +19,7 @@ plain snapshots inside a :class:`~dnet_tpu.sched.policy.TickPlan`.
 from __future__ import annotations
 
 import math
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -66,6 +67,16 @@ class SchedRequest:
     preemptions: int = 0
     #: consecutive starved requeues (bounded before the typed error)
     starved: int = 0
+    #: where the request waited (perf_counter stamps, loop-side): step-0
+    #: enqueue, the start of the tick that ran its first prefill chunk, and
+    #: its first token resolved; plus the prefill chunks (one per tick) it
+    #: took.  sched/engine.py turns them into dnet_sched_queue_wait_ms /
+    #: dnet_sched_prefill_wall_ms / dnet_sched_prefill_ticks and the
+    #: recorder's sched_queue / prefill spans.
+    t_enqueued: float = field(default_factory=time.perf_counter)
+    t_first_chunk: Optional[float] = None
+    t_first_token: Optional[float] = None
+    prefill_chunks: int = 0
     extra: dict = field(default_factory=dict)
 
     def priority(self) -> Tuple[float, int]:

@@ -11,7 +11,7 @@ tick is one mixed launch sequence:
   instead of erroring an arbitrary lane; under DNET_KV_RAGGED=1 the
   dispatch attends the block pool in place through the page tables
   (ops/paged_attention.py) — the gather/scatter round trip and its
-  kv_gather/kv_scatter phases stop existing, while this module's block
+  kv_gather/kv_scatter spans stop existing, while this module's block
   accounting (_decode_need, preemption) is unchanged because admission
   was always a function of blocks, never of the dense view;
 - then the tick's chunked-prefill segments on the engine's B=1 bucket
@@ -33,11 +33,19 @@ never touches the scheduler queue.  Results flow back as plain data in a
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List
 
 from dnet_tpu.kv import KVPoolExhausted
-from dnet_tpu.obs import metric
+from dnet_tpu.obs import metric, span
+from dnet_tpu.obs.phases import (
+    SPAN_PREFILL_ADOPT,
+    SPAN_PREFILL_LAUNCH,
+    SPAN_TICK,
+    SPAN_TICK_DECODE,
+    SPAN_TICK_PREFILL,
+)
 from dnet_tpu.sched.policy import PrefillChunk, TickPlan
 from dnet_tpu.utils.logger import get_logger
 
@@ -69,7 +77,17 @@ class TickResult:
     #: loop-side apply must not resolve these a second time
     dispatched: List[str] = field(default_factory=list)
     prefill_tokens: int = 0
+    #: lanes the tick's decode_batch call answered — from the dispatch it
+    #: made OR from the engine's fused-chunk buffer (feeds
+    #: dnet_sched_batch_tokens{kind="decode"})
     decode_lanes: int = 0
+    #: lanes and fused width R of the dispatch that call sent to the
+    #: device; both 0 when every lane was answered from the buffer
+    dispatched_lanes: int = 0
+    chunk_r: int = 0
+    #: perf_counter when decode_batch returned on the compute thread: the
+    #: loop measures a decode token's wait for its future from here
+    t_decode_done: float = 0.0
 
 
 def _decode_need(engine, nonces) -> int:
@@ -144,7 +162,8 @@ def _run_prefill_chunk(
     logits = None
     if piece:
         try:
-            logits = engine.prefill_chunk(nonce, piece, chunk.seed)
+            with span(SPAN_PREFILL_LAUNCH):
+                logits = engine.prefill_chunk(nonce, piece, chunk.seed)
         except KVPoolExhausted as exc:
             _handle_prefill_starvation(engine, plan, chunk, res, cur, exc)
             return
@@ -154,8 +173,9 @@ def _run_prefill_chunk(
         return
     while True:
         try:
-            engine.store_prefix(nonce, chunk.ids)
-            sample = engine.adopt_prefilled(nonce, logits, chunk.decoding)
+            with span(SPAN_PREFILL_ADOPT):
+                engine.store_prefix(nonce, chunk.ids)
+                sample = engine.adopt_prefilled(nonce, logits, chunk.decoding)
         except KVPoolExhausted as exc:
             victims = [
                 v
@@ -229,15 +249,27 @@ def execute_tick(engine, plan: TickPlan, on_decode=None) -> TickResult:
     Results dispatched this way are also recorded in ``dispatched`` so the
     loop-side apply doesn't resolve them twice."""
     res = TickResult()
+    with span(SPAN_TICK, decode_lanes=len(plan.decode),
+              prefill_chunks=len(plan.prefills)):
+        _execute(engine, plan, on_decode, res)
+    return res
+
+
+def _execute(engine, plan: TickPlan, on_decode, res: TickResult) -> None:
     reqs = dict(plan.decode)
     if reqs and getattr(engine, "kv_pool", None) is not None:
         _preempt_for_decode(engine, plan, reqs, res)
     if reqs:
         budgets = {n: plan.budgets.get(n) for n in reqs}
-        out, errs = engine.decode_batch(reqs, budgets=budgets)
+        with span(SPAN_TICK_DECODE):
+            out, errs = engine.decode_batch(reqs, budgets=budgets)
+        res.t_decode_done = time.perf_counter()
         res.decode_results.update(out)
         res.errors.update(errs)
         res.decode_lanes = len(reqs)
+        res.chunk_r, res.dispatched_lanes = getattr(
+            engine, "last_dispatch", (0, 0)
+        )
         if on_decode is not None:
             for nonce, sample in out.items():
                 try:
@@ -251,7 +283,9 @@ def execute_tick(engine, plan: TickPlan, on_decode=None) -> TickResult:
         if chunk.nonce in res.preempted:
             continue
         try:
-            _run_prefill_chunk(engine, plan, chunk, res)
+            with span(SPAN_TICK_PREFILL, tokens=chunk.end - chunk.start,
+                      first=chunk.first, last=chunk.last):
+                _run_prefill_chunk(engine, plan, chunk, res)
         except Exception as exc:
             log.exception("scheduler prefill chunk failed for %s", chunk.nonce)
             try:
@@ -259,4 +293,3 @@ def execute_tick(engine, plan: TickPlan, on_decode=None) -> TickResult:
             except Exception as inner:
                 log.debug("abandon_prefill after failure: %s", inner)
             res.errors[chunk.nonce] = str(exc)
-    return res

@@ -36,7 +36,7 @@ from dnet_tpu.obs.phases import (
     SEG_DECODE_COMPUTE,
     SEG_HOP_RTT,
     SEG_OTHER,
-    SEG_SAMPLE,
+    SEG_SSE_FLUSH,
     SEG_SHARD_COMPUTE,
     SEG_WIRE_ENCODE,
 )
@@ -86,14 +86,14 @@ def test_decompose_partitions_window_most_specific_wins():
         _span("request", 0, 100),
         _span("decode_step", 0, 100),   # tier-1 umbrella
         _span("hop_rtt", 10, 40),       # tier 2, inside the umbrella
-        _span("sample", 20, 5),         # tier-4 leaves inside the hop
+        _span("sse_flush", 20, 5),      # tier-4 leaves inside the hop
         _span("wire_encode", 30, 5),
     ]))
     seg = led["segments_ms"]
     assert set(seg) == set(REQUEST_SEGMENTS)
     assert seg[SEG_DECODE_COMPUTE] == 60.0   # 100 minus the hop's 40
     assert seg[SEG_HOP_RTT] == 30.0          # 40 minus the two leaves
-    assert seg[SEG_SAMPLE] == 5.0
+    assert seg[SEG_SSE_FLUSH] == 5.0
     assert seg[SEG_WIRE_ENCODE] == 5.0
     assert led["total_ms"] == 100.0
     assert led["e2e_ms"] == 100.0
@@ -107,22 +107,22 @@ def test_decompose_gaps_land_in_other():
     led = decompose(_tl([
         _span("request", 0, 40),
         _span("decode_step", 0, 10),
-        _span("sample", 20, 10),
+        _span("sse_flush", 20, 10),
     ]))
     seg = led["segments_ms"]
     assert seg[SEG_DECODE_COMPUTE] == 10.0
-    assert seg[SEG_SAMPLE] == 10.0
+    assert seg[SEG_SSE_FLUSH] == 10.0
     assert seg[SEG_OTHER] == 20.0  # [10,20) gap + [30,40) tail
     assert led["total_ms"] == 40.0
 
 
 def test_decompose_shard_node_remaps_compute():
-    """On a stitched timeline, generic compute sub-phases recorded by a
+    """On a stitched timeline, generic decode-compute spans recorded by a
     shard are shard_compute, not the API driver's decode_compute."""
     led = decompose(_tl([
         _span("request", 0, 20),
-        _span("compute", 0, 10, node="s0"),
-        _span("compute", 10, 10, node="api"),
+        _span("decode_sync_drain", 0, 10, node="s0"),
+        _span("decode_sync_drain", 10, 10, node="api"),
     ], cluster=True))
     seg = led["segments_ms"]
     assert seg[SEG_SHARD_COMPUTE] == 10.0

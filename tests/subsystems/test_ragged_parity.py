@@ -168,27 +168,34 @@ def _stream(eng, nonce, ids, steps, dec=DecodingParams(temperature=0.0)):
     return toks
 
 
-def test_ragged_engine_flag_and_phases(tiny_llama_dir, ragged_env, monkeypatch):
+def _span_counts():
+    fam = metric("dnet_span_ms")
+    return {
+        sp: fam.labels(span=f"dnet.decode.{sp}").count
+        for sp in ("kv_gather", "launch", "kv_scatter", "readback")
+    }
+
+
+@pytest.mark.parametrize("ragged", [True, False], ids=["ragged", "gather"])
+def test_ragged_engine_flag_and_phases(tiny_llama_dir, ragged_env, ragged):
     """The engine actually takes the ragged path (kv_ragged resolves True),
-    and the kv_gather/kv_scatter phases STOP EXISTING on it: with
-    attribution on, a decode dispatch moves the compute phase counter but
-    neither KV phase — the round trip is deleted, not just cheaper."""
-    monkeypatch.setenv("DNET_OBS_ENABLED", "1")
-    eng = _engine(tiny_llama_dir, ragged=True)
+    and the kv_gather/kv_scatter spans STOP EXISTING on it: a decode
+    dispatch moves the launch and readback span counters but neither KV
+    span — the round trip is deleted, not just cheaper.  The dense-gather
+    paged engine opens both, once per dispatch.  No setting is needed:
+    the spans are always on and fence nothing."""
+    eng = _engine(tiny_llama_dir, ragged=ragged)
     try:
-        assert eng.kv_ragged is True
-        fam = metric("dnet_step_phase_ms")
-        before = {
-            ph: fam.labels(phase=ph).count
-            for ph in ("kv_gather", "compute", "kv_scatter")
-        }
+        assert eng.kv_ragged is ragged
+        before = _span_counts()
         dec = DecodingParams(temperature=0.0)
         res = eng.prefill_and_sample("ph", [256, 72, 101], dec)
         eng.decode_batch({"ph": (int(res.token[0]), dec)})
-        fam = metric("dnet_step_phase_ms")
-        assert fam.labels(phase="compute").count > before["compute"]
-        assert fam.labels(phase="kv_gather").count == before["kv_gather"]
-        assert fam.labels(phase="kv_scatter").count == before["kv_scatter"]
+        moved = {k: v - before[k] for k, v in _span_counts().items()}
+        assert moved["launch"] == 1 and moved["readback"] == 1
+        kv_spans = 0 if ragged else 1
+        assert moved["kv_gather"] == kv_spans
+        assert moved["kv_scatter"] == kv_spans
         eng.end_session("ph")
     finally:
         eng.close()
