@@ -3,10 +3,13 @@
 One jitted function serves every request: all decoding knobs are traced
 scalars (not static args), so changing temperature or top_p never recompiles
 — the fix for the reference's "end-shard sampling under jit" hard part
-(SURVEY.md §7).  Greedy vs stochastic is a `jnp.where` select, top-k with a
-*traced* k uses a rank threshold over a single descending sort shared by all
-filters.  Functionality mirrors the reference's mlx_lm-based Sampler
-(src/dnet/core/decoding/sampler.py:14-65).
+(SURVEY.md §7).  Greedy vs stochastic is a `jnp.where` select.  The filters
+(top-k with a *traced* k, top-p, min-p, min_tokens_to_keep) each keep a
+prefix of the row's descending order, so `filter_keep` reads their common
+prefix length off ONE sort that carries the vocabulary index and cuts back
+to vocabulary order by comparing every entry with the (value, index) pair at
+that length: no ranks, no vocabulary-sized gather.  Functionality mirrors
+the reference's mlx_lm-based Sampler (src/dnet/core/decoding/sampler.py:14-65).
 """
 
 from __future__ import annotations
@@ -84,12 +87,13 @@ class SamplePlan(NamedTuple):
 
     The traced-knob design (SampleParams) means one program serves every
     request — but it also means every decode step pays for machinery most
-    requests never use: three full-vocab sorts for the top-k/p filters and a
-    log_softmax + top_k(20) for logprobs cost ~4ms/step at V=128k on v5e,
-    comparable to a whole 1B-model forward.  The plan collapses the unused
-    machinery at trace time; the handful of plan combinations bound the
-    number of compiled variants, and knobs *within* a plan stay traced (a
-    temperature change still never recompiles).
+    requests never use: one full-vocabulary sort and a few elementwise passes
+    for the filters (`filter_keep`), a log_softmax + top_k(20) for logprobs,
+    a scatter for the bias.  The plan collapses the unused machinery at trace time; the handful
+    of plan combinations bound the number of compiled variants, and knobs
+    *within* a plan stay traced (a temperature change still never
+    recompiles).  What the machinery costs on the chip is in PERF.md
+    (sections 5 and 6) and the ledger, per cell; no figure is kept here.
     """
 
     greedy: bool  # temperature <= 0: token = argmax, no sampling machinery
@@ -142,6 +146,64 @@ def pack_chunk_results(results: SampleResult, with_logprobs: bool) -> jnp.ndarra
     return results.token[..., None].astype(jnp.float32)
 
 
+def filter_keep(scaled: jnp.ndarray, params: SampleParams) -> jnp.ndarray:
+    """The filters' kept set for temperature-scaled logits [B, V], as a
+    boolean mask in vocabulary order, from ONE sort.
+
+    Top-k, top-p, min-p and min_tokens_to_keep each keep a prefix of the
+    row's descending order, so together they keep its first `n` entries for
+    one integer n per row.  One stable sort that carries the vocabulary index
+    gives n and the (value, index) pair at sorted position n-1; the mask is
+    then an elementwise comparison of every entry against that pair.  No
+    ranks are computed and nothing vocabulary-sized is gathered.
+
+    Order of equal values (the common case: logits leave `lm_project` in
+    bf16): ascending and stable, then read backwards, so among equal values
+    the HIGHER index stands first, and a cut inside such a group keeps its
+    higher indices.
+
+    top_p >= 1 keeps the whole row; below 1 the prefix ends at the first
+    sorted position whose exclusive cumulative probability reaches top_p.
+    (A mask `cumsum - p < top_p` taken entry by entry is not a prefix at
+    top_p = 1.0: on a peaked row the float cumsum reaches 1.0 early and
+    wobbles around it, which drops scattered far-tail entries.)
+    """
+    V = scaled.shape[-1]
+    ids = jax.lax.broadcasted_iota(jnp.int32, scaled.shape, 1)
+    asc, asc_ids = jax.lax.sort(
+        (scaled, ids), dimension=1, is_stable=True, num_keys=1
+    )
+
+    # top-k: the first k (k == 0 -> all)
+    k = jnp.where(params.top_k > 0, params.top_k, V)
+
+    # top-p over the descending row: always keeps position 0 (its exclusive
+    # cumulative probability is exactly 0)
+    sorted_probs = jax.nn.softmax(asc[:, ::-1], axis=-1)
+    cumprobs = jnp.cumsum(sorted_probs, axis=-1)
+    reached = (cumprobs - sorted_probs) >= params.top_p
+    n_p = jnp.min(jnp.where(reached, ids, V), axis=-1)
+    n_p = jnp.where(params.top_p >= 1.0, V, n_p)
+
+    # min-p: probability >= min_p * max prob.  Monotone in the value, so the
+    # count of such entries IS their prefix length; counted in vocabulary
+    # order, where no sort is needed.
+    probs = jax.nn.softmax(scaled, axis=-1)
+    pmax = jnp.max(probs, axis=-1, keepdims=True)
+    n_minp = jnp.sum(probs >= params.min_p * pmax, axis=-1)
+
+    # never fewer than min_tokens_to_keep candidates (>= 1: the argmax
+    # always survives), never more than the row holds
+    n = jnp.minimum(jnp.minimum(k, n_p), n_minp)
+    n = jnp.clip(n, jnp.maximum(params.min_tokens_to_keep, 1), V)
+
+    # the cut: descending position n-1 is ascending position V-n
+    at = (V - n)[:, None]
+    cut = jnp.take_along_axis(asc, at, axis=-1)
+    cut_id = jnp.take_along_axis(asc_ids, at, axis=-1)
+    return (scaled > cut) | ((scaled == cut) & (ids >= cut_id))
+
+
 @jax.named_scope(SCOPE_SAMPLE)
 def sample(
     logits: jnp.ndarray,
@@ -186,31 +248,7 @@ def sample(
         temp = jnp.maximum(params.temperature, 1e-6)
         scaled = logits.astype(jnp.float32) / temp
         if plan.filters:
-            # One descending sort powers top-k, top-p and min-p.
-            sorted_logits = jnp.sort(scaled, axis=-1)[:, ::-1]  # [B, V] desc
-            ranks = jnp.argsort(jnp.argsort(scaled, axis=-1)[:, ::-1], axis=-1)
-
-            # top-k: keep ranks < k (k==0 -> keep all)
-            k = jnp.where(params.top_k > 0, params.top_k, V)
-            keep_topk = ranks < k
-
-            # top-p over the sorted distribution: keep the smallest prefix
-            # with cumsum >= top_p (always keep rank 0).
-            sorted_probs = jax.nn.softmax(sorted_logits, axis=-1)
-            cumprobs = jnp.cumsum(sorted_probs, axis=-1)
-            prefix_keep_sorted = (cumprobs - sorted_probs) < params.top_p
-            keep_topp = jnp.take_along_axis(prefix_keep_sorted, ranks, axis=-1)
-
-            # min-p: probability >= min_p * max prob
-            probs = jax.nn.softmax(scaled, axis=-1)
-            pmax = jnp.max(probs, axis=-1, keepdims=True)
-            keep_minp = probs >= params.min_p * pmax
-
-            keep = keep_topk & keep_topp & keep_minp
-            # never mask below min_tokens_to_keep candidates (>= 1: the
-            # argmax always survives)
-            keep = keep | (ranks < jnp.maximum(params.min_tokens_to_keep, 1))
-            masked = jnp.where(keep, scaled, -jnp.inf)
+            masked = jnp.where(filter_keep(scaled, params), scaled, -jnp.inf)
         else:
             masked = scaled
 
@@ -223,7 +261,11 @@ def sample(
         raw_logprobs = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
         logprob = jnp.take_along_axis(raw_logprobs, token[:, None], axis=-1)[:, 0]
         n_top = min(MAX_TOP_LOGPROBS, V)
-        top_lp, top_ids = jax.lax.top_k(raw_logprobs, n_top)
+        # A single row goes in without its batch axis: vmapped a lane at a
+        # time (core/batch.py) the operand is then [slots, V], for which the
+        # TPU has a TopK kernel; [slots, 1, V] it compiles as a full sort.
+        rows = raw_logprobs[0] if B == 1 else raw_logprobs
+        top_lp, top_ids = (x.reshape(B, n_top) for x in jax.lax.top_k(rows, n_top))
         if n_top < MAX_TOP_LOGPROBS:  # tiny-vocab tests: pad to the static width
             pad = MAX_TOP_LOGPROBS - n_top
             top_lp = jnp.pad(top_lp, ((0, 0), (0, pad)), constant_values=-jnp.inf)
