@@ -1,12 +1,13 @@
 """BENCHMARK.json against the contract's rules, and the promise that a later
-PR adds a configuration, a mix, a cell and a metric as files plus one entry."""
+PR adds a configuration, a mix, a cell with its evidence and a metric as files
+plus one entry."""
 
 import json
 import shutil
 
 import pytest
 
-from benchmarks.harness import readers, spec
+from benchmarks.harness import readers, spec, spread
 
 BENCH = spec.load_benchmark()
 CELLS = [w["name"] for w in BENCH["workloads"]]
@@ -82,9 +83,19 @@ def test_a_later_pr_adds_config_mix_cell_and_metrics_as_files(tmp_path):
             "name": name, "unit": "%" if "pct" in name else "count", "better": "lower",
             "source": src, "layer": "scheduler", "moves": "ttft_p50_ms",
             "workloads": ["qwen3moe-chat-greedy"]})
+    # the cell's evidence, a data file too: two sets of six runs, steady enough
+    # for the bounds the metrics already have
+    runs = [{"seed": 2**31 + 6 * k + i, "trace": 0, "compiled": i == 0,
+             "metrics": {"output_tokens_per_s": 400 + (i + k) % 3, "ttft_p50_ms": 250 + i % 2,
+                         "setup_s": 600 if i == 0 else 120 + i}}
+            for k in range(2) for i in range(6)]
+    (root / "benchmarks/evidence/qwen3moe-chat-greedy.json").write_text(json.dumps({
+        "cell": "qwen3moe-chat-greedy",
+        "sets": [{"name": n, "runs": runs[6 * k:6 * k + 6]} for k, n in enumerate("AB")]}))
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
 
     assert spec.validate(bench, root) == []
+    assert spread.faults(bench, root) == []
     cell = spec.resolve_cell("qwen3moe-chat-greedy", root)
     assert cell.config["num_hidden_layers"] == 4 and cell.traffic["clients"] == 8
     assert {m["name"] for m in cell.per_layer} >= {"preemptions_in_window", "fusion_time_pct"}
