@@ -387,9 +387,11 @@ class Smoke:
             raise PhaseFailed("; ".join(bad)[:600])
         gauges = {}
         for line in http.get("/metrics").text.splitlines():
-            name, _, value = line.partition(" ")
+            name, _, value = line.rpartition(" ")
+            name = name.partition("{")[0]  # one series a kind of layer: summed
             if name in ("dnet_kv_blocks_used", "dnet_kv_blocks_free", "dnet_kv_pool_blocks"):
-                gauges[name[len("dnet_kv_"):]] = int(float(value))
+                key = name[len("dnet_kv_"):]
+                gauges[key] = gauges.get(key, 0) + int(float(value))
         if (len(gauges) != 3 or gauges["pool_blocks"] <= 0
                 or gauges["blocks_used"] + gauges["blocks_free"] != gauges["pool_blocks"]):
             raise PhaseFailed(f"block pool does not balance: {gauges}")

@@ -287,8 +287,8 @@ def check_paged_conservation(errors: list) -> int:
         except AssertionError as exc:
             errors.append(f"paged-conservation step {steps}: {exc}")
             return
-        used = metric("dnet_kv_blocks_used").value
-        free = metric("dnet_kv_blocks_free").value
+        used = metric("dnet_kv_blocks_used").labels(kind="full").value
+        free = metric("dnet_kv_blocks_free").labels(kind="full").value
         if (used, free) != (pool.used, pool.free):
             errors.append(
                 f"paged-conservation step {steps}: gauges ({used}, {free}) "
@@ -523,6 +523,16 @@ def check_attribution_labels(errors: list) -> int:
     n += _cross_check_labels(
         errors, text, "dnet_decode_tokens_total", "source",
         DECODE_TOKEN_SOURCES, "obs.phases.DECODE_TOKEN_SOURCES",
+    )
+    from dnet_tpu.obs.phases import KV_KINDS, MOE_HELD
+
+    for fam in ("dnet_kv_blocks_used", "dnet_kv_blocks_free", "dnet_kv_pool_blocks"):
+        n += _cross_check_labels(
+            errors, text, fam, "kind", KV_KINDS, "obs.phases.KV_KINDS"
+        )
+    n += _cross_check_labels(
+        errors, text, "dnet_moe_assignments_total", "held", MOE_HELD,
+        "obs.phases.MOE_HELD",
     )
     from dnet_tpu.core.batch import BatchedEngine
 

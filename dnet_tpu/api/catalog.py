@@ -58,6 +58,15 @@ model_catalog: List[CatalogEntry] = [
     # Mixtral sparse MoE (BASELINE config 4)
     CatalogEntry("mistralai/Mixtral-8x7B-Instruct-v0.1", "mixtral", 46.7, 32, notes="MoE 8x top-2"),
     CatalogEntry("mistralai/Mixtral-8x22B-Instruct-v0.1", "mixtral", 141.0, 56, notes="MoE 8x top-2"),
+    # Cohere2-MoE: window and full layers mixed, parallel block, sigmoid
+    # routing with shared experts; language model only, and the share of
+    # the experts a process holds comes from its config.json
+    # (num_experts of num_experts_routed from expert_offset)
+    CatalogEntry(
+        "CohereLabs/command-a-plus-05-2026", "cohere2_moe", 218.0, 32,
+        notes="MoE 128x top-8 sigmoid + 4 shared, SWA 3:1, parallel block; "
+        "language model only, expert share by config",
+    ),
 ]
 
 

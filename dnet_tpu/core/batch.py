@@ -46,18 +46,23 @@ from dnet_tpu.core.types import DecodingParams
 from dnet_tpu.kv import (
     BlockPool,
     BlockStore,
+    KindStore,
     KVPoolExhausted,
     PagedKVConfig,
     PagedPrefixCache,
     PageTable,
     paged_enabled,
     ragged_enabled,
+    window_blocks,
+    window_first_block,
 )
 from dnet_tpu.kv.store import _bucket_pow2
 from dnet_tpu.obs import get_recorder, metric, span
 from dnet_tpu.obs.jit import instrument_jit
 from dnet_tpu.obs.phases import (
     DECODE_CHUNK_WIDTHS,
+    KV_KIND_FULL,
+    KV_KIND_WINDOW,
     SPAN_DECODE_KV_GATHER,
     SPAN_DECODE_KV_SCATTER,
     SPAN_DECODE_LAUNCH,
@@ -75,6 +80,7 @@ _DECODE_SLOT_STEPS = metric("dnet_decode_slot_steps_total")
 _DECODE_LANE_STEPS = metric("dnet_decode_lane_steps_total")
 _DECODE_TOKENS = metric("dnet_decode_tokens_total")
 _DECODE_BUFFER_DROPPED = metric("dnet_decode_buffer_dropped_total")
+_MOE_ASSIGNMENTS = metric("dnet_moe_assignments_total")
 
 
 class BatchedEngine:
@@ -169,16 +175,40 @@ class BatchedEngine:
         self._kv_cfg: Optional[PagedKVConfig] = None
         self._tables: List[Optional[PageTable]] = [None] * slots
         self._adopt: Dict[str, Tuple[int, List[int], int]] = {}
+        # the books are kept by KIND of layer (obs/phases.py KV_KINDS): a
+        # pool manager and per-slot tables a kind.  Every model has the
+        # `full` kind — kv_pool and _tables are its entries, and what
+        # admission and prefix sharing are functions of; a model with
+        # WINDOW layers among full ones (model.paged_kinds) has the
+        # `window` kind too, whose tables hold only the blocks inside the
+        # window
+        self.kv_pools: Dict[str, BlockPool] = {}
+        self._kind_tables: Dict[str, List[Optional[PageTable]]] = {}
+        self._window = 0
+        windowed = paged and KV_KIND_WINDOW in (m.paged_kinds or ())
         if paged:
             try:
+                if windowed and prefix_size:
+                    # sharing a prefix's window blocks is not sound: the
+                    # donor gives them back as it advances
+                    log.warning(
+                        "paged prefix sharing is OFF for %s: window layers "
+                        "give blocks back, so a prefix entry cannot alias "
+                        "them; DNET_API_PREFIX_CACHE=%d is ignored",
+                        self.eng.config.model_type, prefix_size,
+                    )
+                    prefix_size = 0
                 cfg = PagedKVConfig.from_settings(
                     self.max_seq, slots=slots + prefix_size
                 )
-                store = BlockStore(
-                    m, len(m.layers), cfg, self.eng.kv_dtype,
-                    quant_bits=self.eng.kv_quant_bits,
-                    session_tokens=self.max_seq,
-                )
+                if windowed:
+                    store = self._window_store(m, cfg, slots)
+                else:
+                    store = BlockStore(
+                        m, len(m.layers), cfg, self.eng.kv_dtype,
+                        quant_bits=self.eng.kv_quant_bits,
+                        session_tokens=self.max_seq,
+                    )
             except (ValueError, NotImplementedError) as exc:
                 log.warning(
                     "paged KV disabled for batched engine (%s); "
@@ -196,6 +226,18 @@ class BatchedEngine:
                 self._kv_cfg = cfg
                 self.kv_pool = BlockPool(cfg)
                 self.kv_store = store
+                self.kv_pools = {KV_KIND_FULL: self.kv_pool}
+                self._kind_tables = {KV_KIND_FULL: self._tables}
+                if windowed:
+                    wcfg = store.cfgs[KV_KIND_WINDOW]
+                    self._window = int(m.window)
+                    self.kv_pools[KV_KIND_WINDOW] = BlockPool(wcfg, kind=KV_KIND_WINDOW)
+                    self._kind_tables[KV_KIND_WINDOW] = [None] * slots
+                    log.info(
+                        "window layers page apart: %d blocks (%d a slot) for "
+                        "a window of %d tokens",
+                        wcfg.pool_blocks, wcfg.pool_blocks // slots, self._window,
+                    )
                 if prefix_size > 0:
                     self.paged_prefix = PagedPrefixCache(
                         self.kv_pool, store, prefix_size,
@@ -218,7 +260,13 @@ class BatchedEngine:
         # refuses (quantized caches, non-llama attention stacks), on top
         # of the session layouts BlockStore itself already refused.
         self.kv_ragged = False
-        if paged and ragged_enabled():
+        if self._window:
+            self.kv_ragged = True  # _window_store refused anything else
+            log.info(
+                "ragged paged attention on: decode attends the block "
+                "pools in place, window layers from their lower bound"
+            )
+        elif paged and ragged_enabled():
             from dnet_tpu.ops.paged_attention import ragged_refusal
 
             why = ragged_refusal(m, self.eng.kv_quant_bits)
@@ -265,6 +313,33 @@ class BatchedEngine:
             else None
         )
         self._build()
+
+    def _window_store(self, m, cfg: PagedKVConfig, slots: int) -> KindStore:
+        """Pools by kind for a model with window layers.  Only the ragged
+        kernel reads them (there is no dense gather view of a table that
+        gave blocks back), so everything it refuses is refused here, and
+        the engine then serves dense slots.  The window kind's pool is
+        sized so that it can never be what admission waits for: every slot
+        may hold the most blocks a window table ever has."""
+        from dnet_tpu.config import get_settings
+        from dnet_tpu.ops.paged_attention import ragged_refusal
+
+        why = ragged_refusal(m, self.eng.kv_quant_bits)
+        if why is None and not ragged_enabled():
+            why = "DNET_KV_RAGGED is off"
+        if why is None and KV_KIND_FULL not in m.paged_kinds:
+            why = "no full layer among the window layers"
+        if why is not None:
+            raise NotImplementedError(
+                f"window layers page only under the ragged kernel ({why})"
+            )
+        step = max(int(get_settings().sched.sched_prefill_chunk), *self.CHUNK_BUCKETS)
+        per_slot = window_blocks(int(m.window), cfg.block_tokens, step)
+        wcfg = PagedKVConfig(cfg.block_tokens, slots * per_slot)
+        return KindStore(
+            m, {KV_KIND_FULL: cfg, KV_KIND_WINDOW: wcfg}, self.eng.kv_dtype,
+            window_width=per_slot,
+        )
 
     # ---- program ------------------------------------------------------
     def _build(self) -> None:
@@ -366,11 +441,14 @@ class BatchedEngine:
         swaps only the cache write + attention read), and sampling vmaps
         the identical per-lane tail, so greedy streams are parity-testable
         against the gather path byte for byte."""
-        from dnet_tpu.ops.paged_attention import paged_attend, paged_attend_impl
+        from dnet_tpu.ops.paged_attention import paged_attend_impl
 
         model = self.eng.model
         impl = paged_attend_impl()
+        store = self.kv_store  # it knows the pools' layout, by kind
         sp_axes = SampleParams(0, 0, 0, 0, 0, 0, 0, 0)
+        self._moe_reported = bool(getattr(model, "reports_moe_held", False))
+        self._moe_pending = None
 
         def one_sample(logits, active, sp, key, counts):
             """Per-lane sampling tail, identical to the vmapped `one()`:
@@ -392,24 +470,36 @@ class BatchedEngine:
                         counts):
             """One batched decode step against the pool (READ-ONLY here):
             returns the sampled results plus the stacked per-layer new K/V
-            rows for the kv_append program.  tables [slots, nb] int32
-            (bucketed), pos [slots] int32 live pool rows per slot."""
+            rows for the kv_append program.  tables: each kind's
+            [slots, nb] int32 (bucketed), and with window layers their
+            tables' `base` (_table_ids); pos [slots] int32 live pool rows
+            per slot."""
 
-            def attend_fn(q, k_new, v_new, kvs):
-                attn = paged_attend(
-                    q, kvs["k"], kvs["v"], tables, pos,
-                    k_new[:, 0], v_new[:, 0], impl=impl,
-                )
-                return attn, {"k": k_new[:, 0], "v": v_new[:, 0]}
+            def attend_fn(q, k_new, v_new, kvs, kind=None, layer=None):
+                # a model of one kind hands over its layer's pool slice
+                # `kvs`; one of two names the layer's kind and its index
+                rows = {"k": k_new[:, 0], "v": v_new[:, 0]}
+                attn = store.attend(pool, kvs, q, rows, tables, pos, kind, layer, impl)
+                return attn, rows
 
             x = model.embed(ep, token)  # [slots, 1, D]
             x, rows = model.apply_window(
                 wp, x, pool, pos[:, None], attend_fn=attend_fn
             )
+            # a model with an expert share says how many of each lane's
+            # chosen experts it holds (dnet_moe_assignments_total)
+            held = rows.pop("moe_held", None)  # [L, slots, 1]
+            if held is None:
+                moe = jnp.zeros((2,), jnp.int32)
+            else:
+                live = active.astype(jnp.int32)
+                mine = jnp.sum(held[..., 0] * live[None, :])
+                chosen = held.shape[0] * self.config.num_experts_per_tok * jnp.sum(live)
+                moe = jnp.stack([mine, chosen - mine]).astype(jnp.int32)
             x = model.normalize(ep, x[:, -1:])
             logits = model.lm_project(ep, x)[:, 0]  # [slots, V]
             res, counts, keys = vsample(logits, active, sp, keys, counts)
-            return res, rows, counts, keys
+            return res, rows, counts, keys, moe
 
         self._ragged_step_fn = ragged_step
         self._ragged_step = instrument_jit(
@@ -433,32 +523,37 @@ class BatchedEngine:
                       counts):
                 def body(carry, _):
                     token, pool, pos, keys, counts = carry
-                    res, rows, counts, keys = step(
+                    res, rows, counts, keys, moe = step(
                         wp, ep, token, pool, tables, pos, active, sp, keys,
                         counts,
                     )
-                    nb = tables.shape[1]
-                    bidx = jnp.clip(pos // bt, 0, nb - 1)
-                    phys = jnp.take_along_axis(tables, bidx[:, None], axis=1)[:, 0]
-                    # frozen lanes write PAST the block axis (mode="drop"
-                    # discards out-of-range, but a negative index would
-                    # wrap to block N-1 and clobber a live block)
-                    phys = jnp.where(active, phys, self._kv_cfg.pool_blocks)
-                    off = pos % bt
-                    pool = jax.tree.map(
-                        lambda p, r: p.at[:, phys, off].set(
-                            r.astype(p.dtype), mode="drop"
-                        ),
+
+                    def phys_of(tbl, first, n_blocks):
+                        """The block each lane's new row goes to; frozen
+                        lanes write PAST the block axis (mode="drop"
+                        discards out-of-range, but a negative index would
+                        wrap to block N-1 and clobber a live block)."""
+                        bidx = jnp.clip(pos // bt - first, 0, tbl.shape[1] - 1)
+                        phys = jnp.take_along_axis(tbl, bidx[:, None], axis=1)[:, 0]
+                        return jnp.where(active, phys, n_blocks)
+
+                    first = {KV_KIND_WINDOW: tables.get("base", 0)}
+                    pool = self.kv_store.append_in_program(
                         pool, rows,
+                        {
+                            kind: phys_of(tables[kind], first.get(kind, 0), p.total)
+                            for kind, p in self.kv_pools.items()
+                        },
+                        pos % bt,
                     )
                     token = jnp.where(active[:, None], res.token, token)
                     pos = pos + active.astype(pos.dtype)
-                    return (token, pool, pos, keys, counts), res
+                    return (token, pool, pos, keys, counts), (res, moe)
 
-                (token, pool, pos, keys, counts), stacked = jax.lax.scan(
+                (token, pool, pos, keys, counts), (stacked, moe) = jax.lax.scan(
                     body, (token, pool, pos, keys, counts), None, length=R
                 )
-                return stacked, pool, counts, keys
+                return stacked, pool, counts, keys, jnp.sum(moe, axis=0)
 
             fn = instrument_jit(
                 jax.jit(chunk, donate_argnums=(3, 9)), "paged_attend"
@@ -531,8 +626,10 @@ class BatchedEngine:
                 # block-table release: the whole point of paging — a
                 # finished request's blocks return to the free list (or
                 # drop a refcount on shared prefix blocks)
-                tbl, self._tables[slot] = self._tables[slot], None
-                self.kv_pool.release_table(tbl)
+                for kind, pool in self.kv_pools.items():
+                    tables = self._kind_tables[kind]
+                    tbl, tables[slot] = tables[slot], None
+                    pool.release_table(tbl)
             self.counts = self.counts.at[slot].set(0)
             if self.hist is not None:
                 self.hist = self.hist.at[slot].set(0)
@@ -651,33 +748,67 @@ class BatchedEngine:
         return res
 
     def _commit_paged_slot(self, nonce: str, slot: int, sess) -> None:
-        """Turn a staged B=1 prefill into this slot's page table: aliased
-        prefix blocks stay in place, everything from the first non-shared
-        block commits out of the staged dense row (which already merged
-        shared-partial content with the new tokens — the COW copy)."""
+        """Turn a staged B=1 prefill into this slot's page tables, one a
+        kind.  Full kind: aliased prefix blocks stay in place, everything
+        from the first non-shared block commits out of the staged dense row
+        (which already merged shared-partial content with the new tokens —
+        the COW copy).  Window kind (no prefix to alias: the sharing is
+        off): only the blocks the next token's window still reaches — the
+        blocks behind it are never allocated.  All or nothing."""
         cfg = self._kv_cfg
         n = int(sess.pos)
         nb = cfg.blocks_for(n)
         stash = self._adopt.pop(nonce, None)
         n_sh, blocks, n_full = stash if stash is not None else (0, [], 0)
+        first = {KV_KIND_FULL: n_full}
+        if self._window:
+            first[KV_KIND_WINDOW] = window_first_block(n, self._window, cfg.block_tokens)
+        own: Dict[str, List[int]] = {}
         try:
-            own = self.kv_pool.alloc(nb - n_full)
+            for kind, pool in self.kv_pools.items():
+                own[kind] = pool.alloc(nb - first[kind])
         except KVPoolExhausted:
+            for kind, got in own.items():
+                self.kv_pools[kind].free_blocks(got)
             if stash is not None:
                 self._adopt[nonce] = stash  # abandon_prefill releases it
             raise
-        self.kv_store.commit_row(sess.kv, list(range(n_full, nb)), own)
+        self.kv_store.commit_staged(
+            sess.kv,
+            {kind: (list(range(first[kind], nb)), got) for kind, got in own.items()},
+        )
         if stash is not None:
             if n_sh % cfg.block_tokens:
                 # the request diverged mid-block: the shared tail block was
                 # copied (via the staged row) instead of mutated in place
                 self.kv_pool.count_cow()
             self.kv_pool.free_blocks(blocks[n_full:])  # transient refs
-        # a re-prefilled nonce keeps its slot: drop the superseded table
-        self.kv_pool.release_table(self._tables[slot])
-        self._tables[slot] = PageTable(
-            blocks=list(blocks[:n_full]) + own, shared_upto=n_full
-        )
+        for kind, pool in self.kv_pools.items():
+            tables = self._kind_tables[kind]
+            # a re-prefilled nonce keeps its slot: drop the superseded table
+            pool.release_table(tables[slot])
+            kept = list(blocks[:n_full]) if kind == KV_KIND_FULL else []
+            tables[slot] = PageTable(
+                blocks=kept + own[kind], shared_upto=len(kept),
+                base=first[kind] - len(kept),
+            )
+
+    def _extend_window_tables(self, order, errors, active, R: int) -> None:
+        """Before a dispatch of R steps: every stepping lane's window table
+        gives back the blocks wholly behind its first step's window, then
+        grows to cover R more tokens.  (Inside SPAN_DECODE_PREPARE.)"""
+        bt = self._kv_cfg.block_tokens
+        pool = self.kv_pools[KV_KIND_WINDOW]
+        for nonce, slot in list(order.items()):
+            tbl = self._kind_tables[KV_KIND_WINDOW][slot]
+            p0 = int(self.pos[slot])
+            pool.release_behind(tbl, window_first_block(p0, self._window, bt))
+            try:
+                pool.ensure(tbl, p0 + R)
+            except KVPoolExhausted as exc:  # the pool is sized against this
+                errors[nonce] = str(exc)
+                active[slot] = False
+                del order[nonce]
 
     def _paged_extend(self, order, errors, active, R: int) -> int:
         """Extend every stepping lane's page table to cover R more tokens.
@@ -714,10 +845,11 @@ class BatchedEngine:
                     del tbl.blocks[keep:]
             R = 1
 
-    def _table_ids(self, order: Optional[Dict[str, int]] = None) -> np.ndarray:
-        """[slots, nb] physical block ids (0-padded past each table; padded
-        rows sit beyond every live pos, where the causal mask zeroes them
-        exactly).
+    def _table_ids(self, order: Optional[Dict[str, int]] = None) -> Dict[str, np.ndarray]:
+        """Each kind's [slots, nb] physical block ids (0-padded past each
+        table; padded rows sit beyond every live pos, where the causal mask
+        zeroes them exactly), and with window layers `base`, [slots]: the
+        logical block a window table's first entry backs.
 
         With `order` (the dispatch's active nonce -> slot map), nb is the
         pow2 BUCKET of the widest active table instead of max_seq/bt: the
@@ -747,7 +879,19 @@ class BatchedEngine:
             if tbl is not None and tbl.blocks:
                 n = min(len(tbl.blocks), nb)
                 ids[slot, :n] = tbl.blocks[:n]
-        return ids
+        out = {KV_KIND_FULL: ids}
+        if self._window:
+            # the window kind: one static width (the most blocks a window
+            # table ever holds), entry j backing logical block base + j
+            nbw = self.kv_pools[KV_KIND_WINDOW].total // self.slots
+            wids = np.zeros((self.slots, nbw), dtype=np.int32)
+            base = np.zeros(self.slots, dtype=np.int32)
+            for slot, tbl in enumerate(self._kind_tables[KV_KIND_WINDOW]):
+                if tbl is not None and tbl.blocks:
+                    wids[slot, : len(tbl.blocks)] = tbl.blocks
+                    base[slot] = tbl.base
+            out.update({KV_KIND_WINDOW: wids, "base": base})
+        return out
 
     def _move_to_slot(self, nonce: str, sess) -> None:
         slot = self.alloc_slot(nonce)
@@ -869,7 +1013,7 @@ class BatchedEngine:
         else:
             if paged:
                 with span(SPAN_DECODE_KV_GATHER):
-                    kv_in = self.kv_store.gather(table_ids)
+                    kv_in = self.kv_store.gather(table_ids[KV_KIND_FULL])
             else:
                 kv_in = self.kv
             token_d, pos_d, active_d, sp = dev
@@ -915,6 +1059,11 @@ class BatchedEngine:
             lps = np.asarray(src.logprob)
             tts = np.asarray(src.top_tokens)
             tlps = np.asarray(src.top_logprobs)
+            if self.kv_ragged and self._moe_reported:
+                # summed on the device by the dispatch just read: no sync
+                mine, elsewhere = np.asarray(self._moe_pending)
+                _MOE_ASSIGNMENTS.labels(held="yes").inc(int(mine))
+                _MOE_ASSIGNMENTS.labels(held="no").inc(int(elsewhere))
         with span(SPAN_DECODE_UNPACK):
             now = time.time()
             out: Dict[str, SampleResult] = dict(out_buf)
@@ -1055,9 +1204,13 @@ class BatchedEngine:
             R = self._paged_extend(order, errors, active, R)
             if not order:
                 return None
+            if self._window:
+                self._extend_window_tables(order, errors, active, R)
+                if not order:
+                    return None
             table_ids = self._table_ids(order if R == 1 else None)
             if self.kv_ragged:
-                table_ids = jnp.asarray(table_ids)
+                table_ids = jax.tree.map(jnp.asarray, table_ids)
         dev = (jnp.asarray(token), jnp.asarray(pos), jnp.asarray(active), sp)
         return order, R, dev, table_ids
 
@@ -1080,19 +1233,26 @@ class BatchedEngine:
             self.counts,
         )
         if R > 1:
-            stacked, pool, self.counts, self.keys = self._ragged_chunk_fn(R)(*args)
+            stacked, pool, self.counts, self.keys, self._moe_pending = (
+                self._ragged_chunk_fn(R)(*args)
+            )
             self.kv_store.kv = pool
             return stacked
-        res, rows, self.counts, self.keys = self._ragged_step(*args)
+        res, rows, self.counts, self.keys, self._moe_pending = self._ragged_step(*args)
         bt = self._kv_cfg.block_tokens
         # inactive-lane sentinel: past the block axis, never negative
-        # (see BlockStore append)
-        phys = np.full(self.slots, self._kv_cfg.pool_blocks, dtype=np.int32)
+        # (see BlockStore.append_in_program)
+        phys = {
+            kind: np.full(self.slots, pool.total, dtype=np.int32)
+            for kind, pool in self.kv_pools.items()
+        }
         off = np.zeros(self.slots, dtype=np.int32)
         for _nonce, slot in order.items():
             p0 = int(self.pos[slot])
-            phys[slot] = self._tables[slot].blocks[p0 // bt]
             off[slot] = p0 % bt
+            for kind, tables in self._kind_tables.items():
+                tbl = tables[slot]
+                phys[kind][slot] = tbl.blocks[p0 // bt - tbl.base]
         self.kv_store.append_rows(rows, phys, off)
         return res
 

@@ -17,18 +17,23 @@ from dnet_tpu.kv.paged import (
     ceil_div,
     paged_enabled,
     ragged_enabled,
+    window_blocks,
+    window_first_block,
 )
 from dnet_tpu.kv.prefix import PagedPrefixCache
-from dnet_tpu.kv.store import BlockStore
+from dnet_tpu.kv.store import BlockStore, KindStore
 
 __all__ = [
     "BlockPool",
     "BlockStore",
     "KVPoolExhausted",
+    "KindStore",
     "PagedKVConfig",
     "PagedPrefixCache",
     "PageTable",
     "ceil_div",
     "paged_enabled",
     "ragged_enabled",
+    "window_blocks",
+    "window_first_block",
 ]

@@ -34,6 +34,7 @@ from typing import Dict, List, Optional, Sequence
 
 from dnet_tpu.analysis.runtime import ownership as dsan
 from dnet_tpu.obs import metric
+from dnet_tpu.obs.phases import KV_KIND_FULL, KV_KIND_WINDOW
 
 _USED = metric("dnet_kv_blocks_used")
 _FREE = metric("dnet_kv_blocks_free")
@@ -41,6 +42,7 @@ _POOL = metric("dnet_kv_pool_blocks")
 _COW = metric("dnet_kv_cow_copies_total")
 _SHARED = metric("dnet_kv_prefix_shared_blocks_total")
 _REJECTED = metric("dnet_kv_admission_rejected_total")
+_RELEASED = metric("dnet_kv_window_blocks_released_total")
 
 
 class KVPoolExhausted(RuntimeError):
@@ -101,23 +103,48 @@ class PagedKVConfig:
 class PageTable:
     """One sequence's logical->physical block map.
 
-    `blocks[i]` backs tokens [i*bt, (i+1)*bt); `shared_upto` marks how many
-    LEADING blocks are refcount-aliased from a prefix entry (full blocks
-    only — immutable for this sequence, so decode never writes them; the
-    partial tail of a shared prefix is COW-copied at adoption)."""
+    `blocks[i]` backs tokens [(base+i)*bt, (base+i+1)*bt); `shared_upto`
+    marks how many LEADING blocks are refcount-aliased from a prefix entry
+    (full blocks only — immutable for this sequence, so decode never
+    writes them; the partial tail of a shared prefix is COW-copied at
+    adoption).  `base` is 0 for a table that keeps everything; a WINDOW
+    layer's table has given back its first `base` logical blocks
+    (`BlockPool.release_behind`), and prefix sharing is off for it."""
 
     blocks: List[int] = field(default_factory=list)
     shared_upto: int = 0
+    base: int = 0
 
     def __len__(self) -> int:
         return len(self.blocks)
 
 
-class BlockPool:
-    """Fixed-capacity block allocator with refcounts and exact accounting."""
+def window_blocks(window: int, block_tokens: int, step_tokens: int) -> int:
+    """The most blocks one sequence's WINDOW-kind table ever holds: the
+    window, the tokens one dispatch may add before the blocks behind it are
+    given back (a prefill chunk or a fused decode chunk), and one block for
+    the edges the window and the step cut."""
+    return ceil_div(window + step_tokens, block_tokens) + 1
 
-    def __init__(self, cfg: PagedKVConfig) -> None:
+
+def window_first_block(n_tokens: int, window: int, block_tokens: int) -> int:
+    """Logical index of the first block a window layer still needs when the
+    NEXT token sits at position n_tokens: it attends keys at positions
+    > n_tokens - window."""
+    return max(n_tokens - window + 1, 0) // block_tokens
+
+
+class BlockPool:
+    """Fixed-capacity block allocator with refcounts and exact accounting,
+    for the layers of ONE kind (obs/phases.py KV_KINDS): a model whose
+    layers all keep everything has one pool of the `full` kind; window
+    layers get a pool of their own, whose tables give blocks back."""
+
+    def __init__(self, cfg: PagedKVConfig, kind: str = KV_KIND_FULL) -> None:
         self.cfg = cfg
+        self.kind = kind
+        self._used_g = _USED.labels(kind=kind)
+        self._free_g = _FREE.labels(kind=kind)
         self.block_tokens = cfg.block_tokens
         self.total = cfg.pool_blocks
         # every _free/_ref touch happens under _lock; the guarded-by
@@ -132,7 +159,7 @@ class BlockPool:
         # high-water mark of used blocks (tests/bench read it; the gauge
         # only shows the current value)
         self.peak_used = 0
-        _POOL.set(self.total)
+        _POOL.labels(kind=kind).set(self.total)
         self._publish()
 
     # ---- accounting ---------------------------------------------------
@@ -153,8 +180,8 @@ class BlockPool:
             used, free = len(self._ref), len(self._free)
             if used > self.peak_used:
                 self.peak_used = used
-        _USED.set(used)
-        _FREE.set(free)
+        self._used_g.set(used)
+        self._free_g.set(free)
 
     def can_cover(self, n_blocks: int) -> bool:
         with self._lock:
@@ -256,12 +283,27 @@ class BlockPool:
     def ensure(self, table: PageTable, n_tokens: int) -> List[int]:
         """Grow `table` to cover n_tokens (appending fresh blocks); returns
         the newly appended block ids.  All-or-nothing on exhaustion."""
-        need = self.cfg.blocks_for(n_tokens) - len(table.blocks)
+        need = self.cfg.blocks_for(n_tokens) - table.base - len(table.blocks)
         if need <= 0:
             return []
         fresh = self.alloc(need)
         table.blocks.extend(fresh)
         return fresh
+
+    def release_behind(self, table: PageTable, first_block: int) -> int:
+        """Give back the leading blocks of a WINDOW layer's table that lie
+        wholly before logical block `first_block` (window_first_block): the
+        sequence advanced and no later token attends them.  Returns how
+        many went back to the free list."""
+        n = min(max(first_block - table.base, 0), len(table.blocks))
+        if n == 0:
+            return 0
+        gone = table.blocks[:n]
+        del table.blocks[:n]
+        table.base += n
+        self.free_blocks(gone)
+        _RELEASED.inc(n)
+        return n
 
     def release_table(self, table: Optional[PageTable]) -> int:
         if table is None or not table.blocks:
@@ -269,6 +311,7 @@ class BlockPool:
         n = self.free_blocks(table.blocks)
         table.blocks.clear()
         table.shared_upto = 0
+        table.base = 0
         return n
 
     # ---- invariants ---------------------------------------------------

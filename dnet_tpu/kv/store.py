@@ -23,6 +23,14 @@ Scatter widths are bucketed to powers of two (padding repeats the last
 triple — duplicate scatters of identical content are deterministic) so
 the compiled-program set stays bounded, the same discipline as the
 engines' chunk buckets.
+
+The ragged decode path (core/batch.py `_build_ragged`) speaks to a store by
+KIND of layer (obs/phases.py KV_KINDS) and knows no layout: `kinds`,
+`attend`, `append_in_program`, `append_rows` and `commit_staged` take and
+return `{kind: ...}`.  `BlockStore` is the one-kind store (`full` alone,
+the layout above, which the dense-gather view and the prefix cache also
+read); `KindStore` holds a pool a kind for a model that mixes window and
+full layers.
 """
 
 from __future__ import annotations
@@ -35,6 +43,13 @@ import numpy as np
 
 from dnet_tpu.kv.paged import PagedKVConfig
 from dnet_tpu.obs.jit import instrument_jit
+from dnet_tpu.obs.phases import (
+    KV_KIND_FULL,
+    KV_KIND_WINDOW,
+    KV_KINDS,
+    SCOPE_ATTN_FULL,
+    SCOPE_ATTN_WINDOW,
+)
 
 
 def _bucket_pow2(n: int) -> int:
@@ -119,22 +134,6 @@ class BlockStore:
 
             return jax.tree.map(one, pool, dense)
 
-        @jax.named_scope("kv_append")
-        def append(pool, rows, phys, off):
-            """Write one new token row per slot straight into its physical
-            block: rows leaves [L, slots, KVH, Hd] -> pool[:, phys[s],
-            off[s]].  Inactive lanes pass phys == pool_blocks — PAST the
-            block axis, so mode="drop" discards the write (a negative
-            sentinel would WRAP to block N-1 and clobber a live block
-            before drop semantics ever applied).  The ragged decode
-            path's replacement for the whole dense round-trip: the
-            step's ONLY cache write."""
-
-            def one(p, r):
-                return p.at[:, phys, off].set(r.astype(p.dtype), mode="drop")
-
-            return jax.tree.map(one, pool, rows)
-
         # instrumented: a page-table geometry leak re-tracing these per
         # step shows as climbing dnet_jit_compiles_total{fn=kv_*} (gather
         # widths are pow2-bucketed by the engines, so the compiled-program
@@ -144,8 +143,48 @@ class BlockStore:
             jax.jit(scatter, donate_argnums=(0,)), "kv_scatter"
         )
         self._append = instrument_jit(
-            jax.jit(append, donate_argnums=(0,)), "kv_append"
+            jax.jit(
+                jax.named_scope("kv_append")(self.append_in_program),
+                donate_argnums=(0,),
+            ),
+            "kv_append",
         )
+
+    # ---- the ragged path's view: one kind -----------------------------
+    #: every layer keeps everything
+    kinds = (KV_KIND_FULL,)
+
+    def attend(self, pool, kvs, q, rows, tables, pos, kind, layer, impl):
+        """Traced: one layer's decode attention.  The model's scan slices
+        the stack, so `kvs` is this layer's [N, bt, KVH, Hd] and `pool`,
+        `kind` and `layer` go unread."""
+        from dnet_tpu.ops.paged_attention import paged_attend
+
+        return paged_attend(
+            q, kvs["k"], kvs["v"], tables[KV_KIND_FULL], pos, rows["k"],
+            rows["v"], impl=impl,
+        )
+
+    def append_in_program(self, pool, rows, phys, off):
+        """Traced: write one new token row per slot straight into its
+        physical block: rows leaves [L, slots, KVH, Hd] -> pool[:,
+        phys[full][s], off[s]].  Inactive lanes pass phys == pool_blocks —
+        PAST the block axis, so mode="drop" discards the write (a negative
+        sentinel would WRAP to block N-1 and clobber a live block before
+        drop semantics ever applied).  The ragged decode path's
+        replacement for the whole dense round-trip: the step's ONLY cache
+        write."""
+        at = phys[KV_KIND_FULL]
+
+        def one(p, r):
+            return p.at[:, at, off].set(r.astype(p.dtype), mode="drop")
+
+        return jax.tree.map(one, pool, rows)
+
+    def commit_staged(self, kv_row: dict, blocks: dict) -> None:
+        """blocks: {kind: (logical block indices, physical blocks)} of one
+        staged [L, 1, S, ...] row."""
+        self.commit_row(kv_row, *blocks[KV_KIND_FULL])
 
     # ---- ops ----------------------------------------------------------
     def gather(self, ids: np.ndarray) -> dict:
@@ -179,15 +218,15 @@ class BlockStore:
         phys = jnp.asarray([t[2] for t in padded], dtype=jnp.int32)
         self.kv = self._scatter(self.kv, dense, slot_idx, block_idx, phys)
 
-    def append_rows(self, rows: dict, phys, off) -> None:
+    def append_rows(self, rows: dict, phys: dict, off) -> None:
         """Ragged-decode block append: one new token row per slot, written
         in place (donated pool buffers).  rows leaves [L, slots, KVH, Hd]
-        (the step program's stacked per-layer k/v outputs); phys/off
-        [slots] int32 physical block + in-block offset; phys ==
+        (the step program's stacked per-layer k/v outputs); phys[kind] and
+        off [slots] int32 physical block + in-block offset; phys ==
         pool_blocks (out of range, NOT negative) = skip this lane."""
         self.kv = self._append(
             self.kv, rows,
-            jnp.asarray(phys, dtype=jnp.int32),
+            {k: jnp.asarray(v, dtype=jnp.int32) for k, v in phys.items()},
             jnp.asarray(off, dtype=jnp.int32),
         )
 
@@ -204,3 +243,132 @@ class BlockStore:
             [(0, lb, pb) for lb, pb in zip(logical_blocks, phys_blocks)],
         )
 
+
+
+class KindStore:
+    """Pools for a model whose layers are of two KINDS (models that set
+    `paged_kinds`: window and full attention mixed): each kind's layers
+    share a pool `[L_kind, N_kind, block_tokens, KVH*Hd]` and a pool manager
+    (kv/paged.py BlockPool) of their own, so a window layer's table can hold
+    the blocks inside its window alone while a full layer's keeps
+    everything.  Heads are merged into the lane dimension ONCE, here: the
+    ragged kernel (ops/paged_attention.py) reads a block as `[bt, KVH*Hd]`
+    and takes the layer by index, so no slice or relayout of a pool is made
+    per step.  Only the ragged path reads these pools: there is no dense
+    gather view of a table that gave blocks back.
+
+    `self.kv` is `{kind: {"k": ..., "v": ...}}`; `self.layers[kind]` the
+    local layer indices of the kind, in order."""
+
+    def __init__(self, model, cfgs: dict, kv_dtype: str, window_width: int = 0) -> None:
+        self.cfgs = cfgs
+        self.cfg = cfgs[KV_KIND_FULL]
+        self.window = int(model.window)
+        #: the most blocks one sequence's window table holds (0: unknown)
+        self.window_width = int(window_width)
+        self.block_tokens = bt = self.cfg.block_tokens
+        self.layers = {}
+        for i, kind in enumerate(model.paged_kinds):
+            self.layers[kind] = self.layers.get(kind, ()) + (i,)
+        self.kinds = tuple(k for k in KV_KINDS if k in self.layers)
+        if set(self.layers) != set(cfgs):
+            raise ValueError(f"pool kinds {sorted(cfgs)} != layer kinds {sorted(self.layers)}")
+        c = model.config
+        width = c.num_key_value_heads * c.head_dim
+        dt = jnp.dtype(kv_dtype)
+        self.kv = {
+            kind: {
+                leaf: jnp.zeros((len(idx), cfgs[kind].pool_blocks, bt, width), dt)
+                for leaf in ("k", "v")
+            }
+            for kind, idx in self.layers.items()
+        }
+        layers = self.layers
+
+        @jax.named_scope("kv_scatter")
+        def commit(pool, dense, block_idx, phys):
+            """One staged sequence's blocks into the pools: dense leaves
+            [L, 1, S, KVH, Hd]; per kind, logical block block_idx[kind][j]
+            of that kind's layers -> pool block phys[kind][j]."""
+            out = {}
+            for kind, idx in layers.items():
+                sel = jnp.asarray(idx, jnp.int32)
+
+                def one(p, d, kind=kind, sel=sel):
+                    rows = d[sel, 0]  # [Lk, S, KVH, Hd]
+                    Lk, S = rows.shape[:2]
+                    blk = rows.reshape(Lk, S // bt, bt, -1)[:, block_idx[kind]]
+                    return p.at[:, phys[kind]].set(blk.astype(p.dtype))
+
+                out[kind] = jax.tree.map(one, pool[kind], dense)
+            return out
+
+        self._commit = instrument_jit(
+            jax.jit(commit, donate_argnums=(0,)), "kv_scatter"
+        )
+        self._append = instrument_jit(
+            jax.jit(self.append_in_program, donate_argnums=(0,)), "kv_append"
+        )
+
+    def attend(self, pool, kvs, q, rows, tables, pos, kind, layer, impl):
+        """Traced: one layer's decode attention.  The model names the
+        layer's `kind` (its index in KV_KINDS, riding the scan as data) and
+        its index `layer` within the kind; the kernel takes the layer out
+        of the kind's stack itself.  Each kind's custom call has a name of
+        its own (the trace tells them apart)."""
+        from dnet_tpu.ops.paged_attention import paged_attend
+
+        def of(name, scope, **kw):
+            @jax.named_scope(scope)
+            def run():
+                return paged_attend(
+                    q, pool[name]["k"], pool[name]["v"], tables[name], pos,
+                    rows["k"], rows["v"], impl=impl, layer=layer, **kw,
+                )
+
+            return run
+
+        return jax.lax.cond(
+            kind == KV_KINDS.index(KV_KIND_WINDOW),
+            of(KV_KIND_WINDOW, SCOPE_ATTN_WINDOW, window=self.window, base=tables["base"]),
+            of(KV_KIND_FULL, SCOPE_ATTN_FULL),
+        )
+
+    def append_in_program(self, pool, rows, phys, off):
+        """Traced: one new token row per slot into each kind's pool.  rows
+        leaves [L, slots, KVH, Hd] (every layer, in model order); phys[kind]
+        [slots] the physical block (== pool_blocks, past the block axis,
+        drops the lane's write); off [slots] the row inside the block."""
+        out = {}
+        for kind, idx in self.layers.items():
+            sel = jnp.asarray(idx, jnp.int32)
+
+            def one(p, r, kind=kind, sel=sel):
+                r = r[sel]
+                r = r.reshape(r.shape[0], r.shape[1], -1)
+                return p.at[:, phys[kind], off].set(r.astype(p.dtype), mode="drop")
+
+            out[kind] = jax.tree.map(one, pool[kind], rows)
+        return out
+
+    def commit_staged(self, kv_row: dict, blocks: dict) -> None:
+        """blocks: {kind: (logical block indices, physical blocks)} of one
+        staged [L, 1, S, ...] row.  Widths pad by repeating the last pair (a
+        duplicate write of identical content): the full kind's to a power
+        of two, the window kind's to the ONE width a window table can
+        reach, so the compiled programs are one a power of two and not one
+        a pair of them (a prompt of 4224 tokens holds 32 window blocks, one
+        of 4300 holds 33)."""
+        block_idx, phys = {}, {}
+        for kind in self.layers:
+            lb, pb = blocks[kind]
+            K = _bucket_pow2(max(len(lb), 1))
+            if kind == KV_KIND_WINDOW and self.window_width:
+                K = max(self.window_width, len(lb))
+            lb = list(lb) + [lb[-1]] * (K - len(lb))
+            pb = list(pb) + [pb[-1]] * (K - len(pb))
+            block_idx[kind] = jnp.asarray(lb, jnp.int32)
+            phys[kind] = jnp.asarray(pb, jnp.int32)
+        self.kv = self._commit(self.kv, kv_row, block_idx, phys)
+
+    append_rows = BlockStore.append_rows

@@ -73,7 +73,8 @@ class ModelConfig:
             num_attention_heads=heads,
             num_key_value_heads=d.get("num_key_value_heads", heads),
             head_dim=head_dim,
-            rms_norm_eps=d.get("rms_norm_eps", 1e-5),
+            # cohere2 names it layer_norm_eps and ships rms_norm_eps: null
+            rms_norm_eps=d.get("rms_norm_eps") or d.get("layer_norm_eps") or 1e-5,
             rope_theta=d.get("rope_theta", 10000.0),
             rope_scaling=d.get("rope_scaling"),
             tie_word_embeddings=d.get("tie_word_embeddings", False),
@@ -108,10 +109,17 @@ class RingModel(abc.ABC):
     supports_kv_commit: bool = True
     # apply_window accepts an `attend_fn` override replacing the cache
     # write + attention of every layer (ragged paged attention,
-    # ops/paged_attention.py).  Only the llama-family stack threads it;
-    # models with bespoke attention layouts (gpt_oss paired SWA rings,
-    # deepseek MLA) keep the dense-gather decode path.
+    # ops/paged_attention.py).  The llama-family stack threads it as
+    # attend_fn(q, k, v, kvs); a model whose layers are of two kinds
+    # (cohere2_moe: window and full) sets `paged_kinds` and also passes
+    # kind= and layer= (the index within the kind).  Models with bespoke
+    # attention layouts (gpt_oss paired SWA rings, deepseek MLA) keep the
+    # dense-gather decode path.
     supports_paged_attend: bool = False
+    # per local layer its kind (obs/phases.py KV_KINDS): `full` keeps
+    # everything, a `window` layer's page table gives back the blocks
+    # behind the window; None = all full
+    paged_kinds: Optional[Tuple[str, ...]] = None
     # per-layer param names eligible for weight-only quantization (the big
     # matmuls; norms/biases/routers stay float).  Subclasses override.
     quant_keys: frozenset = frozenset(QUANTIZABLE)
@@ -171,8 +179,11 @@ class RingModel(abc.ABC):
         """Final norm before the LM head."""
 
     @jax.named_scope(SCOPE_LM_HEAD)
-    def lm_project(self, edge_params: dict, x: jnp.ndarray) -> jnp.ndarray:
-        """hidden [B, T, D] -> logits [B, T, V].
+    def lm_project(
+        self, edge_params: dict, x: jnp.ndarray, out_dtype=None
+    ) -> jnp.ndarray:
+        """hidden [B, T, D] -> logits [B, T, V], in x's type or `out_dtype`
+        (the matmul accumulates in float32 either way).
 
         The projection matrix is the single largest per-step HBM read at
         decode (O(hidden x vocab) — ~0.5 GB bf16 for Llama-1B); quantized
@@ -185,7 +196,7 @@ class RingModel(abc.ABC):
             w = dq(w) if is_quantized(w) else w.T
         else:
             w = dq(edge_params["lm_head"]["weight"])
-        return x @ w
+        return jnp.matmul(x, w, preferred_element_type=out_dtype)
 
     # ---- weight mapping ----------------------------------------------
     @abc.abstractmethod

@@ -139,18 +139,35 @@ def _register_core(reg: MetricsRegistry) -> None:
     )
     # paged KV pool (dnet_tpu/kv/paged.py): used + free == pool size at all
     # times (shared blocks count once in used; BlockPool.check_conservation)
-    reg.gauge(
-        "dnet_kv_blocks_used",
-        "Paged KV pool blocks currently allocated (refcount >= 1)",
+    from dnet_tpu.obs.phases import KV_KINDS, MOE_HELD
+
+    for name, help_text in (
+        ("dnet_kv_blocks_used",
+         "Paged KV pool blocks currently allocated (refcount >= 1), by the "
+         "kind of layer the pool serves (obs/phases.py KV_KINDS)"),
+        ("dnet_kv_blocks_free",
+         "Paged KV pool blocks on the free list, by kind"),
+        ("dnet_kv_pool_blocks",
+         "Paged KV pool total capacity in blocks, by kind"),
+    ):
+        fam = reg.gauge(name, help_text, labelnames=("kind",))
+        for kind in KV_KINDS:
+            fam.labels(kind=kind)  # pre-touch: the lint checks these
+    reg.counter(
+        "dnet_kv_window_blocks_released_total",
+        "Blocks a window layer's page table gave back because every row "
+        "of them fell behind the window as the sequence advanced",
     )
-    reg.gauge(
-        "dnet_kv_blocks_free",
-        "Paged KV pool blocks on the free list",
+    moe_fam = reg.counter(
+        "dnet_moe_assignments_total",
+        "(token, chosen expert) pairs of batched decode dispatches' active "
+        "lanes, by whether this process holds the expert (an expert share "
+        "routes over every expert and computes its own; models that do "
+        "not report it leave this at 0)",
+        labelnames=("held",),
     )
-    reg.gauge(
-        "dnet_kv_pool_blocks",
-        "Paged KV pool total capacity in blocks",
-    )
+    for held in MOE_HELD:
+        moe_fam.labels(held=held)  # pre-touch: the lint checks these
     reg.counter(
         "dnet_kv_cow_copies_total",
         "Paged KV copy-on-write block copies (shared block diverged)",

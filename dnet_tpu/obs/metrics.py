@@ -270,6 +270,12 @@ class MetricFamily:
     def sum(self) -> float:
         return self._default().sum
 
+    def total(self) -> float:
+        """Sum over every series of a counter or gauge family (a labeled
+        family's whole, e.g. the paged pool's blocks over its kinds)."""
+        with self._lock:
+            return sum(child.value for child in self._children.values())
+
     def series_count(self) -> int:
         with self._lock:
             return len(self._children)

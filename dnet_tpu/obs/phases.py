@@ -77,7 +77,30 @@ SCOPE_SAMPLE = "dnet.sample"
 SCOPE_LM_HEAD = "dnet.lm_head"
 SCOPE_MOE = "dnet.moe"
 SCOPE_ATTN = "dnet.attn"
-DEVICE_SCOPES = (SCOPE_SAMPLE, SCOPE_LM_HEAD, SCOPE_MOE, SCOPE_ATTN)
+# inside dnet.attn / dnet.moe of a model with layers of two kinds and
+# always-on experts (models/cohere2_moe.py)
+SCOPE_ATTN_WINDOW = "dnet.attn.window"
+SCOPE_ATTN_FULL = "dnet.attn.full"
+SCOPE_MOE_SHARED = "dnet.moe.shared"
+DEVICE_SCOPES = (
+    SCOPE_SAMPLE, SCOPE_LM_HEAD, SCOPE_MOE, SCOPE_ATTN,
+    SCOPE_ATTN_WINDOW, SCOPE_ATTN_FULL, SCOPE_MOE_SHARED,
+)
+
+# dnet_kv_blocks_used / _free / dnet_kv_pool_blocks {kind=}: the paged pool
+# keeps books per KIND of layer (kv/paged.py).  A model whose layers all
+# keep everything has the `full` kind alone; `window` layers hold only the
+# blocks inside their window and give back the ones behind it
+# (dnet_kv_window_blocks_released_total counts those).
+KV_KIND_FULL = "full"
+KV_KIND_WINDOW = "window"
+KV_KINDS = (KV_KIND_FULL, KV_KIND_WINDOW)
+
+# dnet_moe_assignments_total{held=}: (token, chosen expert) pairs of the
+# batched decode dispatches' active lanes, by whether the expert is one
+# this process holds (an expert share, ops/moe.py) — summed on the device,
+# read back with the step's tokens
+MOE_HELD = ("yes", "no")
 
 # dnet_decode_tokens_total{source=}: where a token decode_batch handed the
 # driver came from (core/batch.py)

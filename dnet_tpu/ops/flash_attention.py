@@ -14,7 +14,11 @@ same treatment.  Scope: CAUSAL SELF-ATTENTION against a slot-addressed
 cache — query row i attends keys [0, pos + i] — covering llama-family,
 deepseek-MLA (V's head dim may differ from Q/K's), and gpt_oss
 full-attention prefill (per-head sink logits folded into the softmax
-denominator at emit).  Sliding windows and sp sharding stay dense.
+denominator at emit).  A window layer (`window` > 0) adds the lower bound
+— row i attends keys (pos + i - window, pos + i] — and skips the tiles
+wholly behind it as it skips those above the diagonal; its custom call has
+a name of its own.  Rotating ring-buffer windows and sp sharding stay
+dense.
 
 TPU grids run sequentially over the LAST axis, so the KV-tile axis comes
 last and the scratch accumulator carries across its iterations; blocks
@@ -36,10 +40,20 @@ from dnet_tpu.ops.kernel_select import SELECTIONS, kernel_backend
 NEG_INF = -1e30
 
 
+#: the custom calls' names in a device trace, by the layer's kind
+FLASH_NAME = "flash_prefill"
+FLASH_WINDOW_NAME = "flash_prefill_window"
+#: query heads one grid step holds at most (scratch is [heads, bq, ...] f32:
+#: 32 heads x 128 rows is 6 MB, and 128 heads would not fit VMEM)
+HEADS_PER_STEP = 32
+
+
 def _flash_kernel(pos_ref, sink_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                   acc_ref, *, bq: int, bk: int, scale: float, n_s: int,
-                  KVH: int, G: int, Hd: int, Vd: int):
-    """One (batch, q-tile, kv-tile) step of the online softmax, every head.
+                  KVH: int, G: int, Hd: int, Vd: int, window: int = 0):
+    """One (batch, head-group, q-tile, kv-tile) step of the online softmax,
+    every head of the group (KVH kv heads and their G query heads each;
+    all the heads when they fit one step).
 
     Mosaic tiles the last two dims of a block, so a block can take a head
     out of [.., heads, dim] only whole.  The operands therefore arrive with
@@ -52,8 +66,9 @@ def _flash_kernel(pos_ref, sink_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     underflows to an exact no-op)."""
     import jax.experimental.pallas as pl
 
-    tq = pl.program_id(1)
-    s = pl.program_id(2)
+    hb = pl.program_id(1)
+    tq = pl.program_id(2)
+    s = pl.program_id(3)
     pos = pos_ref[0]
 
     @pl.when(s == 0)
@@ -65,12 +80,23 @@ def _flash_kernel(pos_ref, sink_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     # this q-tile's LAST row attends keys <= pos + tq*bq + bq - 1; a kv
     # tile starting past that is fully masked for the whole tile -> skip
     q_hi = pos + (tq + 1) * bq - 1
+    live = s * bk <= q_hi
+    if window:
+        # the tile's FIRST row attends keys > pos + tq*bq - window, every
+        # later row only later ones: a kv tile ending at or behind that is
+        # wholly behind the window for the whole q-tile -> skip
+        live = live & ((s + 1) * bk - 1 > pos + tq * bq - window)
 
-    @pl.when(s * bk <= q_hi)
+    @pl.when(live)
     def _fold():
         q_pos = pos + tq * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         k_pos = s * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         keep = k_pos <= q_pos
+        if window:
+            # a row whose window opens in a later tile folds only masked
+            # scores here (m stays NEG_INF, p = 1): its first real score
+            # rescales that by exp(NEG_INF - m) == 0.0, exactly
+            keep = keep & (k_pos > q_pos - window)
         for kh in range(KVH):
             k = k_ref[0, :, kh * Hd:(kh + 1) * Hd].astype(jnp.float32)
             v = v_ref[0, :, kh * Vd:(kh + 1) * Vd].astype(jnp.float32)
@@ -100,7 +126,7 @@ def _flash_kernel(pos_ref, sink_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         # fold the sink into the global softmax denominator exactly once
         # (same algebra as the dense op's virtual-key column)
         for h in range(KVH * G):
-            sink = sink_ref[h]
+            sink = sink_ref[hb * KVH * G + h]
             m_fin = jnp.maximum(m_ref[h], sink)
             corr = jnp.exp(m_ref[h] - m_fin)
             l_fin = l_ref[h] * corr + jnp.exp(sink - m_fin)
@@ -109,11 +135,29 @@ def _flash_kernel(pos_ref, sink_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
             ).astype(o_ref.dtype)
 
 
+def _heads_per_step(KVH: int, G: int, Hd: int, Vd: int) -> int:
+    """kv heads one grid step takes: all of them when their query heads
+    fit HEADS_PER_STEP, else the largest divisor that does and keeps the
+    blocks' lane widths whole multiples of 128."""
+    if KVH * G <= HEADS_PER_STEP:
+        return KVH
+    for kb in range(KVH - 1, 0, -1):
+        if (
+            KVH % kb == 0
+            and kb * G <= HEADS_PER_STEP
+            and (kb * Hd) % 128 == 0
+            and (kb * Vd) % 128 == 0
+        ):
+            return kb
+    return KVH
+
+
 @functools.partial(
-    jax.jit, static_argnames=("G", "scale", "bq", "bk", "interpret", "vma")
+    jax.jit,
+    static_argnames=("G", "scale", "bq", "bk", "interpret", "vma", "window"),
 )
 def _flash_pallas(q, k, v, pos, sinks, *, G: int, scale: float, bq: int,
-                  bk: int, interpret: bool, vma: tuple = ()):
+                  bk: int, interpret: bool, vma: tuple = (), window: int = 0):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -121,36 +165,41 @@ def _flash_pallas(q, k, v, pos, sinks, *, G: int, scale: float, bq: int,
     S, KVH = k.shape[1], k.shape[2]
     Vd = v.shape[-1]
     n_s = S // bk
+    KB = _heads_per_step(KVH, G, Hd, Vd)
 
-    # grid (batch, q-tile, kv-tile); kv-tile LAST so the scratch
-    # accumulator carries across its (sequential) iterations
+    # grid (batch, head-group, q-tile, kv-tile); kv-tile LAST so the
+    # scratch accumulator carries across its (sequential) iterations
     kernel = functools.partial(
-        _flash_kernel, bq=bq, bk=bk, scale=scale, n_s=n_s, KVH=KVH, G=G,
-        Hd=Hd, Vd=Vd,
+        _flash_kernel, bq=bq, bk=bk, scale=scale, n_s=n_s, KVH=KB, G=G,
+        Hd=Hd, Vd=Vd, window=window,
     )
     out = pl.pallas_call(
         kernel,
-        grid=(B, T // bq, n_s),
+        grid=(B, KVH // KB, T // bq, n_s),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # pos [1]
             pl.BlockSpec(memory_space=pltpu.SMEM),  # sinks [H]
-            pl.BlockSpec((1, bq, H * Hd), lambda b, tq, s: (b, tq, 0)),
-            pl.BlockSpec((1, bk, KVH * Hd), lambda b, tq, s: (b, s, 0)),
-            pl.BlockSpec((1, bk, KVH * Vd), lambda b, tq, s: (b, s, 0)),
+            pl.BlockSpec((1, bq, KB * G * Hd), lambda b, hb, tq, s: (b, tq, hb)),
+            pl.BlockSpec((1, bk, KB * Hd), lambda b, hb, tq, s: (b, s, hb)),
+            pl.BlockSpec((1, bk, KB * Vd), lambda b, hb, tq, s: (b, s, hb)),
         ],
-        out_specs=pl.BlockSpec((1, bq, H * Vd), lambda b, tq, s: (b, tq, 0)),
+        out_specs=pl.BlockSpec(
+            (1, bq, KB * G * Vd), lambda b, hb, tq, s: (b, tq, hb)
+        ),
         # inside shard_map the output is device-varying over the inputs'
         # mesh axes; check_vma requires the declaration
         out_shape=jax.ShapeDtypeStruct(
             (B, T, H * Vd), q.dtype, vma=frozenset(vma)
         ),
         scratch_shapes=[
-            pltpu.VMEM((H, bq, 1), jnp.float32),
-            pltpu.VMEM((H, bq, 1), jnp.float32),
-            pltpu.VMEM((H, bq, Vd), jnp.float32),
+            pltpu.VMEM((KB * G, bq, 1), jnp.float32),
+            pltpu.VMEM((KB * G, bq, 1), jnp.float32),
+            pltpu.VMEM((KB * G, bq, Vd), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_prefill",
+        # the trace tells window-layer attention from full-layer attention
+        # by this name
+        name=FLASH_WINDOW_NAME if window else FLASH_NAME,
     )(
         pos, sinks, q.reshape(B, T, H * Hd), k.reshape(B, S, KVH * Hd),
         v.reshape(B, S, KVH * Vd),
@@ -158,7 +207,8 @@ def _flash_pallas(q, k, v, pos, sinks, *, G: int, scale: float, bq: int,
     return out.reshape(B, T, H, Vd)
 
 
-def _flash_emulate(q, k, v, pos, sinks, *, scale: float, bk: int):
+def _flash_emulate(q, k, v, pos, sinks, *, scale: float, bk: int,
+                   window: int = 0):
     """Plain-jnp twin of _flash_kernel: the same tile-by-tile online-softmax
     fold (f32, same operation order), for executed coverage where pallas
     cannot run — interpret mode inside shard_map discharges the kernel to a
@@ -184,7 +234,10 @@ def _flash_emulate(q, k, v, pos, sinks, *, scale: float, bk: int):
         scores = jnp.einsum("btkgd,bskd->bkgts", qf, k_t)  # [B,KVH,G,T,bk]
         q_pos = pos + jnp.arange(T)[:, None]
         k_pos = s * bk + jnp.arange(bk)[None, :]
-        scores = jnp.where((k_pos <= q_pos)[None, None, None], scores, NEG_INF)
+        keep = k_pos <= q_pos
+        if window:
+            keep = keep & (k_pos > q_pos - window)
+        scores = jnp.where(keep[None, None, None], scores, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
         p = jnp.exp(scores - m_new)
         corr = jnp.exp(m - m_new)
@@ -264,8 +317,10 @@ def flash_attend_causal(
     pos,
     scale: Optional[float] = None,
     sinks: Optional[jnp.ndarray] = None,
+    window: int = 0,
 ) -> jnp.ndarray:
-    """Causal prefill attention: query row i attends cache slots [0, pos+i].
+    """Causal prefill attention: query row i attends cache slots [0, pos+i],
+    or with `window` > 0 (static) only (pos+i-window, pos+i].
 
     q [B, T, H, Hd]; k [B, S, KVH, Hd], v [B, S, KVH, Vd] (the full cache;
     slots past pos+T are excluded by causality).  Equals
@@ -278,12 +333,26 @@ def flash_attend_causal(
     S, KVH = k.shape[1], k.shape[2]
     scale = Hd**-0.5 if scale is None else scale
 
+    window = int(window or 0)
+
     def dense():
-        from dnet_tpu.ops.attention import attend, causal_mask
+        from dnet_tpu.ops.attention import (
+            attend,
+            causal_mask,
+            sliding_window_mask,
+        )
 
-        return attend(q, k, v, mask=causal_mask(T, S, pos), scale=scale,
-                      sinks=sinks)
+        mask = (
+            sliding_window_mask(T, S, pos, window)
+            if window
+            else causal_mask(T, S, pos)
+        )
+        return attend(q, k, v, mask=mask, scale=scale, sinks=sinks)
 
+    if T == 1 and window:
+        # a window layer's decode over a slot-addressed cache: the masked
+        # dense op (the served path decodes through ops/paged_attention.py)
+        return dense()
     if T == 1:
         # decode: one query row against the (preallocated) cache — the
         # split-K sibling kernel streams only the LIVE tiles
@@ -311,6 +380,7 @@ def flash_attend_causal(
         SELECTIONS.record("flash_prefill", "emulate")
         return _flash_emulate(
             q, k, v, pos, sink_arr, scale=float(scale), bk=_pick_tile(S, 128),
+            window=window,
         )
     SELECTIONS.record("flash_prefill", backend)
     vma = _vma_union(q, k, v, pos, sink_arr) if manual else frozenset()
@@ -318,4 +388,5 @@ def flash_attend_causal(
         q, k, v, jnp.asarray([pos], dtype=jnp.int32), sink_arr, G=H // KVH,
         scale=float(scale), bq=_pick_tile(T, 128), bk=_pick_tile(S, 128),
         interpret=backend == "interpret", vma=tuple(sorted(vma)),
+        window=window,
     )

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dnet_tpu.ops.attention import attend, causal_mask
+from dnet_tpu.ops.attention import attend, causal_mask, sliding_window_mask
 from dnet_tpu.ops.kernel_select import SELECTIONS, device_report, kernel_backend
 
 # Tolerance on |got - want| / max(1, |want|) against the float32 reference.
@@ -113,6 +113,16 @@ def check_serving_kernels(c: Checker, H, KVH, Hd, S, bt, dtype) -> None:
         _reference(q, k, v, causal_mask(T, S, pos)),
     )
 
+    # ... and a window layer's chunk: tiles behind the window are skipped
+    window = S // 4 + 5
+    c.case(
+        f"flash_prefill_window T={T} pos={pos} window={window}",
+        lambda: jax.jit(
+            lambda *a: flash_attend_causal(*a, window=window)
+        )(q, k, v, jnp.int32(pos)),
+        _reference(q, k, v, sliding_window_mask(T, S, pos, window)),
+    )
+
     # flash decode: first slot, a tile edge either side, mid-tile, last slot
     q, k, v = _qkv(jax.random.fold_in(key, 11), 1, 1, S, H, KVH, Hd, dtype)
     if not flash_decode_eligible(q, k):
@@ -157,6 +167,24 @@ def check_serving_kernels(c: Checker, H, KVH, Hd, S, bt, dtype) -> None:
             f"paged_attend slots={slots} nb={nb}",
             lambda tables=tables, pos=pos: jax.jit(
                 lambda *a: paged_attend(*a, impl=impl)
+            )(q, k_pool, v_pool, tables, pos, k_new, v_new),
+            want,
+        )
+        # a window layer's tables: the blocks behind the window given back
+        # (entry j backs logical block base + j), the bound cutting a block
+        window = 2 * bt + 3
+        base = jnp.maximum(pos - window + 1, 0) // bt
+        with jax.default_matmul_precision("highest"):
+            want = _paged_emulate(
+                q.astype(jnp.float32), k_pool.astype(jnp.float32),
+                v_pool.astype(jnp.float32), tables, pos,
+                k_new.astype(jnp.float32), v_new.astype(jnp.float32), Hd**-0.5,
+                window=window, base=base,
+            )
+        c.case(
+            f"paged_attend_window slots={slots} nb={nb} window={window}",
+            lambda tables=tables, pos=pos, base=base: jax.jit(
+                lambda *a: paged_attend(*a, impl=impl, window=window, base=base)
             )(q, k_pool, v_pool, tables, pos, k_new, v_new),
             want,
         )

@@ -162,26 +162,44 @@ def moe_apply(
     k: int,
     tp_axis,
     dense_fn: Callable[[], jnp.ndarray],
+    offset: int = 0,
+    n_routed: int = 0,
 ):
     """One MoE layer through the selected compute path (shared by every MoE
     model; the models supply only their ffn/dense closures and routing).
+
+    `offset` / `n_routed`: the layer holds the contiguous share
+    [offset, offset + held) of `n_routed` routed experts (expert
+    parallelism's share of a layer, here without its exchange): top_idx
+    ranges over all n_routed, and the result is the held experts' part
+    alone.  A layer that holds every expert passes the whole range
+    (offset 0, n_routed 0 = as many as it holds).
 
     Returns (out [N, D], partial): partial=True means the output is a
     per-rank partial sum the caller must psum over tp_axis (the Megatron
     seam both models join their other residual terms at).
     """
     ranks = 1 if tp_axis is None else lax.axis_size(tp_axis)
-    n_experts = n_local * ranks  # tp ranks shard the expert dim
-    impl = resolve_moe_impl(impl, flat.shape[0], n_experts, ranks)
+    n_experts = n_local * ranks  # tp ranks shard the (held) expert dim
+    n_routed = n_routed or n_experts
+    impl = resolve_moe_impl(impl, flat.shape[0], n_routed, ranks)
+    if tp_axis is not None and (offset or n_routed != n_experts):
+        raise NotImplementedError(
+            "an expert share under a tp axis (the share is the expert-"
+            "parallel layout; tp inside it is not wired)"
+        )
     if impl == "a2a" and tp_axis is not None:
         out = moe_a2a_replicated(
             flat, top_idx, top_w, ffn_local, n_experts, capacity_factor, k, tp_axis
         )
         return out, False
     if impl in ("dispatch", "a2a"):
-        capacity = expert_capacity(flat.shape[0], n_experts, k, capacity_factor)
+        capacity = expert_capacity(flat.shape[0], n_routed, k, capacity_factor)
         if tp_axis is None:
-            return moe_dispatch(flat, top_idx, top_w, ffn_local, n_experts, capacity), False
+            # the whole range maps onto itself; a share drops the slots
+            # routed to experts held elsewhere
+            local_idx = localize_topk(top_idx, offset, n_local)
+            return moe_dispatch(flat, local_idx, top_w, ffn_local, n_local, capacity), False
         out = moe_dispatch_sharded(
             flat, top_idx, top_w, ffn_local, n_local, capacity, tp_axis
         )
@@ -256,14 +274,23 @@ def moe_a2a(
     return gather_from_experts(ye, top_idx, pos, top_w)
 
 
-def swiglu_expert_closures(p, flat, scores, top_idx, top_w, tp_axis):
+def held_assignments(top_idx: jnp.ndarray, offset: int, n_held: int) -> jnp.ndarray:
+    """How many of each token's chosen experts lie in [offset, offset +
+    n_held): [N, k] -> [N] int32 (dnet_moe_assignments_total{held="yes"})."""
+    ok = (top_idx >= offset) & (top_idx < offset + n_held)
+    return jnp.sum(ok.astype(jnp.int32), axis=-1)
+
+
+def swiglu_expert_closures(p, flat, scores, top_idx, top_w, tp_axis, offset: int = 0):
     """The (effn, dense) closure pair shared by swiglu-expert MoE families
     (mixtral, deepseek's routed experts): p holds stacked {"e_gate",
     "e_up", "e_down"} expert weights, (in, out)-oriented on a leading
     local-expert axis.  effn computes per-expert buffers [E*, C*, D];
     dense() is the exact all-local-experts einsum masked by the scattered
     routing weights, returning this rank's PARTIAL sum under tp (caller
-    psums at its residual seam).
+    psums at its residual seam).  `scores` is as wide as the ROUTER; the
+    held experts are the range [offset, offset + E_local) of it (the whole
+    range for a layer that holds every expert).
     """
     import jax
 
@@ -286,10 +313,10 @@ def swiglu_expert_closures(p, flat, scores, top_idx, top_w, tp_axis):
         inner = jax.nn.silu(gate) * up
         expert_out = jnp.einsum("nef,efd->ned", inner, dq(p["e_down"]))
         if tp_axis is not None:
-            e_off = lax.axis_index(tp_axis) * E_local
+            e_off = offset + lax.axis_index(tp_axis) * E_local
             w_local = lax.dynamic_slice_in_dim(weights, e_off, E_local, axis=1)
         else:
-            w_local = weights
+            w_local = weights[:, offset:offset + E_local]
         return jnp.einsum("ned,ne->nd", expert_out, w_local.astype(flat.dtype))
 
     return effn, dense, E_local

@@ -15,3 +15,13 @@ def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-5) -> jnp.ndar
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     normed = xf * lax.rsqrt(var + eps)
     return (normed * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def layer_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
+    """Mean-subtracting LayerNorm with a weight and NO bias (Cohere's
+    CohereLayerNorm): y = w * (x - mean(x)) / sqrt(var(x) + eps), float32
+    accumulation, cast back to x.dtype."""
+    xf = x.astype(jnp.float32)
+    xc = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(xc * xc, axis=-1, keepdims=True)
+    return (xc * lax.rsqrt(var + eps) * weight.astype(jnp.float32)).astype(x.dtype)

@@ -60,6 +60,9 @@ NEG_INF = -1e30
 
 #: static implementation choices for the dispatcher (trace-time constant)
 PAGED_IMPLS = ("pallas", "interpret", "emulate")
+#: the custom calls' names in a device trace, by the layer's kind
+PAGED_NAME = "paged_attend"
+PAGED_WINDOW_NAME = "paged_attend_window"
 
 
 def paged_attend_impl() -> str:
@@ -87,23 +90,32 @@ def ragged_refusal(model, kv_quant_bits: int) -> Optional[str]:
     return None
 
 
-def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref,
-                  o_ref, m_ref, l_ref, acc_ref, *, bt: int, scale: float,
-                  nb: int, KVH: int, Hd: int, Vd: int):
-    """One (slot, logical-block) fold of the online softmax, every kv head.
+def _paged_kernel(tbl_ref, pos_ref, base_ref, layer_ref, q_ref, k_ref, v_ref,
+                  kn_ref, vn_ref, o_ref, m_ref, l_ref, acc_ref, *, bt: int,
+                  scale: float, nb: int, KVH: int, Hd: int, Vd: int,
+                  window: int):
+    """One (slot, table-entry) fold of the online softmax, every kv head.
 
     tbl_ref SMEM [slots, nb] page table, pos_ref SMEM [slots] live pool
-    rows per slot (the new token's row arrives via kn/vn, folded at emit).
+    rows per slot (the new token's row arrives via kn/vn, folded at emit),
+    base_ref SMEM [slots] the logical block index of each table's FIRST
+    entry (0 for a table that keeps everything; a window layer's table has
+    given back the blocks behind the window, kv/paged.py), layer_ref SMEM
+    [1] the pool's layer (index maps only).
     Mosaic tiles a block's last two dims, so the pool block arrives with
-    heads merged into the lane dim — k_ref [1, bt, KVH*Hd], v_ref
-    [1, bt, KVH*Vd] — and a kv head is a static lane slice; q_ref
+    heads merged into the lane dim — k_ref [1, 1, bt, KVH*Hd], v_ref
+    [1, 1, bt, KVH*Vd] — and a kv head is a static lane slice; q_ref
     [1, KVH, G, Hd] holds each head's whole GQA group, so one block read
-    amortizes over all G query heads sharing it."""
+    amortizes over all G query heads sharing it.  `window` > 0 (static)
+    adds the lower bound: only keys at absolute positions > live - window
+    score, and a block wholly behind that bound is neither folded nor
+    copied (the index map clamps it like a block past the live length)."""
     import jax.experimental.pallas as pl
 
     b = pl.program_id(0)
     i = pl.program_id(1)
     live = pos_ref[b]
+    first = (base_ref[b] + i) * bt  # absolute position of this block's row 0
 
     @pl.when(i == 0)
     def _init():
@@ -111,16 +123,26 @@ def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(i * bt < live)
+    in_range = first < live
+    if window:
+        # the new token sits at position `live` and attends keys at
+        # positions > live - window: a block whose last row is at or
+        # behind that bound holds nothing to score
+        in_range = in_range & (first + bt - 1 > live - window)
+
+    @pl.when(in_range)
     def _fold():
         # mid-block ragged edge: the last live block is only partially
         # full — rows at absolute positions >= live are stale pool content
-        # (or a clamped repeat of an earlier block) and must not score
-        slot = i * bt + lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+        # (or a clamped repeat of an earlier block) and must not score;
+        # the block the window's edge cuts is masked by absolute position
+        slot = first + lax.broadcasted_iota(jnp.int32, (1, bt), 1)
         valid = slot < live
+        if window:
+            valid = valid & (slot > live - window)
         for kh in range(KVH):
             q = q_ref[0, kh].astype(jnp.float32) * scale  # [G, Hd]
-            k = k_ref[0, :, kh * Hd:(kh + 1) * Hd].astype(jnp.float32)
+            k = k_ref[0, 0, :, kh * Hd:(kh + 1) * Hd].astype(jnp.float32)
             scores = lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -132,7 +154,7 @@ def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref,
             corr = jnp.exp(m_prev - m_new)
             l_ref[kh] = l_ref[kh] * corr + jnp.sum(p, axis=1, keepdims=True)
             pv = lax.dot_general(
-                p, v_ref[0, :, kh * Vd:(kh + 1) * Vd].astype(jnp.float32),
+                p, v_ref[0, 0, :, kh * Vd:(kh + 1) * Vd].astype(jnp.float32),
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )  # [G, Vd]
@@ -158,38 +180,51 @@ def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("G", "scale", "bt", "interpret"),
+    static_argnames=("G", "scale", "bt", "interpret", "window"),
 )
-def _paged_pallas(q, k_pool, v_pool, tables, pos, k_new, v_new, *, G: int,
-                  scale: float, bt: int, interpret: bool):
+def _paged_pallas(q, k_pool, v_pool, tables, pos, k_new, v_new, base=None,
+                  layer=None, *, G: int, scale: float, bt: int,
+                  interpret: bool, window: int = 0):
+    """k_pool/v_pool are ONE layer's [N, bt, KVH, Hd/Vd] slices, or with
+    `layer` ([1] int32) a kind's whole stack [L, N, bt, KVH*Hd/Vd], heads
+    already merged into the lane dim, which the index map takes the layer
+    of.  `base` [B] int32: see _paged_kernel (None = 0)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, T, H, Hd = q.shape
     KVH = H // G
-    N = k_pool.shape[0]
-    Vd = v_pool.shape[-1]
+    if layer is None:
+        N = k_pool.shape[0]
+        k_pool = k_pool.reshape(1, N, bt, -1)
+        v_pool = v_pool.reshape(1, N, bt, -1)
+        layer = jnp.zeros((1,), jnp.int32)
+    if base is None:
+        base = jnp.zeros((B,), jnp.int32)
+    Vd = v_pool.shape[-1] // KVH
     nb = tables.shape[1]
 
-    def live_block(b, pos):
-        """Last logical block holding any live row for slot b; dead grid
-        steps clamp here so the pipeline re-fetches (elides) one block
-        instead of streaming unallocated table entries."""
-        return jnp.clip((pos[b] - 1) // bt, 0, nb - 1)
+    def kv_map(b, i, tbl, pos, base, layer):
+        """Table entries past a slot's live length clamp to its last live
+        block, and entries wholly behind the window to the first block the
+        window reaches: the pipeline re-fetches (elides) one block instead
+        of streaming dead ones."""
+        hi = jnp.clip((pos[b] - 1) // bt - base[b], 0, nb - 1)
+        if window:
+            lo = jnp.clip((pos[b] - window + 1) // bt - base[b], 0, hi)
+            i = jnp.maximum(i, lo)
+        return (layer[0], tbl[b, jnp.minimum(i, hi)], 0, 0)
 
-    def kv_map(b, i, tbl, pos):
-        return (tbl[b, jnp.minimum(i, live_block(b, pos))], 0, 0)
-
-    def whole4(b, i, tbl, pos):
+    def whole4(b, i, tbl, pos, base, layer):
         return (b, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=4,
         grid=(B, nb),
         in_specs=[
             pl.BlockSpec((1, KVH, G, Hd), whole4),
-            pl.BlockSpec((1, bt, KVH * Hd), kv_map),
-            pl.BlockSpec((1, bt, KVH * Vd), kv_map),
+            pl.BlockSpec((1, 1, bt, KVH * Hd), kv_map),
+            pl.BlockSpec((1, 1, bt, KVH * Vd), kv_map),
             pl.BlockSpec((1, KVH, 1, Hd), whole4),
             pl.BlockSpec((1, KVH, 1, Vd), whole4),
         ],
@@ -201,52 +236,59 @@ def _paged_pallas(q, k_pool, v_pool, tables, pos, k_new, v_new, *, G: int,
         ],
     )
     kernel = functools.partial(
-        _paged_kernel, bt=bt, scale=scale, nb=nb, KVH=KVH, Hd=Hd, Vd=Vd
+        _paged_kernel, bt=bt, scale=scale, nb=nb, KVH=KVH, Hd=Hd, Vd=Vd,
+        window=window,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, Vd), q.dtype),
         interpret=interpret,
-        name="paged_attend",
+        # the trace tells window-layer attention from full-layer attention
+        # by this name
+        name=PAGED_WINDOW_NAME if window else PAGED_NAME,
     )(
-        tables, pos, q.reshape(B, KVH, G, Hd),
-        k_pool.reshape(N, bt, KVH * Hd), v_pool.reshape(N, bt, KVH * Vd),
+        tables, pos, base, layer, q.reshape(B, KVH, G, Hd), k_pool, v_pool,
         k_new.reshape(B, KVH, 1, Hd), v_new.reshape(B, KVH, 1, Vd),
     )
     return out.reshape(B, T, H, Vd)
 
 
 def _paged_emulate(q, k_pool, v_pool, tables, pos, k_new, v_new,
-                   scale: float):
+                   scale: float, window: int = 0, base=None):
     """Plain-jnp twin: gather each slot's blocks to a contiguous view
     (width already bounded by the caller's pow2 table bucket), write the
     new row at `pos` exactly like the dense path's write_kv, and attend
     with the causal-at-pos mask through the SAME dense `attend` the
     gather path bottoms out in — one fused program, no separate gather
     dispatch, no scatter.  CPU backends serve through this; interpret mode
-    and TPU run the kernel."""
+    and TPU run the kernel.  View row j of slot b sits at absolute
+    position base[b] * bt + j (`base` None = 0)."""
     from dnet_tpu.ops.attention import attend
 
     B, T, H, Hd = q.shape
     nb = tables.shape[1]
     bt = k_pool.shape[1]
-    KVH = k_pool.shape[2]
+    KVH = k_new.shape[1]
     S = nb * bt
 
     def view(pool):
-        g = pool[tables]  # [B, nb, bt, KVH, D]
-        return g.reshape(B, S, KVH, pool.shape[-1])
+        g = pool[tables]  # [B, nb, bt, ...]
+        return g.reshape(B, S, KVH, -1)
 
     kc = view(k_pool)
     vc = view(v_pool)
+    rel = pos if base is None else pos - base * bt
     write = jax.vmap(
         lambda c, r, p: jax.lax.dynamic_update_slice(c, r[None], (p, 0, 0))
     )
-    kc = write(kc, k_new.astype(kc.dtype), pos)
-    vc = write(vc, v_new.astype(vc.dtype), pos)
-    mask = (jnp.arange(S)[None, :] <= pos[:, None])[:, None, :]  # [B, 1, S]
-    return attend(q, kc, vc, mask=mask, scale=scale)
+    kc = write(kc, k_new.astype(kc.dtype), rel)
+    vc = write(vc, v_new.astype(vc.dtype), rel)
+    slot = jnp.arange(S)[None, :]
+    mask = slot <= rel[:, None]
+    if window:
+        mask = mask & (slot > rel[:, None] - window)
+    return attend(q, kc, vc, mask=mask[:, None, :], scale=scale)
 
 
 def paged_attend(
@@ -259,6 +301,9 @@ def paged_attend(
     v_new: jnp.ndarray,
     scale: Optional[float] = None,
     impl: str = "emulate",
+    window: int = 0,
+    base: Optional[jnp.ndarray] = None,
+    layer=None,
 ) -> jnp.ndarray:
     """Single-token decode attention against the block pool, in place.
 
@@ -269,21 +314,40 @@ def paged_attend(
     token's rows (position == pos, attended in-launch, appended to the
     pool by the caller afterwards).  Equals dense write-then-attend with
     the causal mask at pos.  `impl` is a trace-time constant — callers
-    resolve it once via paged_attend_impl()."""
+    resolve it once via paged_attend_impl().
+
+    A window layer (`window` > 0, static) attends keys at positions
+    > pos - window only, and its table may have given back the blocks
+    behind the window: `base` [B] int32 is the logical block index of each
+    table's first entry (None = 0).  With `layer` (a traced index) the
+    pools are a kind's whole stack, [L, N_blocks, bt, KVH*Hd/Vd] with the
+    heads already merged into the lane dim (kv/store.py KindStore): the
+    kernel indexes the layer itself, so no pool slice is copied."""
     B, T, H, Hd = q.shape
-    KVH = k_pool.shape[2]
+    KVH = k_new.shape[1]
     G = H // KVH
-    bt = k_pool.shape[1]
     scale = Hd**-0.5 if scale is None else float(scale)
     tables = tables.astype(jnp.int32)
     pos = pos.astype(jnp.int32)
+    base = (
+        jnp.zeros((B,), jnp.int32) if base is None else base.astype(jnp.int32)
+    )
     if impl not in PAGED_IMPLS:
         raise ValueError(f"paged_attend impl {impl!r} not in {PAGED_IMPLS}")
     SELECTIONS.record("paged_attend", impl)
+    if layer is None:
+        bt = k_pool.shape[1]
+    else:
+        bt = k_pool.shape[2]
+        layer = jnp.asarray(layer, jnp.int32).reshape(1)
+        if impl == "emulate":
+            k_pool = lax.dynamic_index_in_dim(k_pool, layer[0], 0, keepdims=False)
+            v_pool = lax.dynamic_index_in_dim(v_pool, layer[0], 0, keepdims=False)
     if impl == "emulate":
         return _paged_emulate(q, k_pool, v_pool, tables, pos, k_new, v_new,
-                              scale)
+                              scale, window=int(window), base=base)
     return _paged_pallas(
-        q, k_pool, v_pool, tables, pos, k_new, v_new,
+        q, k_pool, v_pool, tables, pos, k_new, v_new, base, layer,
         G=G, scale=scale, bt=bt, interpret=(impl == "interpret"),
+        window=int(window),
     )

@@ -325,9 +325,10 @@ class SchedulerAdapter(ApiAdapterBase):
                 state: len(self.queue.by_state(state))
                 for state in QUEUE_STATES
             },
-            kv_blocks_used=int(metric("dnet_kv_blocks_used").value),
-            kv_blocks_free=int(metric("dnet_kv_blocks_free").value),
-            kv_pool_blocks=int(metric("dnet_kv_pool_blocks").value),
+            # every kind of the pool together (obs/phases.py KV_KINDS)
+            kv_blocks_used=int(metric("dnet_kv_blocks_used").total()),
+            kv_blocks_free=int(metric("dnet_kv_blocks_free").total()),
+            kv_pool_blocks=int(metric("dnet_kv_pool_blocks").total()),
         )
 
     def _stamp_chunks(self, plan: TickPlan, t0: float) -> None:

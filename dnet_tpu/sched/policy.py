@@ -30,6 +30,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 from dnet_tpu.core.types import DecodingParams
+from dnet_tpu.obs.phases import KV_KIND_WINDOW
 from dnet_tpu.sched.kinds import STATE_PREFILLING
 from dnet_tpu.sched.queue import SchedQueue, SchedRequest
 
@@ -89,7 +90,14 @@ class SchedulerPolicy:
             return True
         cfg = engine._kv_cfg
         need = cfg.blocks_for(min(len(req.ids) + 1, engine.max_seq))
-        return pool.can_cover(need)
+        if not pool.can_cover(need):
+            return False
+        # by KIND of layer: full layers keep the whole prompt (above), a
+        # window layer's table at most the window, one step and a block
+        wpool = getattr(engine, "kv_pools", {}).get(KV_KIND_WINDOW)
+        return wpool is None or wpool.can_cover(
+            min(need, wpool.total // engine.slots)
+        )
 
     def has_work(self, queue: SchedQueue, engine) -> bool:
         """Would the next plan be non-empty?  (The tick loop parks when

@@ -1,0 +1,136 @@
+"""The paged pool's books by KIND of layer (kv/paged.py, kv/store.py):
+full layers keep every block, window layers give back the blocks behind
+the window, and each kind's accounting is exact on its own."""
+
+import pytest
+
+from dnet_tpu.kv import (
+    BlockPool,
+    KVPoolExhausted,
+    PagedKVConfig,
+    PageTable,
+    window_blocks,
+    window_first_block,
+)
+from dnet_tpu.obs import metric
+from dnet_tpu.obs.phases import KV_KIND_FULL, KV_KIND_WINDOW
+
+
+def gauges(kind):
+    return tuple(
+        int(metric(f).labels(kind=kind).value)
+        for f in ("dnet_kv_blocks_used", "dnet_kv_blocks_free", "dnet_kv_pool_blocks")
+    )
+
+
+def test_window_first_block_is_the_first_a_next_token_reaches():
+    # the token at position n attends keys > n - W
+    assert window_first_block(10, 24, 8) == 0  # everything is inside
+    assert window_first_block(24, 24, 8) == 0  # key 1 is the first: block 0
+    assert window_first_block(31, 24, 8) == 1  # first key 8: block 1
+    assert window_first_block(4500, 4096, 128) == 3
+    assert window_first_block(16384, 4096, 128) == (16384 - 4095) // 128
+
+
+@pytest.mark.parametrize("window,bt,step,want", [(4096, 128, 256, 35), (24, 8, 16, 6), (24, 8, 256, 36)])
+def test_window_blocks_covers_window_step_and_edges(window, bt, step, want):
+    assert window_blocks(window, bt, step) == want
+    # the most a table holds before a dispatch of `step` tokens at any pos
+    for pos in range(0, 4 * window, 7):
+        held = -(-(pos + step) // bt) - window_first_block(pos, window, bt)
+        assert held <= want
+
+
+def test_each_kind_keeps_exact_books_and_window_blocks_come_back():
+    full = BlockPool(PagedKVConfig(block_tokens=8, pool_blocks=32))
+    win = BlockPool(PagedKVConfig(block_tokens=8, pool_blocks=12), kind=KV_KIND_WINDOW)
+    W, bt = 24, 8
+    assert gauges(KV_KIND_FULL) == (0, 32, 32) and gauges(KV_KIND_WINDOW) == (0, 12, 12)
+    released0 = metric("dnet_kv_window_blocks_released_total").value
+
+    n = 61  # a prompt longer than the window
+    first = window_first_block(n, W, bt)
+    tf = PageTable(blocks=full.alloc(full.cfg.blocks_for(n)))
+    tw = PageTable(blocks=win.alloc(win.cfg.blocks_for(n) - first), base=first)
+    assert len(tf.blocks) == 8 and (tw.base, len(tw.blocks)) == (4, 4)
+    for pos in range(n, n + 40):  # decode: release behind, then grow
+        win.release_behind(tw, window_first_block(pos, W, bt))
+        win.ensure(tw, pos + 1)
+        full.ensure(tf, pos + 1)
+        assert tw.base * bt <= pos - W + 1 < (tw.base + 1) * bt  # holds the window's first key
+        assert (tw.base + len(tw.blocks)) * bt > pos  # and the new token's row
+        assert len(tw.blocks) <= window_blocks(W, bt, 1)
+        for pool in (full, win):
+            pool.check_conservation()
+            assert pool.used + pool.free == pool.total
+        assert gauges(KV_KIND_WINDOW) == (win.used, win.free, 12)
+        assert gauges(KV_KIND_FULL) == (full.used, full.free, 32)
+    assert len(tf.blocks) == 13 and win.used == len(tw.blocks) <= 5
+    assert metric("dnet_kv_window_blocks_released_total").value - released0 == tw.base - first > 0
+    win.release_table(tw)
+    full.release_table(tf)
+    assert (tw.base, win.used, full.used) == (0, 0, 0)
+
+
+def test_release_behind_never_frees_past_the_table():
+    pool = BlockPool(PagedKVConfig(block_tokens=8, pool_blocks=4), kind=KV_KIND_WINDOW)
+    t = PageTable(blocks=pool.alloc(2), base=3)
+    assert pool.release_behind(t, 2) == 0 and t.base == 3  # nothing is behind
+    assert pool.release_behind(t, 9) == 2 and (t.base, t.blocks) == (5, [])
+    pool.check_conservation()
+    assert pool.free == 4
+    pool.ensure(t, 6 * 8)  # grows from its base, not from zero
+    assert len(t.blocks) == 1 and pool.used == 1
+
+
+def test_admission_counts_a_prompts_need_by_kind():
+    """can_cover by kind: the full kind needs the whole prompt, the window
+    kind at most what one slot's table ever holds."""
+    from types import SimpleNamespace
+
+    from dnet_tpu.sched.policy import SchedulerPolicy
+
+    bt, W, slots = 8, 24, 2
+    per_slot = window_blocks(W, bt, 16)
+    full = BlockPool(PagedKVConfig(bt, 20))
+    win = BlockPool(PagedKVConfig(bt, slots * per_slot), kind=KV_KIND_WINDOW)
+    engine = SimpleNamespace(kv_pool=full, kv_pools={KV_KIND_FULL: full, KV_KIND_WINDOW: win},
+                             _kv_cfg=full.cfg, max_seq=256, slots=slots)
+    req = lambda n: SimpleNamespace(ids=list(range(n)))  # noqa: E731
+    assert SchedulerPolicy.admissible(req(100), engine)  # 13 full blocks, 6 window
+    assert not SchedulerPolicy.admissible(req(200), engine)  # 26 full blocks > 20
+    held = win.alloc(win.total - per_slot + 1)  # less than one slot's share left
+    assert not SchedulerPolicy.admissible(req(100), engine)
+    assert SchedulerPolicy.admissible(req(30), engine)  # a short prompt needs 4
+    win.free_blocks(held)
+    with pytest.raises(KVPoolExhausted):
+        win.alloc(win.total + 1)
+    del engine.kv_pools[KV_KIND_WINDOW]  # a model of one kind: the full pool alone
+    assert SchedulerPolicy.admissible(req(100), engine)
+
+
+def test_a_staged_rows_commit_has_one_window_width_whatever_the_prompt():
+    """The commit program is compiled once a power of two of the FULL kind's
+    blocks: the window kind's list always has the one width a window table
+    can reach, so two prompts of nearly one length (32 or 33 window blocks)
+    never ask for a program the warm-up did not make."""
+    from types import SimpleNamespace
+
+    from dnet_tpu.kv import KindStore
+
+    bt = 8
+    model = SimpleNamespace(
+        paged_kinds=(KV_KIND_WINDOW,) * 3 + (KV_KIND_FULL,), window=24,
+        config=SimpleNamespace(num_key_value_heads=2, head_dim=4),
+    )
+    cfgs = {KV_KIND_FULL: PagedKVConfig(bt, 64), KV_KIND_WINDOW: PagedKVConfig(bt, 12)}
+    store = KindStore(model, cfgs, "float32", window_width=6)
+    assert store.layers == {KV_KIND_WINDOW: (0, 1, 2), KV_KIND_FULL: (3,)}
+    seen = []
+    store._commit = lambda kv, row, idx, phys: seen.append(
+        {k: (int(idx[k].shape[0]), int(phys[k].shape[0])) for k in idx}) or kv
+    for n_full, n_win in ((5, 4), (5, 5), (7, 3), (9, 4)):
+        store.commit_staged({}, {KV_KIND_FULL: (list(range(n_full)), list(range(n_full))),
+                              KV_KIND_WINDOW: (list(range(n_win)), list(range(n_win)))})
+    assert [s[KV_KIND_WINDOW] for s in seen] == [(6, 6)] * 4
+    assert [s[KV_KIND_FULL] for s in seen] == [(8, 8), (8, 8), (8, 8), (16, 16)]

@@ -76,11 +76,11 @@ def test_gauges_track_pool_state():
     reset_obs()
     pool = make_pool(bt=4, blocks=6)
     a = pool.alloc(2)
-    assert metric("dnet_kv_blocks_used").value == 2
-    assert metric("dnet_kv_blocks_free").value == 4
-    assert metric("dnet_kv_pool_blocks").value == 6
+    assert metric("dnet_kv_blocks_used").labels(kind="full").value == 2
+    assert metric("dnet_kv_blocks_free").labels(kind="full").value == 4
+    assert metric("dnet_kv_pool_blocks").labels(kind="full").value == 6
     pool.free_blocks(a)
-    assert metric("dnet_kv_blocks_used").value == 0
+    assert metric("dnet_kv_blocks_used").labels(kind="full").value == 0
     with pytest.raises(KVPoolExhausted):
         pool.require(7)
     assert metric("dnet_kv_admission_rejected_total").value == 1
