@@ -81,11 +81,13 @@ OWNERSHIP_DOMAINS = (
     ("dnet_tpu/core/prefix_cache.py", "PrefixIndex", "_entries", "lock", "_lock"),
     ("dnet_tpu/obs/metrics.py", "MetricsRegistry", "_metrics", "lock", "_lock"),
     ("dnet_tpu/transport/stream_manager.py", "StreamManager", "_streams", "loop", ""),
-    # iteration-level scheduler (dnet_tpu/sched/): the queue and the
-    # pre-arrival deadline stash are loop-owned — the compute thread only
-    # ever sees plain snapshots inside a TickPlan
+    # iteration-level scheduler (dnet_tpu/sched/): the queue, the
+    # pre-arrival deadline stash and the set of drivers a tick still waits
+    # to hear from are loop-owned — the compute thread only ever sees
+    # plain snapshots inside a TickPlan
     ("dnet_tpu/sched/queue.py", "SchedQueue", "_reqs", "loop", ""),
     ("dnet_tpu/sched/engine.py", "SchedulerAdapter", "_deadlines", "loop", ""),
+    ("dnet_tpu/sched/engine.py", "SchedulerAdapter", "_answering", "loop", ""),
     # overlapped wire pipeline (transport/wire_pipeline.py): the encode
     # ring's in-flight count is touched from the compute thread (acquire)
     # AND the tx executor (release) — guarded-by lock; the tx stage's

@@ -959,14 +959,24 @@ class BatchedEngine:
         whose slot vanished (client disconnect race) or hit max_seq fails
         ALONE — it must never poison the rest of the batch.
 
-        `budgets` (nonce -> remaining tokens the driver will accept) widen
-        the dispatch into a fused R-step chunk: active lanes chain their
-        sampled tokens on device and the extra results buffer engine-side,
-        resolving later decode_batch calls instantly — the host pays one
-        dispatch + one packed read per R tokens per lane (the same contract
-        as LocalEngine.decode_chunk / the pipelined engine's rotations).
-        The active set is FIXED across a chunk, so the stream is
-        bit-identical to R serial steps with the same request set.
+        `budgets` (nonce -> remaining tokens the driver will accept) may
+        widen the dispatch into a fused R-step chunk: active lanes chain
+        their sampled tokens on device and the extra results buffer
+        engine-side, resolving later decode_batch calls instantly — the
+        host pays one dispatch + one packed read per R tokens per lane (the
+        same contract as LocalEngine.decode_chunk / the pipelined engine's
+        rotations).  The active set is FIXED across a chunk, so the stream
+        is bit-identical to R serial steps with the same request set.
+
+        A dispatch is fused ONLY when it carries every lane this call asked
+        for.  When some lane was answered from its buffer (or verified a
+        drafted block), the lanes are out of phase: a fused dispatch for
+        the rest would run R steps of the whole batch program for them
+        alone while every other lane's next token waits behind it.  Those
+        lanes take ONE step, all in one dispatch; the buffered lanes drain,
+        and once all are empty together they fuse again.  (The scheduler
+        adds the other half of the condition: it hands out budgets only
+        while no prompt waits, sched/policy.py.)
 
         Host spans (obs/phases.py): prepare, then — only when some lane's
         buffer is empty — [kv_gather] launch [kv_scatter] readback unpack.
@@ -980,6 +990,7 @@ class BatchedEngine:
             return {}, errors
         t_parent = time.perf_counter()
         plan = None
+        asked = len(requests)
         with span(SPAN_DECODE_PREPARE):
             # buffered tokens from an earlier fused chunk resolve first
             out_buf, requests = self._pop_buffered(requests)
@@ -990,7 +1001,10 @@ class BatchedEngine:
             # touch disjoint lanes
             spec_reqs = self._pick_spec_lanes(requests, budgets)
             if requests and not spec_reqs:
-                plan = self._plan_dispatch(requests, budgets, errors)
+                # out of phase (some lane had a buffered row): single step
+                plan = self._plan_dispatch(
+                    requests, budgets if len(requests) == asked else None, errors
+                )
         if spec_reqs:
             spec_out = self._decode_spec_lanes(spec_reqs)
             _DECODE_TOKENS.labels(source="spec").inc(len(spec_out))
@@ -998,7 +1012,7 @@ class BatchedEngine:
             requests = {n: r for n, r in requests.items() if n not in spec_reqs}
             if requests:
                 with span(SPAN_DECODE_PREPARE):
-                    plan = self._plan_dispatch(requests, budgets, errors)
+                    plan = self._plan_dispatch(requests, None, errors)
         if plan is None:
             return out_buf, errors
         order, R, dev, table_ids = plan

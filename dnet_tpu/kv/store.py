@@ -344,9 +344,19 @@ class KindStore:
             sel = jnp.asarray(idx, jnp.int32)
 
             def one(p, r, kind=kind, sel=sel):
-                r = r[sel]
-                r = r.reshape(r.shape[0], r.shape[1], -1)
-                return p.at[:, phys[kind], off].set(r.astype(p.dtype), mode="drop")
+                # ONE plain row scatter into the [Lk*N*bt, W] view (a
+                # bitcast).  Indexed as p[:, phys, off] the update spans
+                # the layer axis and XLA relayouts the WHOLE pool around
+                # the scatter, in and out (the window kind: 2 x 440 MB each
+                # way, every decode step)
+                Lk, N, bt, W = p.shape
+                at = phys[kind]
+                row = (jnp.arange(Lk, dtype=jnp.int32)[:, None] * N + at[None]) * bt + off[None]
+                row = jnp.where(at[None] < N, row, Lk * N * bt)  # past the end: dropped
+                flat = p.reshape(Lk * N * bt, W).at[row.reshape(-1)].set(
+                    r[sel].reshape(-1, W).astype(p.dtype), mode="drop"
+                )
+                return flat.reshape(p.shape)
 
             out[kind] = jax.tree.map(one, pool[kind], rows)
         return out

@@ -67,6 +67,14 @@ def sched_enabled() -> bool:
     return env_flag("DNET_SCHED")
 
 
+#: how long a plan waits for the drivers the last tick handed a token to
+#: ask for the next one: two or three turns of the event loop in practice
+#: (future -> driver task -> send_tokens), so the bound only matters for a
+#: driver that is held up (a client that does not read); that lane then
+#: joins the tick after, and is not waited for again until its next token
+DRIVER_TURN_S = 0.002
+
+
 class SchedulerAdapter(ApiAdapterBase):
     """Iteration-level continuous batching over a batched engine.
 
@@ -105,6 +113,11 @@ class SchedulerAdapter(ApiAdapterBase):
         # declared in analysis/runtime/domains.py
         self._deadlines: Dict[str, float] = dsan.guard_dict(
             {}, dsan.loop_domain(), "SchedulerAdapter._deadlines"
+        )
+        # nonces handed a token by the tick just applied whose drivers have
+        # not answered yet (next step or reset); loop-owned
+        self._answering: set = dsan.guard_set(
+            set(), dsan.loop_domain(), "SchedulerAdapter._answering"
         )
 
     # ---- lifecycle ----------------------------------------------------
@@ -163,6 +176,7 @@ class SchedulerAdapter(ApiAdapterBase):
     async def reset_cache(self, nonce: str) -> None:
         self.queue.remove(nonce)
         self._deadlines.pop(nonce, None)
+        self._answering.discard(nonce)
         if self._executor is not None:
             loop = asyncio.get_running_loop()
             await loop.run_in_executor(
@@ -227,6 +241,7 @@ class SchedulerAdapter(ApiAdapterBase):
             req.ids.append(token_ids[-1])
             req.pending_step = step
             req.pending_budget = budget
+            self._answering.discard(nonce)
         self._wake()
 
     async def await_token(
@@ -242,12 +257,31 @@ class SchedulerAdapter(ApiAdapterBase):
         if self._kick is not None:
             self._kick.set()
 
+    async def _drivers_turn(self) -> None:
+        """Before a plan: the lanes the last tick handed a token get their
+        turn to ask for the next one, so the tick's ONE decode step carries
+        every lane.  A token reaches its driver a turn or two of the loop
+        after `_apply` resolved it; a plan made sooner (a prompt is waiting,
+        so `has_work` says go at once) would leave those lanes out, and a
+        lane left out of every other tick decodes at half the rate while
+        the step costs the same.  Bounded: a driver that is held up misses
+        this tick, no more."""
+        deadline = time.perf_counter() + DRIVER_TURN_S
+        while self._answering and (left := deadline - time.perf_counter()) > 0:
+            self._kick.clear()  # every answer sets it (send_tokens, reset_cache)
+            try:
+                await asyncio.wait_for(self._kick.wait(), left)
+            except asyncio.TimeoutError:
+                break
+        self._answering.clear()
+
     async def _tick_loop(self) -> None:
         loop = asyncio.get_running_loop()
         while True:
             await self._kick.wait()
             self._kick.clear()
             await asyncio.sleep(0)  # coalesce: let concurrent senders enqueue
+            await self._drivers_turn()
             plan = None
             # the WHOLE tick body is guarded: an exception escaping this
             # loop would kill the task silently and wedge every current
@@ -403,6 +437,7 @@ class SchedulerAdapter(ApiAdapterBase):
         if req is not None and req.pending_step == step:
             req.pending_step = None
             req.pending_budget = None
+            self._answering.add(nonce)
 
     def _apply(self, plan: TickPlan, result: TickResult) -> None:
         for nonce in result.preempted:

@@ -6,9 +6,15 @@ work into one :class:`TickPlan`:
 1. **Decode first.**  Every DECODING request with a pending step gets one
    token (decode is what the per-token SLO measures; a long prompt must
    never starve running streams for more than one tick).  Fused-chunk
-   budgets ride along so the engine may still batch R device steps per
-   dispatch — the active set is fixed per tick, so streams stay
-   bit-identical to serial stepping.
+   budgets ride along ONLY while no prompt waits (nothing PREFILLING,
+   nothing WAITING): with a prompt waiting every tick is one decode step
+   that carries every lane, then the prompt's chunks — a fused R-step
+   dispatch would hold the prompt, and every lane it does not carry,
+   behind R steps of the whole batch program.  With nothing waiting the
+   engine may batch R device steps per dispatch (a host round trip a
+   token and nothing to hide it behind), and does so only for lanes in
+   phase (``BatchedEngine.decode_batch``).  The active set is fixed per
+   dispatch, so streams are bit-identical to serial stepping for any R.
 2. **Chunked prefill fills the remainder.**  PREFILLING requests continue
    (most urgent first) in ``DNET_SCHED_PREFILL_CHUNK``-bounded segments.
 3. **Admission.**  WAITING requests are admitted most-urgent-first while
@@ -59,6 +65,8 @@ class TickPlan:
     prefills: List[PrefillChunk] = field(default_factory=list)
     #: nonce -> (last token, decoding) for this tick's batched decode
     decode: Dict[str, Tuple[int, DecodingParams]] = field(default_factory=dict)
+    #: nonce -> remaining tokens the driver accepts; EMPTY while a prompt
+    #: waits (then every decode dispatch is a single step)
     budgets: Dict[str, Optional[int]] = field(default_factory=dict)
     steps: Dict[str, int] = field(default_factory=dict)
     #: replay ids for EVERY decoding request (preemption stash source)
@@ -141,7 +149,6 @@ class SchedulerPolicy:
             if r.pending_step is None:
                 continue
             out.decode[r.nonce] = (r.ids[-1], r.decoding)
-            out.budgets[r.nonce] = r.pending_budget
             out.steps[r.nonce] = r.pending_step
         budget -= len(out.decode)
         out.victims = queue.victims()
@@ -198,5 +205,10 @@ class SchedulerPolicy:
             out.admitted.append(r.nonce)
             slots_free -= 1
             emit(r, first=True)
+        if not queue.prefilling() and not queue.waiting():
+            # no prompt waits: the engine may fuse lanes that are in phase
+            out.budgets = {
+                r.nonce: r.pending_budget for r in decoding if r.nonce in out.decode
+            }
         queue.sync_gauges()
         return out

@@ -6,7 +6,10 @@ tick is one mixed launch sequence:
 
 - the batched decode dispatch first (every running stream advances before
   any prompt token burns — decode latency is what the per-token SLO
-  measures), with block-starvation preemption resolved BEFORE the
+  measures): ONE step that carries every decoding lane whenever a prompt
+  waits (the plan then holds no budgets), a fused R-step chunk only for
+  lanes in phase with nothing queued (core/batch.py: decode_batch); with
+  block-starvation preemption resolved BEFORE the
   dispatch so a pool shortfall evicts the lowest-priority sequence
   instead of erroring an arbitrary lane; under DNET_KV_RAGGED=1 the
   dispatch attends the block pool in place through the page tables
@@ -260,9 +263,8 @@ def _execute(engine, plan: TickPlan, on_decode, res: TickResult) -> None:
     if reqs and getattr(engine, "kv_pool", None) is not None:
         _preempt_for_decode(engine, plan, reqs, res)
     if reqs:
-        budgets = {n: plan.budgets.get(n) for n in reqs}
         with span(SPAN_TICK_DECODE):
-            out, errs = engine.decode_batch(reqs, budgets=budgets)
+            out, errs = engine.decode_batch(reqs, budgets=plan.budgets or None)
         res.t_decode_done = time.perf_counter()
         res.decode_results.update(out)
         res.errors.update(errs)

@@ -1,0 +1,276 @@
+"""Which lanes a tick's decode dispatch carries, and how wide it is.
+
+One rule in two halves (core/batch.py: decode_batch; sched/policy.py: plan):
+a dispatch is fused (R > 1) only if it carries every lane of the tick's
+decode set AND no prompt waits; otherwise every lane whose buffer is empty
+takes one step, all in one dispatch.  A lone stream with nothing queued
+still fuses (tests/subsystems/test_tick_anatomy.py holds the served case).
+Streams are bit-identical to serial stepping whatever R turns out to be.
+"""
+
+import asyncio
+
+import pytest
+
+from dnet_tpu.config import reset_settings_cache
+from dnet_tpu.core.types import DecodingParams
+from dnet_tpu.obs import metric, reset_obs
+from dnet_tpu.sched.flight import get_tick_recorder
+from dnet_tpu.sched.kinds import STATE_DECODING, STATE_PREFILLING, STATE_WAITING
+from dnet_tpu.sched.policy import SchedulerPolicy
+from dnet_tpu.sched.queue import SchedQueue
+
+pytestmark = pytest.mark.api
+
+CHUNK = 8  # prefill chunk and kv block, tokens
+
+
+@pytest.fixture
+def paged_env(monkeypatch):
+    monkeypatch.setenv("DNET_KV_PAGED", "1")
+    monkeypatch.setenv("DNET_KV_BLOCK_TOKENS", str(CHUNK))
+    monkeypatch.setenv("DNET_FLASH_INTERPRET", "1")
+    monkeypatch.setenv("DNET_OBS_ENABLED", "1")  # the tick-record ring
+    reset_settings_cache()
+    reset_obs()
+    yield monkeypatch
+    monkeypatch.undo()
+    reset_settings_cache()
+    reset_obs()
+
+
+def _engine(model_dir, env, kv="ragged", slots=4):
+    from dnet_tpu.core.batch import BatchedEngine
+
+    if kv == "ragged":
+        env.setenv("DNET_KV_RAGGED", "1")
+        reset_settings_cache()
+    eng = BatchedEngine(
+        model_dir, slots=slots, max_seq=128, param_dtype="float32",
+        kv_paged=kv != "dense",
+    )
+    assert eng.kv_ragged is (kv == "ragged")
+    return eng
+
+
+def _prompt(nonce: str, n: int):
+    return [256] + [1 + (ord(nonce[0]) * 7 + 3 * j) % 250 for j in range(n - 1)]
+
+
+def _decoding(nonce: str) -> DecodingParams:
+    return DecodingParams(temperature=0.8, top_p=0.9, seed=ord(nonce[0]))
+
+
+def _chunked_prefill(eng, nonce, ids, dec) -> int:
+    """The scheduler's prefill surface, chunk by chunk (sched/step.py)."""
+    eng.reserve_slot(nonce)
+    eng.seed_from_prefix(nonce, ids, dec.seed)
+    logits = None
+    for i in range(0, len(ids), CHUNK):
+        logits = eng.prefill_chunk(nonce, ids[i : i + CHUNK], dec.seed)
+    eng.store_prefix(nonce, ids)
+    return int(eng.adopt_prefilled(nonce, logits, dec).token[0])
+
+
+def _serial_streams(model_dir, env, asks: dict) -> dict:
+    """The same requests and seeds stepped through decode_batch with no
+    budgets at all: one step a call, every unfinished lane in every call."""
+    eng = _engine(model_dir, env)
+    try:
+        toks = {
+            n: [_chunked_prefill(eng, n, _prompt(n, plen), _decoding(n))]
+            for n, (plen, _ask) in asks.items()
+        }
+        while True:
+            reqs = {
+                n: (toks[n][-1], _decoding(n))
+                for n, (_plen, ask) in asks.items() if len(toks[n]) < ask
+            }
+            if not reqs:
+                return toks
+            out, errs = eng.decode_batch(reqs)
+            assert not errs, errs
+            for n, row in out.items():
+                toks[n].append(int(row.token[0]))
+    finally:
+        eng.close()
+
+
+async def _client(adapter, got, nonce, plen, ask, after=None):
+    """One driver: the API's own loop (send, await, echo), starting once
+    `after` = (nonce, tokens) has been reached by another stream."""
+    while after is not None and len(got.get(after[0], ())) < after[1]:
+        await asyncio.sleep(0.001)
+    dec = _decoding(nonce)
+    send = _prompt(nonce, plen)
+    got[nonce] = []
+    for step in range(ask):
+        await adapter.send_tokens(nonce, send, dec, step, budget=ask - step)
+        res = await adapter.await_token(nonce, step, 120.0)
+        assert not res.error, res.error
+        got[nonce].append(res.token_id)
+        send = [res.token_id]
+    await adapter.reset_cache(nonce)
+
+
+async def _serve(eng, clients):
+    from dnet_tpu.sched.engine import SchedulerAdapter
+
+    adapter = SchedulerAdapter(eng, token_budget=64, prefill_chunk=CHUNK)
+    await adapter.start()
+    got: dict = {}
+    try:
+        await asyncio.gather(*(_client(adapter, got, *c) for c in clients))
+    finally:
+        await adapter.shutdown()
+    return got
+
+
+def _tokens_by_source():
+    tok = metric("dnet_decode_tokens_total")
+    return {s: int(tok.labels(source=s).value) for s in ("dispatch", "buffer", "spec")}
+
+
+def _decode_records():
+    recs = [r.as_dict() for r in get_tick_recorder().records()]
+    return [r for r in recs if r["decode_lanes"]]
+
+
+def test_lanes_out_of_phase_behind_a_prompt_take_one_step_a_tick(tiny_llama_dir, paged_env):
+    """Three lanes adopted on different ticks (1, 2 and 3 chunks of prompt)
+    while a fourth prompt of 14 chunks is still prefilling: every dispatch
+    is a single step that carries every decoding lane of its tick, nothing
+    is ever buffered, and the streams are the serial ones."""
+    asks = {"a": (8, 7), "b": (16, 7), "c": (24, 7), "d": (112, 1)}
+    want = _serial_streams(tiny_llama_dir, paged_env, asks)
+    reset_obs()
+    eng = _engine(tiny_llama_dir, paged_env)
+    try:
+        got = asyncio.run(_serve(eng, [(n, *asks[n]) for n in asks]))
+        slots = eng.slots
+    finally:
+        eng.close()
+    assert got == want
+    recs = _decode_records()
+    # the long prompt was still prefilling when each of these ticks ended
+    assert recs and all(r["queue_depths"][STATE_PREFILLING] >= 1 for r in recs)
+    for r in recs:
+        assert r["chunk_r"] == 1, r
+        assert r["dispatched_lanes"] == r["decode_lanes"], r
+    # lanes joined one by one and no tick left a decoding lane out
+    assert max(r["decode_lanes"] for r in recs) == 3
+    assert [r["decode_lanes"] for r in recs[:3]] == [1, 2, 3]
+    assert len(recs) == 6 + 2  # the last lane's six steps, two ticks behind the first
+    lane_steps = metric("dnet_decode_lane_steps_total").value
+    slot_steps = metric("dnet_decode_slot_steps_total").value
+    assert lane_steps == sum(r["decode_lanes"] for r in recs) == 3 * 6
+    assert slot_steps == slots * len(recs)
+    disp = metric("dnet_decode_dispatch_total")
+    assert disp.labels(r="1").value == len(recs)
+    assert all(disp.labels(r=str(r)).value == 0 for r in (2, 4, 8, 16))
+    assert _tokens_by_source() == {"dispatch": 18, "buffer": 0, "spec": 0}
+
+
+def test_a_new_lane_steps_alone_while_buffers_drain_then_all_fuse(tiny_llama_dir, paged_env):
+    """Two streams in phase with nothing queued fuse; a third arrives: no
+    budgets while it prefills, then it steps at R = 1 while the others
+    drain their buffers, then all three are dispatched together."""
+    asks = {"a": (8, 24), "b": (8, 24), "c": (8, 24)}
+    want = _serial_streams(tiny_llama_dir, paged_env, asks)
+    reset_obs()
+    eng = _engine(tiny_llama_dir, paged_env)
+    try:
+        got = asyncio.run(_serve(eng, [
+            ("a", *asks["a"]), ("b", *asks["b"]), ("c", *asks["c"], ("a", 3)),
+        ]))
+    finally:
+        eng.close()
+    assert got == want
+    recs = _decode_records()
+    shape = [(r["chunk_r"], r["dispatched_lanes"], r["decode_lanes"]) for r in recs]
+    # a dispatch that leaves a lane of its tick out is never fused
+    assert all(r <= 1 for r, sent, lanes in shape if sent < lanes), shape
+    assert shape[0] == (16, 2, 2)  # a and b, in phase, nothing queued
+    alone = [i for i, s in enumerate(shape) if s == (1, 1, 3)]
+    assert alone, shape  # c steps while a and b drain
+    together = [i for i, (r, sent, lanes) in enumerate(shape) if sent == lanes == 3]
+    assert together and together[0] > alone[-1], shape
+    assert shape[together[0]][0] > 1, shape  # in phase again: fused
+    src = _tokens_by_source()
+    assert src["buffer"] > 0 and src["spec"] == 0
+    assert src["dispatch"] + src["buffer"] == 3 * 23
+
+
+@pytest.mark.parametrize("kv", ["dense", "gather", "ragged"])
+def test_decode_batch_fuses_only_a_dispatch_that_carries_every_lane(tiny_llama_dir, paged_env, kv):
+    """The engine's half, without a scheduler: lanes a and b hold rows of an
+    R = 4 dispatch when c appears; c steps at R = 1 (three times) while they
+    drain, then one fused dispatch carries all three.  Same stream as the
+    unbudgeted calls give."""
+    dec = DecodingParams(temperature=0.0)
+    prompts = {"a": [256, 72, 101], "b": [256, 84, 104, 105], "c": [256, 90, 91]}
+
+    def run(budgeted: bool):
+        eng = _engine(tiny_llama_dir, paged_env, kv=kv)
+        try:
+            last, got, sent = {}, {n: [] for n in prompts}, []
+
+            def step(names, budget):
+                reqs = {n: (last[n], dec) for n in names}
+                budgets = {n: budget for n in names} if budgeted else None
+                out, errs = eng.decode_batch(reqs, budgets=budgets)
+                assert not errs and set(out) == set(names)
+                sent.append(eng.last_dispatch)
+                for n in names:
+                    last[n] = int(out[n].token[0])
+                    got[n].append(last[n])
+
+            for n in ("a", "b"):
+                last[n] = int(eng.prefill_and_sample(n, prompts[n], dec).token[0])
+            step(("a", "b"), 6)
+            last["c"] = int(eng.prefill_and_sample("c", prompts["c"], dec).token[0])
+            for _ in range(5):
+                step(("a", "b", "c"), 6)
+            return got, sent
+        finally:
+            eng.close()
+
+    got, sent = run(budgeted=True)
+    assert sent == [(4, 2), (1, 1), (1, 1), (1, 1), (4, 3), (0, 0)]
+    serial, sent = run(budgeted=False)
+    assert sent == [(1, 2)] + [(1, 3)] * 5
+    assert got == serial
+
+
+def _queue(states: dict) -> SchedQueue:
+    q = SchedQueue()
+    for nonce, (state, step) in states.items():
+        r = q.add(nonce, list(range(6)), DecodingParams())
+        r.state, r.pending_step, r.pending_budget = state, step, 9
+        r.prefilled = 0
+    return q
+
+
+class _Slots:
+    max_seq = 256
+
+    def __init__(self, slots):
+        self.slots = slots
+
+
+@pytest.mark.parametrize(
+    "others,slots,budgeted",
+    [
+        ({}, 4, True),  # lanes alone: the engine may fuse
+        ({"p": (STATE_PREFILLING, 0)}, 4, False),  # a prompt mid-prefill
+        ({"w": (STATE_WAITING, 0)}, 4, False),  # admitted this tick: its chunk is in the plan
+        ({"w": (STATE_WAITING, 0)}, 2, False),  # no slot free: it waits for admission
+        ({"w": (STATE_WAITING, None)}, 4, False),  # preempted, its next step moments away
+        ({"x": (STATE_DECODING, None)}, 4, True),  # a lane between steps is no prompt
+    ],
+)
+def test_the_policy_hands_out_budgets_only_while_no_prompt_waits(others, slots, budgeted):
+    q = _queue({"d1": (STATE_DECODING, 3), "d2": (STATE_DECODING, 5), **others})
+    plan = SchedulerPolicy(token_budget=64, prefill_chunk=8).plan(q, _Slots(slots))
+    assert set(plan.decode) == {"d1", "d2"}
+    assert plan.budgets == ({"d1": 9, "d2": 9} if budgeted else {})

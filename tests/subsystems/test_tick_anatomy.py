@@ -4,7 +4,9 @@ Three layers, all always on and unfenced:
 
 - `decode_batch`'s fused-chunk counters (core/batch.py): slot-steps the
   device computed, lane-steps active lanes asked for, tokens the driver
-  received by source, dispatches by width R;
+  received by source, dispatches by width R.  Buffer hits belong to lanes
+  in phase with nothing queued (a lone stream here); under load every
+  dispatch is one step over all lanes (tests/subsystems/test_decode_phase.py);
 - the scheduler's stamps on SchedRequest (sched/engine.py): queue wait,
   prefill wall time and ticks, decode deliver wait, and the recorder's
   `sched_queue` / `prefill` spans that make a request's segment ledger add
@@ -239,7 +241,9 @@ def test_scheduler_rehearsal_waits_spans_and_tick_records(tiny_llama_dir, paged_
         n_disp = sum(metric("dnet_decode_dispatch_total").labels(r=str(r)).value
                      for r in (1, 2, 4, 8, 16))
         assert _span("dnet.decode.launch")[0] == _span("dnet.decode.readback")[0] == n_disp
-        assert 0 < n_disp < n_dec  # fused chunks: some calls were buffer hits
+        # a lone stream with nothing queued still fuses: some calls were
+        # buffer hits (with a prompt waiting every call would dispatch)
+        assert 0 < n_disp < n_dec
         n_pf, pf_ms = _span("dnet.tick.prefill")
         assert n_pf == _span("dnet.prefill.launch")[0] == chunks
         assert _span("dnet.prefill.adopt")[0] == 1

@@ -134,3 +134,46 @@ def test_a_staged_rows_commit_has_one_window_width_whatever_the_prompt():
                               KV_KIND_WINDOW: (list(range(n_win)), list(range(n_win)))})
     assert [s[KV_KIND_WINDOW] for s in seen] == [(6, 6)] * 4
     assert [s[KV_KIND_FULL] for s in seen] == [(8, 8), (8, 8), (8, 8), (16, 16)]
+
+
+@pytest.mark.parametrize("dropped", [(), (1,), (0, 2)])
+def test_a_steps_rows_land_where_indexing_by_layer_block_and_row_puts_them(dropped):
+    """`KindStore.append_in_program` writes one row a slot through the pool's
+    [L*N*bt, W] view (so the compiler need not relayout the pool around the
+    write); it must put every row exactly where `pool[:, phys, off]` does,
+    layer by layer and kind by kind, and drop a lane whose block is past
+    the pool's end."""
+    from types import SimpleNamespace
+
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dnet_tpu.kv import KindStore
+
+    bt, slots = 8, 3
+    model = SimpleNamespace(
+        paged_kinds=(KV_KIND_WINDOW, KV_KIND_FULL, KV_KIND_WINDOW, KV_KIND_WINDOW), window=24,
+        config=SimpleNamespace(num_key_value_heads=2, head_dim=4),
+    )
+    cfgs = {KV_KIND_FULL: PagedKVConfig(bt, 10), KV_KIND_WINDOW: PagedKVConfig(bt, 6)}
+    store = KindStore(model, cfgs, "float32")
+    rng = np.random.default_rng(7)
+    pool = {
+        kind: {leaf: jnp.asarray(rng.normal(size=p.shape).astype(np.float32)) for leaf, p in leaves.items()}
+        for kind, leaves in store.kv.items()
+    }
+    rows = {leaf: jnp.asarray(rng.normal(size=(4, slots, 2, 4)).astype(np.float32)) for leaf in ("k", "v")}
+    phys = {KV_KIND_FULL: np.array([9, 0, 4], np.int32), KV_KIND_WINDOW: np.array([5, 2, 0], np.int32)}
+    for s in dropped:  # an inactive lane: past the block axis of every kind
+        for kind in phys:
+            phys[kind][s] = cfgs[kind].pool_blocks
+    off = np.array([0, 7, 3], np.int32)
+    got = store.append_in_program(pool, rows, {k: jnp.asarray(v) for k, v in phys.items()}, jnp.asarray(off))
+    for kind, idx in store.layers.items():
+        for leaf in ("k", "v"):
+            want = np.array(pool[kind][leaf])
+            for j, layer in enumerate(idx):
+                for s in range(slots):
+                    if s not in dropped:
+                        want[j, phys[kind][s], off[s]] = np.asarray(rows[leaf])[layer, s].reshape(-1)
+            np.testing.assert_array_equal(np.asarray(got[kind][leaf]), want)
