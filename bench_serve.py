@@ -56,9 +56,10 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--slots", type=int, default=4,
                    help="in-process: continuous-batching slots (1 = local)")
     p.add_argument("--sched", action="store_true",
-                   help="in-process: serve through the iteration-level "
-                   "scheduler (DNET_SCHED=1, dnet_tpu/sched/) instead of "
-                   "the legacy kick-coalescing engine path")
+                   help="in-process: the scheduler's own sizing (every "
+                   "in-process load is served by the scheduler, "
+                   "dnet_tpu/sched/): lanes = --slots, admission at the "
+                   "configured concurrency")
     p.add_argument("--ring-tp", action="store_true",
                    help="drive the workload over the in-process two-shard "
                    "ring THREE times — tp=1 baseline (r04's pipelined wire "
@@ -148,14 +149,11 @@ def _free_port() -> int:
 
 
 def _kv_mode(engine) -> str:
-    """The KV serving mode the loaded engine RESOLVED to (not just what
-    the env asked for — a ragged refusal falls back to paged gather, and
-    the stamp must say which path the numbers measured)."""
-    if getattr(engine, "kv_ragged", False):
-        return "ragged"
-    if getattr(engine, "kv_pool", None) is not None:
-        return "paged"
-    return "dense"
+    """The KV layout the loaded engine RESOLVED to (a refusal falls back
+    to dense slots, and the stamp must say which path the numbers
+    measured); `ragged` is the pool attended in place, as the earlier
+    records name it."""
+    return "ragged" if getattr(engine, "kv_pool", None) is not None else "dense"
 
 
 def _tp_mode(engine) -> dict:
@@ -239,7 +237,7 @@ async def _run_inprocess(args, spec) -> dict:
                 include_rows=not args.no_rows,
                 meta={
                     "mode": "in-process",
-                    "engine": "sched" if args.sched else "legacy",
+                    "engine": "sched" if manager.serving.adapter == "SchedulerAdapter" else "legacy",
                     "kv": _kv_mode(manager.engine),
                     "tp": _tp_mode(manager.engine),
                     "slots": args.slots,
@@ -770,13 +768,12 @@ def main(argv=None) -> int:
     if args.sched:
         if args.base_url:
             print("error: --sched is an in-process knob; a remote target "
-                  "picks its own engine via DNET_SCHED", file=sys.stderr)
+                  "sizes its own scheduler", file=sys.stderr)
             return 2
-        # before reset_settings_cache so SchedSettings sees it too
-        os.environ["DNET_SCHED"] = "1"
-        # --slots governs the lane count on BOTH paths (apples-to-apples:
-        # DNET_SCHED_SLOTS=0 would widen the scheduler to max(slots, 8));
-        # an explicit DNET_SCHED_SLOTS in the environment still wins
+        # --slots governs the lane count (DNET_SCHED_SLOTS=0 would widen
+        # the scheduler to max(slots, 8)); an explicit DNET_SCHED_SLOTS in
+        # the environment still wins; before reset_settings_cache so
+        # SchedSettings sees it
         os.environ.setdefault("DNET_SCHED_SLOTS", str(max(args.slots, 1)))
     from dnet_tpu.config import reset_settings_cache
 

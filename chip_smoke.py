@@ -14,12 +14,13 @@ exited before the next starts (this parent never imports jax or dnet_tpu):
   model          seeded HF-format checkpoint on disk (reused when present)
   kernels        every Pallas kernel compiled by Mosaic at this model's
                  shapes and compared with its jnp twin
-  serve-default  `python -m dnet_tpu.cli.api --model <dir>`, no DNET_* set:
-                 greedy twice (identical), a long streamed prompt, sampled
-  serve-sched    DNET_SCHED=1 DNET_KV_PAGED=1 DNET_KV_RAGGED=1: 8 concurrent
-                 streams, then the block pool's books must balance
-  mesh4          (>= 4 devices) --mesh pp=2,tp=2, the serve-default requests,
-                 plus MeshEngine's logits against LocalEngine's
+  serve          `python -m dnet_tpu.cli.api --model <dir>`, no DNET_* set
+                 (the scheduler over the paged pool, attended in place):
+                 greedy twice (identical), a long streamed prompt, sampled;
+                 then 8 concurrent streams, after which the block pool's
+                 books must balance
+  mesh4          (>= 4 devices) --mesh pp=2,tp=2, the lone requests of
+                 `serve`, plus MeshEngine's logits against LocalEngine's
 
 Children run with JAX_PLATFORMS=tpu, so a missing chip is an error and never
 a CPU run; only --rehearse reaches the CPU, and says so first and last.
@@ -363,7 +364,12 @@ class Smoke:
             "sampled_tokens": sampled["usage"]["completion_tokens"],
         }
 
-    def drive_sched(self, http) -> dict:
+    def drive_serve(self, http) -> dict:
+        detail = self.drive_default(http)
+        detail.update(self.drive_streams(http))
+        return detail
+
+    def drive_streams(self, http) -> dict:
         rng = random.Random(0)
         lo, hi = self.mode["sched_prompt_bytes"]
         jobs = [(filler(rng.randint(lo, hi), seed=i), rng.randint(16, 64))
@@ -489,13 +495,11 @@ def main(argv=None) -> int:
         steps = [
             ("model", smoke.write_model),
             ("kernels", smoke.check_kernels),
-            ("serve-default", lambda: smoke.serve(
-                "serve-default", [], None, smoke.drive_default,
-                ("flash_prefill", "flash_decode"))),
-            ("serve-sched", lambda: smoke.serve(
-                "serve-sched", [],
-                {"DNET_SCHED": "1", "DNET_KV_PAGED": "1", "DNET_KV_RAGGED": "1"},
-                smoke.drive_sched, ("flash_prefill", "paged_attend"))),
+            # flash_decode (the dense cache's kernel) is the kernels
+            # phase's and mesh4's; a plain load decodes through paged_attend
+            ("serve", lambda: smoke.serve(
+                "serve", [], None, smoke.drive_serve,
+                ("flash_prefill", "paged_attend"))),
         ]
         if smoke.device["count"] >= 4:
             steps.append(("mesh4", smoke.mesh4))

@@ -52,7 +52,9 @@ _FRAME_REQUIRED = {
 
 _ERROR_BASE = "InferenceError"
 _STATUS_MAP_SUFFIX = "api/http.py"
-_ERROR_HOME_SUFFIX = "api/inference.py"
+#: where typed errors are defined: the api's own, and the leaf below both
+#: layers that holds the base and what core/ raises
+_ERROR_HOME_SUFFIXES = ("api/inference.py", "core/types.py")
 
 
 def _env_read_key(node: ast.AST) -> str:
@@ -164,30 +166,32 @@ class ContractDrift(Check):
         yield from self._frame_headers(project)
 
     def _typed_errors(self, project: Project) -> Iterable[Finding]:
-        home = project.find_suffix(_ERROR_HOME_SUFFIX)
+        homes = [
+            h for h in map(project.find_suffix, _ERROR_HOME_SUFFIXES)
+            if h is not None and h.tree is not None
+        ]
         status_map = project.find_suffix(_STATUS_MAP_SUFFIX)
-        if home is None or home.tree is None or status_map is None or (
-            status_map.tree is None
-        ):
+        if not homes or status_map is None or status_map.tree is None:
             return
-        subclasses: Dict[str, int] = {}
+        subclasses: Dict[str, tuple] = {}
         known: Set[str] = {_ERROR_BASE}
         # two passes so grandchildren (subclass-of-subclass) resolve
         for _ in range(2):
-            for node in ast.walk(home.tree):
-                if isinstance(node, ast.ClassDef) and any(
-                    dotted(b).split(".")[-1] in known for b in node.bases
-                ):
-                    if node.name not in known:
-                        known.add(node.name)
-                        subclasses[node.name] = node.lineno
+            for home in homes:
+                for node in ast.walk(home.tree):
+                    if isinstance(node, ast.ClassDef) and any(
+                        dotted(b).split(".")[-1] in known for b in node.bases
+                    ):
+                        if node.name not in known:
+                            known.add(node.name)
+                            subclasses[node.name] = (home.rel, node.lineno)
         mapped = {
             n.id for n in ast.walk(status_map.tree) if isinstance(n, ast.Name)
         }
-        for name, lineno in sorted(subclasses.items()):
+        for name, (rel, lineno) in sorted(subclasses.items()):
             if name not in mapped:
                 yield self.finding(
-                    home.rel, lineno,
+                    rel, lineno,
                     f"typed error {name} has no status mapping in "
                     f"{status_map.rel} — it will fall through to a "
                     f"generic 500",

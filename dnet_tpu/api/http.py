@@ -16,7 +16,7 @@ Routes (reference: src/dnet/api/http_api.py:75-93):
                                   response embeds the request's
                                   critical-path segment ledger
   GET  /v1/debug/sched          — scheduler tick flight-recorder ring
-                                  (sched/flight.py; DNET_SCHED mode)
+                                  (sched/flight.py; scheduler loads)
   GET  /v1/debug/trace/{rid}    — one request as Chrome trace-event /
                                   Perfetto JSON (?cluster=1 stitches)
   GET  /v1/debug/trace?last_s=N — serving-window Perfetto dump (every
@@ -724,6 +724,11 @@ class ApiHTTPServer:
 
             body["device"] = device_report()
             body["kernels"] = SELECTIONS.snapshot()
+            serving = getattr(self.model_manager, "serving", None)
+            if serving is not None:
+                # engine, adapter, KV layout and why (model_manager.py:
+                # serving_plan): the path no setting selects
+                body["serving"] = serving._asdict()
         monitor = self.inference.failure_monitor
         quarantine = getattr(monitor, "quarantine", None)
         if quarantine is not None:

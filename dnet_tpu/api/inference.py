@@ -34,7 +34,11 @@ from dnet_tpu.admission.controller import (
     request_deadline,
 )
 from dnet_tpu.api.strategies import ApiAdapterBase
-from dnet_tpu.core.types import DecodingParams
+from dnet_tpu.core.types import (  # the two errors live there; re-exported
+    DecodingParams,
+    EngineCapabilityError,
+    InferenceError,
+)
 from dnet_tpu.obs import critical_path, get_recorder, get_slo_tracker, metric
 from dnet_tpu.obs.events import bind, log_event
 from dnet_tpu.resilience.checkpoint import ResumableDecode
@@ -49,10 +53,6 @@ _REQUESTS = metric("dnet_requests_total")
 _REQUEST_ERRORS = metric("dnet_request_errors_total")
 _TOKENS_TOTAL = metric("dnet_tokens_generated_total")
 _CANCELS = metric("dnet_cancel_propagated_total")
-
-
-class InferenceError(Exception):
-    pass
 
 
 class PromptTooLongError(InferenceError):
@@ -74,14 +74,6 @@ class BackpressureError(InferenceError):
     """A capacity limit refused the work (paged-KV pool exhausted, lane /
     batch-slot pools full): maps to HTTP 429 + Retry-After, never 500 —
     the client should back off and retry, nothing is broken."""
-
-
-class EngineCapabilityError(InferenceError):
-    """The loaded engine cannot serve the requested configuration —
-    continuous batching over streamed weights, or a model without gated
-    KV writes (raised by core/batch.py at LOAD time): maps to HTTP 422,
-    an operator/config error, not the generic 500 it used to surface as
-    when a NotImplementedError crossed /v1/load_model."""
 
 
 # capacity-exhaustion signatures that cross the compute/wire boundary as
@@ -125,19 +117,19 @@ def _event_status(exc: BaseException) -> int:
     return 500
 
 
-def _resolved_modes() -> dict:
-    """The serving-mode knobs a postmortem reader wants next to a
-    request's outcome: resolved wire codec, KV layout, TP degree, and
-    whether the continuous-batching scheduler served it."""
+def _resolved_modes(adapter) -> dict:
+    """The serving modes a postmortem reader wants next to a request's
+    outcome: resolved wire codec, the serving engine's KV layout, TP
+    degree, and whether the continuous-batching scheduler served it."""
     from dnet_tpu.config import get_settings
 
     s = get_settings()
-    kv = "ragged" if s.kv.ragged else ("paged" if s.kv.paged else "dense")
+    engine = getattr(adapter, "engine", None)
     return {
         "codec": s.wire.codec,
-        "kv": kv,
+        "kv": "paged" if getattr(engine, "kv_pool", None) is not None else "dense",
         "tp": int(s.tp.tp),
-        "sched": bool(s.sched.sched),
+        "sched": type(adapter).__name__ == "SchedulerAdapter",
     }
 
 
@@ -623,7 +615,7 @@ class InferenceManager:
                 tokens=generated,
                 prompt_tokens=len(prompt_ids),
                 total_ms=round((t_end - t_start) * 1000.0, 3),
-                modes=_resolved_modes(),
+                modes=_resolved_modes(self.adapter),
                 critical_path=ledger,
             )
             completed = True
@@ -671,7 +663,7 @@ class InferenceManager:
                     total_ms=round(
                         (time.perf_counter() - t_start) * 1000.0, 3
                     ),
-                    modes=_resolved_modes(),
+                    modes=_resolved_modes(self.adapter),
                 )
                 completed = True
             cleanup_detached = True
@@ -712,7 +704,7 @@ class InferenceManager:
                     total_ms=round(
                         (time.perf_counter() - t_start) * 1000.0, 3
                     ),
-                    modes=_resolved_modes(),
+                    modes=_resolved_modes(self.adapter),
                 )
                 completed = True
             raise

@@ -10,8 +10,10 @@ events the injected faults perturb.
 
 Scenarios:
 
-- ``local``       single-node legacy engine behind admission + SSE
-- ``sched``       DNET_SCHED=1 + ragged-KV engine, same HTTP surface
+- ``local``       single-node stack behind admission + SSE: the scheduler
+                  over the paged pool, as every local load is served
+- ``sched``       the same stack under its old name (the campaign's
+                  matrix and its golden file count it; ROADMAP D2)
 - ``ring``        two-shard in-process ring (loadgen/ring_harness.py),
                   resume armed — the transport/compute fault surface
 - ``ring_wire``   the same ring under DNET_WIRE_PIPELINE=1 (overlapped
@@ -23,7 +25,7 @@ Scenarios:
 - ``member_auto`` the same with decode-grant batching
                   (DNET_API_RING_AUTO_STEPS=8)
 - ``fleet``       two single-node replicas behind FleetManager
-- ``fleet_sched`` the same over the scheduler engine
+- ``fleet_sched`` the same stack under its old name (as ``sched``)
 - ``fleet_ring``  two in-process RINGS behind FleetManager — the composed
                   acceptance cell (replica dies mid-stream on top of
                   in-ring resume) runs here
@@ -308,9 +310,6 @@ class LocalScenario(Scenario):
 
 class SchedScenario(LocalScenario):
     name = "sched"
-
-    def extra_env(self) -> Dict[str, str]:
-        return {"DNET_SCHED": "1", "DNET_KV_RAGGED": "1"}
 
 
 # ---------------------------------------------------------------------------
@@ -864,15 +863,11 @@ class FleetScenario(Scenario):
     parity = "content"
     points = ("fleet_dispatch", "admit")
 
-    sched = False
     n_replicas = 2
     batch_slots = 2
 
     def extra_env(self) -> Dict[str, str]:
-        env = {"DNET_FLEET": str(self.n_replicas)}
-        if self.sched:
-            env.update({"DNET_SCHED": "1", "DNET_KV_RAGGED": "1"})
-        return env
+        return {"DNET_FLEET": str(self.n_replicas)}
 
     async def _build(self) -> None:
         from dnet_tpu.api.http import ApiHTTPServer
@@ -932,7 +927,6 @@ class FleetScenario(Scenario):
 
 class FleetSchedScenario(FleetScenario):
     name = "fleet_sched"
-    sched = True
 
 
 # ---------------------------------------------------------------------------

@@ -199,22 +199,14 @@ class KVSettings(_EnvGroup):
     group_size: int = 64
     max_seq_len: int = 4096
     ttl_seconds: float = 600.0
-    # paged KV (dnet_tpu/kv/): block-granular allocation with per-sequence
-    # page tables, refcounted copy-on-write prefix sharing, and free-block
-    # admission instead of slots x max_seq dense pinning.  Local/Batched
-    # engines; the dense path stays the default.
-    paged: bool = False
-    # tokens per KV block (the allocation granule); must divide max_seq
+    # the paged pool (dnet_tpu/kv/), which the batched engine attends in
+    # place wherever the model and the cache allow it (core/batch.py:
+    # kv_layout): tokens per KV block (the allocation granule); must
+    # divide max_seq
     block_tokens: int = 16
     # total pool capacity in blocks; 0 = auto-size to the engine's dense
     # equivalent (slots x max_seq / block_tokens)
     pool_blocks: int = 0
-    # ragged paged attention (ops/paged_attention.py): decode attends the
-    # block pool IN PLACE through per-sequence page tables instead of the
-    # gather->step->scatter sandwich.  Requires paged KV; engines fall back
-    # to dense-gather for layouts the kernel refuses (quantized caches,
-    # non-llama-family attention stacks).
-    ragged: bool = False
 
 
 @dataclass
@@ -402,22 +394,17 @@ class MembershipSettings(_EnvGroup):
 
 @dataclass
 class SchedSettings(_EnvGroup):
-    """Iteration-level continuous-batching scheduler (dnet_tpu/sched/).
-
-    ``DNET_SCHED=1`` makes the scheduler the serving engine for local
-    model loads: every tick packs up to ``SCHED_TOKEN_BUDGET`` tokens of
-    chunked-prefill segments plus one decode step per running sequence
-    into one batch plan, admits new work only when the paged-KV block
-    pool can cover it, and preempts the lowest-priority sequence back to
-    WAITING (paged prefix kept) under block starvation.  Off (the
-    default), the legacy engine-selection paths serve unchanged.  The
-    gate is also honored as a raw env flip via ``env_flag("DNET_SCHED")``
-    so post-cache toggles (tests, operators) still see it.
+    """Iteration-level continuous-batching scheduler (dnet_tpu/sched/),
+    the serving engine of every local model load the batched engine can
+    take (api/model_manager.py: serving_plan): every tick packs up to
+    ``SCHED_TOKEN_BUDGET`` tokens of chunked-prefill segments plus one
+    decode step per running sequence into one batch plan, admits new work
+    only when the paged-KV block pool can cover it, and preempts the
+    lowest-priority sequence back to WAITING (paged prefix kept) under
+    block starvation.
     """
 
     env_prefix = "DNET_"
-    # master switch: the scheduler becomes the local serving engine
-    sched: bool = False
     # per-tick token budget shared by chunked-prefill segments (1 token
     # each) and decode steps (1 per running sequence)
     sched_token_budget: int = 2048
@@ -703,8 +690,8 @@ for _cls in (
 def env_flag(name: str, default: bool = False) -> bool:
     """Sanctioned RAW process-env boolean read — the documented DL006
     escape hatch for flags that must see ``os.environ`` flips after the
-    ``get_settings()`` cache warmed: the ``DNET_KV_PAGED`` /
-    ``DNET_PROFILE`` test toggles and the ``DNET_FLASH_DECODE`` /
+    ``get_settings()`` cache warmed: the ``DNET_PROFILE`` test toggle
+    and the ``DNET_FLASH_DECODE`` /
     ``DNET_FLASH_INTERPRET`` operator kill-switches.  Unset,
     set-but-empty (``DNET_X=``, the shell/compose idiom for "unset"),
     or unparseable values return ``default`` — an empty string must not

@@ -7,8 +7,10 @@ single-sequence engine.  On TPU, batch-1 decode is weight-bound — the MXU
 reads every weight to produce ONE token — so lanes 2..N of a batched matmul
 are nearly free.  This engine turns concurrency into throughput:
 
-- A fixed pool of `slots` KV-cache rows ([L, slots, S, ...]) serves all
-  active requests; a request owns one slot from prefill to EOS.
+- A request owns one of `slots` lanes from prefill to EOS.  Its KV cache
+  is a page table over the shared block pool (kv/), which the decode step
+  attends IN PLACE, wherever the model and the cache allow it; dense rows
+  ([L, slots, S, ...]) serve the rest (`kv_layout` below decides).
 - The decode step is `jax.vmap` of the SAME single-example forward+sample
   the LocalEngine uses (per-slot pos / sampling params / RNG key / active
   flag), jitted once — adding or finishing requests never recompiles.
@@ -42,7 +44,7 @@ from dnet_tpu.core.sampler import (
     encode_logit_bias,
     sample,
 )
-from dnet_tpu.core.types import DecodingParams
+from dnet_tpu.core.types import DecodingParams, EngineCapabilityError
 from dnet_tpu.kv import (
     BlockPool,
     BlockStore,
@@ -51,8 +53,6 @@ from dnet_tpu.kv import (
     PagedKVConfig,
     PagedPrefixCache,
     PageTable,
-    paged_enabled,
-    ragged_enabled,
     window_blocks,
     window_first_block,
 )
@@ -63,8 +63,6 @@ from dnet_tpu.obs.phases import (
     DECODE_CHUNK_WIDTHS,
     KV_KIND_FULL,
     KV_KIND_WINDOW,
-    SPAN_DECODE_KV_GATHER,
-    SPAN_DECODE_KV_SCATTER,
     SPAN_DECODE_LAUNCH,
     SPAN_DECODE_PREPARE,
     SPAN_DECODE_READBACK,
@@ -83,15 +81,53 @@ _DECODE_BUFFER_DROPPED = metric("dnet_decode_buffer_dropped_total")
 _MOE_ASSIGNMENTS = metric("dnet_moe_assignments_total")
 
 
+KV_PAGED = "paged"  # page tables over the block pool, attended in place
+KV_DENSE = "dense"  # [L, slots, max_seq, ...] rows, one a lane
+
+
+def kv_layout(
+    model, kv_quant_bits: int, spec_lookahead: int, max_seq: int
+) -> Tuple[str, str]:
+    """THE rule for a batched engine's KV cache: (KV_PAGED | KV_DENSE, why).
+
+    The paged pool attended in place, unless something the code can see
+    rules it out: per-lane speculation was asked for (its verify blocks
+    rewind a dense cache: an explicit request outranks a derived default),
+    the kernel refuses the model or the cache
+    (ops/paged_attention.ragged_refusal), or the pool's geometry refuses
+    max_seq (PagedKVConfig.from_settings).  Dense slots serve those, under
+    the same scheduler."""
+    from dnet_tpu.ops.paged_attention import ragged_refusal
+
+    if spec_lookahead > 0:
+        return KV_DENSE, "per-lane speculation needs the dense cache"
+    why = ragged_refusal(model, kv_quant_bits)
+    if why is not None:
+        return KV_DENSE, why
+    try:
+        PagedKVConfig.from_settings(max_seq)
+    except ValueError as exc:
+        return KV_DENSE, str(exc)
+    return KV_PAGED, "the block pool, attended in place through the page tables"
+
+
 class BatchedEngine:
-    """LocalEngine-compatible surface plus `decode_batch` for the scheduler."""
+    """LocalEngine-compatible surface plus `decode_batch` for the scheduler.
+
+    `kv_paged`: None derives the KV layout by `kv_layout`; False is the
+    explicit dense engine (the tests' reference); True insists on the pool
+    even where speculation was asked for (which it then switches off), and
+    still falls back to dense slots where the pool is refused.  The
+    prefix-cache capacity belongs to whichever layout serves: block
+    aliasing over the pool, or the inner B=1 engine's snapshots."""
 
     token_result = staticmethod(LocalEngine.token_result)
 
     def __init__(self, model_dir: str | Path, slots: int = 8, **engine_kwargs):
         if slots < 1:
             raise ValueError(f"slots must be >= 1, got {slots}")
-        paged, prefix_size, engine_kwargs = self._split_paged_kwargs(engine_kwargs)
+        paged = engine_kwargs.pop("kv_paged", None)
+        prefix_size = int(engine_kwargs.pop("prefix_cache_size", 0) or 0)
         self.eng = LocalEngine(model_dir, **engine_kwargs)
         self._init_state(slots, paged=paged, prefix_size=prefix_size)
 
@@ -104,41 +140,17 @@ class BatchedEngine:
         if slots < 1:
             raise ValueError(f"slots must be >= 1, got {slots}")
         self = cls.__new__(cls)
-        paged, prefix_size, kw = cls._split_paged_kwargs(kw)
+        paged = kw.pop("kv_paged", None)
         self.eng = LocalEngine.from_params(config, window_params, edge_params, **kw)
-        self._init_state(slots, paged=paged, prefix_size=prefix_size)
+        self._init_state(slots, paged=paged)
         return self
 
-    @staticmethod
-    def _split_paged_kwargs(kw: Dict[str, Any]):
-        """Resolve the paged-KV flag and claim the prefix-cache capacity.
-
-        Under DNET_KV_PAGED=1 the BATCHED engine owns the pool, the
-        per-slot page tables, and prefix sharing — the inner B=1 engine is
-        pure prefill staging and must not run its own ledger or snapshot
-        cache (double admission / double memory)."""
-        kw = dict(kw)
-        paged = kw.pop("kv_paged", None)
-        paged = paged_enabled() if paged is None else bool(paged)
-        prefix_size = int(kw.pop("prefix_cache_size", 0) or 0)
-        # ALWAYS pin the inner engine dense: left to read DNET_KV_PAGED
-        # itself it would build a phantom ledger that spuriously rejects
-        # staging prefills and publishes gauges for a pool nobody serves
-        kw["kv_paged"] = False
-        if not paged and prefix_size:
-            kw["prefix_cache_size"] = prefix_size
-        return paged, prefix_size, kw
-
     def _init_state(
-        self, slots: int, paged: bool = False, prefix_size: int = 0
+        self, slots: int, paged: Optional[bool] = None, prefix_size: int = 0
     ) -> None:
         # typed load-time refusals: the HTTP layer maps these to 422
-        # (operator/config error) instead of the generic 500 the old
-        # NotImplementedError fell through to.  Function-level import —
-        # the api layer depends on core, not the other way around, so the
-        # exception type is fetched only at this (load-time) raise site.
-        from dnet_tpu.api.inference import EngineCapabilityError
-
+        # (operator/config error).  A served load never gets here
+        # (api/model_manager.py: serving_plan reads the same two facts).
         if self.eng.plan.streams_weights:
             raise EngineCapabilityError(
                 "continuous batching needs resident weights (fit policy); "
@@ -154,6 +166,7 @@ class BatchedEngine:
         self.max_seq = self.eng.max_seq
         self.config = self.eng.config
         self.model = self.eng.model
+        self.weight_quant_bits = self.eng.weight_quant_bits
         # per-LANE speculative decoding (VERDICT r3 next #5): spec_lookahead
         # flows through engine_kwargs into the inner LocalEngine, whose B=1
         # prefill paths maintain the per-session history buffers we adopt
@@ -166,124 +179,43 @@ class BatchedEngine:
             )
             self.spec_lookahead = 0
         m = self.eng.model
-        # paged KV (kv/): per-slot page tables over a shared block pool
-        # replace the dense [L, slots, S] residency; the dense view exists
-        # only transiently per step (gather -> step -> block scatter)
-        self.kv_pool: Optional[BlockPool] = None
-        self.kv_store: Optional[BlockStore] = None
-        self.paged_prefix: Optional[PagedPrefixCache] = None
-        self._kv_cfg: Optional[PagedKVConfig] = None
-        self._tables: List[Optional[PageTable]] = [None] * slots
-        self._adopt: Dict[str, Tuple[int, List[int], int]] = {}
+        if paged is False:
+            layout, why = KV_DENSE, "dense slots asked for"
+        else:
+            # an explicit True outranks the speculation it then switches off
+            layout, why = kv_layout(
+                m, self.eng.kv_quant_bits,
+                self.spec_lookahead if paged is None else 0, self.max_seq,
+            )
         # the books are kept by KIND of layer (obs/phases.py KV_KINDS): a
         # pool manager and per-slot tables a kind.  Every model has the
         # `full` kind — kv_pool and _tables are its entries, and what
         # admission and prefix sharing are functions of; a model with
         # WINDOW layers among full ones (model.paged_kinds) has the
         # `window` kind too, whose tables hold only the blocks inside the
-        # window
+        # window.  All empty under dense slots.
+        self.kv_pool: Optional[BlockPool] = None
+        self.kv_store: Optional[BlockStore] = None
+        self.paged_prefix: Optional[PagedPrefixCache] = None
+        self._kv_cfg: Optional[PagedKVConfig] = None
+        self._tables: List[Optional[PageTable]] = [None] * slots
+        self._adopt: Dict[str, Tuple[int, List[int], int]] = {}
         self.kv_pools: Dict[str, BlockPool] = {}
         self._kind_tables: Dict[str, List[Optional[PageTable]]] = {}
         self._window = 0
-        windowed = paged and KV_KIND_WINDOW in (m.paged_kinds or ())
-        if paged:
-            try:
-                if windowed and prefix_size:
-                    # sharing a prefix's window blocks is not sound: the
-                    # donor gives them back as it advances
-                    log.warning(
-                        "paged prefix sharing is OFF for %s: window layers "
-                        "give blocks back, so a prefix entry cannot alias "
-                        "them; DNET_API_PREFIX_CACHE=%d is ignored",
-                        self.eng.config.model_type, prefix_size,
-                    )
-                    prefix_size = 0
-                cfg = PagedKVConfig.from_settings(
-                    self.max_seq, slots=slots + prefix_size
-                )
-                if windowed:
-                    store = self._window_store(m, cfg, slots)
-                else:
-                    store = BlockStore(
-                        m, len(m.layers), cfg, self.eng.kv_dtype,
-                        quant_bits=self.eng.kv_quant_bits,
-                        session_tokens=self.max_seq,
-                    )
-            except (ValueError, NotImplementedError) as exc:
-                log.warning(
-                    "paged KV disabled for batched engine (%s); "
-                    "serving dense slots", exc,
-                )
-                paged = False
-                if prefix_size > 0:
-                    # the kwargs split claimed the prefix capacity for the
-                    # (now unavailable) paged cache: give the inner engine
-                    # its dense snapshot cache back
-                    self.eng.prefix_cache = self.eng._build_prefix_cache(
-                        prefix_size
-                    )
-            else:
-                self._kv_cfg = cfg
-                self.kv_pool = BlockPool(cfg)
-                self.kv_store = store
-                self.kv_pools = {KV_KIND_FULL: self.kv_pool}
-                self._kind_tables = {KV_KIND_FULL: self._tables}
-                if windowed:
-                    wcfg = store.cfgs[KV_KIND_WINDOW]
-                    self._window = int(m.window)
-                    self.kv_pools[KV_KIND_WINDOW] = BlockPool(wcfg, kind=KV_KIND_WINDOW)
-                    self._kind_tables[KV_KIND_WINDOW] = [None] * slots
-                    log.info(
-                        "window layers page apart: %d blocks (%d a slot) for "
-                        "a window of %d tokens",
-                        wcfg.pool_blocks, wcfg.pool_blocks // slots, self._window,
-                    )
-                if prefix_size > 0:
-                    self.paged_prefix = PagedPrefixCache(
-                        self.kv_pool, store, prefix_size,
-                        row_tokens=self.max_seq,
-                    )
-                if self.spec_lookahead > 0:
-                    log.warning(
-                        "per-lane speculation disabled under paged KV "
-                        "(verify blocks bypass the block scatter path)"
-                    )
-                    self.spec_lookahead = 0
-                log.info(
-                    "paged KV on: %d blocks x %d tokens serving %d slots",
-                    cfg.pool_blocks, cfg.block_tokens, slots,
-                )
-        # ragged paged attention (DNET_KV_RAGGED=1): decode attends the
-        # pool in place through the page tables; the dense gather/scatter
-        # round trip — and its kv_gather/kv_scatter spans — stop existing.
-        # Dense-gather stays the fallback for everything the kernel
-        # refuses (quantized caches, non-llama attention stacks), on top
-        # of the session layouts BlockStore itself already refused.
-        self.kv_ragged = False
-        if self._window:
-            self.kv_ragged = True  # _window_store refused anything else
-            log.info(
-                "ragged paged attention on: decode attends the block "
-                "pools in place, window layers from their lower bound"
+        if layout == KV_PAGED:
+            self._init_pool(m, slots, prefix_size)
+        else:
+            (log.info if paged is False else log.warning)(
+                "KV cache: dense slots (%s)", why
             )
-        elif paged and ragged_enabled():
-            from dnet_tpu.ops.paged_attention import ragged_refusal
+            if prefix_size > 0:
+                from dnet_tpu.core.prefix_cache import PrefixCache
 
-            why = ragged_refusal(m, self.eng.kv_quant_bits)
-            if why is not None:
-                log.warning(
-                    "ragged paged attention disabled (%s); serving "
-                    "dense-gather decode", why,
-                )
-            else:
-                self.kv_ragged = True
-                log.info(
-                    "ragged paged attention on: decode attends the block "
-                    "pool in place"
-                )
+                self.eng.prefix_cache = PrefixCache(prefix_size)
         self.kv = (
             None
-            if paged
+            if self.kv_pool is not None
             else m.init_kv(
                 len(m.layers), slots, self.max_seq, self.eng.kv_dtype,
                 quant_bits=self.eng.kv_quant_bits,
@@ -314,25 +246,69 @@ class BatchedEngine:
         )
         self._build()
 
-    def _window_store(self, m, cfg: PagedKVConfig, slots: int) -> KindStore:
-        """Pools by kind for a model with window layers.  Only the ragged
-        kernel reads them (there is no dense gather view of a table that
-        gave blocks back), so everything it refuses is refused here, and
-        the engine then serves dense slots.  The window kind's pool is
-        sized so that it can never be what admission waits for: every slot
-        may hold the most blocks a window table ever has."""
-        from dnet_tpu.config import get_settings
-        from dnet_tpu.ops.paged_attention import ragged_refusal
+    @property
+    def kv_ragged(self) -> bool:
+        """The pool is attended in place: the one way a pool is read.  Kept
+        for tests/benchmarks/test_bench_cohere2_moe.py, which asserts it
+        and which only a `benchmark` PR may edit."""
+        return self.kv_pool is not None
 
-        why = ragged_refusal(m, self.eng.kv_quant_bits)
-        if why is None and not ragged_enabled():
-            why = "DNET_KV_RAGGED is off"
-        if why is None and KV_KIND_FULL not in m.paged_kinds:
-            why = "no full layer among the window layers"
-        if why is not None:
-            raise NotImplementedError(
-                f"window layers page only under the ragged kernel ({why})"
+    def _init_pool(self, m, slots: int, prefix_size: int) -> None:
+        """The pool(s), their managers and the per-slot tables (KV_PAGED)."""
+        windowed = KV_KIND_WINDOW in (m.paged_kinds or ())
+        if windowed and prefix_size:
+            # sharing a prefix's window blocks is not sound: the donor
+            # gives them back as it advances
+            log.warning(
+                "paged prefix sharing is OFF for %s: window layers give "
+                "blocks back, so a prefix entry cannot alias them; "
+                "DNET_API_PREFIX_CACHE=%d is ignored",
+                self.eng.config.model_type, prefix_size,
             )
+            prefix_size = 0
+        cfg = PagedKVConfig.from_settings(self.max_seq, slots=slots + prefix_size)
+        if windowed:
+            store = self._window_store(m, cfg, slots)
+        else:
+            store = BlockStore(
+                m, len(m.layers), cfg, self.eng.kv_dtype,
+                quant_bits=self.eng.kv_quant_bits,
+                session_tokens=self.max_seq,
+            )
+        self._kv_cfg = cfg
+        self.kv_pool = BlockPool(cfg)
+        self.kv_store = store
+        self.kv_pools = {KV_KIND_FULL: self.kv_pool}
+        self._kind_tables = {KV_KIND_FULL: self._tables}
+        if windowed:
+            wcfg = store.cfgs[KV_KIND_WINDOW]
+            self._window = int(m.window)
+            self.kv_pools[KV_KIND_WINDOW] = BlockPool(wcfg, kind=KV_KIND_WINDOW)
+            self._kind_tables[KV_KIND_WINDOW] = [None] * slots
+            log.info(
+                "window layers page apart: %d blocks (%d a slot) for "
+                "a window of %d tokens",
+                wcfg.pool_blocks, wcfg.pool_blocks // slots, self._window,
+            )
+        if prefix_size > 0:
+            self.paged_prefix = PagedPrefixCache(self.kv_pool, store, prefix_size)
+        if self.spec_lookahead > 0:
+            log.warning(
+                "per-lane speculation disabled under paged KV "
+                "(verify blocks rewind a dense cache)"
+            )
+            self.spec_lookahead = 0
+        log.info(
+            "paged KV on: %d blocks x %d tokens serving %d slots",
+            cfg.pool_blocks, cfg.block_tokens, slots,
+        )
+
+    def _window_store(self, m, cfg: PagedKVConfig, slots: int) -> KindStore:
+        """Pools by kind for a model with window layers.  The window kind's
+        pool is sized so that it can never be what admission waits for:
+        every slot may hold the most blocks a window table ever has."""
+        from dnet_tpu.config import get_settings
+
         step = max(int(get_settings().sched.sched_prefill_chunk), *self.CHUNK_BUCKETS)
         per_slot = window_blocks(int(m.window), cfg.block_tokens, step)
         wcfg = PagedKVConfig(cfg.block_tokens, slots * per_slot)
@@ -343,6 +319,9 @@ class BatchedEngine:
 
     # ---- program ------------------------------------------------------
     def _build(self) -> None:
+        if self.kv_pool is not None:
+            self._build_ragged()
+            return
         model = self.eng.model
 
         @jax.named_scope("batched_step")
@@ -367,11 +346,7 @@ class BatchedEngine:
             )
             return res, kv, counts, key
 
-        # paged mode has no persistent dense cache; the pool tree has the
-        # same leaf STRUCTURE, which is all the axis spec needs
-        kv_axes = jax.tree.map(
-            lambda _: 1, self.kv if self.kv is not None else self.kv_store.kv
-        )
+        kv_axes = jax.tree.map(lambda _: 1, self.kv)
         sp_axes = SampleParams(0, 0, 0, 0, 0, 0, 0, 0)
         self._vmapped = jax.vmap(
             one,
@@ -384,8 +359,6 @@ class BatchedEngine:
         # fused R-step chunks (budget-driven): sampled tokens re-enter their
         # lanes on device, one dispatch + one packed read per R tokens
         self._chunks: Dict[int, Any] = {}
-        if self.kv_ragged:
-            self._build_ragged()
 
         L = self.spec_lookahead
         if L > 0:
@@ -431,7 +404,7 @@ class BatchedEngine:
             )
 
     def _build_ragged(self) -> None:
-        """The ragged decode programs (ops/paged_attention.py): one step
+        """The pool's decode programs (ops/paged_attention.py): one step
         that reads the block pool IN PLACE — page tables and per-slot
         positions ride along as the kernel's scalar-prefetched block index
         map — plus fused R-step chunks that carry the (donated) pool and
@@ -440,7 +413,7 @@ class BatchedEngine:
         norm/rope/MLP stack runs unchanged (apply_window's attend_fn hook
         swaps only the cache write + attention read), and sampling vmaps
         the identical per-lane tail, so greedy streams are parity-testable
-        against the gather path byte for byte."""
+        against the dense engine byte for byte."""
         from dnet_tpu.ops.paged_attention import paged_attend_impl
 
         model = self.eng.model
@@ -511,8 +484,7 @@ class BatchedEngine:
         """Fused R-step ragged chunk: the pool rides the scan carry
         (donated — XLA appends in place), each step attends it through the
         kernel and block-appends its new rows before the next step reads
-        them.  Same one-dispatch-per-R-tokens contract as _chunk_fn, with
-        the gather/scatter round trip deleted."""
+        them.  Same one-dispatch-per-R-tokens contract as _chunk_fn."""
         fn = self._ragged_chunks.get(R)
         if fn is None:
             step = self._ragged_step_fn
@@ -665,10 +637,10 @@ class BatchedEngine:
 
     # ---- inference ----------------------------------------------------
     def seed_from_prefix(self, nonce, full_ids, seed=None) -> int:
-        """Paged mode: a PrefixIndex hit resolves to SHARED refcounted
+        """Over the pool: a PrefixIndex hit resolves to SHARED refcounted
         blocks — the full blocks alias straight into this request's future
         page table (no copy); only the staging dense row for the inner
-        B=1 prefill is gathered.  Dense mode defers to the inner engine's
+        B=1 prefill is gathered.  Dense slots defer to the inner engine's
         snapshot cache."""
         if self.kv_pool is None:
             return self.eng.seed_from_prefix(nonce, full_ids, seed)
@@ -853,14 +825,12 @@ class BatchedEngine:
 
         With `order` (the dispatch's active nonce -> slot map), nb is the
         pow2 BUCKET of the widest active table instead of max_seq/bt: the
-        dense fallback stops gathering dead blocks every step, the ragged
         kernel walks fewer (elided) grid steps, and the compiled-program
-        set stays bounded — the same discipline as _bucket_pow2 scatter
+        set stays bounded — the same discipline as _bucket_pow2 commit
         widths.  Only R==1 dispatches pass `order` (warm_chunks pre-warms
         the step at every bucket width); fused R-step chunks keep the
-        single full-width program — they amortize the gather over R
-        tokens already, and a per-width chunk set would multiply the
-        compiled programs by the width count.  Frozen lanes' longer
+        single full-width program — a per-width chunk set would multiply
+        the compiled programs by the width count.  Frozen lanes' longer
         tables truncate harmlessly (their compute is garbage, their
         blocks are never written)."""
         nb = self.max_seq // self._kv_cfg.block_tokens
@@ -979,7 +949,7 @@ class BatchedEngine:
         while no prompt waits, sched/policy.py.)
 
         Host spans (obs/phases.py): prepare, then — only when some lane's
-        buffer is empty — [kv_gather] launch [kv_scatter] readback unpack.
+        buffer is empty — launch readback unpack.
         Nothing is fenced: launch is an enqueue, readback is the host
         blocked on the device.  `last_dispatch` says what this call sent
         to the device: (R, lanes), (0, 0) when every lane was answered
@@ -1017,52 +987,18 @@ class BatchedEngine:
             return out_buf, errors
         order, R, dev, table_ids = plan
         lanes = len(order)
-        paged = self.kv_pool is not None
-        if paged and self.kv_ragged:
-            # ragged paged attention: the pool is attended IN PLACE through
-            # the page tables and the new rows block-append — the gather/
-            # scatter round trip (and its two spans) does not exist here
-            with span(SPAN_DECODE_LAUNCH, R=R, lanes=lanes):
+        with span(SPAN_DECODE_LAUNCH, R=R, lanes=lanes):
+            if self.kv_pool is not None:
+                # the pool is attended IN PLACE through the page tables and
+                # the new rows block-append, all inside the launch
                 src = self._dispatch_ragged(order, R, dev, table_ids)
-        else:
-            if paged:
-                with span(SPAN_DECODE_KV_GATHER):
-                    kv_in = self.kv_store.gather(table_ids[KV_KIND_FULL])
             else:
-                kv_in = self.kv
-            token_d, pos_d, active_d, sp = dev
-            args = (
-                self.eng.window_params,
-                self.eng.edge_params,
-                token_d,
-                kv_in,
-                pos_d,
-                active_d,
-                sp,
-                self.keys,
-                self.counts,
-            )
-            with span(SPAN_DECODE_LAUNCH, R=R, lanes=lanes):
-                if R > 1:
-                    src, kv_out, self.counts, self.keys = self._chunk_fn(R)(*args)
-                else:
-                    src, kv_out, self.counts, self.keys = self._step(*args)
-            if paged:
-                # persist ONLY the blocks this step wrote (block-append
-                # write); the contiguous view kv_out is scratch and dies here
-                with span(SPAN_DECODE_KV_SCATTER):
-                    bt = self._kv_cfg.block_tokens
-                    triples = []
-                    for _nonce, slot in order.items():
-                        p0 = int(self.pos[slot])
-                        tbl = self._tables[slot]
-                        triples.extend(
-                            (slot, b, tbl.blocks[b])
-                            for b in range(p0 // bt, (p0 + R - 1) // bt + 1)
-                        )
-                    self.kv_store.scatter(kv_out, triples)
-            else:
-                self.kv = kv_out
+                token_d, pos_d, active_d, sp = dev
+                step = self._chunk_fn(R) if R > 1 else self._step
+                src, self.kv, self.counts, self.keys = step(
+                    self.eng.window_params, self.eng.edge_params, token_d,
+                    self.kv, pos_d, active_d, sp, self.keys, self.counts,
+                )
         # ONE packed device->host read per field per dispatch (the
         # pipelined engine's drain pattern), then host-side slicing —
         # per-element device gathers would reintroduce the dispatch
@@ -1073,7 +1009,7 @@ class BatchedEngine:
             lps = np.asarray(src.logprob)
             tts = np.asarray(src.top_tokens)
             tlps = np.asarray(src.top_logprobs)
-            if self.kv_ragged and self._moe_reported:
+            if self.kv_pool is not None and self._moe_reported:
                 # summed on the device by the dispatch just read: no sync
                 mine, elsewhere = np.asarray(self._moe_pending)
                 _MOE_ASSIGNMENTS.labels(held="yes").inc(int(mine))
@@ -1222,17 +1158,17 @@ class BatchedEngine:
                 self._extend_window_tables(order, errors, active, R)
                 if not order:
                     return None
-            table_ids = self._table_ids(order if R == 1 else None)
-            if self.kv_ragged:
-                table_ids = jax.tree.map(jnp.asarray, table_ids)
+            table_ids = jax.tree.map(
+                jnp.asarray, self._table_ids(order if R == 1 else None)
+            )
         dev = (jnp.asarray(token), jnp.asarray(pos), jnp.asarray(active), sp)
         return order, R, dev, table_ids
 
     def _dispatch_ragged(self, order: Dict[str, int], R: int, dev, tables):
-        """One ragged decode dispatch (R == 1: the read-only paged_attend
-        program + the jitted kv_append block-append; R > 1: the fused
-        chunk carrying the donated pool).  All of it is the launch span —
-        no gather, no scatter on this path."""
+        """One decode dispatch over the pool (R == 1: the read-only
+        paged_attend program + the jitted kv_append block-append; R > 1:
+        the fused chunk carrying the donated pool).  All of it is the
+        launch span."""
         token_d, pos_d, active_d, sp = dev
         args = (
             self.eng.window_params,
@@ -1361,13 +1297,11 @@ class BatchedEngine:
         self.end_session("__warm__")
         widths = 1 + len(self.CHUNK_BUCKETS)
         if self.kv_pool is not None:
-            # R==1 dispatches gather at the pow2 bucket of the widest
+            # R==1 dispatches attend at the pow2 bucket of the widest
             # ACTIVE table (_table_ids): compile the step at every bucket
             # width now, with a throwaway session grown into each bucket,
             # so the first long-context request doesn't stall the whole
             # batch loop on a mid-flight width compile
-            from dnet_tpu.kv import KVPoolExhausted
-
             bt = self._kv_cfg.block_tokens
             nb_full = self.max_seq // bt
             # bucket ladder: pow2 widths, plus the clamped full width when

@@ -30,15 +30,6 @@ from dnet_tpu.core.sampler import (
     sample,
 )
 from dnet_tpu.core.types import DecodingParams, TokenResult
-from dnet_tpu.kv import (
-    BlockPool,
-    BlockStore,
-    KVPoolExhausted,
-    PagedKVConfig,
-    PagedPrefixCache,
-    PageTable,
-    paged_enabled,
-)
 from dnet_tpu.models import ModelConfig, get_ring_model_cls
 from dnet_tpu.obs import get_recorder, metric
 from dnet_tpu.obs.jit import instrument_jit
@@ -80,10 +71,6 @@ class Session:
     # at position i (whose KV landed in slot i).  None unless the engine
     # was built with spec_lookahead > 0.
     hist: jax.Array = None  # [B, max_seq] int32
-    # paged KV (DNET_KV_PAGED=1): this session's block ledger in the
-    # engine's BlockPool — admission/extension debit free blocks as pos
-    # grows instead of pinning max_seq rows up front (kv/paged.py)
-    pages: object = None
     # draft-MODEL speculation: the small model's own KV cache (None unless
     # the engine was built with draft_dir)
     dkv: dict = None
@@ -123,7 +110,6 @@ class LocalEngine:
         prefix_cache_size: int = 0,
         spec_lookahead: int = 0,
         draft_dir: Optional[str | Path] = None,
-        kv_paged: Optional[bool] = None,
     ):
         self.ckpt = Checkpoint(model_dir)
         self.config = ModelConfig.from_hf(self.ckpt.config)
@@ -157,21 +143,6 @@ class LocalEngine:
         self._repack_dir = repack_dir
         self.weight_cache = None
         self._windows: list[list[int]] = []
-        # paged KV (kv/paged.py): the pool is this engine's admission
-        # ledger — sessions debit blocks as their pos grows instead of
-        # pinning max_seq rows, and exhaustion raises KVPoolExhausted (a
-        # queueable backpressure signal) before any compute burns
-        self.kv_pool = None
-        self._kv_paged_cfg = None
-        want_paged = paged_enabled() if kv_paged is None else bool(kv_paged)
-        if want_paged:
-            if shard_mode:
-                log.warning(
-                    "paged KV not supported for shard engines (the ring "
-                    "runtime owns shard admission); serving the dense path"
-                )
-            else:
-                self._init_paged(slots=8 + prefix_cache_size)
         self.prefix_cache = None
         if prefix_cache_size > 0:
             if self.plan.streams_weights or shard_mode:
@@ -181,7 +152,9 @@ class LocalEngine:
                     "weight-streaming" if self.plan.streams_weights else "shard",
                 )
             else:
-                self.prefix_cache = self._build_prefix_cache(prefix_cache_size)
+                from dnet_tpu.core.prefix_cache import PrefixCache
+
+                self.prefix_cache = PrefixCache(prefix_cache_size)
 
         # observability sync knobs (reference core/observability.py:31-107:
         # forced mx.eval sync points; here block_until_ready fences): without
@@ -252,7 +225,6 @@ class LocalEngine:
         kv_quant_bits: int = 0,
         kv_ttl_s: float = 600.0,
         spec_lookahead: int = 0,
-        kv_paged: Optional[bool] = None,
     ) -> "LocalEngine":
         """Build an engine around already-materialised parameters (no
         checkpoint on disk) — the zero-egress bench path: the serving hot
@@ -281,78 +253,12 @@ class LocalEngine:
         self._windows = []
         self.prefix_cache = None
         self.draft = None
-        self.kv_pool = None
-        self._kv_paged_cfg = None
-        if paged_enabled() if kv_paged is None else bool(kv_paged):
-            self._init_paged(slots=8)
         self.window_params = jax.tree.map(jnp.asarray, window_params)
         self.edge_params = jax.tree.map(jnp.asarray, edge_params)
         self._sync_per_layer = False
         self._sync_every_n = 0
         self._build_fns()
         return self
-
-    # ---- paged KV ------------------------------------------------------
-    def _init_paged(self, slots: int) -> None:
-        """Build this engine's BlockPool admission ledger (DNET_KV_PAGED=1).
-        `slots` only feeds the auto pool size when DNET_KV_POOL_BLOCKS=0
-        — how many max_seq sequences' worth of blocks to provision."""
-        try:
-            cfg = PagedKVConfig.from_settings(self.max_seq, slots=max(slots, 1))
-        except ValueError as exc:
-            log.warning("paged KV disabled (%s); serving the dense path", exc)
-            return
-        self._kv_paged_cfg = cfg
-        self.kv_pool = BlockPool(cfg)
-        log.info(
-            "paged KV on: %d blocks x %d tokens (%s sequences' worth)",
-            cfg.pool_blocks, cfg.block_tokens,
-            cfg.pool_blocks * cfg.block_tokens // self.max_seq,
-        )
-
-    def _build_prefix_cache(self, capacity: int):
-        """Dense PrefixCache, or the block-sharing PagedPrefixCache when
-        the paged pool is on (same lookup/store surface; snapshots dedup
-        shared prefixes into refcounted block runs instead of deep copies)."""
-        from dnet_tpu.core.prefix_cache import PrefixCache
-
-        if self.kv_pool is None:
-            return PrefixCache(capacity)
-        if self.batch != 1:
-            log.warning(
-                "paged prefix sharing needs batch=1 sessions; "
-                "using dense snapshots"
-            )
-            return PrefixCache(capacity)
-        try:
-            store = BlockStore(
-                self.model, len(self.model.layers), self._kv_paged_cfg,
-                self.kv_dtype, quant_bits=self.kv_quant_bits,
-                session_tokens=self.max_seq,
-            )
-        except NotImplementedError as exc:
-            log.warning(
-                "paged prefix sharing unavailable (%s); using dense "
-                "snapshots", exc,
-            )
-            return PrefixCache(capacity)
-        return PagedPrefixCache(
-            self.kv_pool, store, capacity, row_tokens=self.max_seq
-        )
-
-    def _paged_ensure(self, sess: "Session", n_tokens: int) -> None:
-        """Admit/extend a session to cover n_tokens: debit the pool for any
-        blocks its ledger is missing.  Raises KVPoolExhausted (typed
-        backpressure) BEFORE any compute — never a shape error mid-step."""
-        if self.kv_pool is None:
-            return
-        if sess.pages is None:
-            sess.pages = PageTable()
-        self.kv_pool.ensure(sess.pages, min(n_tokens, self.max_seq))
-
-    def _paged_release(self, sess: Optional["Session"]) -> None:
-        if self.kv_pool is not None and sess is not None:
-            self.kv_pool.release_table(sess.pages)
 
     # ---- loading ------------------------------------------------------
     def _cast(self, tree):
@@ -756,22 +662,18 @@ class LocalEngine:
         return sess
 
     def end_session(self, nonce: str) -> None:
-        self._paged_release(self.sessions.pop(nonce, None))
+        self.sessions.pop(nonce, None)
 
     def sweep_sessions(self) -> int:
         now = time.time()
         dead = [n for n, s in self.sessions.items() if now - s.last_used > self.kv_ttl_s]
         for n in dead:
-            # the TTL sweep is the paged pool's garbage collector too: an
-            # abandoned session's blocks return to the free list
-            self._paged_release(self.sessions.pop(n))
+            del self.sessions[n]
         if dead:
             _SESS_EVICTED.inc(len(dead))
         return len(dead)
 
     def reset(self) -> None:
-        for sess in self.sessions.values():
-            self._paged_release(sess)
         self.sessions.clear()
 
     def close(self) -> None:
@@ -822,15 +724,6 @@ class LocalEngine:
         else:
             fresh = sess.pos == 0  # explicit chunked continuation
         T = len(prompt_ids)
-        if self.kv_pool is not None:
-            try:
-                # admit BEFORE the forward: exhaustion must cost nothing and
-                # must not leave a half-written cache behind
-                self._paged_ensure(sess, sess.pos + T)
-            except KVPoolExhausted:
-                if fresh:
-                    self.end_session(nonce)
-                raise
         self._commit_prompt_hist(sess, full_ids, prompt_ids)
         # the PADDED width must also fit — dynamic_update_slice would clamp
         # the start index and silently shift the whole KV write otherwise
@@ -971,7 +864,6 @@ class LocalEngine:
             raise ValueError(
                 f"sequence length {sess.pos} reached max_seq {self.max_seq}"
             )
-        self._paged_ensure(sess, sess.pos + 1)  # may raise KVPoolExhausted
         t_step = time.perf_counter()
         sess.key, step_key = jax.random.split(sess.key)
         sp = SampleParams.from_decoding(decoding)
@@ -1084,14 +976,6 @@ class LocalEngine:
                 f"sequence length {sess.pos} reached max_seq {self.max_seq}"
             )
         budget = min(max_new, self.max_seq - sess.pos)
-        if self.kv_pool is not None and sess.pos + L + 1 <= self.max_seq:
-            try:
-                # the verify block writes L+1 positions; a pool that cannot
-                # cover them degrades to a plain step (whose own admission
-                # raises the definitive backpressure error)
-                self._paged_ensure(sess, sess.pos + L + 1)
-            except KVPoolExhausted:
-                budget = 1
         if budget <= 1 or sess.pos + L + 1 > self.max_seq:
             # no room to speculate: one plain step keeps the stream moving
             tid = (
@@ -1173,14 +1057,6 @@ class LocalEngine:
         K = next((b for b in self.DECODE_CHUNK_BUCKETS if b <= budget), 1)
         if K == 1 or self.plan.streams_weights:
             return 0
-        if self.kv_pool is not None:
-            try:
-                self._paged_ensure(sess, sess.pos + K)
-            except KVPoolExhausted:
-                # graceful degradation: an un-extendable chunk falls back to
-                # single steps, whose own admission raises the definitive
-                # backpressure error if even one block is unavailable
-                return 0
         if token_id is None:
             if sess.last_token is None:
                 raise RuntimeError("no device-resident token to chain from")

@@ -21,6 +21,19 @@ def now_ms() -> float:
     return time.time() * 1000.0
 
 
+class InferenceError(Exception):
+    """Base of the typed serving errors (api/inference.py holds the rest;
+    api/http.py maps each to a status)."""
+
+
+class EngineCapabilityError(InferenceError):
+    """The engine cannot serve the requested configuration — continuous
+    batching over streamed weights, or a model without gated KV writes
+    (raised by core/batch.py at LOAD time): maps to HTTP 422, an
+    operator/config error, not a generic 500.  Defined here, below both
+    layers, so that core/ raises it without importing api/."""
+
+
 @dataclass
 class DecodingParams:
     """Per-request sampling knobs carried alongside every token injection.

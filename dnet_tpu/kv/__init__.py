@@ -2,11 +2,12 @@
 copy-on-write prefix sharing, and free-block admission.
 
 Host half: `paged.py` (BlockPool / PageTable / KVPoolExhausted).
-Device half: `store.py` (pool-shaped arrays + gather/scatter programs).
+Device half: `store.py` (pool-shaped arrays, attended in place, + the
+row gather / block commit programs of prefix restore and adoption).
 Sharing: `prefix.py` (PagedPrefixCache over the same pool).
 
-Enabled per-engine via DNET_KV_PAGED=1 (config.KVSettings); the dense
-preallocated path stays the default.
+The batched engine's KV layout wherever the model and the cache allow it
+(core/batch.py: kv_layout); dense slots serve the rest.
 """
 
 from dnet_tpu.kv.paged import (
@@ -15,8 +16,6 @@ from dnet_tpu.kv.paged import (
     PagedKVConfig,
     PageTable,
     ceil_div,
-    paged_enabled,
-    ragged_enabled,
     window_blocks,
     window_first_block,
 )
@@ -32,8 +31,6 @@ __all__ = [
     "PagedPrefixCache",
     "PageTable",
     "ceil_div",
-    "paged_enabled",
-    "ragged_enabled",
     "window_blocks",
     "window_first_block",
 ]

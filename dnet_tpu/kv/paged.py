@@ -12,8 +12,7 @@ This module is the host-side half: a `BlockPool` (allocation, refcounts,
 exact accounting, typed backpressure) and per-sequence `PageTable`s
 mapping logical block index -> physical pool block.  The device half
 (`kv/store.py`) holds the pool-shaped cache arrays and the jitted
-gather/scatter programs that compose with the existing functional cache
-ops.  Everything here is plain Python under one lock: allocator decisions
+programs that attend, append to and commit into them.  Everything here is plain Python under one lock: allocator decisions
 are control flow, never traced.
 
 Invariants (enforced by `check_conservation`, linted from tier-1 via
@@ -81,8 +80,8 @@ class PagedKVConfig:
     def from_settings(cls, max_seq: int, slots: int = 1) -> "PagedKVConfig":
         """Resolve block/pool sizing from KVSettings; pool_blocks=0 auto-
         sizes to the dense equivalent (slots x max_seq worth of blocks), so
-        flipping DNET_KV_PAGED=1 alone never ADMITS less than dense did —
-        the wins come from sharing and variable lengths."""
+        the pool never ADMITS less than dense slots would — the wins come
+        from sharing and variable lengths."""
         from dnet_tpu.config import get_settings
 
         kv = get_settings().kv
@@ -345,27 +344,3 @@ class BlockPool:
                     f"paged pool refcounts {refs} != holder counts {counts}"
                 )
 
-
-def paged_enabled() -> bool:
-    """THE flag gate: DNET_KV_PAGED=1 (KVSettings.paged).  A raw env read
-    (config.env_flag, the sanctioned DL006 escape hatch) backs the
-    settings value so tests toggling os.environ after the settings cache
-    warmed still see the flip."""
-    from dnet_tpu.config import env_flag, get_settings
-
-    if get_settings().kv.paged:
-        return True
-    return env_flag("DNET_KV_PAGED")
-
-
-def ragged_enabled() -> bool:
-    """DNET_KV_RAGGED=1 (KVSettings.ragged): decode attends the block pool
-    in place (ops/paged_attention.py) instead of the gather->step->scatter
-    sandwich.  Only meaningful under paged KV; eligibility is refined per
-    engine (ops.paged_attention.ragged_refusal).  Same env_flag backing as
-    paged_enabled for post-cache test flips."""
-    from dnet_tpu.config import env_flag, get_settings
-
-    if get_settings().kv.ragged:
-        return True
-    return env_flag("DNET_KV_RAGGED")

@@ -12,10 +12,9 @@ resolves hits to refcounted BLOCK RUNS in the pool instead:
 - **adoption** (`lookup_blocks`): the batched engine's page tables alias
   an entry's full blocks directly — no copy at all; the partial tail
   block (a request diverging mid-block) is COW-copied by the adopter.
-- **dense facade** (`lookup`/`store`): the same (n_tokens, kv_row)
-  surface as `PrefixCache`, so `LocalEngine.prefill`'s hit/store flow
-  runs unchanged — restores gather a private dense row out of the pool
-  (its working cache is dense), while stores still dedup block-level.
+- **staged store** (`store`): a prompt still staging on the inner B=1
+  engine (chunked prefill) snapshots out of its dense row, committing
+  only the tail blocks a parent entry doesn't already hold.
 
 Entry eviction releases the entry's references through PrefixIndex's
 `on_evict` hook; the blocks themselves live until the last page table
@@ -64,13 +63,9 @@ class PagedPrefixCache:
         store: BlockStore,
         capacity: int,
         min_tokens: int = 16,
-        row_tokens: int = 0,
     ) -> None:
         self.pool = pool
         self._dev = store
-        # dense-facade restores pad the gathered row to this width (the
-        # consuming engine's max_seq); 0 = facade unused (batched aliasing)
-        self.row_tokens = row_tokens
         self._index = PrefixIndex(
             capacity, min_tokens, kind="prefix", on_evict=self._release
         )
@@ -133,21 +128,7 @@ class PagedPrefixCache:
         self.stats["stores"] += 1
         return True
 
-    # ---- dense facade (LocalEngine's PrefixCache surface) --------------
-    def lookup(self, prompt_ids: Sequence[int]) -> Optional[Tuple[int, dict]]:
-        """(n_tokens, private dense kv row) — gathers the hit's blocks out
-        of the pool into a fresh [L, 1, row_tokens, ...] buffer."""
-        hit = self.lookup_blocks(prompt_ids)
-        if hit is None:
-            return None
-        n, blocks, _n_full = hit
-        try:
-            kv_row = self._dev.gather_row(blocks, self.row_tokens)
-        finally:
-            # the gather copied the contents; the restore owns nothing
-            self.pool.free_blocks(blocks)
-        return n, kv_row
-
+    # ---- staged store (a prompt still on the inner B=1 engine) --------
     def store(self, prompt_ids: Sequence[int], kv_row: dict) -> None:
         """Snapshot a dense session row, committing only the tail blocks a
         parent entry doesn't already hold (block-level dedup)."""

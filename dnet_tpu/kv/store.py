@@ -1,41 +1,37 @@
 """Device half of the paged KV subsystem: pool-shaped cache arrays plus
-the jitted page-table gather / block-scatter programs.
+the jitted programs that read and write them.
 
 The pool reuses the existing functional cache layout with the BATCH axis
 repurposed as the block axis: `model.init_kv(L, pool_blocks, block_tokens)`
 yields `[L, N_blocks, block_tokens, KVH, Hd]` leaves (quantized caches
 bring their scale leaves along for free, since every op here is a
-jax.tree.map).  Composition with the engines:
+jax.tree.map).
 
-- **gather** builds the contiguous per-slot view the existing decode
-  programs (`apply_window` -> `write_kv`/`cached_attend`) consume: one
-  `pool[:, ids]` take per leaf — `batched_gather_cache`'s trick applied to
-  the block axis — reshaped to `[L, slots, nb*bt, ...]`.  Unallocated
-  table entries clamp to block 0; their rows sit at positions the causal
-  mask excludes, so exp() zeroes them EXACTLY and the result is
-  bit-identical to the dense path.
-- **scatter** writes back only the blocks a step actually touched (the
-  block-append write replacing dense `write_kv` persistence): the touched
-  rows are sliced out of the dense view and `.at[:, phys].set` into the
-  pool, with the pool buffers DONATED so XLA updates in place.
+The decode path (core/batch.py `_build_ragged`) attends the pool IN PLACE
+and speaks to a store by KIND of layer (obs/phases.py KV_KINDS), knowing
+no layout: `kinds`, `attend`, `append_in_program`, `append_rows` and
+`commit_staged` take and return `{kind: ...}`.  `BlockStore` is the
+one-kind store (`full` alone, the layout above, which the prefix cache
+also reads); `KindStore` holds a pool a kind for a model that mixes window
+and full layers.
 
-Scatter widths are bucketed to powers of two (padding repeats the last
-triple — duplicate scatters of identical content are deterministic) so
-the compiled-program set stays bounded, the same discipline as the
-engines' chunk buckets.
+Two programs move whole blocks between a staged dense row and the pool:
 
-The ragged decode path (core/batch.py `_build_ragged`) speaks to a store by
-KIND of layer (obs/phases.py KV_KINDS) and knows no layout: `kinds`,
-`attend`, `append_in_program`, `append_rows` and `commit_staged` take and
-return `{kind: ...}`.  `BlockStore` is the one-kind store (`full` alone,
-the layout above, which the dense-gather view and the prefix cache also
-read); `KindStore` holds a pool a kind for a model that mixes window and
-full layers.
+- **gather_row** (prefix restore) builds one sequence's contiguous
+  `[L, 1, nb*bt, ...]` row: one `pool[:, ids]` take per leaf.  Table
+  entries past the sequence clamp to block 0; their rows sit at positions
+  the causal mask excludes, so exp() zeroes them EXACTLY.
+- **commit_row** (adoption, prefix store) writes a staged row's blocks
+  into the pool with `.at[:, phys].set`, the pool buffers DONATED so XLA
+  updates in place.  Widths are bucketed to powers of two (padding repeats
+  the last block — duplicate writes of identical content are
+  deterministic) so the compiled-program set stays bounded, the same
+  discipline as the engines' chunk buckets.
 """
 
 from __future__ import annotations
 
-from typing import List, Tuple
+from typing import List
 
 import jax
 import jax.numpy as jnp
@@ -60,7 +56,7 @@ def _bucket_pow2(n: int) -> int:
 
 
 class BlockStore:
-    """Pool-shaped KV arrays + cached gather/scatter programs."""
+    """Pool-shaped KV arrays + their cached programs."""
 
     def __init__(
         self,
@@ -135,9 +131,7 @@ class BlockStore:
             return jax.tree.map(one, pool, dense)
 
         # instrumented: a page-table geometry leak re-tracing these per
-        # step shows as climbing dnet_jit_compiles_total{fn=kv_*} (gather
-        # widths are pow2-bucketed by the engines, so the compiled-program
-        # set stays bounded — see BatchedEngine._table_ids)
+        # call shows as climbing dnet_jit_compiles_total{fn=kv_*}
         self._gather = instrument_jit(jax.jit(gather), "kv_gather")
         self._scatter = instrument_jit(
             jax.jit(scatter, donate_argnums=(0,)), "kv_scatter"
@@ -187,11 +181,6 @@ class BlockStore:
         self.commit_row(kv_row, *blocks[KV_KIND_FULL])
 
     # ---- ops ----------------------------------------------------------
-    def gather(self, ids: np.ndarray) -> dict:
-        """Contiguous [L, slots, nb*bt, ...] view of the tables in `ids`
-        ([slots, nb], -1/unallocated entries already clamped to 0)."""
-        return self._gather(self.kv, jnp.asarray(ids, dtype=jnp.int32))
-
     def gather_row(self, blocks: List[int], width_tokens: int) -> dict:
         """One sequence's blocks as a [L, 1, width_tokens, ...] dense row
         (padded with clamped block 0 beyond the table — rows the causal
@@ -200,23 +189,7 @@ class BlockStore:
         assert width_tokens % bt == 0
         ids = np.zeros((1, width_tokens // bt), dtype=np.int32)
         ids[0, : len(blocks)] = blocks
-        return self.gather(ids)
-
-    def scatter(
-        self,
-        dense: dict,
-        triples: List[Tuple[int, int, int]],
-    ) -> None:
-        """Persist touched blocks: triples of (slot, logical_block, phys).
-        Pads to a power-of-two width by repeating the last triple."""
-        if not triples:
-            return
-        K = _bucket_pow2(len(triples))
-        padded = list(triples) + [triples[-1]] * (K - len(triples))
-        slot_idx = jnp.asarray([t[0] for t in padded], dtype=jnp.int32)
-        block_idx = jnp.asarray([t[1] for t in padded], dtype=jnp.int32)
-        phys = jnp.asarray([t[2] for t in padded], dtype=jnp.int32)
-        self.kv = self._scatter(self.kv, dense, slot_idx, block_idx, phys)
+        return self._gather(self.kv, jnp.asarray(ids, dtype=jnp.int32))
 
     def append_rows(self, rows: dict, phys: dict, off) -> None:
         """Ragged-decode block append: one new token row per slot, written
@@ -237,12 +210,20 @@ class BlockStore:
         phys_blocks: List[int],
     ) -> None:
         """Persist blocks of a single-sequence dense row ([L, 1, S, ...]):
-        logical block index i of the row -> pool block phys_blocks[i]."""
-        self.scatter(
-            kv_row,
-            [(0, lb, pb) for lb, pb in zip(logical_blocks, phys_blocks)],
+        logical block index i of the row -> pool block phys_blocks[i].
+        Pads to a power-of-two width by repeating the last pair."""
+        if not logical_blocks:
+            return
+        K = _bucket_pow2(len(logical_blocks))
+        pad = K - len(logical_blocks)
+        lb = list(logical_blocks) + [logical_blocks[-1]] * pad
+        pb = list(phys_blocks) + [phys_blocks[-1]] * pad
+        self.kv = self._scatter(
+            self.kv, kv_row,
+            jnp.asarray([0] * K, dtype=jnp.int32),  # the row's one slot
+            jnp.asarray(lb, dtype=jnp.int32),
+            jnp.asarray(pb, dtype=jnp.int32),
         )
-
 
 
 class KindStore:
@@ -254,8 +235,7 @@ class KindStore:
     everything.  Heads are merged into the lane dimension ONCE, here: the
     ragged kernel (ops/paged_attention.py) reads a block as `[bt, KVH*Hd]`
     and takes the layer by index, so no slice or relayout of a pool is made
-    per step.  Only the ragged path reads these pools: there is no dense
-    gather view of a table that gave blocks back.
+    per step.
 
     `self.kv` is `{kind: {"k": ..., "v": ...}}`; `self.layers[kind]` the
     local layer indices of the kind, in order."""

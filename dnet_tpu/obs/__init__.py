@@ -504,7 +504,7 @@ def _register_core(reg: MetricsRegistry) -> None:
     )
     for kind in ZOMBIE_THREAD_KINDS:
         zombies.labels(thread=kind)  # pre-touch: the lint checks these
-    # iteration-level scheduler (dnet_tpu/sched/, DNET_SCHED=1).  State /
+    # iteration-level scheduler (dnet_tpu/sched/).  State /
     # kind / reason label sets are DECLARED in sched/kinds.py (a leaf
     # module, like admission/reasons.py) and cross-checked both ways by
     # the metrics lint (pass 10).

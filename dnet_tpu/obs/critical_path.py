@@ -19,7 +19,7 @@ and each elementary slice goes to the most specific span covering it.
 `hop_rtt` (send->resolve, which contains the remote shard's whole story)
 outranks it; shard compute / prefill outrank the hop; leaf work (codec
 encode, stream writes, SSE flushes) and queue waits outrank everything.
-Under DNET_SCHED=1 the scheduler's own stamps (sched/engine.py) emit
+Under the scheduler its own stamps (sched/engine.py) emit
 `sched_queue` (enqueue to first prefill chunk) and a `prefill` span of the
 real wall time (first chunk to first token), so admission_wait +
 sched_queue + prefill_compute is the time to first token.  Because the

@@ -18,10 +18,8 @@ from __future__ import annotations
 #   dnet.tick                 sched/step.py execute_tick, compute thread
 #     dnet.tick.decode        around engine.decode_batch
 #       dnet.decode.prepare     buffer pops, numpy rows, uploads, table ids
-#       dnet.decode.kv_gather   dense-gather paged path only (enqueue)
 #       dnet.decode.launch      the jitted step/chunk call (+ kv_append):
 #                               an ENQUEUE (and a compile, if one happens)
-#       dnet.decode.kv_scatter  dense-gather paged path only (enqueue)
 #       dnet.decode.readback    the np.asarray reads: host blocked until the
 #                               device finishes the dispatch
 #       dnet.decode.unpack      SampleResult slicing
@@ -37,9 +35,7 @@ from __future__ import annotations
 SPAN_TICK = "dnet.tick"
 SPAN_TICK_DECODE = "dnet.tick.decode"
 SPAN_DECODE_PREPARE = "dnet.decode.prepare"
-SPAN_DECODE_KV_GATHER = "dnet.decode.kv_gather"
 SPAN_DECODE_LAUNCH = "dnet.decode.launch"
-SPAN_DECODE_KV_SCATTER = "dnet.decode.kv_scatter"
 SPAN_DECODE_READBACK = "dnet.decode.readback"
 SPAN_DECODE_UNPACK = "dnet.decode.unpack"
 SPAN_TICK_PREFILL = "dnet.tick.prefill"
@@ -52,9 +48,7 @@ SPAN_SSE_FLUSH = "dnet.api.sse_flush"
 # decode table and the reconciliation tests sum these against the parent)
 DECODE_CHILD_SPANS = (
     SPAN_DECODE_PREPARE,
-    SPAN_DECODE_KV_GATHER,
     SPAN_DECODE_LAUNCH,
-    SPAN_DECODE_KV_SCATTER,
     SPAN_DECODE_READBACK,
     SPAN_DECODE_UNPACK,
 )

@@ -1,16 +1,11 @@
 """Ragged paged attention: decode attends the KV block pool IN PLACE.
 
-The paged subsystem (kv/) gave admission and sharing block granularity, but
-PR 3 kept the compute dense: every decode tick gathers each slot's page
-table into a contiguous per-slot view (`pool[:, ids]` at full width),
-steps the existing attention programs over it, and scatters the touched
-blocks back.  The host spans of that round trip —
-`dnet_span_ms{span=dnet.decode.kv_gather|dnet.decode.kv_scatter}` — exist
-only on that path, and "Ragged Paged
-Attention" (PAPERS.md, arxiv 2604.15464) names the TPU-native fix this
-module implements: an attention program that consumes the pool-shaped
+The paged subsystem (kv/) keeps the cache as blocks of a shared pool
+behind per-sequence page tables.  "Ragged Paged Attention" (PAPERS.md,
+arxiv 2604.15464) names the TPU-native way to read it, which this module
+implements: an attention program that consumes the pool-shaped
 `[N_blocks, bt, KVH, Hd]` arrays and the `[slots, nb]` int32 page tables
-DIRECTLY, so the per-slot view never exists.
+DIRECTLY, so a contiguous per-slot view never exists.
 
 The kernel is the split-K online-softmax fold of `ops/flash_decode.py`
 with the page table as the scalar-prefetched block index map: grid
@@ -35,14 +30,14 @@ Three implementations behind one dispatcher (`paged_attend`):
 - ``emulate``    — a plain-jnp twin for CPU backends, where interpret mode
   is too slow to serve: gather the table's blocks (already width-bounded
   by the caller's pow2 bucket), write the new row at `pos`, and run the
-  shared dense `attend` — the same operation order as the dense-gather
-  path, so greedy streams stay byte-identical, fused into the step
-  program with no separate gather dispatch and NO scatter at all.
+  shared dense `attend` — the same operation order as the dense slot
+  cache, so greedy streams stay byte-identical, fused into the step
+  program.
 
-The caller (core/batch.py) owns eligibility via `ragged_refusal`: the
-llama-family attention stack (supports_paged_attend), unquantized pool
-leaves, and a flat block layout.  Everything else keeps the dense-gather
-fallback.
+The caller (core/batch.py: kv_layout) owns eligibility via
+`ragged_refusal`: the llama-family attention stack
+(supports_paged_attend) and unquantized pool leaves.  Everything else
+serves dense slots.
 """
 
 from __future__ import annotations
@@ -54,6 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from dnet_tpu.obs.phases import KV_KIND_FULL
 from dnet_tpu.ops.kernel_select import SELECTIONS, kernel_backend
 
 NEG_INF = -1e30
@@ -74,19 +70,21 @@ def paged_attend_impl() -> str:
 
 
 def ragged_refusal(model, kv_quant_bits: int) -> Optional[str]:
-    """Why this engine cannot route decode through the ragged program
-    (None = eligible).  Mirrors BlockStore's session-layout refusals: the
-    dense-gather path stays correct for everything refused here."""
+    """Why decode cannot attend the block pool in place for this model and
+    cache (None = it can); such an engine serves dense slots
+    (core/batch.py: kv_layout)."""
     if not getattr(model, "supports_paged_attend", False):
         return (
             f"{model.config.model_type} attention stack has no paged-attend "
-            "hook (non-llama-family layers stay on dense gather)"
+            "hook"
         )
     if kv_quant_bits:
         return (
-            f"quantized KV cache (bits={kv_quant_bits}) dequantizes through "
-            "the dense gather path"
+            f"quantized KV cache (bits={kv_quant_bits}): the kernel reads "
+            "unquantized blocks"
         )
+    if KV_KIND_FULL not in (getattr(model, "paged_kinds", None) or (KV_KIND_FULL,)):
+        return "no full layer among the window layers"
     return None
 
 
@@ -260,8 +258,8 @@ def _paged_emulate(q, k_pool, v_pool, tables, pos, k_new, v_new,
     (width already bounded by the caller's pow2 table bucket), write the
     new row at `pos` exactly like the dense path's write_kv, and attend
     with the causal-at-pos mask through the SAME dense `attend` the
-    gather path bottoms out in — one fused program, no separate gather
-    dispatch, no scatter.  CPU backends serve through this; interpret mode
+    dense slot cache bottoms out in — one fused program.  CPU backends
+    serve through this; interpret mode
     and TPU run the kernel.  View row j of slot b sits at absolute
     position base[b] * bt + j (`base` None = 0)."""
     from dnet_tpu.ops.attention import attend

@@ -50,11 +50,6 @@ class MeshEngine:
     end_session = LocalEngine.end_session
     sweep_sessions = LocalEngine.sweep_sessions
     reset = LocalEngine.reset
-    # paged KV is a Local/Batched engine feature (mesh caches are sharded);
-    # the borrowed session/decode drivers consult these and no-op
-    kv_pool = None
-    _paged_ensure = LocalEngine._paged_ensure
-    _paged_release = LocalEngine._paged_release
     # chunked-scan decode: the ring chunk program (make_ring_chunk_fn) keeps
     # LocalEngine's (packed, last_token, kv, key, counts) contract, so the
     # dispatch/read/pipelining machinery is borrowed verbatim — one

@@ -1,4 +1,4 @@
-"""Iteration-level continuous-batching scheduler (DNET_SCHED=1).
+"""Iteration-level continuous-batching scheduler.
 
 One serving engine for mixed prefill + decode: each tick packs a token
 budget of chunked-prefill segments and one decode step per running
@@ -25,7 +25,6 @@ _EXPORTS = {
     "SchedQueue": "dnet_tpu.sched.queue",
     "SchedRequest": "dnet_tpu.sched.queue",
     "SchedulerAdapter": "dnet_tpu.sched.engine",
-    "sched_enabled": "dnet_tpu.sched.engine",
     "TickResult": "dnet_tpu.sched.step",
     "execute_tick": "dnet_tpu.sched.step",
 }

@@ -1,6 +1,7 @@
-"""SchedulerAdapter: the iteration-level tick loop behind DNET_SCHED=1.
+"""SchedulerAdapter: the iteration-level tick loop that serves every
+BatchedEngine load (api/model_manager.py: serving_plan).
 
-One adapter replaces the kick-coalescing BatchedLocalAdapter AND the
+One adapter in place of the kick-coalescing BatchedLocalAdapter AND the
 monolithic per-request prefill: every tick the policy packs a token
 budget of chunked-prefill segments plus one decode step per running
 sequence into a single :class:`~dnet_tpu.sched.policy.TickPlan`, the
@@ -55,18 +56,6 @@ _PREFILL_TICKS = metric("dnet_sched_prefill_ticks")
 _DELIVER_WAIT_MS = metric("dnet_sched_deliver_wait_ms")
 
 
-def sched_enabled() -> bool:
-    """THE flag gate: DNET_SCHED=1 (SchedSettings.sched).  A raw env read
-    (config.env_flag, the sanctioned DL006 escape hatch) backs the
-    settings value so tests toggling os.environ after the settings cache
-    warmed still see the flip — the same contract as kv.paged_enabled."""
-    from dnet_tpu.config import env_flag, get_settings
-
-    if get_settings().sched.sched:
-        return True
-    return env_flag("DNET_SCHED")
-
-
 #: how long a plan waits for the drivers the last tick handed a token to
 #: ask for the next one: two or three turns of the event loop in practice
 #: (future -> driver task -> send_tokens), so the bound only matters for a
@@ -82,8 +71,7 @@ class SchedulerAdapter(ApiAdapterBase):
     (``reserve_slot`` / ``seed_from_prefix`` / ``prefill_chunk`` /
     ``adopt_prefilled`` / ``decode_batch`` + slot lifecycle).  Engines
     without it (PipelinedMeshEngine prefills in one ring pass) keep the
-    legacy BatchedLocalAdapter — model_manager falls back with a
-    warning."""
+    BatchedLocalAdapter."""
 
     SWEEP_INTERVAL_S = 60.0
 

@@ -11,16 +11,14 @@ tick is one mixed launch sequence:
   lanes in phase with nothing queued (core/batch.py: decode_batch); with
   block-starvation preemption resolved BEFORE the
   dispatch so a pool shortfall evicts the lowest-priority sequence
-  instead of erroring an arbitrary lane; under DNET_KV_RAGGED=1 the
-  dispatch attends the block pool in place through the page tables
-  (ops/paged_attention.py) — the gather/scatter round trip and its
-  kv_gather/kv_scatter spans stop existing, while this module's block
-  accounting (_decode_need, preemption) is unchanged because admission
-  was always a function of blocks, never of the dense view;
+  instead of erroring an arbitrary lane; the dispatch attends the block
+  pool in place through the page tables (ops/paged_attention.py), and
+  this module's block accounting (_decode_need, preemption) is a
+  function of blocks alone;
 - then the tick's chunked-prefill segments on the engine's B=1 bucket
-  programs, each segment's KV commit riding the existing gather/scatter
-  paths; a segment that completes its prompt is adopted into its batch
-  lane and its first token sampled in the same tick.
+  programs, staged in the inner engine's dense row; a segment that
+  completes its prompt is adopted into its batch lane (the row's blocks
+  commit into the pool) and its first token sampled in the same tick.
 
 Preemption keeps the paged prefix intact: the victim's live page table is
 aliased into the PagedPrefixCache (zero copy, refcounted) before the slot

@@ -56,7 +56,7 @@ def wire_pipeline_enabled() -> bool:
     """THE flag gate: DNET_WIRE_PIPELINE=1 (WireSettings.pipeline).  A raw
     env read (config.env_flag, the sanctioned DL006 escape hatch) backs
     the settings value so tests toggling os.environ after the settings
-    cache warmed still see the flip — the sched_enabled contract."""
+    cache warmed still see the flip."""
     from dnet_tpu.config import env_flag, get_settings
 
     if get_settings().wire.pipeline:

@@ -51,8 +51,7 @@ pytestmark = pytest.mark.api
 @pytest.fixture(autouse=True)
 def _obs_env():
     """Every test leaves the obs env exactly as it found it."""
-    keys = ("DNET_OBS_ENABLED", "DNET_OBS_TICK_RECORDS", "DNET_SCHED",
-            "DNET_PROFILE")
+    keys = ("DNET_OBS_ENABLED", "DNET_OBS_TICK_RECORDS", "DNET_PROFILE")
     saved = {k: os.environ.get(k) for k in keys}
     yield
     for k, v in saved.items():
@@ -616,7 +615,6 @@ def test_sched_tick_records_agree_with_counters(tiny_llama_dir):
     from tests.subsystems.test_sched import _serve_burst
 
     os.environ["DNET_OBS_ENABLED"] = "1"
-    os.environ["DNET_KV_PAGED"] = "1"
     reset_settings_cache()
     reset_obs()  # zero counters + empty tick ring: deltas == totals
     try:
@@ -642,6 +640,4 @@ def test_sched_tick_records_agree_with_counters(tiny_llama_dir):
         # the sched tick loop also observed every tick's wall time
         assert metric("dnet_sched_tick_ms").count >= captured
     finally:
-        os.environ.pop("DNET_KV_PAGED", None)
-        os.environ.pop("DNET_SCHED", None)  # set by _serve_burst
         reset_settings_cache()

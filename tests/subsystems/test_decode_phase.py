@@ -27,7 +27,6 @@ CHUNK = 8  # prefill chunk and kv block, tokens
 
 @pytest.fixture
 def paged_env(monkeypatch):
-    monkeypatch.setenv("DNET_KV_PAGED", "1")
     monkeypatch.setenv("DNET_KV_BLOCK_TOKENS", str(CHUNK))
     monkeypatch.setenv("DNET_FLASH_INTERPRET", "1")
     monkeypatch.setenv("DNET_OBS_ENABLED", "1")  # the tick-record ring
@@ -39,17 +38,14 @@ def paged_env(monkeypatch):
     reset_obs()
 
 
-def _engine(model_dir, env, kv="ragged", slots=4):
+def _engine(model_dir, env, kv="paged", slots=4):
     from dnet_tpu.core.batch import BatchedEngine
 
-    if kv == "ragged":
-        env.setenv("DNET_KV_RAGGED", "1")
-        reset_settings_cache()
     eng = BatchedEngine(
         model_dir, slots=slots, max_seq=128, param_dtype="float32",
-        kv_paged=kv != "dense",
+        kv_paged=None if kv == "paged" else False,
     )
-    assert eng.kv_ragged is (kv == "ragged")
+    assert (eng.kv_pool is not None) is (kv == "paged")
     return eng
 
 
@@ -201,7 +197,7 @@ def test_a_new_lane_steps_alone_while_buffers_drain_then_all_fuse(tiny_llama_dir
     assert src["dispatch"] + src["buffer"] == 3 * 23
 
 
-@pytest.mark.parametrize("kv", ["dense", "gather", "ragged"])
+@pytest.mark.parametrize("kv", ["dense", "paged"])
 def test_decode_batch_fuses_only_a_dispatch_that_carries_every_lane(tiny_llama_dir, paged_env, kv):
     """The engine's half, without a scheduler: lanes a and b hold rows of an
     R = 4 dispatch when c appears; c steps at R = 1 (three times) while they

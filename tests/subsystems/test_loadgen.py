@@ -4,7 +4,7 @@ Tiers: pure units (schedule determinism, report math, percentile edges,
 exposition parsing), an overload run over a fake adapter asserting the
 shed/SLO-attainment report surface under chaos-injected admission delay,
 and the ACCEPTANCE smoke: a seeded in-process load run against the real
-BatchedEngine under DNET_KV_PAGED=1 whose report must cross-validate
+BatchedEngine over the paged pool whose report must cross-validate
 against the live `dnet_slo_*` gauges and whose phase breakdown must
 account for the parent decode-step time.
 """
@@ -192,13 +192,13 @@ def test_parse_prometheus_and_deltas():
         "# HELP dnet_x_total help\n"
         "# TYPE dnet_x_total counter\n"
         "dnet_x_total 41\n"
-        'dnet_span_ms_sum{span="dnet.decode.kv_gather"} 12.5\n'
-        'dnet_span_ms_count{span="dnet.decode.kv_gather"} 3\n'
+        'dnet_span_ms_sum{span="dnet.decode.launch"} 12.5\n'
+        'dnet_span_ms_count{span="dnet.decode.launch"} 3\n'
         "garbage line without value\n"
     )
     d = parse_prometheus(text)
     assert d["dnet_x_total"] == 41.0
-    assert d['dnet_span_ms_sum{span="dnet.decode.kv_gather"}'] == 12.5
+    assert d['dnet_span_ms_sum{span="dnet.decode.launch"}'] == 12.5
     assert "garbage" not in "".join(d)
     before = {"dnet_x_total": 40.0}
     assert metric_delta(d, before, "dnet_x_total") == 1.0
@@ -348,13 +348,12 @@ def test_chaos_overload_report_reflects_shed_and_burn(monkeypatch):
 
 
 def test_inprocess_smoke_load_acceptance(tiny_llama_dir, monkeypatch):
-    """The tier-1 acceptance run: real BatchedEngine under DNET_KV_PAGED=1
+    """The tier-1 acceptance run: real BatchedEngine over the paged pool
     behind the real admission/SSE stack, seeded open-loop load through the
     real loadgen client.  Asserts the BENCH_SERVE contract: goodput over
     200-completed only, TTFT/decode p95 and availability cross-validating
     against the live dnet_slo_* gauges, and the decode dispatch's host
     spans (always on, unfenced) summing to the parent decode-step time."""
-    monkeypatch.setenv("DNET_KV_PAGED", "1")
     reset_settings_cache()
     reset_obs()
     try:
@@ -367,7 +366,7 @@ def test_inprocess_smoke_load_acceptance(tiny_llama_dir, monkeypatch):
             eng = BatchedEngine(
                 tiny_llama_dir, slots=4, max_seq=64, param_dtype="float32"
             )
-            assert eng.kv_pool is not None  # paged path engaged
+            assert eng.kv_ragged  # the pool, attended in place: derived
             adapter = BatchedLocalAdapter(eng)
             from dnet_tpu.admission.controller import AdmissionController
             from dnet_tpu.api.http import ApiHTTPServer
@@ -437,9 +436,10 @@ def test_inprocess_smoke_load_acceptance(tiny_llama_dir, monkeypatch):
                 assert metric("dnet_slo_ttft_p99_ms").value > 0
 
                 # -- the dispatch's host spans account for the parent
-                # decode step (dense-gather paged path: all six exist)
+                # decode step (the four spans of the in-place step)
                 pa = rep["phase_attribution"]
                 assert tuple(pa["phases"]) == DECODE_CHILD_SPANS
+                assert len(DECODE_CHILD_SPANS) == 4
                 for ph in DECODE_CHILD_SPANS:
                     assert pa["phases"][ph]["count"] > 0, pa
                 assert pa["decode_step"]["count"] > 0

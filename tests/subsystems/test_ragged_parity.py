@@ -1,13 +1,13 @@
-"""Ragged paged attention end-to-end (DNET_KV_RAGGED=1): the interpret-mode
-kernel — the REAL kernel logic, index-map clamping included — must serve
-byte-identical greedy streams to the dense-gather path through the
-production stack, under both the legacy adapter and the DNET_SCHED=1
-scheduler, across the sharing edges the block pool makes interesting
-(COW mid-block divergence, preemption -> resume re-prefill, mid-block
-positions attended through clamped dead table entries)."""
+"""The paged pool attended in place, end-to-end: the interpret-mode kernel
+— the REAL kernel logic, index-map clamping included — must serve
+byte-identical greedy streams to the dense slot cache (BatchedEngine
+kv_paged=False, the reference) through the production stack, under both
+the scheduler and the legacy adapter, across the sharing edges the block
+pool makes interesting (COW mid-block divergence, preemption -> resume
+re-prefill, mid-block positions attended through clamped dead table
+entries)."""
 
 import asyncio
-import os
 import re
 
 import pytest
@@ -21,34 +21,12 @@ pytestmark = pytest.mark.api
 
 @pytest.fixture
 def ragged_env(monkeypatch):
-    """Paged pool with small blocks + interpret-mode kernels: tier-1 CPU
-    executes the actual Pallas program logic, not just the jnp twin.  The
-    ragged flag itself is flipped per serving run by the helpers below."""
-    monkeypatch.setenv("DNET_KV_PAGED", "1")
+    """Small blocks + interpret-mode kernels: tier-1 CPU executes the
+    actual Pallas program logic, not just the jnp twin."""
     monkeypatch.setenv("DNET_KV_BLOCK_TOKENS", "8")
     monkeypatch.setenv("DNET_FLASH_INTERPRET", "1")
     reset_settings_cache()
     yield
-    reset_settings_cache()
-
-
-def _flip(ragged: bool, sched: bool) -> None:
-    """Per-run env for the A/B halves (monkeypatch can't scope a single
-    asyncio.run); callers pop both keys afterwards."""
-    if ragged:
-        os.environ["DNET_KV_RAGGED"] = "1"
-    else:
-        os.environ.pop("DNET_KV_RAGGED", None)
-    if sched:
-        os.environ["DNET_SCHED"] = "1"
-    else:
-        os.environ.pop("DNET_SCHED", None)
-    reset_settings_cache()
-
-
-def _unflip() -> None:
-    os.environ.pop("DNET_KV_RAGGED", None)
-    os.environ.pop("DNET_SCHED", None)
     reset_settings_cache()
 
 
@@ -59,9 +37,12 @@ def _normalize_sse(raw: str) -> str:
     return re.sub(r'"created": ?\d+', '"created": 0', raw)
 
 
-async def _sse_burst(model_dir, prompts, max_tokens=6, slots=4):
+async def _sse_burst(model_dir, prompts, reference=None, max_tokens=6, slots=4):
     """The real HTTP server: load the tiny model, stream every prompt
-    concurrently, return the raw SSE bytes per prompt."""
+    concurrently, return the raw SSE bytes per prompt.  `reference` None is
+    a plain load (what serving_plan derives: the scheduler over the pool);
+    an adapter class serves the DENSE engine through it instead, installed
+    the way load_model's tail installs an engine."""
     from aiohttp.test_utils import TestClient, TestServer
 
     from dnet_tpu.api.http import ApiHTTPServer
@@ -78,8 +59,20 @@ async def _sse_burst(model_dir, prompts, max_tokens=6, slots=4):
     client = TestClient(TestServer(server.app))
     await client.start_server()
     try:
-        r = await client.post("/v1/load_model", json={"model": str(model_dir)})
-        assert r.status == 200, await r.text()
+        if reference is None:
+            r = await client.post("/v1/load_model", json={"model": str(model_dir)})
+            assert r.status == 200, await r.text()
+            assert manager.serving.adapter == "SchedulerAdapter"
+            assert manager.engine.kv_ragged
+        else:
+            from dnet_tpu.utils.tokenizer import load_tokenizer
+
+            eng = _engine(model_dir, paged=False, slots=8)
+            inference.adapter = reference(eng)
+            await inference.adapter.start()
+            inference.tokenizer = load_tokenizer(model_dir)
+            inference.model_id = "tiny"
+            manager.engine = eng
 
         async def one(p):
             resp = await client.post(
@@ -99,31 +92,30 @@ async def _sse_burst(model_dir, prompts, max_tokens=6, slots=4):
         return await asyncio.gather(*(one(p) for p in prompts))
     finally:
         await client.close()
+        await manager.unload_model()
 
 
-def _sse_ab(model_dir, prompts, sched: bool):
-    """Dense-gather vs ragged halves of one parity run (identical env but
-    for DNET_KV_RAGGED), normalized for comparison."""
-    try:
-        _flip(ragged=False, sched=sched)
-        dense = asyncio.run(_sse_burst(model_dir, prompts))
-        _flip(ragged=True, sched=sched)
-        ragged = asyncio.run(_sse_burst(model_dir, prompts))
-    finally:
-        _unflip()
+def _sse_ab(model_dir, prompts, reference):
+    """The dense reference and the served default of one parity run,
+    normalized for comparison."""
+    dense = asyncio.run(_sse_burst(model_dir, prompts, reference=reference))
+    paged = asyncio.run(_sse_burst(model_dir, prompts))
     return ([_normalize_sse(s) for s in dense],
-            [_normalize_sse(s) for s in ragged])
+            [_normalize_sse(s) for s in paged])
 
 
 @pytest.mark.http
 def test_ragged_legacy_sse_byte_parity(tiny_llama_dir, ragged_env):
-    """Legacy adapter, mixed burst: SSE byte streams identical after
-    normalizing id + created — chunk boundaries, deltas, finish reasons,
-    usage, framing.  Variable prompt lengths land mid-block on purpose so
-    the kernel's live-clamp (dead table entries past each slot's blocks)
-    is on the serving path, not just the unit tier."""
+    """Against the dense engine under the LEGACY adapter, mixed burst: SSE
+    byte streams identical after normalizing id + created — chunk
+    boundaries, deltas, finish reasons, usage, framing.  Variable prompt
+    lengths land mid-block on purpose so the kernel's live-clamp (dead
+    table entries past each slot's blocks) is on the serving path, not just
+    the unit tier."""
+    from dnet_tpu.api.strategies import BatchedLocalAdapter
+
     prompts = ["Hi", "Hello there", "A quick brown fox", "mid prompt here"]
-    dense, ragged = _sse_ab(tiny_llama_dir, prompts, sched=False)
+    dense, ragged = _sse_ab(tiny_llama_dir, prompts, BatchedLocalAdapter)
     assert ragged == dense
     for s in ragged:  # real streams, not error shortcuts
         events = [ln for ln in s.splitlines() if ln.startswith("data: ")]
@@ -132,11 +124,14 @@ def test_ragged_legacy_sse_byte_parity(tiny_llama_dir, ragged_env):
 
 @pytest.mark.http
 def test_ragged_sched_sse_byte_parity(tiny_llama_dir, ragged_env):
-    """Same contract through the DNET_SCHED=1 scheduler: mixed
-    prefill+decode ticks dispatch the ragged program and the byte streams
-    still match the dense-gather scheduler run."""
+    """Same contract with the scheduler on both sides: mixed prefill+decode
+    ticks dispatch the in-place program and the byte streams still match
+    the scheduler's run over dense slots (sched/policy.py handles an engine
+    without a pool)."""
+    from dnet_tpu.sched import SchedulerAdapter
+
     prompts = ["Hi", "Hello there", "A quick brown fox", "tail"]
-    dense, ragged = _sse_ab(tiny_llama_dir, prompts, sched=True)
+    dense, ragged = _sse_ab(tiny_llama_dir, prompts, SchedulerAdapter)
     assert ragged == dense
     for s in ragged:
         events = [ln for ln in s.splitlines() if ln.startswith("data: ")]
@@ -144,18 +139,17 @@ def test_ragged_sched_sse_byte_parity(tiny_llama_dir, ragged_env):
 
 
 # ---------------------------------------------------------------------------
-# engine tier: the sharing edges, ragged vs the dense-gather fallback
+# engine tier: the sharing edges, the pool vs the dense reference
 # ---------------------------------------------------------------------------
 
 
-def _engine(tiny_llama_dir, ragged: bool, **kw):
+def _engine(tiny_llama_dir, paged: bool, **kw):
     from dnet_tpu.core.batch import BatchedEngine
 
-    _flip(ragged=ragged, sched=False)
     kw.setdefault("slots", 4)
     kw.setdefault("max_seq", 64)
     kw.setdefault("param_dtype", "float32")
-    return BatchedEngine(tiny_llama_dir, kv_paged=True, **kw)
+    return BatchedEngine(tiny_llama_dir, kv_paged=None if paged else False, **kw)
 
 
 def _stream(eng, nonce, ids, steps, dec=DecodingParams(temperature=0.0)):
@@ -172,40 +166,34 @@ def _span_counts():
     fam = metric("dnet_span_ms")
     return {
         sp: fam.labels(span=f"dnet.decode.{sp}").count
-        for sp in ("kv_gather", "launch", "kv_scatter", "readback")
+        for sp in ("prepare", "launch", "readback", "unpack")
     }
 
 
-@pytest.mark.parametrize("ragged", [True, False], ids=["ragged", "gather"])
-def test_ragged_engine_flag_and_phases(tiny_llama_dir, ragged_env, ragged):
-    """The engine actually takes the ragged path (kv_ragged resolves True),
-    and the kv_gather/kv_scatter spans STOP EXISTING on it: a decode
-    dispatch moves the launch and readback span counters but neither KV
-    span — the round trip is deleted, not just cheaper.  The dense-gather
-    paged engine opens both, once per dispatch.  No setting is needed:
-    the spans are always on and fence nothing."""
-    eng = _engine(tiny_llama_dir, ragged=ragged)
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
+def test_ragged_engine_flag_and_phases(tiny_llama_dir, ragged_env, paged):
+    """With no argument the engine takes the pool (kv_ragged reads True),
+    and a decode dispatch is the same four spans, once each, on the pool
+    and on dense slots: the pool's append rides inside the launch.  No
+    setting is needed: the spans are always on and fence nothing."""
+    eng = _engine(tiny_llama_dir, paged=paged)
     try:
-        assert eng.kv_ragged is ragged
+        assert eng.kv_ragged is paged and (eng.kv is None) is paged
         before = _span_counts()
         dec = DecodingParams(temperature=0.0)
         res = eng.prefill_and_sample("ph", [256, 72, 101], dec)
         eng.decode_batch({"ph": (int(res.token[0]), dec)})
         moved = {k: v - before[k] for k, v in _span_counts().items()}
-        assert moved["launch"] == 1 and moved["readback"] == 1
-        kv_spans = 0 if ragged else 1
-        assert moved["kv_gather"] == kv_spans
-        assert moved["kv_scatter"] == kv_spans
+        assert moved == {"prepare": 1, "launch": 1, "readback": 1, "unpack": 1}
         eng.end_session("ph")
     finally:
         eng.close()
-        _unflip()
 
 
 def test_ragged_interleaved_mid_block_matches_dense(tiny_llama_dir, ragged_env):
     """>= 3 concurrent variable-length sessions whose positions straddle
     block boundaries (the clamped-dead-block masking edge, mid-block pos):
-    identical greedy streams to the dense-gather engine, single steps and
+    identical greedy streams to the dense engine, single steps and
     budget-driven fused chunks both."""
     prompts = {
         "va": [256, 72, 101],                                  # 1 block, mid
@@ -241,12 +229,12 @@ def test_ragged_interleaved_mid_block_matches_dense(tiny_llama_dir, ragged_env):
         eng.end_session("ck")
         return toks
 
-    eng = _engine(tiny_llama_dir, ragged=False)
+    eng = _engine(tiny_llama_dir, paged=False)
     try:
         want, want_ck = interleaved(eng), chunked(eng)
     finally:
         eng.close()
-    eng = _engine(tiny_llama_dir, ragged=True)
+    eng = _engine(tiny_llama_dir, paged=True)
     try:
         assert eng.kv_ragged is True
         assert interleaved(eng) == want
@@ -254,13 +242,12 @@ def test_ragged_interleaved_mid_block_matches_dense(tiny_llama_dir, ragged_env):
         eng.kv_pool.check_conservation()
     finally:
         eng.close()
-        _unflip()
 
 
 def test_ragged_cow_mid_block_divergence(tiny_llama_dir, ragged_env):
-    """A prompt diverging INSIDE a shared block under the ragged path:
-    the sharer COWs the partial block, both streams match the dense-gather
-    engine's, and the original keeps decoding out of its UN-mutated
+    """A prompt diverging INSIDE a shared block over the pool: the sharer
+    COWs the partial block, both streams match the dense engine's (which
+    shares nothing), and the original keeps decoding out of its UN-mutated
     partial block (the kernel reads the pre-COW physical block through its
     own table while the sharer's table points at the copy)."""
     from dnet_tpu.obs import reset_obs
@@ -269,10 +256,11 @@ def test_ragged_cow_mid_block_divergence(tiny_llama_dir, ragged_env):
     base = list(range(260, 280))  # 20 tokens: 2 full blocks + 4 in a 3rd
     grown = base + [7, 2]
 
-    def run(ragged: bool):
-        eng = _engine(tiny_llama_dir, ragged=ragged, prefix_cache_size=4)
+    def run(paged: bool):
+        eng = _engine(tiny_llama_dir, paged=paged, prefix_cache_size=4 * paged)
         try:
-            eng.paged_prefix.min_tokens = 8
+            if paged:
+                eng.paged_prefix.min_tokens = 8
             got_base = [_stream(eng, "b", base, 1)[0]]
             got_grown = _stream(eng, "g", grown, 6)
             dec = DecodingParams(temperature=0.0)
@@ -282,27 +270,26 @@ def test_ragged_cow_mid_block_divergence(tiny_llama_dir, ragged_env):
                 got_base.append(int(out["b"].token[0]))
             eng.end_session("b")
             eng.end_session("g")
-            eng.kv_pool.check_conservation()
+            if paged:
+                eng.kv_pool.check_conservation()
             return got_base, got_grown
         finally:
             eng.close()
-            _unflip()
 
-    want = run(ragged=False)
+    want = run(paged=False)
     cow_before = metric("dnet_kv_cow_copies_total").value
-    got = run(ragged=True)
+    got = run(paged=True)
     assert got == want
     assert metric("dnet_kv_cow_copies_total").value > cow_before
 
 
 @pytest.mark.slow
 def test_ragged_preempt_resume_reprefill_parity(tiny_llama_dir, monkeypatch):
-    """Scheduler preemption -> resume under ragged: a pool too small for
+    """Scheduler preemption -> resume over the pool: a pool too small for
     both sequences' decode growth forces a block-starvation preemption;
     the victim's prefix is aliased out, it resumes by RE-PREFILLING (the
-    ragged path serves both the re-prefill commit and the resumed decode),
+    pool serves both the re-prefill commit and the resumed decode),
     and both final texts equal uncontended solo runs."""
-    monkeypatch.setenv("DNET_KV_PAGED", "1")
     monkeypatch.setenv("DNET_KV_BLOCK_TOKENS", "8")
     monkeypatch.setenv("DNET_FLASH_INTERPRET", "1")
     # the chat-templated prompt is 45 tokens = 6 blocks: 13 admits BOTH
@@ -346,7 +333,6 @@ def test_ragged_preempt_resume_reprefill_parity(tiny_llama_dir, monkeypatch):
 
     prompts = ["a" * 20, "b" * 20]
     try:
-        _flip(ragged=True, sched=True)
         solo = [asyncio.run(serve([p], [None]))[0] for p in prompts]
         before = metric("dnet_sched_preemptions_total").labels(
             reason="block_starvation"
@@ -355,7 +341,7 @@ def test_ragged_preempt_resume_reprefill_parity(tiny_llama_dir, monkeypatch):
         # first, which becomes the block-starvation victim mid-decode
         got = asyncio.run(serve(prompts, [None, 30.0]))
     finally:
-        _unflip()
+        reset_settings_cache()
     assert got == solo
     after = metric("dnet_sched_preemptions_total").labels(
         reason="block_starvation"

@@ -1,15 +1,16 @@
-"""Iteration-level scheduler (dnet_tpu/sched/, DNET_SCHED=1): tick packing,
+"""Iteration-level scheduler (dnet_tpu/sched/): tick packing,
 deadline-ordered admission, block-starvation preemption/resume, and the
 scheduler-vs-legacy SSE parity contract.
 
 Unit tier drives SchedulerPolicy/SchedQueue over a fake engine (no model);
 the end-to-end tier serves the REAL tiny model through InferenceManager /
-ApiHTTPServer with DNET_KV_PAGED=1 so the paged block pool, preemption,
-and the byte-level SSE framing are all the production code paths.
+ApiHTTPServer, as a plain load serves it, so the paged block pool,
+preemption, and the byte-level SSE framing are all the production code
+paths.  The legacy half of the parity tests is BatchedLocalAdapter,
+constructed directly over the same engine (no load selects it any more).
 """
 
 import asyncio
-import os
 import re
 
 import pytest
@@ -234,15 +235,6 @@ def test_queue_depth_gauges_track_states():
 # ---------------------------------------------------------------------------
 
 
-@pytest.fixture
-def sched_paged_env(monkeypatch):
-    monkeypatch.setenv("DNET_SCHED", "1")
-    monkeypatch.setenv("DNET_KV_PAGED", "1")
-    reset_settings_cache()
-    yield
-    reset_settings_cache()
-
-
 def _req(content: str, max_tokens: int = 8, deadline_s=None):
     from dnet_tpu.api.schemas import ChatCompletionRequest
 
@@ -257,17 +249,32 @@ def _req(content: str, max_tokens: int = 8, deadline_s=None):
     return ChatCompletionRequest.model_validate(body)
 
 
+async def _load(inference, manager, model_dir, sched: bool, slots: int):
+    """sched: a plain load (serving_plan: the scheduler over the pool).
+    Legacy: the same engine under BatchedLocalAdapter, installed the way
+    load_model's tail installs one."""
+    if sched:
+        await manager.load_model(str(model_dir))
+        assert manager.serving.adapter == "SchedulerAdapter"
+        return
+    from dnet_tpu.api.strategies import BatchedLocalAdapter
+    from dnet_tpu.core.batch import BatchedEngine
+    from dnet_tpu.utils.tokenizer import load_tokenizer
+
+    manager.engine = BatchedEngine(
+        model_dir, slots=max(slots, 8), max_seq=64, param_dtype="float32"
+    )
+    inference.adapter = BatchedLocalAdapter(manager.engine)
+    await inference.adapter.start()
+    inference.tokenizer = load_tokenizer(model_dir)
+    inference.model_id = "tiny"
+
+
 async def _serve_burst(model_dir, prompts, sched: bool, max_tokens=8,
                        slots=4, deadlines=None):
-    import os
-
     from dnet_tpu.api.inference import InferenceManager
     from dnet_tpu.api.model_manager import LocalModelManager
 
-    if sched:
-        os.environ["DNET_SCHED"] = "1"
-    else:
-        os.environ.pop("DNET_SCHED", None)
     reset_settings_cache()
     inference = InferenceManager(
         adapter=None, request_timeout_s=120.0, max_concurrent=slots
@@ -275,7 +282,7 @@ async def _serve_burst(model_dir, prompts, sched: bool, max_tokens=8,
     manager = LocalModelManager(
         inference, max_seq=64, param_dtype="float32", batch_slots=slots
     )
-    await manager.load_model(str(model_dir))
+    await _load(inference, manager, model_dir, sched, slots)
     try:
         deadlines = deadlines or [None] * len(prompts)
         outs = await asyncio.gather(*(
@@ -291,14 +298,11 @@ async def _serve_burst(model_dir, prompts, sched: bool, max_tokens=8,
 def test_scheduler_legacy_parity_mixed_burst(tiny_llama_dir, monkeypatch):
     """The acceptance contract: a mixed burst (short/long prompts, more
     requests than slots) produces the SAME greedy texts through the
-    scheduler as through the legacy engine path, under DNET_KV_PAGED=1."""
-    monkeypatch.setenv("DNET_KV_PAGED", "1")
+    scheduler as through the legacy adapter, over the paged pool."""
     prompts = ["Hi", "Hello there", "A quick brown fox", "x" * 30,
                "mid prompt here"]
     legacy = asyncio.run(_serve_burst(tiny_llama_dir, prompts, sched=False))
     sched = asyncio.run(_serve_burst(tiny_llama_dir, prompts, sched=True))
-    os.environ.pop("DNET_SCHED", None)  # set by _serve_burst, not monkeypatch
-    reset_settings_cache()
     assert sched == legacy
 
 
@@ -320,17 +324,9 @@ def test_scheduler_legacy_sse_byte_parity(tiny_llama_dir, monkeypatch):
     from dnet_tpu.api.inference import InferenceManager
     from dnet_tpu.api.model_manager import LocalModelManager
 
-    monkeypatch.setenv("DNET_KV_PAGED", "1")
     prompts = ["Hi", "Hello there", "A quick brown fox", "tail"]
 
     async def streams(sched: bool):
-        import os
-
-        if sched:
-            os.environ["DNET_SCHED"] = "1"
-        else:
-            os.environ.pop("DNET_SCHED", None)
-        reset_settings_cache()
         inference = InferenceManager(
             adapter=None, request_timeout_s=120.0, max_concurrent=4
         )
@@ -341,10 +337,7 @@ def test_scheduler_legacy_sse_byte_parity(tiny_llama_dir, monkeypatch):
         client = TestClient(TestServer(server.app))
         await client.start_server()
         try:
-            r = await client.post(
-                "/v1/load_model", json={"model": str(tiny_llama_dir)}
-            )
-            assert r.status == 200, await r.text()
+            await _load(inference, manager, tiny_llama_dir, sched, 4)
 
             async def one(p):
                 resp = await client.post(
@@ -366,11 +359,10 @@ def test_scheduler_legacy_sse_byte_parity(tiny_llama_dir, monkeypatch):
             return await asyncio.gather(*(one(p) for p in prompts))
         finally:
             await client.close()
+            await manager.unload_model()
 
     legacy = [_normalize_sse(s) for s in asyncio.run(streams(False))]
     sched = [_normalize_sse(s) for s in asyncio.run(streams(True))]
-    os.environ.pop("DNET_SCHED", None)  # set by _serve_burst, not monkeypatch
-    reset_settings_cache()
     assert sched == legacy
     for s in sched:  # and they are real streams, not error shortcuts
         events = [ln for ln in s.splitlines() if ln.startswith("data: ")]
@@ -382,7 +374,6 @@ def test_small_pool_queues_by_blocks_and_completes(tiny_llama_dir, monkeypatch):
     """A pool too small for two residents: admission-by-blocks holds the
     second request in WAITING until the first frees its blocks — both
     complete, and each with the exact greedy text of an uncontended run."""
-    monkeypatch.setenv("DNET_KV_PAGED", "1")
     monkeypatch.setenv("DNET_KV_BLOCK_TOKENS", "8")
     monkeypatch.setenv("DNET_KV_POOL_BLOCKS", "10")
     monkeypatch.setenv("DNET_SCHED_SLOTS", "2")
@@ -399,7 +390,6 @@ def test_small_pool_queues_by_blocks_and_completes(tiny_llama_dir, monkeypatch):
         tiny_llama_dir, prompts, sched=True, max_tokens=10, slots=2,
         deadlines=[None, 30.0],
     ))
-    os.environ.pop("DNET_SCHED", None)  # set by _serve_burst, not monkeypatch
     reset_settings_cache()
     assert got == solo
 

@@ -10,7 +10,7 @@ Three layers, all always on and unfenced:
 - the scheduler's stamps on SchedRequest (sched/engine.py): queue wait,
   prefill wall time and ticks, decode deliver wait, and the recorder's
   `sched_queue` / `prefill` spans that make a request's segment ledger add
-  up to its time to first token under DNET_SCHED=1;
+  up to its time to first token under the scheduler;
 - the host-span tree (obs/phases.py HOST_SPANS): children sum to no more
   than their parent.
 """
@@ -48,7 +48,6 @@ def _moved(before):
 
 @pytest.fixture
 def paged_env(monkeypatch):
-    monkeypatch.setenv("DNET_KV_PAGED", "1")
     monkeypatch.setenv("DNET_KV_BLOCK_TOKENS", "8")
     monkeypatch.setenv("DNET_FLASH_INTERPRET", "1")
     reset_settings_cache()
@@ -57,22 +56,19 @@ def paged_env(monkeypatch):
     reset_settings_cache()
 
 
-@pytest.mark.parametrize("kv", ["dense", "gather", "ragged"])
+@pytest.mark.parametrize("kv", ["dense", "paged"])
 def test_decode_batch_counts_what_the_fused_chunk_did(tiny_llama_dir, paged_env, kv):
     """One budgeted dispatch of R=4 for 2 lanes on 4 slots, then the three
     buffer hits that follow it, then a session that ends with rows still
     buffered: the counters say exactly that, on every KV path."""
     from dnet_tpu.core.batch import BatchedEngine
 
-    if kv == "ragged":
-        paged_env.setenv("DNET_KV_RAGGED", "1")
-        reset_settings_cache()
     eng = BatchedEngine(
         tiny_llama_dir, slots=4, max_seq=64, param_dtype="float32",
-        kv_paged=kv != "dense",
+        kv_paged=None if kv == "paged" else False,
     )
     try:
-        assert eng.kv_ragged is (kv == "ragged")
+        assert (eng.kv_pool is not None) is (kv == "paged")
         dec = DecodingParams(temperature=0.0)
         last = {}
         for n, ids in (("a", [256, 72, 101]), ("b", [256, 84, 104, 105])):
@@ -177,13 +173,11 @@ async def _serve_one(model_dir, prompt, max_tokens):
 
 
 def test_scheduler_rehearsal_waits_spans_and_tick_records(tiny_llama_dir, paged_env):
-    """DNET_SCHED=1 over the ragged paged pool, tiny model, one request:
+    """The scheduler over the paged pool (what a load derives), tiny model, one request:
     the recorder's admission_wait + sched_queue + prefill spans add up to
     the measured time to first token, `sched_queue` is emitted, the wait
     histograms hold one request's worth, the span tree is consistent, and
     /v1/debug/sched's records say which ticks reached the device."""
-    paged_env.setenv("DNET_SCHED", "1")
-    paged_env.setenv("DNET_KV_RAGGED", "1")
     paged_env.setenv("DNET_SCHED_PREFILL_CHUNK", "8")
     paged_env.setenv("DNET_OBS_ENABLED", "1")  # the tick-record ring
     reset_settings_cache()
@@ -237,7 +231,8 @@ def test_scheduler_rehearsal_waits_spans_and_tick_records(tiny_llama_dir, paged_
         child_ms = sum(_span(n)[1] for n in DECODE_CHILD_SPANS)
         assert 0.8 * dec_ms <= child_ms <= dec_ms
         assert _span("dnet.decode.prepare")[0] == n_dec  # one per decode_batch call
-        assert _span("dnet.decode.kv_gather")[0] == _span("dnet.decode.kv_scatter")[0] == 0
+        assert DECODE_CHILD_SPANS == tuple(
+            f"dnet.decode.{s}" for s in ("prepare", "launch", "readback", "unpack"))
         n_disp = sum(metric("dnet_decode_dispatch_total").labels(r=str(r)).value
                      for r in (1, 2, 4, 8, 16))
         assert _span("dnet.decode.launch")[0] == _span("dnet.decode.readback")[0] == n_disp

@@ -350,10 +350,9 @@ def test_execute_tick_dispatches_decode_before_prefill():
 
 
 def test_sched_pipeline_parity_and_no_double_resolve(tiny_llama_dir, monkeypatch):
-    """DNET_SCHED=1 + DNET_WIRE_PIPELINE=1: decode futures resolve through
+    """The scheduler + DNET_WIRE_PIPELINE=1: decode futures resolve through
     the early-dispatch bridge and the barriered apply skips them — the
     burst's greedy texts equal the non-pipelined scheduler run exactly."""
-    monkeypatch.setenv("DNET_KV_PAGED", "1")
     from tests.subsystems.test_sched import _serve_burst
 
     prompts = ["Hi", "Hello there", "A quick brown fox", "tail prompt"]
@@ -361,7 +360,6 @@ def test_sched_pipeline_parity_and_no_double_resolve(tiny_llama_dir, monkeypatch
     os.environ["DNET_WIRE_PIPELINE"] = "1"
     reset_settings_cache()
     piped = asyncio.run(_serve_burst(tiny_llama_dir, prompts, sched=True))
-    os.environ.pop("DNET_SCHED", None)  # set by _serve_burst
     reset_settings_cache()
     assert piped == plain
 

@@ -208,7 +208,7 @@ def test_chip_smoke_without_a_chip_serves_nothing_and_fails(tmp_path):
         proc = _smoke(env={"JAX_PLATFORMS": platforms}, timeout=300)
         assert proc.returncode != 0
         assert "[FAIL] device" in proc.stdout
-        assert "serve-default" not in proc.stdout  # no request was served
+        assert "] serve" not in proc.stdout  # no request was served
         assert not proc.stdout.rstrip().splitlines()[-1].startswith("{")  # no result
     # and alone, without the program beside it, it fails too
     lonely = tmp_path / "chip_smoke.py"
@@ -235,7 +235,7 @@ def test_chip_smoke_rehearsal_end_to_end():
     assert summary["ok"] and summary["rehearsal"] and summary["claim"] is None
     assert summary["device"]["platform"] == "cpu"
     assert list(summary["phases"]) == [
-        "device", "model", "kernels", "serve-default", "serve-sched", "mesh4",
+        "device", "model", "kernels", "serve", "mesh4",
     ]
     assert all(ph["ok"] for ph in summary["phases"].values())
     # a phase forced to fail makes the exit code non-zero

@@ -109,28 +109,29 @@ def _row(model, n_layers, seq, fill):
     return jax.tree.map(lambda a: a + fill, kv)
 
 
-def test_store_gather_scatter_roundtrip():
+def test_store_commit_gather_roundtrip():
+    """commit_row -> gather_row: a staged row's blocks land where the table
+    says and come back in order; a second commit rewrites one block only."""
     cfg = PagedKVConfig(block_tokens=4, pool_blocks=8)
     model = _FlatKVModel()
     store = BlockStore(model, 2, cfg, "float32")
     row = _row(model, 2, 16, 7.0)  # [2, 1, 16, 2, 4] all 7s
     store.commit_row(row, [0, 1, 2, 3], [5, 6, 1, 2])
-    ids = np.zeros((1, 4), dtype=np.int32)
-    ids[0] = [5, 6, 1, 2]
-    dense = store.gather(ids)
+    dense = store.gather_row([5, 6, 1, 2], 16)
     np.testing.assert_array_equal(np.asarray(dense["k"]), np.asarray(row["k"]))
-    # scatter a mutated block 2 back and re-gather
+    # commit a mutated block 2 and re-gather (3 blocks: a padded width)
     import jax
 
     dense2 = jax.tree.map(lambda a: a * 2, dense)
-    store.scatter(dense2, [(0, 2, 1)])
-    out = store.gather(ids)
+    store.commit_row(dense2, [2], [1])
+    out = store.gather_row([5, 6, 1], 16)
     np.testing.assert_array_equal(
         np.asarray(out["k"][:, :, 8:12]), np.asarray(row["k"][:, :, 8:12]) * 2
     )
     np.testing.assert_array_equal(
         np.asarray(out["k"][:, :, :8]), np.asarray(row["k"][:, :, :8])
     )
+    store.commit_row(dense2, [], [])  # nothing to write: no program runs
 
 
 def test_paged_prefix_store_dedups_blocks():
@@ -139,8 +140,7 @@ def test_paged_prefix_store_dedups_blocks():
     model = _FlatKVModel()
     pool = BlockPool(cfg)
     store = BlockStore(model, 2, cfg, "float32")
-    cache = PagedPrefixCache(pool, store, capacity=4, min_tokens=4,
-                             row_tokens=16)
+    cache = PagedPrefixCache(pool, store, capacity=4, min_tokens=4)
     base = list(range(100, 108))  # 8 tokens = 2 full blocks
     cache.store(base, _row(model, 2, 16, 1.0))
     used_after_first = pool.used  # 2 blocks
@@ -149,12 +149,16 @@ def test_paged_prefix_store_dedups_blocks():
     cache.store(base + [1, 2, 3, 4], _row(model, 2, 16, 2.0))
     assert pool.used == used_after_first + 1  # tail block only
     assert metric("dnet_kv_prefix_shared_blocks_total").value == 2
-    # lookup restores a private dense row; pool refs are transient
-    hit = cache.lookup(base + [1, 2, 3, 4, 9])
+    # a hit hands out the entry's blocks (one reference each), which
+    # gather into a private dense row
+    hit = cache.lookup_blocks(base + [1, 2, 3, 4, 9])
     assert hit is not None
-    n, kv_row = hit
-    assert n == 12
+    n, blocks, n_full = hit
+    assert (n, n_full) == (12, 3)
+    kv_row = store.gather_row(blocks, 16)
     assert kv_row["k"].shape[2] == 16
+    assert float(kv_row["k"][0, 0, 8, 0, 0]) == 2.0  # the grown turn's tail
+    pool.free_blocks(blocks)
     pool.check_conservation()
     cache.clear()
     assert pool.used == 0
@@ -165,8 +169,7 @@ def test_paged_prefix_eviction_releases_blocks():
     model = _FlatKVModel()
     pool = BlockPool(cfg)
     store = BlockStore(model, 2, cfg, "float32")
-    cache = PagedPrefixCache(pool, store, capacity=2, min_tokens=4,
-                             row_tokens=16)
+    cache = PagedPrefixCache(pool, store, capacity=2, min_tokens=4)
     for base in (10, 20, 30):  # third store evicts the first (LRU)
         cache.store([base + i for i in range(8)], _row(model, 2, 16, 1.0))
     assert pool.used == 4  # two live entries x 2 blocks
@@ -416,26 +419,24 @@ def test_rotating_swa_model_refused_and_falls_back(tmp_path, paged_env):
         eng.close()
 
 
-def test_explicit_dense_overrides_paged_env(tiny_llama_dir, monkeypatch):
-    """kv_paged=False must pin BOTH engines dense even when DNET_KV_PAGED=1
-    is set: the inner staging engine must never grow a phantom ledger that
-    rejects prefills for a pool the serving path doesn't use."""
-    from dnet_tpu.config import reset_settings_cache
+def test_explicit_dense_overrides_the_derived_pool(tiny_llama_dir):
+    """kv_paged=False is the dense engine whatever kv_layout would derive
+    (this model and cache take the pool: the engine built with no argument
+    beside it shows so), and the prefix capacity goes to the inner engine's
+    snapshot cache."""
     from dnet_tpu.core.batch import BatchedEngine
 
-    monkeypatch.setenv("DNET_KV_PAGED", "1")
-    reset_settings_cache()
-    eng = BatchedEngine(
-        tiny_llama_dir, slots=2, max_seq=64, param_dtype="float32",
-        kv_paged=False, prefix_cache_size=4,
-    )
+    kw = dict(slots=2, max_seq=64, param_dtype="float32", prefix_cache_size=4)
+    eng = BatchedEngine(tiny_llama_dir, kv_paged=False, **kw)
+    derived = BatchedEngine(tiny_llama_dir, **kw)
     try:
-        assert eng.kv_pool is None and eng.kv is not None
-        assert eng.eng.kv_pool is None
-        assert eng.eng.prefix_cache is not None
+        assert eng.kv_pool is None and eng.kv is not None and not eng.kv_ragged
+        assert eng.eng.prefix_cache is not None and eng.paged_prefix is None
+        assert derived.kv_pool is not None and derived.kv is None and derived.kv_ragged
+        assert derived.eng.prefix_cache is None and derived.paged_prefix is not None
     finally:
         eng.close()
-        reset_settings_cache()
+        derived.close()
 
 
 def test_paged_fallback_keeps_dense_prefix_cache(tiny_llama_dir, monkeypatch):
@@ -502,74 +503,4 @@ def test_sweep_returns_blocks_to_free_list(tiny_llama_dir, paged_env):
         assert eng.kv_pool.used == 0 and eng.kv_pool.free == eng.kv_pool.total
         eng.kv_pool.check_conservation([])
     finally:
-        eng.close()
-
-
-def test_local_engine_paged_admission(tiny_llama_dir, paged_env, monkeypatch):
-    """LocalEngine under DNET_KV_PAGED=1: the pool is the admission ledger
-    — session growth debits blocks, exhaustion raises the typed error, and
-    end_session returns blocks."""
-    from dnet_tpu.config import reset_settings_cache
-    from dnet_tpu.core.engine import LocalEngine
-
-    monkeypatch.setenv("DNET_KV_POOL_BLOCKS", "2")
-    reset_settings_cache()
-    eng = LocalEngine(
-        tiny_llama_dir, max_seq=64, param_dtype="float32", kv_paged=True
-    )
-    try:
-        assert eng.kv_pool is not None
-        dec = DecodingParams(temperature=0.0)
-        res = eng.prefill_and_sample("l1", list(range(100, 112)), dec)  # 2 blk
-        with pytest.raises(KVPoolExhausted):
-            eng.prefill_and_sample("l2", list(range(200, 212)), dec)
-        assert "l2" not in eng.sessions  # clean failure, no half session
-        # l1 can still decode inside its reserved blocks
-        res = eng.decode_step("l1", int(res.token[0]), dec)
-        # ...but extension past block 2 backpressures instead of OOMing
-        eng.sessions["l1"].pos = 16
-        with pytest.raises(KVPoolExhausted):
-            eng.decode_step("l1", int(res.token[0]), dec)
-        eng.end_session("l1")
-        assert eng.kv_pool.used == 0
-        eng.kv_pool.check_conservation([])
-    finally:
-        eng.close()
-        reset_settings_cache()
-
-
-def test_local_engine_paged_prefix_facade(tiny_llama_dir, paged_env):
-    """LocalEngine + prefix cache under paging: hits restore through the
-    pool (dense facade) and the stream continues correctly."""
-    from dnet_tpu.core.engine import LocalEngine
-
-    dense = LocalEngine(
-        tiny_llama_dir, max_seq=64, param_dtype="float32", kv_paged=False
-    )
-    eng = LocalEngine(
-        tiny_llama_dir, max_seq=64, param_dtype="float32", kv_paged=True,
-        prefix_cache_size=4,
-    )
-    try:
-        from dnet_tpu.kv import PagedPrefixCache
-
-        assert isinstance(eng.prefix_cache, PagedPrefixCache)
-        eng.prefix_cache.min_tokens = 8
-        dec = DecodingParams(temperature=0.0)
-        base = list(range(280, 296))
-        grown = base + [3, 1, 4]
-
-        def greedy(e, ids, n, nonce):
-            return [
-                r.token_id
-                for r in e.generate(ids, dec, max_tokens=n, nonce=nonce)
-            ]
-
-        want = greedy(dense, grown, 6, "ref")
-        greedy(eng, base, 4, "turn1")  # stores the base snapshot
-        assert greedy(eng, grown, 6, "turn2") == want  # restores via blocks
-        assert eng.prefix_cache.stats["hits"] >= 1
-        eng.kv_pool.check_conservation()
-    finally:
-        dense.close()
         eng.close()

@@ -155,7 +155,10 @@ def test_batched_engine_prefix_cache_hits(tiny_llama_dir):
     assert n == len(prompt)
     logits2 = eng.prefill_chunk("r2", prompt2[n:])
     r2 = eng.adopt_prefilled("r2", logits2, dec)
-    assert eng.eng.prefix_cache.stats["hits"] == 1
+    # the capacity went to whichever layout serves: block aliasing over the
+    # pool (what this model derives), or the inner engine's snapshots
+    assert eng.eng.prefix_cache is None
+    assert eng.paged_prefix.stats["hits"] == 1
 
     # equivalence: suffix-only prefill == full prefill
     full = eng.prefill_and_sample("r3", prompt2, dec)

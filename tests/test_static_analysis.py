@@ -1499,3 +1499,27 @@ def test_makefile_has_dnetlint_diff_target():
     text = (REPO / "Makefile").read_text()
     assert "dnetlint-diff:" in text
     assert "--diff $(REV)" in text
+
+
+# ---- import direction: the layers below the api never import it -------------
+
+
+@pytest.mark.parametrize("package", ["core", "kv", "ops", "models"])
+def test_lower_layers_do_not_import_the_api(package):
+    """api/ depends on core/, kv/, ops/ and models/, never the other way
+    round (what both need lives in a leaf: core/types.py holds the typed
+    errors core/ raises and api/http.py maps).  sched/ is not in the list:
+    its adapter IS an api strategy."""
+    hits = []
+    for path in sorted((REPO / "dnet_tpu" / package).rglob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            names = []
+            if isinstance(node, ast.ImportFrom) and node.module:
+                names = [node.module]
+            elif isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            hits += [
+                f"{path.relative_to(REPO)}:{node.lineno} imports {n}"
+                for n in names if n == "dnet_tpu.api" or n.startswith("dnet_tpu.api.")
+            ]
+    assert not hits, hits
