@@ -524,7 +524,7 @@ def check_attribution_labels(errors: list) -> int:
         errors, text, "dnet_decode_tokens_total", "source",
         DECODE_TOKEN_SOURCES, "obs.phases.DECODE_TOKEN_SOURCES",
     )
-    from dnet_tpu.obs.phases import KV_KINDS, MOE_HELD
+    from dnet_tpu.obs.phases import KV_KINDS, MOE_HELD, MOE_PATHS
 
     for fam in ("dnet_kv_blocks_used", "dnet_kv_blocks_free", "dnet_kv_pool_blocks"):
         n += _cross_check_labels(
@@ -533,6 +533,10 @@ def check_attribution_labels(errors: list) -> int:
     n += _cross_check_labels(
         errors, text, "dnet_moe_assignments_total", "held", MOE_HELD,
         "obs.phases.MOE_HELD",
+    )
+    n += _cross_check_labels(
+        errors, text, "dnet_moe_expert_rows_total", "path", MOE_PATHS,
+        "obs.phases.MOE_PATHS",
     )
     from dnet_tpu.core.batch import BatchedEngine
 

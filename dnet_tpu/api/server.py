@@ -116,12 +116,16 @@ async def serve_async(args) -> None:
         )
         cluster_manager = ClusterManager(discovery)
         # callback address shards dial for SendToken: explicit override, else
-        # the interface facing the shards (reference http_api.py:188-196)
-        from dnet_tpu.utils.network import primary_ip
+        # the interface facing the shards (reference http_api.py:188-196); a
+        # server bound to loopback can only be dialled there, whatever
+        # discovery has heard so far (UDP discovery may know no peer yet)
+        from dnet_tpu.utils.network import LOOPBACK_HOSTS, primary_ip
 
-        callback_addr = s.api.callback_addr or (
-            f"{primary_ip(d.host for d in discovery.peers())}:{args.grpc_port}"
+        callback_host = (
+            args.host if args.host in LOOPBACK_HOSTS
+            else primary_ip(d.host for d in discovery.peers())
         )
+        callback_addr = s.api.callback_addr or f"{callback_host}:{args.grpc_port}"
         model_manager = RingModelManager(
             inference,
             cluster_manager,

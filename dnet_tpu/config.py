@@ -217,13 +217,14 @@ class ComputeSettings(_EnvGroup):
     window_size: int = 0  # 0 = all assigned layers in one window
     residency_windows: int = 2
     donate_activations: bool = True
-    # MoE compute path: dense | auto | dispatch | a2a (ops/moe.py).  dense
-    # is exact (reference semantics) and the default; auto picks dense for
-    # decode-size token counts, capacity dispatch for prefill, and
-    # all_to_all expert parallelism when a tp axis is present — capacity
-    # dispatch may DROP over-capacity tokens (GShard semantics), a
-    # throughput trade the operator opts into.
-    moe_impl: str = "dense"
+    # MoE compute path: auto | dense | grouped | dispatch | a2a (ops/moe.py).
+    # auto (the default) is exact and decided from static shapes: on one
+    # rank, a program with more rows than the ridge (RIDGE_ROWS, 256)
+    # computes its routed experts by a no-drop grouped matmul over rows
+    # sorted by expert, any other program by the dense einsum (under a tp
+    # axis always).  dispatch and a2a may DROP over-capacity tokens (GShard
+    # semantics), a throughput trade the operator opts into by name.
+    moe_impl: str = "auto"
     # per-expert capacity = ceil(k * n_tokens * factor / n_experts);
     # <= 0 selects the exact no-drop capacity (C = n_tokens)
     moe_capacity_factor: float = 1.25
@@ -408,10 +409,17 @@ class SchedSettings(_EnvGroup):
     # per-tick token budget shared by chunked-prefill segments (1 token
     # each) and decode steps (1 per running sequence)
     sched_token_budget: int = 2048
-    # largest chunked-prefill segment per request per tick
-    sched_prefill_chunk: int = 256
+    # largest chunked-prefill segment per request per tick; 0 = the token
+    # budget (a prompt's chunk is as wide as the budget holds: one pass
+    # over the weights for it, not one every 256 tokens)
+    sched_prefill_chunk: int = 0
     # batch lanes the scheduler engine allocates; 0 = max(batch_slots, 8)
     sched_slots: int = 0
+
+    def prefill_chunk_cap(self) -> int:
+        """The resolved cap: what the scheduler hands one request a tick at
+        most, and what the window kind's pool is sized to take."""
+        return int(self.sched_prefill_chunk) or int(self.sched_token_budget)
 
 
 @dataclass

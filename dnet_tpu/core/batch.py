@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dnet_tpu.core.engine import LocalEngine
+from dnet_tpu.core.engine import LocalEngine, count_expert_rows
 from dnet_tpu.core.sampler import (
     MAX_LOGIT_BIAS,
     MAX_TOP_LOGPROBS,
@@ -309,7 +309,7 @@ class BatchedEngine:
         every slot may hold the most blocks a window table ever has."""
         from dnet_tpu.config import get_settings
 
-        step = max(int(get_settings().sched.sched_prefill_chunk), *self.CHUNK_BUCKETS)
+        step = max(get_settings().sched.prefill_chunk_cap(), *self.CHUNK_BUCKETS)
         per_slot = window_blocks(int(m.window), cfg.block_tokens, step)
         wcfg = PagedKVConfig(cfg.block_tokens, slots * per_slot)
         return KindStore(
@@ -991,8 +991,11 @@ class BatchedEngine:
             if self.kv_pool is not None:
                 # the pool is attended IN PLACE through the page tables and
                 # the new rows block-append, all inside the launch
+                count_expert_rows(self.eng.model, self.slots, R)
                 src = self._dispatch_ragged(order, R, dev, table_ids)
             else:
+                # vmapped over the slots: each lane's experts see one row
+                count_expert_rows(self.eng.model, 1, R * self.slots)
                 token_d, pos_d, active_d, sp = dev
                 step = self._chunk_fn(R) if R > 1 else self._step
                 src, self.kv, self.counts, self.keys = step(

@@ -33,6 +33,7 @@ from dnet_tpu.core.types import DecodingParams, TokenResult
 from dnet_tpu.models import ModelConfig, get_ring_model_cls
 from dnet_tpu.obs import get_recorder, metric
 from dnet_tpu.obs.jit import instrument_jit
+from dnet_tpu.obs.phases import MOE_PATHS
 from dnet_tpu.utils.checkpoint import Checkpoint
 from dnet_tpu.utils.logger import get_logger
 
@@ -42,6 +43,17 @@ _DECODE_STEP_MS = metric("dnet_decode_step_ms")
 _PREFILL_MS = metric("dnet_prefill_ms")
 _LAYER_MS = metric("dnet_layer_compute_ms")
 _SESS_EVICTED = metric("dnet_kv_sessions_evicted_total")
+_MOE_EXPERT_ROWS = metric("dnet_moe_expert_rows_total")
+
+
+def count_expert_rows(model, rows: int, passes: int = 1) -> None:
+    """Book `passes` launched programs of `rows` rows under the path their
+    routed experts take (dnet_moe_expert_rows_total{path=}; nothing for a
+    model without routed experts or a path chosen by name outside
+    MOE_PATHS).  Host arithmetic on static shapes: no device sync."""
+    path = model.moe_path(rows)
+    if path in MOE_PATHS:
+        _MOE_EXPERT_ROWS.labels(path=path).inc(rows * passes)
 
 
 def bucket_length(n: int, min_bucket: int = 16) -> int:
@@ -730,6 +742,7 @@ class LocalEngine:
         Tpad = min(bucket_length(T), self.max_seq - sess.pos)
         tokens = np.zeros((self.batch, Tpad), dtype=np.int32)
         tokens[:, :T] = np.asarray(prompt_ids, dtype=np.int32)
+        count_expert_rows(self.model, self.batch * Tpad)
         if self.plan.streams_weights:
             x = self.model.embed(self.edge_params, jnp.asarray(tokens))
             x = self.run_layers(sess, x, sess.pos, t_real=T)

@@ -123,6 +123,10 @@ class RingModel(abc.ABC):
     # per-layer param names eligible for weight-only quantization (the big
     # matmuls; norms/biases/routers stay float).  Subclasses override.
     quant_keys: frozenset = frozenset(QUANTIZABLE)
+    # routed experts: None = the model has none; True = its experts have
+    # the exact grouped closure beside the dense one (ops/moe.py:
+    # swiglu_grouped_closure), False = dense closures only
+    moe_grouped: Optional[bool] = None
 
     def __init__(self, config: ModelConfig, layers: Sequence[int]):
         self.config = config
@@ -140,6 +144,16 @@ class RingModel(abc.ABC):
         cs = get_settings().compute
         self.moe_impl = cs.moe_impl
         self.moe_capacity_factor = cs.moe_capacity_factor
+
+    def moe_path(self, rows: int) -> Optional[str]:
+        """The compute path this model's routed experts take in a one-rank
+        program of `rows` rows: the rule `moe_apply` follows at trace time,
+        asked on the host (None: no routed experts)."""
+        if self.moe_grouped is None:
+            return None
+        from dnet_tpu.ops.moe import resolve_moe_impl
+
+        return resolve_moe_impl(self.moe_impl, rows, 1, self.moe_grouped)
 
     # ---- pure compute -------------------------------------------------
     def embed(self, edge_params: dict, tokens: jnp.ndarray) -> jnp.ndarray:

@@ -70,6 +70,7 @@ class Cohere2MoeRingModel(RingModel):
     #: under an `attend_fn` the scan stacks each lane's count of chosen
     #: experts held here as `moe_held` (dnet_moe_assignments_total)
     reports_moe_held = True
+    moe_grouped = True
     quant_keys = frozenset(
         {"wq", "wk", "wv", "wo", "e_gate", "e_up", "e_down",
          "s_gate", "s_up", "s_down"}
@@ -214,7 +215,12 @@ class Cohere2MoeRingModel(RingModel):
         its rounded copy `h`: with sigmoid scores near 1 the eighth and
         the ninth expert lie close, and a flip moves 1/8 of a token's
         routed term."""
-        from dnet_tpu.ops.moe import held_assignments, moe_apply, swiglu_expert_closures
+        from dnet_tpu.ops.moe import (
+            held_assignments,
+            moe_apply,
+            swiglu_expert_closures,
+            swiglu_grouped_closure,
+        )
 
         B, T, D = h.shape
         flat = h.reshape(B * T, D)
@@ -235,6 +241,9 @@ class Cohere2MoeRingModel(RingModel):
             self.moe_impl, flat, top_idx, top_w, effn, E_local,
             self.moe_capacity_factor, k, None, dense,
             offset=self.expert_offset, n_routed=self.n_routed,
+            grouped_fn=swiglu_grouped_closure(
+                p, flat, top_idx, top_w, offset=self.expert_offset
+            ),
         )
         out = out.astype(jnp.float32)
         if self.n_shared:

@@ -49,6 +49,7 @@ class DeepseekV2RingModel(TwoSegmentStackMixin, RingModel):
     model_type = "deepseek_v2"
     supports_kv_commit = True
     ring_phases = 2  # mesh ring: lap 0 = dense slices, lap 1 = moe slices
+    moe_grouped = True
     quant_keys = frozenset(
         {"wq", "wq_a", "wq_b", "wkv_a", "wkv_b", "wo",  # MLA projections
          "w_gate", "w_up", "w_down",  # dense mlp
@@ -198,7 +199,11 @@ class DeepseekV2RingModel(TwoSegmentStackMixin, RingModel):
             topk_w = topk_w / jnp.sum(topk_w, axis=-1, keepdims=True)
         topk_w = topk_w * self.routed_scaling_factor
 
-        from dnet_tpu.ops.moe import moe_apply, swiglu_expert_closures
+        from dnet_tpu.ops.moe import (
+            moe_apply,
+            swiglu_expert_closures,
+            swiglu_grouped_closure,
+        )
 
         topk_idx = topk_idx.astype(jnp.int32)
         effn, dense, E_local = swiglu_expert_closures(
@@ -207,6 +212,7 @@ class DeepseekV2RingModel(TwoSegmentStackMixin, RingModel):
         routed, routed_partial = moe_apply(
             self.moe_impl, flat, topk_idx, topk_w, effn, E_local,
             self.moe_capacity_factor, k, tp_axis, dense,
+            grouped_fn=swiglu_grouped_closure(p, flat, topk_idx, topk_w),
         )
 
         # shared experts are Megatron-split over tp (col/row), so their

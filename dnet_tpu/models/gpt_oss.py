@@ -50,6 +50,7 @@ LIMIT = 7.0
 
 class GptOssRingModel(RingModel):
     model_type = "gpt_oss"
+    moe_grouped = False  # its own closures (biases, clipped GLU): dense
 
     def __init__(self, config: ModelConfig, layers):
         super().__init__(config, layers)

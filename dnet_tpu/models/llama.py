@@ -142,8 +142,25 @@ class LlamaRingModel(RingModel):
             )
             return xc, kvs
 
-        x, kv_out = lax.scan(body, x, (window_params, kv))
-        return x, kv_out
+        stacks = None
+        if tp_axis is None and self.moe_path(x.shape[0] * x.shape[1]) == "grouped":
+            from dnet_tpu.ops.moe import expert_stacks
+
+            stacks = expert_stacks(window_params)
+        if stacks is None:
+            x, kv_out = lax.scan(body, x, (window_params, kv))
+            return x, kv_out
+
+        # routed experts grouped: the kernel reads each layer's experts out
+        # of the stack in place (ops/moe.py: grouped_matmul), so the scan
+        # closes over the stacks and carries the layer's index beside its
+        # other parameters (their per-layer slices go unused, and away)
+        def body_stacked(carry, per_layer):
+            p, kvs, layer = per_layer
+            return body(carry, ({**p, "e_stack": (stacks, layer)}, kvs))
+
+        layers = jnp.arange(stacks["e_gate"].shape[0], dtype=jnp.int32)
+        return lax.scan(body_stacked, x, (window_params, kv, layers))
 
     def normalize(self, edge_params: dict, x: jnp.ndarray) -> jnp.ndarray:
         return rms_norm(x, edge_params["final_norm"]["weight"], self.config.rms_norm_eps)

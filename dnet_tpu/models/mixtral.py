@@ -41,6 +41,7 @@ class MixtralRingModel(LlamaRingModel):
     # renormalize the kept top-k weights; always on for mixtral, config-read
     # for qwen3_moe ("only diff with mixtral sparse moe block" per HF)
     norm_topk_prob = True
+    moe_grouped = True
 
     @jax.named_scope(SCOPE_MOE)
     def _mlp_block(self, p: dict, x: jnp.ndarray, tp_axis=None) -> jnp.ndarray:
@@ -58,7 +59,11 @@ class MixtralRingModel(LlamaRingModel):
             top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
         top_idx = top_idx.astype(jnp.int32)
 
-        from dnet_tpu.ops.moe import moe_apply, swiglu_expert_closures
+        from dnet_tpu.ops.moe import (
+            moe_apply,
+            swiglu_expert_closures,
+            swiglu_grouped_closure,
+        )
 
         effn, dense, E_local = swiglu_expert_closures(
             p, flat, scores, top_idx, top_w, tp_axis
@@ -66,6 +71,7 @@ class MixtralRingModel(LlamaRingModel):
         routed, routed_partial = moe_apply(
             self.moe_impl, flat, top_idx, top_w, effn, E_local,
             self.moe_capacity_factor, k, tp_axis, dense,
+            grouped_fn=swiglu_grouped_closure(p, flat, top_idx, top_w),
         )
         out = routed.astype(flat.dtype)
         if tp_axis is not None and routed_partial:

@@ -139,7 +139,7 @@ def _register_core(reg: MetricsRegistry) -> None:
     )
     # paged KV pool (dnet_tpu/kv/paged.py): used + free == pool size at all
     # times (shared blocks count once in used; BlockPool.check_conservation)
-    from dnet_tpu.obs.phases import KV_KINDS, MOE_HELD
+    from dnet_tpu.obs.phases import KV_KINDS, MOE_HELD, MOE_PATHS
 
     for name, help_text in (
         ("dnet_kv_blocks_used",
@@ -168,6 +168,16 @@ def _register_core(reg: MetricsRegistry) -> None:
     )
     for held in MOE_HELD:
         moe_fam.labels(held=held)  # pre-touch: the lint checks these
+    rows_fam = reg.counter(
+        "dnet_moe_expert_rows_total",
+        "Rows (padding included) of the prefill chunks and decode steps "
+        "launched for a model with routed experts, by the compute path "
+        "its experts take at that row count (grouped above the ridge, "
+        "dense at or under it: ops/moe.py)",
+        labelnames=("path",),
+    )
+    for path in MOE_PATHS:
+        rows_fam.labels(path=path)  # pre-touch: the lint checks these
     reg.counter(
         "dnet_kv_cow_copies_total",
         "Paged KV copy-on-write block copies (shared block diverged)",

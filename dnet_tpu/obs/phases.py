@@ -96,6 +96,13 @@ KV_KINDS = (KV_KIND_FULL, KV_KIND_WINDOW)
 # read back with the step's tokens
 MOE_HELD = ("yes", "no")
 
+# dnet_moe_expert_rows_total{path=}: rows of the prefill chunks and decode
+# steps launched for a model with routed experts, by the exact compute
+# path the rule gives a program of that many rows (ops/moe.py:
+# resolve_moe_impl through RingModel.moe_path) — counted on the host at
+# the launch, from the program's static row count
+MOE_PATHS = ("grouped", "dense")
+
 # dnet_decode_tokens_total{source=}: where a token decode_batch handed the
 # driver came from (core/batch.py)
 #   dispatch — first row of the dispatch this call made

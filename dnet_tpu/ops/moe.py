@@ -10,10 +10,16 @@ expert computes only the tokens routed to it, and a true expert-parallel
 path where `lax.all_to_all` routes per-expert token buffers between ranks
 over ICI.
 
-Three interchangeable compute paths over the same routed-FFN semantics:
+Four interchangeable compute paths over the same routed-FFN semantics:
 
-- dense       every token x every (local) expert; exact, best for decode-size
-              token counts (the models keep this path inline).
+- dense       every token x every (local) expert; exact, and as cheap as
+              anything at or under the ridge (RIDGE_ROWS), where a pass
+              costs the read of the experts' weights whatever the rows.
+- grouped     the (token, slot) assignments sorted by expert, the rows
+              gathered, and gate / up / down run as grouped matmuls with
+              per-expert group sizes (`grouped_matmul`): exact, nothing
+              dropped, work proportional to the rows.  What `auto` picks
+              above the ridge on one rank.
 - dispatch    scatter tokens into per-expert capacity buffers [E, C, D], run
               the FFN once over the buffers, gather back weighted by the
               router probs.  FLOPs drop from N*E*ffn to E*C*ffn ~= k*cf*N*ffn.
@@ -34,7 +40,7 @@ scans cleanly.
 from __future__ import annotations
 
 import math
-from typing import Callable
+from typing import Callable, Optional
 
 import jax.numpy as jnp
 from jax import lax
@@ -49,26 +55,41 @@ def expert_capacity(n_tokens: int, n_experts: int, k: int, factor: float) -> int
     return max(1, min(int(n_tokens), c))
 
 
-MOE_IMPLS = ("auto", "dense", "dispatch", "a2a")
+MOE_IMPLS = ("auto", "dense", "grouped", "dispatch", "a2a")
+
+#: The most rows a program may carry and still take the dense einsum under
+#: `auto`.  A bf16 matmul on the v5e turns from memory- to compute-bound at
+#: 197e12 FLOP/s / 819e9 B/s = 240 FLOP a byte of weights = 240 rows: under
+#: it a dense pass over E experts costs the read of their weights whatever
+#: the rows (qwen3-30b-a3b at 256 rows: 0.64 ms a down projection measured,
+#: 0.49 ms to read its 403 MB, 0.52 ms for its 103 GFLOP: on the ridge), so
+#: an exact grouped matmul, which reads the same weights, has nothing to
+#: win there and its sort, gather and unsort to lose.  Above it the dense
+#: einsum pays E/k times the routed FLOPs.  Measured crossover: PERF.md
+#: section 6, PR 31.
+RIDGE_ROWS = 256
 
 
-def resolve_moe_impl(impl: str, n_tokens: int, n_experts: int, ranks: int) -> str:
-    """Pick the compute path for a (token count, expert count, ranks) shape.
+def resolve_moe_impl(impl: str, n_rows: int, ranks: int, grouped: bool) -> str:
+    """The compute path for a program of `n_rows` rows, from static shapes
+    (this runs at trace time, so each padding bucket compiles the path that
+    fits it, and on the host to count rows by path: `moe_path`).
 
-    Shapes are static under jit, so this runs at trace time: each padding
-    bucket compiles the path that fits it.  Dense wins below ~2E tokens
-    (decode); above that dispatch cuts FLOPs by ~E/(k*cf), and with multiple
-    expert-sharded ranks the a2a path also shards the dispatch compute.
+    `auto`: one rank, a grouped closure supplied (`grouped`) and more rows
+    than the ridge -> `grouped`; else `dense`.  Both are exact.  Under a tp
+    axis `auto` is `dense`; `dispatch` and `a2a` (capacity semantics, mesh
+    paths) are only ever chosen by name.  `grouped` by name falls to
+    `dense` where it cannot run (no closure, several ranks): same result.
     """
     if impl not in MOE_IMPLS:
         # fail fast: a typo'd DNET_COMPUTE_MOE_IMPL would otherwise fall
         # through every model branch into silent dense compute
         raise ValueError(f"unknown moe_impl {impl!r}; expected one of {MOE_IMPLS}")
-    if impl != "auto":
+    if impl in ("dense", "dispatch", "a2a"):
         return impl
-    if n_tokens < max(2 * n_experts, 16):
+    if not grouped or ranks > 1:
         return "dense"
-    return "a2a" if ranks > 1 else "dispatch"
+    return "grouped" if impl == "grouped" or n_rows > RIDGE_ROWS else "dense"
 
 
 def route_positions(top_idx: jnp.ndarray, n_experts: int) -> jnp.ndarray:
@@ -164,6 +185,7 @@ def moe_apply(
     dense_fn: Callable[[], jnp.ndarray],
     offset: int = 0,
     n_routed: int = 0,
+    grouped_fn: Optional[Callable[[], jnp.ndarray]] = None,
 ):
     """One MoE layer through the selected compute path (shared by every MoE
     model; the models supply only their ffn/dense closures and routing).
@@ -173,7 +195,9 @@ def moe_apply(
     parallelism's share of a layer, here without its exchange): top_idx
     ranges over all n_routed, and the result is the held experts' part
     alone.  A layer that holds every expert passes the whole range
-    (offset 0, n_routed 0 = as many as it holds).
+    (offset 0, n_routed 0 = as many as it holds).  `grouped_fn`: the
+    family's exact grouped-matmul closure (`swiglu_grouped_closure`), for
+    the families that have one.
 
     Returns (out [N, D], partial): partial=True means the output is a
     per-rank partial sum the caller must psum over tp_axis (the Megatron
@@ -182,7 +206,7 @@ def moe_apply(
     ranks = 1 if tp_axis is None else lax.axis_size(tp_axis)
     n_experts = n_local * ranks  # tp ranks shard the (held) expert dim
     n_routed = n_routed or n_experts
-    impl = resolve_moe_impl(impl, flat.shape[0], n_routed, ranks)
+    impl = resolve_moe_impl(impl, flat.shape[0], ranks, grouped_fn is not None)
     if tp_axis is not None and (offset or n_routed != n_experts):
         raise NotImplementedError(
             "an expert share under a tp axis (the share is the expert-"
@@ -204,6 +228,8 @@ def moe_apply(
             flat, top_idx, top_w, ffn_local, n_local, capacity, tp_axis
         )
         return out, True
+    if impl == "grouped":
+        return grouped_fn(), False
     return dense_fn(), tp_axis is not None
 
 
@@ -320,3 +346,113 @@ def swiglu_expert_closures(p, flat, scores, top_idx, top_w, tp_axis, offset: int
         return jnp.einsum("ned,ne->nd", expert_out, w_local.astype(flat.dtype))
 
     return effn, dense, E_local
+
+
+#: rows one grid step of the grouped matmul multiplies by one expert's
+#: weights.  A step whose tile straddles experts is repeated for each, so
+#: work and weight reads grow with the tile: with about 128 sorted rows an
+#: expert (2048 rows, top-8 of 128) XLA's own lowering of `lax.ragged_dot`
+#: (tile 512) took 6.2 ms a layer where this tile takes 3.9 and the dense
+#: einsum 15.5 (PERF.md section 6, PR 31: the table).
+GROUP_TILE_ROWS = 128
+
+
+def grouped_matmul(
+    xs: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray, layer=None
+) -> jnp.ndarray:
+    """xs [M, K] rows sorted by group, w [G, K, N], sizes [G] -> [M, N]:
+    row r of group g is xs[r] @ w[g]; rows past the last group are left
+    undefined.  Operands' dtype out, float32 accumulation.
+
+    With `layer` (a traced index) w is a STACK [L, G, K, N] of which that
+    layer's groups are used.  A custom call cannot read a slice of its
+    operand the way a fused dot does: handed `w[layer]`, XLA copies the
+    layer's weights out of the stack before every call (qwen3-30b-a3b:
+    1.2 GB a layer, twice the kernel's own time).  So the kernel takes the
+    whole stack as [L*G, K, N] (a bitcast) with every other layer's groups
+    empty, and empty groups are never visited.
+
+    On a TPU (and in interpret mode under DNET_FLASH_INTERPRET=1) Pallas'
+    megablox kernel at GROUP_TILE_ROWS; elsewhere, and for a row count the
+    tile does not divide, `lax.ragged_dot`.  Not booked in `/health`'s
+    `kernels` block: the experts' path is counted by rows
+    (dnet_moe_expert_rows_total).
+    """
+    from dnet_tpu.ops.kernel_select import kernel_backend
+
+    backend = kernel_backend()
+    if backend is None or xs.shape[0] % GROUP_TILE_ROWS:
+        return lax.ragged_dot(xs, w if layer is None else w[layer], sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    if layer is not None:
+        n_layers, groups = w.shape[:2]
+        w = w.reshape(n_layers * groups, *w.shape[2:])
+        sizes = lax.dynamic_update_slice(
+            jnp.zeros((n_layers * groups,), sizes.dtype), sizes, (layer * groups,)
+        )
+    return gmm(
+        xs, w, sizes, preferred_element_type=xs.dtype,
+        tiling=(GROUP_TILE_ROWS, min(w.shape[1], 1024), min(w.shape[2], 1024)),
+        interpret=backend == "interpret",
+    )
+
+
+EXPERT_KEYS = ("e_gate", "e_up", "e_down")
+
+
+def expert_stacks(window_params: dict) -> Optional[dict]:
+    """The layer-stacked expert weights [L, E, ...] of a window, for a scan
+    over its layers to close over when its experts go grouped (the body
+    then hands them on as p["e_stack"] = (stacks, layer index), and
+    `grouped_matmul` reads the layer out of the stack in place).  None
+    where the window has no such weights or holds them quantized (a
+    dequantized layer is a fresh array anyway)."""
+    from dnet_tpu.ops.quant import is_quantized
+
+    stacks = {k: window_params.get(k) for k in EXPERT_KEYS}
+    if any(w is None or is_quantized(w) for w in stacks.values()):
+        return None
+    return stacks
+
+
+def swiglu_grouped_closure(p, flat, top_idx, top_w, offset: int = 0):
+    """The exact grouped-matmul twin of `swiglu_expert_closures`' dense():
+    same weights, same result, work proportional to the rows (one rank).
+
+    The N*k (token, slot) assignments are sorted by local expert id;
+    assignments to experts this process does not hold sort into a tail that
+    no group covers, is never computed and contributes zero (an expert
+    share works like a whole layer).  Operands and outputs keep the
+    weights' dtype with float32 accumulation, like the dense einsum; no
+    capacity, nothing dropped.  Where the layer scan handed the stacks on
+    (p["e_stack"], `expert_stacks`) the weights are read out of them.
+    """
+    import jax
+
+    from dnet_tpu.ops.quant import dq, lead_dim
+
+    N, k = top_idx.shape
+    M = N * k
+    E_local = lead_dim(p["e_gate"])
+
+    def grouped():
+        local = localize_topk(top_idx, offset, E_local).reshape(M)
+        order = jnp.argsort(local, stable=True)  # sorted place -> assignment
+        sizes = jnp.sum(
+            local[:, None] == jnp.arange(E_local, dtype=jnp.int32)[None, :],
+            axis=0, dtype=jnp.int32,
+        )
+        xs = flat[order // k]  # [M, D], rows in expert order
+        w, layer = p.get("e_stack") or ({n: dq(p[n]) for n in EXPERT_KEYS}, None)
+        gate = grouped_matmul(xs, w["e_gate"], sizes, layer)
+        up = grouped_matmul(xs, w["e_up"], sizes, layer)
+        ys = grouped_matmul(jax.nn.silu(gate) * up, w["e_down"], sizes, layer)
+        # rows past the last group are whatever the kernel left there
+        held = jnp.arange(M) < jnp.sum(sizes)
+        ys = jnp.where(held[:, None], ys, 0)
+        place = jnp.zeros((M,), jnp.int32).at[order].set(jnp.arange(M, dtype=jnp.int32))
+        y = ys[place].reshape(N, k, ys.shape[-1])
+        return jnp.einsum("nkd,nk->nd", y, top_w.astype(y.dtype))
+
+    return grouped

@@ -88,7 +88,7 @@ class SchedulerAdapter(ApiAdapterBase):
         self.engine = engine
         self.policy = SchedulerPolicy(
             token_budget=token_budget or sched.sched_token_budget,
-            prefill_chunk=prefill_chunk or sched.sched_prefill_chunk,
+            prefill_chunk=prefill_chunk or sched.prefill_chunk_cap(),
         )
         self.queue = SchedQueue()
         self._futures = _TokenFutures()

@@ -16,7 +16,9 @@ work into one :class:`TickPlan`:
    phase (``BatchedEngine.decode_batch``).  The active set is fixed per
    dispatch, so streams are bit-identical to serial stepping for any R.
 2. **Chunked prefill fills the remainder.**  PREFILLING requests continue
-   (most urgent first) in ``DNET_SCHED_PREFILL_CHUNK``-bounded segments.
+   (most urgent first), each by a segment as wide as the budget still
+   holds (one pass over the weights for the prompt, not one every few
+   hundred tokens), or ``DNET_SCHED_PREFILL_CHUNK`` where that is set.
 3. **Admission.**  WAITING requests are admitted most-urgent-first while
    a batch slot is free and the paged-KV pool can cover their whole
    prompt (``BlockPool.can_cover`` — admission is a function of FREE

@@ -6,6 +6,9 @@ import socket
 from typing import Iterable
 
 
+LOOPBACK_HOSTS = ("127.0.0.1", "localhost", "::1")
+
+
 def primary_ip(peer_hosts: Iterable[str] = ()) -> str:
     """Best-effort address peers can reach us on.
 
@@ -14,7 +17,7 @@ def primary_ip(peer_hosts: Iterable[str] = ()) -> str:
     to find the outbound interface address.
     """
     peers = [h for h in peer_hosts if h]
-    non_loop = [h for h in peers if h not in ("127.0.0.1", "localhost", "::1")]
+    non_loop = [h for h in peers if h not in LOOPBACK_HOSTS]
     if peers and not non_loop:
         return "127.0.0.1"
     target = non_loop[0] if non_loop else "8.8.8.8"

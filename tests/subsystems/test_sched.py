@@ -108,6 +108,72 @@ def test_prefill_segments_bounded_by_chunk():
     assert plan2.prefills[0].last and plan2.prefills[0].end == 100
 
 
+def test_a_prompt_longer_than_the_budget_is_two_chunks_under_the_default_cap():
+    """No chunk setting: the cap is the token budget, so a 3000-token
+    prompt under budget 2048 is two chunks (2048, 952), and a prompt the
+    budget holds is one, the next prompt taking what is left."""
+    q = SchedQueue()
+    p = _add(q, "p1", 3000)
+    p.state = STATE_PREFILLING
+    eng = FakeEngine()
+    eng.max_seq = 4096
+    policy = SchedulerPolicy(token_budget=2048, prefill_chunk=2048)
+    first = policy.plan(q, eng).prefills
+    assert [(c.start, c.end, c.last) for c in first] == [(0, 2048, False)]
+    p.prefilled = 2048
+    second = policy.plan(q, eng).prefills
+    assert [(c.start, c.end, c.last) for c in second] == [(2048, 3000, True)]
+    p.prefilled = 0
+    nxt = _add(q, "p2", 1500)
+    nxt.state = STATE_PREFILLING
+    p.ids = p.ids[:1900]
+    both = policy.plan(q, eng).prefills
+    assert [(c.nonce, c.end - c.start) for c in both] == [("p1", 1900), ("p2", 148)]
+
+
+def test_an_explicit_prefill_chunk_still_caps():
+    q = SchedQueue()
+    p = _add(q, "p1", 3000)
+    p.state = STATE_PREFILLING
+    seg = SchedulerPolicy(token_budget=2048, prefill_chunk=256).plan(
+        q, FakeEngine()
+    ).prefills[0]
+    assert seg.end - seg.start == 256
+
+
+@pytest.mark.parametrize(
+    "env, want",
+    [
+        ({}, (2048, 2048)),  # the default: the cap is the budget
+        ({"DNET_SCHED_TOKEN_BUDGET": "256"}, (256, 256)),
+        ({"DNET_SCHED_PREFILL_CHUNK": "16"}, (2048, 16)),  # given: it caps
+        ({"DNET_SCHED_TOKEN_BUDGET": "16", "DNET_SCHED_PREFILL_CHUNK": "8"}, (16, 8)),
+    ],
+    ids=["default", "budget-256", "chunk-16", "both"],
+)
+def test_the_adapters_cap_is_the_setting_or_the_budget(env, want, monkeypatch):
+    from dnet_tpu.config import get_settings, reset_settings_cache
+    from dnet_tpu.sched.engine import SchedulerAdapter
+
+    class Chunked(FakeEngine):
+        def prefill_chunk(self, *a):  # the surface the adapter asks for
+            raise NotImplementedError
+
+    for k in ("DNET_SCHED_TOKEN_BUDGET", "DNET_SCHED_PREFILL_CHUNK"):
+        monkeypatch.delenv(k, raising=False)
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    reset_settings_cache()
+    try:
+        policy = SchedulerAdapter(Chunked()).policy
+        assert (policy.token_budget, policy.prefill_chunk) == want
+        assert get_settings().sched.prefill_chunk_cap() == want[1]
+        given = SchedulerAdapter(Chunked(), token_budget=64, prefill_chunk=4).policy
+        assert (given.token_budget, given.prefill_chunk) == (64, 4)
+    finally:
+        reset_settings_cache()
+
+
 def test_decode_without_pending_step_not_dispatched():
     """A DECODING lane whose driver has not asked for the next token yet
     (SSE backpressure) stays parked: dispatching it would sample a token

@@ -167,10 +167,17 @@ def test_moe_sharded_matches_dense(rng, eight_devices, impl):
 
 
 def test_resolve_moe_impl():
-    assert resolve_moe_impl("dense", 10_000, 8, 4) == "dense"  # explicit wins
-    assert resolve_moe_impl("auto", 8, 32, 1) == "dense"  # decode-size
-    assert resolve_moe_impl("auto", 4096, 32, 1) == "dispatch"
-    assert resolve_moe_impl("auto", 4096, 32, 4) == "a2a"
+    """(impl, rows, ranks, a grouped closure supplied): `auto` is exact,
+    chosen at the ridge on one rank; capacity paths only by name (the
+    cases by row count: tests/test_moe_grouped.py)."""
+    assert resolve_moe_impl("dense", 10_000, 4, True) == "dense"  # explicit wins
+    assert resolve_moe_impl("auto", 8, 1, True) == "dense"  # decode-size
+    assert resolve_moe_impl("auto", 256, 1, True) == "dense"  # on the ridge
+    assert resolve_moe_impl("auto", 257, 1, True) == "grouped"
+    assert resolve_moe_impl("auto", 257, 1, False) == "dense"  # no closure
+    assert resolve_moe_impl("auto", 4096, 4, True) == "dense"  # several ranks
+    assert resolve_moe_impl("dispatch", 4096, 1, True) == "dispatch"
+    assert resolve_moe_impl("a2a", 4096, 4, True) == "a2a"
 
 
 @pytest.fixture(scope="module")
