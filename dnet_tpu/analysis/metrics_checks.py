@@ -524,12 +524,18 @@ def check_attribution_labels(errors: list) -> int:
         errors, text, "dnet_decode_tokens_total", "source",
         DECODE_TOKEN_SOURCES, "obs.phases.DECODE_TOKEN_SOURCES",
     )
-    from dnet_tpu.obs.phases import KV_KINDS, MOE_HELD, MOE_PATHS
+    from dnet_tpu.obs.phases import KV_KINDS, MOE_HELD, MOE_PATHS, RETENTION_PHASES
 
+    # the block families carry the kinds that HAVE blocks; the state kind
+    # (KV_KIND_STATE) keeps its books in dnet_state_slots*
     for fam in ("dnet_kv_blocks_used", "dnet_kv_blocks_free", "dnet_kv_pool_blocks"):
         n += _cross_check_labels(
             errors, text, fam, "kind", KV_KINDS, "obs.phases.KV_KINDS"
         )
+    n += _cross_check_labels(
+        errors, text, "dnet_retention_tokens_total", "phase", RETENTION_PHASES,
+        "obs.phases.RETENTION_PHASES",
+    )
     n += _cross_check_labels(
         errors, text, "dnet_moe_assignments_total", "held", MOE_HELD,
         "obs.phases.MOE_HELD",

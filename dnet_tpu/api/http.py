@@ -729,6 +729,12 @@ class ApiHTTPServer:
                 # engine, adapter, KV layout and why (model_manager.py:
                 # serving_plan): the path no setting selects
                 body["serving"] = serving._asdict()
+                engine = getattr(self.inference.adapter, "engine", None)
+                refusal = getattr(engine, "prefix_refusal", None)
+                if refusal is not None:
+                    # prefix sharing was asked for and the cache's kind
+                    # cannot give it (core/batch.py: _init_state_store)
+                    body["serving"]["prefix_sharing"] = f"off: {refusal}"
         monitor = self.inference.failure_monitor
         quarantine = getattr(monitor, "quarantine", None)
         if quarantine is not None:

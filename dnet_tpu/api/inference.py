@@ -127,7 +127,11 @@ def _resolved_modes(adapter) -> dict:
     engine = getattr(adapter, "engine", None)
     return {
         "codec": s.wire.codec,
-        "kv": "paged" if getattr(engine, "kv_pool", None) is not None else "dense",
+        "kv": (
+            "paged" if getattr(engine, "kv_pool", None) is not None
+            else "state" if getattr(engine, "kv_store", None) is not None
+            else "dense"
+        ),
         "tp": int(s.tp.tp),
         "sched": type(adapter).__name__ == "SchedulerAdapter",
     }

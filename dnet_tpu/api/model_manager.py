@@ -42,8 +42,8 @@ def resolve_model_dir(model_id: str, models_dir: Optional[str | Path] = None) ->
 
 class ServingPlan(NamedTuple):
     """What serves a load: class names of the engine and the adapter, the
-    KV layout (core/batch.py KV_PAGED / KV_DENSE; "mesh" = sharded by the
-    mesh engine) and the one-line reason (the load's log line, /health)."""
+    KV layout (core/batch.py KV_PAGED / KV_DENSE / KV_STATE; "mesh" = sharded
+    by the mesh engine) and the one-line reason (the load's log line, /health)."""
 
     engine: str
     adapter: str
@@ -73,8 +73,10 @@ def serving_plan(
     2. weights stream from disk, the model has no gated KV writes, or a
        draft MODEL speculates -> LocalEngine, one sequence at a time;
     3. otherwise the scheduler over BatchedEngine's lanes;
-    4. its KV cache by core/batch.py: kv_layout — the paged pool attended
-       in place unless the kernel, the pool or speculation refuses."""
+    4. its KV cache by core/batch.py: kv_layout — a state entry a lane for
+       a model whose layers keep a recurrent state; else the paged pool
+       attended in place unless the kernel, the pool or speculation
+       refuses."""
     if mesh is not None:
         why = _pipeline_refusal(model, mesh, batch_slots, n_devices)
         if why is None:

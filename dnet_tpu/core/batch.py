@@ -53,6 +53,7 @@ from dnet_tpu.kv import (
     PagedKVConfig,
     PagedPrefixCache,
     PageTable,
+    StateStore,
     window_blocks,
     window_first_block,
 )
@@ -62,6 +63,7 @@ from dnet_tpu.obs.jit import instrument_jit
 from dnet_tpu.obs.phases import (
     DECODE_CHUNK_WIDTHS,
     KV_KIND_FULL,
+    KV_KIND_STATE,
     KV_KIND_WINDOW,
     SPAN_DECODE_LAUNCH,
     SPAN_DECODE_PREPARE,
@@ -79,18 +81,27 @@ _DECODE_LANE_STEPS = metric("dnet_decode_lane_steps_total")
 _DECODE_TOKENS = metric("dnet_decode_tokens_total")
 _DECODE_BUFFER_DROPPED = metric("dnet_decode_buffer_dropped_total")
 _MOE_ASSIGNMENTS = metric("dnet_moe_assignments_total")
+_STATE_SLOTS_USED = metric("dnet_state_slots_used")
+_RETENTION_BYTES = metric("dnet_retention_state_bytes_total")
+_RETENTION_TOKENS = metric("dnet_retention_tokens_total")
 
 
 KV_PAGED = "paged"  # page tables over the block pool, attended in place
 KV_DENSE = "dense"  # [L, slots, max_seq, ...] rows, one a lane
+KV_STATE = "state"  # one recurrent state entry a lane, updated in place
 
 
 def kv_layout(
     model, kv_quant_bits: int, spec_lookahead: int, max_seq: int
 ) -> Tuple[str, str]:
-    """THE rule for a batched engine's KV cache: (KV_PAGED | KV_DENSE, why).
+    """THE rule for a batched engine's KV cache: (KV_PAGED | KV_DENSE |
+    KV_STATE, why).
 
-    The paged pool attended in place, unless something the code can see
+    A model whose layers keep a recurrent state and no keys
+    (`model.paged_kinds` all `state`) serves from the state store, one
+    entry a lane, whatever was asked of the cache: there are no keys to
+    page, quantize or rewind.  Any other model: the paged pool attended in
+    place, unless something the code can see
     rules it out: per-lane speculation was asked for (its verify blocks
     rewind a dense cache: an explicit request outranks a derived default),
     the kernel refuses the model or the cache
@@ -99,6 +110,8 @@ def kv_layout(
     the same scheduler."""
     from dnet_tpu.ops.paged_attention import ragged_refusal
 
+    if KV_KIND_STATE in (getattr(model, "paged_kinds", None) or ()):
+        return KV_STATE, "one recurrent state entry a lane, updated in place"
     if spec_lookahead > 0:
         return KV_DENSE, "per-lane speculation needs the dense cache"
     why = ragged_refusal(model, kv_quant_bits)
@@ -194,8 +207,13 @@ class BatchedEngine:
         # WINDOW layers among full ones (model.paged_kinds) has the
         # `window` kind too, whose tables hold only the blocks inside the
         # window.  All empty under dense slots.
+        # A model of the `state` kind has a store (kv_store) and NO pool:
+        # kv_pool stays None, which is what admission, preemption and
+        # prefix sharing read, so a lane is all its sequences cost.
         self.kv_pool: Optional[BlockPool] = None
         self.kv_store: Optional[BlockStore] = None
+        #: why prefix sharing is off although it was asked for (/health)
+        self.prefix_refusal: Optional[str] = None
         self.paged_prefix: Optional[PagedPrefixCache] = None
         self._kv_cfg: Optional[PagedKVConfig] = None
         self._tables: List[Optional[PageTable]] = [None] * slots
@@ -205,6 +223,8 @@ class BatchedEngine:
         self._window = 0
         if layout == KV_PAGED:
             self._init_pool(m, slots, prefix_size)
+        elif layout == KV_STATE:
+            self._init_state_store(m, slots, prefix_size)
         else:
             (log.info if paged is False else log.warning)(
                 "KV cache: dense slots (%s)", why
@@ -215,7 +235,7 @@ class BatchedEngine:
                 self.eng.prefix_cache = PrefixCache(prefix_size)
         self.kv = (
             None
-            if self.kv_pool is not None
+            if self.kv_store is not None
             else m.init_kv(
                 len(m.layers), slots, self.max_seq, self.eng.kv_dtype,
                 quant_bits=self.eng.kv_quant_bits,
@@ -303,6 +323,30 @@ class BatchedEngine:
             cfg.pool_blocks, cfg.block_tokens, slots,
         )
 
+    def _init_state_store(self, m, slots: int, prefix_size: int) -> None:
+        """The state kind's store (KV_STATE): an entry a lane and nothing
+        to manage.  Prefix sharing is refused: a state holds the whole
+        sequence folded together and cannot be cut at a prefix."""
+        if prefix_size:
+            self.prefix_refusal = (
+                f"{self.eng.config.model_type} keeps a recurrent state, which "
+                "cannot be cut at a prefix (snapshots at block edges are not "
+                f"built): DNET_API_PREFIX_CACHE={prefix_size} is ignored"
+            )
+            log.warning("prefix sharing is OFF: %s", self.prefix_refusal)
+        if self.eng.kv_quant_bits:
+            log.warning(
+                "DNET_KV_BITS=%d is ignored: a state entry is float32",
+                self.eng.kv_quant_bits,
+            )
+        self.spec_lookahead = 0  # kv_rewindable is False: already warned
+        self.kv_store = StateStore(m, len(m.layers), slots)
+        _STATE_SLOTS_USED.set(0)
+        log.info(
+            "state store on: %d lanes x %.1f MB (%d layers), no blocks",
+            slots, self.kv_store.entry_bytes / 1e6, len(m.layers),
+        )
+
     def _window_store(self, m, cfg: PagedKVConfig, slots: int) -> KindStore:
         """Pools by kind for a model with window layers.  The window kind's
         pool is sized so that it can never be what admission waits for:
@@ -319,7 +363,7 @@ class BatchedEngine:
 
     # ---- program ------------------------------------------------------
     def _build(self) -> None:
-        if self.kv_pool is not None:
+        if self.kv_store is not None:
             self._build_ragged()
             return
         model = self.eng.model
@@ -448,12 +492,17 @@ class BatchedEngine:
             tables' `base` (_table_ids); pos [slots] int32 live pool rows
             per slot."""
 
-            def attend_fn(q, k_new, v_new, kvs, kind=None, layer=None):
+            def attend_fn(q, k_new, v_new, kvs, kind=None, layer=None, gate=None):
                 # a model of one kind hands over its layer's pool slice
                 # `kvs`; one of two names the layer's kind and its index
                 rows = {"k": k_new[:, 0], "v": v_new[:, 0]}
-                attn = store.attend(pool, kvs, q, rows, tables, pos, kind, layer, impl)
-                return attn, rows
+                if store.in_place:
+                    # the state kind: `kvs` is the stack the scan carries,
+                    # and the step is the read AND the write, for the
+                    # active lanes alone: attend returns (attn, the stack)
+                    rows.update(gate=gate[:, 0], active=active)
+                out = store.attend(pool, kvs, q, rows, tables, pos, kind, layer, impl)
+                return out if store.in_place else (out, rows)
 
             x = model.embed(ep, token)  # [slots, 1, D]
             x, rows = model.apply_window(
@@ -475,8 +524,11 @@ class BatchedEngine:
             return res, rows, counts, keys, moe
 
         self._ragged_step_fn = ragged_step
+        # a store updated in place rides the step donated (and comes back
+        # as `rows`); a pool is read only, its new rows appended after
+        donate = (3, 9) if store.in_place else (9,)
         self._ragged_step = instrument_jit(
-            jax.jit(ragged_step, donate_argnums=(9,)), "paged_attend"
+            jax.jit(ragged_step, donate_argnums=donate), "paged_attend"
         )
         self._ragged_chunks: Dict[int, Any] = {}
 
@@ -488,7 +540,7 @@ class BatchedEngine:
         fn = self._ragged_chunks.get(R)
         if fn is None:
             step = self._ragged_step_fn
-            bt = self._kv_cfg.block_tokens
+            bt = self._block_tokens
 
             @jax.named_scope("paged_attend")
             def chunk(wp, ep, token, pool, tables, pos, active, sp, keys,
@@ -579,7 +631,18 @@ class BatchedEngine:
         self.slot_of[nonce] = slot
         self.pos[slot] = 0
         self.last_used[slot] = time.time()
+        self._book_lanes()
         return slot
+
+    def _book_lanes(self) -> None:
+        if self.kv_store is not None and self.kv_store.in_place:
+            _STATE_SLOTS_USED.set(len(self.slot_of))
+
+    @property
+    def _block_tokens(self) -> int:
+        """Tokens a block of the pool holds (1 where no pool is: the state
+        kind's lanes have no blocks to offset into)."""
+        return self._kv_cfg.block_tokens if self._kv_cfg is not None else 1
 
     def free_slot(self, nonce: str) -> None:
         dropped = self._buffer.pop(nonce, None)
@@ -607,6 +670,7 @@ class BatchedEngine:
                 self.hist = self.hist.at[slot].set(0)
             self.pos[slot] = 0
             self._free.append(slot)
+            self._book_lanes()
 
     def end_session(self, nonce: str) -> None:
         self.free_slot(nonce)
@@ -704,6 +768,10 @@ class BatchedEngine:
             n_full = self._adopt.get(nonce, (0, [], 0))[2]
             need = self._kv_cfg.blocks_for(min(pos + len(ids), self.max_seq))
             self.kv_pool.require(max(need - n_full, 0))
+        elif self.kv_store is not None:
+            # the state kind: the chunk takes the session's entry in and
+            # hands it on; nothing to admit, the lane is already held
+            _RETENTION_TOKENS.labels(phase="prefill").inc(len(ids))
         return self.eng.prefill(nonce, list(ids), seed, allow_store=False)
 
     def abandon_prefill(self, nonce) -> None:
@@ -726,7 +794,11 @@ class BatchedEngine:
         (which already merged shared-partial content with the new tokens —
         the COW copy).  Window kind (no prefix to alias: the sharing is
         off): only the blocks the next token's window still reaches — the
-        blocks behind it are never allocated.  All or nothing."""
+        blocks behind it are never allocated.  All or nothing.  State
+        kind: the session's entry overwrites the lane's, whole."""
+        if self.kv_pool is None:
+            self.kv_store.commit_staged(sess.kv, {KV_KIND_STATE: slot})
+            return
         cfg = self._kv_cfg
         n = int(sess.pos)
         nb = cfg.blocks_for(n)
@@ -865,7 +937,7 @@ class BatchedEngine:
 
     def _move_to_slot(self, nonce: str, sess) -> None:
         slot = self.alloc_slot(nonce)
-        if self.kv_pool is not None:
+        if self.kv_store is not None:
             self._commit_paged_slot(nonce, slot, sess)
         else:
             self.kv = jax.tree.map(
@@ -988,7 +1060,7 @@ class BatchedEngine:
         order, R, dev, table_ids = plan
         lanes = len(order)
         with span(SPAN_DECODE_LAUNCH, R=R, lanes=lanes):
-            if self.kv_pool is not None:
+            if self.kv_store is not None:
                 # the pool is attended IN PLACE through the page tables and
                 # the new rows block-append, all inside the launch
                 count_expert_rows(self.eng.model, self.slots, R)
@@ -1012,7 +1084,7 @@ class BatchedEngine:
             lps = np.asarray(src.logprob)
             tts = np.asarray(src.top_tokens)
             tlps = np.asarray(src.top_logprobs)
-            if self.kv_pool is not None and self._moe_reported:
+            if self.kv_store is not None and self._moe_reported:
                 # summed on the device by the dispatch just read: no sync
                 mine, elsewhere = np.asarray(self._moe_pending)
                 _MOE_ASSIGNMENTS.labels(held="yes").inc(int(mine))
@@ -1044,6 +1116,11 @@ class BatchedEngine:
         _DECODE_SLOT_STEPS.inc(R * self.slots)
         _DECODE_LANE_STEPS.inc(R * lanes)
         _DECODE_TOKENS.labels(source="dispatch").inc(lanes)
+        if self.kv_store is not None and self.kv_store.in_place:
+            # what the algorithm needs: each active lane's entry read and
+            # written once a step, in every layer
+            _RETENTION_BYTES.inc(R * lanes * self.kv_store.entry_bytes * 2)
+            _RETENTION_TOKENS.labels(phase="decode").inc(R * lanes)
         # per-token share, observed tokens-served times: the family's
         # count stays == tokens across the local / chunked / speculative /
         # batched paths (LocalEngine's amortization convention), and the
@@ -1164,6 +1241,8 @@ class BatchedEngine:
             table_ids = jax.tree.map(
                 jnp.asarray, self._table_ids(order if R == 1 else None)
             )
+        elif self.kv_store is not None:
+            table_ids = {}  # the state kind: a lane IS the address
         dev = (jnp.asarray(token), jnp.asarray(pos), jnp.asarray(active), sp)
         return order, R, dev, table_ids
 
@@ -1192,7 +1271,7 @@ class BatchedEngine:
             self.kv_store.kv = pool
             return stacked
         res, rows, self.counts, self.keys, self._moe_pending = self._ragged_step(*args)
-        bt = self._kv_cfg.block_tokens
+        bt = self._block_tokens
         # inactive-lane sentinel: past the block axis, never negative
         # (see BlockStore.append_in_program)
         phys = {

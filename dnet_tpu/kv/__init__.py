@@ -3,7 +3,9 @@ copy-on-write prefix sharing, and free-block admission.
 
 Host half: `paged.py` (BlockPool / PageTable / KVPoolExhausted).
 Device half: `store.py` (pool-shaped arrays, attended in place, + the
-row gather / block commit programs of prefix restore and adoption).
+row gather / block commit programs of prefix restore and adoption; and the
+store of the third kind, a recurrent state entry a lane, which has no host
+half: no blocks to manage).
 Sharing: `prefix.py` (PagedPrefixCache over the same pool).
 
 The batched engine's KV layout wherever the model and the cache allow it
@@ -20,7 +22,7 @@ from dnet_tpu.kv.paged import (
     window_first_block,
 )
 from dnet_tpu.kv.prefix import PagedPrefixCache
-from dnet_tpu.kv.store import BlockStore, KindStore
+from dnet_tpu.kv.store import BlockStore, KindStore, StateStore
 
 __all__ = [
     "BlockPool",
@@ -30,6 +32,7 @@ __all__ = [
     "PagedKVConfig",
     "PagedPrefixCache",
     "PageTable",
+    "StateStore",
     "ceil_div",
     "window_blocks",
     "window_first_block",

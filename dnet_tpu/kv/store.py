@@ -13,7 +13,8 @@ no layout: `kinds`, `attend`, `append_in_program`, `append_rows` and
 `commit_staged` take and return `{kind: ...}`.  `BlockStore` is the
 one-kind store (`full` alone, the layout above, which the prefix cache
 also reads); `KindStore` holds a pool a kind for a model that mixes window
-and full layers.
+and full layers; `StateStore` holds the third kind, `state`: one recurrent
+entry a lane, no blocks, behind the same five names.
 
 Two programs move whole blocks between a staged dense row and the pool:
 
@@ -39,13 +40,17 @@ import numpy as np
 
 from dnet_tpu.kv.paged import PagedKVConfig
 from dnet_tpu.obs.jit import instrument_jit
+from dnet_tpu.obs import metric
 from dnet_tpu.obs.phases import (
     KV_KIND_FULL,
+    KV_KIND_STATE,
     KV_KIND_WINDOW,
     KV_KINDS,
     SCOPE_ATTN_FULL,
     SCOPE_ATTN_WINDOW,
 )
+
+_STATE_SLOTS = metric("dnet_state_slots")
 
 
 def _bucket_pow2(n: int) -> int:
@@ -57,6 +62,11 @@ def _bucket_pow2(n: int) -> int:
 
 class BlockStore:
     """Pool-shaped KV arrays + their cached programs."""
+
+    #: the decode step reads the pool and hands back the new rows, which
+    #: `append_rows` writes; a store that is `in_place` updates itself
+    #: inside the step instead (StateStore)
+    in_place = False
 
     def __init__(
         self,
@@ -226,6 +236,73 @@ class BlockStore:
         )
 
 
+class StateStore:
+    """The store of a model whose layers keep a recurrent STATE and no keys
+    (models that set every `paged_kinds` entry to `state`): per layer one
+    entry a lane, `S [L, slots, KVH, R, Hd, Hd]` and `z [L, slots, KVH, R,
+    Hd]` float32 (ops/retention.py), of one size whatever the sequence's
+    length.  No blocks, no page table, no pool manager: a lane is all a
+    sequence costs, so admission is by free lanes alone.
+
+    Behind the by-kind interface the decode path speaks: `attend` is the
+    whole of a layer's read, decay and update, in place on the (donated)
+    stack the model's scan carries, so `append_in_program` and
+    `append_rows` have nothing left to write but the stack the step handed
+    back; `commit_staged` is adoption: one lane's entry overwritten with a
+    prefilled session's."""
+
+    kinds = (KV_KIND_STATE,)
+    in_place = True
+
+    def __init__(self, model, n_layers: int, slots: int) -> None:
+        self.slots = slots
+        self.kv = model.init_kv(n_layers, slots, 0)
+        c = model.config
+        from dnet_tpu.ops.retention import state_entry_bytes
+
+        #: bytes of one lane's state over every layer
+        self.entry_bytes = n_layers * state_entry_bytes(c.num_key_value_heads, c.head_dim)
+        _STATE_SLOTS.set(slots)
+
+        @jax.named_scope("kv_scatter")
+        def adopt(store, row, slot):
+            """row leaves [L, 1, ...] -> store[:, slot]."""
+            return jax.tree.map(
+                lambda s, r: jax.lax.dynamic_update_slice_in_dim(
+                    s, r.astype(s.dtype), slot, axis=1
+                ),
+                store, row,
+            )
+
+        self._adopt = instrument_jit(jax.jit(adopt, donate_argnums=(0,)), "kv_scatter")
+
+    def attend(self, pool, kvs, q, rows, tables, pos, kind, layer, impl):
+        """Traced: one layer's decode step over every lane.  `kvs` is the
+        stack as the scan carries it (`pool`, the stack before the first
+        layer, goes unread); rows carries the lanes' new k and v, their
+        gates' logs and who is active.  Returns (o, the stack)."""
+        from dnet_tpu.ops.retention import retention_step
+
+        o, store = retention_step(
+            kvs, q[:, 0], rows["k"], rows["v"], rows["gate"], rows["active"],
+            layer, impl=impl,
+        )
+        return o[:, None], store
+
+    def append_in_program(self, pool, rows, phys, off):
+        """Traced: the step already wrote; `rows` IS the stack after it."""
+        return rows
+
+    def append_rows(self, rows: dict, phys: dict, off) -> None:
+        self.kv = rows
+
+    def commit_staged(self, kv_row: dict, blocks: dict) -> None:
+        """blocks: {state: the lane} of one session's [L, 1, ...] entries."""
+        self.kv = self._adopt(
+            self.kv, kv_row, jnp.asarray(blocks[KV_KIND_STATE], jnp.int32)
+        )
+
+
 class KindStore:
     """Pools for a model whose layers are of two KINDS (models that set
     `paged_kinds`: window and full attention mixed): each kind's layers
@@ -239,6 +316,8 @@ class KindStore:
 
     `self.kv` is `{kind: {"k": ..., "v": ...}}`; `self.layers[kind]` the
     local layer indices of the kind, in order."""
+
+    in_place = False
 
     def __init__(self, model, cfgs: dict, kv_dtype: str, window_width: int = 0) -> None:
         self.cfgs = cfgs

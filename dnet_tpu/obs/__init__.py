@@ -139,8 +139,31 @@ def _register_core(reg: MetricsRegistry) -> None:
     )
     # paged KV pool (dnet_tpu/kv/paged.py): used + free == pool size at all
     # times (shared blocks count once in used; BlockPool.check_conservation)
-    from dnet_tpu.obs.phases import KV_KINDS, MOE_HELD, MOE_PATHS
+    from dnet_tpu.obs.phases import KV_KINDS, MOE_HELD, MOE_PATHS, RETENTION_PHASES
 
+    # the state kind (kv/store.py StateStore): one entry a lane, no blocks
+    reg.gauge(
+        "dnet_state_slots",
+        "State entries the store of a model with recurrent-state layers "
+        "holds: one a lane (0 for a model without such layers)",
+    )
+    reg.gauge(
+        "dnet_state_slots_used",
+        "State entries that belong to a live sequence right now",
+    )
+    reg.counter(
+        "dnet_retention_state_bytes_total",
+        "Bytes of recurrent state the batched decode dispatches read and "
+        "wrote: active lanes x steps x layers x one entry x 2",
+    )
+    ret_fam = reg.counter(
+        "dnet_retention_tokens_total",
+        "Tokens that went through the state layers' retention op, by the "
+        "program that carried them",
+        labelnames=("phase",),
+    )
+    for phase in RETENTION_PHASES:
+        ret_fam.labels(phase=phase)  # pre-touch: the lint checks these
     for name, help_text in (
         ("dnet_kv_blocks_used",
          "Paged KV pool blocks currently allocated (refcount >= 1), by the "

@@ -76,9 +76,12 @@ SCOPE_ATTN = "dnet.attn"
 SCOPE_ATTN_WINDOW = "dnet.attn.window"
 SCOPE_ATTN_FULL = "dnet.attn.full"
 SCOPE_MOE_SHARED = "dnet.moe.shared"
+# inside dnet.attn of a model whose layers keep a recurrent state and no
+# keys (models/brumby.py): the state's read, decay and update
+SCOPE_ATTN_STATE = "dnet.attn.state"
 DEVICE_SCOPES = (
     SCOPE_SAMPLE, SCOPE_LM_HEAD, SCOPE_MOE, SCOPE_ATTN,
-    SCOPE_ATTN_WINDOW, SCOPE_ATTN_FULL, SCOPE_MOE_SHARED,
+    SCOPE_ATTN_WINDOW, SCOPE_ATTN_FULL, SCOPE_MOE_SHARED, SCOPE_ATTN_STATE,
 )
 
 # dnet_kv_blocks_used / _free / dnet_kv_pool_blocks {kind=}: the paged pool
@@ -89,6 +92,17 @@ DEVICE_SCOPES = (
 KV_KIND_FULL = "full"
 KV_KIND_WINDOW = "window"
 KV_KINDS = (KV_KIND_FULL, KV_KIND_WINDOW)
+# The third kind of layer keeps no blocks at all: one recurrent STATE entry
+# a lane, of one size whatever the sequence's length (kv/store.py
+# StateStore), so it has no pool, no page table and no series among the
+# dnet_kv_blocks_* families: dnet_state_slots / dnet_state_slots_used are
+# its books, and a lane is all a sequence costs.
+KV_KIND_STATE = "state"
+
+# dnet_retention_tokens_total{phase=}: tokens that went through a state
+# layer's retention op, by the program that carried them (a prefill chunk's
+# real tokens; a decode dispatch's lanes x steps)
+RETENTION_PHASES = ("prefill", "decode")
 
 # dnet_moe_assignments_total{held=}: (token, chosen expert) pairs of the
 # batched decode dispatches' active lanes, by whether the expert is one

@@ -1,9 +1,10 @@
 """Which implementation each Pallas dispatcher picked, counted per process.
 
-Five dispatchers choose between a Pallas kernel and a jnp path at trace
+Seven dispatchers choose between a Pallas kernel and a jnp path at trace
 time: causal prefill (`ops/flash_attention.py`), split-K decode
-(`ops/flash_decode.py`), ragged paged attend (`ops/paged_attention.py`) and
-the two hop-codec kernels (`compression/ops.py`).  The backend half of
+(`ops/flash_decode.py`), ragged paged attend (`ops/paged_attention.py`), the
+two hop-codec kernels (`compression/ops.py`) and power retention's decode
+step and prefill chunk (`ops/retention.py`).  The backend half of
 that choice lives here, so that it is made one way: a TPU backend runs the
 Mosaic-compiled kernel and nothing else; DNET_FLASH_INTERPRET=1 selects
 interpret mode on a CPU backend (tier-1) and is an error on a TPU one.
@@ -22,13 +23,15 @@ import jax
 
 #: what a dispatcher can resolve to
 IMPLS = ("pallas", "interpret", "emulate", "dense")
-#: the five dispatchers, by the name `/health` reports them under
+#: the seven dispatchers, by the name `/health` reports them under
 KERNELS = (
     "flash_prefill",
     "flash_decode",
     "paged_attend",
     "column_norms",
     "column_select",
+    "retention_step",
+    "retention_chunk",
 )
 
 

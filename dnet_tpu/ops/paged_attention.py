@@ -49,7 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dnet_tpu.obs.phases import KV_KIND_FULL
+from dnet_tpu.obs.phases import KV_KIND_FULL, KV_KIND_STATE
 from dnet_tpu.ops.kernel_select import SELECTIONS, kernel_backend
 
 NEG_INF = -1e30
@@ -83,7 +83,12 @@ def ragged_refusal(model, kv_quant_bits: int) -> Optional[str]:
             f"quantized KV cache (bits={kv_quant_bits}): the kernel reads "
             "unquantized blocks"
         )
-    if KV_KIND_FULL not in (getattr(model, "paged_kinds", None) or (KV_KIND_FULL,)):
+    kinds = getattr(model, "paged_kinds", None) or (KV_KIND_FULL,)
+    if KV_KIND_STATE in kinds:
+        # not the pool's to serve, and not dense slots' either: kv_layout
+        # sends it to the state store before it asks here
+        return "recurrent-state layers keep no blocks (the state store serves them)"
+    if KV_KIND_FULL not in kinds:
         return "no full layer among the window layers"
     return None
 

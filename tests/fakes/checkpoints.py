@@ -512,3 +512,26 @@ def make_tiny_qwen3_moe(model_dir: str | Path, config: dict | None = None, seed:
             tensors[p + "mlp.down_proj.weight"] = w(D, Fd)
     save_checkpoint(model_dir, cfg, tensors)
     return cfg
+
+
+def tiny_brumby_config() -> dict:
+    """The benchmark configuration `brumby-14b-8l` at its rehearsal size
+    (hidden 64, heads 8/2 of 16, 2 layers): the HF keys alone."""
+    import json
+
+    root = Path(__file__).resolve().parents[2]
+    full = json.loads((root / "benchmarks/configs/brumby-14b-8l.json").read_text())
+    cfg = {k: v for k, v in full.items()
+           if k not in ("assumed", "deployment", "serve", "check", "rehearse")}
+    cfg.update(full["rehearse"]["config"])
+    return cfg
+
+
+def make_tiny_brumby(model_dir: str | Path, seed: int = 2**31 + 32) -> dict:
+    """A seeded float32 brumby checkpoint, written as the benchmark writes
+    its own (tensor names from benchmarks/reference/brumby.py)."""
+    from benchmarks.harness.weights import write_checkpoint
+
+    cfg = tiny_brumby_config()
+    write_checkpoint(Path(model_dir), cfg, seed=seed, dtype="float32")
+    return cfg
