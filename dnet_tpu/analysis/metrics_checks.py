@@ -596,7 +596,12 @@ def check_sched_labels(errors: list) -> int:
     histogram family is checked on its exposition suffixes, like the
     attribution pass."""
     from dnet_tpu.obs import get_registry
-    from dnet_tpu.sched.kinds import BATCH_KINDS, PREEMPT_REASONS, QUEUE_STATES
+    from dnet_tpu.sched.kinds import (
+        BATCH_KINDS,
+        MIXED_TICK_OVERLAP,
+        PREEMPT_REASONS,
+        QUEUE_STATES,
+    )
 
     text = get_registry().expose()
     n = 0
@@ -624,6 +629,10 @@ def check_sched_labels(errors: list) -> int:
     n += _cross_check_labels(
         errors, text, "dnet_sched_queue_depth", "state",
         QUEUE_STATES, "sched.kinds.QUEUE_STATES",
+    )
+    n += _cross_check_labels(
+        errors, text, "dnet_sched_mixed_ticks_total", "overlapped",
+        MIXED_TICK_OVERLAP, "sched.kinds.MIXED_TICK_OVERLAP",
     )
     return n
 

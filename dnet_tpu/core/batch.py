@@ -28,6 +28,7 @@ top-p's batch together (same property as core/sampler.py's traced scalars).
 from __future__ import annotations
 
 import time
+from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -84,6 +85,24 @@ _MOE_ASSIGNMENTS = metric("dnet_moe_assignments_total")
 _STATE_SLOTS_USED = metric("dnet_state_slots_used")
 _RETENTION_BYTES = metric("dnet_retention_state_bytes_total")
 _RETENTION_TOKENS = metric("dnet_retention_tokens_total")
+
+
+@dataclass
+class DecodeFlight:
+    """What `decode_launch` hands `decode_read`: the lanes answered on the
+    host already, and the dispatch the device still owes an answer for."""
+
+    #: answered from a buffer / a verify block
+    out: Dict[str, SampleResult] = field(default_factory=dict)
+    errors: Dict[str, str] = field(default_factory=dict)
+    order: Dict[str, int] = field(default_factory=dict)  # nonce -> slot sent
+    R: int = 0  # fused width of the dispatch; 0 = nothing was sent
+    src: Optional[SampleResult] = None  # its results, ON THE DEVICE
+    moe: Any = None  # [held, elsewhere] summed by the dispatch, on the device
+    host_s: float = 0.0  # host time of the launch half
+    #: the launch half itself read the device (a verify block's acceptance
+    #: counts): work enqueued after it did not overlap this dispatch
+    blocked: bool = False
 
 
 KV_PAGED = "paged"  # page tables over the block pool, attended in place
@@ -363,6 +382,7 @@ class BatchedEngine:
 
     # ---- program ------------------------------------------------------
     def _build(self) -> None:
+        self._build_adopt()
         if self.kv_store is not None:
             self._build_ragged()
             return
@@ -447,6 +467,32 @@ class BatchedEngine:
                 "batched_spec",
             )
 
+    def _build_adopt(self) -> None:
+        """The program that writes a prefilled session's sampling state
+        into its lane (and, under dense slots, its KV row): everything
+        `adopt_prefilled` moves that the store's own commit does not, in
+        ONE launch, the engine's arrays donated.  `slot` is traced: one
+        program whatever the lane."""
+
+        @jax.named_scope("adopt_lane")
+        def adopt_lane(kv, counts, keys, hist, slot, row, row_counts, key, row_hist):
+            if kv is not None:
+                kv = jax.tree.map(
+                    lambda big, one: jax.lax.dynamic_update_slice_in_dim(
+                        big, one.astype(big.dtype), slot, axis=1
+                    ),
+                    kv, row,
+                )
+            counts = counts.at[slot].set(row_counts[0])
+            keys = keys.at[slot].set(key)
+            if hist is not None:
+                hist = hist.at[slot].set(row_hist[0])
+            return kv, counts, keys, hist
+
+        self._adopt_lane = instrument_jit(
+            jax.jit(adopt_lane, donate_argnums=(0, 1, 2, 3)), "adopt_lane"
+        )
+
     def _build_ragged(self) -> None:
         """The pool's decode programs (ops/paged_attention.py): one step
         that reads the block pool IN PLACE — page tables and per-slot
@@ -465,7 +511,6 @@ class BatchedEngine:
         store = self.kv_store  # it knows the pools' layout, by kind
         sp_axes = SampleParams(0, 0, 0, 0, 0, 0, 0, 0)
         self._moe_reported = bool(getattr(model, "reports_moe_held", False))
-        self._moe_pending = None
 
         def one_sample(logits, active, sp, key, counts):
             """Per-lane sampling tail, identical to the vmapped `one()`:
@@ -665,9 +710,8 @@ class BatchedEngine:
                     tables = self._kind_tables[kind]
                     tbl, tables[slot] = tables[slot], None
                     pool.release_table(tbl)
-            self.counts = self.counts.at[slot].set(0)
-            if self.hist is not None:
-                self.hist = self.hist.at[slot].set(0)
+            # the lane's counts, key and history stay as they are: nothing
+            # reads an inactive lane's, and adoption overwrites them whole
             self.pos[slot] = 0
             self._free.append(slot)
             self._book_lanes()
@@ -780,11 +824,32 @@ class BatchedEngine:
         self.eng.end_session(nonce)
 
     def adopt_prefilled(self, nonce, logits, decoding: DecodingParams) -> SampleResult:
-        """Sample the first token from a fully-chunk-prefilled session and
-        move its KV/sampling state into this request's batch slot."""
+        """Move a fully-prefilled session into this request's batch lane and
+        sample its first token: program launches only, nothing dispatched
+        eagerly and nothing read (the result stays on the device for the
+        caller to read when it has enqueued everything else).
+
+        Host work first: the pools' `alloc` raises KVPoolExhausted BEFORE
+        anything is enqueued, with the session, its key and its counts
+        untouched, so the caller may free blocks and call again.  Then the
+        store's commit (kv/store.py), the sample (core/engine.py
+        sample_with_counts) and the lane's sampling state (`_adopt_lane`)."""
         sess = self.eng.sessions[nonce]
+        slot = self.alloc_slot(nonce)
+        if self.kv_store is not None:
+            self._commit_paged_slot(nonce, slot, sess)
         res = self.eng._sample_with_counts(sess, logits, decoding)
-        self._move_to_slot(nonce, sess)
+        dense_row = sess.kv if self.kv_store is None else None
+        self.kv, self.counts, self.keys, self.hist = self._adopt_lane(
+            self.kv, self.counts, self.keys, self.hist, np.int32(slot),
+            dense_row, sess.counts, sess.key,
+            # the inner LocalEngine's prefill paths committed the prompt to
+            # the session history; adopt it for this lane's prompt-lookup
+            sess.hist if self.hist is not None else None,
+        )
+        self.pos[slot] = sess.pos
+        self.last_used[slot] = time.time()
+        self.eng.end_session(nonce)  # B=1 cache row no longer needed
         return res
 
     def _commit_paged_slot(self, nonce: str, slot: int, sess) -> None:
@@ -935,26 +1000,6 @@ class BatchedEngine:
             out.update({KV_KIND_WINDOW: wids, "base": base})
         return out
 
-    def _move_to_slot(self, nonce: str, sess) -> None:
-        slot = self.alloc_slot(nonce)
-        if self.kv_store is not None:
-            self._commit_paged_slot(nonce, slot, sess)
-        else:
-            self.kv = jax.tree.map(
-                lambda big, one: big.at[:, slot : slot + 1].set(one.astype(big.dtype)),
-                self.kv,
-                sess.kv,
-            )
-        self.counts = self.counts.at[slot].set(sess.counts[0])
-        self.keys = self.keys.at[slot].set(sess.key)
-        if self.hist is not None and sess.hist is not None:
-            # the inner LocalEngine's prefill paths committed the prompt to
-            # the session history; adopt it for this lane's prompt-lookup
-            self.hist = self.hist.at[slot].set(sess.hist[0])
-        self.pos[slot] = sess.pos
-        self.last_used[slot] = time.time()
-        self.eng.end_session(nonce)  # B=1 cache row no longer needed
-
     def prefill_and_sample(
         self, nonce: str, prompt_ids: Sequence[int], decoding: DecodingParams
     ) -> SampleResult:
@@ -962,9 +1007,8 @@ class BatchedEngine:
         and sampling state into this request's batch slot."""
         self.alloc_slot(nonce)  # fail on a full pool BEFORE burning prefill
         if self.kv_pool is None:
-            res = self.eng.prefill_and_sample(nonce, prompt_ids, decoding)
-            self._move_to_slot(nonce, self.eng.sessions[nonce])
-            return res
+            logits = self.eng.prefill(nonce, prompt_ids, decoding.seed)
+            return self.adopt_prefilled(nonce, logits, decoding)
         full = list(prompt_ids)
         try:
             n = self.seed_from_prefix(nonce, full, decoding.seed)
@@ -980,10 +1024,7 @@ class BatchedEngine:
             logits = self.eng.prefill(
                 nonce, full[n:], decoding.seed, allow_store=False
             )
-            res = self.eng._sample_with_counts(
-                self.eng.sessions[nonce], logits, decoding
-            )
-            self._move_to_slot(nonce, self.eng.sessions[nonce])
+            res = self.adopt_prefilled(nonce, logits, decoding)
         except Exception:
             self.abandon_prefill(nonce)
             raise
@@ -1020,22 +1061,34 @@ class BatchedEngine:
         adds the other half of the condition: it hands out budgets only
         while no prompt waits, sched/policy.py.)
 
-        Host spans (obs/phases.py): prepare, then — only when some lane's
-        buffer is empty — launch readback unpack.
-        Nothing is fenced: launch is an enqueue, readback is the host
-        blocked on the device.  `last_dispatch` says what this call sent
-        to the device: (R, lanes), (0, 0) when every lane was answered
-        from the buffer."""
-        errors: Dict[str, str] = {}
+        Two halves at one seam: `decode_launch` (host spans prepare, then
+        — only when some lane's buffer is empty — launch) ENQUEUES and
+        returns; `decode_read` (readback, unpack, the counters) blocks on
+        the device.  A caller with more device work for the same tick
+        (sched/step.py) enqueues it between the two; every other caller
+        runs them in a row, here."""
+        return self.decode_read(self.decode_launch(requests, budgets))
+
+    def decode_launch(
+        self,
+        requests: Dict[str, Tuple[int, DecodingParams]],
+        budgets: Optional[Dict[str, Optional[int]]] = None,
+    ) -> DecodeFlight:
+        """The half of `decode_batch` that enqueues.  Nothing is fenced and
+        nothing read (but a verify block, which reads its acceptance
+        counts: `blocked`).  `last_dispatch` says what this call sent to
+        the device: (R, lanes), (0, 0) when every lane was answered from
+        the buffer.  The lanes' `pos` advance in `decode_read`."""
+        t0 = time.perf_counter()
+        flight = DecodeFlight()
         self.last_dispatch = (0, 0)
         if not requests:
-            return {}, errors
-        t_parent = time.perf_counter()
+            return flight
         plan = None
         asked = len(requests)
         with span(SPAN_DECODE_PREPARE):
             # buffered tokens from an earlier fused chunk resolve first
-            out_buf, requests = self._pop_buffered(requests)
+            flight.out, requests = self._pop_buffered(requests)
             # per-lane speculation: greedy lanes with budget to spare verify
             # a drafted block instead of stepping once; they advance by
             # their OWN acceptance count (buffered), while the remaining
@@ -1045,35 +1098,60 @@ class BatchedEngine:
             if requests and not spec_reqs:
                 # out of phase (some lane had a buffered row): single step
                 plan = self._plan_dispatch(
-                    requests, budgets if len(requests) == asked else None, errors
+                    requests, budgets if len(requests) == asked else None,
+                    flight.errors,
                 )
         if spec_reqs:
             spec_out = self._decode_spec_lanes(spec_reqs)
+            flight.blocked = True
             _DECODE_TOKENS.labels(source="spec").inc(len(spec_out))
-            out_buf.update(spec_out)
+            flight.out.update(spec_out)
             requests = {n: r for n, r in requests.items() if n not in spec_reqs}
             if requests:
                 with span(SPAN_DECODE_PREPARE):
-                    plan = self._plan_dispatch(requests, None, errors)
-        if plan is None:
-            return out_buf, errors
-        order, R, dev, table_ids = plan
+                    plan = self._plan_dispatch(requests, None, flight.errors)
+        if plan is not None:
+            flight.order, flight.R, dev, table_ids = plan
+            self._launch(flight, dev, table_ids)
+        flight.host_s = time.perf_counter() - t0
+        return flight
+
+    def _launch(self, flight: DecodeFlight, dev, table_ids) -> None:
+        order, R = flight.order, flight.R
         lanes = len(order)
         with span(SPAN_DECODE_LAUNCH, R=R, lanes=lanes):
             if self.kv_store is not None:
                 # the pool is attended IN PLACE through the page tables and
                 # the new rows block-append, all inside the launch
                 count_expert_rows(self.eng.model, self.slots, R)
-                src = self._dispatch_ragged(order, R, dev, table_ids)
+                flight.src, flight.moe = self._dispatch_ragged(
+                    order, R, dev, table_ids
+                )
             else:
                 # vmapped over the slots: each lane's experts see one row
                 count_expert_rows(self.eng.model, 1, R * self.slots)
                 token_d, pos_d, active_d, sp = dev
                 step = self._chunk_fn(R) if R > 1 else self._step
-                src, self.kv, self.counts, self.keys = step(
+                flight.src, self.kv, self.counts, self.keys = step(
                     self.eng.window_params, self.eng.edge_params, token_d,
                     self.kv, pos_d, active_d, sp, self.keys, self.counts,
                 )
+        self.last_dispatch = (R, lanes)
+
+    def decode_read(
+        self, flight: DecodeFlight
+    ) -> Tuple[Dict[str, SampleResult], Dict[str, str]]:
+        """The half of `decode_batch` that reads: blocks until the device
+        has finished the flight's dispatch, then hands each lane its row.
+
+        A lane that LEFT between launch and read (preempted or ended while
+        its step was in flight: `slot_of` no longer maps its nonce to the
+        slot it was sent on) gets nothing: its `pos` is not advanced, its
+        token is dropped, and whoever holds the slot now is not touched."""
+        if flight.src is None:
+            return flight.out, flight.errors
+        t0 = time.perf_counter()
+        src, R, lanes = flight.src, flight.R, len(flight.order)
         # ONE packed device->host read per field per dispatch (the
         # pipelined engine's drain pattern), then host-side slicing —
         # per-element device gathers would reintroduce the dispatch
@@ -1086,13 +1164,17 @@ class BatchedEngine:
             tlps = np.asarray(src.top_logprobs)
             if self.kv_store is not None and self._moe_reported:
                 # summed on the device by the dispatch just read: no sync
-                mine, elsewhere = np.asarray(self._moe_pending)
+                mine, elsewhere = np.asarray(flight.moe)
                 _MOE_ASSIGNMENTS.labels(held="yes").inc(int(mine))
                 _MOE_ASSIGNMENTS.labels(held="no").inc(int(elsewhere))
         with span(SPAN_DECODE_UNPACK):
             now = time.time()
-            out: Dict[str, SampleResult] = dict(out_buf)
-            for nonce, slot in order.items():
+            out = flight.out
+            delivered = 0
+            for nonce, slot in flight.order.items():
+                if self.slot_of.get(nonce) != slot:
+                    continue  # the lane left with its step in flight
+                delivered += 1
                 self.pos[slot] += R
                 self.last_used[slot] = now
                 if R > 1:
@@ -1111,11 +1193,10 @@ class BatchedEngine:
         # what the fused-chunk path did: the device computed R steps for
         # every slot, lanes asked for R x lanes of them, and the driver
         # received one token per lane now (the rest wait in the buffer)
-        self.last_dispatch = (R, lanes)
         _DECODE_DISPATCHES.labels(r=str(R)).inc()
         _DECODE_SLOT_STEPS.inc(R * self.slots)
         _DECODE_LANE_STEPS.inc(R * lanes)
-        _DECODE_TOKENS.labels(source="dispatch").inc(lanes)
+        _DECODE_TOKENS.labels(source="dispatch").inc(delivered)
         if self.kv_store is not None and self.kv_store.in_place:
             # what the algorithm needs: each active lane's entry read and
             # written once a step, in every layer
@@ -1124,11 +1205,12 @@ class BatchedEngine:
         # per-token share, observed tokens-served times: the family's
         # count stays == tokens across the local / chunked / speculative /
         # batched paths (LocalEngine's amortization convention), and the
-        # sum stays == this call's wall time, prepare through unpack
+        # sum stays == the two halves' host time, prepare through unpack
+        # (what a caller enqueued between them is not in it)
         n_tok = R * lanes
-        per_tok_ms = (time.perf_counter() - t_parent) * 1000.0 / n_tok
-        _DECODE_STEP_MS.observe_n(per_tok_ms, n_tok)
-        return out, errors
+        host_s = flight.host_s + time.perf_counter() - t0
+        _DECODE_STEP_MS.observe_n(host_s * 1000.0 / n_tok, n_tok)
+        return out, flight.errors
 
     def _pop_buffered(self, requests):
         """Answer every lane that still holds rows of an earlier fused
@@ -1171,9 +1253,9 @@ class BatchedEngine:
 
     def _plan_dispatch(self, requests, budgets, errors):
         """Everything the host prepares for one dispatch: per-slot numpy
-        parameter rows, the chunk width, page-table extension, and the
-        uploads.  Returns (order, R, device arrays, table ids) or None when
-        no lane is left to step."""
+        parameter rows, the chunk width and page-table extension.  Returns
+        (order, R, the step's host arguments, table ids) or None when no
+        lane is left to step."""
         token = np.zeros((self.slots, 1), dtype=np.int32)
         active = np.zeros(self.slots, dtype=bool)
         pos = np.zeros(self.slots, dtype=np.int32)
@@ -1210,15 +1292,13 @@ class BatchedEngine:
         if not order:
             return None
 
+        # host rows: they go into the jitted step as arguments, which
+        # uploads them in the call (one eager device_put a row costs more
+        # host time than the whole launch)
         sp = SampleParams(
-            temperature=jnp.asarray(temp),
-            top_p=jnp.asarray(top_p),
-            top_k=jnp.asarray(top_k),
-            min_p=jnp.asarray(min_p),
-            repetition_penalty=jnp.asarray(rep),
-            min_tokens_to_keep=jnp.asarray(mtk),
-            bias_ids=jnp.asarray(b_ids),
-            bias_vals=jnp.asarray(b_vals),
+            temperature=temp, top_p=top_p, top_k=top_k, min_p=min_p,
+            repetition_penalty=rep, min_tokens_to_keep=mtk,
+            bias_ids=b_ids, bias_vals=b_vals,
         )
         # fused-chunk width: bounded by the smallest remaining budget and
         # by every active lane's sequence capacity
@@ -1238,19 +1318,17 @@ class BatchedEngine:
                 self._extend_window_tables(order, errors, active, R)
                 if not order:
                     return None
-            table_ids = jax.tree.map(
-                jnp.asarray, self._table_ids(order if R == 1 else None)
-            )
+            table_ids = self._table_ids(order if R == 1 else None)
         elif self.kv_store is not None:
             table_ids = {}  # the state kind: a lane IS the address
-        dev = (jnp.asarray(token), jnp.asarray(pos), jnp.asarray(active), sp)
-        return order, R, dev, table_ids
+        return order, R, (token, pos, active, sp), table_ids
 
     def _dispatch_ragged(self, order: Dict[str, int], R: int, dev, tables):
         """One decode dispatch over the pool (R == 1: the read-only
         paged_attend program + the jitted kv_append block-append; R > 1:
         the fused chunk carrying the donated pool).  All of it is the
-        launch span."""
+        launch span.  Returns the results and the dispatch's [held,
+        elsewhere] expert assignments, both on the device."""
         token_d, pos_d, active_d, sp = dev
         args = (
             self.eng.window_params,
@@ -1265,12 +1343,12 @@ class BatchedEngine:
             self.counts,
         )
         if R > 1:
-            stacked, pool, self.counts, self.keys, self._moe_pending = (
+            stacked, pool, self.counts, self.keys, moe = (
                 self._ragged_chunk_fn(R)(*args)
             )
             self.kv_store.kv = pool
-            return stacked
-        res, rows, self.counts, self.keys, self._moe_pending = self._ragged_step(*args)
+            return stacked, moe
+        res, rows, self.counts, self.keys, moe = self._ragged_step(*args)
         bt = self._block_tokens
         # inactive-lane sentinel: past the block axis, never negative
         # (see BlockStore.append_in_program)
@@ -1286,7 +1364,7 @@ class BatchedEngine:
                 tbl = tables[slot]
                 phys[kind][slot] = tbl.blocks[p0 // bt - tbl.base]
         self.kv_store.append_rows(rows, phys, off)
-        return res
+        return res, moe
 
     # adaptive spec gate, same thresholds/semantics as LocalEngine's
     SPEC_WARMUP_BLOCKS = LocalEngine.SPEC_WARMUP_BLOCKS
@@ -1428,8 +1506,9 @@ class BatchedEngine:
         eos = eos_token_ids or set()
         self.end_session(nonce)
         res = self.prefill_and_sample(nonce, prompt_ids, decoding)
-        token = int(res.token[0])
-        yield self.token_result(nonce, res, step=0, decoding=decoding)
+        first = self.token_result(nonce, res, step=0, decoding=decoding)
+        token = first.token_id
+        yield first
         if token in eos:
             self.end_session(nonce)
             return

@@ -365,6 +365,31 @@ class LocalEngine:
             jax.jit(prefill_logits, donate_argnums=(3,)), "local_prefill"
         )
 
+        @jax.named_scope("new_session")
+        def fresh_session(seed, with_kv):
+            """A new session's device state in one program: the zeroed
+            cache row (unless a prefix snapshot seeds it), the key of
+            `jax.random.key(seed)`, zeroed counts (and spec history)."""
+            kv = (
+                model.init_kv(
+                    len(model.layers), self.batch, self.max_seq,
+                    self.kv_dtype, quant_bits=self.kv_quant_bits,
+                )
+                if with_kv
+                else None
+            )
+            counts = jnp.zeros((self.batch, self.config.vocab_size), dtype=jnp.int32)
+            hist = (
+                jnp.zeros((self.batch, self.max_seq), dtype=jnp.int32)
+                if self.spec_lookahead > 0
+                else None
+            )
+            return kv, jax.random.key(seed), counts, hist
+
+        self._fresh_session = instrument_jit(
+            jax.jit(fresh_session, static_argnums=(1,)), "new_session"
+        )
+
         @jax.named_scope("local_decode")
         def decode_and_sample(window_params, edge_params, token, kv, pos, sp, key, counts,
                               plan=None):
@@ -633,34 +658,29 @@ class LocalEngine:
             # fresh entropy per unseeded request — two users must not share a stream
             seed = int.from_bytes(__import__("os").urandom(4), "little")
         kv_list = None
-        if kv is None:
-            if self.plan.streams_weights:
-                kv_list = [
-                    init_cache(
-                        self.model.kv_config(
-                            1, self.batch, self.max_seq, self.kv_dtype,
-                            quant_bits=self.kv_quant_bits,
-                        )
+        if kv is None and self.plan.streams_weights:
+            kv_list = [
+                init_cache(
+                    self.model.kv_config(
+                        1, self.batch, self.max_seq, self.kv_dtype,
+                        quant_bits=self.kv_quant_bits,
                     )
-                    for _ in self.model.layers
-                ]
-            else:
-                kv = self.model.init_kv(
-                    len(self.model.layers), self.batch, self.max_seq,
-                    self.kv_dtype, quant_bits=self.kv_quant_bits,
                 )
+                for _ in self.model.layers
+            ]
+        # ONE program launch (a prompt's first chunk runs between a tick's
+        # decode launch and its read): the seed goes in as a host scalar
+        fresh_kv, key, counts, hist = self._fresh_session(
+            np.uint32(seed & 0xFFFFFFFF), kv is None and kv_list is None
+        )
         sess = Session(
             nonce=nonce,
-            kv=kv,
+            kv=fresh_kv if kv is None else kv,
             kv_list=kv_list,
             pos=pos,
-            key=jax.random.key(seed),
-            counts=jnp.zeros((self.batch, self.config.vocab_size), dtype=jnp.int32),
-            hist=(
-                jnp.zeros((self.batch, self.max_seq), dtype=jnp.int32)
-                if self.spec_lookahead > 0
-                else None
-            ),
+            key=key,
+            counts=counts,
+            hist=hist,
             dkv=(
                 self.draft.model.init_kv(
                     self.draft.config.num_hidden_layers, self.batch,
@@ -751,8 +771,8 @@ class LocalEngine:
             logits = self.model.lm_project(self.edge_params, x_last)[:, 0]
         else:
             logits, sess.kv = self._forward(
-                self.window_params, self.edge_params, jnp.asarray(tokens), sess.kv,
-                jnp.int32(sess.pos), jnp.int32(T - 1),
+                self.window_params, self.edge_params, tokens, sess.kv,
+                np.int32(sess.pos), np.int32(T - 1),
             )
         if self.draft is not None:
             if fresh and len(prompt_ids) != len(full_ids):
@@ -1236,18 +1256,12 @@ class LocalEngine:
     def _sample_with_counts(
         self, sess: "Session", logits, decoding: DecodingParams
     ) -> SampleResult:
-        """THE place owning the key-split/sample/counts invariants (shared by
-        LocalEngine and MeshEngine)."""
-        sess.key, step_key = jax.random.split(sess.key)
-        res = sample(
-            logits, SampleParams.from_decoding(decoding), step_key,
-            token_counts=sess.counts, plan=SamplePlan.from_decoding(decoding),
+        """A session's next token from `logits`: `sample_with_counts` below
+        (shared by LocalEngine and MeshEngine) over the session's key and
+        counts.  One program launch, nothing read."""
+        res, sess.key, sess.counts = sample_with_counts(
+            logits, decoding, sess.key, sess.counts
         )
-        # per-lane counts, matching the jitted decode/chunk programs exactly —
-        # penalty state must not depend on which dispatch path served a step
-        sess.counts = sess.counts.at[
-            jnp.arange(sess.counts.shape[0]), res.token
-        ].add(1)
         return res
 
     def prefill_and_sample(
@@ -1259,19 +1273,53 @@ class LocalEngine:
 
     @staticmethod
     def token_result(nonce: str, res: SampleResult, step: int, decoding: DecodingParams) -> TokenResult:
+        """A SampleResult as the driver's TokenResult.  Each field it needs
+        is converted ONCE, whole, and indexed on the host: a device result
+        (the adapters') costs one transfer a field and no program, a host
+        result (the scheduler's, read on the compute thread) nothing.
+        Indexing a device array would enqueue a slice program behind
+        whatever the device has queued and wait it out."""
         top = None
         if decoding.logprobs and decoding.top_logprobs > 0:
             n = min(decoding.top_logprobs, res.top_tokens.shape[-1])
             top = list(
                 zip(
-                    np.asarray(res.top_tokens[0, :n]).tolist(),
-                    np.asarray(res.top_logprobs[0, :n]).tolist(),
+                    np.asarray(res.top_tokens)[0, :n].tolist(),
+                    np.asarray(res.top_logprobs)[0, :n].tolist(),
                 )
             )
         return TokenResult(
             nonce=nonce,
-            token_id=int(res.token[0]),
-            logprob=float(res.logprob[0]) if decoding.logprobs else None,
+            token_id=int(np.asarray(res.token)[0]),
+            logprob=float(np.asarray(res.logprob)[0]) if decoding.logprobs else None,
             top_logprobs=top,
             step=step,
         )
+
+
+def _split_sample_count(logits, sp, key, counts, plan):
+    key, step_key = jax.random.split(key)
+    res = sample(logits, sp, step_key, token_counts=counts, plan=plan)
+    # per-lane counts, matching the jitted decode/chunk programs exactly:
+    # penalty state must not depend on which dispatch path served a step
+    counts = counts.at[jnp.arange(counts.shape[0]), res.token].add(1)
+    return res, key, counts
+
+
+_SAMPLE_WITH_COUNTS = instrument_jit(
+    jax.jit(_split_sample_count, static_argnames=("plan",)), "sample_with_counts"
+)
+
+
+def sample_with_counts(logits, decoding: DecodingParams, key, counts):
+    """THE place owning the key-split / sample / counts invariants outside
+    the decode programs (a prompt's first token on every engine, the mesh
+    engine's steps): (result, the advanced key, counts with the sampled
+    token booked).  ONE jitted program a SamplePlan (the plan static, every
+    knob traced, as in the decode programs), the request's SampleParams
+    going in as a host-built pytree: nothing is dispatched eagerly and
+    nothing is read."""
+    return _SAMPLE_WITH_COUNTS(
+        logits, SampleParams.from_decoding(decoding), key, counts,
+        plan=SamplePlan.from_decoding(decoding),
+    )

@@ -19,6 +19,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from dnet_tpu.core.types import DecodingParams
 from dnet_tpu.obs.phases import SCOPE_SAMPLE
@@ -33,8 +34,6 @@ MAX_LOGIT_BIAS = 300
 def encode_logit_bias(bias) -> tuple:
     """dict {token_id: bias} -> fixed-width (ids [MAX], vals [MAX]) numpy
     arrays, id -1 padding (scattered with mode=drop).  None = no bias."""
-    import numpy as np
-
     ids = np.full((MAX_LOGIT_BIAS,), -1, dtype=np.int32)
     vals = np.zeros((MAX_LOGIT_BIAS,), dtype=np.float32)
     if bias:
@@ -69,16 +68,19 @@ class SampleParams(NamedTuple):
 
     @classmethod
     def from_decoding(cls, d: DecodingParams) -> "SampleParams":
+        """One request's knobs as a HOST pytree (numpy leaves): it goes
+        into a jitted program as arguments, so building it dispatches
+        nothing and no leaf is a device array before the call."""
         ids, vals = encode_logit_bias(getattr(d, "logit_bias", None))
         return cls(
-            temperature=jnp.float32(d.temperature),
-            top_p=jnp.float32(d.top_p),
-            top_k=jnp.int32(d.top_k),
-            min_p=jnp.float32(d.min_p),
-            repetition_penalty=jnp.float32(d.repetition_penalty),
-            min_tokens_to_keep=jnp.int32(d.min_tokens_to_keep),
-            bias_ids=jnp.asarray(ids),
-            bias_vals=jnp.asarray(vals),
+            temperature=np.float32(d.temperature),
+            top_p=np.float32(d.top_p),
+            top_k=np.int32(d.top_k),
+            min_p=np.float32(d.min_p),
+            repetition_penalty=np.float32(d.repetition_penalty),
+            min_tokens_to_keep=np.int32(d.min_tokens_to_keep),
+            bias_ids=ids,
+            bias_vals=vals,
         )
 
 

@@ -209,8 +209,8 @@ class BlockStore:
         pool_blocks (out of range, NOT negative) = skip this lane."""
         self.kv = self._append(
             self.kv, rows,
-            {k: jnp.asarray(v, dtype=jnp.int32) for k, v in phys.items()},
-            jnp.asarray(off, dtype=jnp.int32),
+            {k: np.asarray(v, dtype=np.int32) for k, v in phys.items()},
+            np.asarray(off, dtype=np.int32),
         )
 
     def commit_row(
@@ -228,11 +228,12 @@ class BlockStore:
         pad = K - len(logical_blocks)
         lb = list(logical_blocks) + [logical_blocks[-1]] * pad
         pb = list(phys_blocks) + [phys_blocks[-1]] * pad
+        # host index rows: arguments of the jitted scatter, no eager upload
         self.kv = self._scatter(
             self.kv, kv_row,
-            jnp.asarray([0] * K, dtype=jnp.int32),  # the row's one slot
-            jnp.asarray(lb, dtype=jnp.int32),
-            jnp.asarray(pb, dtype=jnp.int32),
+            np.zeros(K, dtype=np.int32),  # the row's one slot
+            np.asarray(lb, dtype=np.int32),
+            np.asarray(pb, dtype=np.int32),
         )
 
 
@@ -299,7 +300,7 @@ class StateStore:
     def commit_staged(self, kv_row: dict, blocks: dict) -> None:
         """blocks: {state: the lane} of one session's [L, 1, ...] entries."""
         self.kv = self._adopt(
-            self.kv, kv_row, jnp.asarray(blocks[KV_KIND_STATE], jnp.int32)
+            self.kv, kv_row, np.int32(blocks[KV_KIND_STATE])
         )
 
 
@@ -436,8 +437,8 @@ class KindStore:
                 K = max(self.window_width, len(lb))
             lb = list(lb) + [lb[-1]] * (K - len(lb))
             pb = list(pb) + [pb[-1]] * (K - len(pb))
-            block_idx[kind] = jnp.asarray(lb, jnp.int32)
-            phys[kind] = jnp.asarray(pb, jnp.int32)
+            block_idx[kind] = np.asarray(lb, np.int32)
+            phys[kind] = np.asarray(pb, np.int32)
         self.kv = self._commit(self.kv, kv_row, block_idx, phys)
 
     append_rows = BlockStore.append_rows

@@ -541,7 +541,12 @@ def _register_core(reg: MetricsRegistry) -> None:
     # kind / reason label sets are DECLARED in sched/kinds.py (a leaf
     # module, like admission/reasons.py) and cross-checked both ways by
     # the metrics lint (pass 10).
-    from dnet_tpu.sched.kinds import BATCH_KINDS, PREEMPT_REASONS, QUEUE_STATES
+    from dnet_tpu.sched.kinds import (
+        BATCH_KINDS,
+        MIXED_TICK_OVERLAP,
+        PREEMPT_REASONS,
+        QUEUE_STATES,
+    )
 
     reg.histogram(
         "dnet_sched_tick_ms",
@@ -569,10 +574,10 @@ def _register_core(reg: MetricsRegistry) -> None:
     )
     reg.histogram(
         "dnet_sched_deliver_wait_ms",
-        "A decode token's wait from decode_batch returning on the compute "
-        "thread to its future resolved on the event loop (the tick's "
-        "prefill chunks lie in between unless the wire pipeline "
-        "dispatches early) (ms)",
+        "A decode token's wait from the decode read ending on the compute "
+        "thread to its future resolved on the event loop: a hop of the "
+        "loop in a tick with prefill chunks (handed off at the read), the "
+        "rest of the tick otherwise (ms)",
         buckets=_WAIT_MS_BUCKETS,
     )
     batch_fam = reg.histogram(
@@ -593,6 +598,15 @@ def _register_core(reg: MetricsRegistry) -> None:
     )
     for reason in PREEMPT_REASONS:
         preempt.labels(reason=reason)  # pre-touch: the lint checks these
+    mixed = reg.counter(
+        "dnet_sched_mixed_ticks_total",
+        "Ticks that carried a decode step AND a prefill chunk, by whether "
+        "every chunk was enqueued before the step's blocking read "
+        "(sched/step.py; overlapped per sched/kinds.py)",
+        labelnames=("overlapped",),
+    )
+    for overlap in MIXED_TICK_OVERLAP:
+        mixed.labels(overlapped=overlap)  # pre-touch: the lint checks these
     depth = reg.gauge(
         "dnet_sched_queue_depth",
         "Requests resident in the scheduler queue, by live state "

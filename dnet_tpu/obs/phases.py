@@ -16,18 +16,24 @@ from __future__ import annotations
 # HOST did; device time is the device trace's to tell.  A span's self time is
 # its duration less its children's.  The tree (parent > child):
 #   dnet.tick                 sched/step.py execute_tick, compute thread
-#     dnet.tick.decode        around engine.decode_batch
+#     dnet.tick.decode        TWO a tick with decode lanes: around
+#                             engine.decode_launch, and, after the tick's
+#                             chunks are launched, around engine.decode_read
 #       dnet.decode.prepare     buffer pops, numpy rows, uploads, table ids
 #       dnet.decode.launch      the jitted step/chunk call (+ kv_append):
 #                               an ENQUEUE (and a compile, if one happens)
 #       dnet.decode.readback    the np.asarray reads: host blocked until the
 #                               device finishes the dispatch
 #       dnet.decode.unpack      SampleResult slicing
-#     dnet.tick.prefill       one per prefill chunk
+#     dnet.tick.prefill       one per prefill chunk, between the two halves
 #       dnet.prefill.launch     engine.prefill_chunk: ENQUEUE only
-#       dnet.prefill.adopt      store_prefix + adopt_prefilled: the
-#                               first-token sample (eager dispatches,
-#                               enqueued) and the slot commit
+#       dnet.prefill.adopt      store_prefix + adopt_prefilled: the slot
+#                               commit, the first-token sample and the
+#                               lane's sampling state, ENQUEUED (compiled
+#                               programs; the pools' alloc is its host work)
+#     dnet.prefill.readback   the tick's first tokens read, whole fields at
+#                             a time: host blocked until the device has
+#                             finished the tick's chunks and adoptions
 #   dnet.sched.plan           event loop, policy.plan
 #   dnet.sched.apply          event loop, SchedulerAdapter._apply
 #   dnet.api.sse_flush        api/http.py write_chunk (awaits: histogram
@@ -41,6 +47,7 @@ SPAN_DECODE_UNPACK = "dnet.decode.unpack"
 SPAN_TICK_PREFILL = "dnet.tick.prefill"
 SPAN_PREFILL_LAUNCH = "dnet.prefill.launch"
 SPAN_PREFILL_ADOPT = "dnet.prefill.adopt"
+SPAN_PREFILL_READBACK = "dnet.prefill.readback"
 SPAN_SCHED_PLAN = "dnet.sched.plan"
 SPAN_SCHED_APPLY = "dnet.sched.apply"
 SPAN_SSE_FLUSH = "dnet.api.sse_flush"
@@ -59,6 +66,7 @@ HOST_SPANS = (
     SPAN_TICK_PREFILL,
     SPAN_PREFILL_LAUNCH,
     SPAN_PREFILL_ADOPT,
+    SPAN_PREFILL_READBACK,
     SPAN_SCHED_PLAN,
     SPAN_SCHED_APPLY,
     SPAN_SSE_FLUSH,
@@ -136,11 +144,18 @@ DECODE_CHUNK_WIDTHS = (1, 2, 4, 8, 16)
 # one of these names — the lint fails a stray label either direction.
 JIT_FNS = (
     "local_prefill",        # LocalEngine._forward (bucketed prefill)
+    "new_session",          # LocalEngine._fresh_session: a session's zeroed
+                            # cache row, key and counts in one program
     "local_decode",         # LocalEngine._decode (fused decode+sample)
     "local_decode_chunk",   # LocalEngine._decode_chunk (R-step scan)
+    "sample_with_counts",   # core/engine.py sample_with_counts: key split,
+                            # sample and counts outside the decode programs
+                            # (a prompt's first token), one program a plan
     "batched_step",         # BatchedEngine._step (vmapped decode+sample)
     "batched_chunk",        # BatchedEngine fused R-step chunk programs
     "batched_spec",         # BatchedEngine._spec_step (verify blocks)
+    "adopt_lane",           # BatchedEngine._adopt_lane: a prefilled session's
+                            # counts, key (hist, dense KV row) into its lane
     "kv_gather",            # BlockStore page-table gather
     "kv_scatter",           # BlockStore block write-back
     "paged_attend",         # BatchedEngine ragged decode programs (step +

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from dnet_tpu.core.engine import sample_with_counts
 from dnet_tpu.core.sampler import (
     MAX_LOGIT_BIAS,
     SampleParams,
@@ -654,14 +655,10 @@ class PipelinedMeshEngine:
         seed = decoding.seed
         if seed is None:
             seed = int.from_bytes(__import__("os").urandom(4), "little")
-        key = jax.random.key(seed)
-        key, step_key = jax.random.split(key)
-        counts0 = jnp.zeros((B, self.config.vocab_size), dtype=jnp.int32)
-        res = sample(
-            logits, SampleParams.from_decoding(decoding), step_key,
-            token_counts=counts0,
+        res, key, counts0 = sample_with_counts(
+            logits, decoding, jax.random.key(seed),
+            jnp.zeros((B, self.config.vocab_size), dtype=jnp.int32),
         )
-        counts0 = counts0.at[jnp.arange(B), res.token].add(1)
         # inject: the sampled token is this slot's first pipeline entry
         self.tokens = self.tokens.at[slot].set(res.token)
         self.pos_vec = self.pos_vec.at[slot].set(T_total)

@@ -42,7 +42,6 @@ from dnet_tpu.sched.kinds import QUEUE_STATES, STATE_DECODING
 from dnet_tpu.sched.policy import SchedulerPolicy, TickPlan
 from dnet_tpu.sched.queue import SchedQueue
 from dnet_tpu.sched.step import MAX_STARVED_REQUEUES, TickResult, execute_tick
-from dnet_tpu.transport.wire_pipeline import wire_pipeline_enabled
 from dnet_tpu.utils.logger import get_logger
 
 log = get_logger()
@@ -282,18 +281,18 @@ class SchedulerAdapter(ApiAdapterBase):
                 t0 = time.perf_counter()
                 self._stamp_chunks(plan, t0)
                 on_decode = None
-                if plan.prefills and wire_pipeline_enabled():
-                    # wire-pipeline tick dispatch: decode results leave the
-                    # compute thread the moment the batched dispatch lands,
-                    # so their futures resolve while this tick's prefill
-                    # chunks are still burning — decode TPOT stops paying
-                    # for co-scheduled prompt work.  call_soon_threadsafe
-                    # is the sanctioned bridge (domains.BRIDGE_MODULES);
-                    # FIFO loop ordering guarantees every early resolve
-                    # runs before the executor future resumes _apply.
-                    # (the lambda runs on the compute thread the moment
-                    # decode_batch returns: its clock reading is where the
-                    # token's wait for its future starts)
+                if plan.prefills:
+                    # decode results leave the compute thread the moment
+                    # the step is read, so their futures resolve — and the
+                    # drivers ask for the next token — while this tick's
+                    # prefill chunks are still running on the device.
+                    # call_soon_threadsafe is the sanctioned bridge
+                    # (domains.BRIDGE_MODULES); FIFO loop ordering
+                    # guarantees every early resolve runs before the
+                    # executor future resumes _apply.  (The lambda runs on
+                    # the compute thread the moment the read ends: its
+                    # clock reading is where the token's wait for its
+                    # future starts.)
                     on_decode = lambda nonce, sample: loop.call_soon_threadsafe(  # noqa: E731
                         self._dispatch_decode, plan, nonce, sample,
                         time.perf_counter(),
@@ -390,7 +389,7 @@ class SchedulerAdapter(ApiAdapterBase):
     def _dispatch_decode(
         self, plan: TickPlan, nonce: str, sample, t_done: float
     ) -> None:
-        """Early decode resolution (wire-pipeline tick dispatch): runs on
+        """Early decode resolution (a tick with prefill chunks): runs on
         the loop via call_soon_threadsafe while the tick's prefill chunks
         are still executing.  _apply later skips nonces listed in
         TickResult.dispatched, so a result resolves exactly once."""
@@ -428,6 +427,9 @@ class SchedulerAdapter(ApiAdapterBase):
             self._answering.add(nonce)
 
     def _apply(self, plan: TickPlan, result: TickResult) -> None:
+        """The tick's results into the request state machines and the
+        drivers' futures.  `result` holds host data only (the compute
+        thread read it): nothing here touches a device array."""
         for nonce in result.preempted:
             self.queue.requeue(nonce, reason_preempt=True)
             log_event("preempted", rid=nonce, reason="policy")
@@ -465,13 +467,13 @@ class SchedulerAdapter(ApiAdapterBase):
         dispatched = set(result.dispatched)
         for nonce, sample in result.decode_results.items():
             if nonce in dispatched:
-                continue  # already resolved mid-tick (wire-pipeline path)
+                continue  # already resolved mid-tick (on_decode)
             step = plan.steps.get(nonce)
             if step is None:
                 continue
             self._resolve_step(nonce, step, sample=sample)
-            # readback ended on the compute thread -> future resolved here:
-            # the tick's prefill chunks ran in between
+            # readback ended on the compute thread -> future resolved here
+            # (a tick without chunks: nothing ran in between)
             _DELIVER_WAIT_MS.observe(
                 (time.perf_counter() - result.t_decode_done) * 1000.0
             )

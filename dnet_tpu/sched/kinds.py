@@ -35,3 +35,11 @@ BATCH_KINDS = ("prefill", "decode")
 #: and returned to WAITING because the pool could not cover its next
 #: chunk and no lower-priority victim existed.
 PREEMPT_REASONS = ("block_starvation", "starved_requeue")
+
+#: Label set of dnet_sched_mixed_ticks_total{overlapped=}: ticks that
+#: carried a decode step AND a prefill chunk.  ``yes`` — every chunk was
+#: enqueued behind the step before the step's blocking read, so the device
+#: had work through the read (sched/step.py's rule).  ``no`` — the launch
+#: half had already waited the device out (a per-lane verify block reads
+#: its acceptance counts), so the chunks started on a drained device.
+MIXED_TICK_OVERLAP = ("yes", "no")

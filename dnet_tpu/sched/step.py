@@ -2,34 +2,51 @@
 
 ``execute_tick`` runs ON THE COMPUTE THREAD (the adapter's single-worker
 executor — the same ownership model as every other engine touch).  One
-tick is one mixed launch sequence:
+rule orders a tick: **enqueue all of its device work, then read**.
+Nothing blocks on the device until everything the tick will ask of it is
+queued, so the device never drains inside a tick:
 
-- the batched decode dispatch first (every running stream advances before
-  any prompt token burns — decode latency is what the per-token SLO
-  measures): ONE step that carries every decoding lane whenever a prompt
-  waits (the plan then holds no budgets), a fused R-step chunk only for
-  lanes in phase with nothing queued (core/batch.py: decode_batch); with
-  block-starvation preemption resolved BEFORE the
-  dispatch so a pool shortfall evicts the lowest-priority sequence
-  instead of erroring an arbitrary lane; the dispatch attends the block
-  pool in place through the page tables (ops/paged_attention.py), and
-  this module's block accounting (_decode_need, preemption) is a
-  function of blocks alone;
-- then the tick's chunked-prefill segments on the engine's B=1 bucket
-  programs, staged in the inner engine's dense row; a segment that
-  completes its prompt is adopted into its batch lane (the row's blocks
-  commit into the pool) and its first token sampled in the same tick.
+1. the batched decode dispatch is LAUNCHED first (every running stream
+   advances before any prompt token burns — decode latency is what the
+   per-token SLO measures): ONE step that carries every decoding lane
+   whenever a prompt waits (the plan then holds no budgets), a fused
+   R-step chunk only for lanes in phase with nothing queued
+   (core/batch.py: decode_launch); with block-starvation preemption
+   resolved BEFORE the dispatch so a pool shortfall evicts the
+   lowest-priority sequence instead of erroring an arbitrary lane; the
+   dispatch attends the block pool in place through the page tables
+   (ops/paged_attention.py), and this module's block accounting
+   (_decode_need, preemption) is a function of blocks alone;
+2. then every chunked-prefill segment of the plan is LAUNCHED on the
+   engine's B=1 bucket programs, staged in the inner engine's dense row;
+   a segment that completes its prompt has its adoption ENQUEUED too (the
+   row's blocks commit into the pool, the first token is sampled and the
+   lane's sampling state written: compiled programs, no eager op);
+3. then the decode step's results are READ (core/batch.py: decode_read)
+   and leave the tick at once through ``on_decode``: the drivers answer
+   while the chunks run;
+4. then the tick's first tokens are read, whole fields at a time.
+
+Launch order is device order: decode step, chunks, adoptions.  The step
+appends to the pool it donates and a chunk works in its session's staged
+row, so nothing races.
 
 Preemption keeps the paged prefix intact: the victim's live page table is
 aliased into the PagedPrefixCache (zero copy, refcounted) before the slot
 is released, so its eventual resume re-prefills only what the cache
 cannot cover.  Victims holding engine-buffered fused-chunk tokens are
 skipped — their device position is ahead of the driver-confirmed stream,
-so their table cannot be snapshotted consistently.
+so their table cannot be snapshotted consistently.  A victim a CHUNK
+evicts (step 2) has its decode step in flight: the read half drops that
+step's token and leaves its position alone (its freed lane may already
+be another prompt's), the alias covers what was committed before the
+step, and its pending driver step rides the resume like that of a lane
+evicted before the dispatch.
 
 The executor only reads the plan (loop-side snapshots) and the engine; it
-never touches the scheduler queue.  Results flow back as plain data in a
-:class:`TickResult` the loop applies.
+never touches the scheduler queue.  Results flow back as plain HOST data
+in a :class:`TickResult` the loop applies: no device array leaves the
+compute thread.
 """
 
 from __future__ import annotations
@@ -38,11 +55,14 @@ import time
 from dataclasses import dataclass, field
 from typing import Dict, List
 
+import jax
+
 from dnet_tpu.kv import KVPoolExhausted
 from dnet_tpu.obs import metric, span
 from dnet_tpu.obs.phases import (
     SPAN_PREFILL_ADOPT,
     SPAN_PREFILL_LAUNCH,
+    SPAN_PREFILL_READBACK,
     SPAN_TICK,
     SPAN_TICK_DECODE,
     SPAN_TICK_PREFILL,
@@ -53,6 +73,7 @@ from dnet_tpu.utils.logger import get_logger
 log = get_logger()
 
 _PREEMPTIONS = metric("dnet_sched_preemptions_total")
+_MIXED_TICKS = metric("dnet_sched_mixed_ticks_total")
 
 #: consecutive starved requeues before a prefill surfaces the typed
 #: backpressure error instead of waiting for blocks that may never free
@@ -61,9 +82,10 @@ MAX_STARVED_REQUEUES = 8
 
 @dataclass
 class TickResult:
-    #: nonce -> SampleResult from the batched decode dispatch
+    #: nonce -> SampleResult from the batched decode dispatch (host arrays)
     decode_results: Dict[str, object] = field(default_factory=dict)
-    #: nonce -> SampleResult sampled at prefill completion (adopt)
+    #: nonce -> SampleResult sampled at prefill completion (adopt); host
+    #: arrays once execute_tick returns
     adopted: Dict[str, object] = field(default_factory=dict)
     #: nonce -> absolute staged-token position after this tick's chunk
     progress: Dict[str, int] = field(default_factory=dict)
@@ -74,8 +96,8 @@ class TickResult:
     #: lost the slot race) and should retry from WAITING
     requeued: List[str] = field(default_factory=list)
     #: nonces whose decode result was already handed off mid-tick through
-    #: the wire-pipeline dispatch seam (execute_tick's on_decode) — the
-    #: loop-side apply must not resolve these a second time
+    #: execute_tick's on_decode — the loop-side apply must not resolve
+    #: these a second time
     dispatched: List[str] = field(default_factory=list)
     prefill_tokens: int = 0
     #: lanes the tick's decode_batch call answered — from the dispatch it
@@ -86,7 +108,7 @@ class TickResult:
     #: device; both 0 when every lane was answered from the buffer
     dispatched_lanes: int = 0
     chunk_r: int = 0
-    #: perf_counter when decode_batch returned on the compute thread: the
+    #: perf_counter when the decode read ended on the compute thread: the
     #: loop measures a decode token's wait for its future from here
     t_decode_done: float = 0.0
 
@@ -174,6 +196,8 @@ def _run_prefill_chunk(
         return
     while True:
         try:
+            # enqueues only: the result stays on the device until the tick
+            # has launched everything (execute_tick reads it last)
             with span(SPAN_PREFILL_ADOPT):
                 engine.store_prefix(nonce, chunk.ids)
                 sample = engine.adopt_prefilled(nonce, logits, chunk.decoding)
@@ -187,7 +211,9 @@ def _run_prefill_chunk(
                 # evict and retry IN THIS TICK: end_session frees the
                 # victim's blocks synchronously, and a next-tick retry is
                 # impossible here — the chunks are fully committed, so a
-                # re-driven tick would have no logits left to adopt from
+                # re-driven tick would have no logits left to adopt from.
+                # (The pools' alloc raised before adopt_prefilled enqueued
+                # anything or touched the session's key and counts.)
                 _preempt(engine, victims[0], plan.ids.get(victims[0], []))
                 res.preempted.append(victims[0])
                 continue
@@ -241,14 +267,16 @@ def _handle_prefill_starvation(
 
 
 def execute_tick(engine, plan: TickPlan, on_decode=None) -> TickResult:
-    """One tick on the compute thread.  ``on_decode`` is the wire-pipeline
-    dispatch seam (DNET_WIRE_PIPELINE=1): when set, each decode result is
-    handed off the moment the batched dispatch lands — BEFORE this tick's
-    prefill chunks run — so decode futures resolve (and, on a ring, the
-    next hop's frames launch) while prompt tokens are still burning,
-    instead of barriering the whole tick behind its slowest segment.
-    Results dispatched this way are also recorded in ``dispatched`` so the
-    loop-side apply doesn't resolve them twice."""
+    """One tick on the compute thread: launch the decode step, launch every
+    chunk (and enqueue the adoption of a prompt it completes), read the
+    step, read the first tokens (the module docstring has the why).
+
+    ``on_decode`` hands each decode result off the moment the step is read
+    — while this tick's chunks are still running on the device — so decode
+    futures resolve (and, on a ring, the next hop's frames launch) instead
+    of barriering behind the tick's slowest segment.  Results handed off
+    this way are also recorded in ``dispatched`` so the loop-side apply
+    doesn't resolve them twice."""
     res = TickResult()
     with span(SPAN_TICK, decode_lanes=len(plan.decode),
               prefill_chunks=len(plan.prefills)):
@@ -260,25 +288,14 @@ def _execute(engine, plan: TickPlan, on_decode, res: TickResult) -> None:
     reqs = dict(plan.decode)
     if reqs and getattr(engine, "kv_pool", None) is not None:
         _preempt_for_decode(engine, plan, reqs, res)
+    flight = None
     if reqs:
         with span(SPAN_TICK_DECODE):
-            out, errs = engine.decode_batch(reqs, budgets=plan.budgets or None)
-        res.t_decode_done = time.perf_counter()
-        res.decode_results.update(out)
-        res.errors.update(errs)
+            flight = engine.decode_launch(reqs, budgets=plan.budgets or None)
         res.decode_lanes = len(reqs)
         res.chunk_r, res.dispatched_lanes = getattr(
             engine, "last_dispatch", (0, 0)
         )
-        if on_decode is not None:
-            for nonce, sample in out.items():
-                try:
-                    on_decode(nonce, sample)
-                    res.dispatched.append(nonce)
-                except Exception:
-                    # a failed early dispatch falls back to the barriered
-                    # apply path — the result is still in decode_results
-                    log.exception("early decode dispatch failed for %s", nonce)
     for chunk in plan.prefills:
         if chunk.nonce in res.preempted:
             continue
@@ -293,3 +310,30 @@ def _execute(engine, plan: TickPlan, on_decode, res: TickResult) -> None:
             except Exception as inner:
                 log.debug("abandon_prefill after failure: %s", inner)
             res.errors[chunk.nonce] = str(exc)
+    if flight is not None:
+        if res.chunk_r and plan.prefills:
+            # a step AND a chunk: did the chunks queue up behind the step
+            # (yes), or had the launch half already waited the device out
+            _MIXED_TICKS.labels(
+                overlapped="no" if flight.blocked else "yes"
+            ).inc()
+        with span(SPAN_TICK_DECODE):
+            out, errs = engine.decode_read(flight)
+        res.t_decode_done = time.perf_counter()
+        res.decode_results.update(out)
+        res.errors.update(errs)
+        if on_decode is not None:
+            for nonce, sample in out.items():
+                try:
+                    on_decode(nonce, sample)
+                    res.dispatched.append(nonce)
+                except Exception:
+                    # a failed early dispatch falls back to the barriered
+                    # apply path — the result is still in decode_results
+                    log.exception("early decode dispatch failed for %s", nonce)
+    if res.adopted:
+        # the first tokens, whole fields at a time: the one other place a
+        # tick waits for the device, after everything is enqueued
+        with span(SPAN_PREFILL_READBACK):
+            # dnetlint: disable=DL005 the tick's designed read of its first tokens: every field's copy started at once, waited for after all of the tick's device work is enqueued
+            res.adopted = jax.device_get(res.adopted)

@@ -528,8 +528,24 @@ class FakeStepEngine:
         self.ended.append(nonce)
         self.slot_of.pop(nonce, None)
 
-    def decode_batch(self, requests, budgets=None):
-        return {n: f"tok-{n}" for n in requests}, {}
+    def decode_launch(self, requests, budgets=None):
+        """The enqueue half: the lanes the step was sent for."""
+        from types import SimpleNamespace
+
+        self.last_dispatch = (1, len(requests))
+        return SimpleNamespace(
+            order={n: self.slot_of[n] for n in requests}, blocked=False
+        )
+
+    def decode_read(self, flight):
+        """The read half: as on BatchedEngine, a lane that left while its
+        step was in flight gets nothing and its `pos` is not advanced."""
+        out = {}
+        for n, slot in flight.order.items():
+            if self.slot_of.get(n) == slot:
+                self.pos[slot] += 1
+                out[n] = f"tok-{n}"
+        return out, {}
 
 
 def _chunk(nonce, n_ids=8, victims=(), last=True):
@@ -555,10 +571,13 @@ def test_prefill_starvation_evicts_lower_priority_victim():
     plan.victims = ["low"]
     plan.prefills = [_chunk("urgent", victims=["low"])]
     res = execute_tick(eng, plan)
-    # the victim decoded this tick (decode runs first), was then evicted
-    # with its prefix aliased, and the urgent prefill keeps its staging
-    assert "low" in res.decode_results
+    # the victim's step was launched (decode is enqueued first) and still in
+    # flight when the chunk evicted it, its prefix aliased: the read half
+    # drops that token (the pending step rides the resume) and leaves the
+    # freed lane's position alone; the urgent prefill keeps its staging
+    assert "low" not in res.decode_results
     assert res.preempted == ["low"]
+    assert eng.pos[0] == 6
     assert eng.ended == ["low"] and eng.stored == ["low"]
     assert res.progress["urgent"] == 0  # staged work kept; retry next tick
     assert "urgent" not in res.errors

@@ -202,14 +202,17 @@ def test_scheduler_rehearsal_waits_spans_and_tick_records(tiny_llama_dir, paged_
             + by_name["sched_queue"][0]["dur_ms"]
             + sched_prefill[0]["dur_ms"]
         )
-        assert parts == pytest.approx(ttft, rel=0.05), (parts, ttft)
+        # within 5 %, or the few ms a loaded event loop takes to hand the
+        # token to its driver (since PR 33 the whole wait is some 60 ms here)
+        assert parts == pytest.approx(ttft, rel=0.05, abs=6.0), (parts, ttft)
         # the engine's per-chunk `prefill` spans (enqueue times) lie inside
         # the scheduler's: the ledger's prefill_compute is the real wall
         led = decompose(tl)["segments_ms"]
         assert led["prefill_compute"] == pytest.approx(sched_prefill[0]["dur_ms"], rel=0.02)
         assert led["sched_queue"] == pytest.approx(by_name["sched_queue"][0]["dur_ms"], abs=0.01)
         step0 = next(s for s in by_name["decode_step"] if s["meta"]["step"] == 0)
-        assert led["sched_queue"] + led["prefill_compute"] >= 0.95 * step0["dur_ms"]
+        assert led["sched_queue"] + led["prefill_compute"] >= min(
+            0.95 * step0["dur_ms"], step0["dur_ms"] - 6.0)
 
         # ---- the always-on wait families hold this one request
         assert metric("dnet_sched_queue_wait_ms").count == 1
@@ -227,10 +230,14 @@ def test_scheduler_rehearsal_waits_spans_and_tick_records(tiny_llama_dir, paged_
         n_tick, tick_ms = _span("dnet.tick")
         assert n_tick == metric("dnet_sched_tick_ms").count > chunks
         assert tick_ms <= metric("dnet_sched_tick_ms").sum
-        n_dec, dec_ms = _span("dnet.tick.decode")
+        # dnet.tick.decode opens twice a tick with decode lanes: around the
+        # launch half (prepare, launch) and, once the tick's chunks are
+        # launched, around the read half (readback, unpack)
+        n_halves, dec_ms = _span("dnet.tick.decode")
         child_ms = sum(_span(n)[1] for n in DECODE_CHILD_SPANS)
         assert 0.8 * dec_ms <= child_ms <= dec_ms
-        assert _span("dnet.decode.prepare")[0] == n_dec  # one per decode_batch call
+        n_dec = _span("dnet.decode.prepare")[0]  # one per decode_launch call
+        assert n_halves == 2 * n_dec
         assert DECODE_CHILD_SPANS == tuple(
             f"dnet.decode.{s}" for s in ("prepare", "launch", "readback", "unpack"))
         n_disp = sum(metric("dnet_decode_dispatch_total").labels(r=str(r)).value
@@ -243,7 +250,9 @@ def test_scheduler_rehearsal_waits_spans_and_tick_records(tiny_llama_dir, paged_
         assert n_pf == _span("dnet.prefill.launch")[0] == chunks
         assert _span("dnet.prefill.adopt")[0] == 1
         assert _span("dnet.prefill.launch")[1] + _span("dnet.prefill.adopt")[1] <= pf_ms
-        assert dec_ms + pf_ms <= tick_ms
+        n_rb, rb_ms = _span("dnet.prefill.readback")
+        assert n_rb == 1  # the one tick that adopted a prompt read its first token
+        assert dec_ms + pf_ms + rb_ms <= tick_ms
         assert _span("dnet.sched.apply")[0] == n_tick <= _span("dnet.sched.plan")[0]
         assert _span("dnet.api.sse_flush")[0] == 0  # no HTTP layer in this stack
 

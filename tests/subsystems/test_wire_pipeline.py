@@ -319,40 +319,71 @@ def test_stream_reopen_resends_encoded_frame_with_original_seq(tiny_llama_dir):
 # ---------------------------------------------------------------------------
 
 
-def test_execute_tick_dispatches_decode_before_prefill():
+def test_execute_tick_launches_every_chunk_before_the_decode_read():
+    """The order inside a tick (sched/step.py): the step is LAUNCHED, every
+    chunk launched and a completed prompt's adoption enqueued, THEN the
+    decode read; its results leave through on_decode before any first
+    token is read."""
+    import numpy as np
+
     from dnet_tpu.core.types import DecodingParams
     from dnet_tpu.sched.policy import TickPlan
     from dnet_tpu.sched.step import execute_tick
     from tests.subsystems.test_sched import FakeStepEngine, _chunk
 
     order = []
+
+    class OnDevice:
+        """Stands in for an adopted first token still on the device: the
+        tick's read (jax.device_get) is the only thing that converts it."""
+
+        def __init__(self, nonce):
+            self.nonce = nonce
+
+        def __array__(self, dtype=None, copy=None):
+            order.append(("first_token_read", self.nonce))
+            return np.zeros(1, np.int32)
+
+    def tracked(name, fn, what=lambda *a: a[0]):
+        def call(*a, **k):
+            order.append((name, what(*a)))
+            return fn(*a, **k)
+
+        return call
+
     eng = FakeStepEngine()
     eng.occupy("dec", committed=4, blocks=1)
-    real_prefill = eng.prefill_chunk
-
-    def tracking_prefill(nonce, ids, seed=None):
-        order.append(("prefill", nonce))
-        return real_prefill(nonce, ids, seed)
-
-    eng.prefill_chunk = tracking_prefill
+    eng.decode_launch = tracked("decode_launch", eng.decode_launch, lambda r, **k: sorted(r))
+    eng.decode_read = tracked("decode_read", eng.decode_read, lambda f: sorted(f.order))
+    eng.prefill_chunk = tracked("prefill", eng.prefill_chunk)
+    eng.adopt_prefilled = tracked("adopt", lambda n, logits, dec: OnDevice(n))
     plan = TickPlan()
     plan.decode = {"dec": (42, DecodingParams())}
     plan.steps = {"dec": 3}
-    plan.prefills = [_chunk("new")]
+    plan.prefills = [_chunk("new", last=False), _chunk("done")]
     res = execute_tick(
-        eng, plan, on_decode=lambda n, s: order.append(("decode", n))
+        eng, plan, on_decode=lambda n, s: order.append(("on_decode", n))
     )
-    # the decode result left the tick BEFORE the prefill chunk ran
-    assert order[0] == ("decode", "dec")
-    assert ("prefill", "new") in order
+    assert order == [
+        ("decode_launch", ["dec"]),
+        ("prefill", "new"),
+        ("prefill", "done"),
+        ("adopt", "done"),
+        ("decode_read", ["dec"]),
+        ("on_decode", "dec"),
+        ("first_token_read", "done"),
+    ]
     assert res.dispatched == ["dec"]
     assert "dec" in res.decode_results  # still in the barriered result too
+    assert isinstance(res.adopted["done"], np.ndarray)  # host data only
 
 
 def test_sched_pipeline_parity_and_no_double_resolve(tiny_llama_dir, monkeypatch):
-    """The scheduler + DNET_WIRE_PIPELINE=1: decode futures resolve through
-    the early-dispatch bridge and the barriered apply skips them — the
-    burst's greedy texts equal the non-pipelined scheduler run exactly."""
+    """The scheduler hands decode results off mid-tick whenever the plan
+    has chunks, whatever DNET_WIRE_PIPELINE says (its other readers are
+    shard/compute.py and transport/): decode futures resolve through the
+    early-dispatch bridge, the barriered apply skips them, and the burst's
+    greedy texts are the same with the switch on."""
     from tests.subsystems.test_sched import _serve_burst
 
     prompts = ["Hi", "Hello there", "A quick brown fox", "tail prompt"]
