@@ -532,10 +532,11 @@ def check_attribution_labels(errors: list) -> int:
         n += _cross_check_labels(
             errors, text, fam, "kind", KV_KINDS, "obs.phases.KV_KINDS"
         )
-    n += _cross_check_labels(
-        errors, text, "dnet_retention_tokens_total", "phase", RETENTION_PHASES,
-        "obs.phases.RETENTION_PHASES",
-    )
+    for fam in ("dnet_retention_tokens_total", "dnet_gdn_tokens_total"):
+        n += _cross_check_labels(
+            errors, text, fam, "phase", RETENTION_PHASES,
+            "obs.phases.RETENTION_PHASES",
+        )
     n += _cross_check_labels(
         errors, text, "dnet_moe_assignments_total", "held", MOE_HELD,
         "obs.phases.MOE_HELD",

@@ -117,6 +117,14 @@ def _event_status(exc: BaseException) -> int:
     return 500
 
 
+def _kv_mode(engine) -> str:
+    """The serving engine's KV layout, by core/batch.py's names."""
+    store = getattr(engine, "kv_store", None)
+    if getattr(engine, "kv_pool", None) is not None:
+        return "state+paged" if getattr(store, "in_place", False) else "paged"
+    return "state" if store is not None else "dense"
+
+
 def _resolved_modes(adapter) -> dict:
     """The serving modes a postmortem reader wants next to a request's
     outcome: resolved wire codec, the serving engine's KV layout, TP
@@ -127,11 +135,7 @@ def _resolved_modes(adapter) -> dict:
     engine = getattr(adapter, "engine", None)
     return {
         "codec": s.wire.codec,
-        "kv": (
-            "paged" if getattr(engine, "kv_pool", None) is not None
-            else "state" if getattr(engine, "kv_store", None) is not None
-            else "dense"
-        ),
+        "kv": _kv_mode(engine),
         "tp": int(s.tp.tp),
         "sched": type(adapter).__name__ == "SchedulerAdapter",
     }

@@ -49,6 +49,7 @@ from dnet_tpu.core.types import DecodingParams, EngineCapabilityError
 from dnet_tpu.kv import (
     BlockPool,
     BlockStore,
+    HybridStore,
     KindStore,
     KVPoolExhausted,
     PagedKVConfig,
@@ -83,8 +84,6 @@ _DECODE_TOKENS = metric("dnet_decode_tokens_total")
 _DECODE_BUFFER_DROPPED = metric("dnet_decode_buffer_dropped_total")
 _MOE_ASSIGNMENTS = metric("dnet_moe_assignments_total")
 _STATE_SLOTS_USED = metric("dnet_state_slots_used")
-_RETENTION_BYTES = metric("dnet_retention_state_bytes_total")
-_RETENTION_TOKENS = metric("dnet_retention_tokens_total")
 
 
 @dataclass
@@ -108,18 +107,24 @@ class DecodeFlight:
 KV_PAGED = "paged"  # page tables over the block pool, attended in place
 KV_DENSE = "dense"  # [L, slots, max_seq, ...] rows, one a lane
 KV_STATE = "state"  # one recurrent state entry a lane, updated in place
+KV_HYBRID = "state+paged"  # a lane of state AND a page table, one sequence
 
 
 def kv_layout(
     model, kv_quant_bits: int, spec_lookahead: int, max_seq: int
 ) -> Tuple[str, str]:
     """THE rule for a batched engine's KV cache: (KV_PAGED | KV_DENSE |
-    KV_STATE, why).
+    KV_STATE | KV_HYBRID, why), read off `model.paged_kinds`.
 
-    A model whose layers keep a recurrent state and no keys
-    (`model.paged_kinds` all `state`) serves from the state store, one
-    entry a lane, whatever was asked of the cache: there are no keys to
-    page, quantize or rewind.  Any other model: the paged pool attended in
+    A model whose layers keep a recurrent state and no keys (every kind
+    `state`) serves from the state store, one entry a lane, whatever was
+    asked of the cache: there are no keys to page, quantize or rewind.  A
+    model that MIXES state layers with key-value layers serves from the
+    store that holds both (kv/store.py HybridStore): a lane of state and a
+    page table for the same sequence; a state cannot be rewound, so
+    speculation is off, and where the pool's geometry refuses max_seq the
+    load fails (dense slots have no lane of state to offer).  Any other
+    model: the paged pool attended in
     place, unless something the code can see
     rules it out: per-lane speculation was asked for (its verify blocks
     rewind a dense cache: an explicit request outranks a derived default),
@@ -129,8 +134,20 @@ def kv_layout(
     the same scheduler."""
     from dnet_tpu.ops.paged_attention import ragged_refusal
 
-    if KV_KIND_STATE in (getattr(model, "paged_kinds", None) or ()):
+    kinds = set(getattr(model, "paged_kinds", None) or ())
+    if kinds == {KV_KIND_STATE}:
         return KV_STATE, "one recurrent state entry a lane, updated in place"
+    if KV_KIND_STATE in kinds:
+        why = ragged_refusal(model, kv_quant_bits)
+        if why is not None:
+            raise EngineCapabilityError(
+                f"{model.config.model_type} mixes state and key-value layers: {why}"
+            )
+        PagedKVConfig.from_settings(max_seq)  # a refusing geometry fails the load
+        return KV_HYBRID, (
+            "a lane of recurrent state and a page table over the block pool "
+            "for the same sequence, both updated in place"
+        )
     if spec_lookahead > 0:
         return KV_DENSE, "per-lane speculation needs the dense cache"
     why = ragged_refusal(model, kv_quant_bits)
@@ -228,7 +245,10 @@ class BatchedEngine:
         # window.  All empty under dense slots.
         # A model of the `state` kind has a store (kv_store) and NO pool:
         # kv_pool stays None, which is what admission, preemption and
-        # prefix sharing read, so a lane is all its sequences cost.
+        # prefix sharing read, so a lane is all its sequences cost.  A
+        # model that MIXES state layers with full ones has both: a store
+        # that is updated in place AND the full kind's pool and tables, so
+        # a sequence costs a lane and its blocks, and both are admitted by.
         self.kv_pool: Optional[BlockPool] = None
         self.kv_store: Optional[BlockStore] = None
         #: why prefix sharing is off although it was asked for (/health)
@@ -242,6 +262,8 @@ class BatchedEngine:
         self._window = 0
         if layout == KV_PAGED:
             self._init_pool(m, slots, prefix_size)
+        elif layout == KV_HYBRID:
+            self._init_hybrid_store(m, slots, prefix_size)
         elif layout == KV_STATE:
             self._init_state_store(m, slots, prefix_size)
         else:
@@ -342,10 +364,9 @@ class BatchedEngine:
             cfg.pool_blocks, cfg.block_tokens, slots,
         )
 
-    def _init_state_store(self, m, slots: int, prefix_size: int) -> None:
-        """The state kind's store (KV_STATE): an entry a lane and nothing
-        to manage.  Prefix sharing is refused: a state holds the whole
-        sequence folded together and cannot be cut at a prefix."""
+    def _refuse_prefix_sharing(self, prefix_size: int) -> None:
+        """A state holds the whole sequence folded together and cannot be
+        cut at a prefix: sharing is refused, with its reason (/health)."""
         if prefix_size:
             self.prefix_refusal = (
                 f"{self.eng.config.model_type} keeps a recurrent state, which "
@@ -353,12 +374,39 @@ class BatchedEngine:
                 f"built): DNET_API_PREFIX_CACHE={prefix_size} is ignored"
             )
             log.warning("prefix sharing is OFF: %s", self.prefix_refusal)
+        self.spec_lookahead = 0  # kv_rewindable is False: already warned
+
+    def _init_hybrid_store(self, m, slots: int, prefix_size: int) -> None:
+        """A lane of state AND a page table a sequence (KV_HYBRID): the
+        `full` kind's pool, manager and tables as `_init_pool` keeps them,
+        the `state` kind's entries a lane, in one store.  No prefix cache:
+        a lane's blocks are never aliased, because the state beside them
+        cannot be cut where the blocks can."""
+        self._refuse_prefix_sharing(prefix_size)
+        cfg = PagedKVConfig.from_settings(self.max_seq, slots=slots)
+        self._kv_cfg = cfg
+        self.kv_pool = BlockPool(cfg)
+        self.kv_store = HybridStore(m, cfg, slots, self.eng.kv_dtype)
+        self.kv_pools = {KV_KIND_FULL: self.kv_pool}
+        self._kind_tables = {KV_KIND_FULL: self._tables}
+        _STATE_SLOTS_USED.set(0)
+        log.info(
+            "hybrid store on: %d lanes x %.1f MB of state (%d layers) beside "
+            "%d blocks x %d tokens (%d layers)",
+            slots, self.kv_store.entry_bytes / 1e6,
+            len(self.kv_store.layers[KV_KIND_STATE]), cfg.pool_blocks,
+            cfg.block_tokens, len(self.kv_store.layers[KV_KIND_FULL]),
+        )
+
+    def _init_state_store(self, m, slots: int, prefix_size: int) -> None:
+        """The state kind's store (KV_STATE): an entry a lane and nothing
+        to manage."""
+        self._refuse_prefix_sharing(prefix_size)
         if self.eng.kv_quant_bits:
             log.warning(
                 "DNET_KV_BITS=%d is ignored: a state entry is float32",
                 self.eng.kv_quant_bits,
             )
-        self.spec_lookahead = 0  # kv_rewindable is False: already warned
         self.kv_store = StateStore(m, len(m.layers), slots)
         _STATE_SLOTS_USED.set(0)
         log.info(
@@ -540,12 +588,16 @@ class BatchedEngine:
             def attend_fn(q, k_new, v_new, kvs, kind=None, layer=None, gate=None):
                 # a model of one kind hands over its layer's pool slice
                 # `kvs`; one of two names the layer's kind and its index
-                rows = {"k": k_new[:, 0], "v": v_new[:, 0]}
+                rows = {} if k_new is None else {"k": k_new[:, 0], "v": v_new[:, 0]}
                 if store.in_place:
                     # the state kind: `kvs` is the stack the scan carries,
                     # and the step is the read AND the write, for the
-                    # active lanes alone: attend returns (attn, the stack)
-                    rows.update(gate=gate[:, 0], active=active)
+                    # active lanes alone: attend returns (attn, the stack).
+                    # A gate that is a dict is the layer's own affair (a
+                    # state layer of a hybrid model), handed on as it is
+                    if gate is not None and not isinstance(gate, dict):
+                        gate = gate[:, 0]
+                    rows.update(gate=gate, active=active)
                 out = store.attend(pool, kvs, q, rows, tables, pos, kind, layer, impl)
                 return out if store.in_place else (out, rows)
 
@@ -812,10 +864,11 @@ class BatchedEngine:
             n_full = self._adopt.get(nonce, (0, [], 0))[2]
             need = self._kv_cfg.blocks_for(min(pos + len(ids), self.max_seq))
             self.kv_pool.require(max(need - n_full, 0))
-        elif self.kv_store is not None:
+        if self.kv_store is not None and self.kv_store.in_place:
             # the state kind: the chunk takes the session's entry in and
-            # hands it on; nothing to admit, the lane is already held
-            _RETENTION_TOKENS.labels(phase="prefill").inc(len(ids))
+            # hands it on; nothing of it to admit, the lane is already held
+            _, state_tokens = self.kv_store.state_counters
+            state_tokens.labels(phase="prefill").inc(len(ids))
         return self.eng.prefill(nonce, list(ids), seed, allow_store=False)
 
     def abandon_prefill(self, nonce) -> None:
@@ -882,10 +935,10 @@ class BatchedEngine:
             if stash is not None:
                 self._adopt[nonce] = stash  # abandon_prefill releases it
             raise
-        self.kv_store.commit_staged(
-            sess.kv,
-            {kind: (list(range(first[kind], nb)), got) for kind, got in own.items()},
-        )
+        staged = {kind: (list(range(first[kind], nb)), got) for kind, got in own.items()}
+        if KV_KIND_STATE in self.kv_store.kinds:
+            staged[KV_KIND_STATE] = slot  # the session's entry over the lane's
+        self.kv_store.commit_staged(sess.kv, staged)
         if stash is not None:
             if n_sh % cfg.block_tokens:
                 # the request diverged mid-block: the shared tail block was
@@ -1200,8 +1253,9 @@ class BatchedEngine:
         if self.kv_store is not None and self.kv_store.in_place:
             # what the algorithm needs: each active lane's entry read and
             # written once a step, in every layer
-            _RETENTION_BYTES.inc(R * lanes * self.kv_store.entry_bytes * 2)
-            _RETENTION_TOKENS.labels(phase="decode").inc(R * lanes)
+            state_bytes, state_tokens = self.kv_store.state_counters
+            state_bytes.inc(R * lanes * self.kv_store.entry_bytes * 2)
+            state_tokens.labels(phase="decode").inc(R * lanes)
         # per-token share, observed tokens-served times: the family's
         # count stays == tokens across the local / chunked / speculative /
         # batched paths (LocalEngine's amortization convention), and the
@@ -1349,6 +1403,9 @@ class BatchedEngine:
             self.kv_store.kv = pool
             return stacked, moe
         res, rows, self.counts, self.keys, moe = self._ragged_step(*args)
+        if self.kv_store.in_place:
+            self.kv_store.append_rows(rows, {}, None)  # the step already wrote
+            return res, moe
         bt = self._block_tokens
         # inactive-lane sentinel: past the block axis, never negative
         # (see BlockStore.append_in_program)

@@ -14,7 +14,9 @@ no layout: `kinds`, `attend`, `append_in_program`, `append_rows` and
 one-kind store (`full` alone, the layout above, which the prefix cache
 also reads); `KindStore` holds a pool a kind for a model that mixes window
 and full layers; `StateStore` holds the third kind, `state`: one recurrent
-entry a lane, no blocks, behind the same five names.
+entry a lane, no blocks, behind the same five names; `HybridStore` holds a
+`full` pool AND `state` entries for a model that mixes the two, so that
+one sequence sees one store.
 
 Two programs move whole blocks between a staged dense row and the pool:
 
@@ -51,6 +53,18 @@ from dnet_tpu.obs.phases import (
 )
 
 _STATE_SLOTS = metric("dnet_state_slots")
+#: the state kind's traffic counters (bytes, tokens{phase=}), by the op
+#: family the model's state layers run (`RingModel.state_family`)
+_STATE_COUNTERS = {
+    "retention": (
+        metric("dnet_retention_state_bytes_total"),
+        metric("dnet_retention_tokens_total"),
+    ),
+    "gdn": (
+        metric("dnet_gdn_state_bytes_total"),
+        metric("dnet_gdn_tokens_total"),
+    ),
+}
 
 
 def _bucket_pow2(n: int) -> int:
@@ -263,6 +277,7 @@ class StateStore:
 
         #: bytes of one lane's state over every layer
         self.entry_bytes = n_layers * state_entry_bytes(c.num_key_value_heads, c.head_dim)
+        self.state_counters = _STATE_COUNTERS[model.state_family]
         _STATE_SLOTS.set(slots)
 
         @jax.named_scope("kv_scatter")
@@ -442,3 +457,148 @@ class KindStore:
         self.kv = self._commit(self.kv, kv_row, block_idx, phys)
 
     append_rows = BlockStore.append_rows
+
+
+class HybridStore:
+    """ONE store for a model that mixes `state` layers with `full` layers
+    (models/qwen3_next.py): a sequence holds a lane of recurrent state for
+    the former and a table of blocks for the latter, at once.  Made of the
+    two layouts that exist: the full kind's pool as `KindStore` keeps it
+    (`[L_full, N, block_tokens, KVH*Hd]`, heads merged into the lanes, the
+    kernel takes the layer by index), the state kind's entries as
+    `StateStore` keeps them (`[L_state, slots, ...]`, a lane IS the
+    address).  `self.kv` is `{"full": {"k", "v"}, "state": {...}}`.
+
+    `in_place`: the whole store rides the decode step donated, as the
+    carry of the model's scan.  A state layer's `attend` is its read, decay
+    and correction (`gdn_decode`); a full layer's is the kernel's read of
+    the pool through the page tables AND the new row's write into the
+    lane's block, so `append_in_program` / `append_rows` have nothing left
+    to write.  `commit_staged` is adoption: the staged row's blocks into
+    the pool and the session's state entry over the lane's, in one program.
+
+    Admission is by both: a free lane and the blocks of the `full` pool
+    (core/batch.py, sched/policy.py).  A lane's blocks are never aliased
+    into a prefix cache: the state beside them cannot be cut at a prefix."""
+
+    in_place = True
+    kinds = (KV_KIND_FULL, KV_KIND_STATE)
+
+    def __init__(self, model, cfg: PagedKVConfig, slots: int, kv_dtype: str) -> None:
+        self.cfg = cfg
+        self.slots = slots
+        self.block_tokens = bt = cfg.block_tokens
+        self.layers = {}
+        for i, kind in enumerate(model.paged_kinds):
+            self.layers[kind] = self.layers.get(kind, ()) + (i,)
+        if set(self.layers) != set(self.kinds):
+            raise ValueError(f"layer kinds {sorted(self.layers)} != {sorted(self.kinds)}")
+        c = model.config
+        width = c.num_key_value_heads * c.head_dim
+        dt = jnp.dtype(kv_dtype)
+        n_full = len(self.layers[KV_KIND_FULL])
+        # the model's own session layout, a lane where a sequence would be
+        entries = model.init_kv(len(model.paged_kinds), slots, 0, kv_dtype)
+        state_keys = tuple(k for k in entries if k not in ("k", "v"))
+        self.kv = {
+            KV_KIND_FULL: {
+                leaf: jnp.zeros((n_full, cfg.pool_blocks, bt, width), dt)
+                for leaf in ("k", "v")
+            },
+            KV_KIND_STATE: {k: entries[k] for k in state_keys},
+        }
+        #: bytes of one lane's state over every state layer
+        self.entry_bytes = sum(
+            int(np.prod(v.shape[2:])) * v.shape[0] * v.dtype.itemsize
+            for v in self.kv[KV_KIND_STATE].values()
+        )
+        self.state_counters = _STATE_COUNTERS[model.state_family]
+        _STATE_SLOTS.set(slots)
+
+        @jax.named_scope("kv_scatter")
+        def commit(store, dense, block_idx, phys, slot):
+            """One staged sequence into the store: dense {"k", "v":
+            [L_full, 1, S, KVH, Hd], state leaves [L_state, 1, ...]}."""
+
+            def blocks(p, d):
+                rows = d[:, 0]  # [Lf, S, KVH, Hd]
+                Lf, S = rows.shape[:2]
+                blk = rows.reshape(Lf, S // bt, bt, -1)[:, block_idx]
+                return p.at[:, phys].set(blk.astype(p.dtype))
+
+            def lane(s, r):
+                return jax.lax.dynamic_update_slice_in_dim(s, r.astype(s.dtype), slot, axis=1)
+
+            return {
+                KV_KIND_FULL: {
+                    leaf: blocks(store[KV_KIND_FULL][leaf], dense[leaf]) for leaf in ("k", "v")
+                },
+                KV_KIND_STATE: {
+                    k: lane(store[KV_KIND_STATE][k], dense[k]) for k in state_keys
+                },
+            }
+
+        self._commit_both = instrument_jit(jax.jit(commit, donate_argnums=(0,)), "kv_scatter")
+
+    def attend(self, pool, kvs, q, rows, tables, pos, kind, layer, impl):
+        """Traced: one layer's decode step over every lane.  `kvs` is the
+        store as the scan carries it (`pool`, the store before the first
+        layer, goes unread); `kind` is the layer's, a static name; `layer`
+        its (traced) index within the kind.  Returns (o, the store)."""
+        active = rows["active"]
+        if kind == KV_KIND_STATE:
+            from dnet_tpu.ops.gated_delta import gdn_decode
+
+            gate = rows["gate"]
+            o, state = gdn_decode(
+                kvs[KV_KIND_STATE], q[:, 0], gate["conv_w"], gate["g"], gate["beta"],
+                active, layer, impl=impl,
+            )
+            return o[:, None], {**kvs, KV_KIND_STATE: state}
+        from dnet_tpu.ops.paged_attention import paged_attend
+
+        full = kvs[KV_KIND_FULL]
+        table = tables[KV_KIND_FULL]
+        out = paged_attend(
+            q, full["k"], full["v"], table, pos, rows["k"], rows["v"],
+            impl=impl, layer=layer,
+        )
+        # the new row into the lane's block: ONE plain row scatter into the
+        # [L*N*bt, W] view (KindStore.append_in_program has the why); an
+        # idle lane's row lands past the end and is dropped
+        bt = self.block_tokens
+        Lf, N, _, W = full["k"].shape
+        bidx = jnp.clip(pos // bt, 0, table.shape[1] - 1)
+        phys = jnp.take_along_axis(table, bidx[:, None], axis=1)[:, 0]
+        row = (layer * N + phys) * bt + pos % bt
+        row = jnp.where(active, row, Lf * N * bt)
+
+        def write(p, r):
+            flat = p.reshape(Lf * N * bt, W).at[row].set(
+                r.reshape(-1, W).astype(p.dtype), mode="drop"
+            )
+            return flat.reshape(p.shape)
+
+        full = {"k": write(full["k"], rows["k"]), "v": write(full["v"], rows["v"])}
+        return out, {**kvs, KV_KIND_FULL: full}
+
+    def append_in_program(self, pool, rows, phys, off):
+        """Traced: the step already wrote; `rows` IS the store after it."""
+        return rows
+
+    def append_rows(self, rows: dict, phys: dict, off) -> None:
+        self.kv = rows
+
+    def commit_staged(self, kv_row: dict, blocks: dict) -> None:
+        """blocks: {full: (logical block indices, physical blocks), state:
+        the lane} of one session's staged row and entries.  The block list
+        pads to a power of two by repeating the last pair (a duplicate
+        write of identical content)."""
+        lb, pb = blocks[KV_KIND_FULL]
+        K = _bucket_pow2(max(len(lb), 1))
+        lb = list(lb) + [lb[-1]] * (K - len(lb))
+        pb = list(pb) + [pb[-1]] * (K - len(pb))
+        self.kv = self._commit_both(
+            self.kv, kv_row, np.asarray(lb, np.int32), np.asarray(pb, np.int32),
+            np.int32(blocks[KV_KIND_STATE]),
+        )

@@ -120,6 +120,10 @@ class RingModel(abc.ABC):
     # everything, a `window` layer's page table gives back the blocks
     # behind the window; None = all full
     paged_kinds: Optional[Tuple[str, ...]] = None
+    # the op family of a model's `state` layers, which names the counters
+    # their traffic is booked under (kv/store.py): "retention"
+    # (dnet_retention_*) or "gdn" (dnet_gdn_*)
+    state_family: str = "retention"
     # per-layer param names eligible for weight-only quantization (the big
     # matmuls; norms/biases/routers stay float).  Subclasses override.
     quant_keys: frozenset = frozenset(QUANTIZABLE)

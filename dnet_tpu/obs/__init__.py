@@ -164,6 +164,22 @@ def _register_core(reg: MetricsRegistry) -> None:
     )
     for phase in RETENTION_PHASES:
         ret_fam.labels(phase=phase)  # pre-touch: the lint checks these
+    # the same two books for the gated delta rule's state layers
+    # (ops/gated_delta.py; a hybrid model's, kv/store.py HybridStore)
+    reg.counter(
+        "dnet_gdn_state_bytes_total",
+        "Bytes of gated-delta-rule state (S and the conv tail) the batched "
+        "decode dispatches read and wrote: active lanes x steps x state "
+        "layers x one entry x 2",
+    )
+    gdn_fam = reg.counter(
+        "dnet_gdn_tokens_total",
+        "Tokens that went through the state layers' gated delta rule, by "
+        "the program that carried them",
+        labelnames=("phase",),
+    )
+    for phase in RETENTION_PHASES:
+        gdn_fam.labels(phase=phase)
     for name, help_text in (
         ("dnet_kv_blocks_used",
          "Paged KV pool blocks currently allocated (refcount >= 1), by the "

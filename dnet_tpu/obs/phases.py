@@ -85,7 +85,9 @@ SCOPE_ATTN_WINDOW = "dnet.attn.window"
 SCOPE_ATTN_FULL = "dnet.attn.full"
 SCOPE_MOE_SHARED = "dnet.moe.shared"
 # inside dnet.attn of a model whose layers keep a recurrent state and no
-# keys (models/brumby.py): the state's read, decay and update
+# keys (models/brumby.py), or of one that mixes such layers with full ones
+# (models/qwen3_next.py: the delta rule and its convolution here, the
+# softmax layers under dnet.attn.full): the state's read, decay and update
 SCOPE_ATTN_STATE = "dnet.attn.state"
 DEVICE_SCOPES = (
     SCOPE_SAMPLE, SCOPE_LM_HEAD, SCOPE_MOE, SCOPE_ATTN,
@@ -104,12 +106,15 @@ KV_KINDS = (KV_KIND_FULL, KV_KIND_WINDOW)
 # a lane, of one size whatever the sequence's length (kv/store.py
 # StateStore), so it has no pool, no page table and no series among the
 # dnet_kv_blocks_* families: dnet_state_slots / dnet_state_slots_used are
-# its books, and a lane is all a sequence costs.
+# its books, and a lane is all a sequence costs.  A model that mixes state
+# layers with full ones (kv/store.py HybridStore) keeps BOTH books: a lane
+# and the `full` kind's blocks for the same sequence.
 KV_KIND_STATE = "state"
 
-# dnet_retention_tokens_total{phase=}: tokens that went through a state
-# layer's retention op, by the program that carried them (a prefill chunk's
-# real tokens; a decode dispatch's lanes x steps)
+# dnet_retention_tokens_total{phase=} / dnet_gdn_tokens_total{phase=}:
+# tokens that went through a state layer's op (power retention; the gated
+# delta rule), by the program that carried them (a prefill chunk's real
+# tokens; a decode dispatch's lanes x steps)
 RETENTION_PHASES = ("prefill", "decode")
 
 # dnet_moe_assignments_total{held=}: (token, chosen expert) pairs of the

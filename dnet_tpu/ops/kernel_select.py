@@ -1,10 +1,11 @@
 """Which implementation each Pallas dispatcher picked, counted per process.
 
-Seven dispatchers choose between a Pallas kernel and a jnp path at trace
+Nine dispatchers choose between a Pallas kernel and a jnp path at trace
 time: causal prefill (`ops/flash_attention.py`), split-K decode
 (`ops/flash_decode.py`), ragged paged attend (`ops/paged_attention.py`), the
-two hop-codec kernels (`compression/ops.py`) and power retention's decode
-step and prefill chunk (`ops/retention.py`).  The backend half of
+two hop-codec kernels (`compression/ops.py`), power retention's decode
+step and prefill chunk (`ops/retention.py`) and the gated delta rule's
+(`ops/gated_delta.py`).  The backend half of
 that choice lives here, so that it is made one way: a TPU backend runs the
 Mosaic-compiled kernel and nothing else; DNET_FLASH_INTERPRET=1 selects
 interpret mode on a CPU backend (tier-1) and is an error on a TPU one.
@@ -23,7 +24,7 @@ import jax
 
 #: what a dispatcher can resolve to
 IMPLS = ("pallas", "interpret", "emulate", "dense")
-#: the seven dispatchers, by the name `/health` reports them under
+#: the nine dispatchers, by the name `/health` reports them under
 KERNELS = (
     "flash_prefill",
     "flash_decode",
@@ -32,6 +33,8 @@ KERNELS = (
     "column_select",
     "retention_step",
     "retention_chunk",
+    "gdn_step",
+    "gdn_chunk",
 )
 
 

@@ -83,13 +83,17 @@ def ragged_refusal(model, kv_quant_bits: int) -> Optional[str]:
             f"quantized KV cache (bits={kv_quant_bits}): the kernel reads "
             "unquantized blocks"
         )
-    kinds = getattr(model, "paged_kinds", None) or (KV_KIND_FULL,)
-    if KV_KIND_STATE in kinds:
+    kinds = set(getattr(model, "paged_kinds", None) or (KV_KIND_FULL,))
+    if kinds == {KV_KIND_STATE}:
         # not the pool's to serve, and not dense slots' either: kv_layout
         # sends it to the state store before it asks here
         return "recurrent-state layers keep no blocks (the state store serves them)"
     if KV_KIND_FULL not in kinds:
         return "no full layer among the window layers"
+    if KV_KIND_STATE in kinds and len(kinds) > 2:
+        # the store that holds a lane of state beside a page table
+        # (kv/store.py HybridStore) has the full kind's pool alone
+        return "state layers beside full AND window layers (no store holds all three)"
     return None
 
 

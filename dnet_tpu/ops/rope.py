@@ -110,16 +110,22 @@ def apply_rope(
     x: [B, T, N, head_dim] (head_dim even, half-split convention as in HF).
     positions: [B, T] or [T] absolute token positions.
     attention_scaling: YaRN mscale multiplier on cos/sin.
+
+    A rotary width narrower than the head (HF `partial_rotary_factor`):
+    `inv_freq` has R/2 entries for R < head_dim, the FIRST R dims rotate
+    (half-split within them) and the rest pass through.
     """
-    angles = positions[..., None].astype(jnp.float32) * inv_freq  # [..., T, D/2]
-    if angles.ndim == 2:  # [T, D/2] -> broadcast over batch
+    angles = positions[..., None].astype(jnp.float32) * inv_freq  # [..., T, R/2]
+    if angles.ndim == 2:  # [T, R/2] -> broadcast over batch
         angles = angles[None]
-    cos = (jnp.cos(angles) * attention_scaling)[:, :, None, :]  # [B, T, 1, D/2]
+    cos = (jnp.cos(angles) * attention_scaling)[:, :, None, :]  # [B, T, 1, R/2]
     sin = (jnp.sin(angles) * attention_scaling)[:, :, None, :]
-    half = x.shape[-1] // 2
+    half = inv_freq.shape[-1]
     x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:].astype(jnp.float32)
+    x2 = x[..., half:2 * half].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    if 2 * half < x.shape[-1]:
+        return jnp.concatenate([out.astype(x.dtype), x[..., 2 * half:]], axis=-1)
     return out.astype(x.dtype)
 
 

@@ -43,6 +43,15 @@ be another prompt's), the alias covers what was committed before the
 step, and its pending driver step rides the resume like that of a lane
 evicted before the dispatch.
 
+**A lane that holds recurrent state is never aliased** (a hybrid model's
+store, kv/store.py HybridStore: a lane of state beside the page table).
+Its blocks could be cut at a prefix, the state beside them cannot, and a
+prefix entry that aliased the blocks would hand a later request keys
+without the state that goes with them.  The rule taken: such a victim is
+preempted by giving EVERYTHING back (its lane, its state, its blocks) and
+its resume prefills again from token 0.  Parking the state aside is not
+built (PERF.md section 7).
+
 The executor only reads the plan (loop-side snapshots) and the engine; it
 never touches the scheduler queue.  Results flow back as plain HOST data
 in a :class:`TickResult` the loop applies: no device array leaves the
@@ -138,7 +147,9 @@ def _preempt(engine, nonce: str, ids: List[int]) -> None:
     the inconsistent snapshot — and its resume recomputes the dropped
     lookahead (greedy-deterministic, so the stream is unchanged)."""
     slot = engine.slot_of.get(nonce)
-    if slot is not None and ids:
+    store = getattr(engine, "kv_store", None)
+    stateful = store is not None and store.in_place
+    if slot is not None and ids and not stateful:
         committed = ids[: int(engine.pos[slot])]
         try:
             engine.store_prefix(nonce, committed)

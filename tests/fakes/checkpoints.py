@@ -535,3 +535,30 @@ def make_tiny_brumby(model_dir: str | Path, seed: int = 2**31 + 32) -> dict:
     cfg = tiny_brumby_config()
     write_checkpoint(Path(model_dir), cfg, seed=seed, dtype="float32")
     return cfg
+
+
+def tiny_qwen3_next_config(**over) -> dict:
+    """The benchmark configuration `qwen3-next-80b-a3b-4l-ep2` at its
+    rehearsal size (hidden 64, 2 key / 4 value heads of 16, one period of
+    4 layers, 16 experts held of 32, top-4): the HF keys alone."""
+    import json
+
+    root = Path(__file__).resolve().parents[2]
+    full = json.loads(
+        (root / "benchmarks/configs/qwen3-next-80b-a3b-4l-ep2.json").read_text()
+    )
+    cfg = {k: v for k, v in full.items()
+           if k not in ("assumed", "deployment", "serve", "check", "rehearse")}
+    cfg.update(full["rehearse"]["config"])
+    cfg.update(over)
+    return cfg
+
+
+def make_tiny_qwen3_next(model_dir: str | Path, seed: int = 2**31 + 37, **over) -> dict:
+    """A seeded float32 qwen3_next checkpoint, written as the benchmark
+    writes its own (tensor names from benchmarks/reference/qwen3_next.py)."""
+    from benchmarks.harness.weights import write_checkpoint
+
+    cfg = tiny_qwen3_next_config(**over)
+    write_checkpoint(Path(model_dir), cfg, seed=seed, dtype="float32")
+    return cfg
