@@ -48,7 +48,6 @@ from dnet_tpu.core.sampler import (
 from dnet_tpu.core.types import DecodingParams, EngineCapabilityError
 from dnet_tpu.kv import (
     BlockPool,
-    BlockStore,
     HybridStore,
     KindStore,
     KVPoolExhausted,
@@ -250,7 +249,7 @@ class BatchedEngine:
         # that is updated in place AND the full kind's pool and tables, so
         # a sequence costs a lane and its blocks, and both are admitted by.
         self.kv_pool: Optional[BlockPool] = None
-        self.kv_store: Optional[BlockStore] = None
+        self.kv_store = None  # a store of kv/store.py, whatever the kinds
         #: why prefix sharing is off although it was asked for (/health)
         self.prefix_refusal: Optional[str] = None
         self.paged_prefix: Optional[PagedPrefixCache] = None
@@ -328,14 +327,20 @@ class BatchedEngine:
             )
             prefix_size = 0
         cfg = PagedKVConfig.from_settings(self.max_seq, slots=slots + prefix_size)
+        cfgs, per_slot = {KV_KIND_FULL: cfg}, 0
         if windowed:
-            store = self._window_store(m, cfg, slots)
-        else:
-            store = BlockStore(
-                m, len(m.layers), cfg, self.eng.kv_dtype,
-                quant_bits=self.eng.kv_quant_bits,
-                session_tokens=self.max_seq,
-            )
+            # the window kind's pool is sized so that it can never be what
+            # admission waits for: every slot may hold the most blocks a
+            # window table ever has
+            from dnet_tpu.config import get_settings
+
+            step = max(get_settings().sched.prefill_chunk_cap(), *self.CHUNK_BUCKETS)
+            per_slot = window_blocks(int(m.window), cfg.block_tokens, step)
+            cfgs[KV_KIND_WINDOW] = PagedKVConfig(cfg.block_tokens, slots * per_slot)
+        store = KindStore(
+            m, cfgs, self.eng.kv_dtype, window_width=per_slot,
+            session_tokens=self.max_seq,
+        )
         self._kv_cfg = cfg
         self.kv_pool = BlockPool(cfg)
         self.kv_store = store
@@ -412,20 +417,6 @@ class BatchedEngine:
         log.info(
             "state store on: %d lanes x %.1f MB (%d layers), no blocks",
             slots, self.kv_store.entry_bytes / 1e6, len(m.layers),
-        )
-
-    def _window_store(self, m, cfg: PagedKVConfig, slots: int) -> KindStore:
-        """Pools by kind for a model with window layers.  The window kind's
-        pool is sized so that it can never be what admission waits for:
-        every slot may hold the most blocks a window table ever has."""
-        from dnet_tpu.config import get_settings
-
-        step = max(get_settings().sched.prefill_chunk_cap(), *self.CHUNK_BUCKETS)
-        per_slot = window_blocks(int(m.window), cfg.block_tokens, step)
-        wcfg = PagedKVConfig(cfg.block_tokens, slots * per_slot)
-        return KindStore(
-            m, {KV_KIND_FULL: cfg, KV_KIND_WINDOW: wcfg}, self.eng.kv_dtype,
-            window_width=per_slot,
         )
 
     # ---- program ------------------------------------------------------
@@ -586,8 +577,8 @@ class BatchedEngine:
             per slot."""
 
             def attend_fn(q, k_new, v_new, kvs, kind=None, layer=None, gate=None):
-                # a model of one kind hands over its layer's pool slice
-                # `kvs`; one of two names the layer's kind and its index
+                # the model names the layer's index within its kind (and
+                # the kind, where it has two); the pool is closed over
                 rows = {} if k_new is None else {"k": k_new[:, 0], "v": v_new[:, 0]}
                 if store.in_place:
                     # the state kind: `kvs` is the stack the scan carries,
@@ -1408,7 +1399,7 @@ class BatchedEngine:
             return res, moe
         bt = self._block_tokens
         # inactive-lane sentinel: past the block axis, never negative
-        # (see BlockStore.append_in_program)
+        # (see KindStore.append_in_program)
         phys = {
             kind: np.full(self.slots, pool.total, dtype=np.int32)
             for kind, pool in self.kv_pools.items()

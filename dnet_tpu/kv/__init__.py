@@ -23,11 +23,10 @@ from dnet_tpu.kv.paged import (
     window_first_block,
 )
 from dnet_tpu.kv.prefix import PagedPrefixCache
-from dnet_tpu.kv.store import BlockStore, HybridStore, KindStore, StateStore
+from dnet_tpu.kv.store import HybridStore, KindStore, StateStore
 
 __all__ = [
     "BlockPool",
-    "BlockStore",
     "HybridStore",
     "KVPoolExhausted",
     "KindStore",

@@ -28,7 +28,7 @@ from typing import List, Optional, Sequence, Tuple, Union
 
 from dnet_tpu.core.prefix_cache import PrefixIndex
 from dnet_tpu.kv.paged import BlockPool, KVPoolExhausted
-from dnet_tpu.kv.store import BlockStore
+from dnet_tpu.kv.store import KindStore
 from dnet_tpu.utils.logger import get_logger
 
 log = get_logger()
@@ -60,7 +60,7 @@ class PagedPrefixCache:
     def __init__(
         self,
         pool: BlockPool,
-        store: BlockStore,
+        store: KindStore,
         capacity: int,
         min_tokens: int = 16,
     ) -> None:

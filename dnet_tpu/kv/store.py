@@ -1,35 +1,39 @@
 """Device half of the paged KV subsystem: pool-shaped cache arrays plus
 the jitted programs that read and write them.
 
-The pool reuses the existing functional cache layout with the BATCH axis
-repurposed as the block axis: `model.init_kv(L, pool_blocks, block_tokens)`
-yields `[L, N_blocks, block_tokens, KVH, Hd]` leaves (quantized caches
-bring their scale leaves along for free, since every op here is a
-jax.tree.map).
+ONE pool layout: a kind's layers share `[L_kind, N_blocks, block_tokens,
+KVH*Hd]` leaves `{"k", "v"}`, the heads merged into the lane dimension once,
+at construction: the layout the ragged kernel reads (it takes the layer by
+index), so nothing slices, reshapes or relayouts a pool per step or per
+prompt.  (A quantised cache is never paged: `core/batch.py: kv_layout` sends
+it to dense slots, so no pool carries scale leaves.)
 
 The decode path (core/batch.py `_build_ragged`) attends the pool IN PLACE
 and speaks to a store by KIND of layer (obs/phases.py KV_KINDS), knowing
 no layout: `kinds`, `attend`, `append_in_program`, `append_rows` and
-`commit_staged` take and return `{kind: ...}`.  `BlockStore` is the
-one-kind store (`full` alone, the layout above, which the prefix cache
-also reads); `KindStore` holds a pool a kind for a model that mixes window
-and full layers; `StateStore` holds the third kind, `state`: one recurrent
-entry a lane, no blocks, behind the same five names; `HybridStore` holds a
-`full` pool AND `state` entries for a model that mixes the two, so that
-one sequence sees one store.
+`commit_staged` take and return `{kind: ...}`.  `KindStore` is the pool
+store: a pool a kind, `full` alone for most models (which the prefix cache
+also reads), `window` beside it for a model that mixes window and full
+layers; `StateStore` holds the third kind, `state`: one recurrent entry a
+lane, no blocks, behind the same five names; `HybridStore` holds a `full`
+pool (the same layout) AND `state` entries for a model that mixes the two,
+so that one sequence sees one store.
 
 Two programs move whole blocks between a staged dense row and the pool:
 
 - **gather_row** (prefix restore) builds one sequence's contiguous
-  `[L, 1, nb*bt, ...]` row: one `pool[:, ids]` take per leaf.  Table
-  entries past the sequence clamp to block 0; their rows sit at positions
-  the causal mask excludes, so exp() zeroes them EXACTLY.
-- **commit_row** (adoption, prefix store) writes a staged row's blocks
-  into the pool with `.at[:, phys].set`, the pool buffers DONATED so XLA
-  updates in place.  Widths are bucketed to powers of two (padding repeats
-  the last block — duplicate writes of identical content are
-  deterministic) so the compiled-program set stays bounded, the same
-  discipline as the engines' chunk buckets.
+  `[L, 1, nb*bt, KVH, Hd]` row: one `pool[:, ids]` take per leaf, the
+  heads split on the gathered row.  Table entries past the sequence clamp
+  to block 0; their rows sit at positions the causal mask excludes, so
+  exp() zeroes them EXACTLY.
+- **commit_staged** / **commit_row** (adoption, prefix store) take a
+  staged row's blocks, merge the heads on THOSE, and write them into the
+  pool with `.at[:, phys].set`, the pool buffers DONATED so XLA updates in
+  place (tests/test_pool_layout_v5e_compile.py holds the compiled programs
+  to that: no copy as large as a pool leaf).  Widths are bucketed to powers
+  of two (padding repeats the last block: duplicate writes of identical
+  content are deterministic) so the compiled-program set stays bounded, the
+  same discipline as the engines' chunk buckets.
 """
 
 from __future__ import annotations
@@ -74,181 +78,14 @@ def _bucket_pow2(n: int) -> int:
     return b
 
 
-class BlockStore:
-    """Pool-shaped KV arrays + their cached programs."""
-
-    #: the decode step reads the pool and hands back the new rows, which
-    #: `append_rows` writes; a store that is `in_place` updates itself
-    #: inside the step instead (StateStore)
-    in_place = False
-
-    def __init__(
-        self,
-        model,
-        n_layers: int,
-        cfg: PagedKVConfig,
-        kv_dtype: str,
-        quant_bits: int = 0,
-        session_tokens: int = 0,
-    ) -> None:
-        self.cfg = cfg
-        self.block_tokens = cfg.block_tokens
-        self.kv = model.init_kv(
-            n_layers, cfg.pool_blocks, cfg.block_tokens, kv_dtype,
-            quant_bits=quant_bits, rotating=False,
-        )
-        for leaf in jax.tree.leaves(self.kv):
-            if leaf.shape[1] != cfg.pool_blocks or leaf.shape[2] != cfg.block_tokens:
-                # a model with per-kind cache shapes cannot repurpose the
-                # batch axis as blocks
-                raise NotImplementedError(
-                    "paged KV needs the flat [L, B, S, ...] cache layout; "
-                    f"got leaf shape {leaf.shape}"
-                )
-        if session_tokens:
-            # the pool probe alone cannot catch rotating-SWA models: their
-            # ring buffers collapse to uniform leaves when rotating=False,
-            # but the SESSION caches the engines gather into / commit from
-            # (init_kv rotating=True, the default) carry W-wide ring halves
-            # whose slots are position MOD W — block geometry over absolute
-            # positions would silently commit the wrong rows.  Probe the
-            # session layout and refuse anything non-slot-addressed.
-            probe = model.init_kv(
-                n_layers, 1, session_tokens, kv_dtype, quant_bits=quant_bits
-            )
-            if jax.tree.structure(probe) != jax.tree.structure(self.kv):
-                raise NotImplementedError(
-                    "paged KV needs session caches with the pool's tree "
-                    "structure (per-kind cache layouts stay dense)"
-                )
-            for leaf in jax.tree.leaves(probe):
-                if leaf.shape[1] != 1 or leaf.shape[2] != session_tokens:
-                    raise NotImplementedError(
-                        "paged KV needs slot-addressed max_seq session "
-                        f"caches; got session leaf shape {leaf.shape} "
-                        "(rotating ring buffers stay dense)"
-                    )
-        bt = self.block_tokens
-
-        @jax.named_scope("kv_gather")
-        def gather(pool, ids):
-            """ids [slots, nb] int32 -> dense [L, slots, nb*bt, ...]."""
-
-            def one(p):
-                g = p[:, ids]  # [L, slots, nb, bt, ...]
-                L, s, nb = g.shape[:3]
-                return g.reshape(L, s, nb * bt, *g.shape[4:])
-
-            return jax.tree.map(one, pool)
-
-        @jax.named_scope("kv_scatter")
-        def scatter(pool, dense, slot_idx, block_idx, phys):
-            """Write dense blocks (slot_idx[k], block_idx[k]) -> pool[phys[k]]."""
-
-            def one(p, d):
-                L, s, S = d.shape[:3]
-                blk = d.reshape(L, s, S // bt, bt, *d.shape[3:])[
-                    :, slot_idx, block_idx
-                ]  # [L, K, bt, ...]
-                return p.at[:, phys].set(blk)
-
-            return jax.tree.map(one, pool, dense)
-
-        # instrumented: a page-table geometry leak re-tracing these per
-        # call shows as climbing dnet_jit_compiles_total{fn=kv_*}
-        self._gather = instrument_jit(jax.jit(gather), "kv_gather")
-        self._scatter = instrument_jit(
-            jax.jit(scatter, donate_argnums=(0,)), "kv_scatter"
-        )
-        self._append = instrument_jit(
-            jax.jit(
-                jax.named_scope("kv_append")(self.append_in_program),
-                donate_argnums=(0,),
-            ),
-            "kv_append",
-        )
-
-    # ---- the ragged path's view: one kind -----------------------------
-    #: every layer keeps everything
-    kinds = (KV_KIND_FULL,)
-
-    def attend(self, pool, kvs, q, rows, tables, pos, kind, layer, impl):
-        """Traced: one layer's decode attention.  The model's scan slices
-        the stack, so `kvs` is this layer's [N, bt, KVH, Hd] and `pool`,
-        `kind` and `layer` go unread."""
-        from dnet_tpu.ops.paged_attention import paged_attend
-
-        return paged_attend(
-            q, kvs["k"], kvs["v"], tables[KV_KIND_FULL], pos, rows["k"],
-            rows["v"], impl=impl,
-        )
-
-    def append_in_program(self, pool, rows, phys, off):
-        """Traced: write one new token row per slot straight into its
-        physical block: rows leaves [L, slots, KVH, Hd] -> pool[:,
-        phys[full][s], off[s]].  Inactive lanes pass phys == pool_blocks —
-        PAST the block axis, so mode="drop" discards the write (a negative
-        sentinel would WRAP to block N-1 and clobber a live block before
-        drop semantics ever applied).  The ragged decode path's
-        replacement for the whole dense round-trip: the step's ONLY cache
-        write."""
-        at = phys[KV_KIND_FULL]
-
-        def one(p, r):
-            return p.at[:, at, off].set(r.astype(p.dtype), mode="drop")
-
-        return jax.tree.map(one, pool, rows)
-
-    def commit_staged(self, kv_row: dict, blocks: dict) -> None:
-        """blocks: {kind: (logical block indices, physical blocks)} of one
-        staged [L, 1, S, ...] row."""
-        self.commit_row(kv_row, *blocks[KV_KIND_FULL])
-
-    # ---- ops ----------------------------------------------------------
-    def gather_row(self, blocks: List[int], width_tokens: int) -> dict:
-        """One sequence's blocks as a [L, 1, width_tokens, ...] dense row
-        (padded with clamped block 0 beyond the table — rows the causal
-        mask excludes)."""
-        bt = self.block_tokens
-        assert width_tokens % bt == 0
-        ids = np.zeros((1, width_tokens // bt), dtype=np.int32)
-        ids[0, : len(blocks)] = blocks
-        return self._gather(self.kv, jnp.asarray(ids, dtype=jnp.int32))
-
-    def append_rows(self, rows: dict, phys: dict, off) -> None:
-        """Ragged-decode block append: one new token row per slot, written
-        in place (donated pool buffers).  rows leaves [L, slots, KVH, Hd]
-        (the step program's stacked per-layer k/v outputs); phys[kind] and
-        off [slots] int32 physical block + in-block offset; phys ==
-        pool_blocks (out of range, NOT negative) = skip this lane."""
-        self.kv = self._append(
-            self.kv, rows,
-            {k: np.asarray(v, dtype=np.int32) for k, v in phys.items()},
-            np.asarray(off, dtype=np.int32),
-        )
-
-    def commit_row(
-        self,
-        kv_row: dict,
-        logical_blocks: List[int],
-        phys_blocks: List[int],
-    ) -> None:
-        """Persist blocks of a single-sequence dense row ([L, 1, S, ...]):
-        logical block index i of the row -> pool block phys_blocks[i].
-        Pads to a power-of-two width by repeating the last pair."""
-        if not logical_blocks:
-            return
-        K = _bucket_pow2(len(logical_blocks))
-        pad = K - len(logical_blocks)
-        lb = list(logical_blocks) + [logical_blocks[-1]] * pad
-        pb = list(phys_blocks) + [phys_blocks[-1]] * pad
-        # host index rows: arguments of the jitted scatter, no eager upload
-        self.kv = self._scatter(
-            self.kv, kv_row,
-            np.zeros(K, dtype=np.int32),  # the row's one slot
-            np.asarray(lb, dtype=np.int32),
-            np.asarray(pb, dtype=np.int32),
-        )
+def _commit_blocks(p, rows, block_idx, phys):
+    """Traced: blocks `block_idx` [K] of staged rows [L, S, KVH, Hd] into
+    blocks `phys` [K] of the (donated) pool leaf `p` [L, N, bt, KVH*Hd].
+    The row's blocks are taken FIRST and the heads merged on those (tens
+    of MB at most); on this layout the update is one in-place scatter."""
+    L, S = rows.shape[:2]
+    blk = rows.reshape(L, S // p.shape[2], p.shape[2], -1)[:, block_idx]
+    return p.at[:, phys].set(blk.astype(p.dtype))
 
 
 class StateStore:
@@ -320,36 +157,61 @@ class StateStore:
 
 
 class KindStore:
-    """Pools for a model whose layers are of two KINDS (models that set
-    `paged_kinds`: window and full attention mixed): each kind's layers
-    share a pool `[L_kind, N_kind, block_tokens, KVH*Hd]` and a pool manager
-    (kv/paged.py BlockPool) of their own, so a window layer's table can hold
-    the blocks inside its window alone while a full layer's keeps
-    everything.  Heads are merged into the lane dimension ONCE, here: the
-    ragged kernel (ops/paged_attention.py) reads a block as `[bt, KVH*Hd]`
-    and takes the layer by index, so no slice or relayout of a pool is made
-    per step.
+    """THE block pool store: every model whose layers keep keys and values.
+    Layers are of a KIND (`model.paged_kinds`; None = every layer `full`):
+    each kind's layers share a pool `[L_kind, N_kind, block_tokens, KVH*Hd]`
+    and a pool manager (kv/paged.py BlockPool) of their own, so a window
+    layer's table can hold the blocks inside its window alone while a full
+    layer's keeps everything.  Heads are merged into the lane dimension
+    ONCE, here: the ragged kernel (ops/paged_attention.py) reads a block as
+    `[bt, KVH*Hd]` and takes the layer by index, so no slice or relayout of
+    a pool is made per step, and a write that names blocks or rows moves
+    those alone (kept with the heads apart, `[L, N, bt, 4, 128]`, XLA tiles
+    the pool T(4,128) and copies a WHOLE leaf to T(8,128) and back around
+    every scatter).
 
     `self.kv` is `{kind: {"k": ..., "v": ...}}`; `self.layers[kind]` the
-    local layer indices of the kind, in order."""
+    local layer indices of the kind, in order.  The prefix cache
+    (kv/prefix.py) reads and writes the `full` kind through `gather_row`
+    and `commit_row`."""
 
     in_place = False
 
-    def __init__(self, model, cfgs: dict, kv_dtype: str, window_width: int = 0) -> None:
+    def __init__(
+        self, model, cfgs: dict, kv_dtype: str, window_width: int = 0,
+        session_tokens: int = 0,
+    ) -> None:
         self.cfgs = cfgs
         self.cfg = cfgs[KV_KIND_FULL]
-        self.window = int(model.window)
         #: the most blocks one sequence's window table holds (0: unknown)
         self.window_width = int(window_width)
         self.block_tokens = bt = self.cfg.block_tokens
         self.layers = {}
-        for i, kind in enumerate(model.paged_kinds):
+        by_layer = model.paged_kinds or (KV_KIND_FULL,) * len(model.layers)
+        for i, kind in enumerate(by_layer):
             self.layers[kind] = self.layers.get(kind, ()) + (i,)
         self.kinds = tuple(k for k in KV_KINDS if k in self.layers)
         if set(self.layers) != set(cfgs):
             raise ValueError(f"pool kinds {sorted(cfgs)} != layer kinds {sorted(self.layers)}")
+        self.window = int(model.window) if KV_KIND_WINDOW in self.layers else 0
         c = model.config
-        width = c.num_key_value_heads * c.head_dim
+        heads = (c.num_key_value_heads, c.head_dim)
+        width = heads[0] * heads[1]
+        if session_tokens:
+            # blocks are cut out of a staged SESSION row by absolute
+            # position: the rows the engines gather into and commit from
+            # must be slot-addressed [L, 1, max_seq, KVH, Hd] keys and
+            # values.  A rotating window ring (slots are position MOD W) or
+            # a cache laid out by kind would commit the wrong rows silently
+            n = len(by_layer)
+            probe = jax.eval_shape(lambda: model.init_kv(n, 1, session_tokens, kv_dtype))
+            want = (n, 1, session_tokens) + heads
+            shapes = jax.tree.map(jnp.shape, probe)
+            if shapes != {"k": want, "v": want}:
+                raise NotImplementedError(
+                    f"paged KV needs slot-addressed session caches, k and v of {want}; "
+                    f"got {shapes} (rotating ring buffers and per-kind layouts stay dense)"
+                )
         dt = jnp.dtype(kv_dtype)
         self.kv = {
             kind: {
@@ -370,14 +232,25 @@ class KindStore:
                 sel = jnp.asarray(idx, jnp.int32)
 
                 def one(p, d, kind=kind, sel=sel):
-                    rows = d[sel, 0]  # [Lk, S, KVH, Hd]
-                    Lk, S = rows.shape[:2]
-                    blk = rows.reshape(Lk, S // bt, bt, -1)[:, block_idx[kind]]
-                    return p.at[:, phys[kind]].set(blk.astype(p.dtype))
+                    return _commit_blocks(p, d[sel, 0], block_idx[kind], phys[kind])
 
                 out[kind] = jax.tree.map(one, pool[kind], dense)
             return out
 
+        @jax.named_scope("kv_gather")
+        def gather(pool, ids):
+            """The full kind's blocks ids [nb] -> one dense row
+            [L, 1, nb*bt, KVH, Hd]: the heads split on the gathered row."""
+
+            def one(p):
+                g = p[:, ids]  # [L, nb, bt, W]
+                return g.reshape(g.shape[0], 1, g.shape[1] * bt, *heads)
+
+            return jax.tree.map(one, pool[KV_KIND_FULL])
+
+        # instrumented: a page-table geometry leak re-tracing these per
+        # call shows as climbing dnet_jit_compiles_total{fn=kv_*}
+        self._gather = instrument_jit(jax.jit(gather), "kv_gather")
         self._commit = instrument_jit(
             jax.jit(commit, donate_argnums=(0,)), "kv_scatter"
         )
@@ -387,10 +260,11 @@ class KindStore:
 
     def attend(self, pool, kvs, q, rows, tables, pos, kind, layer, impl):
         """Traced: one layer's decode attention.  The model names the
-        layer's `kind` (its index in KV_KINDS, riding the scan as data) and
-        its index `layer` within the kind; the kernel takes the layer out
-        of the kind's stack itself.  Each kind's custom call has a name of
-        its own (the trace tells them apart)."""
+        layer's index `layer` within its kind and, where there are two, the
+        layer's `kind` (its index in KV_KINDS, riding the scan as data);
+        the kernel takes the layer out of the kind's stack itself (`kvs`
+        goes unread: the pool is never sliced).  Each kind's custom call
+        has a name of its own (the trace tells them apart)."""
         from dnet_tpu.ops.paged_attention import paged_attend
 
         def of(name, scope, **kw):
@@ -403,10 +277,13 @@ class KindStore:
 
             return run
 
+        full = of(KV_KIND_FULL, SCOPE_ATTN_FULL)
+        if KV_KIND_WINDOW not in self.layers:
+            return full()
         return jax.lax.cond(
             kind == KV_KINDS.index(KV_KIND_WINDOW),
             of(KV_KIND_WINDOW, SCOPE_ATTN_WINDOW, window=self.window, base=tables["base"]),
-            of(KV_KIND_FULL, SCOPE_ATTN_FULL),
+            full,
         )
 
     def append_in_program(self, pool, rows, phys, off):
@@ -456,7 +333,36 @@ class KindStore:
             phys[kind] = np.asarray(pb, np.int32)
         self.kv = self._commit(self.kv, kv_row, block_idx, phys)
 
-    append_rows = BlockStore.append_rows
+    def append_rows(self, rows: dict, phys: dict, off) -> None:
+        """Ragged-decode block append: one new token row per slot, written
+        in place (donated pool buffers).  rows leaves [L, slots, KVH, Hd]
+        (the step program's stacked per-layer k/v outputs); phys[kind] and
+        off [slots] int32 physical block + in-block offset; phys ==
+        pool_blocks (out of range, NOT negative) = skip this lane."""
+        self.kv = self._append(
+            self.kv, rows,
+            {k: np.asarray(v, dtype=np.int32) for k, v in phys.items()},
+            np.asarray(off, dtype=np.int32),
+        )
+
+    # ---- the prefix cache's two calls: the full kind alone --------------
+    def gather_row(self, blocks: List[int], width_tokens: int) -> dict:
+        """One sequence's blocks as a [L, 1, width_tokens, KVH, Hd] dense
+        row (padded with clamped block 0 beyond the table: rows the causal
+        mask excludes)."""
+        bt = self.block_tokens
+        assert width_tokens % bt == 0
+        ids = np.zeros(width_tokens // bt, dtype=np.int32)
+        ids[: len(blocks)] = blocks
+        return self._gather(self.kv, ids)
+
+    def commit_row(
+        self, kv_row: dict, logical_blocks: List[int], phys_blocks: List[int]
+    ) -> None:
+        """Persist blocks of a single-sequence dense row ([L, 1, S, ...]):
+        logical block index i of the row -> pool block phys_blocks[i]."""
+        if logical_blocks:
+            self.commit_staged(kv_row, {KV_KIND_FULL: (logical_blocks, phys_blocks)})
 
 
 class HybridStore:
@@ -521,10 +427,7 @@ class HybridStore:
             [L_full, 1, S, KVH, Hd], state leaves [L_state, 1, ...]}."""
 
             def blocks(p, d):
-                rows = d[:, 0]  # [Lf, S, KVH, Hd]
-                Lf, S = rows.shape[:2]
-                blk = rows.reshape(Lf, S // bt, bt, -1)[:, block_idx]
-                return p.at[:, phys].set(blk.astype(p.dtype))
+                return _commit_blocks(p, d[:, 0], block_idx, phys)
 
             def lane(s, r):
                 return jax.lax.dynamic_update_slice_in_dim(s, r.astype(s.dtype), slot, axis=1)

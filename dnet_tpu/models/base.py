@@ -109,10 +109,11 @@ class RingModel(abc.ABC):
     supports_kv_commit: bool = True
     # apply_window accepts an `attend_fn` override replacing the cache
     # write + attention of every layer (ragged paged attention,
-    # ops/paged_attention.py).  The llama-family stack threads it as
-    # attend_fn(q, k, v, kvs); a model whose layers are of two kinds
+    # ops/paged_attention.py).  The caller's pool is never scanned over:
+    # the llama-family stack threads attend_fn(q, k, v, None, layer=) with
+    # the layer's index; a model whose layers are of two kinds
     # (cohere2_moe: window and full) sets `paged_kinds` and also passes
-    # kind= and layer= (the index within the kind).  Models with bespoke
+    # kind=, with layer= the index within the kind.  Models with bespoke
     # attention layouts (gpt_oss paired SWA rings, deepseek MLA) keep the
     # dense-gather decode path.
     supports_paged_attend: bool = False

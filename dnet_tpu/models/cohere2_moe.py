@@ -171,9 +171,7 @@ class Cohere2MoeRingModel(RingModel):
         k = jnp.where(
             is_win, apply_rope_interleaved(k, positions, self.inv_freq, self.rope_scale), k
         )
-        if attend_fn is not None and self.paged_kinds is None:
-            attn, kvs = attend_fn(q, k, v, kvs)  # one kind: kvs is the layer's slice
-        elif attend_fn is not None:
+        if attend_fn is not None:
             attn, kvs = attend_fn(q, k, v, kvs, kind=kind, layer=idx)
         else:
             attn, kvs = self._cached_attend(q, k, v, kvs, pos, is_win, mask, kv_commit)
@@ -299,20 +297,22 @@ class Cohere2MoeRingModel(RingModel):
 
         if attend_fn is not None:
             # the caller owns cache write and attention read: `kv` is its
-            # own (per-kind) affair, and each layer hands it the kind and
-            # the layer's index within that kind; the scan stacks whatever
-            # the hook returns, plus the held-assignment counts
-            by_kind = self.paged_kinds is not None
+            # own (per-kind) affair, never scanned over, and each layer
+            # hands it the kind and the layer's index within that kind (the
+            # pools' kinds: where no layer has a window, all are one); the
+            # scan stacks whatever the hook returns, plus the
+            # held-assignment counts
+            within = (
+                jnp.arange(L, dtype=jnp.int32) if self.paged_kinds is None
+                else self._kind_index
+            )
 
             def body(xc, per):
-                p, kvs, kind, idx = per
-                xc, rows, held = self._layer(
-                    p, xc, kv if by_kind else kvs, pos, kind, idx, None, None, attend_fn
-                )
+                p, kind, idx = per
+                xc, rows, held = self._layer(p, xc, kv, pos, kind, idx, None, None, attend_fn)
                 return xc, dict(rows, moe_held=held)
 
-            xs = (window_params, None if by_kind else kv, kinds, self._kind_index)
-            return lax.scan(body, x, xs)
+            return lax.scan(body, x, (window_params, kinds, within))
 
         def body(xc, per):
             p, kvs, kind = per

@@ -22,7 +22,7 @@ from dnet_tpu.obs.phases import SCOPE_ATTN
 from dnet_tpu.ops.attention import cached_attend
 from dnet_tpu.parallel.tp_collectives import tp_all_reduce
 from dnet_tpu.ops.norms import rms_norm
-from dnet_tpu.ops.quant import dq, out_dim
+from dnet_tpu.ops.quant import dq, lead_dim, out_dim
 from dnet_tpu.ops.rope import apply_rope, rope_frequencies
 
 
@@ -83,9 +83,10 @@ class LlamaRingModel(RingModel):
             if attend_fn is not None:
                 # ragged paged attention (ops/paged_attention.py): the caller
                 # owns both the cache write (block append) and the attention
-                # read; kvs is this layer's pool slice dict, passed through so
-                # the hook can read it and return what the scan should stack
-                attn, kvs = attend_fn(q, k, v, kvs)
+                # read, and its pool; where a cache slice would ride, the scan
+                # carries the layer's index (apply_window), and stacks what
+                # the hook returns
+                attn, kvs = attend_fn(q, k, v, None, layer=kvs)
             else:
                 attn, kvs = cached_attend(
                     q, k, v, kvs, pos, mask, kv_commit=kv_commit, sp_axis=sp_axis,
@@ -142,6 +143,10 @@ class LlamaRingModel(RingModel):
             )
             return xc, kvs
 
+        if attend_fn is not None:
+            # the pool is the hook's own, closed over and never sliced: the
+            # scan carries each layer's index in its place
+            kv = jnp.arange(lead_dim(window_params["wq"]), dtype=jnp.int32)
         stacks = None
         if tp_axis is None and self.moe_path(x.shape[0] * x.shape[1]) == "grouped":
             from dnet_tpu.ops.moe import expert_stacks

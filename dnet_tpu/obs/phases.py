@@ -161,11 +161,11 @@ JIT_FNS = (
     "batched_spec",         # BatchedEngine._spec_step (verify blocks)
     "adopt_lane",           # BatchedEngine._adopt_lane: a prefilled session's
                             # counts, key (hist, dense KV row) into its lane
-    "kv_gather",            # BlockStore page-table gather
-    "kv_scatter",           # BlockStore block write-back
+    "kv_gather",            # pool store: page-table gather (prefix restore)
+    "kv_scatter",           # pool store: a staged row's blocks into the pool
     "paged_attend",         # BatchedEngine ragged decode programs (step +
                             # fused chunks) attending the pool in place
-    "kv_append",            # BlockStore per-step block-append of new K/V rows
+    "kv_append",            # pool store: per-step block-append of new K/V rows
     "wire_encode",          # wire-pipeline hop encode launches (lossless
                             # cast / sparse / qsparse8 — compression/ops.py)
     "tp_window",            # TpEngine shard_map window/step programs over

@@ -177,3 +177,50 @@ def test_a_steps_rows_land_where_indexing_by_layer_block_and_row_puts_them(dropp
                     if s not in dropped:
                         want[j, phys[kind][s], off[s]] = np.asarray(rows[leaf])[layer, s].reshape(-1)
             np.testing.assert_array_equal(np.asarray(got[kind][leaf]), want)
+
+
+def test_a_model_of_two_layer_types_and_no_window_pages_as_one_kind(tmp_path, monkeypatch):
+    """cohere2_moe without `sliding_window`: its layers still differ (three
+    of four rotate), but every one keeps everything, so `paged_kinds` is
+    None and the one pool's `full` kind holds them all, taken by each
+    layer's index in the MODEL (not within its rope kind).  Greedy streams
+    equal dense slots' byte for byte."""
+    from benchmarks.harness import spec
+    from benchmarks.harness.weights import write_checkpoint
+    from dnet_tpu.config import reset_settings_cache
+    from dnet_tpu.core.batch import BatchedEngine
+    from dnet_tpu.core.types import DecodingParams
+    from dnet_tpu.kv import KindStore
+
+    full = spec.load_json(spec.BENCH_DIR / "configs" / "command-a-plus-4l-ep8.json")
+    cfg = {k: v for k, v in full.items()
+           if k not in ("assumed", "deployment", "serve", "check", "rehearse")}
+    cfg.update(full["rehearse"]["config"], sliding_window=None)
+    write_checkpoint(tmp_path, cfg, seed=2**31 + 38, dtype="float32")
+    monkeypatch.setenv("DNET_KV_BLOCK_TOKENS", "8")
+    reset_settings_cache()
+    dec = DecodingParams(temperature=0.0)
+    prompts = {"a": list(range(5, 30)), "b": list(range(40, 51))}
+
+    def streams(eng):
+        try:
+            last = {n: int(eng.prefill_and_sample(n, ids, dec).token[0]) for n, ids in prompts.items()}
+            got = {n: [t] for n, t in last.items()}
+            for _ in range(5):
+                out, errs = eng.decode_batch({n: (got[n][-1], dec) for n in prompts})
+                assert not errs
+                for n, res in out.items():
+                    got[n].append(int(res.token[0]))
+            return got
+        finally:
+            eng.close()
+
+    try:
+        kw = dict(slots=2, max_seq=64, param_dtype="float32")
+        paged = BatchedEngine(tmp_path, **kw)
+        assert paged.model.paged_kinds is None and isinstance(paged.kv_store, KindStore)
+        assert paged.kv_store.kinds == (KV_KIND_FULL,)
+        assert paged.kv_store.kv[KV_KIND_FULL]["k"].shape[0] == cfg["num_hidden_layers"]
+        assert streams(paged) == streams(BatchedEngine(tmp_path, kv_paged=False, **kw))
+    finally:
+        reset_settings_cache()

@@ -1,13 +1,15 @@
 """Paged KV subsystem: allocator invariants, COW/sharing, backpressure,
 and paged-vs-dense engine parity (ISSUE 3 acceptance)."""
 
+from types import SimpleNamespace
+
 import numpy as np
 import pytest
 
 from dnet_tpu.core.types import DecodingParams
 from dnet_tpu.kv import (
     BlockPool,
-    BlockStore,
+    KindStore,
     KVPoolExhausted,
     PagedKVConfig,
     PagedPrefixCache,
@@ -90,7 +92,12 @@ def test_gauges_track_pool_state():
 
 
 class _FlatKVModel:
-    """Minimal init_kv provider with the flat [L, B, S, KVH, Hd] layout."""
+    """Minimal init_kv provider with the flat [L, B, S, KVH, Hd] layout:
+    two layers, all of the full kind."""
+
+    paged_kinds = None
+    layers = range(2)
+    config = SimpleNamespace(num_key_value_heads=2, head_dim=4)
 
     def init_kv(self, n_layers, batch, max_seq, dtype="float32",
                 quant_bits=0, rotating=True):
@@ -100,6 +107,10 @@ class _FlatKVModel:
             KVConfig(n_layers, batch, max_seq, n_kv_heads=2, head_dim=4,
                      dtype=dtype, quant_bits=quant_bits)
         )
+
+
+def _store(model, cfg, **kw):
+    return KindStore(model, {"full": cfg}, "float32", **kw)
 
 
 def _row(model, n_layers, seq, fill):
@@ -114,7 +125,7 @@ def test_store_commit_gather_roundtrip():
     says and come back in order; a second commit rewrites one block only."""
     cfg = PagedKVConfig(block_tokens=4, pool_blocks=8)
     model = _FlatKVModel()
-    store = BlockStore(model, 2, cfg, "float32")
+    store = _store(model, cfg)
     row = _row(model, 2, 16, 7.0)  # [2, 1, 16, 2, 4] all 7s
     store.commit_row(row, [0, 1, 2, 3], [5, 6, 1, 2])
     dense = store.gather_row([5, 6, 1, 2], 16)
@@ -134,12 +145,74 @@ def test_store_commit_gather_roundtrip():
     store.commit_row(dense2, [], [])  # nothing to write: no program runs
 
 
+@pytest.mark.parametrize("impl", ["emulate", "interpret"])
+def test_commit_gather_and_attend_by_layer_match_dense_attention(impl):
+    """The merged layout end to end, float32: staged rows of ragged lengths
+    (a partial last block; a three-block commit padded to four by repeating
+    its last pair) are committed to scattered blocks, gathered back equal,
+    and `paged_attend(layer=)` over the pool AS IT IS KEPT, every layer's
+    stack passed whole, equals dense attention over each row with the new
+    token written at its position."""
+    import jax
+    import jax.numpy as jnp
+
+    from dnet_tpu.ops.attention import attend
+    from dnet_tpu.ops.paged_attention import paged_attend
+
+    L, KVH, Hd, H, bt, S = 3, 2, 16, 4, 8, 32
+
+    class Model(_FlatKVModel):
+        layers = range(L)
+        config = SimpleNamespace(num_key_value_heads=KVH, head_dim=Hd)
+
+    cfg = PagedKVConfig(block_tokens=bt, pool_blocks=16)
+    store = _store(Model(), cfg)
+    assert store.kv["full"]["k"].shape == (L, 16, bt, KVH * Hd)
+    rng = np.random.default_rng(38)
+    lengths = [5, 19, 24]  # live rows before the step: 1, 3 (padded to 4) and 3 blocks
+    phys = [[9], [4, 13, 2], [7, 0, 11]]
+    rows = []
+    for n, blocks in zip(lengths, phys):
+        row = {leaf: jnp.asarray(rng.normal(size=(L, 1, S, KVH, Hd)).astype(np.float32))
+               for leaf in ("k", "v")}
+        rows.append(row)
+        store.commit_row(row, list(range(len(blocks))), blocks)
+        back = store.gather_row(blocks, S)
+        live = len(blocks) * bt
+        for leaf in ("k", "v"):
+            assert back[leaf].shape == (L, 1, S, KVH, Hd)
+            np.testing.assert_array_equal(
+                np.asarray(back[leaf][:, :, :live]), np.asarray(row[leaf][:, :, :live])
+            )
+    # the pad repeated the pair (2 -> 2): no other block was touched
+    np.testing.assert_array_equal(np.asarray(store.kv["full"]["k"][:, 1]), 0.0)
+    tables = np.zeros((3, S // bt), np.int32)
+    for b, blocks in enumerate(phys):
+        tables[b, : len(blocks)] = blocks
+    pos = jnp.asarray(lengths, jnp.int32)
+    q = jnp.asarray(rng.normal(size=(3, 1, H, Hd)).astype(np.float32))
+    k_new, v_new = (jnp.asarray(rng.normal(size=(3, KVH, Hd)).astype(np.float32)) for _ in "kv")
+    pool = store.kv["full"]
+    for layer in range(L):
+        got = paged_attend(
+            q, pool["k"], pool["v"], jnp.asarray(tables), pos, k_new, v_new,
+            impl=impl, layer=jnp.int32(layer),
+        )
+        for b, n in enumerate(lengths):
+            k = rows[b]["k"][layer].at[0, n].set(k_new[b])
+            v = rows[b]["v"][layer].at[0, n].set(v_new[b])
+            want = attend(q[b : b + 1], k, v, mask=(jnp.arange(S) <= n)[None, :])
+            np.testing.assert_allclose(
+                np.asarray(got[b]), np.asarray(want[0]), rtol=1e-5, atol=1e-5
+            )
+
+
 def test_paged_prefix_store_dedups_blocks():
     reset_obs()
     cfg = PagedKVConfig(block_tokens=4, pool_blocks=16)
     model = _FlatKVModel()
     pool = BlockPool(cfg)
-    store = BlockStore(model, 2, cfg, "float32")
+    store = _store(model, cfg)
     cache = PagedPrefixCache(pool, store, capacity=4, min_tokens=4)
     base = list(range(100, 108))  # 8 tokens = 2 full blocks
     cache.store(base, _row(model, 2, 16, 1.0))
@@ -168,7 +241,7 @@ def test_paged_prefix_eviction_releases_blocks():
     cfg = PagedKVConfig(block_tokens=4, pool_blocks=16)
     model = _FlatKVModel()
     pool = BlockPool(cfg)
-    store = BlockStore(model, 2, cfg, "float32")
+    store = _store(model, cfg)
     cache = PagedPrefixCache(pool, store, capacity=2, min_tokens=4)
     for base in (10, 20, 30):  # third store evicts the first (LRU)
         cache.store([base + i for i in range(8)], _row(model, 2, 16, 1.0))
@@ -283,6 +356,58 @@ def test_paged_chunked_decode_matches_dense(tiny_llama_dir, dense_ref, paged_env
         eng.kv_pool.check_conservation()
     finally:
         eng.close()
+
+
+@pytest.mark.parametrize("family", ["llama", "qwen3", "qwen3_moe"])
+def test_one_kind_pool_matches_dense_slots_by_family(family, tmp_path, paged_env):
+    """Every llama-family model of one kind serves from the one pool layout:
+    interleaved greedy streams byte-identical to dense slots, and a second
+    turn that aliases the first's full blocks and diverges INSIDE the third
+    (COW) stays byte-identical too."""
+    from dnet_tpu.core.batch import BatchedEngine
+
+    from tests.fakes import checkpoints
+
+    reset_obs()
+    d = tmp_path
+    getattr(checkpoints, f"make_tiny_{family}")(d)  # qwen3_moe: every layer routed
+    kw = dict(slots=4, max_seq=64, param_dtype="float32")
+    dense = BatchedEngine(d, kv_paged=False, **kw)
+    eng = BatchedEngine(d, kv_paged=True, prefix_cache_size=4, **kw)
+    try:
+        assert isinstance(eng.kv_store, KindStore) and eng.kv is None
+        pool = eng.kv_store.kv["full"]["k"]
+        c = eng.config
+        assert pool.shape[0] == c.num_hidden_layers and pool.ndim == 4
+        assert pool.shape[2:] == (8, c.num_key_value_heads * c.head_dim)
+        eng.paged_prefix.min_tokens = 8
+        assert _interleaved_greedy(eng, PROMPTS, 6) == _interleaved_greedy(dense, PROMPTS, 6)
+        dec = DecodingParams(temperature=0.0)
+        base = list(range(60, 80))  # 20 tokens: 2 full blocks + 4 in a third
+
+        def turns(e):
+            got = []
+            for nonce, ids in (("t1", base), ("t2", base + [7, 2])):
+                res = e.prefill_and_sample(nonce, ids, dec)
+                toks = [int(res.token[0])]
+                for _ in range(5):
+                    out, errs = e.decode_batch({nonce: (toks[-1], dec)})
+                    assert not errs
+                    toks.append(int(out[nonce].token[0]))
+                got.append(toks)
+            for nonce in ("t1", "t2"):
+                e.end_session(nonce)
+            return got
+
+        assert turns(eng) == turns(dense)
+        assert metric("dnet_kv_prefix_shared_blocks_total").value >= 2
+        assert metric("dnet_kv_cow_copies_total").value >= 1
+        eng.paged_prefix.clear()
+        eng.kv_pool.check_conservation()
+        assert eng.kv_pool.used == 0
+    finally:
+        eng.close()
+        dense.close()
 
 
 def test_prefix_sharing_pair_aliases_blocks(tiny_llama_dir, paged_env):
@@ -405,11 +530,7 @@ def test_rotating_swa_model_refused_and_falls_back(tmp_path, paged_env):
     cfg = ModelConfig.from_hf(cfg_d)
     model = get_ring_model_cls("gpt_oss")(cfg, range(cfg.num_hidden_layers))
     with pytest.raises(NotImplementedError):
-        BlockStore(
-            model, cfg.num_hidden_layers,
-            PagedKVConfig(block_tokens=8, pool_blocks=8), "float32",
-            session_tokens=64,
-        )
+        _store(model, PagedKVConfig(block_tokens=8, pool_blocks=8), session_tokens=64)
     eng = BatchedEngine(
         d, slots=2, max_seq=64, param_dtype="float32", kv_paged=True
     )

@@ -1401,7 +1401,7 @@ def test_seeded_dl022_python_scalar_jit_argument():
     rel = "dnet_tpu/kv/store.py"
     assert _flow_findings({rel: (REPO / rel).read_text()}) == []
     texts, line = _inject(
-        rel, "return self._gather(self.kv, jnp.asarray(ids",
+        rel, "return self._gather(self.kv, ids)",
         "self._gather(self.kv, ids.shape[0])",
     )
     fs = _flow_findings(texts)
