@@ -23,8 +23,9 @@ Pass catalog (the original scripts/check_metrics_names.py passes 1-8):
 - DL016 membership    — stale-epoch kinds / recovery outcomes <-> declared
   enums, both directions
 - DL017 attribution   — host spans (series AND call sites) / decode
-  dispatch widths / token sources / jit fns / device-mem kinds <->
-  declared enums, both directions
+  dispatch widths / token sources / jit fns / device-mem kinds /
+  turn-around device states / drivers-turn outcomes <-> declared enums,
+  both directions
 - DL018 sanitizer     — dsan check codes / zombie-thread kinds <->
   declared enums, both directions (pass 9)
 - DL019 scheduler     — sched queue states / batch kinds / preemption
@@ -544,6 +545,19 @@ def check_attribution_labels(errors: list) -> int:
     n += _cross_check_labels(
         errors, text, "dnet_moe_expert_rows_total", "path", MOE_PATHS,
         "obs.phases.MOE_PATHS",
+    )
+    from dnet_tpu.obs.phases import DRIVERS_TURN_OUTCOMES, TURN_DEVICE
+
+    # the turn-around between two ticks: a histogram by what the device
+    # had to do (every labeled child exposes a _count, so that series
+    # stands for the family) and a counter by how the drivers' turn ended
+    n += _cross_check_labels(
+        errors, text, "dnet_sched_turnaround_ms_count", "device",
+        TURN_DEVICE, "obs.phases.TURN_DEVICE",
+    )
+    n += _cross_check_labels(
+        errors, text, "dnet_sched_drivers_turn_total", "outcome",
+        DRIVERS_TURN_OUTCOMES, "obs.phases.DRIVERS_TURN_OUTCOMES",
     )
     from dnet_tpu.core.batch import BatchedEngine
 

@@ -42,7 +42,6 @@ log = get_logger()
 _DECODE_STEP_MS = metric("dnet_decode_step_ms")
 _PREFILL_MS = metric("dnet_prefill_ms")
 _LAYER_MS = metric("dnet_layer_compute_ms")
-_SESS_EVICTED = metric("dnet_kv_sessions_evicted_total")
 _MOE_EXPERT_ROWS = metric("dnet_moe_expert_rows_total")
 
 
@@ -701,8 +700,6 @@ class LocalEngine:
         dead = [n for n, s in self.sessions.items() if now - s.last_used > self.kv_ttl_s]
         for n in dead:
             del self.sessions[n]
-        if dead:
-            _SESS_EVICTED.inc(len(dead))
         return len(dead)
 
     def reset(self) -> None:

@@ -12,7 +12,8 @@ name fails loudly at import instead of silently creating a parallel series.
 `jax.profiler.TraceAnnotation` (next to free while no profiler session
 runs; in the same `.xplane.pb` as the device ops, on the same clock, while
 one does) plus one always-on `dnet_span_ms{span=}` observation.  Names are
-declared in obs/phases.py HOST_SPANS.  It fences nothing.
+declared in obs/phases.py HOST_SPANS.  It fences nothing.  `open()` /
+`close()` are `with` for a span whose ends lie in two iterations of a loop.
 
 `obs_enabled()` is the ONE truth for profile gating: the `[PROFILE]` log
 filter (utils/logger.py) and any sampling decisions both consult it, so the
@@ -68,6 +69,11 @@ _CACHE_KINDS = ("prefix", "snapshot")
 _WAIT_MS_BUCKETS = (1.0, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0, 500.0, 1000.0,
                     2500.0, 5000.0, 10000.0, 30000.0)
 
+# the turn-around between two ticks is milliseconds and its bound two: the
+# ladder starts under one and is fine around DRIVER_TURN_S
+_TURN_MS_BUCKETS = (0.1, 0.25, 0.5, 1.0, 2.0, 3.0, 5.0, 10.0, 25.0, 50.0,
+                    100.0, 250.0, 1000.0, 5000.0)
+
 
 def _register_core(reg: MetricsRegistry) -> None:
     """The canonical family set, pre-registered (and labeled children
@@ -117,10 +123,6 @@ def _register_core(reg: MetricsRegistry) -> None:
         "Activation/token frame payload bytes admitted at ingress",
     )
     reg.counter(
-        "dnet_transport_tx_frames_total",
-        "Frames written to outbound streams",
-    )
-    reg.counter(
         "dnet_transport_backpressure_total",
         "Backpressure ACKs that paused an outbound stream",
     )
@@ -133,10 +135,6 @@ def _register_core(reg: MetricsRegistry) -> None:
         fam = reg.counter(name, help_text, labelnames=("cache",))
         for kind in _CACHE_KINDS:
             fam.labels(cache=kind)  # pre-touch: expose at 0 from the start
-    reg.counter(
-        "dnet_kv_sessions_evicted_total",
-        "Per-nonce KV sessions dropped by the TTL sweep",
-    )
     # paged KV pool (dnet_tpu/kv/paged.py): used + free == pool size at all
     # times (shared blocks count once in used; BlockPool.check_conservation)
     from dnet_tpu.obs.phases import KV_KINDS, MOE_HELD, MOE_PATHS, RETENTION_PHASES
@@ -623,6 +621,47 @@ def _register_core(reg: MetricsRegistry) -> None:
     )
     for overlap in MIXED_TICK_OVERLAP:
         mixed.labels(overlapped=overlap)  # pre-touch: the lint checks these
+    # the turn-around between two ticks (sched/step.py, sched/engine.py):
+    # one number on the compute thread, its segments as host spans
+    # (obs/phases.py: dnet.turn.*, dnet.sched.*), and what the turn left out
+    from dnet_tpu.obs.phases import DRIVERS_TURN_OUTCOMES, TURN_DEVICE
+
+    turnaround = reg.histogram(
+        "dnet_sched_turnaround_ms",
+        "End of tick n (execute_tick about to return) to tick n+1's first "
+        "device program enqueued, both on the compute thread's clock, by "
+        "what the device had to do meanwhile (obs/phases.py TURN_DEVICE: "
+        "drained = tick n read everything it enqueued); runs through a "
+        "tick that enqueues nothing (answered from a fused dispatch's "
+        "buffer); not observed across a park with nothing to do (ms)",
+        labelnames=("device",),
+        buckets=_TURN_MS_BUCKETS,
+    )
+    for device in TURN_DEVICE:
+        turnaround.labels(device=device)  # pre-touch: the lint checks these
+    reg.counter(
+        "dnet_sched_lanes_left_out_total",
+        "Requests that held a decoding lane but had not asked for their "
+        "next token when a plan with a decode step was made: the step ran "
+        "without them (sched/engine.py _tick_loop)",
+    )
+    turns = reg.counter(
+        "dnet_sched_drivers_turn_total",
+        "Waits for the drivers the last tick handed a token to, by how "
+        "they ended (obs/phases.py DRIVERS_TURN_OUTCOMES; "
+        "sched/engine.py _drivers_turn)",
+        labelnames=("outcome",),
+    )
+    for outcome in DRIVERS_TURN_OUTCOMES:
+        turns.labels(outcome=outcome)  # pre-touch: the lint checks these
+    reg.histogram(
+        "dnet_sched_answer_wait_ms",
+        "A token's future resolved to the same request's next send_tokens, "
+        "both on the event loop: the driver's whole way back (recorder, "
+        "SLO tracker, detokenizer, chunk, SSE flush) as the scheduler "
+        "feels it, once per token asked for (ms)",
+        buckets=_TURN_MS_BUCKETS,
+    )
     depth = reg.gauge(
         "dnet_sched_queue_depth",
         "Requests resident in the scheduler queue, by live state "
@@ -790,9 +829,24 @@ class span:
     Opens a profiler TraceAnnotation (args become the event's stats) and,
     on exit, observes the host-clock duration into dnet_span_ms{span=name}.
     Always on, never fenced, never gated on obs_enabled().  Open and close
-    it on ONE thread and never across an `await` (annotations nest per
-    thread); code that awaits times itself and calls observe_span().
-    `name` must be declared in obs/phases.py HOST_SPANS."""
+    it on ONE thread; code that awaits times itself and calls
+    observe_span(), and so does an interval whose two ends lie on two
+    threads.  `name` must be declared in obs/phases.py HOST_SPANS.
+
+    Two spans ARE held across awaits, by design: `dnet.sched.turn` and
+    `dnet.sched.drivers_turn` (sched/engine.py _tick_loop), so that the
+    host plane of a profile says what the loop did while the device was
+    drained between two ticks (benchmarks/harness/xplane.py names an idle
+    gap by the span over it).  That is sound because the profiler keeps no
+    stack: a TraceAnnotation is a TraceMe, which notes its start when it
+    is entered and records ONE finished event (name, start, duration) when
+    it is left, so whatever other coroutines open and close on the loop
+    thread meanwhile become events of their own, inside it or across its
+    edge, and move neither its start nor its duration.  What it costs is
+    the reading: such a span's time is wall time of the loop thread, other
+    coroutines' turns included, and a viewer draws an event that crosses
+    its edge as overlapping.  Hence only these two, which exist to be
+    wall time; tests/subsystems/test_turnaround.py holds a profile to it."""
 
     __slots__ = ("_child", "_ann", "_t0", "ms")
 
@@ -810,6 +864,12 @@ class span:
         self.ms = (time.perf_counter() - self._t0) * 1000.0
         self._ann.__exit__(*exc)
         self._child.observe(self.ms)
+
+    def open(self) -> "span":
+        return self.__enter__()
+
+    def close(self) -> None:
+        self.__exit__(None, None, None)
 
 
 def observe_span(name: str, dur_ms: float) -> None:

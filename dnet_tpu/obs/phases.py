@@ -34,10 +34,25 @@ from __future__ import annotations
 #     dnet.prefill.readback   the tick's first tokens read, whole fields at
 #                             a time: host blocked until the device has
 #                             finished the tick's chunks and adoptions
-#   dnet.sched.plan           event loop, policy.plan
-#   dnet.sched.apply          event loop, SchedulerAdapter._apply
+#   dnet.sched.turn           event loop, sched/engine.py _tick_loop: the
+#                             loop's whole share of the turn-around between
+#                             two ticks, from its resume after tick n to the
+#                             submit of tick n+1 (held across awaits:
+#                             obs/__init__.py span says why that is sound)
+#     dnet.sched.apply          SchedulerAdapter._apply
+#     dnet.sched.drivers_turn   _apply's end to _drivers_turn's end: the park
+#                               until the first driver asks again, and the
+#                               bounded wait for the rest (held across awaits;
+#                               only where a driver was owed an answer)
+#     dnet.sched.plan           policy.plan
+#   dnet.turn.to_loop         dnet.tick n's end (compute thread) to the
+#                             loop's resume: crosses threads, histogram only
+#   dnet.turn.to_thread       the submit (loop) to dnet.tick n+1's start
+#                             (compute thread): histogram only
 #   dnet.api.sse_flush        api/http.py write_chunk (awaits: histogram
 #                             only, no annotation)
+# to_loop + sched.turn + to_thread + decode.prepare + decode.launch is the
+# turn-around dnet_sched_turnaround_ms observes on the compute thread.
 SPAN_TICK = "dnet.tick"
 SPAN_TICK_DECODE = "dnet.tick.decode"
 SPAN_DECODE_PREPARE = "dnet.decode.prepare"
@@ -50,6 +65,10 @@ SPAN_PREFILL_ADOPT = "dnet.prefill.adopt"
 SPAN_PREFILL_READBACK = "dnet.prefill.readback"
 SPAN_SCHED_PLAN = "dnet.sched.plan"
 SPAN_SCHED_APPLY = "dnet.sched.apply"
+SPAN_SCHED_TURN = "dnet.sched.turn"
+SPAN_SCHED_DRIVERS_TURN = "dnet.sched.drivers_turn"
+SPAN_TURN_TO_LOOP = "dnet.turn.to_loop"
+SPAN_TURN_TO_THREAD = "dnet.turn.to_thread"
 SPAN_SSE_FLUSH = "dnet.api.sse_flush"
 # the children of dnet.tick.decode, in dispatch order (loadgen/report.py's
 # decode table and the reconciliation tests sum these against the parent)
@@ -70,6 +89,31 @@ HOST_SPANS = (
     SPAN_SCHED_PLAN,
     SPAN_SCHED_APPLY,
     SPAN_SSE_FLUSH,
+    SPAN_SCHED_TURN,
+    SPAN_SCHED_DRIVERS_TURN,
+    SPAN_TURN_TO_LOOP,
+    SPAN_TURN_TO_THREAD,
+)
+
+# dnet_sched_turnaround_ms{device=}: what the device was doing while the
+# host turned from tick n to tick n+1 (sched/step.py).  `drained`: tick n
+# ended in a blocking read of everything it enqueued (a decode-only tick;
+# a tick whose last program was an adoption, read by dnet.prefill.readback),
+# so the device has nothing until tick n+1's first launch.  `busy`: chunks
+# tick n enqueued and did not read may still run.
+TURN_DEVICE_DRAINED = "drained"
+TURN_DEVICE_BUSY = "busy"
+TURN_DEVICE = (TURN_DEVICE_DRAINED, TURN_DEVICE_BUSY)
+
+# dnet_sched_drivers_turn_total{outcome=}: how the wait for the drivers
+# the last tick handed a token to ended (sched/engine.py _drivers_turn):
+# every one asked again inside DRIVER_TURN_S, the bound cut the wait (the
+# lanes still out join the tick after), or nobody was owed an answer
+DRIVERS_TURN_ANSWERED = "answered"
+DRIVERS_TURN_TIMED_OUT = "timed_out"
+DRIVERS_TURN_NONE = "none"
+DRIVERS_TURN_OUTCOMES = (
+    DRIVERS_TURN_ANSWERED, DRIVERS_TURN_TIMED_OUT, DRIVERS_TURN_NONE,
 )
 
 # jax.named_scope names inside the traced programs, beside each jitted

@@ -34,9 +34,18 @@ from dnet_tpu.api.strategies import (
     _TokenFutures,
 )
 from dnet_tpu.core.types import DecodingParams, TokenResult
-from dnet_tpu.obs import get_recorder, metric, obs_enabled, span
+from dnet_tpu.obs import get_recorder, metric, obs_enabled, observe_span, span
 from dnet_tpu.obs.events import log_event
-from dnet_tpu.obs.phases import SPAN_SCHED_APPLY, SPAN_SCHED_PLAN
+from dnet_tpu.obs.phases import (
+    DRIVERS_TURN_ANSWERED,
+    DRIVERS_TURN_NONE,
+    DRIVERS_TURN_TIMED_OUT,
+    SPAN_SCHED_APPLY,
+    SPAN_SCHED_DRIVERS_TURN,
+    SPAN_SCHED_PLAN,
+    SPAN_SCHED_TURN,
+    SPAN_TURN_TO_LOOP,
+)
 from dnet_tpu.sched.flight import get_tick_recorder
 from dnet_tpu.sched.kinds import QUEUE_STATES, STATE_DECODING
 from dnet_tpu.sched.policy import SchedulerPolicy, TickPlan
@@ -53,6 +62,9 @@ _QUEUE_WAIT_MS = metric("dnet_sched_queue_wait_ms")
 _PREFILL_WALL_MS = metric("dnet_sched_prefill_wall_ms")
 _PREFILL_TICKS = metric("dnet_sched_prefill_ticks")
 _DELIVER_WAIT_MS = metric("dnet_sched_deliver_wait_ms")
+_LANES_LEFT_OUT = metric("dnet_sched_lanes_left_out_total")
+_DRIVERS_TURN = metric("dnet_sched_drivers_turn_total")
+_ANSWER_WAIT_MS = metric("dnet_sched_answer_wait_ms")
 
 
 #: how long a plan waits for the drivers the last tick handed a token to
@@ -106,6 +118,8 @@ class SchedulerAdapter(ApiAdapterBase):
         self._answering: set = dsan.guard_set(
             set(), dsan.loop_domain(), "SchedulerAdapter._answering"
         )
+        # dnet.sched.drivers_turn, open while `_answering` waits its turn
+        self._owed: Optional[span] = None
 
     # ---- lifecycle ----------------------------------------------------
     async def start(self) -> None:
@@ -229,6 +243,12 @@ class SchedulerAdapter(ApiAdapterBase):
             req.pending_step = step
             req.pending_budget = budget
             self._answering.discard(nonce)
+            if req.t_token is not None:
+                # the driver's whole way back, as the scheduler feels it
+                _ANSWER_WAIT_MS.observe(
+                    (time.perf_counter() - req.t_token) * 1000.0
+                )
+                req.t_token = None
         self._wake()
 
     async def await_token(
@@ -252,7 +272,14 @@ class SchedulerAdapter(ApiAdapterBase):
         so `has_work` says go at once) would leave those lanes out, and a
         lane left out of every other tick decodes at half the rate while
         the step costs the same.  Bounded: a driver that is held up misses
-        this tick, no more."""
+        this tick, no more.
+
+        `_owed` is dnet.sched.drivers_turn, opened at `_apply`'s end where a
+        driver was owed an answer: it covers the park until the first one
+        asks again as well as the wait here, and closes here.  The outcome
+        (dnet_sched_drivers_turn_total) is told from the same moment: all
+        of them may have answered before this coroutine was even resumed."""
+        owed, self._owed = self._owed, None
         deadline = time.perf_counter() + DRIVER_TURN_S
         while self._answering and (left := deadline - time.perf_counter()) > 0:
             self._kick.clear()  # every answer sets it (send_tokens, reset_cache)
@@ -260,10 +287,26 @@ class SchedulerAdapter(ApiAdapterBase):
                 await asyncio.wait_for(self._kick.wait(), left)
             except asyncio.TimeoutError:
                 break
+        if owed is None:
+            outcome = DRIVERS_TURN_NONE
+        else:
+            owed.close()
+            outcome = (
+                DRIVERS_TURN_TIMED_OUT if self._answering else DRIVERS_TURN_ANSWERED
+            )
+        _DRIVERS_TURN.labels(outcome=outcome).inc()
         self._answering.clear()
 
     async def _tick_loop(self) -> None:
         loop = asyncio.get_running_loop()
+        # The turn-around between two ticks, as the loop sees it.  `last`
+        # is the tick the next one follows (None once the loop has parked
+        # with nothing to do: an idle server's seconds are no turn-around);
+        # `turn` is dnet.sched.turn, open from the resume after `last` to
+        # the submit of the next tick (or to the park).  Held across awaits,
+        # like `_owed` (obs.span says why that is sound).
+        last: Optional[TickResult] = None
+        turn: Optional[span] = None
         while True:
             await self._kick.wait()
             self._kick.clear()
@@ -277,7 +320,18 @@ class SchedulerAdapter(ApiAdapterBase):
                 with span(SPAN_SCHED_PLAN):
                     plan = self.policy.plan(self.queue, self.engine)
                 if plan.empty():
+                    if turn is not None and not len(self.queue):
+                        # no lane and no prompt: the loop parks, and the
+                        # tick that ends the park follows nothing
+                        turn.close()
+                        turn = last = None
                     continue
+                if plan.decode:
+                    # lanes whose drivers have not asked yet: the step
+                    # runs without them and costs the same
+                    _LANES_LEFT_OUT.inc(sum(
+                        r.pending_step is None for r in self.queue.decoding()
+                    ))
                 t0 = time.perf_counter()
                 self._stamp_chunks(plan, t0)
                 on_decode = None
@@ -297,10 +351,17 @@ class SchedulerAdapter(ApiAdapterBase):
                         self._dispatch_decode, plan, nonce, sample,
                         time.perf_counter(),
                     )
+                if turn is not None:
+                    turn.close()
+                    turn = None
                 result = await loop.run_in_executor(
-                    self._executor, execute_tick, self.engine, plan, on_decode
+                    self._executor, execute_tick, self.engine, plan,
+                    on_decode, last, time.perf_counter(),
                 )
-                tick_ms = (time.perf_counter() - t0) * 1000.0
+                t1 = time.perf_counter()
+                observe_span(SPAN_TURN_TO_LOOP, (t1 - result.t_done) * 1000.0)
+                last, turn = result, span(SPAN_SCHED_TURN).open()
+                tick_ms = (t1 - t0) * 1000.0
                 _TICK_MS.observe(tick_ms)
                 _BATCH_TOKENS.labels(kind="prefill").observe(
                     float(result.prefill_tokens)
@@ -310,6 +371,8 @@ class SchedulerAdapter(ApiAdapterBase):
                 )
                 with span(SPAN_SCHED_APPLY):
                     self._apply(plan, result)
+                if self._answering:
+                    self._owed = span(SPAN_SCHED_DRIVERS_TURN).open()
                 if obs_enabled():
                     self._record_tick(tick_ms, result)
                 if self.policy.has_work(self.queue, self.engine):
@@ -325,6 +388,7 @@ class SchedulerAdapter(ApiAdapterBase):
                     # queue, so it would fail every tick: error the pending
                     # futures instead of wedging them to their timeouts
                     self._futures.fail_all(str(exc))
+                last = None  # a failed tick is no start of a turn-around
                 continue
 
     def _record_tick(self, tick_ms: float, result: TickResult) -> None:
@@ -425,6 +489,7 @@ class SchedulerAdapter(ApiAdapterBase):
             req.pending_step = None
             req.pending_budget = None
             self._answering.add(nonce)
+            req.t_token = time.perf_counter()
 
     def _apply(self, plan: TickPlan, result: TickResult) -> None:
         """The tick's results into the request state machines and the

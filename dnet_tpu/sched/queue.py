@@ -78,6 +78,10 @@ class SchedRequest:
     t_first_chunk: Optional[float] = None
     t_first_token: Optional[float] = None
     prefill_chunks: int = 0
+    #: when the token the driver now holds was resolved (perf_counter,
+    #: loop-side), until its next send_tokens closes the interval into
+    #: dnet_sched_answer_wait_ms
+    t_token: Optional[float] = None
     extra: dict = field(default_factory=dict)
 
     def priority(self) -> Tuple[float, int]:

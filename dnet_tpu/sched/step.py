@@ -67,7 +67,7 @@ from typing import Dict, List
 import jax
 
 from dnet_tpu.kv import KVPoolExhausted
-from dnet_tpu.obs import metric, span
+from dnet_tpu.obs import metric, observe_span, span
 from dnet_tpu.obs.phases import (
     SPAN_PREFILL_ADOPT,
     SPAN_PREFILL_LAUNCH,
@@ -75,6 +75,9 @@ from dnet_tpu.obs.phases import (
     SPAN_TICK,
     SPAN_TICK_DECODE,
     SPAN_TICK_PREFILL,
+    SPAN_TURN_TO_THREAD,
+    TURN_DEVICE_BUSY,
+    TURN_DEVICE_DRAINED,
 )
 from dnet_tpu.sched.policy import PrefillChunk, TickPlan
 from dnet_tpu.utils.logger import get_logger
@@ -83,6 +86,7 @@ log = get_logger()
 
 _PREEMPTIONS = metric("dnet_sched_preemptions_total")
 _MIXED_TICKS = metric("dnet_sched_mixed_ticks_total")
+_TURNAROUND_MS = metric("dnet_sched_turnaround_ms")
 
 #: consecutive starved requeues before a prefill surfaces the typed
 #: backpressure error instead of waiting for blocks that may never free
@@ -120,6 +124,29 @@ class TickResult:
     #: perf_counter when the decode read ended on the compute thread: the
     #: loop measures a decode token's wait for its future from here
     t_decode_done: float = 0.0
+    #: perf_counter when this tick's FIRST device program was enqueued (0.0:
+    #: it enqueued none): the turn-around since the tick before ends here
+    t_launched: float = 0.0
+    #: perf_counter when dnet.tick ended (execute_tick about to return)
+    t_done: float = 0.0
+    #: perf_counter since when the device has had nothing new from the host:
+    #: the turn-around to the next enqueue starts here.  `t_done`, or, where
+    #: this tick enqueued nothing (every lane answered from a fused
+    #: dispatch's buffer), the `t_idle` of the tick before: the turn-around
+    #: runs through such a tick, as the device's wait does
+    t_idle: float = 0.0
+    #: the tick's last device program was read before it returned (the step
+    #: of a tick without chunks; an adoption, by dnet.prefill.readback), so
+    #: the device has nothing to do until the next launch.  False where a
+    #: chunk it enqueued may still run.  A tick that enqueued nothing hands
+    #: on what the tick before left.
+    drained: bool = True
+
+
+def _launched(res: TickResult) -> None:
+    """A device program of this tick has just been enqueued."""
+    if not res.t_launched:
+        res.t_launched = time.perf_counter()
 
 
 def _decode_need(engine, nonces) -> int:
@@ -201,6 +228,8 @@ def _run_prefill_chunk(
         except KVPoolExhausted as exc:
             _handle_prefill_starvation(engine, plan, chunk, res, cur, exc)
             return
+        _launched(res)
+        res.drained = False  # until something enqueued behind it is read
         res.prefill_tokens += len(piece)
     res.progress[nonce] = cur + len(piece)
     if not chunk.last:
@@ -237,6 +266,7 @@ def _run_prefill_chunk(
             return
         break
     res.adopted[nonce] = sample
+    res.drained = True  # the tick reads it, after everything before it
 
 
 def _handle_prefill_starvation(
@@ -277,10 +307,21 @@ def _handle_prefill_starvation(
     res.errors[chunk.nonce] = str(exc)
 
 
-def execute_tick(engine, plan: TickPlan, on_decode=None) -> TickResult:
+def execute_tick(
+    engine, plan: TickPlan, on_decode=None, follows=None, t_submit=None
+) -> TickResult:
     """One tick on the compute thread: launch the decode step, launch every
     chunk (and enqueue the adoption of a prompt it completes), read the
     step, read the first tokens (the module docstring has the why).
+
+    ``follows`` is the result of the tick this one follows with no park in
+    between (the loop says so, sched/engine.py), ``t_submit`` the loop's
+    clock when it handed this tick to the executor.  From them the
+    turn-around between the two ticks: ``dnet.turn.to_thread`` (the submit
+    to this tick's start) and ``dnet_sched_turnaround_ms`` (the end of
+    ``follows`` to this tick's FIRST device program enqueued, both read on
+    this thread).  A tick that enqueues nothing observes none and hands the
+    start on: the next tick's turn-around holds it whole.
 
     ``on_decode`` hands each decode result off the moment the step is read
     — while this tick's chunks are still running on the device — so decode
@@ -289,9 +330,21 @@ def execute_tick(engine, plan: TickPlan, on_decode=None) -> TickResult:
     this way are also recorded in ``dispatched`` so the loop-side apply
     doesn't resolve them twice."""
     res = TickResult()
+    if t_submit is not None:
+        observe_span(
+            SPAN_TURN_TO_THREAD, (time.perf_counter() - t_submit) * 1000.0
+        )
     with span(SPAN_TICK, decode_lanes=len(plan.decode),
               prefill_chunks=len(plan.prefills)):
         _execute(engine, plan, on_decode, res)
+    res.t_done = res.t_idle = time.perf_counter()
+    if follows is not None:
+        if res.t_launched:
+            _TURNAROUND_MS.labels(
+                device=TURN_DEVICE_DRAINED if follows.drained else TURN_DEVICE_BUSY
+            ).observe((res.t_launched - follows.t_idle) * 1000.0)
+        else:
+            res.t_idle, res.drained = follows.t_idle, follows.drained
     return res
 
 
@@ -307,6 +360,8 @@ def _execute(engine, plan: TickPlan, on_decode, res: TickResult) -> None:
         res.chunk_r, res.dispatched_lanes = getattr(
             engine, "last_dispatch", (0, 0)
         )
+        if res.chunk_r:
+            _launched(res)  # dnet.decode.launch has just ended
     for chunk in plan.prefills:
         if chunk.nonce in res.preempted:
             continue

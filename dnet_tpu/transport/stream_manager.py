@@ -24,7 +24,6 @@ from dnet_tpu.utils.logger import get_logger
 log = get_logger()
 
 _TX_BYTES = metric("dnet_transport_tx_bytes_total")
-_TX_FRAMES = metric("dnet_transport_tx_frames_total")
 _BACKPRESSURE = metric("dnet_transport_backpressure_total")
 _REOPENS = metric("dnet_stream_reopens_total")
 _WIRE_BYTES = metric("dnet_wire_bytes_total")
@@ -123,7 +122,6 @@ class StreamManager:
         n_bytes = len(getattr(frame, "payload", b"") or b"")
         _TX_BYTES.inc(n_bytes)
         _WIRE_BYTES.labels(dir="tx").inc(n_bytes)
-        _TX_FRAMES.inc()
         # seq rides along so the Perfetto export (obs/trace.py) can pair
         # this send with the receiving node's transport_recv flow arrow
         get_recorder().span(
