@@ -81,6 +81,8 @@ _DECODE_SLOT_STEPS = metric("dnet_decode_slot_steps_total")
 _DECODE_LANE_STEPS = metric("dnet_decode_lane_steps_total")
 _DECODE_TOKENS = metric("dnet_decode_tokens_total")
 _DECODE_BUFFER_DROPPED = metric("dnet_decode_buffer_dropped_total")
+_DECODE_CHAINED = metric("dnet_decode_chained_lanes_total")
+_DECODE_SURPLUS = metric("dnet_decode_surplus_steps_total")
 _MOE_ASSIGNMENTS = metric("dnet_moe_assignments_total")
 _STATE_SLOTS_USED = metric("dnet_state_slots_used")
 
@@ -101,6 +103,38 @@ class DecodeFlight:
     #: the launch half itself read the device (a verify block's acceptance
     #: counts): work enqueued after it did not overlap this dispatch
     blocked: bool = False
+    #: lanes of `order` whose input token this dispatch took from the
+    #: flight before it, on the device (`decode_launch(chain=)`)
+    chained: frozenset = frozenset()
+
+    def answered(self) -> Tuple[Dict[str, SampleResult], Dict[str, str]]:
+        """What the launch half settled on the host (lanes answered from
+        their buffer, lanes refused), handed out ONCE: a caller that keeps
+        this flight in the air past its tick takes them now, and
+        `decode_read` then returns the dispatch's rows alone."""
+        out, errors = self.out, self.errors
+        self.out, self.errors = {}, {}
+        return out, errors
+
+
+#: a lane's host token row where the step takes the lane's input from the
+#: flight before it instead (`_chain_tokens`): no token id is negative
+CHAINED = -1
+
+
+def _chain_tokens(token, prev_token):
+    """The step's input tokens [slots, 1], inside its program: a lane whose
+    host row says CHAINED takes what the flight before sampled for it,
+    which is still on the device; every other lane keeps its host token."""
+    return jnp.where(token < 0, prev_token, token)
+
+
+def takes_another(budgets, nonce) -> bool:
+    """Will this lane's driver take a token AFTER the one it has asked
+    for?  (`budgets`: nonce -> tokens it still accepts, that one included;
+    requests end by `max_tokens`, so the driver knows.)  Only then may the
+    lane step again before that token has been read."""
+    return ((budgets or {}).get(nonce) or 1) >= 2
 
 
 KV_PAGED = "paged"  # page tables over the block pool, attended in place
@@ -283,6 +317,9 @@ class BatchedEngine:
         )
         V = self.config.vocab_size
         self.counts = jnp.zeros((slots, V), dtype=jnp.int32)
+        # what a step with no flight before it takes in the place of that
+        # flight's tokens (no lane reads it): the same program either way
+        self._no_token = jnp.zeros((slots, 1), dtype=jnp.int32)
         self.keys = jax.random.split(
             jax.random.key(int.from_bytes(__import__("os").urandom(4), "little")),
             slots,
@@ -456,8 +493,15 @@ class BatchedEngine:
             in_axes=(None, None, 0, kv_axes, 0, 0, sp_axes, 0, 0),
             out_axes=(0, kv_axes, 0, 0),
         )
+
+        def step(wp, ep, token, kv, pos, active, sp, keys, counts, prev_token):
+            return self._vmapped(
+                wp, ep, _chain_tokens(token, prev_token), kv, pos, active, sp,
+                keys, counts,
+            )
+
         self._step = instrument_jit(
-            jax.jit(self._vmapped, donate_argnums=(3, 8)), "batched_step"
+            jax.jit(step, donate_argnums=(3, 8)), "batched_step"
         )
         # fused R-step chunks (budget-driven): sampled tokens re-enter their
         # lanes on device, one dispatch + one packed read per R tokens
@@ -568,13 +612,17 @@ class BatchedEngine:
 
         @jax.named_scope("paged_attend")
         def ragged_step(wp, ep, token, pool, tables, pos, active, sp, keys,
-                        counts):
+                        counts, prev_token=None):
             """One batched decode step against the pool (READ-ONLY here):
             returns the sampled results plus the stacked per-layer new K/V
             rows for the kv_append program.  tables: each kind's
             [slots, nb] int32 (bucketed), and with window layers their
             tables' `base` (_table_ids); pos [slots] int32 live pool rows
-            per slot."""
+            per slot.  prev_token: the tokens of the flight before, for
+            the lanes chained to it (None inside a fused chunk's scan,
+            which chains its own)."""
+            if prev_token is not None:
+                token = _chain_tokens(token, prev_token)
 
             def attend_fn(q, k_new, v_new, kvs, kind=None, layer=None, gate=None):
                 # the model names the layer's index within its kind (and
@@ -946,10 +994,13 @@ class BatchedEngine:
                 base=first[kind] - len(kept),
             )
 
-    def _extend_window_tables(self, order, errors, active, R: int) -> None:
+    def _extend_window_tables(self, order, errors, active, R: int, ahead) -> None:
         """Before a dispatch of R steps: every stepping lane's window table
-        gives back the blocks wholly behind its first step's window, then
-        grows to cover R more tokens.  (Inside SPAN_DECODE_PREPARE.)"""
+        gives back the blocks wholly behind the window of the first step
+        the host has NOT READ yet (`pos`: a step in flight still reads
+        from there, so a chained lane's release lags its launch by that
+        step), then grows to cover R more tokens past the `ahead` steps in
+        flight.  (Inside SPAN_DECODE_PREPARE.)"""
         bt = self._kv_cfg.block_tokens
         pool = self.kv_pools[KV_KIND_WINDOW]
         for nonce, slot in list(order.items()):
@@ -957,14 +1008,25 @@ class BatchedEngine:
             p0 = int(self.pos[slot])
             pool.release_behind(tbl, window_first_block(p0, self._window, bt))
             try:
-                pool.ensure(tbl, p0 + R)
+                pool.ensure(tbl, p0 + int(ahead[slot]) + R)
             except KVPoolExhausted as exc:  # the pool is sized against this
-                errors[nonce] = str(exc)
-                active[slot] = False
-                del order[nonce]
+                self._refuse_lane(nonce, slot, str(exc), order, errors, active, ahead)
 
-    def _paged_extend(self, order, errors, active, R: int) -> int:
-        """Extend every stepping lane's page table to cover R more tokens.
+    def _refuse_lane(self, nonce, slot, why, order, errors, active, ahead) -> None:
+        """A lane the pools cannot extend leaves the dispatch: with its
+        typed error where it asked with a host token; silently where it
+        was only being chained (its step in flight still answers it, and
+        it asks again with that token: the refusal, if it stands, is told
+        then, after preemption has had its turn)."""
+        if not ahead[slot]:
+            errors[nonce] = why
+        active[slot] = False
+        ahead[slot] = 0
+        del order[nonce]
+
+    def _paged_extend(self, order, errors, active, R: int, ahead) -> int:
+        """Extend every stepping lane's page table to cover R more tokens
+        (past the `ahead` steps it has in flight).
         If the pool cannot cover the full chunk width, the WHOLE dispatch
         shrinks to single steps (keeping one program) and only lanes that
         cannot get even one block fail — alone, with the typed
@@ -974,14 +1036,14 @@ class BatchedEngine:
             for nonce, slot in list(order.items()):
                 try:
                     appended[slot] = self.kv_pool.ensure(
-                        self._tables[slot], int(self.pos[slot]) + R
+                        self._tables[slot], int(self.pos[slot]) + int(ahead[slot]) + R
                     )
                 except KVPoolExhausted as exc:
                     if R > 1:
                         break  # shrink the chunk and re-try every lane
-                    errors[nonce] = str(exc)
-                    active[slot] = False
-                    del order[nonce]
+                    self._refuse_lane(
+                        nonce, slot, str(exc), order, errors, active, ahead
+                    )
             else:
                 return R
             # roll the failed wide pass back before retrying at R=1: a
@@ -1086,43 +1148,60 @@ class BatchedEngine:
         whose slot vanished (client disconnect race) or hit max_seq fails
         ALONE — it must never poison the rest of the batch.
 
-        `budgets` (nonce -> remaining tokens the driver will accept) may
-        widen the dispatch into a fused R-step chunk: active lanes chain
-        their sampled tokens on device and the extra results buffer
-        engine-side, resolving later decode_batch calls instantly — the
-        host pays one dispatch + one packed read per R tokens per lane (the
-        same contract as LocalEngine.decode_chunk / the pipelined engine's
-        rotations).  The active set is FIXED across a chunk, so the stream
-        is bit-identical to R serial steps with the same request set.
+        Two halves at one seam: `decode_launch` ENQUEUES and returns,
+        `decode_read` blocks on the device.  This call runs them in a row:
+        the caller reads every step before it asks for the next, so a
+        dispatch may be worth more than one step.  `budgets` (nonce ->
+        remaining tokens the driver will accept) may widen it into a fused
+        R-step chunk: active lanes chain their sampled tokens on device
+        and the extra results buffer engine-side, resolving later
+        decode_batch calls instantly — the host pays one dispatch + one
+        packed read per R tokens per lane (the same contract as
+        LocalEngine.decode_chunk / the pipelined engine's rotations).  The
+        active set is FIXED across a chunk, so the stream is bit-identical
+        to R serial steps with the same request set.  A dispatch is fused
+        ONLY when it carries every lane this call asked for: when some
+        lane was answered from its buffer (or verified a drafted block)
+        the lanes are out of phase and take ONE step, all in one dispatch,
+        until every buffer is empty together.  Its callers: the batched
+        adapter (api/strategies.py), the ring, bench.py, the parity tests.
 
-        A dispatch is fused ONLY when it carries every lane this call asked
-        for.  When some lane was answered from its buffer (or verified a
-        drafted block), the lanes are out of phase: a fused dispatch for
-        the rest would run R steps of the whole batch program for them
-        alone while every other lane's next token waits behind it.  Those
-        lanes take ONE step, all in one dispatch; the buffered lanes drain,
-        and once all are empty together they fuse again.  (The scheduler
-        adds the other half of the condition: it hands out budgets only
-        while no prompt waits, sched/policy.py.)
-
-        Two halves at one seam: `decode_launch` (host spans prepare, then
-        — only when some lane's buffer is empty — launch) ENQUEUES and
-        returns; `decode_read` (readback, unpack, the counters) blocks on
-        the device.  A caller with more device work for the same tick
-        (sched/step.py) enqueues it between the two; every other caller
-        runs them in a row, here."""
+        The SERVED path (sched/step.py) never fuses and never reads a step
+        before the next is enqueued: it calls the halves itself and keeps
+        one step in flight, `decode_launch(chain=)`."""
         return self.decode_read(self.decode_launch(requests, budgets))
 
     def decode_launch(
         self,
         requests: Dict[str, Tuple[int, DecodingParams]],
         budgets: Optional[Dict[str, Optional[int]]] = None,
+        chain: Optional[DecodeFlight] = None,
     ) -> DecodeFlight:
         """The half of `decode_batch` that enqueues.  Nothing is fenced and
         nothing read (but a verify block, which reads its acceptance
         counts: `blocked`).  `last_dispatch` says what this call sent to
         the device: (R, lanes), (0, 0) when every lane was answered from
-        the buffer.  The lanes' `pos` advance in `decode_read`."""
+        the buffer.  The lanes' `pos` advance in `decode_read`.
+
+        `chain` is the flight BEFORE this one, not read yet (an empty
+        DecodeFlight where there is none): the caller keeps one step in
+        flight ahead of the one it reads.  `requests` are then the lanes
+        whose drivers have ASKED, and the dispatch is always ONE step:
+
+        - a lane in `chain` that will take a token after the one it is
+          owed (`budgets[nonce] >= 2`) steps again at `pos + 1`, its input
+          token taken from `chain`'s result on the device, inside the
+          step's program (`_chain_tokens`); with a budget of 1 it only
+          waits for `chain` to be read;
+        - a lane not in `chain` (it has just been adopted, or resumed)
+          steps from its host token, in the same dispatch;
+        - a lane whose driver was late for the flight before last holds
+          that token in its buffer: it is answered now (`answered()`),
+          and steps on from that token if it will take another.
+
+        A chained lane that ends at the token it is owed (a stop id, a
+        cancel, a preemption: the host learns at the read) leaves a
+        surplus step in the air, which `decode_read` drops."""
         t0 = time.perf_counter()
         flight = DecodeFlight()
         self.last_dispatch = (0, 0)
@@ -1130,20 +1209,25 @@ class BatchedEngine:
             return flight
         plan = None
         asked = len(requests)
+        served = chain is not None  # one step in flight, never a fused one
+        ahead_of = chain.order if served and chain.src is not None else {}
         with span(SPAN_DECODE_PREPARE):
-            # buffered tokens from an earlier fused chunk resolve first
-            flight.out, requests = self._pop_buffered(requests)
+            # buffered tokens (an earlier fused chunk's, a late driver's)
+            # resolve first
+            flight.out, requests = self._pop_buffered(
+                requests, budgets if served else None
+            )
             # per-lane speculation: greedy lanes with budget to spare verify
             # a drafted block instead of stepping once; they advance by
             # their OWN acceptance count (buffered), while the remaining
             # lanes take the plain batched step below — the two programs
             # touch disjoint lanes
-            spec_reqs = self._pick_spec_lanes(requests, budgets)
+            spec_reqs = {} if served else self._pick_spec_lanes(requests, budgets)
             if requests and not spec_reqs:
                 # out of phase (some lane had a buffered row): single step
                 plan = self._plan_dispatch(
-                    requests, budgets if len(requests) == asked else None,
-                    flight.errors,
+                    requests, budgets, flight.errors, ahead_of,
+                    fuse=not served and len(requests) == asked,
                 )
         if spec_reqs:
             spec_out = self._decode_spec_lanes(spec_reqs)
@@ -1153,14 +1237,15 @@ class BatchedEngine:
             requests = {n: r for n, r in requests.items() if n not in spec_reqs}
             if requests:
                 with span(SPAN_DECODE_PREPARE):
-                    plan = self._plan_dispatch(requests, None, flight.errors)
+                    plan = self._plan_dispatch(requests, None, flight.errors, {}, False)
         if plan is not None:
-            flight.order, flight.R, dev, table_ids = plan
-            self._launch(flight, dev, table_ids)
+            flight.order, flight.R, dev, table_ids, flight.chained = plan
+            prev_token = chain.src.token if flight.chained else self._no_token
+            self._launch(flight, dev, table_ids, prev_token)
         flight.host_s = time.perf_counter() - t0
         return flight
 
-    def _launch(self, flight: DecodeFlight, dev, table_ids) -> None:
+    def _launch(self, flight: DecodeFlight, dev, table_ids, prev_token) -> None:
         order, R = flight.order, flight.R
         lanes = len(order)
         with span(SPAN_DECODE_LAUNCH, R=R, lanes=lanes):
@@ -1169,21 +1254,25 @@ class BatchedEngine:
                 # the new rows block-append, all inside the launch
                 count_expert_rows(self.eng.model, self.slots, R)
                 flight.src, flight.moe = self._dispatch_ragged(
-                    order, R, dev, table_ids
+                    order, R, dev, table_ids, prev_token
                 )
             else:
                 # vmapped over the slots: each lane's experts see one row
                 count_expert_rows(self.eng.model, 1, R * self.slots)
                 token_d, pos_d, active_d, sp = dev
-                step = self._chunk_fn(R) if R > 1 else self._step
-                flight.src, self.kv, self.counts, self.keys = step(
+                args = (
                     self.eng.window_params, self.eng.edge_params, token_d,
                     self.kv, pos_d, active_d, sp, self.keys, self.counts,
                 )
+                if R > 1:
+                    out = self._chunk_fn(R)(*args)
+                else:
+                    out = self._step(*args, prev_token)
+                flight.src, self.kv, self.counts, self.keys = out
         self.last_dispatch = (R, lanes)
 
     def decode_read(
-        self, flight: DecodeFlight
+        self, flight: DecodeFlight, asked: Optional[Any] = None
     ) -> Tuple[Dict[str, SampleResult], Dict[str, str]]:
         """The half of `decode_batch` that reads: blocks until the device
         has finished the flight's dispatch, then hands each lane its row.
@@ -1191,7 +1280,17 @@ class BatchedEngine:
         A lane that LEFT between launch and read (preempted or ended while
         its step was in flight: `slot_of` no longer maps its nonce to the
         slot it was sent on) gets nothing: its `pos` is not advanced, its
-        token is dropped, and whoever holds the slot now is not touched."""
+        token is dropped, and whoever holds the slot now is not touched.
+        Where the step was chained that is a SURPLUS step (the lane ended
+        at the token before it, which the host had not read at the launch);
+        what it wrote went with the lane: its blocks, state entry and
+        sampling row are given back whole and overwritten whole by the
+        next adoption, which is enqueued after it.
+
+        `asked` (the served path): the lanes whose drivers have asked for
+        this flight's token.  A lane of the flight that is not among them
+        (its driver's turn was cut) keeps its token in the per-nonce
+        buffer, where its next ask finds it (`_pop_buffered`)."""
         if flight.src is None:
             return flight.out, flight.errors
         t0 = time.perf_counter()
@@ -1214,11 +1313,12 @@ class BatchedEngine:
         with span(SPAN_DECODE_UNPACK):
             now = time.time()
             out = flight.out
-            delivered = 0
+            delivered = surplus = 0
             for nonce, slot in flight.order.items():
                 if self.slot_of.get(nonce) != slot:
-                    continue  # the lane left with its step in flight
-                delivered += 1
+                    # the lane left with its step in flight
+                    surplus += nonce in flight.chained
+                    continue
                 self.pos[slot] += R
                 self.last_used[slot] = now
                 if R > 1:
@@ -1227,20 +1327,25 @@ class BatchedEngine:
                                      tts[k, slot], tlps[k, slot])
                         for k in range(R)
                     ]
-                    out[nonce] = rows[0]
-                    self._buffer.setdefault(nonce, []).extend(rows[1:])
                 else:
-                    out[nonce] = SampleResult(
+                    rows = [SampleResult(
                         token=toks[slot], logprob=lps[slot],
                         top_tokens=tts[slot], top_logprobs=tlps[slot],
-                    )
-        # what the fused-chunk path did: the device computed R steps for
-        # every slot, lanes asked for R x lanes of them, and the driver
-        # received one token per lane now (the rest wait in the buffer)
+                    )]
+                if asked is None or nonce in asked:
+                    delivered += 1
+                    out[nonce] = rows.pop(0)
+                if rows:
+                    self._buffer.setdefault(nonce, []).extend(rows)
+        # what the dispatch did: the device computed R steps for every
+        # slot, lanes asked for R x lanes of them, and the drivers received
+        # one token per lane now (the rest wait in the buffer)
         _DECODE_DISPATCHES.labels(r=str(R)).inc()
         _DECODE_SLOT_STEPS.inc(R * self.slots)
         _DECODE_LANE_STEPS.inc(R * lanes)
         _DECODE_TOKENS.labels(source="dispatch").inc(delivered)
+        _DECODE_CHAINED.inc(len(flight.chained))
+        _DECODE_SURPLUS.inc(surplus)
         if self.kv_store is not None and self.kv_store.in_place:
             # what the algorithm needs: each active lane's entry read and
             # written once a step, in every layer
@@ -1257,9 +1362,12 @@ class BatchedEngine:
         _DECODE_STEP_MS.observe_n(host_s * 1000.0 / n_tok, n_tok)
         return out, flight.errors
 
-    def _pop_buffered(self, requests):
-        """Answer every lane that still holds rows of an earlier fused
-        dispatch with the next one; returns (results, remaining requests)."""
+    def _pop_buffered(self, requests, budgets=None):
+        """Answer every lane that still holds rows of an earlier dispatch
+        with the next one; returns (results, remaining requests).  With
+        `budgets` (the served path) a lane answered so steps on in this
+        call, FROM the token it was just handed, where it will take
+        another: its request stays, with that token."""
         out_buf: Dict[str, SampleResult] = {}
         now = time.time()
         for nonce in requests:
@@ -1271,7 +1379,13 @@ class BatchedEngine:
                     self.last_used[slot] = now
         if out_buf:
             _DECODE_TOKENS.labels(source="buffer").inc(len(out_buf))
+            goes_on = {
+                n: (int(np.asarray(out_buf[n].token).reshape(-1)[0]), requests[n][1])
+                for n in out_buf
+                if takes_another(budgets, n) and not self._buffer.get(n)
+            }
             requests = {n: r for n, r in requests.items() if n not in out_buf}
+            requests.update(goes_on)
         return out_buf, requests
 
     def _pick_spec_lanes(self, requests, budgets) -> Dict[str, Tuple[int, int, int]]:
@@ -1296,14 +1410,21 @@ class BatchedEngine:
                 spec_reqs[nonce] = (tok, slot, budget)
         return spec_reqs
 
-    def _plan_dispatch(self, requests, budgets, errors):
+    def _plan_dispatch(self, requests, budgets, errors, ahead_of, fuse):
         """Everything the host prepares for one dispatch: per-slot numpy
         parameter rows, the chunk width and page-table extension.  Returns
-        (order, R, the step's host arguments, table ids) or None when no
-        lane is left to step."""
+        (order, R, the step's host arguments, table ids, the chained
+        lanes) or None when no lane is left to step.
+
+        `ahead_of` is the order (nonce -> slot) of a flight the host has
+        not read yet: a lane in it steps at `pos + 1` from that flight's
+        token (its host row says CHAINED), where its budget holds a token
+        after the one it is owed; otherwise it is left out of this
+        dispatch, and no error is told for it."""
         token = np.zeros((self.slots, 1), dtype=np.int32)
         active = np.zeros(self.slots, dtype=bool)
         pos = np.zeros(self.slots, dtype=np.int32)
+        ahead = np.zeros(self.slots, dtype=np.int32)  # steps in flight, unread
         temp = np.zeros(self.slots, dtype=np.float32)
         top_p = np.ones(self.slots, dtype=np.float32)
         top_k = np.zeros(self.slots, dtype=np.int32)
@@ -1318,14 +1439,20 @@ class BatchedEngine:
             if slot is None:
                 errors[nonce] = f"request {nonce!r} has no batch slot (cancelled?)"
                 continue
+            chained = ahead_of.get(nonce) == slot
+            if chained and not (
+                takes_another(budgets, nonce) and self.pos[slot] + 2 <= self.max_seq
+            ):
+                continue  # it takes the token it is owed and no step more
             if self.pos[slot] >= self.max_seq:
                 errors[nonce] = (
                     f"sequence length {self.pos[slot]} reached max_seq {self.max_seq}"
                 )
                 continue
-            token[slot, 0] = tok
+            token[slot, 0] = CHAINED if chained else tok
             active[slot] = True
-            pos[slot] = self.pos[slot]
+            ahead[slot] = int(chained)
+            pos[slot] = self.pos[slot] + ahead[slot]
             temp[slot] = dec.temperature
             top_p[slot] = dec.top_p
             top_k[slot] = dec.top_k
@@ -1348,7 +1475,7 @@ class BatchedEngine:
         # fused-chunk width: bounded by the smallest remaining budget and
         # by every active lane's sequence capacity
         R = 1
-        if budgets:
+        if budgets and fuse:
             cap = min((budgets.get(n) or 1) for n in order)
             cap = min(cap, *(int(self.max_seq - self.pos[s]) for s in order.values()))
             R = next((r for r in self.CHUNK_BUCKETS if r <= cap), 1)
@@ -1356,19 +1483,19 @@ class BatchedEngine:
         if self.kv_pool is not None:
             # block-table extension is admission: a lane the pool cannot
             # cover fails ALONE with the typed backpressure message
-            R = self._paged_extend(order, errors, active, R)
+            R = self._paged_extend(order, errors, active, R, ahead)
+            if order and self._window:
+                self._extend_window_tables(order, errors, active, R, ahead)
             if not order:
                 return None
-            if self._window:
-                self._extend_window_tables(order, errors, active, R)
-                if not order:
-                    return None
             table_ids = self._table_ids(order if R == 1 else None)
         elif self.kv_store is not None:
             table_ids = {}  # the state kind: a lane IS the address
-        return order, R, (token, pos, active, sp), table_ids
+        chained = frozenset(n for n, s in order.items() if ahead[s])
+        return order, R, (token, pos, active, sp), table_ids, chained
 
-    def _dispatch_ragged(self, order: Dict[str, int], R: int, dev, tables):
+    def _dispatch_ragged(self, order: Dict[str, int], R: int, dev, tables,
+                         prev_token):
         """One decode dispatch over the pool (R == 1: the read-only
         paged_attend program + the jitted kv_append block-append; R > 1:
         the fused chunk carrying the donated pool).  All of it is the
@@ -1393,7 +1520,9 @@ class BatchedEngine:
             )
             self.kv_store.kv = pool
             return stacked, moe
-        res, rows, self.counts, self.keys, moe = self._ragged_step(*args)
+        res, rows, self.counts, self.keys, moe = self._ragged_step(
+            *args, prev_token
+        )
         if self.kv_store.in_place:
             self.kv_store.append_rows(rows, {}, None)  # the step already wrote
             return res, moe
@@ -1406,7 +1535,7 @@ class BatchedEngine:
         }
         off = np.zeros(self.slots, dtype=np.int32)
         for _nonce, slot in order.items():
-            p0 = int(self.pos[slot])
+            p0 = int(pos_d[slot])  # the row this step writes, chained or not
             off[slot] = p0 % bt
             for kind, tables in self._kind_tables.items():
                 tbl = tables[slot]
@@ -1476,11 +1605,13 @@ class BatchedEngine:
         _DECODE_STEP_MS.observe_n(per_tok_ms, total_emitted)
         return res
 
-    def warm_chunks(self) -> None:
+    def warm_chunks(self, fused: bool = True) -> None:
         """Compile the batched step and the fused-chunk widths up front with
         a throwaway session, so the FIRST budgeted request doesn't stall
         every concurrent lane on a multi-second scan compile (the batch loop
-        runs all lanes on one compute executor)."""
+        runs all lanes on one compute executor).  `fused=False`: the load
+        is served by the scheduler, which dispatches single steps alone
+        (sched/step.py), and the R-step programs are left uncompiled."""
         t0 = time.time()
         dec = DecodingParams(temperature=0.0)
         self.prefill_and_sample("__warm__", [0], dec)
@@ -1495,15 +1626,13 @@ class BatchedEngine:
         # sampled decoding is spec-ineligible, so these rounds compile the
         # PLAIN step/chunk programs even on spec-enabled engines
         dec_plain = DecodingParams(temperature=1.0) if self.spec_lookahead else dec
-        for r in (1,) + tuple(self.CHUNK_BUCKETS):
+        self._warm_step(dec_plain)
+        for r in self.CHUNK_BUCKETS if fused else ():
             if self.pos[slot] + r < self.max_seq:
-                self.decode_batch(
-                    {"__warm__": (0, dec_plain)},
-                    budgets={"__warm__": r} if r > 1 else None,
-                )
+                self.decode_batch({"__warm__": (0, dec_plain)}, budgets={"__warm__": r})
                 self._buffer.pop("__warm__", None)
         self.end_session("__warm__")
-        widths = 1 + len(self.CHUNK_BUCKETS)
+        widths = 1 + (len(self.CHUNK_BUCKETS) if fused else 0)
         if self.kv_pool is not None:
             # R==1 dispatches attend at the pow2 bucket of the widest
             # ACTIVE table (_table_ids): compile the step at every bucket
@@ -1530,8 +1659,7 @@ class BatchedEngine:
                     # so the width can never be dispatched either
                     self.end_session("__warm__")
                     break
-                self.decode_batch({"__warm__": (0, dec_plain)})
-                self._buffer.pop("__warm__", None)
+                self._warm_step(dec_plain)
                 self.end_session("__warm__")
                 widths += 1
                 half = w
@@ -1539,6 +1667,17 @@ class BatchedEngine:
             "[PROFILE] warmed batched chunk programs (%d widths) in %.1fs",
             widths, time.time() - t0,
         )
+
+    def _warm_step(self, dec: DecodingParams) -> None:
+        """The warm session's step as both of its callers give it: with no
+        flight before it, and chained to that one (the same program)."""
+        reqs = {"__warm__": (0, dec)}
+        first = self.decode_launch(reqs, chain=DecodeFlight())
+        if self.pos[self.slot_of["__warm__"]] + 2 < self.max_seq:
+            second = self.decode_launch(reqs, budgets={"__warm__": 2}, chain=first)
+            self.decode_read(first)
+            first = second
+        self.decode_read(first)
 
     def generate(
         self,

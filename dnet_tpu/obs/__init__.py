@@ -445,8 +445,19 @@ def _register_core(reg: MetricsRegistry) -> None:
         tokens_fam.labels(source=source)  # pre-touch: the lint checks these
     reg.counter(
         "dnet_decode_buffer_dropped_total",
-        "Buffered fused-chunk tokens thrown away when their session ended "
-        "(computed on the device, never delivered)",
+        "Buffered tokens (a fused chunk's, a late driver's) thrown away "
+        "when their session ended (computed on the device, never delivered)",
+    )
+    reg.counter(
+        "dnet_decode_chained_lanes_total",
+        "Lanes of a decode dispatch that took their input token from the "
+        "flight before it, on the device (core/batch.py decode_launch "
+        "chain=; counted at decode_read)",
+    )
+    reg.counter(
+        "dnet_decode_surplus_steps_total",
+        "Chained lane-steps whose token was dropped at the read: the lane "
+        "had ended at the token before (a stop id, a cancel, a preemption)",
     )
     compiles = reg.counter(
         "dnet_jit_compiles_total",

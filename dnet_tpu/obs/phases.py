@@ -16,9 +16,9 @@ from __future__ import annotations
 # HOST did; device time is the device trace's to tell.  A span's self time is
 # its duration less its children's.  The tree (parent > child):
 #   dnet.tick                 sched/step.py execute_tick, compute thread
-#     dnet.tick.decode        TWO a tick with decode lanes: around
-#                             engine.decode_launch, and, after the tick's
-#                             chunks are launched, around engine.decode_read
+#     dnet.tick.decode        around engine.decode_launch (step n+1, after the
+#                             tick's chunks are launched) and around
+#                             engine.decode_read (step n, enqueued a tick ago)
 #       dnet.decode.prepare     buffer pops, numpy rows, uploads, table ids
 #       dnet.decode.launch      the jitted step/chunk call (+ kv_append):
 #                               an ENQUEUE (and a compile, if one happens)
@@ -177,8 +177,10 @@ MOE_PATHS = ("grouped", "dense")
 # dnet_decode_tokens_total{source=}: where a token decode_batch handed the
 # driver came from (core/batch.py)
 #   dispatch — first row of the dispatch this call made
-#   buffer   — a row an earlier fused R-step (or verify) dispatch left in
-#              the engine's buffer: no device work in this call
+#   buffer   — a row an earlier dispatch left in the engine's buffer (a
+#              fused R-step or verify dispatch's later rows; under the
+#              scheduler, the token a late driver had not asked for
+#              when its step was read): no device work in this call
 #   spec     — first row of a per-lane speculative verify block
 DECODE_TOKEN_SOURCES = ("dispatch", "buffer", "spec")
 

@@ -300,8 +300,10 @@ class SchedulerAdapter(ApiAdapterBase):
     async def _tick_loop(self) -> None:
         loop = asyncio.get_running_loop()
         # The turn-around between two ticks, as the loop sees it.  `last`
-        # is the tick the next one follows (None once the loop has parked
-        # with nothing to do: an idle server's seconds are no turn-around);
+        # is the tick the next one follows: it holds the decode step that
+        # tick left in flight, which the next one reads (once the loop has
+        # parked with nothing to do it holds that alone: an idle server's
+        # seconds are no turn-around; None after a tick that failed);
         # `turn` is dnet.sched.turn, open from the resume after `last` to
         # the submit of the next tick (or to the park).  Held across awaits,
         # like `_owed` (obs.span says why that is sound).
@@ -322,9 +324,10 @@ class SchedulerAdapter(ApiAdapterBase):
                 if plan.empty():
                     if turn is not None and not len(self.queue):
                         # no lane and no prompt: the loop parks, and the
-                        # tick that ends the park follows nothing
+                        # tick that ends the park follows no turn-around
                         turn.close()
-                        turn = last = None
+                        turn = None
+                        last = last.after_park() if last is not None else None
                     continue
                 if plan.decode:
                     # lanes whose drivers have not asked yet: the step

@@ -42,7 +42,7 @@ class TickRecord:
     budget_wasted: int       # budget - used (0 on a saturated tick)
     prefill_tokens: int      # prompt tokens chunk-prefilled this tick
     decode_lanes: int        # decode lanes answered this tick (from the
-                             # dispatch or the engine's fused-chunk buffer)
+                             # step it read or the engine's buffer)
     preempted: int           # sequences evicted back to WAITING
     requeued: int            # starved prefills requeued
     errors: int              # per-nonce errors the tick surfaced
@@ -50,8 +50,8 @@ class TickRecord:
     kv_blocks_used: int = 0
     kv_blocks_free: int = 0
     kv_pool_blocks: int = 0
-    dispatched_lanes: int = 0  # lanes in the dispatch that reached the device
-    chunk_r: int = 0           # its fused width R; 0 = answered from the buffer
+    dispatched_lanes: int = 0  # lanes of the step this tick enqueued
+    chunk_r: int = 0           # its width R (1 when served); 0 = none enqueued
 
     def as_dict(self) -> dict:
         return asdict(self)

@@ -5,16 +5,15 @@ work into one :class:`TickPlan`:
 
 1. **Decode first.**  Every DECODING request with a pending step gets one
    token (decode is what the per-token SLO measures; a long prompt must
-   never starve running streams for more than one tick).  Fused-chunk
-   budgets ride along ONLY while no prompt waits (nothing PREFILLING,
-   nothing WAITING): with a prompt waiting every tick is one decode step
-   that carries every lane, then the prompt's chunks — a fused R-step
-   dispatch would hold the prompt, and every lane it does not carry,
-   behind R steps of the whole batch program.  With nothing waiting the
-   engine may batch R device steps per dispatch (a host round trip a
-   token and nothing to hide it behind), and does so only for lanes in
-   phase (``BatchedEngine.decode_batch``).  The active set is fixed per
-   dispatch, so streams are bit-identical to serial stepping for any R.
+   never starve running streams for more than one tick).  Every dispatch
+   on this path is ONE step that carries every lane that asked; the
+   drivers' remaining budgets ride along in every plan and say which
+   lanes may be CHAINED: a lane that will take a token after the one it
+   is owed steps again, from that token on the device, before the host
+   has read it (sched/step.py keeps one step in flight ahead of the one
+   it reads, so no host round trip is left for a fused R-step dispatch
+   to save).  A lane steps in the same order whoever supplies its input
+   token, so streams are bit-identical to serial stepping.
 2. **Chunked prefill fills the remainder.**  PREFILLING requests continue
    (most urgent first), each by a segment as wide as the budget still
    holds (one pass over the weights for the prompt, not one every few
@@ -67,8 +66,8 @@ class TickPlan:
     prefills: List[PrefillChunk] = field(default_factory=list)
     #: nonce -> (last token, decoding) for this tick's batched decode
     decode: Dict[str, Tuple[int, DecodingParams]] = field(default_factory=dict)
-    #: nonce -> remaining tokens the driver accepts; EMPTY while a prompt
-    #: waits (then every decode dispatch is a single step)
+    #: nonce -> remaining tokens the driver accepts, the one it asked for
+    #: included: 2 or more, and the lane may be chained a step ahead
     budgets: Dict[str, Optional[int]] = field(default_factory=dict)
     steps: Dict[str, int] = field(default_factory=dict)
     #: replay ids for EVERY decoding request (preemption stash source)
@@ -207,10 +206,8 @@ class SchedulerPolicy:
             out.admitted.append(r.nonce)
             slots_free -= 1
             emit(r, first=True)
-        if not queue.prefilling() and not queue.waiting():
-            # no prompt waits: the engine may fuse lanes that are in phase
-            out.budgets = {
-                r.nonce: r.pending_budget for r in decoding if r.nonce in out.decode
-            }
+        out.budgets = {
+            r.nonce: r.pending_budget for r in decoding if r.nonce in out.decode
+        }
         queue.sync_gauges()
         return out

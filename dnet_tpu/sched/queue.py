@@ -62,8 +62,8 @@ class SchedRequest:
     #: the driver's outstanding step awaiting a token, or None
     pending_step: Optional[int] = None
     #: remaining token allowance the driver advertised with the pending
-    #: step (the plan hands it to the engine, which may then fuse R steps
-    #: into one dispatch, only while no prompt waits: sched/policy.py)
+    #: step, that step's token included (the plan hands it to the engine:
+    #: 2 or more, and the lane may be chained a step ahead, sched/step.py)
     pending_budget: Optional[int] = None
     preemptions: int = 0
     #: consecutive starved requeues (bounded before the typed error)

@@ -1,11 +1,13 @@
 """Which lanes a tick's decode dispatch carries, and how wide it is.
 
-One rule in two halves (core/batch.py: decode_batch; sched/policy.py: plan):
-a dispatch is fused (R > 1) only if it carries every lane of the tick's
-decode set AND no prompt waits; otherwise every lane whose buffer is empty
-takes one step, all in one dispatch.  A lone stream with nothing queued
-still fuses (tests/subsystems/test_tick_anatomy.py holds the served case).
-Streams are bit-identical to serial stepping whatever R turns out to be.
+On the served path (sched/step.py; sched/policy.py: plan) every dispatch is
+ONE step that carries every lane that asked: a lane of the step in flight
+is chained to it on the device, a lane that has just been adopted joins the
+same dispatch with its host token, and nobody waits for anybody's buffer.
+The engine's own half (core/batch.py: decode_batch, for the callers that
+read every step before they ask for the next) still fuses a dispatch that
+carries every lane of its call.  Streams are bit-identical to serial
+stepping either way.
 """
 
 import asyncio
@@ -132,11 +134,18 @@ def _decode_records():
     return [r for r in recs if r["decode_lanes"]]
 
 
-def test_lanes_out_of_phase_behind_a_prompt_take_one_step_a_tick(tiny_llama_dir, paged_env):
+def _dispatches():
+    disp = metric("dnet_decode_dispatch_total")
+    return {r: int(disp.labels(r=str(r)).value) for r in (1, 2, 4, 8, 16)}
+
+
+def test_lanes_out_of_phase_behind_a_prompt_step_together_every_second_tick(tiny_llama_dir, paged_env):
     """Three lanes adopted on different ticks (1, 2 and 3 chunks of prompt)
     while a fourth prompt of 14 chunks is still prefilling: every dispatch
-    is a single step that carries every decoding lane of its tick, nothing
-    is ever buffered, and the streams are the serial ones."""
+    is a single step that carries every lane that asked, and while the long
+    prompt's chunks go on the ticks alternate (a chunk and a step, then a
+    chunk and that step's read: the chunk is what the device runs across
+    the host's turn, sched/step.py).  The streams are the serial ones."""
     asks = {"a": (8, 7), "b": (16, 7), "c": (24, 7), "d": (112, 1)}
     want = _serial_streams(tiny_llama_dir, paged_env, asks)
     reset_obs()
@@ -147,30 +156,32 @@ def test_lanes_out_of_phase_behind_a_prompt_take_one_step_a_tick(tiny_llama_dir,
     finally:
         eng.close()
     assert got == want
-    recs = _decode_records()
-    # the long prompt was still prefilling when each of these ticks ended
-    assert recs and all(r["queue_depths"][STATE_PREFILLING] >= 1 for r in recs)
-    for r in recs:
-        assert r["chunk_r"] == 1, r
-        assert r["dispatched_lanes"] == r["decode_lanes"], r
-    # lanes joined one by one and no tick left a decoding lane out
-    assert max(r["decode_lanes"] for r in recs) == 3
-    assert [r["decode_lanes"] for r in recs[:3]] == [1, 2, 3]
-    assert len(recs) == 6 + 2  # the last lane's six steps, two ticks behind the first
+    recs = [r.as_dict() for r in get_tick_recorder().records()]
+    sent = [r for r in recs if r["chunk_r"]]
+    assert sent and all(r["chunk_r"] == 1 for r in sent)
+    # the long prompt was still prefilling when each of these ticks began
+    assert all(r["prefill_tokens"] for r in sent)
+    # no tick sends a step behind a chunk while one is in flight
+    assert not any(x["chunk_r"] and y["chunk_r"] for x, y in zip(recs, recs[1:]))
+    # lanes joined as they were adopted, and once in they stepped together
+    assert max(r["dispatched_lanes"] for r in sent) == 3
+    assert sum(r["dispatched_lanes"] == 3 for r in sent) >= 4
     lane_steps = metric("dnet_decode_lane_steps_total").value
     slot_steps = metric("dnet_decode_slot_steps_total").value
-    assert lane_steps == sum(r["decode_lanes"] for r in recs) == 3 * 6
-    assert slot_steps == slots * len(recs)
-    disp = metric("dnet_decode_dispatch_total")
-    assert disp.labels(r="1").value == len(recs)
-    assert all(disp.labels(r=str(r)).value == 0 for r in (2, 4, 8, 16))
-    assert _tokens_by_source() == {"dispatch": 18, "buffer": 0, "spec": 0}
+    assert lane_steps == sum(r["dispatched_lanes"] for r in sent) == 3 * 6
+    assert slot_steps == slots * len(sent)
+    assert _dispatches() == {1: len(sent), 2: 0, 4: 0, 8: 0, 16: 0}
+    assert metric("dnet_decode_surplus_steps_total").value == 0
+    src = _tokens_by_source()
+    assert src["dispatch"] + src["buffer"] == 18 and src["spec"] == 0
+    assert sum(r["decode_lanes"] for r in recs) == 18
 
 
-def test_a_new_lane_steps_alone_while_buffers_drain_then_all_fuse(tiny_llama_dir, paged_env):
-    """Two streams in phase with nothing queued fuse; a third arrives: no
-    budgets while it prefills, then it steps at R = 1 while the others
-    drain their buffers, then all three are dispatched together."""
+def test_a_new_lane_joins_the_next_dispatch_and_nobody_waits_for_a_buffer(tiny_llama_dir, paged_env):
+    """Two streams step together, each step chained to the one before; a
+    third arrives: the tick after its adoption it is in the SAME dispatch as
+    the other two, with its host token.  No step is launched for it alone,
+    and no dispatch is ever fused."""
     asks = {"a": (8, 24), "b": (8, 24), "c": (8, 24)}
     want = _serial_streams(tiny_llama_dir, paged_env, asks)
     reset_obs()
@@ -182,19 +193,20 @@ def test_a_new_lane_steps_alone_while_buffers_drain_then_all_fuse(tiny_llama_dir
     finally:
         eng.close()
     assert got == want
-    recs = _decode_records()
-    shape = [(r["chunk_r"], r["dispatched_lanes"], r["decode_lanes"]) for r in recs]
-    # a dispatch that leaves a lane of its tick out is never fused
-    assert all(r <= 1 for r, sent, lanes in shape if sent < lanes), shape
-    assert shape[0] == (16, 2, 2)  # a and b, in phase, nothing queued
-    alone = [i for i, s in enumerate(shape) if s == (1, 1, 3)]
-    assert alone, shape  # c steps while a and b drain
-    together = [i for i, (r, sent, lanes) in enumerate(shape) if sent == lanes == 3]
-    assert together and together[0] > alone[-1], shape
-    assert shape[together[0]][0] > 1, shape  # in phase again: fused
+    recs = [r.as_dict() for r in get_tick_recorder().records()]
+    sent = [r["dispatched_lanes"] for r in recs if r["chunk_r"]]
+    assert all(r["chunk_r"] in (0, 1) for r in recs)
+    assert _dispatches() == {1: len(sent), 2: 0, 4: 0, 8: 0, 16: 0}
+    joined = sent.index(3)  # c's first step, beside a and b
+    assert set(sent[:joined]) <= {1, 2} and sent[joined - 1] == 2, sent
+    # a and b end together one step after the other or so: until then no
+    # dispatch carries fewer than all three (late drivers aside: none here)
+    both_alive = sent[joined : joined + 15]
+    assert both_alive == [3] * len(both_alive), sent
+    assert metric("dnet_decode_lane_steps_total").value == 3 * 23
+    assert metric("dnet_decode_chained_lanes_total").value >= 3 * 23 - 3 - 2
     src = _tokens_by_source()
-    assert src["buffer"] > 0 and src["spec"] == 0
-    assert src["dispatch"] + src["buffer"] == 3 * 23
+    assert src["spec"] == 0 and src["dispatch"] + src["buffer"] == 3 * 23
 
 
 @pytest.mark.parametrize("kv", ["dense", "paged"])
@@ -255,18 +267,21 @@ class _Slots:
 
 
 @pytest.mark.parametrize(
-    "others,slots,budgeted",
+    "others,slots",
     [
-        ({}, 4, True),  # lanes alone: the engine may fuse
-        ({"p": (STATE_PREFILLING, 0)}, 4, False),  # a prompt mid-prefill
-        ({"w": (STATE_WAITING, 0)}, 4, False),  # admitted this tick: its chunk is in the plan
-        ({"w": (STATE_WAITING, 0)}, 2, False),  # no slot free: it waits for admission
-        ({"w": (STATE_WAITING, None)}, 4, False),  # preempted, its next step moments away
-        ({"x": (STATE_DECODING, None)}, 4, True),  # a lane between steps is no prompt
+        ({}, 4),  # lanes alone
+        ({"p": (STATE_PREFILLING, 0)}, 4),  # a prompt mid-prefill
+        ({"w": (STATE_WAITING, 0)}, 4),  # admitted this tick: its chunk is in the plan
+        ({"w": (STATE_WAITING, 0)}, 2),  # no slot free: it waits for admission
+        ({"w": (STATE_WAITING, None)}, 4),  # preempted, its next step moments away
+        ({"x": (STATE_DECODING, None)}, 4),  # a lane between steps has no budget to tell
     ],
 )
-def test_the_policy_hands_out_budgets_only_while_no_prompt_waits(others, slots, budgeted):
+def test_the_policy_hands_out_budgets_in_every_plan(others, slots):
+    """`plan.budgets` says which lanes may be chained a step ahead, whether
+    or not a prompt waits: nothing is fused on this path, so a budget holds
+    nobody up."""
     q = _queue({"d1": (STATE_DECODING, 3), "d2": (STATE_DECODING, 5), **others})
     plan = SchedulerPolicy(token_budget=64, prefill_chunk=8).plan(q, _Slots(slots))
     assert set(plan.decode) == {"d1", "d2"}
-    assert plan.budgets == ({"d1": 9, "d2": 9} if budgeted else {})
+    assert plan.budgets == {"d1": 9, "d2": 9}

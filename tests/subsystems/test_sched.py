@@ -528,23 +528,34 @@ class FakeStepEngine:
         self.ended.append(nonce)
         self.slot_of.pop(nonce, None)
 
-    def decode_launch(self, requests, budgets=None):
-        """The enqueue half: the lanes the step was sent for."""
-        from types import SimpleNamespace
+    def decode_launch(self, requests, budgets=None, chain=None):
+        """The enqueue half: the lanes the step was sent for.  As on
+        BatchedEngine, a lane of `chain` (the step not read yet) steps
+        again only with a budget of 2 or more."""
+        from dnet_tpu.core.batch import DecodeFlight, takes_another
 
-        self.last_dispatch = (1, len(requests))
-        return SimpleNamespace(
-            order={n: self.slot_of[n] for n in requests}, blocked=False
+        ahead = chain.order if chain is not None and chain.src is not None else {}
+        order = {
+            n: self.slot_of[n] for n in requests
+            if ahead.get(n) != self.slot_of[n] or takes_another(budgets, n)
+        }
+        self.last_dispatch = (1, len(order)) if order else (0, 0)
+        if not order:
+            return DecodeFlight()
+        return DecodeFlight(
+            order=order, R=1, src="on the device",
+            chained=frozenset(n for n in order if n in ahead),
         )
 
-    def decode_read(self, flight):
+    def decode_read(self, flight, asked=None):
         """The read half: as on BatchedEngine, a lane that left while its
         step was in flight gets nothing and its `pos` is not advanced."""
         out = {}
         for n, slot in flight.order.items():
             if self.slot_of.get(n) == slot:
                 self.pos[slot] += 1
-                out[n] = f"tok-{n}"
+                if asked is None or n in asked:
+                    out[n] = f"tok-{n}"
         return out, {}
 
 
@@ -571,11 +582,11 @@ def test_prefill_starvation_evicts_lower_priority_victim():
     plan.victims = ["low"]
     plan.prefills = [_chunk("urgent", victims=["low"])]
     res = execute_tick(eng, plan)
-    # the victim's step was launched (decode is enqueued first) and still in
-    # flight when the chunk evicted it, its prefix aliased: the read half
-    # drops that token (the pending step rides the resume) and leaves the
-    # freed lane's position alone; the urgent prefill keeps its staging
-    assert "low" not in res.decode_results
+    # the chunk evicted the victim before this tick's step was enqueued
+    # (chunks go first), its prefix aliased: it is left out of the step,
+    # its pending step rides the resume and the freed lane's position is
+    # left alone; the urgent prefill keeps its staging
+    assert "low" not in res.decode_results and res.flight is None
     assert res.preempted == ["low"]
     assert eng.pos[0] == 6
     assert eng.ended == ["low"] and eng.stored == ["low"]
@@ -631,8 +642,12 @@ def test_decode_starvation_evicts_least_urgent_lane():
     plan.victims = ["low", "high"]  # least urgent first
     res = execute_tick(eng, plan)
     assert res.preempted == ["low"]
-    assert "high" in res.decode_results  # the urgent lane still stepped
-    assert "low" not in res.decode_results
+    assert list(res.flight.order) == ["high"]  # the urgent lane still stepped
+    del plan.decode["low"]  # requeued: WAITING, no lane
+    plan.budgets = {"high": 3}
+    nxt = execute_tick(eng, plan, follows=res)  # and the tick after reads it
+    assert set(nxt.decode_results) == {"high"}
+    assert nxt.flight.chained == {"high"} and not nxt.preempted
 
 
 def test_starved_requeue_is_bounded_by_typed_error():

@@ -4,9 +4,10 @@ Three layers, all always on and unfenced:
 
 - `decode_batch`'s fused-chunk counters (core/batch.py): slot-steps the
   device computed, lane-steps active lanes asked for, tokens the driver
-  received by source, dispatches by width R.  Buffer hits belong to lanes
-  in phase with nothing queued (a lone stream here); under load every
-  dispatch is one step over all lanes (tests/subsystems/test_decode_phase.py);
+  received by source, dispatches by width R.  Buffer hits belong to a
+  caller that reads each step before it asks for the next; the served path
+  dispatches one step over all lanes, a step ahead of the one it reads
+  (tests/subsystems/test_decode_phase.py), a lone stream too;
 - the scheduler's stamps on SchedRequest (sched/engine.py): queue wait,
   prefill wall time and ticks, decode deliver wait, and the recorder's
   `sched_queue` / `prefill` spans that make a request's segment ledger add
@@ -230,22 +231,27 @@ def test_scheduler_rehearsal_waits_spans_and_tick_records(tiny_llama_dir, paged_
         n_tick, tick_ms = _span("dnet.tick")
         assert n_tick == metric("dnet_sched_tick_ms").count > chunks
         assert tick_ms <= metric("dnet_sched_tick_ms").sum
-        # dnet.tick.decode opens twice a tick with decode lanes: around the
-        # launch half (prepare, launch) and, once the tick's chunks are
-        # launched, around the read half (readback, unpack)
+        # dnet.tick.decode opens around the launch half (prepare, launch)
+        # of every tick whose plan holds a lane that asked, and around the
+        # read half (readback, unpack) of every tick that follows a step
         n_halves, dec_ms = _span("dnet.tick.decode")
         child_ms = sum(_span(n)[1] for n in DECODE_CHILD_SPANS)
         assert 0.8 * dec_ms <= child_ms <= dec_ms
         n_dec = _span("dnet.decode.prepare")[0]  # one per decode_launch call
-        assert n_halves == 2 * n_dec
         assert DECODE_CHILD_SPANS == tuple(
             f"dnet.decode.{s}" for s in ("prepare", "launch", "readback", "unpack"))
-        n_disp = sum(metric("dnet_decode_dispatch_total").labels(r=str(r)).value
-                     for r in (1, 2, 4, 8, 16))
+        disp = metric("dnet_decode_dispatch_total")
+        n_disp = disp.labels(r="1").value
+        assert all(disp.labels(r=str(r)).value == 0 for r in (2, 4, 8, 16))
         assert _span("dnet.decode.launch")[0] == _span("dnet.decode.readback")[0] == n_disp
-        # a lone stream with nothing queued still fuses: some calls were
-        # buffer hits (with a prompt waiting every call would dispatch)
-        assert 0 < n_disp < n_dec
+        assert n_halves == n_dec + n_disp
+        # a lone stream is never fused and never answered from a buffer:
+        # one step a token, each but the first chained to the one before;
+        # the last ask (a budget of 1) only reads
+        assert n_disp == decode_tokens == n_dec - 1
+        assert metric("dnet_decode_chained_lanes_total").value == decode_tokens - 1
+        assert metric("dnet_decode_surplus_steps_total").value == 0
+        assert tok.labels(source="buffer").value == 0
         n_pf, pf_ms = _span("dnet.tick.prefill")
         assert n_pf == _span("dnet.prefill.launch")[0] == chunks
         assert _span("dnet.prefill.adopt")[0] == 1
@@ -260,12 +266,13 @@ def test_scheduler_rehearsal_waits_spans_and_tick_records(tiny_llama_dir, paged_
         recs = [r.as_dict() for r in get_tick_recorder().records()]
         assert len(recs) == n_tick
         decode_ticks = [r for r in recs if r["decode_lanes"]]
-        assert len(decode_ticks) == n_dec == decode_tokens
-        reached = [r for r in decode_ticks if r["chunk_r"]]
+        assert len(decode_ticks) == decode_tokens  # the ticks that read a step
+        reached = [r for r in recs if r["chunk_r"]]  # the ticks that sent one
         assert len(reached) == n_disp
         assert all(r["dispatched_lanes"] == 1 for r in reached)
-        assert all(r["dispatched_lanes"] == 0 for r in decode_ticks if not r["chunk_r"])
-        # decode_lanes keeps counting buffer-answered lanes, as before
+        assert all(r["dispatched_lanes"] == 0 for r in recs if not r["chunk_r"])
+        # the first sends and reads nothing, the last reads and sends nothing
+        assert reached[0] not in decode_ticks and decode_ticks[-1] not in reached
         assert sum(r["decode_lanes"] for r in recs) == decode_tokens
         slot_steps = metric("dnet_decode_slot_steps_total").value
         assert slot_steps == slots * sum(r["chunk_r"] for r in reached)

@@ -140,14 +140,18 @@ def test_a_victim_evicted_by_an_adoption_is_preempted_exactly_once():
     plan = TickPlan()
     plan.decode = {"keep": (1, DecodingParams()), "low": (2, DecodingParams())}
     plan.steps = {"keep": 4, "low": 3}
+    plan.budgets = {"keep": 9, "low": 9}
     plan.ids = {"low": list(range(8))}
     plan.victims = ["low", "keep"]
+    first = execute_tick(eng, plan)  # both lanes' step goes into flight
+    assert sorted(first.flight.order) == ["keep", "low"] and not first.decode_results
     plan.prefills = [fake_chunk("urgent", victims=["low"])]
-    res = execute_tick(eng, plan)
+    res = execute_tick(eng, plan, follows=first)
     assert res.preempted == ["low"] and eng.ended == ["low"]
     assert res.adopted == {"urgent": "sample-urgent"}  # retried in the tick
     assert set(res.decode_results) == {"keep"}  # the in-flight token is dropped
     assert eng.pos[slot_low] == 6  # and the freed lane's position left alone
+    assert list(res.flight.order) == ["keep"]  # nor is the victim chained
     assert not res.errors and not res.requeued
 
 
@@ -239,8 +243,10 @@ def test_tick_results_hold_no_device_array(engine):
     plan = TickPlan()
     plan.decode = {"a": (ta, d)}
     plan.steps = {"a": 1}
+    plan.budgets = {"a": 5}
+    first = execute_tick(engine, plan)  # a's step goes into flight
     plan.prefills = [_chunk("b", _prompt(19, 2), d)]
-    res = execute_tick(engine, plan)
+    res = execute_tick(engine, plan, follows=first)
     assert not res.errors
     assert set(res.decode_results) == {"a"} and set(res.adopted) == {"b"}
     for leaf in jax.tree.leaves((res.decode_results, res.adopted)):
@@ -291,18 +297,21 @@ def test_mixed_ticks_counter_counts_a_step_with_a_chunk(engine):
     plan = TickPlan()  # a decode-only tick: nothing to overlap, nothing counted
     plan.decode = {"a": (ta, GREEDY)}
     plan.steps = {"a": 1}
+    plan.budgets = {"a": 9}
     res = execute_tick(engine, plan)
     assert _mixed() == before
-    plan = TickPlan()  # a prefill-only tick: neither
     plan.prefills = [_chunk("b", _prompt(19, 2))]
-    execute_tick(engine, plan)
-    assert _mixed() == before
-    plan = TickPlan()  # a step AND a chunk, the chunk enqueued before the read
-    plan.decode = {"a": (_tok(res.decode_results["a"]), GREEDY)}
-    plan.steps = {"a": 2}
+    plan.decode = plan.steps = plan.budgets = {}  # a prefill-only tick: neither
+    res = execute_tick(engine, plan, follows=res)  # (it reads a's step: late)
+    assert _mixed() == before and not res.decode_results
+    plan = TickPlan()  # a step AND a chunk, the step enqueued behind the chunk
+    plan.decode = {"a": (ta, GREEDY)}
+    plan.steps = {"a": 1}
+    plan.budgets = {"a": 9}
     plan.prefills = [_chunk("c", _prompt(13, 3))]
-    execute_tick(engine, plan)
+    res = execute_tick(engine, plan, follows=res)
     assert _mixed() == {"yes": before["yes"] + 1, "no": before["no"]}
+    assert set(res.decode_results) == {"a"}  # the held token, at its next ask
 
 
 @pytest.mark.parametrize("seed", [0, 7, 2**31 - 1, 2**31 + 7, 2**32 - 1, 2**32 + 3, 2**40 + 1])

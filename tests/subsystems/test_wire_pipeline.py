@@ -320,10 +320,14 @@ def test_stream_reopen_resends_encoded_frame_with_original_seq(tiny_llama_dir):
 
 
 def test_execute_tick_launches_every_chunk_before_the_decode_read():
-    """The order inside a tick (sched/step.py): the step is LAUNCHED, every
-    chunk launched and a completed prompt's adoption enqueued, THEN the
-    decode read; its results leave through on_decode before any first
-    token is read."""
+    """The order inside a tick (sched/step.py), with step n in flight from
+    the tick before: every chunk launched and a completed prompt's adoption
+    enqueued, then step n+1 LAUNCHED, chained to step n; only THEN step n
+    read, its results leaving through on_decode before any first token is
+    read.  And a tick whose chunk runs on past it launches no step behind
+    a step in flight."""
+    import dataclasses
+
     import numpy as np
 
     from dnet_tpu.core.types import DecodingParams
@@ -346,34 +350,56 @@ def test_execute_tick_launches_every_chunk_before_the_decode_read():
 
     def tracked(name, fn, what=lambda *a: a[0]):
         def call(*a, **k):
-            order.append((name, what(*a)))
+            order.append((name, what(*a, **k)))
             return fn(*a, **k)
 
         return call
 
     eng = FakeStepEngine()
     eng.occupy("dec", committed=4, blocks=1)
-    eng.decode_launch = tracked("decode_launch", eng.decode_launch, lambda r, **k: sorted(r))
-    eng.decode_read = tracked("decode_read", eng.decode_read, lambda f: sorted(f.order))
+    eng.decode_launch = tracked(
+        "decode_launch", eng.decode_launch,
+        lambda r, budgets=None, chain=None: (sorted(r), sorted(chain.order)),
+    )
+    eng.decode_read = tracked("decode_read", eng.decode_read, lambda f, **k: sorted(f.order))
     eng.prefill_chunk = tracked("prefill", eng.prefill_chunk)
     eng.adopt_prefilled = tracked("adopt", lambda n, logits, dec: OnDevice(n))
     plan = TickPlan()
     plan.decode = {"dec": (42, DecodingParams())}
     plan.steps = {"dec": 3}
+    plan.budgets = {"dec": 9}
+    first = execute_tick(eng, plan)  # nothing in flight yet: launch, no read
+    assert order == [("decode_launch", (["dec"], []))] and not first.decode_results
+    del order[:]
     plan.prefills = [_chunk("new", last=False), _chunk("done")]
     res = execute_tick(
-        eng, plan, on_decode=lambda n, s: order.append(("on_decode", n))
+        eng, plan, on_decode=lambda n, s: order.append(("on_decode", n)),
+        follows=first,
     )
     assert order == [
-        ("decode_launch", ["dec"]),
         ("prefill", "new"),
         ("prefill", "done"),
         ("adopt", "done"),
+        ("decode_launch", (["dec"], ["dec"])),
         ("decode_read", ["dec"]),
         ("on_decode", "dec"),
         ("first_token_read", "done"),
     ]
+    assert res.flight.chained == {"dec"} and res.flight is not first.flight
     assert res.dispatched == ["dec"]
+    # a tick that leaves a chunk running behind the step in flight (it
+    # adopts nothing, so it does not wait the chunk out) launches no step:
+    # the chunk is what the device runs across the host's turn
+    del order[:]
+    plan.prefills = [_chunk("long", last=False)]
+    held = execute_tick(eng, plan, follows=res)
+    assert order == [("prefill", "long"), ("decode_read", ["dec"])]
+    assert held.flight is None and set(held.decode_results) == {"dec"}
+    del order[:]
+    plan.prefills = [dataclasses.replace(plan.prefills[0], first=False)]
+    after = execute_tick(eng, plan, follows=held)  # none in flight: chunk, then the step
+    assert order == [("prefill", "long"), ("decode_launch", (["dec"], []))]
+    assert list(after.flight.order) == ["dec"] and not after.decode_results
     assert "dec" in res.decode_results  # still in the barriered result too
     assert isinstance(res.adopted["done"], np.ndarray)  # host data only
 
