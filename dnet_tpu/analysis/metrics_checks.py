@@ -533,7 +533,7 @@ def check_attribution_labels(errors: list) -> int:
         n += _cross_check_labels(
             errors, text, fam, "kind", KV_KINDS, "obs.phases.KV_KINDS"
         )
-    for fam in ("dnet_retention_tokens_total", "dnet_gdn_tokens_total"):
+    for fam in ("dnet_retention_tokens_total", "dnet_gdn_tokens_total", "dnet_mla_tokens_total"):
         n += _cross_check_labels(
             errors, text, fam, "phase", RETENTION_PHASES,
             "obs.phases.RETENTION_PHASES",

@@ -84,6 +84,9 @@ _DECODE_BUFFER_DROPPED = metric("dnet_decode_buffer_dropped_total")
 _DECODE_CHAINED = metric("dnet_decode_chained_lanes_total")
 _DECODE_SURPLUS = metric("dnet_decode_surplus_steps_total")
 _MOE_ASSIGNMENTS = metric("dnet_moe_assignments_total")
+_MLA_TOKENS = metric("dnet_mla_tokens_total")
+_MLA_LATENT_BYTES = metric("dnet_mla_latent_bytes_total")
+_MLA_EXPANDED = metric("dnet_mla_expanded_tokens_total")
 _STATE_SLOTS_USED = metric("dnet_state_slots_used")
 
 
@@ -307,6 +310,14 @@ class BatchedEngine:
                 from dnet_tpu.core.prefix_cache import PrefixCache
 
                 self.eng.prefix_cache = PrefixCache(prefix_size)
+        #: > 0: the pool's entry is latent (models/deepseek_v2.py); the bytes
+        #: of ONE token's entry over every layer BY THE ALGORITHM (the lanes
+        #: the pool pads it with are not in it), what dnet_mla_latent_bytes
+        #: counts in
+        self._latent_entry_bytes = (
+            m.latent_dim * jnp.dtype(self.eng.kv_dtype).itemsize * len(m.layers)
+            if getattr(self.kv_store, "latent_rank", 0) else 0
+        )
         self.kv = (
             None
             if self.kv_store is not None
@@ -626,8 +637,11 @@ class BatchedEngine:
 
             def attend_fn(q, k_new, v_new, kvs, kind=None, layer=None, gate=None):
                 # the model names the layer's index within its kind (and
-                # the kind, where it has two); the pool is closed over
-                rows = {} if k_new is None else {"k": k_new[:, 0], "v": v_new[:, 0]}
+                # the kind, where it has two); the pool is closed over.  A
+                # token's new rows go by the pool's leaves: keys and values,
+                # or a latent model's ONE entry (v_new None)
+                new = [a[:, 0] for a in (k_new, v_new) if a is not None]
+                rows = dict(zip(getattr(store, "leaves", ("k", "v")), new))
                 if store.in_place:
                     # the state kind: `kvs` is the stack the scan carries,
                     # and the step is the read AND the write, for the
@@ -908,6 +922,13 @@ class BatchedEngine:
             # hands it on; nothing of it to admit, the lane is already held
             _, state_tokens = self.kv_store.state_counters
             state_tokens.labels(phase="prefill").inc(len(ids))
+        if self._latent_entry_bytes:
+            # a latent model's chunk at position p attends keys and values
+            # expanded from the row's latents [0, p + T), in every layer
+            sess = self.eng.sessions.get(nonce)
+            upto = (0 if sess is None else int(sess.pos)) + len(ids)
+            _MLA_TOKENS.labels(phase="prefill").inc(len(ids))
+            _MLA_EXPANDED.inc(upto * len(self.model.layers))
         return self.eng.prefill(nonce, list(ids), seed, allow_store=False)
 
     def abandon_prefill(self, nonce) -> None:
@@ -1313,12 +1334,14 @@ class BatchedEngine:
         with span(SPAN_DECODE_UNPACK):
             now = time.time()
             out = flight.out
-            delivered = surplus = 0
+            delivered = surplus = live = 0
             for nonce, slot in flight.order.items():
                 if self.slot_of.get(nonce) != slot:
                     # the lane left with its step in flight
                     surplus += nonce in flight.chained
                     continue
+                # the entries its R steps attended: pos, pos + 1, ...
+                live += R * int(self.pos[slot]) + R * (R - 1) // 2
                 self.pos[slot] += R
                 self.last_used[slot] = now
                 if R > 1:
@@ -1346,6 +1369,11 @@ class BatchedEngine:
         _DECODE_TOKENS.labels(source="dispatch").inc(delivered)
         _DECODE_CHAINED.inc(len(flight.chained))
         _DECODE_SURPLUS.inc(surplus)
+        if self._latent_entry_bytes:
+            # what the algorithm reads: every live token's ONE entry, once a
+            # layer a step, from positions the host already has (no sync)
+            _MLA_LATENT_BYTES.inc(live * self._latent_entry_bytes)
+            _MLA_TOKENS.labels(phase="decode").inc(R * lanes)
         if self.kv_store is not None and self.kv_store.in_place:
             # what the algorithm needs: each active lane's entry read and
             # written once a step, in every layer

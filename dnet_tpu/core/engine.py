@@ -127,6 +127,9 @@ class LocalEngine:
         model_cls = get_ring_model_cls(self.config.model_type)
         all_layers = list(range(self.config.num_hidden_layers))
         self.model = model_cls(self.config, layers if layers is not None else all_layers)
+        # a subclass that shards over a mesh (parallel/shard_mesh.py) built
+        # its mesh first: the model's cache then shards by kv head
+        self.model.on_mesh = getattr(self, "mesh", None) is not None
         self.batch = batch
         self.max_seq = max_seq
         self.param_dtype = jnp.dtype(param_dtype)

@@ -2,10 +2,15 @@
 the jitted programs that read and write them.
 
 ONE pool layout: a kind's layers share `[L_kind, N_blocks, block_tokens,
-KVH*Hd]` leaves `{"k", "v"}`, the heads merged into the lane dimension once,
-at construction: the layout the ragged kernel reads (it takes the layer by
+heads*dim]` leaves, the heads merged into the lane dimension once, at
+construction: the layout the ragged kernel reads (it takes the layer by
 index), so nothing slices, reshapes or relayouts a pool per step or per
-prompt.  (A quantised cache is never paged: `core/batch.py: kv_layout` sends
+prompt.  WHICH leaves and how wide comes from the model
+(`RingModel.pool_leaves`): keys and values `{"k", "v"}` of `KVH*Hd` for
+every model but one whose cache is latent (models/deepseek_v2.py), which
+keeps ONE leaf `{"c"}` a token, `[c | k_pe]` in zero-padded lanes up to a
+multiple of 128, whatever its heads, attended absorbed (paged_attend_latent).  (A quantised
+cache is never paged: `core/batch.py: kv_layout` sends
 it to dense slots, so no pool carries scale leaves.)
 
 The decode path (core/batch.py `_build_ragged`) attends the pool IN PLACE
@@ -194,9 +199,15 @@ class KindStore:
         if set(self.layers) != set(cfgs):
             raise ValueError(f"pool kinds {sorted(cfgs)} != layer kinds {sorted(self.layers)}")
         self.window = int(model.window) if KV_KIND_WINDOW in self.layers else 0
-        c = model.config
-        heads = (c.num_key_value_heads, c.head_dim)
-        width = heads[0] * heads[1]
+        #: a token's entry: {leaf: (heads, dim)}, the model's word
+        #: (RingModel.pool_leaves): "k", "v" of (KVH, Hd), or one latent "c"
+        from dnet_tpu.models.base import RingModel
+
+        # (a stand-in that is no RingModel gets the default: k and v of KVH x Hd)
+        own = getattr(model, "pool_leaves", None)
+        self.leaves = leaves = dict(own() if own else RingModel.pool_leaves(model))
+        #: > 0: the entry is latent, its first `latent_rank` lanes the value
+        self.latent_rank = int(getattr(model, "latent_rank", 0))
         if session_tokens:
             # blocks are cut out of a staged SESSION row by absolute
             # position: the rows the engines gather into and commit from
@@ -205,18 +216,18 @@ class KindStore:
             # a cache laid out by kind would commit the wrong rows silently
             n = len(by_layer)
             probe = jax.eval_shape(lambda: model.init_kv(n, 1, session_tokens, kv_dtype))
-            want = (n, 1, session_tokens) + heads
+            want = {leaf: (n, 1, session_tokens) + hd for leaf, hd in leaves.items()}
             shapes = jax.tree.map(jnp.shape, probe)
-            if shapes != {"k": want, "v": want}:
+            if shapes != want:
                 raise NotImplementedError(
-                    f"paged KV needs slot-addressed session caches, k and v of {want}; "
+                    f"paged KV needs slot-addressed session caches, {want}; "
                     f"got {shapes} (rotating ring buffers and per-kind layouts stay dense)"
                 )
         dt = jnp.dtype(kv_dtype)
         self.kv = {
             kind: {
-                leaf: jnp.zeros((len(idx), cfgs[kind].pool_blocks, bt, width), dt)
-                for leaf in ("k", "v")
+                leaf: jnp.zeros((len(idx), cfgs[kind].pool_blocks, bt, h * d), dt)
+                for leaf, (h, d) in leaves.items()
             }
             for kind, idx in self.layers.items()
         }
@@ -242,11 +253,11 @@ class KindStore:
             """The full kind's blocks ids [nb] -> one dense row
             [L, 1, nb*bt, KVH, Hd]: the heads split on the gathered row."""
 
-            def one(p):
+            def one(p, heads):
                 g = p[:, ids]  # [L, nb, bt, W]
                 return g.reshape(g.shape[0], 1, g.shape[1] * bt, *heads)
 
-            return jax.tree.map(one, pool[KV_KIND_FULL])
+            return {leaf: one(p, leaves[leaf]) for leaf, p in pool[KV_KIND_FULL].items()}
 
         # instrumented: a page-table geometry leak re-tracing these per
         # call shows as climbing dnet_jit_compiles_total{fn=kv_*}
@@ -265,7 +276,15 @@ class KindStore:
         the kernel takes the layer out of the kind's stack itself (`kvs`
         goes unread: the pool is never sliced).  Each kind's custom call
         has a name of its own (the trace tells them apart)."""
-        from dnet_tpu.ops.paged_attention import paged_attend
+        from dnet_tpu.ops.paged_attention import paged_attend, paged_attend_latent
+
+        if self.latent_rank:
+            # ONE entry a token, shared by the heads: read once by the
+            # absorbed kernel (the model's dnet.attn.latent scope is around)
+            return paged_attend_latent(
+                q, pool[KV_KIND_FULL]["c"], tables[KV_KIND_FULL], pos, rows["c"],
+                self.latent_rank, layer, impl=impl,
+            )
 
         def of(name, scope, **kw):
             @jax.named_scope(scope)

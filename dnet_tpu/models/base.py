@@ -64,6 +64,12 @@ class ModelConfig:
     def from_hf(cls, d: Dict[str, Any]) -> "ModelConfig":
         heads = d["num_attention_heads"]
         head_dim = d.get("head_dim") or d["hidden_size"] // heads
+        # newer configs (mistral4) keep theta and the scaling in ONE group,
+        # `rope_parameters`; it is read where a config has no `rope_scaling`
+        rope = d.get("rope_parameters") or {}
+        scaling = d.get("rope_scaling")
+        if scaling is None and rope.get("rope_type", rope.get("type", "default")) != "default":
+            scaling = rope
         return cls(
             model_type=d["model_type"],
             vocab_size=d["vocab_size"],
@@ -75,8 +81,8 @@ class ModelConfig:
             head_dim=head_dim,
             # cohere2 names it layer_norm_eps and ships rms_norm_eps: null
             rms_norm_eps=d.get("rms_norm_eps") or d.get("layer_norm_eps") or 1e-5,
-            rope_theta=d.get("rope_theta", 10000.0),
-            rope_scaling=d.get("rope_scaling"),
+            rope_theta=d.get("rope_theta") or rope.get("rope_theta", 10000.0),
+            rope_scaling=scaling,
             tie_word_embeddings=d.get("tie_word_embeddings", False),
             max_position_embeddings=d.get("max_position_embeddings", 8192),
             attention_bias=d.get("attention_bias", False),
@@ -114,9 +120,18 @@ class RingModel(abc.ABC):
     # the layer's index; a model whose layers are of two kinds
     # (cohere2_moe: window and full) sets `paged_kinds` and also passes
     # kind=, with layer= the index within the kind.  Models with bespoke
-    # attention layouts (gpt_oss paired SWA rings, deepseek MLA) keep the
-    # dense-gather decode path.
+    # attention layouts (gpt_oss paired SWA rings) keep the dense-gather
+    # decode path.
     supports_paged_attend: bool = False
+    # > 0: a token's cache entry is ONE latent row shared by every head
+    # (multi-head latent attention, models/deepseek_v2.py), whose first
+    # `latent_rank` lanes are also the value: the pool attends it absorbed
+    # (ops/paged_attention.py paged_attend_latent)
+    latent_rank: int = 0
+    # set by the engines that shard a model over a mesh (tp / sp / pp):
+    # their caches shard by kv head (parallel/mesh.py kv_spec), which a
+    # latent entry has none of
+    on_mesh: bool = False
     # per local layer its kind (obs/phases.py KV_KINDS): `full` keeps
     # everything, a `window` layer's page table gives back the blocks
     # behind the window; None = all full
@@ -238,6 +253,15 @@ class RingModel(abc.ABC):
         return out
 
     # ---- cache construction ------------------------------------------
+    def pool_leaves(self) -> Dict[str, Tuple[int, int]]:
+        """The leaves of a token's entry in the block pool (kv/store.py
+        KindStore), each as (heads, dim): the pool keeps a leaf with
+        `heads * dim` lanes, and a staged session row keeps it as
+        [L, 1, S, heads, dim].  Default: keys and values of KVH x Hd."""
+        c = self.config
+        heads = (c.num_key_value_heads, c.head_dim)
+        return {"k": heads, "v": heads}
+
     def kv_config(
         self,
         n_layers: int,

@@ -178,6 +178,28 @@ def _register_core(reg: MetricsRegistry) -> None:
     )
     for phase in RETENTION_PHASES:
         gdn_fam.labels(phase=phase)
+    # a latent cache (multi-head latent attention, models/deepseek_v2.py):
+    # the pool's books are the `full` kind's; these count what crosses it
+    mla_fam = reg.counter(
+        "dnet_mla_tokens_total",
+        "Tokens that went through latent-attention layers on the served "
+        "path, by the program that carried them (a prefill chunk's real "
+        "tokens; a decode dispatch's lanes x steps)",
+        labelnames=("phase",),
+    )
+    for phase in RETENTION_PHASES:
+        mla_fam.labels(phase=phase)
+    reg.counter(
+        "dnet_mla_latent_bytes_total",
+        "Bytes of latent cache entries the batched decode dispatches' "
+        "absorbed attention reads, by the algorithm: live tokens of the "
+        "active lanes x layers x one entry",
+    )
+    reg.counter(
+        "dnet_mla_expanded_tokens_total",
+        "Latent entries the prefill chunks expanded to per-head keys and "
+        "values: position + chunk tokens, a chunk a layer",
+    )
     for name, help_text in (
         ("dnet_kv_blocks_used",
          "Paged KV pool blocks currently allocated (refcount >= 1), by the "

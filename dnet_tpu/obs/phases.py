@@ -133,9 +133,15 @@ SCOPE_MOE_SHARED = "dnet.moe.shared"
 # (models/qwen3_next.py: the delta rule and its convolution here, the
 # softmax layers under dnet.attn.full): the state's read, decay and update
 SCOPE_ATTN_STATE = "dnet.attn.state"
+# inside dnet.attn of a model whose cache entry is ONE latent row a token
+# (multi-head latent attention, models/deepseek_v2.py): the absorb, the
+# attention over the latents (the decode kernel paged_attend_latent; a
+# prefill chunk's expansion and its flash kernel) and the un-absorb
+SCOPE_ATTN_LATENT = "dnet.attn.latent"
 DEVICE_SCOPES = (
     SCOPE_SAMPLE, SCOPE_LM_HEAD, SCOPE_MOE, SCOPE_ATTN,
     SCOPE_ATTN_WINDOW, SCOPE_ATTN_FULL, SCOPE_MOE_SHARED, SCOPE_ATTN_STATE,
+    SCOPE_ATTN_LATENT,
 )
 
 # dnet_kv_blocks_used / _free / dnet_kv_pool_blocks {kind=}: the paged pool
@@ -155,10 +161,11 @@ KV_KINDS = (KV_KIND_FULL, KV_KIND_WINDOW)
 # and the `full` kind's blocks for the same sequence.
 KV_KIND_STATE = "state"
 
-# dnet_retention_tokens_total{phase=} / dnet_gdn_tokens_total{phase=}:
-# tokens that went through a state layer's op (power retention; the gated
-# delta rule), by the program that carried them (a prefill chunk's real
-# tokens; a decode dispatch's lanes x steps)
+# dnet_retention_tokens_total{phase=} / dnet_gdn_tokens_total{phase=} /
+# dnet_mla_tokens_total{phase=}: tokens that went through a state layer's
+# op (power retention; the gated delta rule) or a latent-attention layer,
+# by the program that carried them (a prefill chunk's real tokens; a decode
+# dispatch's lanes x steps)
 RETENTION_PHASES = ("prefill", "decode")
 
 # dnet_moe_assignments_total{held=}: (token, chosen expert) pairs of the

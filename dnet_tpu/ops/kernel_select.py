@@ -1,8 +1,9 @@
 """Which implementation each Pallas dispatcher picked, counted per process.
 
-Nine dispatchers choose between a Pallas kernel and a jnp path at trace
+Ten dispatchers choose between a Pallas kernel and a jnp path at trace
 time: causal prefill (`ops/flash_attention.py`), split-K decode
-(`ops/flash_decode.py`), ragged paged attend (`ops/paged_attention.py`), the
+(`ops/flash_decode.py`), ragged paged attend and its absorbed twin over
+latent entries (`ops/paged_attention.py`), the
 two hop-codec kernels (`compression/ops.py`), power retention's decode
 step and prefill chunk (`ops/retention.py`) and the gated delta rule's
 (`ops/gated_delta.py`).  The backend half of
@@ -24,11 +25,12 @@ import jax
 
 #: what a dispatcher can resolve to
 IMPLS = ("pallas", "interpret", "emulate", "dense")
-#: the nine dispatchers, by the name `/health` reports them under
+#: the ten dispatchers, by the name `/health` reports them under
 KERNELS = (
     "flash_prefill",
     "flash_decode",
     "paged_attend",
+    "paged_attend_latent",
     "column_norms",
     "column_select",
     "retention_step",

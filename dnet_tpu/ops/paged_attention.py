@@ -34,10 +34,20 @@ Three implementations behind one dispatcher (`paged_attend`):
   cache, so greedy streams stay byte-identical, fused into the step
   program.
 
+A model whose cache entry is ONE latent row a token (multi-head latent
+attention, models/deepseek_v2.py) is attended ABSORBED by a kernel of its
+own, `paged_attend_latent`: every query head scores against the same entry
+`[c | k_pe]`, whose first `rank` lanes are also the value, so a block is
+read from HBM ONCE and used for both (passing the latent to `paged_attend`
+as keys and again as values would store and read it twice).  Same grid,
+same clamping of dead table entries, same fold of the current token at the
+emit step, same three implementations behind its dispatcher.
+
 The caller (core/batch.py: kv_layout) owns eligibility via
-`ragged_refusal`: the llama-family attention stack
-(supports_paged_attend) and unquantized pool leaves.  Everything else
-serves dense slots.
+`ragged_refusal`: a model whose attention threads the `attend_fn` hook
+(supports_paged_attend: the llama family, cohere2_moe's two kinds, the
+hybrid qwen3_next, the latent deepseek_v2 / mistral4) and unquantized pool
+leaves.  Everything else serves dense slots.
 """
 
 from __future__ import annotations
@@ -59,6 +69,7 @@ PAGED_IMPLS = ("pallas", "interpret", "emulate")
 #: the custom calls' names in a device trace, by the layer's kind
 PAGED_NAME = "paged_attend"
 PAGED_WINDOW_NAME = "paged_attend_window"
+PAGED_LATENT_NAME = "paged_attend_latent"
 
 
 def paged_attend_impl() -> str:
@@ -75,8 +86,9 @@ def ragged_refusal(model, kv_quant_bits: int) -> Optional[str]:
     (core/batch.py: kv_layout)."""
     if not getattr(model, "supports_paged_attend", False):
         return (
-            f"{model.config.model_type} attention stack has no paged-attend "
-            "hook"
+            f"{model.config.model_type}: no paged-attend hook (its attention "
+            "does not thread apply_window's attend_fn), so decode cannot read "
+            "the pool in place"
         )
     if kv_quant_bits:
         return (
@@ -358,3 +370,199 @@ def paged_attend(
         G=G, scale=scale, bt=bt, interpret=(impl == "interpret"),
         window=int(window),
     )
+
+
+# ---- latent entries: absorbed multi-head latent attention -------------------
+
+
+def _latent_kernel(tbl_ref, pos_ref, layer_ref, q_ref, *refs, bt: int, nb: int,
+                   rank: int, n_sub: int):
+    """One (slot, `n_sub` table entries) fold of the online softmax, every
+    head against the SAME latent entries.
+
+    q_ref [1, H, W] the absorbed queries `[W_kvb[K]^T q_nope | q_pe]`, the
+    softmax scale (and a position-dependent one) already folded in;
+    refs: `n_sub` views of the pool, each one block [1, 1, bt, W] of
+    entries `[c | k_pe]` (consecutive table entries: a grid step costs the
+    same whatever it moves, and one block of 128 entries is a tenth of a
+    microsecond of HBM), then cn_ref [1, 1, W] the current token's entry,
+    o_ref [1, H, rank] and the scratch m, l [H, 1], acc [H, rank] float32.
+    A block scores through its whole width and is the value through its
+    first `rank` lanes: read once, used twice.  Dots take the pool's type
+    with float32 accumulation (a float32 pool multiplies at `highest`)."""
+    import jax.experimental.pallas as pl
+
+    c_refs, (cn_ref, o_ref, m_ref, l_ref, acc_ref) = refs[:n_sub], refs[n_sub:]
+    b = pl.program_id(0)
+    i = pl.program_id(1)
+    live = pos_ref[b]
+
+    @pl.when(i == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(i * n_sub * bt < live)
+    def _fold():
+        # ONE softmax update for the step's `n_sub` blocks: their score and
+        # value products do not depend on each other (a chain of per-block
+        # updates keeps one matrix unit busy loading 128-row tiles for 32
+        # query rows: 0.68 us a block measured, PERF.md section 6, PR 41)
+        q = q_ref[0]
+        blks = [c_ref[0, 0] for c_ref in c_refs]  # [bt, W] each
+        q = q.astype(blks[0].dtype)  # [H, W]
+        exact = lax.Precision.HIGHEST if blks[0].dtype == jnp.float32 else None
+        scores = jnp.concatenate(
+            [
+                lax.dot_general(
+                    q, blk, (((1,), (1,)), ((), ())), precision=exact,
+                    preferred_element_type=jnp.float32,
+                )
+                for blk in blks
+            ],
+            axis=1,
+        )  # [H, n_sub * bt]
+        # the last live block is partly full, and blocks past it are
+        # clamped repeats: rows at or past `live` must not score
+        slot = i * n_sub * bt + lax.broadcasted_iota(jnp.int32, (1, n_sub * bt), 1)
+        scores = jnp.where(slot < live, scores, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+        p = jnp.exp(scores - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        pv = sum(
+            lax.dot_general(
+                p[:, j * bt:(j + 1) * bt].astype(blk.dtype), blk[:, :rank],
+                (((1,), (0,)), ((), ())), precision=exact,
+                preferred_element_type=jnp.float32,
+            )
+            for j, blk in enumerate(blks)
+        )  # [H, rank]
+        acc_ref[...] = acc_ref[...] * corr + pv
+        m_ref[...] = m_new
+
+    @pl.when(i == nb // n_sub - 1)
+    def _emit():
+        # the CURRENT token's entry (position == live, always attended)
+        # reaches the pool only after the launch: folded here
+        q = q_ref[0].astype(jnp.float32)  # [H, W]
+        cn = cn_ref[0].astype(jnp.float32)  # [1, W]
+        s_new = jnp.sum(q * cn, axis=1, keepdims=True)  # [H, 1]
+        m_fin = jnp.maximum(m_ref[...], s_new)
+        corr = jnp.exp(m_ref[...] - m_fin)
+        p_new = jnp.exp(s_new - m_fin)
+        l_fin = l_ref[...] * corr + p_new
+        acc_fin = acc_ref[...] * corr + p_new * cn[:, :rank]
+        o_ref[0] = (acc_fin / jnp.maximum(l_fin, 1e-30)).astype(o_ref.dtype)
+
+
+#: table entries one grid step of the latent kernel folds at most
+LATENT_SUB_BLOCKS = 16
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "bt", "interpret"))
+def _latent_pallas(q, pool, tables, pos, c_new, layer, *, rank: int, bt: int,
+                   interpret: bool):
+    """q [B, H, W]; pool [L, N, bt, W] (a kind's whole stack: the index map
+    takes the layer); tables [B, nb]; pos [B]; c_new [B, 1, W]; layer [1]."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, H, W = q.shape
+    nb = tables.shape[1]
+    n_sub = next(n for n in (LATENT_SUB_BLOCKS, 4, 2, 1) if nb % n == 0)
+
+    def sub_map(j):
+        def c_map(b, i, tbl, pos, layer):
+            """Entries past a slot's live length clamp to its last live
+            block: the pipeline re-fetches (elides) one block instead of
+            streaming dead ones."""
+            hi = jnp.clip((pos[b] - 1) // bt, 0, nb - 1)
+            return (layer[0], tbl[b, jnp.minimum(i * n_sub + j, hi)], 0, 0)
+
+        return c_map
+
+    def whole3(b, i, tbl, pos, layer):
+        return (b, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, nb // n_sub),
+        in_specs=[pl.BlockSpec((1, H, W), whole3)]
+        + [pl.BlockSpec((1, 1, bt, W), sub_map(j)) for j in range(n_sub)]
+        + [pl.BlockSpec((1, 1, W), whole3)],
+        out_specs=pl.BlockSpec((1, H, rank), whole3),
+        scratch_shapes=[
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, rank), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _latent_kernel, bt=bt, nb=nb, rank=rank, n_sub=n_sub
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, rank), q.dtype),
+        interpret=interpret,
+        name=PAGED_LATENT_NAME,
+    )(tables, pos, layer, q, *([pool] * n_sub), c_new)
+
+
+def _latent_emulate(q, pool, tables, pos, c_new, rank: int):
+    """Plain-jnp twin: gather each slot's blocks to a contiguous view,
+    write the new entry at `pos` and attend through the shared dense
+    `attend` with ONE kv head whose value is the entry's first `rank`
+    lanes.  pool [N, bt, W] (one layer's)."""
+    from dnet_tpu.ops.attention import attend
+
+    B, H, W = q.shape
+    nb, bt = tables.shape[1], pool.shape[1]
+    S = nb * bt
+    view = pool[tables].reshape(B, S, 1, W)
+    view = jax.vmap(
+        lambda c, r, p: jax.lax.dynamic_update_slice(c, r[None], (p, 0, 0))
+    )(view, c_new.astype(view.dtype), pos)
+    mask = jnp.arange(S)[None, :] <= pos[:, None]
+    return attend(
+        q[:, None], view, view[..., :rank], mask=mask[:, None, :], scale=1.0
+    )[:, 0]
+
+
+def paged_attend_latent(
+    q: jnp.ndarray,
+    pool: jnp.ndarray,
+    tables: jnp.ndarray,
+    pos: jnp.ndarray,
+    c_new: jnp.ndarray,
+    rank: int,
+    layer,
+    impl: str = "emulate",
+) -> jnp.ndarray:
+    """Single-token ABSORBED latent attention against the block pool, in
+    place.  q [B, 1, H, W]: each head's absorbed query `[W_kvb[K]^T q_nope
+    | q_pe]` with every scale folded in (the kernel scores as is); pool
+    [L, N_blocks, bt, W] the latent kind's whole stack of entries `[c |
+    k_pe]`, `layer` (traced) the one to read; tables [B, nb] int32; pos
+    [B] live pool rows per slot; c_new [B, 1, W] the current token's entry
+    (position == pos: attended in the launch, appended by the caller
+    afterwards).  Returns o_lat [B, 1, H, rank] = sum_j softmax_j(q .
+    entry_j) entry_j[:rank]: the caller un-absorbs it through W_kvb[V]."""
+    if impl not in PAGED_IMPLS:
+        raise ValueError(f"paged_attend_latent impl {impl!r} not in {PAGED_IMPLS}")
+    SELECTIONS.record(PAGED_LATENT_NAME, impl)
+    tables = tables.astype(jnp.int32)
+    pos = pos.astype(jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    if impl == "emulate":
+        one = lax.dynamic_index_in_dim(pool, layer[0], 0, keepdims=False)
+        out = _latent_emulate(q[:, 0], one, tables, pos, c_new, rank)
+    else:
+        out = _latent_pallas(
+            q[:, 0], pool, tables, pos, c_new, layer, rank=int(rank),
+            bt=pool.shape[2], interpret=(impl == "interpret"),
+        )
+    return out[:, None]

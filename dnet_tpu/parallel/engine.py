@@ -96,6 +96,7 @@ class MeshEngine:
         self.config = ModelConfig.from_hf(self.ckpt.config)
         model_cls = get_ring_model_cls(self.config.model_type)
         self.model = model_cls(self.config, range(self.config.num_hidden_layers))
+        self.model.on_mesh = True  # the cache shards by kv head (mesh.py kv_spec)
         L = self.config.num_hidden_layers
         # segmented models zero-pad their stacks to pp divisibility — per
         # segment for multi-lap rings (ring_phases > 1), chunk-aligned for
@@ -187,6 +188,7 @@ class MeshEngine:
         self.config = config
         model_cls = get_ring_model_cls(config.model_type)
         self.model = model_cls(config, range(config.num_hidden_layers))
+        self.model.on_mesh = True
         L = config.num_hidden_layers
         segmented = (
             getattr(self.model, "ring_phases", 1) > 1

@@ -562,3 +562,31 @@ def make_tiny_qwen3_next(model_dir: str | Path, seed: int = 2**31 + 37, **over) 
     cfg = tiny_qwen3_next_config(**over)
     write_checkpoint(Path(model_dir), cfg, seed=seed, dtype="float32")
     return cfg
+
+
+def tiny_mistral4_config(**over) -> dict:
+    """The benchmark configuration `mistral-small-4-119b-6l-ep8` at its
+    rehearsal size (hidden 64, 4 heads of 16 + 8 over a latent of 16, two
+    layers, 4 experts held of 8, top-2, a(t)'s period 32): the HF keys
+    alone."""
+    import json
+
+    root = Path(__file__).resolve().parents[2]
+    full = json.loads(
+        (root / "benchmarks/configs/mistral-small-4-119b-6l-ep8.json").read_text()
+    )
+    cfg = {k: v for k, v in full.items()
+           if k not in ("assumed", "deployment", "serve", "check", "rehearse")}
+    cfg.update(full["rehearse"]["config"])
+    cfg.update(over)
+    return cfg
+
+
+def make_tiny_mistral4(model_dir: str | Path, seed: int = 2**31 + 41, **over) -> dict:
+    """A seeded float32 mistral4 checkpoint, written as the benchmark
+    writes its own (tensor names from benchmarks/reference/mistral4.py)."""
+    from benchmarks.harness.weights import write_checkpoint
+
+    cfg = tiny_mistral4_config(**over)
+    write_checkpoint(Path(model_dir), cfg, seed=seed, dtype="float32")
+    return cfg

@@ -212,16 +212,26 @@ def test_budget_chunks_match_serial_steps(tiny_llama_dir):
 
 
 def test_deepseek_accepted_at_load(tmp_path_factory):
-    """DeepSeek-V2 now gates its KV writes (supports_kv_commit), so the
-    batched engine must accept it (full behavior covered by
-    tests/test_deepseek_mesh_batch.py)."""
+    """DeepSeek-V2 gates its KV writes (supports_kv_commit) and threads the
+    paged-attend hook: the batched engine accepts it and serves it from the
+    block pool, whose entry is the LATENT (one leaf, kv_lora_rank +
+    qk_rope_head_dim kept in 128 lanes), not the heads' keys and values
+    (full behavior covered by tests/test_deepseek_mesh_batch.py and, for
+    the block, tests/test_mistral4_parity.py)."""
     from tests.fakes.checkpoints import make_tiny_deepseek_v2
-    from dnet_tpu.core.batch import BatchedEngine
+    from dnet_tpu.core.batch import KV_PAGED, BatchedEngine, kv_layout
+    from dnet_tpu.kv import KindStore
 
     d = tmp_path_factory.mktemp("batch_dsv2")
     make_tiny_deepseek_v2(d)
     eng = BatchedEngine(d, slots=2, max_seq=32, param_dtype="float32")
-    assert eng.model.supports_kv_commit
+    assert eng.model.supports_kv_commit and eng.model.supports_paged_attend
+    assert kv_layout(eng.model, 0, 0, 32)[0] == KV_PAGED
+    assert isinstance(eng.kv_store, KindStore) and eng.kv_ragged
+    assert eng.kv_store.leaves == {"c": (1, 128)} and eng.kv_store.latent_rank == 24
+    # a quantised cache is the expanded one, on dense slots
+    assert kv_layout(eng.model, 8, 0, 32)[0] == "dense"
+    eng.close()
 
 
 def test_logit_bias_per_lane(tiny_llama_dir):
