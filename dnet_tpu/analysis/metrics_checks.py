@@ -546,6 +546,21 @@ def check_attribution_labels(errors: list) -> int:
         errors, text, "dnet_moe_expert_rows_total", "path", MOE_PATHS,
         "obs.phases.MOE_PATHS",
     )
+    from dnet_tpu.obs.phases import FLASH_TILE_STATES
+
+    # two labels: the layer's kind x what the flash kernel makes of a tile
+    want = {
+        f'dnet_flash_tiles_total{{kind="{k}",state="{s}"}}'
+        for k in KV_KINDS for s in FLASH_TILE_STATES
+    }
+    n += len(want)
+    for series in sorted(want ^ set(re.findall(r"dnet_flash_tiles_total\{[^}]*\}", text))):
+        errors.append(
+            f"attribution: {series} is "
+            + ("not exposed (pre-touch it in dnet_tpu.obs._register_core)"
+               if series in want
+               else "exposed, not declared in obs.phases KV_KINDS x FLASH_TILE_STATES")
+        )
     from dnet_tpu.obs.phases import DRIVERS_TURN_OUTCOMES, TURN_DEVICE
 
     # the turn-around between two ticks: a histogram by what the device

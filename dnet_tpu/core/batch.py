@@ -28,6 +28,7 @@ top-p's batch together (same property as core/sampler.py's traced scalars).
 from __future__ import annotations
 
 import time
+from collections import Counter
 from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -36,7 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dnet_tpu.core.engine import LocalEngine, count_expert_rows
+from dnet_tpu.core.engine import LocalEngine, bucket_length, count_expert_rows
 from dnet_tpu.core.sampler import (
     MAX_LOGIT_BIAS,
     MAX_TOP_LOGPROBS,
@@ -71,6 +72,7 @@ from dnet_tpu.obs.phases import (
     SPAN_DECODE_READBACK,
     SPAN_DECODE_UNPACK,
 )
+from dnet_tpu.ops.flash_attention import flash_tiles
 from dnet_tpu.utils.logger import get_logger
 
 log = get_logger()
@@ -87,6 +89,7 @@ _MOE_ASSIGNMENTS = metric("dnet_moe_assignments_total")
 _MLA_TOKENS = metric("dnet_mla_tokens_total")
 _MLA_LATENT_BYTES = metric("dnet_mla_latent_bytes_total")
 _MLA_EXPANDED = metric("dnet_mla_expanded_tokens_total")
+_FLASH_TILES = metric("dnet_flash_tiles_total")
 _STATE_SLOTS_USED = metric("dnet_state_slots_used")
 
 
@@ -318,6 +321,9 @@ class BatchedEngine:
             m.latent_dim * jnp.dtype(self.eng.kv_dtype).itemsize * len(m.layers)
             if getattr(self.kv_store, "latent_rank", 0) else 0
         )
+        #: (kind, window) -> how many layers of it a prefill chunk attends
+        #: through the flash kernel (dnet_flash_tiles_total)
+        self._flash_layers = Counter(m.flash_layers())
         self.kv = (
             None
             if self.kv_store is not None
@@ -922,13 +928,21 @@ class BatchedEngine:
             # hands it on; nothing of it to admit, the lane is already held
             _, state_tokens = self.kv_store.state_counters
             state_tokens.labels(phase="prefill").inc(len(ids))
+        sess = self.eng.sessions.get(nonce)
+        pos = 0 if sess is None else int(sess.pos)
         if self._latent_entry_bytes:
             # a latent model's chunk at position p attends keys and values
             # expanded from the row's latents [0, p + T), in every layer
-            sess = self.eng.sessions.get(nonce)
-            upto = (0 if sess is None else int(sess.pos)) + len(ids)
             _MLA_TOKENS.labels(phase="prefill").inc(len(ids))
-            _MLA_EXPANDED.inc(upto * len(self.model.layers))
+            _MLA_EXPANDED.inc((pos + len(ids)) * len(self.model.layers))
+        # the chunk's padded rows against the staged row, as the engine
+        # launches them: what the flash kernel's grid makes of each layer
+        width = min(bucket_length(len(ids)), self.max_seq - pos)
+        for (kind, window), layers in self._flash_layers.items():
+            tiles = flash_tiles(pos, width, self.max_seq, window)
+            if tiles is not None:
+                _FLASH_TILES.labels(kind=kind, state="folded").inc(tiles[0] * layers)
+                _FLASH_TILES.labels(kind=kind, state="skipped").inc(tiles[1] * layers)
         return self.eng.prefill(nonce, list(ids), seed, allow_store=False)
 
     def abandon_prefill(self, nonce) -> None:

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from dnet_tpu.core.kvcache import KVConfig
-from dnet_tpu.obs.phases import SCOPE_LM_HEAD
+from dnet_tpu.obs.phases import KV_KIND_FULL, KV_KIND_STATE, KV_KIND_WINDOW, SCOPE_LM_HEAD
 from dnet_tpu.ops.quant import QUANTIZABLE
 
 
@@ -174,6 +174,18 @@ class RingModel(abc.ABC):
         from dnet_tpu.ops.moe import resolve_moe_impl
 
         return resolve_moe_impl(self.moe_impl, rows, 1, self.moe_grouped)
+
+    def flash_layers(self) -> Tuple[Tuple[str, int], ...]:
+        """(kind, window) of each local layer whose prefill chunk attends
+        its staged row through ops/flash_attention.py flash_attend_causal,
+        asked on the host (dnet_flash_tiles_total is booked from it): a
+        `state` layer keeps no keys, a `window` layer bounds them below."""
+        kinds = self.paged_kinds or (KV_KIND_FULL,) * len(self.layers)
+        return tuple(
+            (k, int(getattr(self, "window", 0)) if k == KV_KIND_WINDOW else 0)
+            for k in kinds
+            if k != KV_KIND_STATE
+        )
 
     # ---- pure compute -------------------------------------------------
     def embed(self, edge_params: dict, tokens: jnp.ndarray) -> jnp.ndarray:

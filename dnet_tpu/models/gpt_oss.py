@@ -40,6 +40,7 @@ from dnet_tpu.ops.attention import (
     sp_causal_mask,
     sp_sliding_window_mask,
 )
+from dnet_tpu.obs.phases import KV_KIND_FULL
 from dnet_tpu.ops.norms import rms_norm
 from dnet_tpu.ops.quant import dq, lead_dim, out_dim
 from dnet_tpu.ops.rope import apply_rope, rope_frequencies
@@ -74,6 +75,12 @@ class GptOssRingModel(RingModel):
             a, b = kind_list[0::2], kind_list[1::2]
             if len(set(a)) == 1 and len(set(b)) == 1:
                 self.pair_kinds = (a[0], b[0])
+
+    def flash_layers(self):
+        # the paired layout's full half declares the causal predicate; a
+        # sliding layer attends a ring buffer or under a mask: dense
+        kinds = self.pair_kinds or ()
+        return ((KV_KIND_FULL, 0),) * (kinds.count(0) * len(self.layers) // 2)
 
     # ---- pure compute -------------------------------------------------
     @jax.named_scope(SCOPE_ATTN)

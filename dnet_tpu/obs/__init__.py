@@ -137,7 +137,13 @@ def _register_core(reg: MetricsRegistry) -> None:
             fam.labels(cache=kind)  # pre-touch: expose at 0 from the start
     # paged KV pool (dnet_tpu/kv/paged.py): used + free == pool size at all
     # times (shared blocks count once in used; BlockPool.check_conservation)
-    from dnet_tpu.obs.phases import KV_KINDS, MOE_HELD, MOE_PATHS, RETENTION_PHASES
+    from dnet_tpu.obs.phases import (
+        FLASH_TILE_STATES,
+        KV_KINDS,
+        MOE_HELD,
+        MOE_PATHS,
+        RETENTION_PHASES,
+    )
 
     # the state kind (kv/store.py StateStore): one entry a lane, no blocks
     reg.gauge(
@@ -200,6 +206,17 @@ def _register_core(reg: MetricsRegistry) -> None:
         "Latent entries the prefill chunks expanded to per-head keys and "
         "values: position + chunk tokens, a chunk a layer",
     )
+    flash_fam = reg.counter(
+        "dnet_flash_tiles_total",
+        "(q tile, kv tile) pairs of the grid a prefill chunk spans against "
+        "its staged row, a layer that attends through the flash kernel, by "
+        "the layer's kind and by whether the kernel folds the pair or "
+        "neither copies nor steps over it",
+        labelnames=("kind", "state"),
+    )
+    for kind in KV_KINDS:
+        for state in FLASH_TILE_STATES:
+            flash_fam.labels(kind=kind, state=state)
     for name, help_text in (
         ("dnet_kv_blocks_used",
          "Paged KV pool blocks currently allocated (refcount >= 1), by the "

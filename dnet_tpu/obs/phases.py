@@ -168,6 +168,15 @@ KV_KIND_STATE = "state"
 # dispatch's lanes x steps)
 RETENTION_PHASES = ("prefill", "decode")
 
+# dnet_flash_tiles_total{kind=, state=}: (q tile, kv tile) pairs of the
+# [T / bq, S / bk] grid a prefill chunk spans against its staged row, a
+# layer that attends through ops/flash_attention.py (kind: KV_KINDS), by
+# what the kernel makes of them: `folded` = copied and multiplied,
+# `skipped` = above the causal diagonal, behind the window or past the
+# chunk, so neither copied nor stepped over — booked on the host from the
+# chunk's position by the kernel's own range (flash_tiles)
+FLASH_TILE_STATES = ("folded", "skipped")
+
 # dnet_moe_assignments_total{held=}: (token, chosen expert) pairs of the
 # batched decode dispatches' active lanes, by whether the expert is one
 # this process holds (an expert share, ops/moe.py) — summed on the device,

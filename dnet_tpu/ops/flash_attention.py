@@ -21,9 +21,13 @@ a name of its own.  Rotating ring-buffer windows and sp sharding stay
 dense.
 
 TPU grids run sequentially over the LAST axis, so the KV-tile axis comes
-last and the scratch accumulator carries across its iterations; blocks
-strictly above the causal diagonal are skipped (`pl.when`), halving the
-work like every flash implementation.
+last and the scratch accumulator carries across its iterations.  That axis
+counts from the q tile's FIRST live kv tile (`_live_tiles`) and ends with
+the chunk's last one: `pos` is a scalar prefetch, the k and v index maps
+clamp into the q tile's live range (a repeated block index is not copied),
+and the grid's bound is the most tiles any q tile of this chunk folds.  A
+tile above the causal diagonal or behind the window is neither copied nor,
+past the chunk, stepped over; the staged row's length costs nothing.
 """
 
 from __future__ import annotations
@@ -33,6 +37,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from dnet_tpu.ops.kernel_select import SELECTIONS, kernel_backend
@@ -48,12 +53,61 @@ FLASH_WINDOW_NAME = "flash_prefill_window"
 HEADS_PER_STEP = 32
 
 
+def _live_tiles(pos, tq, *, bq: int, bk: int, n_s: int, window: int, xp=jnp):
+    """-> (lo, hi): the first and the last kv tile holding a (query, key)
+    pair that q tile `tq` of a chunk at `pos` attends.  The tile's LAST row
+    attends keys <= pos + (tq + 1) * bq - 1, so a kv tile starting past
+    that is masked for the whole q tile; with a window the tile's FIRST
+    row attends keys > pos + tq * bq - window and every later row only
+    later ones, so a kv tile ending at or behind that is too.  The index
+    maps, the kernel's `live` predicate, the grid's bound and the host's
+    count (`xp=np`) all read this."""
+    hi = xp.minimum((pos + (tq + 1) * bq - 1) // bk, n_s - 1)
+    lo = xp.maximum(pos + tq * bq - window + 1, 0) // bk if window else 0
+    return lo, hi
+
+
+def _kv_tile(pos, tq, s, *, xp=jnp, **geom):
+    """The kv tile step `s` of q tile `tq` holds: its live tiles in order
+    from the first, then the last one again (a repeated block index is not
+    copied, and the body skips the step)."""
+    lo, hi = _live_tiles(pos, tq, xp=xp, **geom)
+    return xp.minimum(lo + s, hi)
+
+
+def _kv_steps(pos, T: int, *, bq: int, bk: int, n_s: int, window: int, xp=jnp):
+    """The kv axis' bound: the most tiles any q tile of the chunk folds.
+    Without a window that is the last q tile's (they all start at tile 0);
+    a window of `window` keys over `bq` rows spans at most
+    (window + bq - 2) // bk + 2 tiles wherever it lies."""
+    _, hi = _live_tiles(pos, T // bq - 1, bq=bq, bk=bk, n_s=n_s, window=window, xp=xp)
+    return xp.minimum(hi + 1, (window + bq - 2) // bk + 2) if window else hi + 1
+
+
+def flash_tiles(pos: int, T: int, S: int, window: int = 0):
+    """Host twin of the kernel's grid for one chunk of `T` rows at `pos`
+    against a row of `S` keys -> ((q tile, kv tile) pairs it folds, pairs
+    of the whole [T / bq, S / bk] grid it neither copies nor steps over),
+    or None where the shapes do not take the kernel."""
+    if kernel_backend() is None or not _tiles_ok(T, S):
+        return None
+    bq, bk = _pick_tile(T, 128), _pick_tile(S, 128)
+    geom = dict(bq=bq, bk=bk, n_s=S // bk, window=int(window or 0), xp=np)
+    folded = 0
+    for tq in range(T // bq):
+        lo, hi = _live_tiles(int(pos), tq, **geom)
+        folded += int(hi) - int(lo) + 1
+    return folded, (T // bq) * (S // bk) - folded
+
+
 def _flash_kernel(pos_ref, sink_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                   acc_ref, *, bq: int, bk: int, scale: float, n_s: int,
                   KVH: int, G: int, Hd: int, Vd: int, window: int = 0):
-    """One (batch, head-group, q-tile, kv-tile) step of the online softmax,
+    """One (batch, head-group, q-tile, kv-step) step of the online softmax,
     every head of the group (KVH kv heads and their G query heads each;
-    all the heads when they fit one step).
+    all the heads when they fit one step).  Step j of a q tile holds its
+    j-th live kv tile (`_kv_tile`); the steps left over once a q tile's
+    live tiles are folded hold the last one again and do nothing.
 
     Mosaic tiles the last two dims of a block, so a block can take a head
     out of [.., heads, dim] only whole.  The operands therefore arrive with
@@ -68,26 +122,18 @@ def _flash_kernel(pos_ref, sink_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 
     hb = pl.program_id(1)
     tq = pl.program_id(2)
-    s = pl.program_id(3)
+    step = pl.program_id(3)
     pos = pos_ref[0]
+    lo, hi = _live_tiles(pos, tq, bq=bq, bk=bk, n_s=n_s, window=window)
+    s = lo + step  # the kv tile this step holds, where it is live
 
-    @pl.when(s == 0)
+    @pl.when(step == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # this q-tile's LAST row attends keys <= pos + tq*bq + bq - 1; a kv
-    # tile starting past that is fully masked for the whole tile -> skip
-    q_hi = pos + (tq + 1) * bq - 1
-    live = s * bk <= q_hi
-    if window:
-        # the tile's FIRST row attends keys > pos + tq*bq - window, every
-        # later row only later ones: a kv tile ending at or behind that is
-        # wholly behind the window for the whole q-tile -> skip
-        live = live & ((s + 1) * bk - 1 > pos + tq * bq - window)
-
-    @pl.when(live)
+    @pl.when(s <= hi)
     def _fold():
         q_pos = pos + tq * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         k_pos = s * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
@@ -121,7 +167,7 @@ def _flash_kernel(pos_ref, sink_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                 acc_ref[h] = acc_ref[h] * corr + pv
                 m_ref[h] = m_new
 
-    @pl.when(s == n_s - 1)
+    @pl.when(step == pl.num_programs(3) - 1)
     def _emit():
         # fold the sink into the global softmax denominator exactly once
         # (same algebra as the dense op's virtual-key column)
@@ -167,35 +213,44 @@ def _flash_pallas(q, k, v, pos, sinks, *, G: int, scale: float, bq: int,
     n_s = S // bk
     KB = _heads_per_step(KVH, G, Hd, Vd)
 
-    # grid (batch, head-group, q-tile, kv-tile); kv-tile LAST so the
-    # scratch accumulator carries across its (sequential) iterations
+    geom = dict(bq=bq, bk=bk, n_s=n_s, window=window)
+
+    # grid (batch, head-group, q-tile, kv-step); kv-step LAST so the
+    # scratch accumulator carries across its (sequential) iterations, and
+    # only as long as this chunk's q tiles have live kv tiles to fold
     kernel = functools.partial(
-        _flash_kernel, bq=bq, bk=bk, scale=scale, n_s=n_s, KVH=KB, G=G,
-        Hd=Hd, Vd=Vd, window=window,
+        _flash_kernel, scale=scale, KVH=KB, G=G, Hd=Hd, Vd=Vd, **geom
     )
+
+    def q_map(b, hb, tq, s, pos_ref):
+        return (b, tq, hb)
+
+    def kv_map(b, hb, tq, s, pos_ref):
+        return (b, _kv_tile(pos_ref[0], tq, s, **geom), hb)
+
     out = pl.pallas_call(
         kernel,
-        grid=(B, KVH // KB, T // bq, n_s),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # pos [1]
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # sinks [H]
-            pl.BlockSpec((1, bq, KB * G * Hd), lambda b, hb, tq, s: (b, tq, hb)),
-            pl.BlockSpec((1, bk, KB * Hd), lambda b, hb, tq, s: (b, s, hb)),
-            pl.BlockSpec((1, bk, KB * Vd), lambda b, hb, tq, s: (b, s, hb)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, bq, KB * G * Vd), lambda b, hb, tq, s: (b, tq, hb)
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,  # pos [1]
+            grid=(B, KVH // KB, T // bq, _kv_steps(pos[0], T, **geom)),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),  # sinks [H]
+                pl.BlockSpec((1, bq, KB * G * Hd), q_map),
+                pl.BlockSpec((1, bk, KB * Hd), kv_map),
+                pl.BlockSpec((1, bk, KB * Vd), kv_map),
+            ],
+            out_specs=pl.BlockSpec((1, bq, KB * G * Vd), q_map),
+            scratch_shapes=[
+                pltpu.VMEM((KB * G, bq, 1), jnp.float32),
+                pltpu.VMEM((KB * G, bq, 1), jnp.float32),
+                pltpu.VMEM((KB * G, bq, Vd), jnp.float32),
+            ],
         ),
         # inside shard_map the output is device-varying over the inputs'
         # mesh axes; check_vma requires the declaration
         out_shape=jax.ShapeDtypeStruct(
             (B, T, H * Vd), q.dtype, vma=frozenset(vma)
         ),
-        scratch_shapes=[
-            pltpu.VMEM((KB * G, bq, 1), jnp.float32),
-            pltpu.VMEM((KB * G, bq, 1), jnp.float32),
-            pltpu.VMEM((KB * G, bq, Vd), jnp.float32),
-        ],
         interpret=interpret,
         # the trace tells window-layer attention from full-layer attention
         # by this name
@@ -291,15 +346,12 @@ def _vma_union(*xs) -> frozenset:
     return out
 
 
+def _tiles_ok(T: int, S: int) -> bool:
+    return T >= 8 and _pick_tile(T, 128) > 0 and _pick_tile(S, 128) > 0
+
+
 def _shape_ok(q: jnp.ndarray, k: jnp.ndarray) -> bool:
-    T, H = q.shape[1], q.shape[2]
-    S, KVH = k.shape[1], k.shape[2]
-    return (
-        H % KVH == 0
-        and T >= 8
-        and _pick_tile(T, 128) > 0
-        and _pick_tile(S, 128) > 0
-    )
+    return q.shape[2] % k.shape[2] == 0 and _tiles_ok(q.shape[1], k.shape[1])
 
 
 def flash_eligible(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray) -> bool:

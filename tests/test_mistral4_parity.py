@@ -125,6 +125,8 @@ def test_chunked_prefill_then_decode_through_the_latent_pool(
     pre0 = metric("dnet_mla_tokens_total").labels(phase="prefill").value
     exp0 = metric("dnet_mla_expanded_tokens_total").value
     byt0 = metric("dnet_mla_latent_bytes_total").value
+    tiles = metric("dnet_flash_tiles_total")
+    til0 = sum(tiles.labels(kind="full", state=s).value for s in ("folded", "skipped"))
     # another sequence holds a lane and a table, and steps beside ours
     o = eng.prefill_and_sample("other", other, dec)
     o_tok = int(o.token[0])
@@ -134,6 +136,10 @@ def test_chunked_prefill_then_decode_through_the_latent_pool(
     assert metric("dnet_mla_tokens_total").labels(phase="prefill").value - pre0 == 77
     ends = [min(i + chunk, 77) for i in range(0, 77, chunk)]
     assert metric("dnet_mla_expanded_tokens_total").value - exp0 == 2 * sum(ends)
+    # each chunk is one q tile against the staged row's one kv tile, in
+    # both layers, where a kernel runs to fold it
+    til1 = sum(tiles.labels(kind="full", state=s).value for s in ("folded", "skipped"))
+    assert til1 - til0 == (2 * len(ends) if kernels == "interpret" else 0)
     res = eng.adopt_prefilled("a", logits, dec)
     assert "a" not in eng.eng.sessions  # the session's latent row moved into the pool
     assert len(eng._tables[eng.slot_of["a"]].blocks) == 10  # 77 tokens in blocks of 8

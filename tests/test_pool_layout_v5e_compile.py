@@ -235,3 +235,47 @@ def test_two_kind_commit_append_and_attention_at_the_mix_geometry(one_chip, no_c
     assert pool_sized_copies(
         jax.jit(step, donate_argnums=(0,)), step_args, smallest, "tpu_custom_call"
     ) == []
+
+
+@pytest.mark.parametrize(
+    "T,S,H,KVH,Hd,Vd,window",
+    [
+        (2048, 33792, 32, 32, 192, 128, 0),  # lat: expanded latents, G = 1, Vd != Hd
+        (2048, 33280, 16, 2, 256, 256, 0),  # doc: gated attention at head 256
+        (2048, 4096, 32, 4, 128, 128, 0),  # rag
+        (256, 16512, 128, 8, 128, 128, 0),  # mix, its full layer: four head groups
+        (256, 16512, 128, 8, 128, 128, 4096),  # mix, a window layer
+    ],
+    ids=["lat", "doc", "rag", "mix_full", "mix_window"],
+)
+def test_flash_prefill_takes_its_position_as_a_prefetch_and_a_grid_bound(
+    one_chip, no_cache, T, S, H, KVH, Hd, Vd, window
+):
+    """The causal prefill kernel at the five geometries the cells run it
+    at: Mosaic accepts `pos` as a scalar prefetch that the k / v index maps
+    read, and a key axis whose bound is computed from it (a dynamic grid
+    dimension), before any chip call."""
+    from dnet_tpu.ops.flash_attention import FLASH_NAME, FLASH_WINDOW_NAME, _flash_pallas
+
+    def a(shape, dtype=BF):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def call(q, k, v, pos, sinks):
+        return _flash_pallas(
+            q.reshape(1, T, H, Hd), k.reshape(1, S, KVH, Hd), v.reshape(1, S, KVH, Vd),
+            pos, sinks, G=H // KVH, scale=Hd**-0.5, bq=128, bk=128, interpret=False,
+            window=window,
+        )
+
+    args = (
+        a((1, T, H * Hd)), a((1, S, KVH * Hd)), a((1, S, KVH * Vd)), a((1,), jnp.int32),
+        a((H,), jnp.float32),
+    )
+    text = jax.jit(call).trace(*args).lower(lowering_platforms=("tpu",)).compile().as_text()
+    name = FLASH_WINDOW_NAME if window else FLASH_NAME
+    calls = re.findall(rf"%{name}[.\w]* = .*custom-call\((.*?)\), custom_call_target", text)
+    assert len(calls) == 1, text[:2000]
+    # the grid's bound rides in front of pos, sinks, q, k and v
+    assert len(calls[0].split(", ")) == 6
+    # merged heads in, merged heads out: no copy as large as the row
+    assert not re.search(rf"copy\([^)]*\[1,{S},", text)
