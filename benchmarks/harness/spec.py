@@ -3,7 +3,9 @@
 A cell names a configuration and a traffic mix; a per-layer metric names
 its reader file.  Nothing here knows any particular cell: a later PR adds
 `configs/<config>.json`, `traffic/<mix>.json`,
-`layer_metrics/<metric>.json` and one entry in BENCHMARK.json.
+`layer_metrics/<metric>.json` and one entry in BENCHMARK.json.  One entry
+a READING: a new cell joins the `workloads` list of every entry whose
+reader reads there, and brings entries only for readings no entry has.
 """
 
 from __future__ import annotations
@@ -52,6 +54,16 @@ def _in_cell(metric: dict, cell: str) -> bool:
 
 def layer_metric_file(name: str, bench_dir: Path = BENCH_DIR) -> Path:
     return bench_dir / "layer_metrics" / f"{name}.json"
+
+
+def reading(metric: dict, bench_dir: Path = BENCH_DIR) -> tuple:
+    """What an entry reads and which way it points: its reader file less
+    `what`, with its arrow.  Two entries with one reading are one entry."""
+    reader = load_json(layer_metric_file(metric["name"], bench_dir))
+    reader.pop("what", None)
+    return (json.dumps(reader, sort_keys=True),) + tuple(
+        metric[k] for k in ("moves", "better", "unit", "source", "layer")
+    )
 
 
 def load_benchmark(root: Path = ROOT) -> dict:
@@ -150,6 +162,7 @@ def validate(bench: dict, root: Path = ROOT) -> List[str]:
         faults.append("too many four-chip cells")
 
     metric_names = set()
+    readings: dict = {}
     e2e = {m["name"]: m for m in bench["end_to_end"]}
     if "setup_s" not in e2e:
         faults.append("no setup_s")
@@ -183,6 +196,13 @@ def validate(bench: dict, root: Path = ROOT) -> List[str]:
                 line_ok(f"per_layer {m['name']} layer", m["layer"])
                 if not layer_metric_file(m["name"], root / "benchmarks").is_file():
                     faults.append(f"per_layer {m['name']}: no reader file")
+                else:
+                    twin = readings.setdefault(reading(m, root / "benchmarks"), m["name"])
+                    if twin != m["name"]:
+                        faults.append(
+                            f"per_layer {m['name']}: the reading {twin} already has; "
+                            f"a cell joins that entry's workloads"
+                        )
                 moved = e2e.get(m["moves"])
                 if moved is None:
                     faults.append(f"per_layer {m['name']}: moves unknown {m['moves']}")
@@ -193,6 +213,10 @@ def validate(bench: dict, root: Path = ROOT) -> List[str]:
                             f"per_layer {m['name']}: {m['moves']} is not "
                             f"reported in cell {cell}"
                         )
+    listed = {m.get("name") for m in bench["per_layer"]}
+    for f in sorted((root / "benchmarks" / "layer_metrics").glob("*.json")):
+        if f.stem not in listed:
+            faults.append(f"reader file {f.name}: no per_layer entry")
     for cell in cells:
         mine = [m for m in bench["end_to_end"] if _in_cell(m, cell)]
         if len(mine) < 2 or not any(m["name"] == "setup_s" for m in mine):

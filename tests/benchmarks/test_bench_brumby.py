@@ -268,23 +268,25 @@ def test_rehearsal_of_the_long_generation_cell():
     assert result["correct"] is True and result["failed"] == 0 and result["attempted"] > 0
     m = {k: v["value"] for k, v in result["metrics"].items()}
     assert all(k.startswith("rehearsal.") for k in m)  # no CPU number under a device name
-    for name in ("state_slots_used_peak_pct.gen", "retention_state_bytes_in_window.gen",
-                 "retention_prefill_tokens_in_window.gen", "decode_lane_steps_in_window.gen",
-                 "decode_tokens_delivered_in_window.gen", "prefill_ticks_mean.gen",
-                 "prefill_adopt_mean_ms.gen", "decode_prepare_mean_ms.gen",
-                 "sched_batch_tokens_mean.gen", "itl_p50_ms.gen", "sched_tick_host_mean_ms.gen"):
+    for name in ("state_slots_used_peak_pct", "retention_state_bytes_in_window",
+                 "retention_prefill_tokens_in_window", "decode_lane_steps_in_window",
+                 "decode_tokens_delivered_in_window", "prefill_ticks_mean",
+                 "prefill_adopt_mean_ms", "decode_prepare_mean_ms",
+                 "sched_batch_tokens_mean", "itl_p50_ms", "sched_tick_host_mean_ms"):
         assert m[f"rehearsal.{name}"] > 0, name
-    for name in ("sched_queue_wait_mean_ms.gen", "admit_wait_mean_ms.gen",
-                 "decode_deliver_wait_mean_ms.gen", "decode_readback_wait_mean_ms.gen",
-                 "decode_slot_steps_in_window.gen", "prefill_wall_mean_ms.gen"):
+    for name in ("sched_queue_wait_mean_ms", "admit_wait_mean_ms",
+                 "decode_deliver_wait_mean_ms", "decode_readback_wait_mean_ms",
+                 "decode_slot_steps_in_window", "prefill_wall_mean_ms"):
         assert f"rehearsal.{name}" in m, name
-    assert m["rehearsal.state_slots_used_peak_pct.gen"] <= 100.0
+    assert m["rehearsal.state_slots_used_peak_pct"] <= 100.0
     # bytes booked = lane steps x one entry x 2 (2 layers x 2 KV heads x 9 x 16 x 17 floats)
     entry = 2 * 2 * 9 * 16 * 17 * 4
-    assert m["rehearsal.retention_state_bytes_in_window.gen"] == (
-        m["rehearsal.decode_lane_steps_in_window.gen"] * entry * 2
-    )
-    assert not any(k.endswith((".rag", ".mix")) for k in m)  # the other cells' twins stay theirs
+    # the two counters move one after the other on the compute thread and a
+    # scrape from the event loop can fall between them, at either edge of the
+    # window: whole entries, and at most one 4-lane dispatch apart at each edge
+    booked, rest = divmod(m["rehearsal.retention_state_bytes_in_window"], entry * 2)
+    assert rest == 0 and abs(booked - m["rehearsal.decode_lane_steps_in_window"]) <= 2 * 4
+    assert not any(k.endswith((".rag", ".mix", ".gen", ".doc", ".lat")) for k in m)  # no suffix names a cell
     earlier = "\n".join(lines[:-1])
     assert "REHEARSAL" in earlier and "check: largest" in earlier and "-> ok" in earlier
     assert '"retention_step"' in earlier and '"retention_chunk"' in earlier

@@ -85,8 +85,7 @@ def test_chunked_prefill_then_paged_decode_matches_the_reference(checkpoint, mon
     from dnet_tpu.obs import metric
 
     cfg, model_dir = checkpoint
-    for k, v in {"DNET_KV_PAGED": "1", "DNET_KV_RAGGED": "1", "DNET_KV_BLOCK_TOKENS": "8",
-                 "DNET_SCHED_PREFILL_CHUNK": "16"}.items():
+    for k, v in {"DNET_KV_BLOCK_TOKENS": "8", "DNET_SCHED_PREFILL_CHUNK": "16"}.items():
         monkeypatch.setenv(k, v)
     if kernels == "interpret":
         monkeypatch.setenv("DNET_FLASH_INTERPRET", "1")
@@ -96,7 +95,7 @@ def test_chunked_prefill_then_paged_decode_matches_the_reference(checkpoint, mon
 
         eng = BatchedEngine(model_dir, slots=3, max_seq=128, param_dtype="float32")
         wpool, wtables = eng.kv_pools[KV_KIND_WINDOW], eng._kind_tables[KV_KIND_WINDOW]
-        assert eng.kv_ragged and wpool.total == 3 * 6  # window 24 + step 16 in blocks of 8, + 1
+        assert eng.kv_pool is not None and wpool.total == 3 * 6  # window 24 + step 16 in blocks of 8, + 1
         released0 = metric("dnet_kv_window_blocks_released_total").value
         dec, ids = decoding(), prompt(cfg)
         eng.reserve_slot("a")

@@ -32,7 +32,7 @@ def test_parser_keeps_labels_and_skips_what_is_not_a_sample():
 @pytest.mark.parametrize(
     "metric,want",
     [
-        ("sched_tick_host_mean_ms.rag", 2000.0),
+        ("sched_tick_host_mean_ms", 2000.0),
         ("sched_batch_tokens_mean", (7680 + 940 - 2560 - 300) / 40),
         ("compiles_in_window", 1.0),
         ("kv_blocks_used_peak_pct", 1024 / 8192 * 100),
@@ -88,8 +88,8 @@ def test_trace_share_patterns_of_the_shipped_metrics():
     def share(name):
         return readers.read(spec.load_json(spec.layer_metric_file(name)), ev)
 
-    assert share("sort_time_pct.rag") == pytest.approx(30 / 150 * 100 / 2)  # not the fusion
-    assert share("pallas_time_pct.rag") == pytest.approx(20 / 150 * 100 / 2)
+    assert share("sort_time_pct.ttft") == pytest.approx(30 / 150 * 100 / 2)  # not the fusion
+    assert share("pallas_time_pct.attn") == pytest.approx(20 / 150 * 100 / 2)
     assert share("device_idle_pct") == pytest.approx(12.5)
     collectives = {"reader": "trace_share", "pattern": COLLECTIVES, "of": "busy"}
     assert readers.read(collectives, ev) == pytest.approx(100 / 2)  # mean over devices
@@ -114,8 +114,8 @@ def test_recorded_v5e_trace():
     busy = xplane.union_ns([(s, s + d) for _, s, d in ops])
     assert sum(d for _, d in selfs) == pytest.approx(busy, rel=1e-6)
     assert 0.0 <= xplane.idle_pct(t) < 100.0
-    shares = {p: xplane.share_pct(t, spec.load_json(spec.layer_metric_file(p + ".rag"))["pattern"])
-              for p in ("sort_time_pct", "pallas_time_pct")}
+    shares = {p.split(".")[0]: xplane.share_pct(t, spec.load_json(spec.layer_metric_file(p))["pattern"])
+              for p in ("sort_time_pct.ttft", "pallas_time_pct.attn")}
     assert xplane.share_pct(t, COLLECTIVES) == 0.0  # one chip
     assert shares["sort_time_pct"] > 0 and shares["pallas_time_pct"] > 0
     assert sum(shares.values()) < 100.0

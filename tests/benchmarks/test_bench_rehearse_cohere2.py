@@ -25,20 +25,20 @@ def test_rehearsal_of_the_mixed_length_cell():
     assert result["correct"] is True and result["failed"] == 0 and result["attempted"] > 0
     m = {k: v["value"] for k, v in result["metrics"].items()}
     assert all(k.startswith("rehearsal.") for k in m)  # no CPU number under a device name
-    for name in ("kv_full_blocks_used_peak_pct.mix", "kv_window_blocks_used_peak_pct.mix",
-                 "moe_assignments_held_in_window.mix", "moe_assignments_routed_in_window.mix",
-                 "decode_tokens_delivered_in_window.mix", "prefill_ticks_mean.mix",
+    for name in ("kv_full_blocks_used_peak_pct", "kv_window_blocks_used_peak_pct",
+                 "moe_assignments_held_in_window", "moe_assignments_routed_in_window",
+                 "decode_tokens_delivered_in_window", "prefill_ticks_mean",
                  # the host cost of the window tables: released and grown inside
                  # dnet.decode.prepare, committed by kind inside dnet.prefill.adopt
-                 "decode_prepare_mean_ms.mix", "prefill_adopt_mean_ms.mix",
-                 "sched_batch_tokens_mean.mix", "decode_lane_steps_in_window.mix"):
+                 "decode_prepare_mean_ms", "prefill_adopt_mean_ms",
+                 "sched_batch_tokens_mean", "decode_lane_steps_in_window"):
         assert m[f"rehearsal.{name}"] > 0, name
-    for name in ("sched_queue_wait_mean_ms.mix", "admit_wait_mean_ms.mix",
-                 "decode_deliver_wait_mean_ms.mix"):
+    for name in ("sched_queue_wait_mean_ms", "admit_wait_mean_ms",
+                 "decode_deliver_wait_mean_ms"):
         assert f"rehearsal.{name}" in m, name
-    assert "rehearsal.kv_window_blocks_released_in_window.mix" in m
-    held = m["rehearsal.moe_assignments_held_in_window.mix"]
-    assert held < m["rehearsal.moe_assignments_routed_in_window.mix"]  # 4 of 8 experts held
+    assert "rehearsal.kv_window_blocks_released_in_window" in m
+    held = m["rehearsal.moe_assignments_held_in_window"]
+    assert held < m["rehearsal.moe_assignments_routed_in_window"]  # 4 of 8 experts held
     earlier = "\n".join(lines[:-1])
     assert "REHEARSAL" in earlier and "check: largest" in earlier and "-> ok" in earlier
     assert '"paged_attend"' in earlier and '"flash_prefill"' in earlier

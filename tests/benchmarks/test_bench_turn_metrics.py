@@ -2,7 +2,9 @@
 `prom_delta`, each read through `readers.read` from a recorded scrape pair;
 BENCHMARK.json with their twelve entries keeps the contract's rules; the
 parent's program (PR 38) gives nothing for the eight whose span or family
-it lacks and does not raise.
+it lacks and does not raise.  PR 43: the mean over the DRAINED turn-arounds
+went (PR 40 keeps a step in flight, so there are none and it read `null`)
+and the mean over the BUSY ones stands in its place, same family.
 
 The pairs under harness/testdata/ are the `dnet_span_ms` and `dnet_sched_*`
 families of real expositions (the scheduler over the tiny llama on the CPU,
@@ -22,10 +24,11 @@ CELLS = [
 ]
 SCHED, PROGRAMS, API = "scheduler", "engine programs", "HTTP + admission"
 TOKENS, TTFT = "output_tokens_per_s", "ttft_p50_ms"
+BUSY_SUM_MS = 450.0 - 50.0  # dnet_sched_turnaround_ms_sum{device="busy"} over the recorded window
 
 # metric -> (value over the recorded window, unit, source, layer, moves)
 WANT = {
-    "turnaround_drained_mean_ms": (7920 / 900, "ms", "program_span", SCHED, TOKENS),
+    "turnaround_busy_mean_ms": (BUSY_SUM_MS / 100, "ms", "program_span", SCHED, TOKENS),
     "turnarounds_drained_in_window": (900, "ticks", "program_counter", SCHED, TOKENS),
     "turn_to_loop_mean_ms": (410 / 1025, "ms", "program_span", SCHED, TOKENS),
     "turn_to_thread_mean_ms": (205 / 1025, "ms", "program_span", SCHED, TOKENS),
@@ -43,12 +46,9 @@ PARENT_HAS = {
     "sched_apply_mean_ms", "sched_plan_mean_ms", "decode_launch_mean_ms",
     "prefill_readback_wait_mean_ms",
 }
-# A metric lists the cells in which its reader finds something to read.  No
-# tick of the rag cell ends drained (every one launches a chunk behind its
-# last adoption: my chip run, PR 39), so the mean of the drained
-# turn-arounds has nothing to divide there, and the mix cell's ticks are of
-# the same kind; the count reads 0 and is listed.
-CELLS_OF = {"turnaround_drained_mean_ms": CELLS[2:]}
+# A metric lists the cells in which its reader finds something to read: all
+# twelve read in PR 39's four cells (a drained count of 0 is a reading), and
+# cells added since may have joined.
 
 
 def evidence(scrapes):
@@ -96,21 +96,20 @@ def test_each_entry_lists_its_cells_and_names_its_layer(metric):
     bench = spec.load_benchmark()
     entry = next(m for m in bench["per_layer"] if m["name"] == metric)
     _, unit, source, layer, moves = WANT[metric]
-    cells = CELLS_OF.get(metric, CELLS)
-    assert entry == {
+    assert {k: v for k, v in entry.items() if k != "workloads"} == {
         "name": metric, "unit": unit, "better": "lower", "source": source,
-        "layer": layer, "moves": moves, "workloads": cells,
+        "layer": layer, "moves": moves,
     }
-    for cell in CELLS:
-        listed = metric in {m["name"] for m in spec.resolve_cell(cell).per_layer}
-        assert listed == (cell in cells)
+    assert set(CELLS) <= set(entry["workloads"])
+    for cell in entry["workloads"]:
+        assert metric in {m["name"] for m in spec.resolve_cell(cell).per_layer}
 
 
 def test_a_mean_over_no_observation_is_left_out_of_the_line():
-    """The rag cell's case: the family is there, its drained child never
-    moved: the mean reads None (the line leaves it out), the count 0."""
+    """The family is there, the child never moved: a mean reads None (the
+    line leaves it out, which is why the drained mean went), a count 0."""
     before, _ = recorded("turn")
-    mean = spec.load_json(spec.layer_metric_file("turnaround_drained_mean_ms"))
+    mean = spec.load_json(spec.layer_metric_file("turnaround_busy_mean_ms"))
     count = spec.load_json(spec.layer_metric_file("turnarounds_drained_in_window"))
     assert readers.read(mean, evidence([before, before])) is None
     assert readers.read(count, evidence([before, before])) == 0.0
@@ -119,19 +118,22 @@ def test_a_mean_over_no_observation_is_left_out_of_the_line():
 def test_the_extended_benchmark_keeps_the_contracts_rules():
     bench = spec.load_benchmark()
     assert spec.validate(bench) == []
-    assert [w["name"] for w in bench["workloads"]] == CELLS
+    assert set(CELLS) <= {w["name"] for w in bench["workloads"]}  # at least PR 39's four
     # the layers are ones the benchmark already named, letter for letter
     older = {m["layer"] for m in bench["per_layer"] if m["name"] not in WANT}
     assert {SCHED, PROGRAMS, API} <= older
 
 
-def test_the_drained_mean_leaves_the_busy_turnarounds_out():
+def test_the_busy_mean_leaves_the_drained_turnarounds_out():
     before, after = recorded("turn")
     fam = "dnet_sched_turnaround_ms"
     both = prom.delta(after, before, fam + "_sum") / prom.delta(after, before, fam + "_count")
-    assert both != pytest.approx(WANT["turnaround_drained_mean_ms"][0])
+    assert both != pytest.approx(WANT["turnaround_busy_mean_ms"][0])
     busy = prom.delta(after, before, fam + "_count", {"device": "busy"})
     assert busy == 100 and WANT["turnarounds_drained_in_window"][0] == 900
+    drained = prom.delta(after, before, fam + "_sum", {"device": "drained"})
+    assert drained / 900 == pytest.approx(7920 / 900)  # what the retired mean read
+    assert prom.delta(after, before, fam + "_sum", {"device": "busy"}) == pytest.approx(BUSY_SUM_MS)
 
 
 def test_the_timed_out_count_is_one_outcome_of_three():
