@@ -5,10 +5,11 @@ scalars (not static args), so changing temperature or top_p never recompiles
 — the fix for the reference's "end-shard sampling under jit" hard part
 (SURVEY.md §7).  Greedy vs stochastic is a `jnp.where` select.  The filters
 (top-k with a *traced* k, top-p, min-p, min_tokens_to_keep) each keep a
-prefix of the row's descending order, so `filter_keep` reads their common
-prefix length off ONE sort that carries the vocabulary index and cuts back
-to vocabulary order by comparing every entry with the (value, index) pair at
-that length: no ranks, no vocabulary-sized gather.  Functionality mirrors
+prefix of the row's descending order, and a prefix is fixed by its last
+entry: `filter_keep` finds that (value, index) pair by a threshold search
+over the row, counting and weighing the entries above each threshold, and
+cuts in vocabulary order by comparing every entry with the pair: no sort, no
+ranks, no vocabulary-sized gather.  Functionality mirrors
 the reference's mlx_lm-based Sampler (src/dnet/core/decoding/sampler.py:14-65).
 """
 
@@ -89,9 +90,9 @@ class SamplePlan(NamedTuple):
 
     The traced-knob design (SampleParams) means one program serves every
     request — but it also means every decode step pays for machinery most
-    requests never use: one full-vocabulary sort and a few elementwise passes
-    for the filters (`filter_keep`), a log_softmax + top_k(20) for logprobs,
-    a scatter for the bias.  The plan collapses the unused machinery at trace time; the handful
+    requests never use: a threshold search over the row, a few dozen counting
+    passes, for the filters (`filter_keep`), a log_softmax + top_k(20) for
+    logprobs, a scatter for the bias.  The plan collapses the unused machinery at trace time; the handful
     of plan combinations bound the number of compiled variants, and knobs
     *within* a plan stay traced (a temperature change still never
     recompiles).  What the machinery costs on the chip is in PERF.md
@@ -148,62 +149,112 @@ def pack_chunk_results(results: SampleResult, with_logprobs: bool) -> jnp.ndarra
     return results.token[..., None].astype(jnp.float32)
 
 
+# bits of a threshold settled by one read of the row: 2**n - 1 thresholds are
+# tried together (what a pass costs on the chip: PERF.md section 6)
+_BITS_A_PASS = 2
+
+
+def _largest(holds, bits: int, like: jnp.ndarray) -> jnp.ndarray:
+    """The largest threshold t of `bits` bits (uint32, shaped and sharded as
+    `like`) at which `holds(t)` is true, for a `holds` that is true at 0 and
+    never true above a threshold where it is false.  Built from the top bit
+    down, `_BITS_A_PASS` at a time: a fixed trip count, so a per-lane `vmap`
+    batches the loop."""
+    step = _BITS_A_PASS
+    passes = -(-bits // step)
+
+    def settle(i, t):
+        shift = step * (passes - 1 - i).astype(jnp.uint32)
+        tried = [holds(t | (jnp.uint32(j) << shift)) for j in range(1, 2**step)]
+        return t | (sum(h.astype(jnp.uint32) for h in tried) << shift)
+
+    return jax.lax.fori_loop(0, passes, settle, jnp.zeros_like(like, jnp.uint32))
+
+
 def filter_keep(scaled: jnp.ndarray, params: SampleParams) -> jnp.ndarray:
     """The filters' kept set for temperature-scaled logits [B, V], as a
-    boolean mask in vocabulary order, from ONE sort.
+    boolean mask in vocabulary order, from a threshold search over the row.
 
     Top-k, top-p, min-p and min_tokens_to_keep each keep a prefix of the
-    row's descending order, so together they keep its first `n` entries for
-    one integer n per row.  One stable sort that carries the vocabulary index
-    gives n and the (value, index) pair at sorted position n-1; the mask is
-    then an elementwise comparison of every entry against that pair.  No
-    ranks are computed and nothing vocabulary-sized is gathered.
+    row's descending order, so together they keep a prefix, and a prefix is
+    fixed by ONE (value, index) pair: its last entry.  Finding it needs no
+    order, only how many entries stand above a threshold and what they
+    weigh, both step functions of the threshold: the pair's value is
+    searched bit by bit over a monotone integer key of the float, its index
+    bit by bit inside the group of entries that share the value, and the
+    mask compares every entry with the pair.  Nothing is sorted, ranked or
+    gathered.
 
     Order of equal values (the common case: logits leave `lm_project` in
-    bf16): ascending and stable, then read backwards, so among equal values
-    the HIGHER index stands first, and a cut inside such a group keeps its
-    higher indices.
+    bf16): among equal values the HIGHER index stands first, and a cut
+    inside such a group keeps its higher indices.
 
     top_p >= 1 keeps the whole row; below 1 the prefix ends at the first
-    sorted position whose exclusive cumulative probability reaches top_p.
-    (A mask `cumsum - p < top_p` taken entry by entry is not a prefix at
+    position whose exclusive cumulative probability reaches top_p.  (A mask
+    `cumsum - p < top_p` taken entry by entry is not a prefix at
     top_p = 1.0: on a peaked row the float cumsum reaches 1.0 early and
     wobbles around it, which drops scattered far-tail entries.)
     """
-    V = scaled.shape[-1]
-    ids = jax.lax.broadcasted_iota(jnp.int32, scaled.shape, 1)
-    asc, asc_ids = jax.lax.sort(
-        (scaled, ids), dimension=1, is_stable=True, num_keys=1
+    B, V = scaled.shape
+    # a single row goes without its batch axis: vmapped a lane at a time
+    # (core/batch.py) every pass then reads [slots, V], not [slots, 1, V]
+    x = scaled[0] if B == 1 else scaled
+    ids = jax.lax.broadcasted_iota(jnp.uint32, x.shape, x.ndim - 1)
+
+    def count(where):
+        return jnp.sum(where, axis=-1, keepdims=True, dtype=jnp.int32)
+
+    # ascending uint32 key of the float32 value: the sign bit flipped for
+    # non-negatives, every bit for negatives (-inf, a banned token, last)
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    key = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+    # how many entries a prefix may hold: top-k (k == 0 -> all) and min-p
+    # (probability >= min_p * max prob: monotone in the value, so the count
+    # of such entries IS their prefix length); never fewer than
+    # min_tokens_to_keep (>= 1: the argmax always survives) or over the row
+    probs = jax.nn.softmax(x, axis=-1)
+    pmax = jnp.max(probs, axis=-1, keepdims=True)
+    most = jnp.minimum(
+        jnp.where(params.top_k > 0, params.top_k, V),
+        count(probs >= params.min_p * pmax),
+    )
+    least = jnp.clip(params.min_tokens_to_keep, 1, V)
+    no_top_p = params.top_p >= 1.0
+
+    def mass(where):
+        return jnp.sum(jnp.where(where, probs, 0.0), axis=-1, keepdims=True)
+
+    def too_many(where):
+        """More entries, or more weight, than may stand ahead of a kept entry?"""
+        ahead, before = count(where), mass(where)
+        over = (ahead >= most) | ((before >= params.top_p) & ~no_top_p)
+        return over & (ahead >= least)
+
+    # the cut's value: the largest key with too many entries at or above it
+    cut_key = _largest(lambda t: too_many(key >= t), 32, pmax)
+
+    # inside the group that shares it, exclusive cumulative probabilities
+    # step by the group's one probability (0 at temperature ~ 0: the
+    # division then says all of the group or none, as it should)
+    above, group = key > cut_key, key == cut_key
+    ahead, before, size = count(above), mass(above), count(group)
+    cut = jnp.max(jnp.where(group, x, -jnp.inf), axis=-1, keepdims=True)
+    each = jnp.max(jnp.where(group, probs, 0.0), axis=-1, keepdims=True)
+    by_mass = jnp.where(
+        before < params.top_p, jnp.ceil((params.top_p - before) / each), 0.0
+    )
+    by_mass = jnp.where(no_top_p, V, jnp.clip(by_mass, 0, V)).astype(jnp.int32)
+    n = jnp.clip(
+        jnp.maximum(jnp.minimum(most - ahead, by_mass), least - ahead), 1, size
     )
 
-    # top-k: the first k (k == 0 -> all)
-    k = jnp.where(params.top_k > 0, params.top_k, V)
-
-    # top-p over the descending row: always keeps position 0 (its exclusive
-    # cumulative probability is exactly 0)
-    sorted_probs = jax.nn.softmax(asc[:, ::-1], axis=-1)
-    cumprobs = jnp.cumsum(sorted_probs, axis=-1)
-    reached = (cumprobs - sorted_probs) >= params.top_p
-    n_p = jnp.min(jnp.where(reached, ids, V), axis=-1)
-    n_p = jnp.where(params.top_p >= 1.0, V, n_p)
-
-    # min-p: probability >= min_p * max prob.  Monotone in the value, so the
-    # count of such entries IS their prefix length; counted in vocabulary
-    # order, where no sort is needed.
-    probs = jax.nn.softmax(scaled, axis=-1)
-    pmax = jnp.max(probs, axis=-1, keepdims=True)
-    n_minp = jnp.sum(probs >= params.min_p * pmax, axis=-1)
-
-    # never fewer than min_tokens_to_keep candidates (>= 1: the argmax
-    # always survives), never more than the row holds
-    n = jnp.minimum(jnp.minimum(k, n_p), n_minp)
-    n = jnp.clip(n, jnp.maximum(params.min_tokens_to_keep, 1), V)
-
-    # the cut: descending position n-1 is ascending position V-n
-    at = (V - n)[:, None]
-    cut = jnp.take_along_axis(asc, at, axis=-1)
-    cut_id = jnp.take_along_axis(asc_ids, at, axis=-1)
-    return (scaled > cut) | ((scaled == cut) & (ids >= cut_id))
+    # the cut's index: the largest with n of the group at or above it
+    cut_id = _largest(
+        lambda i: count(group & (ids >= i)) >= n, max(V - 1, 1).bit_length(), pmax
+    )
+    keep = (x > cut) | ((x == cut) & (ids >= cut_id))
+    return keep.reshape(scaled.shape)
 
 
 @jax.named_scope(SCOPE_SAMPLE)
