@@ -219,11 +219,14 @@ class ComputeSettings(_EnvGroup):
     donate_activations: bool = True
     # MoE compute path: auto | dense | grouped | dispatch | a2a (ops/moe.py).
     # auto (the default) is exact and decided from static shapes: on one
-    # rank, a program with more rows than the ridge (RIDGE_ROWS, 256)
-    # computes its routed experts by a no-drop grouped matmul over rows
-    # sorted by expert, any other program by the dense einsum (under a tp
-    # axis always).  dispatch and a2a may DROP over-capacity tokens (GShard
-    # semantics), a throughput trade the operator opts into by name.
+    # rank, a program with more rows than the ridge (RIDGE_ROWS, 256), or
+    # one whose rows are expected to choose at most SPARSE_SHARE of the held
+    # experts (a decode step over a share of many experts; not under a tp
+    # axis or a vmap over lanes, not with quantized experts), computes its
+    # routed experts by a no-drop grouped matmul over rows sorted by expert,
+    # any other program by the dense einsum (on several ranks always).
+    # dispatch and a2a may DROP over-capacity tokens (GShard semantics), a
+    # throughput trade the operator opts into by name.
     moe_impl: str = "auto"
     # per-expert capacity = ceil(k * n_tokens * factor / n_experts);
     # <= 0 selects the exact no-drop capacity (C = n_tokens)

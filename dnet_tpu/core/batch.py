@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dnet_tpu.core.engine import LocalEngine, bucket_length, count_expert_rows
+from dnet_tpu.core.engine import LocalEngine, apply_whole, bucket_length, count_expert_rows
 from dnet_tpu.core.sampler import (
     MAX_LOGIT_BIAS,
     MAX_TOP_LOGPROBS,
@@ -661,8 +661,8 @@ class BatchedEngine:
                 return out if store.in_place else (out, rows)
 
             x = model.embed(ep, token)  # [slots, 1, D]
-            x, rows = model.apply_window(
-                wp, x, pool, pos[:, None], attend_fn=attend_fn
+            x, rows = apply_whole(
+                model, wp, x, pool, pos[:, None], attend_fn=attend_fn
             )
             # a model with an expert share says how many of each lane's
             # chosen experts it holds (dnet_moe_assignments_total)
@@ -1287,7 +1287,7 @@ class BatchedEngine:
             if self.kv_store is not None:
                 # the pool is attended IN PLACE through the page tables and
                 # the new rows block-append, all inside the launch
-                count_expert_rows(self.eng.model, self.slots, R)
+                count_expert_rows(self.eng.model, self.slots, R, whole=True)
                 flight.src, flight.moe = self._dispatch_ragged(
                     order, R, dev, table_ids, prev_token
                 )

@@ -34,6 +34,7 @@ from dnet_tpu.models import ModelConfig, get_ring_model_cls
 from dnet_tpu.obs import get_recorder, metric
 from dnet_tpu.obs.jit import instrument_jit
 from dnet_tpu.obs.phases import MOE_PATHS
+from dnet_tpu.ops.moe import whole_batch
 from dnet_tpu.utils.checkpoint import Checkpoint
 from dnet_tpu.utils.logger import get_logger
 
@@ -45,14 +46,24 @@ _LAYER_MS = metric("dnet_layer_compute_ms")
 _MOE_EXPERT_ROWS = metric("dnet_moe_expert_rows_total")
 
 
-def count_expert_rows(model, rows: int, passes: int = 1) -> None:
-    """Book `passes` launched programs of `rows` rows under the path their
-    routed experts take (dnet_moe_expert_rows_total{path=}; nothing for a
-    model without routed experts or a path chosen by name outside
-    MOE_PATHS).  Host arithmetic on static shapes: no device sync."""
-    path = model.moe_path(rows)
+def count_expert_rows(model, rows: int, passes: int = 1, whole: bool = False) -> None:
+    """Book `passes` launched programs of `rows` rows (`whole`: all the
+    rows the program carries, as `apply_whole` traced it; else one lane of
+    a program vmapped over the lanes) under the path their routed experts
+    take (dnet_moe_expert_rows_total{path=}; nothing for a model without
+    routed experts or a path chosen by name outside MOE_PATHS).  Host
+    arithmetic on static shapes: no device sync."""
+    path = model.moe_path(rows, whole)
     if path in MOE_PATHS:
         _MOE_EXPERT_ROWS.labels(path=path).inc(rows * passes)
+
+
+def apply_whole(model, *args, **kwargs):
+    """`model.apply_window` for a program that hands the model every row
+    it carries in this one trace, none of them under `jax.vmap` (ops/moe.py:
+    whole_batch): the routed experts may then go grouped under the ridge."""
+    with whole_batch():
+        return model.apply_window(*args, **kwargs)
 
 
 def bucket_length(n: int, min_bucket: int = 16) -> int:
@@ -101,6 +112,10 @@ class LocalEngine:
     # class default so engine subclasses with their own __init__ (MeshEngine)
     # are spec-ineligible unless they opt in
     spec_lookahead = 0
+    # this engine's programs hand the model every row they carry in one
+    # trace (`apply_whole`); a subclass whose programs run under a mesh
+    # axis or vmap lanes says False, and its rows are booked as they trace
+    whole_batch_programs = True
 
     def __init__(
         self,
@@ -291,6 +306,7 @@ class LocalEngine:
             raise NotImplementedError(
                 f"weight quantization not supported for {self.config.model_type}"
             )
+        m.experts_quantized = bool(self.weight_quant_bits) and "e_gate" in m.quant_keys
         if self.plan.streams_weights:
             # offload / sliding_fit: layers stream host<->HBM via WeightCache;
             # quantized layers shrink the host->HBM transfer (the streaming
@@ -350,7 +366,7 @@ class LocalEngine:
 
         def full_logits(window_params, edge_params, tokens, kv, pos, last_idx):
             x = model.embed(edge_params, tokens)
-            x, kv = model.apply_window(window_params, x, kv, pos, t_real=last_idx + 1)
+            x, kv = apply_whole(model, window_params, x, kv, pos, t_real=last_idx + 1)
             x_last = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, axis=1)
             x_last = model.normalize(edge_params, x_last)
             logits = model.lm_project(edge_params, x_last)
@@ -443,8 +459,8 @@ class LocalEngine:
         )
 
         def hidden_step(window_params, x, kv, pos, t_real, kinds=None):
-            return model.apply_window(
-                window_params, x, kv, pos, layer_kinds=kinds, t_real=t_real
+            return apply_whole(
+                model, window_params, x, kv, pos, layer_kinds=kinds, t_real=t_real
             )
 
         # mid-shard path (no embed/head): used by the ring runtime and the
@@ -457,8 +473,8 @@ class LocalEngine:
             XLA slices in place, no host-side weight copies)."""
             wp = jax.tree.map(lambda a: a[lo:hi], window_params)
             kv_r = jax.tree.map(lambda a: a[lo:hi], kv)
-            x, kv_r = model.apply_window(
-                wp, x, kv_r, pos, layer_kinds=kinds, t_real=t_real
+            x, kv_r = apply_whole(
+                model, wp, x, kv_r, pos, layer_kinds=kinds, t_real=t_real
             )
             kv = jax.tree.map(lambda f, s: f.at[lo:hi].set(s), kv, kv_r)
             return x, kv
@@ -470,13 +486,13 @@ class LocalEngine:
         def embed_window(window_params, edge_params, tokens, kv, pos, t_real):
             """First-shard path: embed + this shard's window, hidden out."""
             x = model.embed(edge_params, tokens)
-            return model.apply_window(window_params, x, kv, pos, t_real=t_real)
+            return apply_whole(model, window_params, x, kv, pos, t_real=t_real)
 
         self._embed_window = jax.jit(embed_window, donate_argnums=(3,))
 
         def hidden_tail(window_params, edge_params, x, kv, pos, last_idx, sp, key, counts):
             """Last-shard path: window + normalize + head + sample."""
-            x, kv = model.apply_window(window_params, x, kv, pos, t_real=last_idx + 1)
+            x, kv = apply_whole(model, window_params, x, kv, pos, t_real=last_idx + 1)
             x_last = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, axis=1)
             x_last = model.normalize(edge_params, x_last)
             logits = model.lm_project(edge_params, x_last)[:, 0]
@@ -495,7 +511,7 @@ class LocalEngine:
             from dnet_tpu.core.spec import make_spec_step
 
             def window_pass(wp, x, kv, pos, t_real):
-                return model.apply_window(wp, x, kv, pos, t_real=t_real)
+                return apply_whole(model, wp, x, kv, pos, t_real=t_real)
 
             self._spec_step = jax.jit(
                 make_spec_step(model, window_pass, L), donate_argnums=(3, 4)
@@ -514,7 +530,7 @@ class LocalEngine:
 
             def draft_forward(dwp, dep, tok, dkv, p):
                 x = dmodel.embed(dep, tok)
-                x, dkv = dmodel.apply_window(dwp, x, dkv, p, t_real=1)
+                x, dkv = apply_whole(dmodel, dwp, x, dkv, p, t_real=1)
                 x = dmodel.normalize(dep, x)
                 return dmodel.lm_project(dep, x)[:, 0], dkv
 
@@ -531,7 +547,7 @@ class LocalEngine:
                 drafts = jnp.moveaxis(drafts, 0, 1)  # [B, L]
                 block = jnp.concatenate([tok, drafts], axis=1)  # [B, L+1]
                 x = model.embed(ep, block)
-                x, kv = model.apply_window(wp, x, kv, pos, t_real=L + 1)
+                x, kv = apply_whole(model, wp, x, kv, pos, t_real=L + 1)
                 x = model.normalize(ep, x)
                 logits = model.lm_project(ep, x)
                 preds = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -544,7 +560,7 @@ class LocalEngine:
 
             def draft_prefill(dwp, dep, tokens, dkv, pos, t_real):
                 x = dmodel.embed(dep, tokens)
-                _, dkv = dmodel.apply_window(dwp, x, dkv, pos, t_real=t_real)
+                _, dkv = apply_whole(dmodel, dwp, x, dkv, pos, t_real=t_real)
                 return dkv
 
             self._draft_prefill = jax.jit(draft_prefill, donate_argnums=(3,))
@@ -762,7 +778,7 @@ class LocalEngine:
         Tpad = min(bucket_length(T), self.max_seq - sess.pos)
         tokens = np.zeros((self.batch, Tpad), dtype=np.int32)
         tokens[:, :T] = np.asarray(prompt_ids, dtype=np.int32)
-        count_expert_rows(self.model, self.batch * Tpad)
+        count_expert_rows(self.model, self.batch * Tpad, whole=self.whole_batch_programs)
         if self.plan.streams_weights:
             x = self.model.embed(self.edge_params, jnp.asarray(tokens))
             x = self.run_layers(sess, x, sess.pos, t_real=T)

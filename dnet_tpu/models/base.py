@@ -147,6 +147,10 @@ class RingModel(abc.ABC):
     # the exact grouped closure beside the dense one (ops/moe.py:
     # swiglu_grouped_closure), False = dense closures only
     moe_grouped: Optional[bool] = None
+    # the held experts are kept quantized: set at load by the engine whose
+    # programs may go grouped under the ridge (LocalEngine._load_params),
+    # read by the host (`moe_path`) and handed to the trace (`moe_apply`)
+    experts_quantized: bool = False
 
     def __init__(self, config: ModelConfig, layers: Sequence[int]):
         self.config = config
@@ -165,15 +169,24 @@ class RingModel(abc.ABC):
         self.moe_impl = cs.moe_impl
         self.moe_capacity_factor = cs.moe_capacity_factor
 
-    def moe_path(self, rows: int) -> Optional[str]:
+    def moe_path(self, rows: int, whole: Optional[bool] = None) -> Optional[str]:
         """The compute path this model's routed experts take in a one-rank
         program of `rows` rows: the rule `moe_apply` follows at trace time,
-        asked on the host (None: no routed experts)."""
+        asked on the host (None: no routed experts).  `whole`: the rows are
+        all the program carries, handed to the model in one trace (None: as
+        the trace around this call declares; ops/moe.py: whole_batch)."""
         if self.moe_grouped is None:
             return None
-        from dnet_tpu.ops.moe import resolve_moe_impl
+        from dnet_tpu.ops.moe import resolve_moe_impl, sparse_share, whole_batch_declared
 
-        return resolve_moe_impl(self.moe_impl, rows, 1, self.moe_grouped)
+        if whole is None:
+            whole = whole_batch_declared()
+        # the router's width: a share's `n_routed`, else the experts held
+        n_routed = getattr(self, "n_routed", 0) or self.config.num_local_experts
+        share = sparse_share(
+            rows, self.config.num_experts_per_tok, n_routed, whole, self.experts_quantized
+        )
+        return resolve_moe_impl(self.moe_impl, rows, 1, self.moe_grouped, share)
 
     def flash_layers(self) -> Tuple[Tuple[str, int], ...]:
         """(kind, window) of each local layer whose prefill chunk attends

@@ -242,6 +242,7 @@ class Cohere2MoeRingModel(RingModel):
             grouped_fn=swiglu_grouped_closure(
                 p, flat, top_idx, top_w, offset=self.expert_offset
             ),
+            quantized=self.experts_quantized,
         )
         out = out.astype(jnp.float32)
         if self.n_shared:
@@ -295,6 +296,19 @@ class Cohere2MoeRingModel(RingModel):
                 "needs its kinds passed (layer_kinds)"
             )
 
+        # routed experts grouped: the kernel reads each layer's experts out
+        # of the stack in place (ops/moe.py: grouped_matmul), so the scan
+        # closes over the stacks and carries the layer's index as well
+        stacks = None
+        if self.moe_path(x.shape[0] * x.shape[1]) == "grouped":
+            from dnet_tpu.ops.moe import expert_stacks
+
+            stacks = expert_stacks(window_params)
+        tail = () if stacks is None else (jnp.arange(L, dtype=jnp.int32),)
+
+        def stacked(p, layer):
+            return {**p, "e_stack": (stacks, layer[0])} if layer else p
+
         if attend_fn is not None:
             # the caller owns cache write and attention read: `kv` is its
             # own (per-kind) affair, never scanned over, and each layer
@@ -308,18 +322,22 @@ class Cohere2MoeRingModel(RingModel):
             )
 
             def body(xc, per):
-                p, kind, idx = per
-                xc, rows, held = self._layer(p, xc, kv, pos, kind, idx, None, None, attend_fn)
+                p, kind, idx, *layer = per
+                xc, rows, held = self._layer(
+                    stacked(p, layer), xc, kv, pos, kind, idx, None, None, attend_fn
+                )
                 return xc, dict(rows, moe_held=held)
 
-            return lax.scan(body, x, (window_params, kinds, within))
+            return lax.scan(body, x, (window_params, kinds, within, *tail))
 
         def body(xc, per):
-            p, kvs, kind = per
-            xc, kvs, _ = self._layer(p, xc, kvs, pos, kind, None, mask, kv_commit, None)
+            p, kvs, kind, *layer = per
+            xc, kvs, _ = self._layer(
+                stacked(p, layer), xc, kvs, pos, kind, None, mask, kv_commit, None
+            )
             return xc, kvs
 
-        return lax.scan(body, x, (window_params, kv, kinds))
+        return lax.scan(body, x, (window_params, kv, kinds, *tail))
 
     def normalize(self, edge_params: dict, x: jnp.ndarray) -> jnp.ndarray:
         return layer_norm(x, edge_params["final_norm"]["weight"], self.eps)

@@ -385,6 +385,7 @@ class DeepseekV2RingModel(TwoSegmentStackMixin, RingModel):
             self.moe_capacity_factor, k, tp_axis, dense,
             offset=off, n_routed=self.n_routed,
             grouped_fn=swiglu_grouped_closure(p, flat, topk_idx, topk_w, offset=off),
+            quantized=self.experts_quantized,
         )
 
         # shared experts are Megatron-split over tp (col/row), so their

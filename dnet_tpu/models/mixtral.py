@@ -72,6 +72,7 @@ class MixtralRingModel(LlamaRingModel):
             self.moe_impl, flat, top_idx, top_w, effn, E_local,
             self.moe_capacity_factor, k, tp_axis, dense,
             grouped_fn=swiglu_grouped_closure(p, flat, top_idx, top_w),
+            quantized=self.experts_quantized,
         )
         out = routed.astype(flat.dtype)
         if tp_axis is not None and routed_partial:

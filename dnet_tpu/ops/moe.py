@@ -12,14 +12,20 @@ over ICI.
 
 Four interchangeable compute paths over the same routed-FFN semantics:
 
-- dense       every token x every (local) expert; exact, and as cheap as
-              anything at or under the ridge (RIDGE_ROWS), where a pass
-              costs the read of the experts' weights whatever the rows.
+- dense       every token x every (local) expert; exact.  A pass costs the
+              read of EVERY held expert's weights whatever the rows, so it
+              is as cheap as anything only at or under the ridge
+              (RIDGE_ROWS) and only where the rows choose most of the held
+              experts anyway.
 - grouped     the (token, slot) assignments sorted by expert, the rows
               gathered, and gate / up / down run as grouped matmuls with
               per-expert group sizes (`grouped_matmul`): exact, nothing
-              dropped, work proportional to the rows.  What `auto` picks
-              above the ridge on one rank.
+              dropped, work proportional to the rows and weights read only
+              for the experts some row chose.  What `auto` picks on one
+              rank above the ridge, and under it where the routing leaves
+              enough held experts untouched (`resolve_moe_impl`: a decode
+              step of 16 lanes x top-10 over 512 routed reads 0.27 of
+              them).
 - dispatch    scatter tokens into per-expert capacity buffers [E, C, D], run
               the FFN once over the buffers, gather back weighted by the
               router probs.  FLOPs drop from N*E*ffn to E*C*ffn ~= k*cf*N*ffn.
@@ -39,7 +45,9 @@ scans cleanly.
 
 from __future__ import annotations
 
+import contextlib
 import math
+import threading
 from typing import Callable, Optional
 
 import jax.numpy as jnp
@@ -57,29 +65,63 @@ def expert_capacity(n_tokens: int, n_experts: int, k: int, factor: float) -> int
 
 MOE_IMPLS = ("auto", "dense", "grouped", "dispatch", "a2a")
 
-#: The most rows a program may carry and still take the dense einsum under
-#: `auto`.  A bf16 matmul on the v5e turns from memory- to compute-bound at
-#: 197e12 FLOP/s / 819e9 B/s = 240 FLOP a byte of weights = 240 rows: under
-#: it a dense pass over E experts costs the read of their weights whatever
-#: the rows (qwen3-30b-a3b at 256 rows: 0.64 ms a down projection measured,
-#: 0.49 ms to read its 403 MB, 0.52 ms for its 103 GFLOP: on the ridge), so
-#: an exact grouped matmul, which reads the same weights, has nothing to
-#: win there and its sort, gather and unsort to lose.  Above it the dense
-#: einsum pays E/k times the routed FLOPs.  Measured crossover: PERF.md
-#: section 6, PR 31.
+#: The ridge: a bf16 matmul on the v5e turns from memory- to compute-bound
+#: at 197e12 FLOP/s / 819e9 B/s = 240 FLOP a byte of weights = 240 rows.
+#: Above it the dense einsum pays E/k times the routed FLOPs and `auto` is
+#: `grouped`.  At or under it a dense pass costs the read of every held
+#: expert whatever the rows (qwen3-30b-a3b at 256 rows: 0.64 ms a down
+#: projection measured, 0.49 ms to read its 403 MB: on the ridge), and a
+#: grouped pass the read of the experts some row chose plus its sort,
+#: gather and unsort: which is less depends on how many the rows choose
+#: (SPARSE_SHARE).  Measured crossover by rows: PERF.md section 6, PR 31.
 RIDGE_ROWS = 256
 
+#: At or under the ridge `auto` is `grouped` where the routing is expected
+#: to touch at most this share of the held experts (`expected_share`), else
+#: `dense`.  From `scripts/moe_crossover.py --steps` (grouped / dense time
+#: a layer over three seeds, rows 1-256 at the four MoE cells' expert
+#: shapes; PERF.md section 6, PR 47) the ratio follows the share: 0.28-0.35
+#: at 0.27 (16 x top-10 of 512, 256 held: 2.16 -> 0.70 ms), 0.46-0.76 at
+#: 0.63 (32 x top-4 and 16 x top-8 of 128), 0.84-1.00 at 0.87 (32 x top-8
+#: of 128: 1.64 -> 1.45), 1.00-1.04 at 0.92, and from 0.95 up the einsum
+#: wins, by up to 11 % where every expert is read (128 x top-8 of 128:
+#: 1.64 -> 1.82): the sort, gather and unsort buy nothing there.  Every row
+#: at or under the constant ran at least 24 % faster grouped in every seed;
+#: between it and the break-even near 0.9 the layer's gain is 0-24 %,
+#: under what a cell resolves end to end (PERF.md section 7).
+SPARSE_SHARE = 0.65
 
-def resolve_moe_impl(impl: str, n_rows: int, ranks: int, grouped: bool) -> str:
+
+def expected_share(n_rows: int, k: int, n_routed: int) -> float:
+    """The share of its held experts a program of `n_rows` rows reads when
+    each row chooses `k` of `n_routed` experts uniformly: the chance that
+    some assignment falls on a given expert.  Uniform routing is the upper
+    bound (a skewed router touches fewer); 1.0 where the routing is not
+    known."""
+    if k <= 0 or n_routed <= 0:
+        return 1.0
+    return 1.0 - (1.0 - 1.0 / n_routed) ** (n_rows * k)
+
+
+def resolve_moe_impl(
+    impl: str, n_rows: int, ranks: int, grouped: bool, share: float = 1.0
+) -> str:
     """The compute path for a program of `n_rows` rows, from static shapes
     (this runs at trace time, so each padding bucket compiles the path that
     fits it, and on the host to count rows by path: `moe_path`).
 
-    `auto`: one rank, a grouped closure supplied (`grouped`) and more rows
-    than the ridge -> `grouped`; else `dense`.  Both are exact.  Under a tp
-    axis `auto` is `dense`; `dispatch` and `a2a` (capacity semantics, mesh
-    paths) are only ever chosen by name.  `grouped` by name falls to
-    `dense` where it cannot run (no closure, several ranks): same result.
+    `auto` on one rank with a grouped closure supplied (`grouped`): more
+    rows than the ridge -> `grouped`; at or under it `grouped` where the
+    rows are expected to choose at most SPARSE_SHARE of the held experts,
+    else `dense`.  `share` is `expected_share` of the program's routing
+    where it may go grouped under the ridge at all, and 1.0 (every expert:
+    the einsum) where it may not or nothing is known: rows that are one
+    lane's under a vmap (`whole_batch`), a tp axis, quantized experts (a
+    dequantized layer would be materialised whole).  Both are exact.  On
+    several ranks `auto` is `dense`; `dispatch` and `a2a` (capacity
+    semantics, mesh paths) are only ever chosen by name.  `grouped` by name
+    falls to `dense` where it cannot run (no closure, several ranks): same
+    result.
     """
     if impl not in MOE_IMPLS:
         # fail fast: a typo'd DNET_COMPUTE_MOE_IMPL would otherwise fall
@@ -89,7 +131,15 @@ def resolve_moe_impl(impl: str, n_rows: int, ranks: int, grouped: bool) -> str:
         return impl
     if not grouped or ranks > 1:
         return "dense"
-    return "grouped" if impl == "grouped" or n_rows > RIDGE_ROWS else "dense"
+    if impl == "grouped" or n_rows > RIDGE_ROWS or share <= SPARSE_SHARE:
+        return "grouped"
+    return "dense"
+
+
+def sparse_share(n_rows: int, k: int, n_routed: int, whole: bool, quantized: bool) -> float:
+    """The `share` `resolve_moe_impl` is told, by the trace (`moe_apply`)
+    and by the host (`RingModel.moe_path`) alike."""
+    return expected_share(n_rows, k, n_routed) if whole and not quantized else 1.0
 
 
 def route_positions(top_idx: jnp.ndarray, n_experts: int) -> jnp.ndarray:
@@ -172,6 +222,34 @@ def moe_dispatch_sharded(
     return gather_from_experts(ffn_local(xe), local_idx, pos, top_w)
 
 
+_TRACING = threading.local()
+
+
+@contextlib.contextmanager
+def whole_batch():
+    """Entered around a model call by a program that hands the model EVERY
+    row it carries in one trace (the paged step and the prefill chunk of
+    core/batch.py, `LocalEngine`'s programs): only there may a program at
+    or under the ridge take the grouped path.  Unmarked, the rows a trace
+    sees may be ONE lane's under `jax.vmap` over the lanes (the dense-slot
+    engines and the ring's `LanePool` vmap a one-row program), where the
+    batched dense einsum reads the weights once for all lanes and neither
+    `lax.ragged_dot` nor a kernel with scalar prefetch batches over them;
+    a trace cannot see a vmap around a `lax.scan` body, so the caller that
+    knows says so, and one that says nothing keeps the einsum.  The host's
+    twin: `RingModel.moe_path(rows, whole=True)`."""
+    was = whole_batch_declared()
+    _TRACING.whole = True
+    try:
+        yield
+    finally:
+        _TRACING.whole = was
+
+
+def whole_batch_declared() -> bool:
+    return getattr(_TRACING, "whole", False)
+
+
 def moe_apply(
     impl: str,
     flat: jnp.ndarray,
@@ -186,6 +264,7 @@ def moe_apply(
     offset: int = 0,
     n_routed: int = 0,
     grouped_fn: Optional[Callable[[], jnp.ndarray]] = None,
+    quantized: bool = False,
 ):
     """One MoE layer through the selected compute path (shared by every MoE
     model; the models supply only their ffn/dense closures and routing).
@@ -197,7 +276,8 @@ def moe_apply(
     alone.  A layer that holds every expert passes the whole range
     (offset 0, n_routed 0 = as many as it holds).  `grouped_fn`: the
     family's exact grouped-matmul closure (`swiglu_grouped_closure`), for
-    the families that have one.
+    the families that have one; `quantized`: the model's
+    `experts_quantized`, which the host's `moe_path` reads too.
 
     Returns (out [N, D], partial): partial=True means the output is a
     per-rank partial sum the caller must psum over tp_axis (the Megatron
@@ -206,7 +286,11 @@ def moe_apply(
     ranks = 1 if tp_axis is None else lax.axis_size(tp_axis)
     n_experts = n_local * ranks  # tp ranks shard the (held) expert dim
     n_routed = n_routed or n_experts
-    impl = resolve_moe_impl(impl, flat.shape[0], ranks, grouped_fn is not None)
+    # under a tp axis (of one rank too) a program at or under the ridge
+    # keeps the einsum: no caller that passes one declares `whole_batch`
+    whole = whole_batch_declared() and tp_axis is None
+    share = sparse_share(flat.shape[0], k, n_routed, whole, quantized)
+    impl = resolve_moe_impl(impl, flat.shape[0], ranks, grouped_fn is not None, share)
     if tp_axis is not None and (offset or n_routed != n_experts):
         raise NotImplementedError(
             "an expert share under a tp axis (the share is the expert-"
@@ -348,13 +432,35 @@ def swiglu_expert_closures(p, flat, scores, top_idx, top_w, tp_axis, offset: int
     return effn, dense, E_local
 
 
-#: rows one grid step of the grouped matmul multiplies by one expert's
-#: weights.  A step whose tile straddles experts is repeated for each, so
-#: work and weight reads grow with the tile: with about 128 sorted rows an
-#: expert (2048 rows, top-8 of 128) XLA's own lowering of `lax.ragged_dot`
-#: (tile 512) took 6.2 ms a layer where this tile takes 3.9 and the dense
-#: einsum 15.5 (PERF.md section 6, PR 31: the table).
+#: the most rows one grid step of the grouped matmul multiplies by one
+#: expert's weights.  A step whose tile straddles experts is repeated for
+#: each, so work and weight reads grow with the tile: with about 128 sorted
+#: rows an expert (2048 rows, top-8 of 128) XLA's own lowering of
+#: `lax.ragged_dot` (tile 512) took 6.2 ms a layer where this tile takes
+#: 3.9 and the dense einsum 15.5 (PERF.md section 6, PR 31: the table).
 GROUP_TILE_ROWS = 128
+
+#: the fewest: one bf16 sublane tile
+MIN_TILE_ROWS = 16
+
+#: the most columns of an expert's weights one grid step reads (the k and
+#: the n tile): a [1024, 1024] bf16 tile is 2 MB, two in flight.  At a
+#: step's rows 512 is 2-50 % slower and 2048 within 1 % where it fits
+#: VMEM (not for 4096-wide experts): PERF.md section 6, PR 47.
+GROUP_TILE_COLS = 1024
+
+
+def group_tile_rows(m: int) -> int:
+    """The row tile for `m` sorted rows, from the shape alone: the largest
+    of GROUP_TILE_ROWS, its halves and MIN_TILE_ROWS that divides `m` (a
+    prefill program's rows: 128; a decode step of 16 lanes x top-10: 32),
+    MIN_TILE_ROWS where none does (the rows are then padded to it).  Each
+    visit multiplies one tile by one expert, so a step's few rows an
+    expert also multiply less under a small tile."""
+    tile = GROUP_TILE_ROWS
+    while tile > MIN_TILE_ROWS and m % tile:
+        tile //= 2
+    return tile
 
 
 def grouped_matmul(
@@ -370,18 +476,20 @@ def grouped_matmul(
     layer's weights out of the stack before every call (qwen3-30b-a3b:
     1.2 GB a layer, twice the kernel's own time).  So the kernel takes the
     whole stack as [L*G, K, N] (a bitcast) with every other layer's groups
-    empty, and empty groups are never visited.
+    empty, and empty groups are never visited: neither are the groups of
+    this layer that no row chose, which is what a decode step gains.
 
     On a TPU (and in interpret mode under DNET_FLASH_INTERPRET=1) Pallas'
-    megablox kernel at GROUP_TILE_ROWS; elsewhere, and for a row count the
-    tile does not divide, `lax.ragged_dot`.  Not booked in `/health`'s
-    `kernels` block: the experts' path is counted by rows
+    megablox kernel at `group_tile_rows(M)`, the rows padded to the tile
+    where it does not divide them (the padding lies past the last group);
+    elsewhere `lax.ragged_dot`.  Not booked in `/health`'s `kernels`
+    block: the experts' path is counted by rows
     (dnet_moe_expert_rows_total).
     """
     from dnet_tpu.ops.kernel_select import kernel_backend
 
     backend = kernel_backend()
-    if backend is None or xs.shape[0] % GROUP_TILE_ROWS:
+    if backend is None:
         return lax.ragged_dot(xs, w if layer is None else w[layer], sizes)
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
@@ -391,11 +499,17 @@ def grouped_matmul(
         sizes = lax.dynamic_update_slice(
             jnp.zeros((n_layers * groups,), sizes.dtype), sizes, (layer * groups,)
         )
-    return gmm(
+    m = xs.shape[0]
+    tile = group_tile_rows(m)
+    pad = -m % tile
+    if pad:
+        xs = jnp.pad(xs, ((0, pad), (0, 0)))
+    out = gmm(
         xs, w, sizes, preferred_element_type=xs.dtype,
-        tiling=(GROUP_TILE_ROWS, min(w.shape[1], 1024), min(w.shape[2], 1024)),
+        tiling=(tile, min(w.shape[1], GROUP_TILE_COLS), min(w.shape[2], GROUP_TILE_COLS)),
         interpret=backend == "interpret",
     )
+    return out[:m] if pad else out
 
 
 EXPERT_KEYS = ("e_gate", "e_up", "e_down")

@@ -58,6 +58,10 @@ class MeshShardEngine(LocalEngine):
     same Session contract, the window math runs SPMD over `devices`.
     """
 
+    # every program here runs under the mesh's tp axis (ops/moe.py: under
+    # the ridge such a program keeps the dense einsum)
+    whole_batch_programs = False
+
     def __init__(
         self,
         model_dir: str | Path,

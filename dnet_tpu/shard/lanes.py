@@ -128,7 +128,10 @@ class LanePool:
         sample_one = lane_sampler(model)
 
         def window_one(wp, x, kv, pos, active):
-            """Shared body: one lane's window pass (B=1 re-added)."""
+            """Shared body: one lane's window pass (B=1 re-added).  The rows
+            are one lane's under the vmap below, so nothing is declared of
+            them and routed experts keep the dense einsum (ops/moe.py:
+            whole_batch)."""
             kv = jax.tree.map(lambda a: a[:, None], kv)
             x, kv = model.apply_window(wp, x, kv, pos, kv_commit=active)
             return x, jax.tree.map(lambda a: a[:, 0], kv)

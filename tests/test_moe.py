@@ -167,11 +167,14 @@ def test_moe_sharded_matches_dense(rng, eight_devices, impl):
 
 
 def test_resolve_moe_impl():
-    """(impl, rows, ranks, a grouped closure supplied): `auto` is exact,
-    chosen at the ridge on one rank; capacity paths only by name (the
-    cases by row count: tests/test_moe_grouped.py)."""
+    """(impl, rows, ranks, a grouped closure supplied, the share of the
+    held experts the rows choose): `auto` is exact, chosen on one rank by
+    the ridge and, under it, by that share; capacity paths only by name
+    (the cases by shape: tests/test_moe_grouped.py)."""
     assert resolve_moe_impl("dense", 10_000, 4, True) == "dense"  # explicit wins
-    assert resolve_moe_impl("auto", 8, 1, True) == "dense"  # decode-size
+    assert resolve_moe_impl("auto", 8, 1, True) == "dense"  # decode-size, share not known
+    assert resolve_moe_impl("auto", 8, 1, True, 0.9) == "dense"  # .. 16 onto 8
+    assert resolve_moe_impl("auto", 8, 1, True, 0.06) == "grouped"  # .. 16 onto 256
     assert resolve_moe_impl("auto", 256, 1, True) == "dense"  # on the ridge
     assert resolve_moe_impl("auto", 257, 1, True) == "grouped"
     assert resolve_moe_impl("auto", 257, 1, False) == "dense"  # no closure

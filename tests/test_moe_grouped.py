@@ -109,6 +109,48 @@ def test_the_chips_kernel_interpreted_equals_dense(family, monkeypatch):
     np.testing.assert_allclose(np.asarray(got), np.asarray(dense()), atol=5e-5, rtol=5e-5)
 
 
+@pytest.mark.parametrize("stacked", [False, True], ids=["one-layer", "stack"])
+@pytest.mark.parametrize("m, tile", [(160, 32), (128, 128), (320, 64), (48, 16), (10, 16), (4, 16)])
+def test_a_steps_rows_reach_the_kernel(m, tile, stacked, monkeypatch):
+    """A decode step's few sorted rows (16 lanes x top-10 = 160; a single
+    stream's 10 or 4) go through the kernel on the chip, not its twin:
+    the row tile is read off the row count, rows no tile divides are
+    padded past the last group, and the groups nobody chose (most, at a
+    step) are never visited.  Here the kernel interpreted, against
+    `lax.ragged_dot`."""
+    import jax.experimental.pallas.ops.tpu.megablox as megablox
+
+    from dnet_tpu.ops import moe
+
+    assert moe.group_tile_rows(m) == tile
+    rng = np.random.default_rng(m)
+    groups, K, N = 24, 128, 256
+    # most groups empty, and a tail of rows that no group covers
+    sizes = np.zeros(groups, np.int32)
+    chosen = rng.choice(groups, size=5, replace=False)
+    sizes[chosen] = rng.multinomial(m - max(1, m // 5), np.ones(5) / 5)
+    xs = jnp.asarray(rng.standard_normal((m, K)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((3, groups, K, N)), jnp.float32) * 0.1
+    want = np.asarray(jax.lax.ragged_dot(xs, w[1], jnp.asarray(sizes)))
+
+    tiles, real_gmm = [], megablox.gmm
+    monkeypatch.setattr(
+        megablox, "gmm", lambda *a, **kw: tiles.append(kw["tiling"][0]) or real_gmm(*a, **kw)
+    )
+    twin = []
+    real = moe.lax.ragged_dot
+    monkeypatch.setattr(moe.lax, "ragged_dot", lambda *a, **kw: twin.append(1) or real(*a, **kw))
+    monkeypatch.setenv("DNET_FLASH_INTERPRET", "1")
+    if stacked:
+        got = jax.jit(lambda layer: moe.grouped_matmul(xs, w, jnp.asarray(sizes), layer))(jnp.int32(1))
+    else:
+        got = moe.grouped_matmul(xs, w[1], jnp.asarray(sizes))
+    assert tiles == [tile] and not twin and got.shape == (m, N)
+    covered = int(sizes.sum())
+    assert 0 < covered < m
+    np.testing.assert_allclose(np.asarray(got)[:covered], want[:covered], atol=2e-4, rtol=2e-4)
+
+
 @pytest.mark.parametrize("kernel", ["twin", "interpret"])
 def test_a_layer_is_read_out_of_the_stack_in_place(kernel, monkeypatch):
     """What llama's layer scan hands on when the experts go grouped:
@@ -146,10 +188,14 @@ def test_a_forced_dense_program_never_calls_the_grouped_closure():
     def boom():
         raise AssertionError("grouped closure traced under dense")
 
-    for impl in ("dense", "auto"):  # 37 rows: under the ridge
+    for impl in ("dense", "auto"):  # 37 rows x top-2 of 8: under the ridge, every expert chosen
         out, _ = moe_apply(impl, flat, top_idx, top_w, effn, held, 0.0, 2, None, dense,
                            grouped_fn=boom)
         assert np.array_equal(np.asarray(out), np.asarray(dense()))
+
+
+#: a cell's step: (rows, top-k, routed experts), PERF.md section 4
+DOC, LAT, MIX, RAG = (16, 10, 512), (32, 4, 128), (16, 8, 128), (32, 8, 128)
 
 
 @pytest.mark.parametrize(
@@ -158,7 +204,7 @@ def test_a_forced_dense_program_never_calls_the_grouped_closure():
         ("auto", RIDGE_ROWS, 1, True, "dense"),  # on the ridge: the weight read either way
         ("auto", RIDGE_ROWS + 1, 1, True, "grouped"),
         ("auto", 2048, 1, True, "grouped"),
-        ("auto", 32, 1, True, "dense"),  # a decode step
+        ("auto", 32, 1, True, "dense"),  # a decode step that chooses 0.86 of the held
         ("auto", RIDGE_ROWS + 1, 1, False, "dense"),  # gpt_oss: no closure
         ("auto", 4096, 4, True, "dense"),  # under a tp axis auto is dense
         ("auto", 4096, 4, False, "dense"),
@@ -171,7 +217,136 @@ def test_a_forced_dense_program_never_calls_the_grouped_closure():
     ],
 )
 def test_resolve_moe_impl(impl, rows, ranks, closure, want):
-    assert resolve_moe_impl(impl, rows, ranks, closure) == want
+    """By rows, at the rag cell's routing (top-8 of 128)."""
+    from dnet_tpu.ops.moe import expected_share
+
+    assert resolve_moe_impl(impl, rows, ranks, closure, expected_share(rows, 8, 128)) == want
+    if rows > RIDGE_ROWS:  # above the ridge the share is not asked
+        assert resolve_moe_impl(impl, rows, ranks, closure) == want
+
+
+@pytest.mark.parametrize(
+    "step, share, want",
+    [
+        (DOC, 0.27, "grouped"),  # 16 lanes x top-10 over 512: 69 of 256 held a layer
+        (LAT, 0.63, "grouped"),  # measured 1.16 -> 0.59 ms a layer: admitted
+        (MIX, 0.63, "grouped"),  # 2.19 -> 1.52 (cohere2_moe hands its stack on too)
+        (RAG, 0.87, "dense"),  # 1.64 -> 1.45: within a skewed step's sort and gather
+        ((16, 4, 128), 0.39, "grouped"),  # lat: a prompt's last chunk in the 16-row bucket
+        ((1, 10, 512), 0.02, "grouped"),  # a single stream (LocalEngine)
+        ((1, 4, 128), 0.03, "grouped"),
+        ((1, 8, 128), 0.06, "grouped"),
+        ((1, 2, 8), 0.23, "grouped"),  # mixtral's 8 experts: one row ..
+        ((4, 2, 8), 0.66, "dense"),  # .. but not four
+        ((5, 2, 8), 0.74, "dense"),  # a speculative L + 1 rows there
+        ((64, 10, 512), 0.71, "dense"),  # a prompt's ragged last chunk in doc
+        ((16, 0, 0), 1.0, "dense"),  # routing not known: every expert
+    ],
+    ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else str(v),
+)
+def test_under_the_ridge_the_share_of_held_experts_chosen_decides(step, share, want):
+    from dnet_tpu.ops.moe import SPARSE_SHARE, expected_share, sparse_share
+
+    rows, k, n_routed = step
+    assert expected_share(rows, k, n_routed) == pytest.approx(share, abs=0.01)
+    assert (share <= SPARSE_SHARE) == (want == "grouped")
+    told = sparse_share(rows, k, n_routed, whole=True, quantized=False)
+    assert told == expected_share(rows, k, n_routed)
+    assert resolve_moe_impl("auto", rows, 1, True, told) == want
+    # what keeps the einsum whatever the share: no closure, several ranks,
+    # and a program that may not go grouped there, which is told 1.0: rows
+    # that are one lane's under a vmap (not declared whole), quantized
+    # experts (dq would materialise every one); a name wins
+    assert resolve_moe_impl("auto", rows, 1, False, told) == "dense"
+    assert resolve_moe_impl("auto", rows, 2, True, told) == "dense"
+    for whole, quantized in ((False, False), (True, True), (False, True)):
+        assert sparse_share(rows, k, n_routed, whole, quantized) == 1.0
+    assert resolve_moe_impl("auto", rows, 1, True, 1.0) == "dense"
+    assert resolve_moe_impl("auto", rows, 1, True) == "dense"
+    assert resolve_moe_impl("dense", rows, 1, True, told) == "dense"
+    assert resolve_moe_impl("grouped", rows, 1, True, 1.0) == "grouped"
+
+
+def test_quantized_experts_stay_grouped_above_the_ridge_as_before():
+    assert resolve_moe_impl("auto", RIDGE_ROWS + 1, 1, True, 1.0) == "grouped"
+
+
+def _model_like(step, held, **over):
+    """What `RingModel.moe_path` reads off a model, at a cell's routing."""
+    from types import SimpleNamespace
+
+    _, k, n_routed = step
+    fields = dict(
+        moe_grouped=True, moe_impl="auto", experts_quantized=False, n_routed=n_routed,
+        config=SimpleNamespace(num_experts_per_tok=k, num_local_experts=held),
+    )
+    return SimpleNamespace(**{**fields, **over})
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+@pytest.mark.parametrize("rows", [1, 16, 32, RIDGE_ROWS, 2048])
+@pytest.mark.parametrize(
+    "step, held", [(DOC, 256), (LAT, 16), (MIX, 16), (RAG, 128)], ids=["doc", "lat", "mix", "rag"]
+)
+def test_the_hosts_moe_path_is_the_traced_programs(step, held, rows, quantized):
+    """The counter of rows by path is booked on the host by the rule the
+    program was traced under: `RingModel.moe_path` and `moe_apply` agree
+    at every cell's routing, at its step's rows and around them, for a
+    program that declares its rows whole and for one that does not."""
+    import contextlib
+
+    from dnet_tpu.models.base import RingModel
+    from dnet_tpu.ops.moe import whole_batch
+
+    _, k, n_routed = step
+    flat = jnp.zeros((rows, 8))
+    idx = jnp.zeros((rows, k), jnp.int32)
+
+    def traced(held, **kw):
+        out, partial = moe_apply(
+            "auto", flat, idx, idx.astype(jnp.float32), None, held, 0.0, k, None,
+            lambda: "dense", grouped_fn=lambda: "grouped", **kw,
+        )
+        assert not partial
+        return out
+
+    like = _model_like(step, held, experts_quantized=quantized)
+    every = _model_like(step, n_routed, n_routed=0)  # a layer that holds every expert
+    for whole in (False, True):
+        with whole_batch() if whole else contextlib.nullcontext():
+            share = traced(held, n_routed=n_routed, quantized=quantized)
+            assert RingModel.moe_path(like, rows) == share  # as the trace around declares
+            assert RingModel.moe_path(every, rows) == traced(n_routed)
+        assert RingModel.moe_path(like, rows, whole) == share
+        if rows > RIDGE_ROWS:
+            assert share == "grouped"
+        elif not whole or quantized:  # the parent's rule: one lane's rows may be these
+            assert share == "dense"
+        elif rows == step[0]:
+            assert share == ("dense" if step is RAG else "grouped")
+    assert RingModel.moe_path(_model_like(step, held, moe_grouped=None), rows, True) is None
+
+
+@pytest.mark.parametrize("rows, want", [(1, 0.0), (32, 0.0), (RIDGE_ROWS + 1, 1.0)])
+def test_under_a_tp_axis_of_one_rank_the_ridge_alone_decides(rows, want):
+    """`parallel/pipelined.py` and `parallel/ring.py` always pass their tp
+    axis: on a mesh with tp = 1 a program above the ridge goes grouped as
+    before PR 47, and one at or under it keeps the einsum whatever its
+    share and whatever is declared around it."""
+    from dnet_tpu.ops.moe import whole_batch
+
+    flat = jnp.zeros((1, rows, 8))
+    idx = jnp.zeros((rows, 10), jnp.int32)
+
+    def one(x):
+        out, _ = moe_apply(
+            "auto", x, idx, idx.astype(jnp.float32), None, 256, 0.0, 10, "tp",
+            lambda: jnp.zeros(()), grouped_fn=lambda: jnp.ones(()),
+        )
+        return out
+
+    with whole_batch():
+        assert float(jax.vmap(one, axis_name="tp")(flat)[0]) == want
 
 
 def test_resolve_moe_impl_refuses_an_unknown_name():
@@ -199,7 +374,8 @@ def _tiny(family, d):
         write_checkpoint(d, cfg, seed=2**31 + 31, dtype="float32")
         return cfg
     make = {"qwen3_moe": ck.make_tiny_qwen3_moe, "mixtral": ck.make_tiny_mixtral,
-            "deepseek_v2": ck.make_tiny_deepseek_v2}[family]
+            "deepseek_v2": ck.make_tiny_deepseek_v2, "qwen3_next": ck.make_tiny_qwen3_next,
+            "mistral4": ck.make_tiny_mistral4}[family]
     return make(d)
 
 
@@ -318,6 +494,107 @@ def test_wide_chunks_give_the_logits_of_one_whole_prefill(chunks, tiny_dirs, pag
     # the decode step is a `slots`-row program: dense, and booked so
     assert _rows("grouped") - g0 == 512 and _rows("dense") - d0 == (3 if chunks == 1 else 19)
     eng.close()
+
+
+def _served_tokens(d, impl, prompts, steps, kernels=False):
+    """Greedy tokens of `prompts` decoded side by side through the paged
+    engine, one lane each; and the rows its steps booked by path."""
+    from dnet_tpu.core.batch import BatchedEngine
+    from dnet_tpu.core.types import DecodingParams
+
+    eng = BatchedEngine(d, slots=len(prompts), max_seq=128, param_dtype="float32")
+    eng.eng.model.moe_impl = impl
+    dec = DecodingParams(temperature=0.0)
+    last, got = {}, {n: [] for n in prompts}
+    for n, ids in prompts.items():
+        last[n] = int(eng.prefill_and_sample(n, ids, dec).token[0])
+    g0, d0 = _rows("grouped"), _rows("dense")
+    for s in range(1, steps + 1):
+        out, errs = eng.decode_batch({n: (last[n], dec) for n in prompts})
+        assert not errs
+        for n in prompts:
+            last[n] = eng.token_result(n, out[n], step=s, decoding=dec).token_id
+            got[n].append(last[n])
+    booked = (_rows("grouped") - g0, _rows("dense") - d0)
+    eng.close()
+    return got, booked
+
+
+@pytest.mark.parametrize(
+    "family, kernels",
+    [("qwen3_next", False), ("qwen3_next", True), ("cmdaplus-share", False), ("mistral4", False)],
+    ids=["qwen3_next", "qwen3_next-interpreted", "cmdaplus-share", "mistral4"],
+)
+def test_a_paged_decode_step_reads_only_the_chosen_experts_and_keeps_its_tokens(
+    family, kernels, tiny_dirs, paged_env
+):
+    """`auto`, nothing forced: two lanes of a share (16 held of 32 routed,
+    top-4; 16 of 128, top-8; 4 of 8, top-2) choose under half of the held
+    experts, so the step's routed experts go grouped: out of the stack in
+    place in all three families' scans, through the hybrid store, the
+    pools by kind and the latent pool.  The tokens are the dense run's and
+    every step's rows are booked grouped."""
+    if kernels:  # the chip's kernels, interpreted: the step's 8 rows padded to a tile
+        from dnet_tpu.config import reset_settings_cache
+
+        paged_env.setenv("DNET_FLASH_INTERPRET", "1")
+        reset_settings_cache()
+    cfg, d = tiny_dirs(family)
+    rng = np.random.default_rng(47)
+    prompts = {
+        n: [int(i) for i in rng.integers(1, cfg["vocab_size"], size=size)]
+        for n, size in (("a", 21), ("b", 13))
+    }
+    want, (g, dn) = _served_tokens(d, "dense", prompts, steps=6)
+    assert g == 0 and dn == 2 * 6
+    from dnet_tpu.ops import moe
+
+    layers, real = [], moe.grouped_matmul
+    paged_env.setattr(
+        moe, "grouped_matmul", lambda xs, w, sizes, layer=None: layers.append(layer)
+        or real(xs, w, sizes, layer),
+    )
+    got, (g, dn) = _served_tokens(d, "auto", prompts, steps=6)
+    assert got == want
+    assert g == 2 * 6 and dn == 0
+    # the layer scan handed the kernel the stack and the layer's index
+    assert layers and all(layer is not None for layer in layers)
+
+
+def test_lanes_vmapped_over_a_one_row_program_keep_the_einsum(tiny_dirs, monkeypatch):
+    """The dense-slot engine vmaps a ONE-row program over its lanes: alone
+    that row would choose 0.44 of the 4 experts and go grouped (as
+    LocalEngine's single stream does), but no grouped closure batches over
+    lanes, and the batched einsum reads the weights once for all of them.
+    Only a program that declares its rows whole (`ops/moe.py: whole_batch`)
+    goes grouped under the ridge; this one says nothing, traces no grouped
+    closure, and the host books the same."""
+    from dnet_tpu.core.batch import BatchedEngine
+    from dnet_tpu.core.types import DecodingParams
+    from dnet_tpu.ops import moe
+
+    cfg, d = tiny_dirs("qwen3_moe")
+    eng = BatchedEngine(d, slots=2, max_seq=64, param_dtype="float32", kv_paged=False)
+    model = eng.eng.model
+    assert model.moe_path(1, whole=True) == "grouped"
+    assert model.moe_path(1) == model.moe_path(1, whole=False) == "dense"
+    dec = DecodingParams(temperature=0.0)
+    tok = int(eng.prefill_and_sample("a", list(range(3, 20)), dec).token[0])
+    calls, real = [], moe.grouped_matmul
+    monkeypatch.setattr(moe, "grouped_matmul", lambda *a, **kw: calls.append(1) or real(*a, **kw))
+    g0, d0 = _rows("grouped"), _rows("dense")
+    out, errs = eng.decode_batch({"a": (tok, dec)})
+    assert not errs and (_rows("grouped") - g0, _rows("dense") - d0) == (0, 2)
+    assert not calls
+    eng.close()
+    # .. and the declaration is the trace's: it ends with the call it is around
+    flat, idx = jnp.zeros((1, 8)), jnp.zeros((1, 2), jnp.int32)
+    args = ("auto", flat, idx, idx.astype(jnp.float32), None, 4, 0.0, 2, None, lambda: "dense")
+    with moe.whole_batch():
+        assert moe.whole_batch_declared()
+        assert moe_apply(*args, grouped_fn=lambda: "grouped")[0] == "grouped"
+    assert not moe.whole_batch_declared()
+    assert moe_apply(*args, grouped_fn=lambda: "grouped")[0] == "dense"
 
 
 def test_a_window_model_takes_a_chunk_wider_than_256_past_its_window(tiny_dirs, paged_env):

@@ -279,3 +279,117 @@ def test_flash_prefill_takes_its_position_as_a_prefetch_and_a_grid_bound(
     assert len(calls[0].split(", ")) == 6
     # merged heads in, merged heads out: no copy as large as the row
     assert not re.search(rf"copy\([^)]*\[1,{S},", text)
+
+
+def _qwen3_next_params(cfg: dict, periods: int, dtype):
+    """`models/qwen3_next.py`'s window parameters as shapes, from a config's
+    numbers (held to the loader's own tree at the tiny size below)."""
+    D, Hd = cfg["hidden_size"], cfg["head_dim"]
+    H, KVH = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    HK, HV = cfg["linear_num_key_heads"], cfg["linear_num_value_heads"]
+    Dk, Dv, taps = cfg["linear_key_head_dim"], cfg["linear_value_head_dim"], cfg["linear_conv_kernel_dim"]
+    E, F, R = cfg["num_experts"], cfg["moe_intermediate_size"], cfg["num_experts_routed"]
+    Fs, C = cfg["shared_expert_intermediate_size"], 2 * HK * Dk + HV * Dv
+    tree = {
+        "attn": {"attn_norm": (D,), "k_norm": (Hd,), "q_norm": (Hd,), "w_qgate": (D, H * Hd),
+                 "wk": (D, KVH * Hd), "wo": (H * Hd, D), "wq": (D, H * Hd), "wv": (D, KVH * Hd)},
+        "gdn": {"A_log": (HV,), "attn_norm": (D,), "conv_w": (taps, C), "dt_bias": (HV,),
+                "o_norm": (Dv,), "w_a": (D, HV), "w_b": (D, HV), "w_qkv": (D, C),
+                "w_z": (D, HV * Dv), "wo": (HV * Dv, D)},
+        "moe": {"e_down": (E, F, D), "e_gate": (E, D, F), "e_up": (E, D, F), "gate_w": (D, R),
+                "mlp_norm": (D,), "s_down": (Fs, D), "s_gate": (D, Fs), "s_up": (D, Fs),
+                "sg_w": (D, 1)},
+    }
+    lead = {"attn": (periods,), "gdn": (periods, 3), "moe": (periods, 4)}
+    return {
+        group: {n: jax.ShapeDtypeStruct(lead[group] + s, dtype) for n, s in leaves.items()}
+        for group, leaves in tree.items()
+    }
+
+
+def test_the_doc_cells_decode_step_reads_its_experts_through_the_grouped_kernel(
+    one_chip, no_cache, tmp_path, monkeypatch
+):
+    """The doc cell's step (16 lanes x top-10 over 512 routed, 256 held of
+    2048 x 512 in each of 4 layers: 69 of 256 chosen a layer) as the v5e
+    compiles `apply_window` for it, the store's kernels stubbed out: the
+    routed experts are `gmm` custom calls over the WHOLE stack in place,
+    no dot runs over the 256 held experts and no expert stack (1.6 GB a
+    layer) is copied or sliced out for a kernel."""
+    import json
+    from pathlib import Path
+
+    from tests.fakes.checkpoints import make_tiny_qwen3_next
+
+    from dnet_tpu.core.engine import LocalEngine, apply_whole
+    from dnet_tpu.models import get_ring_model_cls
+    from dnet_tpu.models.base import ModelConfig
+    from dnet_tpu.obs.phases import KV_KIND_STATE
+    from dnet_tpu.ops import kernel_select
+
+    # the shapes' formula is the loader's tree, at the size a loader can run
+    tiny = make_tiny_qwen3_next(tmp_path)
+    eng = LocalEngine(tmp_path, max_seq=64, param_dtype="float32")
+    assert jax.tree.map(lambda a: a.shape, eng.window_params) == jax.tree.map(
+        lambda a: a.shape, _qwen3_next_params(tiny, 1, jnp.float32)
+    )
+    eng.close()
+
+    root = Path(__file__).resolve().parents[1]
+    cfg = json.loads((root / "benchmarks/configs/qwen3-next-80b-a3b-4l-ep2.json").read_text())
+    model = get_ring_model_cls("qwen3_next")(ModelConfig.from_hf(cfg), range(4))
+    lanes, E, D, F = 16, cfg["num_experts"], cfg["hidden_size"], cfg["moe_intermediate_size"]
+    assert (E, D, F, model.n_routed) == (256, 2048, 512, 512)
+    assert model.moe_path(lanes, whole=True) == "grouped"
+    assert model.moe_path(64, whole=True) == model.moe_path(lanes) == "dense"
+
+    def attend(q, k, v, store, kind, layer, gate=None):
+        """The store's hook, without its kernels: something of the mixer's
+        output shape that depends on its input."""
+        n = model.HV * model.Dv if kind == KV_KIND_STATE else q.shape[2] * q.shape[3]
+        return q.reshape(lanes, 1, -1)[..., :n], store
+
+    def step(params, x, pos):  # the paged step's call (core/batch.py: ragged_step)
+        return apply_whole(model, params, x, {}, pos, attend_fn=attend)[0]
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+        )
+
+    args = on_chip((
+        _qwen3_next_params(cfg, 1, BF), jax.ShapeDtypeStruct((lanes, 1, D), BF),
+        jax.ShapeDtypeStruct((lanes, 1), jnp.int32),
+    ))
+    monkeypatch.setattr(kernel_select, "on_tpu", lambda: True)  # the chip's branch
+    text = jax.jit(step).trace(*args).lower(lowering_platforms=("tpu",)).compile().as_text()
+    # gate, up and down of each of the period's four layers: twelve
+    # kernels, each handed all 4 x 256 experts of the stack as its groups;
+    # a layer's three share ONE set of group metadata (XLA merges the
+    # three `make_group_metadata` of `gmm`)
+    calls = re.findall(
+        r"%gmm[.\w]* = [^\n]*custom-call\(([^\n]*?)\), custom_call_target=\"tpu_custom_call\", "
+        r"operand_layout_constraints=\{(.*?)\}, frontend_attributes", text,
+    )
+    assert len(calls) == 12, len(calls)
+    stacks = (f"bf16[{4 * E},{D},{F}]", f"bf16[{4 * E},{F},{D}]")
+    assert all(any(st in shapes for st in stacks) for _, shapes in calls), calls[0]
+    metadata = [tuple(operands.split(", ")[:4]) for operands, _ in calls]
+    assert len(set(metadata)) == 4 and all(metadata.count(m) == 3 for m in metadata)
+    # nothing multiplies, copies or slices out a layer's 256 experts
+    for m in re.finditer(
+        r"^\s*(?:ROOT\s+)?%?([\w.-]+) = \w+\[([\d,]+)\][^\n]*? (\w[\w-]*)\(", text, re.M
+    ):
+        name, dims, op = m.group(1), [int(d) for d in m.group(2).split(",")], m.group(3)
+        assert int(np.prod(dims)) < E * D * F or op in ("parameter", "bitcast", "get-tuple-element"), (
+            name, dims, op
+        )
+    # .. nor names one layer's share of the stack at all (the dense einsum's
+    # fusions take `[256, 2048, 512]` slices of it)
+    assert not re.search(rf"bf16\[(1,)?{E},({D},{F}|{F},{D})\]", text)
+    model.moe_impl = "dense"
+    dense = (  # a new function: the trace of `step` above is cached
+        jax.jit(lambda *a: step(*a)).trace(*args).lower(lowering_platforms=("tpu",))
+        .compile().as_text()
+    )
+    assert "%gmm" not in dense and re.search(rf"bf16\[(1,)?{E},({D},{F}|{F},{D})\]", dense)

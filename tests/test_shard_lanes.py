@@ -177,6 +177,94 @@ def test_single_shard_ring_lanes(tiny_llama_dir):
     assert got == want
 
 
+@pytest.fixture(scope="module")
+def tiny_moe_dirs(tmp_path_factory):
+    from tests.fakes import checkpoints as ck
+
+    make = {"qwen3_moe": ck.make_tiny_qwen3_moe, "mixtral": ck.make_tiny_mixtral,
+            "deepseek_v2": ck.make_tiny_deepseek_v2}
+    made = {}
+
+    def get(family):
+        if family not in made:
+            made[family] = tmp_path_factory.mktemp(f"lanes_{family}")
+            make[family](made[family])
+        return made[family]
+
+    return get
+
+
+@pytest.mark.parametrize("split", [True, False], ids=["head-tail", "one-shard"])
+@pytest.mark.parametrize("family", ["qwen3_moe", "mixtral", "deepseek_v2"])
+def test_moe_lanes_keep_the_dense_einsum_and_match_solo(family, split, tiny_moe_dirs, monkeypatch):
+    """The pool vmaps a ONE-row window over its lanes.  Alone that row (top-2
+    of 4 experts: 0.44 of them chosen) goes grouped, as the solo session's
+    step does; under the vmap no grouped closure may be traced (neither
+    `lax.ragged_dot` nor the kernel batches over lanes, and the batched
+    einsum reads the experts once for all lanes).  The pool says nothing
+    (ops/moe.py: whole_batch) and so keeps the einsum: head, tail and the
+    fused one-shard program, with the solo streams' tokens."""
+    from dnet_tpu.ops import moe
+    from dnet_tpu.shard.compute import ShardCompute
+
+    d = tiny_moe_dirs(family)
+    calls, real = [], moe.grouped_matmul
+    monkeypatch.setattr(
+        moe, "grouped_matmul", lambda *a, **kw: calls.append(1) or real(*a, **kw)
+    )
+
+    def shards(lanes):
+        spans = ([0, 1], [2, 3]) if split else ([0, 1, 2, 3],)
+        return [
+            ShardCompute(d, span, max_seq=64, param_dtype="float32",
+                         wire_dtype="float32", lanes=lanes)
+            for span in spans
+        ]
+
+    dec = DecodingParams(temperature=0.0)
+    prompts = {"x": [256, 72, 101], "y": [7, 3, 11, 5]}
+    n_tok = 5
+
+    def step(chain, members, seq):
+        msg = _batch_frame(members, seq) if len(members) > 1 else None
+        if msg is None:
+            (n, tok, pos, _), = members
+            arr = np.asarray([[tok]], dtype=np.int32)
+            msg = ActivationMessage(
+                nonce=n, layer_id=-1, seq=seq, dtype="tokens", shape=arr.shape,
+                data=arr.tobytes(), pos=pos, decoding=dec,
+            )
+        for sc in chain:
+            msg = sc.process(msg)
+        assert msg.is_final
+        if msg.lane_finals is None:
+            return {members[0][0]: msg.token_id}
+        return {f["nonce"]: int(f["token_id"]) for f in msg.lane_finals}
+
+    want = {}
+    for n, ids in prompts.items():  # solo: a lane-free chain, one row a step
+        chain = shards(0)
+        want[n] = [_prefill(chain, n, ids, dec)]
+        before = len(calls)
+        for s in range(1, n_tok):
+            want[n].append(step(chain, [(n, want[n][-1], len(ids) + s - 1, dec)], s)[n])
+        assert len(calls) > before  # the solo step's one row went grouped
+        for sc in chain:
+            sc.engine.close()
+
+    chain = shards(2)
+    got = {n: [_prefill(chain, n, ids, dec)] for n, ids in prompts.items()}
+    before = len(calls)
+    for s in range(1, n_tok):
+        out = step(chain, [(n, got[n][-1], len(prompts[n]) + s - 1, dec) for n in prompts], s)
+        for n in prompts:
+            got[n].append(out[n])
+    assert len(calls) == before  # nothing grouped was traced under the lanes' vmap
+    for sc in chain:
+        sc.engine.close()
+    assert got == want
+
+
 def test_faulted_lane_fails_alone(tiny_llama_dir):
     """A bad member (stale pos / reset race) is flagged and error-failed
     ALONE; its batchmate's stream continues exactly."""
