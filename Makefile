@@ -5,14 +5,14 @@
 
 PY ?= python
 
-.PHONY: tier1 dnetlint dnetlint-diff dnetlint-report bench-compare bench-fleet chaos chaos-smoke
+.PHONY: tier1 dnetlint dnetlint-diff dnetlint-report bench-compare chaos chaos-smoke
 
 tier1:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m 'not slow' \
 		--continue-on-collection-errors -p no:cacheprovider
 
-# regression diff of two BENCH_SERVE records:
-#   make bench-compare OLD=BENCH_SERVE_r04.json NEW=BENCH_SERVE_r05.json \
+# regression diff of two load-test records (dnet_tpu/loadgen/compare.py):
+#   make bench-compare OLD=<old>.json NEW=<new>.json \
 #        FAIL_ON='--fail-on goodput.tok_s=-5%'
 # the events sanity leg runs first: the wide-event vocabulary must agree
 # with the dnet_events_total exposition (metrics pass 15) before bench
@@ -21,20 +21,6 @@ tier1:
 bench-compare:
 	JAX_PLATFORMS=cpu $(PY) scripts/check_metrics_names.py
 	$(PY) scripts/bench_compare.py $(OLD) $(NEW) $(FAIL_ON)
-
-# fleet front-door legs (bench_serve --fleet 2): 1-replica vs 2-replica
-# vs mid-burst failover over MODEL (a checkpoint dir).  The r07 gates,
-# applied when diffing against a prior fleet record:
-#   make bench-compare OLD=BENCH_SERVE_r07.json NEW=<new>.json \
-#        FAIL_ON='--fail-on comparison.goodput_ratio=-10% \
-#                 --fail-on comparison.failover_http_5xx=+0 \
-#                 --fail-on comparison.ttft_p99_ms_two=+25%'
-# (goodput_ratio is the 2-replica/1-replica goodput multiple — the
-# >=1.8x scaling claim; failover_http_5xx=+0 is absolute: any 5xx during
-# the kill-mid-burst drill is a regression)
-bench-fleet:
-	JAX_PLATFORMS=cpu DNET_OBS_ENABLED=1 $(PY) bench_serve.py \
-		--model $(MODEL) --fleet 2 $(ARGS)
 
 # chaos campaigns (scripts/chaos_campaign.py): the smoke slice is <= 8
 # cells over the fast scenarios and exits 1 on any invariant violation —

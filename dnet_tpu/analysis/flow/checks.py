@@ -6,7 +6,7 @@ Per-node pattern matching (DL001-DL020) cannot see "used *after*" or
 
 DL021 — donation-after-use: a name passed at a ``donate_argnums`` /
 ``donate_argnames`` position of a jitted callable (resolved through
-``instrument_jit`` wrappers, factory methods, and ``*args`` tuples — see
+``instrument_jit`` wrappers and ``*args`` tuples — see
 flow/jitmodel.py) is read on some CFG path after the call without being
 reassigned.  XLA frees donated buffers; on CPU the read silently works,
 on TPU it is garbage.  The sanctioned quiet pattern is the

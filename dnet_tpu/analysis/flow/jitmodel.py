@@ -3,18 +3,18 @@
 The flow passes need to know, for a call like ``self._step(*args)``, that
 ``self._step`` is ``jax.jit(fn, donate_argnums=(3, 8))`` — possibly
 wrapped in ``instrument_jit`` (the ``JIT_FNS`` seed set from
-``obs.phases``) and possibly produced by a factory method
-(``self._chunk_fn(R)`` returning a per-width jitted program).  This module
-builds that map per source file with the same call-graph spirit as DL004:
+``obs.phases``).  This module builds that map per source file with the
+same call-graph spirit as DL004:
 
 - direct bindings: ``x = jax.jit(f, ...)``, ``self._step =
   instrument_jit(jax.jit(f, donate_argnums=(3, 8)), "batched_step")``,
   dict-literal bindings (``self._programs = {"head": jax.jit(...)}``)
   keyed by their constant string;
-- decorator entries: ``@jax.jit`` / ``@partial(jax.jit, ...)`` defs;
-- factories: a function whose return value resolves to a jit binding
-  (returning the jit call directly, or a local name bound to one)
-  registers under ``<fname>()`` so ``self._chunk_fn(R)(*args)`` resolves.
+- decorator entries: ``@jax.jit`` / ``@partial(jax.jit, ...)`` defs.
+
+A program that a factory method returns (``self._rot_fn(R)(*args)``,
+parallel/pipelined.py) is not modelled: no such call site in the tree
+resolves within its own file.
 
 ``donate_argnums`` / ``static_argnums`` are honoured only when literal
 ints/tuples — a computed tuple (``donate_argnums=donate``) yields a spec
@@ -140,31 +140,6 @@ def _unwrap_jit(node: ast.AST) -> Optional[Tuple[ast.Call, Optional[str]]]:
     return None
 
 
-def _returned_spec(fn: ast.AST) -> Optional[JitSpec]:
-    """Spec of the jitted callable a factory returns: either the jit call
-    directly, or a local name bound to one anywhere in the factory."""
-    local: Dict[str, JitSpec] = {}
-    for node in ast.walk(fn):
-        if isinstance(node, ast.Assign):
-            hit = _unwrap_jit(node.value)
-            if hit is None:
-                continue
-            for t in node.targets:
-                if isinstance(t, ast.Name):
-                    local[t.id] = _spec_from_jit_call(
-                        hit[0], hit[1] or t.id
-                    )
-    for node in ast.walk(fn):
-        if isinstance(node, ast.Return) and node.value is not None:
-            hit = _unwrap_jit(node.value)
-            if hit is not None:
-                return _spec_from_jit_call(hit[0], hit[1] or fn.name)
-            d = dotted(node.value)
-            if d in local:
-                return local[d]
-    return None
-
-
 def scope_chain(src: SourceFile, node: ast.AST) -> Tuple[str, ...]:
     """Names of the function defs enclosing ``node``, outermost first."""
     names: List[str] = []
@@ -181,12 +156,10 @@ def _scoped_key(chain: Tuple[str, ...], name: str) -> str:
 def jit_bindings(src: SourceFile) -> Dict[str, JitSpec]:
     """dotted binding -> :class:`JitSpec` for one module.
 
-    Keys are the names call sites use: ``self._step``, ``step_fn``,
-    ``self._programs['head']`` (dict-literal bindings), and
-    ``self._chunk_fn()`` / ``_make_chunk()`` (factories — the trailing
-    ``()`` marks "the value this callable returns").  Plain-name bindings
-    inside a function are scoped to it (``'outer/inner:name'``) so two
-    factories' local ``jitted`` variables never collide; dotted
+    Keys are the names call sites use: ``self._step``, ``step_fn`` and
+    ``self._programs['head']`` (dict-literal bindings).  Plain-name
+    bindings inside a function are scoped to it (``'outer/inner:name'``)
+    so two functions' local ``jitted`` variables never collide; dotted
     (``self.*``) bindings are module-wide."""
     out: Dict[str, JitSpec] = {}
     tree = src.tree
@@ -228,10 +201,6 @@ def jit_bindings(src: SourceFile) -> Dict[str, JitSpec]:
                     hit = _unwrap_jit(dec)
                     if hit is not None:
                         out[node.name] = _spec_from_jit_call(hit[0], node.name)
-            spec = _returned_spec(node)
-            if spec is not None:
-                out[f"{node.name}()"] = spec
-                out[f"self.{node.name}()"] = spec
     return out
 
 
@@ -242,10 +211,10 @@ def resolve_jit_call(
 ) -> Optional[JitSpec]:
     """The spec a call site dispatches to, or None.
 
-    Handles ``self._step(...)`` (direct), ``self._chunk_fn(R)(...)``
-    (factory result), and ``self._programs['head'](...)`` (dict
-    binding).  With ``src``, plain-name lookups walk the call's scope
-    chain innermost-out, matching the function-scoped binding keys."""
+    Handles ``self._step(...)`` (direct) and
+    ``self._programs['head'](...)`` (dict binding).  With ``src``,
+    plain-name lookups walk the call's scope chain innermost-out, matching
+    the function-scoped binding keys."""
     func = call.func
     d = dotted(func)
     if d:
@@ -260,12 +229,6 @@ def resolve_jit_call(
             return spec
         short = d.split(".", 1)[-1] if d.startswith("self.") else d
         return bindings.get(short)
-    if isinstance(func, ast.Call):
-        fd = dotted(func.func)
-        if fd:
-            return bindings.get(f"{fd}()") or bindings.get(
-                f"{fd.split('.', 1)[-1] if fd.startswith('self.') else fd}()"
-            )
     if isinstance(func, ast.Subscript):
         base = dotted(func.value)
         if base and isinstance(func.slice, ast.Constant) and isinstance(

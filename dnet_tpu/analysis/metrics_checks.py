@@ -457,12 +457,10 @@ def check_attribution_labels(errors: list) -> int:
     checked on `_count` and strays on any exposition suffix.  Also: every
     `span("...")` / `observe_span("...")` literal in the tree names a
     declared host span and every declared span has a call site (by
-    constant or literal), and BatchedEngine.CHUNK_BUCKETS is exactly the
-    declared dispatch widths above 1."""
+    constant or literal)."""
     from dnet_tpu.obs import get_registry
     from dnet_tpu.obs import phases
     from dnet_tpu.obs.phases import (
-        DECODE_CHUNK_WIDTHS,
         DECODE_TOKEN_SOURCES,
         DEVICE_MEM_KINDS,
         HOST_SPANS,
@@ -517,11 +515,6 @@ def check_attribution_labels(errors: list) -> int:
                 f"no span()/observe_span() call site opens it"
             )
     n += _cross_check_labels(
-        errors, text, "dnet_decode_dispatch_total", "r",
-        tuple(str(w) for w in DECODE_CHUNK_WIDTHS),
-        "obs.phases.DECODE_CHUNK_WIDTHS",
-    )
-    n += _cross_check_labels(
         errors, text, "dnet_decode_tokens_total", "source",
         DECODE_TOKEN_SOURCES, "obs.phases.DECODE_TOKEN_SOURCES",
     )
@@ -574,15 +567,6 @@ def check_attribution_labels(errors: list) -> int:
         errors, text, "dnet_sched_drivers_turn_total", "outcome",
         DRIVERS_TURN_OUTCOMES, "obs.phases.DRIVERS_TURN_OUTCOMES",
     )
-    from dnet_tpu.core.batch import BatchedEngine
-
-    n += 1
-    if set(BatchedEngine.CHUNK_BUCKETS) | {1} != set(DECODE_CHUNK_WIDTHS):
-        errors.append(
-            f"attribution: BatchedEngine.CHUNK_BUCKETS "
-            f"{BatchedEngine.CHUNK_BUCKETS!r} + 1 != "
-            f"obs.phases.DECODE_CHUNK_WIDTHS {DECODE_CHUNK_WIDTHS!r}"
-        )
     n += _cross_check_labels(
         errors, text, "dnet_jit_compiles_total", "fn",
         JIT_FNS, "obs.phases.JIT_FNS",

@@ -320,11 +320,7 @@ class LocalModelManager:
             # the most expensive compiles in the codebase), not on the
             # first request while every lane shares one executor
             if get_settings().api.warm_on_load:
-                if plan.adapter == "SchedulerAdapter":
-                    # the scheduler dispatches single steps alone
-                    engine.warm_chunks(fused=False)
-                else:
-                    engine.warm_chunks()
+                engine.warm_chunks()
             return plan, engine, load_tokenizer(model_dir)
 
         plan, engine, tokenizer = await loop.run_in_executor(None, _build)

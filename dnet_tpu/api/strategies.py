@@ -198,8 +198,6 @@ class BatchedLocalAdapter(ApiAdapterBase):
     PREFILL_CHUNK = 256  # prompt tokens per executor job (interleave grain)
 
     def __init__(self, engine) -> None:
-        from dnet_tpu.config import get_settings
-
         self.engine = engine  # BatchedEngine
         self._futures = _TokenFutures()
         self._executor: Optional[ThreadPoolExecutor] = None
@@ -207,12 +205,6 @@ class BatchedLocalAdapter(ApiAdapterBase):
         self._kick: Optional[asyncio.Event] = None
         self._task: Optional[asyncio.Task] = None
         self._prefill_tasks: set = set()
-        # DNET_FLEET_DECODE_PACE_MS: floor wall-clock per batched step,
-        # emulating device-bound decode where the host waits on the
-        # accelerator instead of owning the core (config.FleetSettings)
-        self._pace_s = (
-            max(get_settings().fleet.fleet_decode_pace_ms, 0.0) / 1000.0
-        )
 
     SWEEP_INTERVAL_S = 60.0
 
@@ -398,16 +390,7 @@ class BatchedLocalAdapter(ApiAdapterBase):
             pending, self._pending = self._pending, {}
             if not pending:
                 continue
-            t0 = loop.time()
             await loop.run_in_executor(self._executor, self._batched_step, pending)
-            if self._pace_s > 0.0:
-                # device-bound emulation: a batched step may not complete
-                # faster than the pace floor.  The wait is loop-yielding,
-                # so co-hosted replicas overlap their floors — unlike the
-                # compute itself, which serializes on the CPU.
-                remain = self._pace_s - (loop.time() - t0)
-                if remain > 0.0:
-                    await asyncio.sleep(remain)
 
     async def await_token(self, nonce: str, step: int, timeout: float) -> TokenResult:
         return await self._futures.wait(nonce, step, timeout)

@@ -1,7 +1,7 @@
 """Chaos-campaign scenarios: real serving stacks behind a loopback port.
 
 Each scenario builds one REAL serving topology in-process — the same
-stacks bench_serve.py measures and the subsystem tests pin — and exposes
+stacks the subsystem tests pin — and exposes
 the uniform surface the campaign runner (campaign.py) drives cells
 through: a loopback HTTP base URL for the seeded workload, a resource
 snapshot for the conservation audit, a quiesce barrier, and (where the
@@ -86,7 +86,7 @@ async def _wait(cond, timeout_s: float, what: str) -> None:
 
 class _EnvScope:
     """Set env overrides + fresh settings/obs books for one scenario;
-    restore the previous environment on exit (the bench_serve leg idiom)."""
+    restore the previous environment on exit."""
 
     def __init__(self, env: Dict[str, str]) -> None:
         self.env = dict(env)
@@ -255,7 +255,7 @@ class Scenario:
 
 
 # ---------------------------------------------------------------------------
-# local / sched: the single-node stack (bench_serve._run_inprocess)
+# local / sched: the single-node stack
 # ---------------------------------------------------------------------------
 
 

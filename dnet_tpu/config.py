@@ -450,14 +450,6 @@ class FleetSettings(_EnvGroup):
     # migrate in-flight streams off a dead replica via replay; off =
     # a mid-stream death surfaces as an in-band stream error instead
     fleet_failover: bool = True
-    # emulated device-bound decode: minimum wall-clock ms per batched
-    # decode step.  On a real TPU ring the host mostly WAITS on the
-    # device, so replicas scale across hosts; a CPU-only container has
-    # no such idle time and N in-process replicas just contend for the
-    # same cores.  A nonzero pace restores the device-bound regime for
-    # fleet scaling benches (every token still crosses the full
-    # engine/KV/admission/SSE path).  0 = off, no behavior change.
-    fleet_decode_pace_ms: float = 0.0
 
 
 @dataclass

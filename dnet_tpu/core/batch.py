@@ -63,7 +63,6 @@ from dnet_tpu.kv.store import _bucket_pow2
 from dnet_tpu.obs import get_recorder, metric, span
 from dnet_tpu.obs.jit import instrument_jit
 from dnet_tpu.obs.phases import (
-    DECODE_CHUNK_WIDTHS,
     KV_KIND_FULL,
     KV_KIND_STATE,
     KV_KIND_WINDOW,
@@ -102,8 +101,8 @@ class DecodeFlight:
     out: Dict[str, SampleResult] = field(default_factory=dict)
     errors: Dict[str, str] = field(default_factory=dict)
     order: Dict[str, int] = field(default_factory=dict)  # nonce -> slot sent
-    R: int = 0  # fused width of the dispatch; 0 = nothing was sent
-    src: Optional[SampleResult] = None  # its results, ON THE DEVICE
+    #: the step's results, ON THE DEVICE; None = nothing was sent
+    src: Optional[SampleResult] = None
     moe: Any = None  # [held, elsewhere] summed by the dispatch, on the device
     host_s: float = 0.0  # host time of the launch half
     #: the launch half itself read the device (a verify block's acceptance
@@ -142,6 +141,11 @@ def takes_another(budgets, nonce) -> bool:
     lane step again before that token has been read."""
     return ((budgets or {}).get(nonce) or 1) >= 2
 
+
+#: the most tokens past a lane's `pos` that a decode step's window table
+#: covers before the blocks behind the window are given back: the step in
+#: flight and the one chained to it (`_extend_window_tables`)
+WINDOW_STEP_TOKENS = 2
 
 KV_PAGED = "paged"  # page tables over the block pool, attended in place
 KV_DENSE = "dense"  # [L, slots, max_seq, ...] rows, one a lane
@@ -345,12 +349,10 @@ class BatchedEngine:
         self.last_used = np.zeros(slots, dtype=np.float64)
         self.slot_of: Dict[str, int] = {}  # nonce -> slot
         self._free: List[int] = list(range(slots))
-        # fused-chunk results not yet handed to the driver (nonce -> FIFO);
-        # dropped with the session like the pipelined engine's buffers
+        # tokens computed and not yet handed to the driver (nonce -> FIFO):
+        # a verify block's later rows, the token a late driver had not asked
+        # for when its step was read; dropped with the session
         self._buffer: Dict[str, List[SampleResult]] = {}
-        # (R, lanes) of the dispatch the last decode_batch call sent to the
-        # device; (0, 0) when every lane was answered from the buffer
-        self.last_dispatch: Tuple[int, int] = (0, 0)
         # per-nonce [blocks, emitted] acceptance stats (adaptive spec gate)
         self._spec_stats: Dict[str, List[int]] = {}
         self.hist = (
@@ -359,13 +361,6 @@ class BatchedEngine:
             else None
         )
         self._build()
-
-    @property
-    def kv_ragged(self) -> bool:
-        """The pool is attended in place: the one way a pool is read.  Kept
-        for tests/benchmarks/test_bench_cohere2_moe.py, which asserts it
-        and which only a `benchmark` PR may edit."""
-        return self.kv_pool is not None
 
     def _init_pool(self, m, slots: int, prefix_size: int) -> None:
         """The pool(s), their managers and the per-slot tables (KV_PAGED)."""
@@ -388,7 +383,7 @@ class BatchedEngine:
             # window table ever has
             from dnet_tpu.config import get_settings
 
-            step = max(get_settings().sched.prefill_chunk_cap(), *self.CHUNK_BUCKETS)
+            step = max(get_settings().sched.prefill_chunk_cap(), WINDOW_STEP_TOKENS)
             per_slot = window_blocks(int(m.window), cfg.block_tokens, step)
             cfgs[KV_KIND_WINDOW] = PagedKVConfig(cfg.block_tokens, slots * per_slot)
         store = KindStore(
@@ -520,9 +515,6 @@ class BatchedEngine:
         self._step = instrument_jit(
             jax.jit(step, donate_argnums=(3, 8)), "batched_step"
         )
-        # fused R-step chunks (budget-driven): sampled tokens re-enter their
-        # lanes on device, one dispatch + one packed read per R tokens
-        self._chunks: Dict[int, Any] = {}
 
         L = self.spec_lookahead
         if L > 0:
@@ -594,11 +586,11 @@ class BatchedEngine:
         )
 
     def _build_ragged(self) -> None:
-        """The pool's decode programs (ops/paged_attention.py): one step
+        """The pool's decode program (ops/paged_attention.py): one step
         that reads the block pool IN PLACE — page tables and per-slot
         positions ride along as the kernel's scalar-prefetched block index
-        map — plus fused R-step chunks that carry the (donated) pool and
-        block-append each step's new K/V rows in-program.  The per-slot
+        map — and hands back each lane's new K/V rows, which the store's
+        kv_append program block-appends behind it.  The per-slot
         forward is the SAME math as the vmapped dense program: the model's
         norm/rope/MLP stack runs unchanged (apply_window's attend_fn hook
         swaps only the cache write + attention read), and sampling vmaps
@@ -629,17 +621,15 @@ class BatchedEngine:
 
         @jax.named_scope("paged_attend")
         def ragged_step(wp, ep, token, pool, tables, pos, active, sp, keys,
-                        counts, prev_token=None):
+                        counts, prev_token):
             """One batched decode step against the pool (READ-ONLY here):
             returns the sampled results plus the stacked per-layer new K/V
             rows for the kv_append program.  tables: each kind's
             [slots, nb] int32 (bucketed), and with window layers their
             tables' `base` (_table_ids); pos [slots] int32 live pool rows
             per slot.  prev_token: the tokens of the flight before, for
-            the lanes chained to it (None inside a fused chunk's scan,
-            which chains its own)."""
-            if prev_token is not None:
-                token = _chain_tokens(token, prev_token)
+            the lanes chained to it."""
+            token = _chain_tokens(token, prev_token)
 
             def attend_fn(q, k_new, v_new, kvs, kind=None, layer=None, gate=None):
                 # the model names the layer's index within its kind (and
@@ -679,103 +669,12 @@ class BatchedEngine:
             res, counts, keys = vsample(logits, active, sp, keys, counts)
             return res, rows, counts, keys, moe
 
-        self._ragged_step_fn = ragged_step
         # a store updated in place rides the step donated (and comes back
         # as `rows`); a pool is read only, its new rows appended after
         donate = (3, 9) if store.in_place else (9,)
         self._ragged_step = instrument_jit(
             jax.jit(ragged_step, donate_argnums=donate), "paged_attend"
         )
-        self._ragged_chunks: Dict[int, Any] = {}
-
-    def _ragged_chunk_fn(self, R: int):
-        """Fused R-step ragged chunk: the pool rides the scan carry
-        (donated — XLA appends in place), each step attends it through the
-        kernel and block-appends its new rows before the next step reads
-        them.  Same one-dispatch-per-R-tokens contract as _chunk_fn."""
-        fn = self._ragged_chunks.get(R)
-        if fn is None:
-            step = self._ragged_step_fn
-            bt = self._block_tokens
-
-            @jax.named_scope("paged_attend")
-            def chunk(wp, ep, token, pool, tables, pos, active, sp, keys,
-                      counts):
-                def body(carry, _):
-                    token, pool, pos, keys, counts = carry
-                    res, rows, counts, keys, moe = step(
-                        wp, ep, token, pool, tables, pos, active, sp, keys,
-                        counts,
-                    )
-
-                    def phys_of(tbl, first, n_blocks):
-                        """The block each lane's new row goes to; frozen
-                        lanes write PAST the block axis (mode="drop"
-                        discards out-of-range, but a negative index would
-                        wrap to block N-1 and clobber a live block)."""
-                        bidx = jnp.clip(pos // bt - first, 0, tbl.shape[1] - 1)
-                        phys = jnp.take_along_axis(tbl, bidx[:, None], axis=1)[:, 0]
-                        return jnp.where(active, phys, n_blocks)
-
-                    first = {KV_KIND_WINDOW: tables.get("base", 0)}
-                    pool = self.kv_store.append_in_program(
-                        pool, rows,
-                        {
-                            kind: phys_of(tables[kind], first.get(kind, 0), p.total)
-                            for kind, p in self.kv_pools.items()
-                        },
-                        pos % bt,
-                    )
-                    token = jnp.where(active[:, None], res.token, token)
-                    pos = pos + active.astype(pos.dtype)
-                    return (token, pool, pos, keys, counts), (res, moe)
-
-                (token, pool, pos, keys, counts), (stacked, moe) = jax.lax.scan(
-                    body, (token, pool, pos, keys, counts), None, length=R
-                )
-                return stacked, pool, counts, keys, jnp.sum(moe, axis=0)
-
-            fn = instrument_jit(
-                jax.jit(chunk, donate_argnums=(3, 9)), "paged_attend"
-            )
-            self._ragged_chunks[R] = fn
-        return fn
-
-    # chunk widths tried largest-first (bounded compiled-program set, same
-    # discipline as LocalEngine.DECODE_CHUNK_BUCKETS); declared in
-    # obs/phases.py so dnet_decode_dispatch_total{r=} carries exactly these
-    CHUNK_BUCKETS = tuple(sorted((w for w in DECODE_CHUNK_WIDTHS if w > 1),
-                                 reverse=True))
-
-    def _chunk_fn(self, R: int):
-        fn = self._chunks.get(R)
-        if fn is None:
-            vstep = self._vmapped
-
-            @jax.named_scope("batched_chunk")
-            def chunk(wp, ep, token, kv, pos, active, sp, keys, counts):
-                def body(carry, _):
-                    token, kv, pos, keys, counts = carry
-                    res, kv, counts, keys = vstep(
-                        wp, ep, token, kv, pos, active, sp, keys, counts
-                    )
-                    # active lanes chain their sampled token on device;
-                    # frozen lanes keep feeding their stale input (inert:
-                    # their KV/counts/keys writes are gated off)
-                    token = jnp.where(active[:, None], res.token, token)
-                    pos = pos + active.astype(pos.dtype)
-                    return (token, kv, pos, keys, counts), res
-
-                (_, kv, _, keys, counts), stacked = jax.lax.scan(
-                    body, (token, kv, pos, keys, counts), None, length=R
-                )
-                return stacked, kv, counts, keys
-
-            fn = instrument_jit(
-                jax.jit(chunk, donate_argnums=(3, 8)), "batched_chunk"
-            )
-            self._chunks[R] = fn
-        return fn
 
     # ---- slot lifecycle ----------------------------------------------
     def alloc_slot(self, nonce: str) -> int:
@@ -1029,13 +928,14 @@ class BatchedEngine:
                 base=first[kind] - len(kept),
             )
 
-    def _extend_window_tables(self, order, errors, active, R: int, ahead) -> None:
-        """Before a dispatch of R steps: every stepping lane's window table
-        gives back the blocks wholly behind the window of the first step
-        the host has NOT READ yet (`pos`: a step in flight still reads
-        from there, so a chained lane's release lags its launch by that
-        step), then grows to cover R more tokens past the `ahead` steps in
-        flight.  (Inside SPAN_DECODE_PREPARE.)"""
+    def _extend_window_tables(self, order, errors, active, ahead) -> None:
+        """Before a step: every stepping lane's window table gives back
+        the blocks wholly behind the window of the first step the host has
+        NOT READ yet (`pos`: a step in flight still reads from there, so a
+        chained lane's release lags its launch by that step), then grows
+        to cover one more token past the `ahead` steps in flight: at most
+        two tokens past `pos` (WINDOW_STEP_TOKENS).  (Inside
+        SPAN_DECODE_PREPARE.)"""
         bt = self._kv_cfg.block_tokens
         pool = self.kv_pools[KV_KIND_WINDOW]
         for nonce, slot in list(order.items()):
@@ -1043,7 +943,7 @@ class BatchedEngine:
             p0 = int(self.pos[slot])
             pool.release_behind(tbl, window_first_block(p0, self._window, bt))
             try:
-                pool.ensure(tbl, p0 + int(ahead[slot]) + R)
+                pool.ensure(tbl, p0 + int(ahead[slot]) + 1)
             except KVPoolExhausted as exc:  # the pool is sized against this
                 self._refuse_lane(nonce, slot, str(exc), order, errors, active, ahead)
 
@@ -1059,69 +959,42 @@ class BatchedEngine:
         ahead[slot] = 0
         del order[nonce]
 
-    def _paged_extend(self, order, errors, active, R: int, ahead) -> int:
-        """Extend every stepping lane's page table to cover R more tokens
-        (past the `ahead` steps it has in flight).
-        If the pool cannot cover the full chunk width, the WHOLE dispatch
-        shrinks to single steps (keeping one program) and only lanes that
-        cannot get even one block fail — alone, with the typed
-        backpressure message."""
-        while True:
-            appended: Dict[int, List[int]] = {}
-            for nonce, slot in list(order.items()):
-                try:
-                    appended[slot] = self.kv_pool.ensure(
-                        self._tables[slot], int(self.pos[slot]) + int(ahead[slot]) + R
-                    )
-                except KVPoolExhausted as exc:
-                    if R > 1:
-                        break  # shrink the chunk and re-try every lane
-                    self._refuse_lane(
-                        nonce, slot, str(exc), order, errors, active, ahead
-                    )
-            else:
-                return R
-            # roll the failed wide pass back before retrying at R=1: a
-            # lane's unused hoard (blocks past its next single step) must
-            # not starve the lanes that come after it in the retry
-            for slot, fresh in appended.items():
-                tbl = self._tables[slot]
-                keep = max(
-                    len(tbl.blocks) - len(fresh),
-                    self._kv_cfg.blocks_for(int(self.pos[slot]) + 1),
+    def _paged_extend(self, order, errors, active, ahead) -> None:
+        """Extend every stepping lane's page table to cover one more token
+        (past the `ahead` steps it has in flight).  A lane the pool cannot
+        cover fails ALONE, with the typed backpressure message."""
+        for nonce, slot in list(order.items()):
+            try:
+                self.kv_pool.ensure(
+                    self._tables[slot], int(self.pos[slot]) + int(ahead[slot]) + 1
                 )
-                if keep < len(tbl.blocks):
-                    self.kv_pool.free_blocks(tbl.blocks[keep:])
-                    del tbl.blocks[keep:]
-            R = 1
+            except KVPoolExhausted as exc:
+                self._refuse_lane(nonce, slot, str(exc), order, errors, active, ahead)
 
-    def _table_ids(self, order: Optional[Dict[str, int]] = None) -> Dict[str, np.ndarray]:
+    def _table_ids(self, order: Dict[str, int]) -> Dict[str, np.ndarray]:
         """Each kind's [slots, nb] physical block ids (0-padded past each
         table; padded rows sit beyond every live pos, where the causal mask
         zeroes them exactly), and with window layers `base`, [slots]: the
         logical block a window table's first entry backs.
 
-        With `order` (the dispatch's active nonce -> slot map), nb is the
-        pow2 BUCKET of the widest active table instead of max_seq/bt: the
+        nb is the pow2 BUCKET of the widest table among `order` (the
+        dispatch's active nonce -> slot map), at most max_seq/bt: the
         kernel walks fewer (elided) grid steps, and the compiled-program
         set stays bounded — the same discipline as _bucket_pow2 commit
-        widths.  Only R==1 dispatches pass `order` (warm_chunks pre-warms
-        the step at every bucket width); fused R-step chunks keep the
-        single full-width program — a per-width chunk set would multiply
-        the compiled programs by the width count.  Frozen lanes' longer
-        tables truncate harmlessly (their compute is garbage, their
-        blocks are never written)."""
-        nb = self.max_seq // self._kv_cfg.block_tokens
-        if order:
-            widest = max(
-                (
-                    len(self._tables[s].blocks)
-                    for s in order.values()
-                    if self._tables[s] is not None
-                ),
-                default=1,
-            )
-            nb = min(_bucket_pow2(max(widest, 1)), nb)
+        widths (warm_chunks compiles the step at every bucket width).
+        Frozen lanes' longer tables truncate harmlessly (their compute is
+        garbage, their blocks are never written)."""
+        widest = max(
+            (
+                len(self._tables[s].blocks)
+                for s in order.values()
+                if self._tables[s] is not None
+            ),
+            default=1,
+        )
+        nb = min(
+            _bucket_pow2(max(widest, 1)), self.max_seq // self._kv_cfg.block_tokens
+        )
         ids = np.zeros((self.slots, nb), dtype=np.int32)
         for slot, tbl in enumerate(self._tables):
             if tbl is not None and tbl.blocks:
@@ -1184,26 +1057,18 @@ class BatchedEngine:
         ALONE — it must never poison the rest of the batch.
 
         Two halves at one seam: `decode_launch` ENQUEUES and returns,
-        `decode_read` blocks on the device.  This call runs them in a row:
-        the caller reads every step before it asks for the next, so a
-        dispatch may be worth more than one step.  `budgets` (nonce ->
-        remaining tokens the driver will accept) may widen it into a fused
-        R-step chunk: active lanes chain their sampled tokens on device
-        and the extra results buffer engine-side, resolving later
-        decode_batch calls instantly — the host pays one dispatch + one
-        packed read per R tokens per lane (the same contract as
-        LocalEngine.decode_chunk / the pipelined engine's rotations).  The
-        active set is FIXED across a chunk, so the stream is bit-identical
-        to R serial steps with the same request set.  A dispatch is fused
-        ONLY when it carries every lane this call asked for: when some
-        lane was answered from its buffer (or verified a drafted block)
-        the lanes are out of phase and take ONE step, all in one dispatch,
-        until every buffer is empty together.  Its callers: the batched
-        adapter (api/strategies.py), the ring, bench.py, the parity tests.
+        `decode_read` blocks on the device.  This call runs them in a row,
+        and a dispatch is ONE step for the lanes that asked.  `budgets`
+        (nonce -> remaining tokens the driver will accept) never widens it:
+        here it only says which greedy lanes of a speculating engine may
+        verify a drafted block instead (`_pick_spec_lanes`), whose later
+        rows wait in the lane's buffer and answer its next calls with no
+        device work.  Its callers: the batched adapter (api/strategies.py)
+        and the parity tests.
 
-        The SERVED path (sched/step.py) never fuses and never reads a step
-        before the next is enqueued: it calls the halves itself and keeps
-        one step in flight, `decode_launch(chain=)`."""
+        The SERVED path (sched/step.py) never reads a step before the next
+        is enqueued: it calls the halves itself and keeps one step in
+        flight, `decode_launch(chain=)`."""
         return self.decode_read(self.decode_launch(requests, budgets))
 
     def decode_launch(
@@ -1214,14 +1079,15 @@ class BatchedEngine:
     ) -> DecodeFlight:
         """The half of `decode_batch` that enqueues.  Nothing is fenced and
         nothing read (but a verify block, which reads its acceptance
-        counts: `blocked`).  `last_dispatch` says what this call sent to
-        the device: (R, lanes), (0, 0) when every lane was answered from
-        the buffer.  The lanes' `pos` advance in `decode_read`.
+        counts: `blocked`).  The flight says what this call sent to the
+        device: `order`, the lanes of its one step, with `src` None where
+        every lane was answered on the host.  The lanes' `pos` advance in
+        `decode_read`.
 
         `chain` is the flight BEFORE this one, not read yet (an empty
         DecodeFlight where there is none): the caller keeps one step in
         flight ahead of the one it reads.  `requests` are then the lanes
-        whose drivers have ASKED, and the dispatch is always ONE step:
+        whose drivers have ASKED:
 
         - a lane in `chain` that will take a token after the one it is
           owed (`budgets[nonce] >= 2`) steps again at `pos + 1`, its input
@@ -1239,16 +1105,14 @@ class BatchedEngine:
         surplus step in the air, which `decode_read` drops."""
         t0 = time.perf_counter()
         flight = DecodeFlight()
-        self.last_dispatch = (0, 0)
         if not requests:
             return flight
         plan = None
-        asked = len(requests)
-        served = chain is not None  # one step in flight, never a fused one
+        served = chain is not None  # one step in flight ahead of the read
         ahead_of = chain.order if served and chain.src is not None else {}
         with span(SPAN_DECODE_PREPARE):
-            # buffered tokens (an earlier fused chunk's, a late driver's)
-            # resolve first
+            # buffered tokens (a verify block's later rows, a late
+            # driver's) resolve first
             flight.out, requests = self._pop_buffered(
                 requests, budgets if served else None
             )
@@ -1259,11 +1123,7 @@ class BatchedEngine:
             # touch disjoint lanes
             spec_reqs = {} if served else self._pick_spec_lanes(requests, budgets)
             if requests and not spec_reqs:
-                # out of phase (some lane had a buffered row): single step
-                plan = self._plan_dispatch(
-                    requests, budgets, flight.errors, ahead_of,
-                    fuse=not served and len(requests) == asked,
-                )
+                plan = self._plan_dispatch(requests, budgets, flight.errors, ahead_of)
         if spec_reqs:
             spec_out = self._decode_spec_lanes(spec_reqs)
             flight.blocked = True
@@ -1272,39 +1132,33 @@ class BatchedEngine:
             requests = {n: r for n, r in requests.items() if n not in spec_reqs}
             if requests:
                 with span(SPAN_DECODE_PREPARE):
-                    plan = self._plan_dispatch(requests, None, flight.errors, {}, False)
+                    plan = self._plan_dispatch(requests, None, flight.errors, {})
         if plan is not None:
-            flight.order, flight.R, dev, table_ids, flight.chained = plan
+            flight.order, dev, table_ids, flight.chained = plan
             prev_token = chain.src.token if flight.chained else self._no_token
             self._launch(flight, dev, table_ids, prev_token)
         flight.host_s = time.perf_counter() - t0
         return flight
 
     def _launch(self, flight: DecodeFlight, dev, table_ids, prev_token) -> None:
-        order, R = flight.order, flight.R
-        lanes = len(order)
-        with span(SPAN_DECODE_LAUNCH, R=R, lanes=lanes):
+        with span(SPAN_DECODE_LAUNCH, lanes=len(flight.order)):
             if self.kv_store is not None:
                 # the pool is attended IN PLACE through the page tables and
                 # the new rows block-append, all inside the launch
-                count_expert_rows(self.eng.model, self.slots, R, whole=True)
+                count_expert_rows(self.eng.model, self.slots, 1, whole=True)
                 flight.src, flight.moe = self._dispatch_ragged(
-                    order, R, dev, table_ids, prev_token
+                    flight.order, dev, table_ids, prev_token
                 )
             else:
                 # vmapped over the slots: each lane's experts see one row
-                count_expert_rows(self.eng.model, 1, R * self.slots)
+                count_expert_rows(self.eng.model, 1, self.slots)
                 token_d, pos_d, active_d, sp = dev
-                args = (
+                out = self._step(
                     self.eng.window_params, self.eng.edge_params, token_d,
                     self.kv, pos_d, active_d, sp, self.keys, self.counts,
+                    prev_token,
                 )
-                if R > 1:
-                    out = self._chunk_fn(R)(*args)
-                else:
-                    out = self._step(*args, prev_token)
                 flight.src, self.kv, self.counts, self.keys = out
-        self.last_dispatch = (R, lanes)
 
     def decode_read(
         self, flight: DecodeFlight, asked: Optional[Any] = None
@@ -1329,12 +1183,11 @@ class BatchedEngine:
         if flight.src is None:
             return flight.out, flight.errors
         t0 = time.perf_counter()
-        src, R, lanes = flight.src, flight.R, len(flight.order)
+        src, lanes = flight.src, len(flight.order)
         # ONE packed device->host read per field per dispatch (the
-        # pipelined engine's drain pattern), then host-side slicing —
-        # per-element device gathers would reintroduce the dispatch
-        # overhead the fused chunk exists to remove.  The first read blocks
-        # until the device has finished the dispatch.
+        # pipelined engine's drain pattern), then host-side slicing: a
+        # device gather a lane would cost a dispatch each.  The first read
+        # blocks until the device has finished the dispatch.
         with span(SPAN_DECODE_READBACK):
             toks = np.asarray(src.token)
             lps = np.asarray(src.logprob)
@@ -1354,32 +1207,24 @@ class BatchedEngine:
                     # the lane left with its step in flight
                     surplus += nonce in flight.chained
                     continue
-                # the entries its R steps attended: pos, pos + 1, ...
-                live += R * int(self.pos[slot]) + R * (R - 1) // 2
-                self.pos[slot] += R
+                live += int(self.pos[slot])  # the entries its step attended
+                self.pos[slot] += 1
                 self.last_used[slot] = now
-                if R > 1:
-                    rows = [
-                        SampleResult(toks[k, slot], lps[k, slot],
-                                     tts[k, slot], tlps[k, slot])
-                        for k in range(R)
-                    ]
-                else:
-                    rows = [SampleResult(
-                        token=toks[slot], logprob=lps[slot],
-                        top_tokens=tts[slot], top_logprobs=tlps[slot],
-                    )]
+                row = SampleResult(
+                    token=toks[slot], logprob=lps[slot],
+                    top_tokens=tts[slot], top_logprobs=tlps[slot],
+                )
                 if asked is None or nonce in asked:
                     delivered += 1
-                    out[nonce] = rows.pop(0)
-                if rows:
-                    self._buffer.setdefault(nonce, []).extend(rows)
-        # what the dispatch did: the device computed R steps for every
-        # slot, lanes asked for R x lanes of them, and the drivers received
-        # one token per lane now (the rest wait in the buffer)
-        _DECODE_DISPATCHES.labels(r=str(R)).inc()
-        _DECODE_SLOT_STEPS.inc(R * self.slots)
-        _DECODE_LANE_STEPS.inc(R * lanes)
+                    out[nonce] = row
+                else:
+                    self._buffer.setdefault(nonce, []).append(row)
+        # what the dispatch did: the device computed a step for every slot,
+        # `lanes` of them asked for, and the drivers that had asked received
+        # their token now (a late driver's waits in the buffer)
+        _DECODE_DISPATCHES.inc()
+        _DECODE_SLOT_STEPS.inc(self.slots)
+        _DECODE_LANE_STEPS.inc(lanes)
         _DECODE_TOKENS.labels(source="dispatch").inc(delivered)
         _DECODE_CHAINED.inc(len(flight.chained))
         _DECODE_SURPLUS.inc(surplus)
@@ -1387,21 +1232,20 @@ class BatchedEngine:
             # what the algorithm reads: every live token's ONE entry, once a
             # layer a step, from positions the host already has (no sync)
             _MLA_LATENT_BYTES.inc(live * self._latent_entry_bytes)
-            _MLA_TOKENS.labels(phase="decode").inc(R * lanes)
+            _MLA_TOKENS.labels(phase="decode").inc(lanes)
         if self.kv_store is not None and self.kv_store.in_place:
             # what the algorithm needs: each active lane's entry read and
             # written once a step, in every layer
             state_bytes, state_tokens = self.kv_store.state_counters
-            state_bytes.inc(R * lanes * self.kv_store.entry_bytes * 2)
-            state_tokens.labels(phase="decode").inc(R * lanes)
+            state_bytes.inc(lanes * self.kv_store.entry_bytes * 2)
+            state_tokens.labels(phase="decode").inc(lanes)
         # per-token share, observed tokens-served times: the family's
         # count stays == tokens across the local / chunked / speculative /
         # batched paths (LocalEngine's amortization convention), and the
         # sum stays == the two halves' host time, prepare through unpack
         # (what a caller enqueued between them is not in it)
-        n_tok = R * lanes
         host_s = flight.host_s + time.perf_counter() - t0
-        _DECODE_STEP_MS.observe_n(host_s * 1000.0 / n_tok, n_tok)
+        _DECODE_STEP_MS.observe_n(host_s * 1000.0 / lanes, lanes)
         return out, flight.errors
 
     def _pop_buffered(self, requests, budgets=None):
@@ -1452,11 +1296,11 @@ class BatchedEngine:
                 spec_reqs[nonce] = (tok, slot, budget)
         return spec_reqs
 
-    def _plan_dispatch(self, requests, budgets, errors, ahead_of, fuse):
+    def _plan_dispatch(self, requests, budgets, errors, ahead_of):
         """Everything the host prepares for one dispatch: per-slot numpy
-        parameter rows, the chunk width and page-table extension.  Returns
-        (order, R, the step's host arguments, table ids, the chained
-        lanes) or None when no lane is left to step.
+        parameter rows and the page-table extension.  Returns (order, the
+        step's host arguments, table ids, the chained lanes) or None when
+        no lane is left to step.
 
         `ahead_of` is the order (nonce -> slot) of a flight the host has
         not read yet: a lane in it steps at `pos + 1` from that flight's
@@ -1514,56 +1358,32 @@ class BatchedEngine:
             repetition_penalty=rep, min_tokens_to_keep=mtk,
             bias_ids=b_ids, bias_vals=b_vals,
         )
-        # fused-chunk width: bounded by the smallest remaining budget and
-        # by every active lane's sequence capacity
-        R = 1
-        if budgets and fuse:
-            cap = min((budgets.get(n) or 1) for n in order)
-            cap = min(cap, *(int(self.max_seq - self.pos[s]) for s in order.values()))
-            R = next((r for r in self.CHUNK_BUCKETS if r <= cap), 1)
         table_ids = None
         if self.kv_pool is not None:
             # block-table extension is admission: a lane the pool cannot
             # cover fails ALONE with the typed backpressure message
-            R = self._paged_extend(order, errors, active, R, ahead)
+            self._paged_extend(order, errors, active, ahead)
             if order and self._window:
-                self._extend_window_tables(order, errors, active, R, ahead)
+                self._extend_window_tables(order, errors, active, ahead)
             if not order:
                 return None
-            table_ids = self._table_ids(order if R == 1 else None)
+            table_ids = self._table_ids(order)
         elif self.kv_store is not None:
             table_ids = {}  # the state kind: a lane IS the address
         chained = frozenset(n for n, s in order.items() if ahead[s])
-        return order, R, (token, pos, active, sp), table_ids, chained
+        return order, (token, pos, active, sp), table_ids, chained
 
-    def _dispatch_ragged(self, order: Dict[str, int], R: int, dev, tables,
-                         prev_token):
-        """One decode dispatch over the pool (R == 1: the read-only
-        paged_attend program + the jitted kv_append block-append; R > 1:
-        the fused chunk carrying the donated pool).  All of it is the
-        launch span.  Returns the results and the dispatch's [held,
-        elsewhere] expert assignments, both on the device."""
+    def _dispatch_ragged(self, order: Dict[str, int], dev, tables, prev_token):
+        """One decode step over the pool: the read-only paged_attend
+        program, then the jitted kv_append block-append (a store updated
+        in place has written inside the step).  All of it is the launch
+        span.  Returns the results and the dispatch's [held, elsewhere]
+        expert assignments, both on the device."""
         token_d, pos_d, active_d, sp = dev
-        args = (
-            self.eng.window_params,
-            self.eng.edge_params,
-            token_d,
-            self.kv_store.kv,
-            tables,
-            pos_d,
-            active_d,
-            sp,
-            self.keys,
-            self.counts,
-        )
-        if R > 1:
-            stacked, pool, self.counts, self.keys, moe = (
-                self._ragged_chunk_fn(R)(*args)
-            )
-            self.kv_store.kv = pool
-            return stacked, moe
         res, rows, self.counts, self.keys, moe = self._ragged_step(
-            *args, prev_token
+            self.eng.window_params, self.eng.edge_params, token_d,
+            self.kv_store.kv, tables, pos_d, active_d, sp, self.keys,
+            self.counts, prev_token,
         )
         if self.kv_store.in_place:
             self.kv_store.append_rows(rows, {}, None)  # the step already wrote
@@ -1647,17 +1467,15 @@ class BatchedEngine:
         _DECODE_STEP_MS.observe_n(per_tok_ms, total_emitted)
         return res
 
-    def warm_chunks(self, fused: bool = True) -> None:
-        """Compile the batched step and the fused-chunk widths up front with
-        a throwaway session, so the FIRST budgeted request doesn't stall
-        every concurrent lane on a multi-second scan compile (the batch loop
-        runs all lanes on one compute executor).  `fused=False`: the load
-        is served by the scheduler, which dispatches single steps alone
-        (sched/step.py), and the R-step programs are left uncompiled."""
+    def warm_chunks(self) -> None:
+        """Compile the batched step (at every table bucket) and, where the
+        engine speculates, the verify block up front with a throwaway
+        session, so the FIRST request doesn't stall every concurrent lane
+        on a multi-second compile (all lanes run on one compute executor).
+        The name is the engines' common one (LocalEngine.warm_chunks)."""
         t0 = time.time()
         dec = DecodingParams(temperature=0.0)
         self.prefill_and_sample("__warm__", [0], dec)
-        slot = self.slot_of["__warm__"]
         if self.spec_lookahead > 0:
             # the greedy warm request IS spec-eligible: the first budgeted
             # round below compiles the verify block; disable the gate stats
@@ -1665,22 +1483,18 @@ class BatchedEngine:
             self.decode_batch({"__warm__": (0, dec)}, budgets={"__warm__": 8})
             self._buffer.pop("__warm__", None)
             self._spec_stats.pop("__warm__", None)
-        # sampled decoding is spec-ineligible, so these rounds compile the
-        # PLAIN step/chunk programs even on spec-enabled engines
+        # sampled decoding is spec-ineligible, so this compiles the PLAIN
+        # step even on spec-enabled engines
         dec_plain = DecodingParams(temperature=1.0) if self.spec_lookahead else dec
         self._warm_step(dec_plain)
-        for r in self.CHUNK_BUCKETS if fused else ():
-            if self.pos[slot] + r < self.max_seq:
-                self.decode_batch({"__warm__": (0, dec_plain)}, budgets={"__warm__": r})
-                self._buffer.pop("__warm__", None)
         self.end_session("__warm__")
-        widths = 1 + (len(self.CHUNK_BUCKETS) if fused else 0)
+        widths = 1
         if self.kv_pool is not None:
-            # R==1 dispatches attend at the pow2 bucket of the widest
-            # ACTIVE table (_table_ids): compile the step at every bucket
-            # width now, with a throwaway session grown into each bucket,
-            # so the first long-context request doesn't stall the whole
-            # batch loop on a mid-flight width compile
+            # a dispatch attends at the pow2 bucket of the widest ACTIVE
+            # table (_table_ids): compile the step at every bucket width
+            # now, with a throwaway session grown into each bucket, so the
+            # first long-context request doesn't stall the whole batch
+            # loop on a mid-flight width compile
             bt = self._kv_cfg.block_tokens
             nb_full = self.max_seq // bt
             # bucket ladder: pow2 widths, plus the clamped full width when
@@ -1706,7 +1520,7 @@ class BatchedEngine:
                 widths += 1
                 half = w
         log.info(
-            "[PROFILE] warmed batched chunk programs (%d widths) in %.1fs",
+            "[PROFILE] warmed the batched step (%d table widths) in %.1fs",
             widths, time.time() - t0,
         )
 

@@ -121,8 +121,8 @@ class PageTable:
 def window_blocks(window: int, block_tokens: int, step_tokens: int) -> int:
     """The most blocks one sequence's WINDOW-kind table ever holds: the
     window, the tokens one dispatch may add before the blocks behind it are
-    given back (a prefill chunk or a fused decode chunk), and one block for
-    the edges the window and the step cut."""
+    given back (a prefill chunk; a decode step adds two at most), and one
+    block for the edges the window and the step cut."""
     return ceil_div(window + step_tokens, block_tokens) + 1
 
 

@@ -15,12 +15,12 @@ it to dense slots, so no pool carries scale leaves.)
 
 The decode path (core/batch.py `_build_ragged`) attends the pool IN PLACE
 and speaks to a store by KIND of layer (obs/phases.py KV_KINDS), knowing
-no layout: `kinds`, `attend`, `append_in_program`, `append_rows` and
-`commit_staged` take and return `{kind: ...}`.  `KindStore` is the pool
+no layout: `kinds`, `attend`, `append_rows` and `commit_staged` take and
+return `{kind: ...}`.  `KindStore` is the pool
 store: a pool a kind, `full` alone for most models (which the prefix cache
 also reads), `window` beside it for a model that mixes window and full
 layers; `StateStore` holds the third kind, `state`: one recurrent entry a
-lane, no blocks, behind the same five names; `HybridStore` holds a `full`
+lane, no blocks, behind the same four names; `HybridStore` holds a `full`
 pool (the same layout) AND `state` entries for a model that mixes the two,
 so that one sequence sees one store.
 
@@ -103,10 +103,9 @@ class StateStore:
 
     Behind the by-kind interface the decode path speaks: `attend` is the
     whole of a layer's read, decay and update, in place on the (donated)
-    stack the model's scan carries, so `append_in_program` and
-    `append_rows` have nothing left to write but the stack the step handed
-    back; `commit_staged` is adoption: one lane's entry overwritten with a
-    prefilled session's."""
+    stack the model's scan carries, so `append_rows` has nothing left to
+    write but the stack the step handed back; `commit_staged` is adoption:
+    one lane's entry overwritten with a prefilled session's."""
 
     kinds = (KV_KIND_STATE,)
     in_place = True
@@ -146,10 +145,6 @@ class StateStore:
             layer, impl=impl,
         )
         return o[:, None], store
-
-    def append_in_program(self, pool, rows, phys, off):
-        """Traced: the step already wrote; `rows` IS the stack after it."""
-        return rows
 
     def append_rows(self, rows: dict, phys: dict, off) -> None:
         self.kv = rows
@@ -398,9 +393,9 @@ class HybridStore:
     carry of the model's scan.  A state layer's `attend` is its read, decay
     and correction (`gdn_decode`); a full layer's is the kernel's read of
     the pool through the page tables AND the new row's write into the
-    lane's block, so `append_in_program` / `append_rows` have nothing left
-    to write.  `commit_staged` is adoption: the staged row's blocks into
-    the pool and the session's state entry over the lane's, in one program.
+    lane's block, so `append_rows` has nothing left to write.
+    `commit_staged` is adoption: the staged row's blocks into the pool and
+    the session's state entry over the lane's, in one program.
 
     Admission is by both: a free lane and the blocks of the `full` pool
     (core/batch.py, sched/policy.py).  A lane's blocks are never aliased
@@ -503,10 +498,6 @@ class HybridStore:
 
         full = {"k": write(full["k"], rows["k"]), "v": write(full["v"], rows["v"])}
         return out, {**kvs, KV_KIND_FULL: full}
-
-    def append_in_program(self, pool, rows, phys, off):
-        """Traced: the step already wrote; `rows` IS the store after it."""
-        return rows
 
     def append_rows(self, rows: dict, phys: dict, off) -> None:
         self.kv = rows

@@ -4,9 +4,10 @@ An open-loop, seeded load harness for the OpenAI-compatible serving
 surface: `workload` builds deterministic arrival/length schedules,
 `client` drives one streaming request to one outcome row, `runner`
 orchestrates the fan-out and brackets it with metric scrapes, and
-`report` turns the rows into the machine-readable ``BENCH_SERVE_*.json``
-artifact every subsequent perf PR reports its before/after through.
-`bench_serve.py` (repo root) is the operator entry point.
+`report` turns the rows into a machine-readable record (``kind``
+``BENCH_SERVE``) that `compare` diffs.  The chaos campaign
+(dnet_tpu/chaos/, scripts/chaos_campaign.py) drives it; speed on the chip
+is `benchmarks/run.py`'s to measure, not this harness's.
 """
 
 from dnet_tpu.loadgen.client import RequestOutcome, run_request

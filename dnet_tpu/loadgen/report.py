@@ -114,8 +114,8 @@ def _phase_summary(
             "count": int(parent_n),
         },
         # fraction of the dispatches' wall time the spans explain; above 1
-        # by the prepare time of calls answered from the fused-chunk
-        # buffer alone, 0 off the batched path
+        # by the prepare time of calls answered from the engine's buffer
+        # alone, 0 off the batched path
         "coverage": round(phase_sum / parent_sum, 4) if parent_sum else 0.0,
     }
 

@@ -10,9 +10,8 @@ an aiohttp client (loadgen, tests) exercises the identical admission/SSE/
 driver path a remote deployment would.
 
 Used by tests/subsystems/test_wire_pipeline.py (byte-identical SSE parity
-legacy-vs-pipelined) and `bench_serve.py --ring-inproc` (BENCH_SERVE_r04:
-legacy vs overlapped wire on the seeded r01-r03 workload).  Per-edge frame
-accounting (`RingWireStats`) gives the per-hop tx bytes the report embeds:
+legacy-vs-pipelined).  Per-edge frame accounting (`RingWireStats`) gives
+the per-hop tx bytes:
 hidden activation hops are the "inter-hop bytes" the qsparse8 codec is
 supposed to shrink, token/continuation frames are counted separately so
 they cannot dilute the ratio.

@@ -433,12 +433,11 @@ def _register_core(reg: MetricsRegistry) -> None:
     for kind in SLO_KINDS:
         burning.labels(slo=kind)  # pre-touch: expose at 0 from the start
     # performance attribution (obs/phases.py, obs/jit.py): host spans, the
-    # fused-chunk decode counters, jit compile tracking, device memory.
-    # Span / source / width / fn / kind label sets are DECLARED in
+    # batched decode counters, jit compile tracking, device memory.
+    # Span / source / fn / kind label sets are DECLARED in
     # obs/phases.py (a leaf module) and cross-checked both ways by the
     # metrics lint (pass 8).
     from dnet_tpu.obs.phases import (
-        DECODE_CHUNK_WIDTHS,
         DECODE_TOKEN_SOURCES,
         DEVICE_MEM_KINDS,
         HOST_SPANS,
@@ -456,23 +455,19 @@ def _register_core(reg: MetricsRegistry) -> None:
     )
     for name in HOST_SPANS:
         span_fam.labels(span=name)  # pre-touch: the lint checks these
-    dispatches = reg.counter(
+    reg.counter(
         "dnet_decode_dispatch_total",
-        "Batched decode dispatches that reached the device, by fused "
-        "chunk width R (obs/phases.py DECODE_CHUNK_WIDTHS)",
-        labelnames=("r",),
+        "Batched decode dispatches that reached the device (one step each)",
     )
-    for width in DECODE_CHUNK_WIDTHS:
-        dispatches.labels(r=str(width))  # pre-touch: the lint checks these
     reg.counter(
         "dnet_decode_slot_steps_total",
         "Slot-steps the device computed in batched decode dispatches "
-        "(R x slots per dispatch, inactive slots included)",
+        "(slots per dispatch, inactive slots included)",
     )
     reg.counter(
         "dnet_decode_lane_steps_total",
         "Slot-steps active lanes asked for in batched decode dispatches "
-        "(R x dispatched lanes per dispatch)",
+        "(dispatched lanes per dispatch)",
     )
     tokens_fam = reg.counter(
         "dnet_decode_tokens_total",
@@ -484,7 +479,7 @@ def _register_core(reg: MetricsRegistry) -> None:
         tokens_fam.labels(source=source)  # pre-touch: the lint checks these
     reg.counter(
         "dnet_decode_buffer_dropped_total",
-        "Buffered tokens (a fused chunk's, a late driver's) thrown away "
+        "Buffered tokens (a verify block's, a late driver's) thrown away "
         "when their session ended (computed on the device, never delivered)",
     )
     reg.counter(
@@ -682,7 +677,7 @@ def _register_core(reg: MetricsRegistry) -> None:
         "device program enqueued, both on the compute thread's clock, by "
         "what the device had to do meanwhile (obs/phases.py TURN_DEVICE: "
         "drained = tick n read everything it enqueued); runs through a "
-        "tick that enqueues nothing (answered from a fused dispatch's "
+        "tick that enqueues nothing (every lane answered from its "
         "buffer); not observed across a park with nothing to do (ms)",
         labelnames=("device",),
         buckets=_TURN_MS_BUCKETS,

@@ -118,7 +118,7 @@ class _HistogramChild:
 
     def observe_n(self, v: float, n: int) -> None:
         """n identical observations under ONE lock round-trip — the
-        amortization convention (a fused R-step chunk or verify block
+        amortization convention (a decode chunk or verify block
         records its per-token share tokens-served times) without n
         acquire/release cycles per dispatch."""
         if n <= 0:

@@ -20,7 +20,7 @@ from __future__ import annotations
 #                             tick's chunks are launched) and around
 #                             engine.decode_read (step n, enqueued a tick ago)
 #       dnet.decode.prepare     buffer pops, numpy rows, uploads, table ids
-#       dnet.decode.launch      the jitted step/chunk call (+ kv_append):
+#       dnet.decode.launch      the jitted step's call (+ kv_append):
 #                               an ENQUEUE (and a compile, if one happens)
 #       dnet.decode.readback    the np.asarray reads: host blocked until the
 #                               device finishes the dispatch
@@ -194,17 +194,11 @@ MOE_PATHS = ("grouped", "dense")
 # driver came from (core/batch.py)
 #   dispatch — first row of the dispatch this call made
 #   buffer   — a row an earlier dispatch left in the engine's buffer (a
-#              fused R-step or verify dispatch's later rows; under the
-#              scheduler, the token a late driver had not asked for
-#              when its step was read): no device work in this call
+#              verify block's later rows; under the scheduler, the token
+#              a late driver had not asked for when its step was read):
+#              no device work in this call
 #   spec     — first row of a per-lane speculative verify block
 DECODE_TOKEN_SOURCES = ("dispatch", "buffer", "spec")
-
-# dnet_decode_dispatch_total{r=}: the widths a batched decode dispatch may
-# take — one step, or a fused R-step chunk.  BatchedEngine.CHUNK_BUCKETS is
-# derived from this tuple, so the label set and the compiled-program set
-# cannot drift apart.
-DECODE_CHUNK_WIDTHS = (1, 2, 4, 8, 16)
 
 # Instrumented jitted entry points (obs/jit.py instrument_jit): the `fn`
 # label of dnet_jit_compiles_total.  Every instrument_jit call site must use
@@ -219,14 +213,13 @@ JIT_FNS = (
                             # sample and counts outside the decode programs
                             # (a prompt's first token), one program a plan
     "batched_step",         # BatchedEngine._step (vmapped decode+sample)
-    "batched_chunk",        # BatchedEngine fused R-step chunk programs
     "batched_spec",         # BatchedEngine._spec_step (verify blocks)
     "adopt_lane",           # BatchedEngine._adopt_lane: a prefilled session's
                             # counts, key (hist, dense KV row) into its lane
     "kv_gather",            # pool store: page-table gather (prefix restore)
     "kv_scatter",           # pool store: a staged row's blocks into the pool
-    "paged_attend",         # BatchedEngine ragged decode programs (step +
-                            # fused chunks) attending the pool in place
+    "paged_attend",         # BatchedEngine's decode step attending the
+                            # pool in place
     "kv_append",            # pool store: per-step block-append of new K/V rows
     "wire_encode",          # wire-pipeline hop encode launches (lossless
                             # cast / sparse / qsparse8 — compression/ops.py)

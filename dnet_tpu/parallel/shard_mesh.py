@@ -9,7 +9,7 @@ slice; this engine lets ONE ring shard drive that whole slice: its layer
 window runs tensor-parallel (and optionally sequence-parallel) across the
 local chips, while activations still hop host-to-host over gRPC/DCN.
 
-The north-star v5e-16 topology (BASELINE.md) becomes expressible:
+The v5e-16 topology the seed aimed at becomes expressible:
 4 hosts x 4 chips = a 4-shard gRPC ring where each shard is a tp=4 mesh.
 
 Design: LocalEngine's shard step functions (_embed_window / _hidden /

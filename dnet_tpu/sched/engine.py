@@ -405,7 +405,6 @@ class SchedulerAdapter(ApiAdapterBase):
             prefill_tokens=result.prefill_tokens,
             decode_lanes=result.decode_lanes,
             dispatched_lanes=result.dispatched_lanes,
-            chunk_r=result.chunk_r,
             preempted=len(result.preempted),
             requeued=len(result.requeued),
             errors=len(result.errors),

@@ -50,8 +50,7 @@ class TickRecord:
     kv_blocks_used: int = 0
     kv_blocks_free: int = 0
     kv_pool_blocks: int = 0
-    dispatched_lanes: int = 0  # lanes of the step this tick enqueued
-    chunk_r: int = 0           # its width R (1 when served); 0 = none enqueued
+    dispatched_lanes: int = 0  # lanes of the step this tick enqueued; 0 = none
 
     def as_dict(self) -> dict:
         return asdict(self)
@@ -95,7 +94,6 @@ class TickFlightRecorder:
         kv_blocks_free: int = 0,
         kv_pool_blocks: int = 0,
         dispatched_lanes: int = 0,
-        chunk_r: int = 0,
     ) -> Optional[TickRecord]:
         """Capture one tick; returns the record (None when capture is
         disabled via DNET_OBS_TICK_RECORDS=0)."""
@@ -120,7 +118,6 @@ class TickFlightRecorder:
             kv_blocks_free=int(kv_blocks_free),
             kv_pool_blocks=int(kv_pool_blocks),
             dispatched_lanes=int(dispatched_lanes),
-            chunk_r=int(chunk_r),
         )
         with self._lock:
             rec.seq = self._seq

@@ -6,14 +6,13 @@ work into one :class:`TickPlan`:
 1. **Decode first.**  Every DECODING request with a pending step gets one
    token (decode is what the per-token SLO measures; a long prompt must
    never starve running streams for more than one tick).  Every dispatch
-   on this path is ONE step that carries every lane that asked; the
-   drivers' remaining budgets ride along in every plan and say which
-   lanes may be CHAINED: a lane that will take a token after the one it
-   is owed steps again, from that token on the device, before the host
-   has read it (sched/step.py keeps one step in flight ahead of the one
-   it reads, so no host round trip is left for a fused R-step dispatch
-   to save).  A lane steps in the same order whoever supplies its input
-   token, so streams are bit-identical to serial stepping.
+   is ONE step that carries every lane that asked; the drivers' remaining
+   budgets ride along in every plan and say which lanes may be CHAINED:
+   a lane that will take a token after the one it is owed steps again,
+   from that token on the device, before the host has read it
+   (sched/step.py keeps one step in flight ahead of the one it reads).
+   A lane steps in the same order whoever supplies its input token, so
+   streams are bit-identical to serial stepping.
 2. **Chunked prefill fills the remainder.**  PREFILLING requests continue
    (most urgent first), each by a segment as wide as the budget still
    holds (one pass over the weights for the prompt, not one every few

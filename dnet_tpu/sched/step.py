@@ -7,9 +7,11 @@ rules order a tick: **enqueue all of its device work, then read**, and
 does next**: the next step, chained to that step's tokens on the device,
 or a waiting prompt's chunk.  So the device neither drains inside a tick
 nor between two of them (the host's way from one tick to the next, the
-drivers' answers included, runs while that program does), and no dispatch
-is ever fused: with nothing to wait for, R steps in one program would only
-hold a joining lane and a waiting prompt behind them.
+drivers' answers included, runs while that program does).  A decode
+dispatch is ONE step for the lanes that asked, which is all the engine
+has: with nothing to wait for, R steps in one program only held a joining
+lane and a waiting prompt behind them (PR 40, gen: 222.98 -> 348.02
+tokens/s with one step in flight and no wider dispatch).
 
 1. every chunked-prefill segment of the plan is LAUNCHED on the engine's
    B=1 bucket programs, staged in the inner engine's dense row; a segment
@@ -153,10 +155,8 @@ class TickResult:
     #: lanes this tick handed a token — from the step it read OR from the
     #: engine's buffer (feeds dnet_sched_batch_tokens{kind="decode"})
     decode_lanes: int = 0
-    #: lanes and width R of the step this tick ENQUEUED (R is 1 on the
-    #: served path); both 0 when it enqueued none
+    #: lanes of the step this tick ENQUEUED; 0 when it enqueued none
     dispatched_lanes: int = 0
-    chunk_r: int = 0
     #: the step this tick enqueued and did not read (the engine's
     #: DecodeFlight: device arrays, the compute thread's alone; the loop
     #: only hands it to the next tick with ``follows``), or None
@@ -421,13 +421,12 @@ def _launch_step(engine, plan: TickPlan, reqs: dict, res: TickResult, chain):
         _preempt_for_decode(engine, plan, reqs, res, in_flight)
     if not reqs:
         return None
-    budgets = plan.budgets
-    if chain is None and plan.prefills:
-        budgets = None  # read at once, so it may fuse: not past a prompt
     with span(SPAN_TICK_DECODE):
-        flight = engine.decode_launch(reqs, budgets=budgets or None, chain=chain)
-    res.chunk_r, res.dispatched_lanes = getattr(engine, "last_dispatch", (0, 0))
-    if res.chunk_r:
+        flight = engine.decode_launch(
+            reqs, budgets=plan.budgets or None, chain=chain
+        )
+    if flight.src is not None:
+        res.dispatched_lanes = len(flight.order)
         _launched(res)  # dnet.decode.launch has just ended
     return flight
 
@@ -475,7 +474,7 @@ def _execute(engine, plan: TickPlan, on_decode, res: TickResult, prev) -> bool:
             res.errors.update(errs)
             if flight.src is not None:
                 res.flight = flight
-    if res.chunk_r and plan.prefills:
+    if res.dispatched_lanes and plan.prefills:
         # a step AND a chunk: is the device kept busy from the one to the
         # other (yes), or had the launch half already waited it out
         _MIXED_TICKS.labels(

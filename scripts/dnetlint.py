@@ -68,7 +68,7 @@ def main(argv=None) -> int:
     ap.add_argument("--json", nargs="?", const="auto", default=None,
                     metavar="PATH",
                     help="write a JSON report (default path: next "
-                         "ANALYSIS_r<NN>.json beside the BENCH records)")
+                         "ANALYSIS_r<NN>.json)")
     ap.add_argument("--list-checks", action="store_true")
     ap.add_argument("-q", "--quiet", action="store_true")
     args = ap.parse_args(argv)

@@ -5,9 +5,9 @@ ONE step that carries every lane that asked: a lane of the step in flight
 is chained to it on the device, a lane that has just been adopted joins the
 same dispatch with its host token, and nobody waits for anybody's buffer.
 The engine's own half (core/batch.py: decode_batch, for the callers that
-read every step before they ask for the next) still fuses a dispatch that
-carries every lane of its call.  Streams are bit-identical to serial
-stepping either way.
+read every step before they ask for the next) sends one step a call too,
+whatever the budgets say: the engine has no wider dispatch.  Streams are
+bit-identical to serial stepping either way.
 """
 
 import asyncio
@@ -134,9 +134,8 @@ def _decode_records():
     return [r for r in recs if r["decode_lanes"]]
 
 
-def _dispatches():
-    disp = metric("dnet_decode_dispatch_total")
-    return {r: int(disp.labels(r=str(r)).value) for r in (1, 2, 4, 8, 16)}
+def _dispatches() -> int:
+    return int(metric("dnet_decode_dispatch_total").value)
 
 
 def test_lanes_out_of_phase_behind_a_prompt_step_together_every_second_tick(tiny_llama_dir, paged_env):
@@ -157,12 +156,14 @@ def test_lanes_out_of_phase_behind_a_prompt_step_together_every_second_tick(tiny
         eng.close()
     assert got == want
     recs = [r.as_dict() for r in get_tick_recorder().records()]
-    sent = [r for r in recs if r["chunk_r"]]
-    assert sent and all(r["chunk_r"] == 1 for r in sent)
+    sent = [r for r in recs if r["dispatched_lanes"]]
+    assert sent
     # the long prompt was still prefilling when each of these ticks began
     assert all(r["prefill_tokens"] for r in sent)
     # no tick sends a step behind a chunk while one is in flight
-    assert not any(x["chunk_r"] and y["chunk_r"] for x, y in zip(recs, recs[1:]))
+    assert not any(
+        x["dispatched_lanes"] and y["dispatched_lanes"] for x, y in zip(recs, recs[1:])
+    )
     # lanes joined as they were adopted, and once in they stepped together
     assert max(r["dispatched_lanes"] for r in sent) == 3
     assert sum(r["dispatched_lanes"] == 3 for r in sent) >= 4
@@ -170,7 +171,7 @@ def test_lanes_out_of_phase_behind_a_prompt_step_together_every_second_tick(tiny
     slot_steps = metric("dnet_decode_slot_steps_total").value
     assert lane_steps == sum(r["dispatched_lanes"] for r in sent) == 3 * 6
     assert slot_steps == slots * len(sent)
-    assert _dispatches() == {1: len(sent), 2: 0, 4: 0, 8: 0, 16: 0}
+    assert _dispatches() == len(sent)
     assert metric("dnet_decode_surplus_steps_total").value == 0
     src = _tokens_by_source()
     assert src["dispatch"] + src["buffer"] == 18 and src["spec"] == 0
@@ -180,8 +181,7 @@ def test_lanes_out_of_phase_behind_a_prompt_step_together_every_second_tick(tiny
 def test_a_new_lane_joins_the_next_dispatch_and_nobody_waits_for_a_buffer(tiny_llama_dir, paged_env):
     """Two streams step together, each step chained to the one before; a
     third arrives: the tick after its adoption it is in the SAME dispatch as
-    the other two, with its host token.  No step is launched for it alone,
-    and no dispatch is ever fused."""
+    the other two, with its host token.  No step is launched for it alone."""
     asks = {"a": (8, 24), "b": (8, 24), "c": (8, 24)}
     want = _serial_streams(tiny_llama_dir, paged_env, asks)
     reset_obs()
@@ -194,9 +194,8 @@ def test_a_new_lane_joins_the_next_dispatch_and_nobody_waits_for_a_buffer(tiny_l
         eng.close()
     assert got == want
     recs = [r.as_dict() for r in get_tick_recorder().records()]
-    sent = [r["dispatched_lanes"] for r in recs if r["chunk_r"]]
-    assert all(r["chunk_r"] in (0, 1) for r in recs)
-    assert _dispatches() == {1: len(sent), 2: 0, 4: 0, 8: 0, 16: 0}
+    sent = [r["dispatched_lanes"] for r in recs if r["dispatched_lanes"]]
+    assert _dispatches() == len(sent)
     joined = sent.index(3)  # c's first step, beside a and b
     assert set(sent[:joined]) <= {1, 2} and sent[joined - 1] == 2, sent
     # a and b end together one step after the other or so: until then no
@@ -210,44 +209,49 @@ def test_a_new_lane_joins_the_next_dispatch_and_nobody_waits_for_a_buffer(tiny_l
 
 
 @pytest.mark.parametrize("kv", ["dense", "paged"])
-def test_decode_batch_fuses_only_a_dispatch_that_carries_every_lane(tiny_llama_dir, paged_env, kv):
-    """The engine's half, without a scheduler: lanes a and b hold rows of an
-    R = 4 dispatch when c appears; c steps at R = 1 (three times) while they
-    drain, then one fused dispatch carries all three.  Same stream as the
-    unbudgeted calls give."""
+def test_decode_batch_offers_no_way_to_widen_a_dispatch(tiny_llama_dir, paged_env, kv):
+    """The engine's half, without a scheduler: 16 calls under budgets as
+    large as the widest chunk there used to be send 16 dispatches, one step
+    each for the lanes that asked, and compile no program but the step's
+    own (over the pool: at the table buckets the lanes grow into, and the
+    append behind it).  Same stream as the unbudgeted calls give."""
+    from dnet_tpu.obs.phases import JIT_FNS
+
     dec = DecodingParams(temperature=0.0)
-    prompts = {"a": [256, 72, 101], "b": [256, 84, 104, 105], "c": [256, 90, 91]}
+    prompts = {"a": [256, 72, 101], "b": [256, 84, 104, 105]}
+    compiles = metric("dnet_jit_compiles_total")
+    steps_own = {"dense": {"batched_step"}, "paged": {"paged_attend", "kv_append"}}[kv]
+    assert steps_own <= set(JIT_FNS)
 
     def run(budgeted: bool):
         eng = _engine(tiny_llama_dir, paged_env, kv=kv)
         try:
-            last, got, sent = {}, {n: [] for n in prompts}, []
-
-            def step(names, budget):
-                reqs = {n: (last[n], dec) for n in names}
-                budgets = {n: budget for n in names} if budgeted else None
-                out, errs = eng.decode_batch(reqs, budgets=budgets)
-                assert not errs and set(out) == set(names)
-                sent.append(eng.last_dispatch)
-                for n in names:
+            last = {
+                n: int(eng.prefill_and_sample(n, ids, dec).token[0])
+                for n, ids in prompts.items()
+            }
+            got = {n: [t] for n, t in last.items()}
+            sent0, lanes0 = _dispatches(), metric("dnet_decode_lane_steps_total").value
+            compiled0 = {fn: compiles.labels(fn=fn).value for fn in JIT_FNS}
+            for k in range(16):
+                budgets = {n: 32 - k for n in last} if budgeted else None
+                out, errs = eng.decode_batch(
+                    {n: (t, dec) for n, t in last.items()}, budgets=budgets
+                )
+                assert not errs and set(out) == set(last)
+                assert _dispatches() - sent0 == k + 1
+                assert not eng._buffer  # nothing computed ahead of the asking
+                for n in last:
                     last[n] = int(out[n].token[0])
                     got[n].append(last[n])
-
-            for n in ("a", "b"):
-                last[n] = int(eng.prefill_and_sample(n, prompts[n], dec).token[0])
-            step(("a", "b"), 6)
-            last["c"] = int(eng.prefill_and_sample("c", prompts["c"], dec).token[0])
-            for _ in range(5):
-                step(("a", "b", "c"), 6)
-            return got, sent
+            assert metric("dnet_decode_lane_steps_total").value - lanes0 == 16 * 2
+            moved = {fn for fn in JIT_FNS if compiles.labels(fn=fn).value != compiled0[fn]}
+            assert moved <= steps_own, moved
+            return got
         finally:
             eng.close()
 
-    got, sent = run(budgeted=True)
-    assert sent == [(4, 2), (1, 1), (1, 1), (1, 1), (4, 3), (0, 0)]
-    serial, sent = run(budgeted=False)
-    assert sent == [(1, 2)] + [(1, 3)] * 5
-    assert got == serial
+    assert run(budgeted=True) == run(budgeted=False)
 
 
 def _queue(states: dict) -> SchedQueue:

@@ -366,7 +366,7 @@ def test_inprocess_smoke_load_acceptance(tiny_llama_dir, monkeypatch):
             eng = BatchedEngine(
                 tiny_llama_dir, slots=4, max_seq=64, param_dtype="float32"
             )
-            assert eng.kv_ragged  # the pool, attended in place: derived
+            assert eng.kv_pool is not None  # the pool, attended in place: derived
             adapter = BatchedLocalAdapter(eng)
             from dnet_tpu.admission.controller import AdmissionController
             from dnet_tpu.api.http import ApiHTTPServer

@@ -63,7 +63,7 @@ async def _sse_burst(model_dir, prompts, reference=None, max_tokens=6, slots=4):
             r = await client.post("/v1/load_model", json={"model": str(model_dir)})
             assert r.status == 200, await r.text()
             assert manager.serving.adapter == "SchedulerAdapter"
-            assert manager.engine.kv_ragged
+            assert manager.engine.kv_pool is not None
         else:
             from dnet_tpu.utils.tokenizer import load_tokenizer
 
@@ -172,13 +172,13 @@ def _span_counts():
 
 @pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
 def test_ragged_engine_flag_and_phases(tiny_llama_dir, ragged_env, paged):
-    """With no argument the engine takes the pool (kv_ragged reads True),
+    """With no argument the engine takes the pool (kv_pool is there),
     and a decode dispatch is the same four spans, once each, on the pool
     and on dense slots: the pool's append rides inside the launch.  No
     setting is needed: the spans are always on and fence nothing."""
     eng = _engine(tiny_llama_dir, paged=paged)
     try:
-        assert eng.kv_ragged is paged and (eng.kv is None) is paged
+        assert (eng.kv_pool is not None) is paged and (eng.kv is None) is paged
         before = _span_counts()
         dec = DecodingParams(temperature=0.0)
         res = eng.prefill_and_sample("ph", [256, 72, 101], dec)
@@ -193,8 +193,8 @@ def test_ragged_engine_flag_and_phases(tiny_llama_dir, ragged_env, paged):
 def test_ragged_interleaved_mid_block_matches_dense(tiny_llama_dir, ragged_env):
     """>= 3 concurrent variable-length sessions whose positions straddle
     block boundaries (the clamped-dead-block masking edge, mid-block pos):
-    identical greedy streams to the dense engine, single steps and
-    budget-driven fused chunks both."""
+    identical greedy streams to the dense engine, with and without a
+    budget riding along (it never widens a dispatch)."""
     prompts = {
         "va": [256, 72, 101],                                  # 1 block, mid
         "vb": [256, 84, 104, 105, 110, 3, 9, 12, 44, 7, 81],   # 2 blocks
@@ -236,7 +236,7 @@ def test_ragged_interleaved_mid_block_matches_dense(tiny_llama_dir, ragged_env):
         eng.close()
     eng = _engine(tiny_llama_dir, paged=True)
     try:
-        assert eng.kv_ragged is True
+        assert eng.kv_pool is not None
         assert interleaved(eng) == want
         assert chunked(eng) == want_ck
         eng.kv_pool.check_conservation()

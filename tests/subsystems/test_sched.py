@@ -539,11 +539,10 @@ class FakeStepEngine:
             n: self.slot_of[n] for n in requests
             if ahead.get(n) != self.slot_of[n] or takes_another(budgets, n)
         }
-        self.last_dispatch = (1, len(order)) if order else (0, 0)
         if not order:
             return DecodeFlight()
         return DecodeFlight(
-            order=order, R=1, src="on the device",
+            order=order, src="on the device",
             chained=frozenset(n for n in order if n in ahead),
         )
 

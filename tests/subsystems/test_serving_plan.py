@@ -118,7 +118,7 @@ def test_a_plain_load_serves_the_scheduler_over_the_pool(tiny_llama_dir, monkeyp
             assert isinstance(inference.adapter, SchedulerAdapter)
             eng = manager.engine
             assert isinstance(eng, BatchedEngine) and eng.slots == 8
-            assert eng.kv_ragged and eng.kv is None and eng.kv_pool is not None
+            assert eng.kv is None and eng.kv_pool is not None
             r = await client.post("/v1/chat/completions", json={
                 "model": "tiny", "messages": [{"role": "user", "content": "hi"}],
                 "max_tokens": 3, "temperature": 0,

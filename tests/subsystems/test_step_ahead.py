@@ -140,9 +140,8 @@ def _same(got, want):
     np.testing.assert_allclose([lp for _, lp in got], [lp for _, lp in want], atol=2e-5)
 
 
-def _dispatches():
-    disp = metric("dnet_decode_dispatch_total")
-    return {r: int(disp.labels(r=str(r)).value) for r in (1, 2, 4, 8, 16)}
+def _dispatches() -> int:
+    return int(metric("dnet_decode_dispatch_total").value)
 
 
 # ---- (a) the streams are the serial ones, on every store ---------------------
@@ -174,8 +173,7 @@ def test_every_lanes_stream_is_the_serial_one(checkpoints, paged_env, store):
         eng.close()
     for n in asks:
         _same(got[n], want[n])
-    sent = _dispatches()
-    assert sent[1] > 0 and not any(sent[r] for r in (2, 4, 8, 16))
+    assert _dispatches() > 0
     lane_steps = metric("dnet_decode_lane_steps_total").value
     assert lane_steps == sum(ask - 1 for _, ask in asks.values())  # no surplus, none twice
     # every step but a lane's first (and a late driver's next) is chained
@@ -281,7 +279,8 @@ def test_a_late_driver_gets_the_held_token_at_its_next_ask(tiny_llama_dir, paged
 def test_a_tick_of_an_engine_that_speculates_keeps_the_serial_order():
     """`spec_lookahead > 0`: the launch half reads the device, so the step
     goes first, is read in the same tick and leaves nothing in flight; its
-    budgets may fuse it only while no chunk is in the plan."""
+    budgets (which lanes may verify a drafted block) ride along whether a
+    chunk is in the plan or not: they widen no dispatch."""
     from dnet_tpu.sched.policy import TickPlan
     from dnet_tpu.sched.step import execute_tick
     from tests.subsystems.test_sched import FakeStepEngine, _chunk
@@ -312,7 +311,9 @@ def test_a_tick_of_an_engine_that_speculates_keeps_the_serial_order():
     del calls[:]
     plan.prefills = [_chunk("new", last=False)]
     res = execute_tick(eng, plan, follows=res)
-    assert calls == [("launch", None, None), ("prefill",), ("read", ["dec"], {"asked": None})]
+    assert calls == [
+        ("launch", {"dec": 9}, None), ("prefill",), ("read", ["dec"], {"asked": None}),
+    ]
     assert set(res.decode_results) == {"dec"} and res.flight is None
 
 

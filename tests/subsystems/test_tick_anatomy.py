@@ -2,11 +2,11 @@
 
 Three layers, all always on and unfenced:
 
-- `decode_batch`'s fused-chunk counters (core/batch.py): slot-steps the
-  device computed, lane-steps active lanes asked for, tokens the driver
-  received by source, dispatches by width R.  Buffer hits belong to a
-  caller that reads each step before it asks for the next; the served path
-  dispatches one step over all lanes, a step ahead of the one it reads
+- `decode_batch`'s counters (core/batch.py): dispatches (one step each),
+  slot-steps the device computed, lane-steps active lanes asked for,
+  tokens the driver received by source.  A buffer hit is a late driver's
+  token (or a verify block's later rows); the served path dispatches one
+  step over all lanes, a step ahead of the one it reads
   (tests/subsystems/test_decode_phase.py), a lone stream too;
 - the scheduler's stamps on SchedRequest (sched/engine.py): queue wait,
   prefill wall time and ticks, decode deliver wait, and the recorder's
@@ -32,14 +32,13 @@ pytestmark = pytest.mark.api
 
 def _counters():
     tok = metric("dnet_decode_tokens_total")
-    disp = metric("dnet_decode_dispatch_total")
     out = {
+        "sent": metric("dnet_decode_dispatch_total").value,
         "slot_steps": metric("dnet_decode_slot_steps_total").value,
         "lane_steps": metric("dnet_decode_lane_steps_total").value,
         "dropped": metric("dnet_decode_buffer_dropped_total").value,
     }
     out.update({s: tok.labels(source=s).value for s in ("dispatch", "buffer", "spec")})
-    out.update({f"r{r}": disp.labels(r=str(r)).value for r in (1, 2, 4, 8, 16)})
     return out
 
 
@@ -58,11 +57,13 @@ def paged_env(monkeypatch):
 
 
 @pytest.mark.parametrize("kv", ["dense", "paged"])
-def test_decode_batch_counts_what_the_fused_chunk_did(tiny_llama_dir, paged_env, kv):
-    """One budgeted dispatch of R=4 for 2 lanes on 4 slots, then the three
-    buffer hits that follow it, then a session that ends with rows still
-    buffered: the counters say exactly that, on every KV path."""
-    from dnet_tpu.core.batch import BatchedEngine
+def test_decode_batch_counts_what_the_dispatch_did(tiny_llama_dir, paged_env, kv):
+    """Four budgeted calls for 2 lanes on 4 slots (a dispatch each, one
+    step wide), then a step read while one driver is late (its token waits
+    in the buffer and answers its next ask with no device work), then a
+    session that ends with a token still buffered: the counters say exactly
+    that, on every KV path."""
+    from dnet_tpu.core.batch import BatchedEngine, DecodeFlight
 
     eng = BatchedEngine(
         tiny_llama_dir, slots=4, max_seq=64, param_dtype="float32",
@@ -76,38 +77,39 @@ def test_decode_batch_counts_what_the_fused_chunk_did(tiny_llama_dir, paged_env,
             last[n] = int(eng.prefill_and_sample(n, ids, dec).token[0])
         before = _counters()
         reqs = {n: (t, dec) for n, t in last.items()}
-        out, errs = eng.decode_batch(reqs, budgets={"a": 5, "b": 7})
-        assert not errs and set(out) == {"a", "b"}
-        # R = the largest bucket under the SMALLEST budget; the device
-        # computed it for every slot, two lanes asked for it
-        assert eng.last_dispatch == (4, 2)
-        assert _moved(before) == {
-            "r4": 1, "slot_steps": 4 * 4, "lane_steps": 4 * 2, "dispatch": 2,
-        }
-        for hit in range(1, 4):  # rows 2..4 of the chunk: no device work
+        for k in range(4):  # a budget never widens a dispatch
+            out, errs = eng.decode_batch(reqs, budgets={"a": 5 - k, "b": 7 - k})
+            assert not errs and set(out) == {"a", "b"}
             reqs = {n: (int(out[n].token[0]), dec) for n in out}
-            out, errs = eng.decode_batch(reqs, budgets={"a": 5 - hit, "b": 7 - hit})
-            assert not errs and eng.last_dispatch == (0, 0)
-        assert _moved(before) == {
-            "r4": 1, "slot_steps": 16, "lane_steps": 8, "dispatch": 2,
-            "buffer": 2 * 3,
-        }
-        # useful over attempted: delivered tokens over slot-steps
+        # the device computed a step for every slot, two lanes asked for it
         m = _moved(before)
-        assert (m["dispatch"] + m["buffer"]) / m["slot_steps"] == 0.5
-        # an unbudgeted call is a single step; lane "a" alone
-        out, errs = eng.decode_batch({"a": (int(out["a"].token[0]), dec)})
-        assert eng.last_dispatch == (1, 1)
-        # a fused dispatch whose rows are never collected: dropped
-        eng.decode_batch({"b": (int(out.get("b", out["a"]).token[0]), dec)},
-                         budgets={"b": 4})
-        assert eng.last_dispatch == (4, 1)
+        assert m == {"sent": 4, "slot_steps": 4 * 4, "lane_steps": 4 * 2, "dispatch": 8}
+        # useful over attempted: delivered tokens over slot-steps
+        assert m["dispatch"] / m["slot_steps"] == 0.5
+        # the served path's two halves: b's driver is late for this step
+        flight = eng.decode_launch(reqs, chain=DecodeFlight())
+        got, errs = eng.decode_read(flight, asked={"a"})
+        assert not errs and set(got) == {"a"}
+        assert _moved(before) == {
+            "sent": 5, "slot_steps": 20, "lane_steps": 10, "dispatch": 9,
+        }
+        # its next ask finds the token: no device work
+        out, errs = eng.decode_batch({"b": reqs["b"]})
+        assert not errs and set(out) == {"b"}
+        assert _moved(before) == {
+            "sent": 5, "slot_steps": 20, "lane_steps": 10, "dispatch": 9, "buffer": 1,
+        }
+        # lane "a" alone
+        _, errs = eng.decode_batch({"a": (int(got["a"].token[0]), dec)})
+        assert not errs
+        # a late driver's token that is never collected: dropped
+        flight = eng.decode_launch({"b": (int(out["b"].token[0]), dec)}, chain=DecodeFlight())
+        assert eng.decode_read(flight, asked=()) == ({}, {})
         eng.end_session("b")
         m = _moved(before)
-        assert m["dropped"] == 3
-        assert m["r1"] == 1 and m["r4"] == 2
-        assert m["slot_steps"] == 16 + 4 + 16 and m["lane_steps"] == 8 + 1 + 4
-        assert m["lane_steps"] <= m["slot_steps"]
+        assert m["dropped"] == 1 and m["sent"] == 7
+        assert m["slot_steps"] == 4 * m["sent"] and m["lane_steps"] == 8 + 2 + 1 + 1
+        assert m["dispatch"] == 10 and m["buffer"] == 1
         assert "spec" not in m
     finally:
         eng.close()
@@ -115,8 +117,8 @@ def test_decode_batch_counts_what_the_fused_chunk_did(tiny_llama_dir, paged_env,
 
 def test_decode_batch_counts_spec_tokens_by_source(tiny_llama_dir):
     """A speculating lane's first token is `spec`, the rest of its accepted
-    block come back as `buffer`; the fused-chunk step counters stay out of
-    it (another program computed those)."""
+    block come back as `buffer`; the step's counters stay out of it
+    (another program computed those)."""
     from dnet_tpu.core.batch import BatchedEngine
 
     eng = BatchedEngine(
@@ -130,9 +132,9 @@ def test_decode_batch_counts_spec_tokens_by_source(tiny_llama_dir):
         tok = int(eng.prefill_and_sample("s", [256, 72, 101, 108], dec).token[0])
         before = _counters()
         out, errs = eng.decode_batch({"s": (tok, dec)}, budgets={"s": 8})
-        assert not errs and eng.last_dispatch == (0, 0)
+        assert not errs
         m = _moved(before)
-        assert m == {"spec": 1}
+        assert m == {"spec": 1}  # no step went to the device
         buffered = len(eng._buffer.get("s", []))
         for _ in range(buffered):
             out, _ = eng.decode_batch({"s": (int(out["s"].token[0]), dec)},
@@ -240,12 +242,10 @@ def test_scheduler_rehearsal_waits_spans_and_tick_records(tiny_llama_dir, paged_
         n_dec = _span("dnet.decode.prepare")[0]  # one per decode_launch call
         assert DECODE_CHILD_SPANS == tuple(
             f"dnet.decode.{s}" for s in ("prepare", "launch", "readback", "unpack"))
-        disp = metric("dnet_decode_dispatch_total")
-        n_disp = disp.labels(r="1").value
-        assert all(disp.labels(r=str(r)).value == 0 for r in (2, 4, 8, 16))
+        n_disp = metric("dnet_decode_dispatch_total").value
         assert _span("dnet.decode.launch")[0] == _span("dnet.decode.readback")[0] == n_disp
         assert n_halves == n_dec + n_disp
-        # a lone stream is never fused and never answered from a buffer:
+        # a lone stream is never answered from a buffer:
         # one step a token, each but the first chained to the one before;
         # the last ask (a budget of 1) only reads
         assert n_disp == decode_tokens == n_dec - 1
@@ -267,14 +267,14 @@ def test_scheduler_rehearsal_waits_spans_and_tick_records(tiny_llama_dir, paged_
         assert len(recs) == n_tick
         decode_ticks = [r for r in recs if r["decode_lanes"]]
         assert len(decode_ticks) == decode_tokens  # the ticks that read a step
-        reached = [r for r in recs if r["chunk_r"]]  # the ticks that sent one
+        reached = [r for r in recs if r["dispatched_lanes"]]  # the ticks that sent one
         assert len(reached) == n_disp
         assert all(r["dispatched_lanes"] == 1 for r in reached)
-        assert all(r["dispatched_lanes"] == 0 for r in recs if not r["chunk_r"])
+        assert all("chunk_r" not in r for r in recs)
         # the first sends and reads nothing, the last reads and sends nothing
         assert reached[0] not in decode_ticks and decode_ticks[-1] not in reached
         assert sum(r["decode_lanes"] for r in recs) == decode_tokens
         slot_steps = metric("dnet_decode_slot_steps_total").value
-        assert slot_steps == slots * sum(r["chunk_r"] for r in reached)
+        assert slot_steps == slots * len(reached)
     finally:
         reset_obs()

@@ -350,8 +350,8 @@ def test_a_tick_that_leaves_a_step_in_flight_is_followed_busy(engine):
 
 
 def test_every_tick_but_the_last_enqueues_a_step_and_follows_one(engine):
-    """Two lanes in phase, 18 tokens each: nothing is fused and nothing is
-    answered from a buffer, so every tick between the prompts' and the last
+    """Two lanes in phase, 18 tokens each: nothing is answered from a
+    buffer, so every tick between the prompts' and the last
     enqueues one step; each of those turn-arounds but the first (it follows
     the adoptions' read) finds the step before still in flight, and their
     sum is the loop's share and the hops of the ticks between."""
@@ -359,9 +359,7 @@ def test_every_tick_but_the_last_enqueues_a_step_and_follows_one(engine):
     asyncio.run(_serve(engine, [(n, 18, None, None) for n in ("a", "b")]))
     m = _moved(before)
     ticks, launches = m["dnet.tick"][0], m["dnet.decode.launch"][0]
-    disp = metric("dnet_decode_dispatch_total")
-    assert launches == 17 == disp.labels(r="1").value
-    assert all(disp.labels(r=str(r)).value == 0 for r in (2, 4, 8, 16))
+    assert launches == 17 == metric("dnet_decode_dispatch_total").value
     assert launches + 2 <= ticks <= launches + 3  # the prompts' tick(s), the last read
     assert m["drained"][0] + m["busy"][0] == ticks - 2
     assert m["busy"][0] == launches - 1

@@ -504,7 +504,7 @@ def test_pipeline_qsparse8_token_parity_tolerance(tiny_llama_dir):
     tolerance-level token parity vs the lossless ring, at strictly fewer
     inter-hop bytes.  (The 64-dim random-weight fixture is hypersensitive
     to column dropping; byte-reduction at pct>0 is proven by the units
-    above and BENCH_SERVE_r04.)"""
+    above.)"""
     prompts = ["Hi", "Hello there", "A quick brown"]
     os.environ["DNET_WIRE_PIPELINE"] = "1"
     os.environ["DNET_WIRE_QSPARSE_PCT"] = "0.0"

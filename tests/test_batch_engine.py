@@ -178,10 +178,14 @@ def test_seeded_sampling_immune_to_other_traffic(tiny_llama_dir):
 
 
 def test_budget_chunks_match_serial_steps(tiny_llama_dir):
-    """Budget-driven fused chunks (R steps in one dispatch, extras buffered
-    engine-side) must produce the exact serial stream, including a lane
-    frozen mid-chunk and a seeded sampled lane."""
+    """A budget never changes the stream and never widens a dispatch: every
+    call sends ONE step for the lanes that asked, including a lane frozen
+    midway and a seeded sampled lane."""
     from dnet_tpu.core.batch import BatchedEngine
+    from dnet_tpu.obs import metric
+
+    sent = metric("dnet_decode_dispatch_total")
+    lane_steps = metric("dnet_decode_lane_steps_total")
 
     dec = DecodingParams(temperature=0.0)
     hot = DecodingParams(temperature=1.0, seed=9)
@@ -200,8 +204,10 @@ def test_budget_chunks_match_serial_steps(tiny_llama_dir):
             if step > 4:
                 reqs.pop("g")  # g freezes; h keeps decoding
             budgets = {n: 9 - step for n in reqs} if budgeted else None
+            before = sent.value, lane_steps.value
             out, errs = eng.decode_batch(reqs, budgets=budgets)
             assert not errs, errs
+            assert (sent.value, lane_steps.value) == (before[0] + 1, before[1] + len(reqs))
             for n, r in out.items():
                 last[n] = int(r.token[0])
                 got[n].append(last[n])
@@ -227,7 +233,7 @@ def test_deepseek_accepted_at_load(tmp_path_factory):
     eng = BatchedEngine(d, slots=2, max_seq=32, param_dtype="float32")
     assert eng.model.supports_kv_commit and eng.model.supports_paged_attend
     assert kv_layout(eng.model, 0, 0, 32)[0] == KV_PAGED
-    assert isinstance(eng.kv_store, KindStore) and eng.kv_ragged
+    assert isinstance(eng.kv_store, KindStore) and eng.kv_pool is not None
     assert eng.kv_store.leaves == {"c": (1, 128)} and eng.kv_store.latent_rank == 24
     # a quantised cache is the expanded one, on dense slots
     assert kv_layout(eng.model, 8, 0, 32)[0] == "dense"

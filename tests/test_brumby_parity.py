@@ -1,7 +1,8 @@
 """Brumby on the served path against the plain reference, float32 on seeded
 weights at a tiny size: prefill in chunks (the state crosses chunk edges and
 a ragged last chunk), adoption into a lane of the `StateStore`, then decode
-through it, one step at a time and fused, with another lane busy beside it.
+through it, one step at a time (alone too, under a budget), with another lane
+busy beside it.
 The reference (benchmarks/reference/brumby.py) is the QUADRATIC form over
 the whole sequence: no state anywhere.  Logits and log-probabilities are
 compared, not tokens."""
@@ -90,12 +91,15 @@ def test_chunked_prefill_then_decode_through_the_state_store(checkpoint, monkeyp
             assert not errs
             o_tok = int(out["other"].token[0])
             got.append(eng.token_result("a", out["a"], step=step, decoding=dec))
-        # one fused dispatch of four steps, alone (the other lane idles)
-        out, errs = eng.decode_batch({"a": (got[-1].token_id, dec)}, budgets={"a": 4})
-        assert not errs and eng.last_dispatch == (4, 1)
-        got.append(eng.token_result("a", out["a"], step=4, decoding=dec))
-        for step in range(5, 8):
-            out, _ = eng.decode_batch({"a": (got[-1].token_id, dec)})
+        # four steps alone (the other lane idles), a budget riding along:
+        # it never widens a dispatch
+        sent = metric("dnet_decode_dispatch_total")
+        sent0 = sent.value
+        for step in range(4, 8):
+            out, errs = eng.decode_batch(
+                {"a": (got[-1].token_id, dec)}, budgets={"a": 8 - step}
+            )
+            assert not errs and sent.value - sent0 == step - 3
             got.append(eng.token_result("a", out["a"], step=step, decoding=dec))
         assert worst_error(cfg, model_dir, ids, got) < TOL
         eng.close()

@@ -12,7 +12,6 @@ import json
 import subprocess
 import sys
 from pathlib import Path
-from types import SimpleNamespace
 
 import jax
 import pytest
@@ -157,20 +156,6 @@ def test_local_preload_failure_exits_nonzero(tmp_path):
     )
     assert proc.returncode == 1, proc.stderr[-2000:]
     assert "preload of" in proc.stderr + proc.stdout
-
-
-def test_bench_knows_no_default_chip():
-    sys.path.insert(0, str(REPO))
-    try:
-        import bench
-    finally:
-        sys.path.remove(str(REPO))
-    assert bench._chip_gen(SimpleNamespace(device_kind="TPU v5 lite")) == "v5 lite"
-    assert bench.CHIP_SPECS["v5 lite"] == bench.CHIP_SPECS["v5e"]
-    with pytest.raises(ValueError, match="no row in CHIP_SPECS"):
-        bench._chip_gen(SimpleNamespace(device_kind="TPU v9 hypothetical"))
-    with pytest.raises(ValueError):
-        bench._chip_gen(SimpleNamespace(device_kind="cpu"))
 
 
 # ---- chip_smoke.py --------------------------------------------------------

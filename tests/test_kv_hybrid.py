@@ -145,18 +145,18 @@ def test_two_lanes_do_not_see_each_other_and_the_books_follow(engine, checkpoint
     assert tb == run(engine, "b_alone", b, steps=2)
 
 
-def test_fused_steps_carry_the_store_in_place(engine, checkpoint):
+def test_budgeted_steps_carry_the_store_in_place(engine, checkpoint):
     cfg = checkpoint[0]
-    a = ids(cfg, 38, 1)  # the fused steps cross a block's edge (40)
+    a = ids(cfg, 38, 1)  # the steps cross a block's edge (40)
     want = run(engine, "a", a, steps=8)
     engine.end_session("a")
     res = engine.prefill_and_sample("a", a, decoding())
     toks = [int(res.token[0])]
-    out, errs = engine.decode_batch({"a": (toks[-1], decoding())}, budgets={"a": 8})
-    assert not errs and engine.last_dispatch == (8, 1)
-    toks.append(int(out["a"].token[0]))
-    for _ in range(7):  # the rest come from the engine's buffer
-        out, _ = engine.decode_batch({"a": (toks[-1], decoding())})
+    sent = metric("dnet_decode_dispatch_total")
+    sent0 = sent.value
+    for k in range(8):  # a budget rides along and never widens a dispatch
+        out, errs = engine.decode_batch({"a": (toks[-1], decoding())}, budgets={"a": 8 - k})
+        assert not errs and sent.value - sent0 == k + 1
         toks.append(int(out["a"].token[0]))
     assert toks == want
 

@@ -2,6 +2,7 @@
 full layers keep every block, window layers give back the blocks behind
 the window, and each kind's accounting is exact on its own."""
 
+import jax.numpy as jnp
 import pytest
 
 from dnet_tpu.kv import (
@@ -39,6 +40,55 @@ def test_window_blocks_covers_window_step_and_edges(window, bt, step, want):
     for pos in range(0, 4 * window, 7):
         held = -(-(pos + step) // bt) - window_first_block(pos, window, bt)
         assert held <= want
+
+
+@pytest.mark.parametrize(
+    "window,bt,cap,want",
+    [(4096, 128, 256, 35), (24, 8, 16, 6), (24, 8, 1, 5)],
+    ids=["the_mix_cell", "the_tiny_cell", "a_cap_under_the_step"],
+)
+def test_the_engine_sizes_the_window_pool_by_the_prefill_cap(monkeypatch, window, bt, cap, want):
+    """`BatchedEngine._init_pool`'s own arithmetic: a slot's share of the
+    window kind's pool covers the window and the widest prefill chunk
+    (DNET_SCHED_PREFILL_CHUNK, else the tick's token budget), and never
+    less than the two tokens a decode step may add past `pos` (the step in
+    flight and the one chained to it)."""
+    from types import SimpleNamespace
+
+    from dnet_tpu.config import reset_settings_cache
+    from dnet_tpu.core.batch import WINDOW_STEP_TOKENS, BatchedEngine
+    from dnet_tpu.kv import KindStore
+
+    monkeypatch.setenv("DNET_KV_BLOCK_TOKENS", str(bt))
+    monkeypatch.setenv("DNET_SCHED_TOKEN_BUDGET", str(cap))
+    monkeypatch.delenv("DNET_SCHED_PREFILL_CHUNK", raising=False)
+    reset_settings_cache()
+    try:
+        slots = 3
+        model = SimpleNamespace(
+            paged_kinds=(KV_KIND_WINDOW,) * 3 + (KV_KIND_FULL,), window=window,
+            config=SimpleNamespace(num_key_value_heads=2, head_dim=4, model_type="stub"),
+            # a slot-addressed session row, as the pool cuts blocks out of
+            init_kv=lambda n, b, s, dt: {
+                leaf: jnp.zeros((n, b, s, 2, 4), dt) for leaf in ("k", "v")
+            },
+        )
+        eng = BatchedEngine.__new__(BatchedEngine)
+        eng.max_seq, eng.spec_lookahead, eng._tables = 2 * bt * 32, 0, [None] * slots
+        eng.eng = SimpleNamespace(kv_dtype="float32", config=model.config)
+        eng._init_pool(model, slots, 0)
+        assert isinstance(eng.kv_store, KindStore) and eng._window == window
+        wpool = eng.kv_pools[KV_KIND_WINDOW]
+        assert wpool.total == slots * want
+        assert want == window_blocks(window, bt, max(cap, WINDOW_STEP_TOKENS))
+        assert eng.kv_store.kv[KV_KIND_WINDOW]["k"].shape[:3] == (3, slots * want, bt)
+        # a decode step's table: from the window's first block at `pos`
+        # through the chained step's row at pos + 1, wherever pos stands
+        for pos in range(0, 3 * window, max(window // 37, 1)):
+            held = (pos + 1) // bt - window_first_block(pos, window, bt) + 1
+            assert held <= want, (pos, held)
+    finally:
+        reset_settings_cache()
 
 
 def test_each_kind_keeps_exact_books_and_window_blocks_come_back():

@@ -1,6 +1,6 @@
 """Long-context (128K north star) proofs on CPU proxies.
 
-BASELINE.md config 5 (Llama-3-70B 128K-context ring) cannot run in this
+The north star's long-context ring (Llama-3-70B, 128K) cannot run in this
 image; what CAN be pinned down here is (a) the solver's KV memory model —
 128K of KV per layer must displace resident layers and flip assignments to
 weight-streaming, scaled by kv_bits — and (b) the sequence-parallel serving

@@ -3,7 +3,7 @@ after every one) on the served path against the plain reference, float32
 on seeded weights at a tiny size (4 heads of 16 + 8 over a latent of 16,
 two layers, 4 experts held of 8, top-2): prefill in chunks that EXPAND the
 latent row, adoption into the pool of latent blocks, then decode through
-it ABSORBED, one step at a time and fused, with another lane busy beside
+it ABSORBED, one step at a time (alone too), with another lane busy beside
 it.  The reference (benchmarks/reference/mistral4.py) makes every head's
 keys and values from the latent and attends every pair of positions: not
 absorbed, no cache.  Logits and log-probabilities are compared, not tokens.
@@ -155,12 +155,13 @@ def test_chunked_prefill_then_decode_through_the_latent_pool(
     assert metric("dnet_mla_latent_bytes_total").value - byt1 == live * 2 * 24 * 4
     assert byt1 == byt0  # a prefill books none
     assert len(eng._tables[eng.slot_of["a"]].blocks) == 11
-    # one fused dispatch of four steps, alone (the other lane idles)
-    out, errs = eng.decode_batch({"a": (got[-1].token_id, dec)}, budgets={"a": 4})
-    assert not errs and eng.last_dispatch == (4, 1)
-    got.append(eng.token_result("a", out["a"], step=5, decoding=dec))
-    for step in range(6, 9):
-        out, _ = eng.decode_batch({"a": (got[-1].token_id, dec)})
+    # four steps alone (the other lane idles), a budget riding along: it
+    # never widens a dispatch
+    sent = metric("dnet_decode_dispatch_total")
+    sent0 = sent.value
+    for step in range(5, 9):
+        out, errs = eng.decode_batch({"a": (got[-1].token_id, dec)}, budgets={"a": 9 - step})
+        assert not errs and sent.value - sent0 == step - 4
         got.append(eng.token_result("a", out["a"], step=step, decoding=dec))
     assert worst_error(cfg, model_dir, ids, got) < TOL
     eng.close()

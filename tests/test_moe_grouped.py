@@ -476,7 +476,7 @@ def test_wide_chunks_give_the_logits_of_one_whole_prefill(chunks, tiny_dirs, pag
     ids = [int(i) for i in rng.integers(1, cfg["vocab_size"], size=300)]
     want = _prefill_logits(d, "dense", ids, max_seq=1024)
     eng = BatchedEngine(d, slots=3, max_seq=1024, param_dtype="float32")
-    assert eng.kv_ragged and eng.eng.model.moe_impl == "auto"
+    assert eng.kv_pool is not None and eng.eng.model.moe_impl == "auto"
     g0, d0 = _rows("grouped"), _rows("dense")
     eng.reserve_slot("a")
     cuts = [300] if chunks == 1 else [288, 300]

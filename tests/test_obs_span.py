@@ -80,7 +80,7 @@ def test_span_starts_no_jax_backend_and_is_cheap():
         "with span('dnet.tick', decode_lanes=1, prefill_chunks=0): pass\n"
         "t = time.perf_counter()\n"
         "for _ in range(2000):\n"
-        "    with span('dnet.decode.launch', R=1, lanes=1): pass\n"
+        "    with span('dnet.decode.launch', lanes=1): pass\n"
         "per_us = (time.perf_counter() - t) / 2000 * 1e6\n"
         "from jax._src import xla_bridge\n"
         "print(len(xla_bridge._backends), per_us)\n"

@@ -332,19 +332,23 @@ def test_paged_matches_dense_streams(tiny_llama_dir, dense_ref, paged_env):
 
 
 def test_paged_chunked_decode_matches_dense(tiny_llama_dir, dense_ref, paged_env):
-    """Budget-driven fused chunks take the gather/scatter path too; the
-    buffered stream must stay identical to the dense chunked stream."""
+    """A budget never changes the stream, over the pool as on dense slots:
+    every call's dispatch carries one step, across a block's edge (16)."""
     dec = DecodingParams(temperature=0.0)
+    sent = metric("dnet_decode_dispatch_total")
+    slot_steps = metric("dnet_decode_slot_steps_total")
 
     def run(eng):
         eng.end_session("ck")
         res = eng.prefill_and_sample("ck", PROMPTS["vb"], dec)
         toks = [int(res.token[0])]
         while len(toks) < 12:
+            before = sent.value, slot_steps.value
             out, errs = eng.decode_batch(
                 {"ck": (toks[-1], dec)}, budgets={"ck": 12 - len(toks)}
             )
             assert not errs
+            assert (sent.value, slot_steps.value) == (before[0] + 1, before[1] + eng.slots)
             toks.append(int(out["ck"].token[0]))
         eng.end_session("ck")
         return toks
@@ -551,9 +555,9 @@ def test_explicit_dense_overrides_the_derived_pool(tiny_llama_dir):
     eng = BatchedEngine(tiny_llama_dir, kv_paged=False, **kw)
     derived = BatchedEngine(tiny_llama_dir, **kw)
     try:
-        assert eng.kv_pool is None and eng.kv is not None and not eng.kv_ragged
+        assert eng.kv_pool is None and eng.kv is not None
         assert eng.eng.prefix_cache is not None and eng.paged_prefix is None
-        assert derived.kv_pool is not None and derived.kv is None and derived.kv_ragged
+        assert derived.kv_pool is not None and derived.kv is None
         assert derived.eng.prefix_cache is None and derived.paged_prefix is not None
     finally:
         eng.close()
@@ -581,29 +585,39 @@ def test_paged_fallback_keeps_dense_prefix_cache(tiny_llama_dir, monkeypatch):
         reset_settings_cache()
 
 
-def test_chunk_shrink_rolls_back_hoarded_blocks(tiny_llama_dir, paged_env, monkeypatch):
-    """When the pool can't cover a wide fused chunk, the shrink to R=1 must
-    return the wide pass's speculative blocks — the first lane's unused
-    hoard must not starve the lanes behind it."""
+def test_a_lane_the_pool_cannot_extend_fails_alone_while_the_others_step(
+    tiny_llama_dir, paged_env, monkeypatch
+):
+    """One pass over the lanes, one token each: the lane that finds no block
+    is refused ALONE, with the typed message and nothing hoarded, while the
+    lane before it (which took the last block) and the lane after it (which
+    needs none) take their step.  A budget changes none of it."""
     from dnet_tpu.config import reset_settings_cache
 
     monkeypatch.setenv("DNET_KV_POOL_BLOCKS", "4")
     reset_settings_cache()
-    eng = _paged_engine(tiny_llama_dir, slots=2)
+    eng = _paged_engine(tiny_llama_dir, slots=3)
     try:
         dec = DecodingParams(temperature=0.0)
         last = {}
-        for n in ("r1", "r2"):  # one full block each (bt=8), pos at boundary
-            res = eng.prefill_and_sample(n, list(range(100, 108)), dec)
+        # r1, r2: one full block each (bt=8), pos at the edge; r3 has room
+        for n, k in (("r1", 8), ("r2", 8), ("r3", 5)):
+            res = eng.prefill_and_sample(n, list(range(100, 100 + k)), dec)
             last[n] = int(res.token[0])
-        assert eng.kv_pool.free == 2
-        # a 16-token budget asks for R=16 (2 extra blocks per lane: only
-        # one lane fits) — both lanes must still take their single step
+        assert eng.kv_pool.free == 1
         out, errs = eng.decode_batch(
-            {n: (t, dec) for n, t in last.items()},
-            budgets={"r1": 16, "r2": 16},
+            {n: (t, dec) for n, t in last.items()}, budgets=dict.fromkeys(last, 16)
         )
-        assert not errs and set(out) == {"r1", "r2"}
+        assert set(out) == {"r1", "r3"}
+        assert errs == {"r2": "paged KV pool exhausted: need 1 block(s), 0 free of 4"}
+        assert eng.kv_pool.free == 0
+        pos = {n: int(eng.pos[eng.slot_of[n]]) for n in last}
+        assert pos == {"r1": 9, "r2": 8, "r3": 6}
+        assert [len(eng._tables[eng.slot_of[n]].blocks) for n in last] == [2, 1, 1]
+        # the refused lane lost nothing: a block comes back and it steps
+        eng.end_session("r3")
+        out, errs = eng.decode_batch({"r2": (last["r2"], dec)})
+        assert not errs and set(out) == {"r2"}
         eng.end_session("r1")
         eng.end_session("r2")
         eng.kv_pool.check_conservation()

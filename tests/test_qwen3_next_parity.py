@@ -3,7 +3,7 @@ seeded weights at a tiny size (one period: three Gated DeltaNet layers and
 one gated-attention layer, 16 experts held of 32, top-4): prefill in chunks
 (the state, the convolution's tail and the block table cross chunk edges
 and a ragged last chunk), adoption into a lane AND a page table of the
-combined store, then decode through it, one step at a time and fused, with
+combined store, then decode through it, one step at a time (alone too), with
 another lane busy beside it.  The reference (benchmarks/reference/
 qwen3_next.py) is the token-by-token recurrence and quadratic attention
 over the whole sequence: no chunks, no cache.  Logits and log-probabilities
@@ -102,12 +102,15 @@ def test_chunked_prefill_then_decode_through_the_combined_store(checkpoint, monk
             o_tok = int(out["other"].token[0])
             got.append(eng.token_result("a", out["a"], step=step, decoding=dec))
         assert len(eng._tables[eng.slot_of["a"]].blocks) == 11
-        # one fused dispatch of four steps, alone (the other lane idles)
-        out, errs = eng.decode_batch({"a": (got[-1].token_id, dec)}, budgets={"a": 4})
-        assert not errs and eng.last_dispatch == (4, 1)
-        got.append(eng.token_result("a", out["a"], step=5, decoding=dec))
-        for step in range(6, 9):
-            out, _ = eng.decode_batch({"a": (got[-1].token_id, dec)})
+        # four steps alone (the other lane idles), a budget riding along:
+        # it never widens a dispatch
+        sent = metric("dnet_decode_dispatch_total")
+        sent0 = sent.value
+        for step in range(5, 9):
+            out, errs = eng.decode_batch(
+                {"a": (got[-1].token_id, dec)}, budgets={"a": 9 - step}
+            )
+            assert not errs and sent.value - sent0 == step - 4
             got.append(eng.token_result("a", out["a"], step=step, decoding=dec))
         assert worst_error(cfg, model_dir, ids, got) < TOL
         eng.close()

@@ -944,7 +944,7 @@ def test_cfg_break_terminates_path():
 
 
 def test_jit_bindings_resolution():
-    """The jit model resolves wrappers, factories, and scoped locals."""
+    """The jit model resolves wrappers and scoped locals."""
     from dnet_tpu.analysis import SourceFile as SF
 
     src = SF("dnet_tpu/ops/m.py", (
@@ -956,9 +956,6 @@ def test_jit_bindings_resolution():
         "    def build(self):\n"
         "        self._step = instrument_jit(\n"
         "            jax.jit(step, donate_argnums=(0,)), 'batched_step')\n"
-        "    def chunk_fn(self, R):\n"
-        "        fn = jax.jit(step, donate_argnums=(0, 1))\n"
-        "        return fn\n"
         "def fac_a():\n"
         "    jitted = jax.jit(step, donate_argnums=(0,))\n"
         "    return jitted\n"
@@ -969,8 +966,7 @@ def test_jit_bindings_resolution():
     b = jit_bindings(src)
     assert b["self._step"].donate == (0,)
     assert b["self._step"].label == "batched_step"
-    assert b["self.chunk_fn()"].donate == (0, 1)
-    # per-function scoping: the two factories' `jitted` locals don't collide
+    # per-function scoping: the two functions' `jitted` locals don't collide
     assert b["fac_a:jitted"].donate == (0,)
     assert b["fac_b:jitted"].donate == (1,)
 
@@ -1064,8 +1060,8 @@ def test_dl021_quiet_on_starred_args_rebind():
 
 
 def test_dl021_real_batch_engine_rebind_idiom_is_quiet():
-    """The live donate-and-rebind sites in core/batch.py (the ragged
-    chunk's donated pool rebound via `self.kv_store.kv = pool`) must stay
+    """The live donate-and-rebind sites in core/batch.py (the dense
+    step's donated cache rebound via `... self.kv, ... = out`) must stay
     quiet — they are the sanctioned pattern the check's message points
     at."""
     text = (REPO / "dnet_tpu" / "core" / "batch.py").read_text()
@@ -1380,19 +1376,19 @@ def _flow_findings(texts):
     return analyze_texts(texts, checks=FLOW_CHECKS)
 
 
-def test_seeded_dl021_donated_pool_read_after_ragged_step():
-    """Injecting a read of the donated pool between the ragged chunk call
-    and its sanctioned rebind produces exactly one DL021 at that line;
-    the clean file produces none."""
+def test_seeded_dl021_donated_cache_read_after_the_batched_step():
+    """Injecting a read of the donated cache between the batched step's
+    call and its sanctioned rebind produces exactly one DL021 at that
+    line; the clean file produces none."""
     rel = "dnet_tpu/core/batch.py"
     assert _flow_findings({rel: (REPO / rel).read_text()}) == []
     texts, line = _inject(
-        rel, "self.kv_store.kv = pool",
-        "probe = jax.tree.map(jnp.shape, self.kv_store.kv)",
+        rel, "flight.src, self.kv, self.counts, self.keys = out",
+        "probe = jax.tree.map(jnp.shape, self.kv)",
     )
     fs = _flow_findings(texts)
     assert codes(fs) == ["DL021"], fs
-    assert fs[0].line == line and "self.kv_store.kv" in fs[0].message
+    assert fs[0].line == line and "self.kv" in fs[0].message
 
 
 def test_seeded_dl022_python_scalar_jit_argument():
