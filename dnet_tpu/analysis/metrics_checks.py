@@ -526,11 +526,24 @@ def check_attribution_labels(errors: list) -> int:
         n += _cross_check_labels(
             errors, text, fam, "kind", KV_KINDS, "obs.phases.KV_KINDS"
         )
-    for fam in ("dnet_retention_tokens_total", "dnet_gdn_tokens_total", "dnet_mla_tokens_total"):
+    for fam in (
+        "dnet_retention_tokens_total", "dnet_gdn_tokens_total", "dnet_mla_tokens_total",
+        "dnet_lightning_tokens_total",
+    ):
         n += _cross_check_labels(
             errors, text, fam, "phase", RETENTION_PHASES,
             "obs.phases.RETENTION_PHASES",
         )
+    from dnet_tpu.obs.phases import SPARSE_BLOCK_STATES, SPARSE_MODES
+
+    n += _cross_check_labels(
+        errors, text, "dnet_sparse_blocks_total", "state", SPARSE_BLOCK_STATES,
+        "obs.phases.SPARSE_BLOCK_STATES",
+    )
+    n += _cross_check_labels(
+        errors, text, "dnet_sparse_tokens_total", "mode", SPARSE_MODES,
+        "obs.phases.SPARSE_MODES",
+    )
     n += _cross_check_labels(
         errors, text, "dnet_moe_assignments_total", "held", MOE_HELD,
         "obs.phases.MOE_HELD",

@@ -88,6 +88,9 @@ _MOE_ASSIGNMENTS = metric("dnet_moe_assignments_total")
 _MLA_TOKENS = metric("dnet_mla_tokens_total")
 _MLA_LATENT_BYTES = metric("dnet_mla_latent_bytes_total")
 _MLA_EXPANDED = metric("dnet_mla_expanded_tokens_total")
+_SPARSE_BLOCKS = metric("dnet_sparse_blocks_total")
+_SPARSE_TOKENS = metric("dnet_sparse_tokens_total")
+_SPARSE_INDEX_ROWS = metric("dnet_sparse_index_rows_total")
 _FLASH_TILES = metric("dnet_flash_tiles_total")
 _STATE_SLOTS_USED = metric("dnet_state_slots_used")
 
@@ -324,6 +327,12 @@ class BatchedEngine:
         self._latent_entry_bytes = (
             m.latent_dim * jnp.dtype(self.eng.kv_dtype).itemsize * len(m.layers)
             if getattr(self.kv_store, "latent_rank", 0) else 0
+        )
+        #: the full layers' choice of blocks (ops/sparse_attention.py
+        #: SparseConfig; None: they attend everything) and how many there are
+        self._sparse = getattr(self.kv_store, "sparse", None)
+        self._sparse_layers = (
+            len(self.kv_store.layers[KV_KIND_FULL]) if self._sparse is not None else 0
         )
         #: (kind, window) -> how many layers of it a prefill chunk attends
         #: through the flash kernel (dnet_flash_tiles_total)
@@ -829,6 +838,12 @@ class BatchedEngine:
             state_tokens.labels(phase="prefill").inc(len(ids))
         sess = self.eng.sessions.get(nonce)
         pos = 0 if sess is None else int(sess.pos)
+        if self._sparse is not None:
+            # the chunk's positions by the side of dense_len their context
+            # lies on (position t has context t + 1)
+            dense = min(max(self._sparse.dense_len - pos, 0), len(ids))
+            _SPARSE_TOKENS.labels(mode="dense").inc(dense)
+            _SPARSE_TOKENS.labels(mode="sparse").inc(len(ids) - dense)
         if self._latent_entry_bytes:
             # a latent model's chunk at position p attends keys and values
             # expanded from the row's latents [0, p + T), in every layer
@@ -912,6 +927,10 @@ class BatchedEngine:
         if KV_KIND_STATE in self.kv_store.kinds:
             staged[KV_KIND_STATE] = slot  # the session's entry over the lane's
         self.kv_store.commit_staged(sess.kv, staged)
+        if self._sparse is not None and n >= self._sparse.kernel_size:
+            # the spans the prompt completed, pooled into the index leaf
+            spans = (n - self._sparse.kernel_size) // self._sparse.kernel_stride + 1
+            _SPARSE_INDEX_ROWS.inc(spans * self._sparse_layers)
         if stash is not None:
             if n_sh % cfg.block_tokens:
                 # the request diverged mid-block: the shared tail block was
@@ -1202,12 +1221,24 @@ class BatchedEngine:
             now = time.time()
             out = flight.out
             delivered = surplus = live = 0
+            sp = self._sparse
+            stayed = chosen = resident = past_dense = spans = 0
             for nonce, slot in flight.order.items():
                 if self.slot_of.get(nonce) != slot:
                     # the lane left with its step in flight
                     surplus += nonce in flight.chained
                     continue
                 live += int(self.pos[slot])  # the entries its step attended
+                if sp is not None:
+                    # from the position the host has (no sync): the blocks
+                    # the step's query holds and reads, whether its token
+                    # completed a span of the index
+                    n = int(self.pos[slot]) + 1
+                    stayed += 1
+                    resident += -(-n // sp.block_size)
+                    chosen += sp.blocks_attended(n)
+                    past_dense += n > sp.dense_len
+                    spans += n >= sp.kernel_size and (n - sp.kernel_size) % sp.kernel_stride == 0
                 self.pos[slot] += 1
                 self.last_used[slot] = now
                 row = SampleResult(
@@ -1233,6 +1264,13 @@ class BatchedEngine:
             # layer a step, from positions the host already has (no sync)
             _MLA_LATENT_BYTES.inc(live * self._latent_entry_bytes)
             _MLA_TOKENS.labels(phase="decode").inc(lanes)
+        if sp is not None:
+            layers = self._sparse_layers
+            _SPARSE_BLOCKS.labels(state="chosen").inc(chosen * layers)
+            _SPARSE_BLOCKS.labels(state="resident").inc(resident * layers)
+            _SPARSE_TOKENS.labels(mode="sparse").inc(past_dense)
+            _SPARSE_TOKENS.labels(mode="dense").inc(stayed - past_dense)
+            _SPARSE_INDEX_ROWS.inc(spans * layers)
         if self.kv_store is not None and self.kv_store.in_place:
             # what the algorithm needs: each active lane's entry read and
             # written once a step, in every layer

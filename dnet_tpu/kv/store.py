@@ -73,7 +73,36 @@ _STATE_COUNTERS = {
         metric("dnet_gdn_state_bytes_total"),
         metric("dnet_gdn_tokens_total"),
     ),
+    "lightning": (
+        metric("dnet_lightning_state_bytes_total"),
+        metric("dnet_lightning_tokens_total"),
+    ),
 }
+
+
+def _gdn_state_step(state, q, rows, layer, impl):
+    from dnet_tpu.ops.gated_delta import gdn_decode
+
+    gate = rows["gate"]
+    return gdn_decode(
+        state, q[:, 0], gate["conv_w"], gate["g"], gate["beta"],
+        rows["active"], layer, impl=impl,
+    )
+
+
+def _lightning_state_step(state, q, rows, layer, impl):
+    from dnet_tpu.ops.lightning import lightning_step
+
+    o, S = lightning_step(
+        state["S"], q[:, 0], rows["k"], rows["v"], rows["active"], layer, impl=impl
+    )
+    return o, {**state, "S": S}
+
+
+#: a hybrid model's state layers' decode step, by the same family: (the
+#: state kind's stacks, q [B, 1, ...], the step's rows, the layer's index
+#: within the kind, impl) -> (o [B, ...], the stacks)
+_STATE_STEPS = {"gdn": _gdn_state_step, "lightning": _lightning_state_step}
 
 
 def _bucket_pow2(n: int) -> int:
@@ -390,12 +419,22 @@ class HybridStore:
     address).  `self.kv` is `{"full": {"k", "v"}, "state": {...}}`.
 
     `in_place`: the whole store rides the decode step donated, as the
-    carry of the model's scan.  A state layer's `attend` is its read, decay
-    and correction (`gdn_decode`); a full layer's is the kernel's read of
+    carry of the model's scan.  A state layer's `attend` is its family's
+    step (`model.state_family`: the delta rule's read, decay and correction,
+    or lightning attention's); a full layer's is the kernel's read of
     the pool through the page tables AND the new row's write into the
     lane's block, so `append_rows` has nothing left to write.
     `commit_staged` is adoption: the staged row's blocks into the pool and
     the session's state entry over the lane's, in one program.
+
+    The full kind's leaves are the MODEL's (`pool_leaves`, as KindStore's):
+    keys and values, and for a model whose full layers attend a chosen
+    subset of blocks (`model.sparse`, ops/sparse_attention.py) the index
+    beside them: a leaf `kc` whose rows are not tokens but pooled keys,
+    one for `pool_strides()["kc"]` tokens, `[L_full, N, block_tokens /
+    stride, KVH*Hd]` under the same page table.  Adoption pools the staged
+    row's keys into it; a decode step extends it when its token completes
+    a span, in the same donated program as the row's write.
 
     Admission is by both: a free lane and the blocks of the `full` pool
     (core/batch.py, sched/policy.py).  A lane's blocks are never aliased
@@ -413,8 +452,20 @@ class HybridStore:
             self.layers[kind] = self.layers.get(kind, ()) + (i,)
         if set(self.layers) != set(self.kinds):
             raise ValueError(f"layer kinds {sorted(self.layers)} != {sorted(self.kinds)}")
-        c = model.config
-        width = c.num_key_value_heads * c.head_dim
+        from dnet_tpu.models.base import RingModel
+
+        # (a stand-in that is no RingModel gets the default: k and v of KVH x Hd)
+        own = getattr(model, "pool_leaves", None)
+        self.leaves = leaves = dict(own() if own else RingModel.pool_leaves(model))
+        #: tokens a row of a leaf stands for, where that is not one
+        strides = dict(getattr(model, "pool_strides", dict)())
+        #: the full layers' choice of blocks (None: they attend everything)
+        self.sparse = sparse = getattr(model, "sparse", None)
+        if sparse is not None and (bt % sparse.block_size or set(strides) != {"kc"}):
+            raise ValueError(
+                f"pool blocks of {bt} tokens do not hold whole blocks of "
+                f"{sparse.block_size} (or the index leaf is not `kc`: {strides})"
+            )
         dt = jnp.dtype(kv_dtype)
         n_full = len(self.layers[KV_KIND_FULL])
         # the model's own session layout, a lane where a sequence would be
@@ -422,8 +473,10 @@ class HybridStore:
         state_keys = tuple(k for k in entries if k not in ("k", "v"))
         self.kv = {
             KV_KIND_FULL: {
-                leaf: jnp.zeros((n_full, cfg.pool_blocks, bt, width), dt)
-                for leaf in ("k", "v")
+                leaf: jnp.zeros(
+                    (n_full, cfg.pool_blocks, bt // strides.get(leaf, 1), h * d), dt
+                )
+                for leaf, (h, d) in leaves.items()
             },
             KV_KIND_STATE: {k: entries[k] for k in state_keys},
         }
@@ -433,6 +486,7 @@ class HybridStore:
             for v in self.kv[KV_KIND_STATE].values()
         )
         self.state_counters = _STATE_COUNTERS[model.state_family]
+        self._state_step = _STATE_STEPS[model.state_family]
         _STATE_SLOTS.set(slots)
 
         @jax.named_scope("kv_scatter")
@@ -446,10 +500,25 @@ class HybridStore:
             def lane(s, r):
                 return jax.lax.dynamic_update_slice_in_dim(s, r.astype(s.dtype), slot, axis=1)
 
+            full = {
+                leaf: blocks(store[KV_KIND_FULL][leaf], dense[leaf]) for leaf in ("k", "v")
+            }
+            if sparse is not None:
+                # the index: the staged keys pooled, a layer at a time, cut
+                # into the same blocks (rows of spans the prompt has not
+                # completed hold partial means: the index masks them by
+                # position, and the decode step that completes one rewrites it)
+                from dnet_tpu.ops.sparse_attention import pooled_keys
+
+                rows = dense["k"][:, 0]
+                pooled = jax.vmap(
+                    lambda r: pooled_keys(r.reshape(r.shape[0], -1), sparse)
+                )(rows)
+                full["kc"] = _commit_blocks(
+                    store[KV_KIND_FULL]["kc"], pooled, block_idx, phys
+                )
             return {
-                KV_KIND_FULL: {
-                    leaf: blocks(store[KV_KIND_FULL][leaf], dense[leaf]) for leaf in ("k", "v")
-                },
+                KV_KIND_FULL: full,
                 KV_KIND_STATE: {
                     k: lane(store[KV_KIND_STATE][k], dense[k]) for k in state_keys
                 },
@@ -464,18 +533,22 @@ class HybridStore:
         its (traced) index within the kind.  Returns (o, the store)."""
         active = rows["active"]
         if kind == KV_KIND_STATE:
-            from dnet_tpu.ops.gated_delta import gdn_decode
-
-            gate = rows["gate"]
-            o, state = gdn_decode(
-                kvs[KV_KIND_STATE], q[:, 0], gate["conv_w"], gate["g"], gate["beta"],
-                active, layer, impl=impl,
-            )
+            o, state = self._state_step(kvs[KV_KIND_STATE], q, rows, layer, impl)
             return o[:, None], {**kvs, KV_KIND_STATE: state}
-        from dnet_tpu.ops.paged_attention import paged_attend
-
         full = kvs[KV_KIND_FULL]
         table = tables[KV_KIND_FULL]
+        if self.sparse is not None:
+            # the row's write, the index's extension, the choice and the
+            # read of the chosen blocks alone
+            from dnet_tpu.ops.sparse_attention import sparse_decode
+
+            out, full = sparse_decode(
+                full, q, rows["k"], rows["v"], table, pos, active, layer,
+                self.sparse, impl=impl,
+            )
+            return out, {**kvs, KV_KIND_FULL: full}
+        from dnet_tpu.ops.paged_attention import paged_attend
+
         out = paged_attend(
             q, full["k"], full["v"], table, pos, rows["k"], rows["v"],
             impl=impl, layer=layer,

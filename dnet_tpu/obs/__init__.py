@@ -143,6 +143,8 @@ def _register_core(reg: MetricsRegistry) -> None:
         MOE_HELD,
         MOE_PATHS,
         RETENTION_PHASES,
+        SPARSE_BLOCK_STATES,
+        SPARSE_MODES,
     )
 
     # the state kind (kv/store.py StateStore): one entry a lane, no blocks
@@ -184,6 +186,46 @@ def _register_core(reg: MetricsRegistry) -> None:
     )
     for phase in RETENTION_PHASES:
         gdn_fam.labels(phase=phase)
+    # and for lightning linear attention's (ops/lightning.py)
+    reg.counter(
+        "dnet_lightning_state_bytes_total",
+        "Bytes of lightning-attention state the batched decode dispatches "
+        "read and wrote: active lanes x steps x state layers x one entry x 2",
+    )
+    light_fam = reg.counter(
+        "dnet_lightning_tokens_total",
+        "Tokens that went through the state layers' lightning attention, by "
+        "the program that carried them",
+        labelnames=("phase",),
+    )
+    for phase in RETENTION_PHASES:
+        light_fam.labels(phase=phase)
+    # block-sparse attention over an index of pooled keys
+    # (ops/sparse_attention.py): booked on the host from positions it has
+    blocks_fam = reg.counter(
+        "dnet_sparse_blocks_total",
+        "Blocks of the sparse layers' block size the batched decode "
+        "dispatches' active lanes read (chosen) and hold (resident), a "
+        "sparse layer a step",
+        labelnames=("state",),
+    )
+    for state in SPARSE_BLOCK_STATES:
+        blocks_fam.labels(state=state)
+    modes_fam = reg.counter(
+        "dnet_sparse_tokens_total",
+        "Query positions that went through the sparse layers on the served "
+        "path (prefill chunks' real tokens and decode lanes), by the side "
+        "of dense_len their context lies on",
+        labelnames=("mode",),
+    )
+    for mode in SPARSE_MODES:
+        modes_fam.labels(mode=mode)
+    reg.counter(
+        "dnet_sparse_index_rows_total",
+        "Pooled keys written into the sparse layers' index leaf: the spans "
+        "an adopted prompt completed and those decode tokens completed, a "
+        "sparse layer each",
+    )
     # a latent cache (multi-head latent attention, models/deepseek_v2.py):
     # the pool's books are the `full` kind's; these count what crosses it
     mla_fam = reg.counter(

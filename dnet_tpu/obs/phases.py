@@ -138,10 +138,17 @@ SCOPE_ATTN_STATE = "dnet.attn.state"
 # attention over the latents (the decode kernel paged_attend_latent; a
 # prefill chunk's expansion and its flash kernel) and the un-absorb
 SCOPE_ATTN_LATENT = "dnet.attn.latent"
+# inside dnet.attn of a model whose full layers attend a CHOSEN subset of a
+# sequence's blocks (ops/sparse_attention.py; models/minicpm_sala.py): the
+# index (pooled keys to scores to the choice) and the read of the chosen
+# blocks (the decode kernel paged_attend_sparse; a prefill chunk's masked
+# tiles, flash_prefill_sparse)
+SCOPE_ATTN_INDEX = "dnet.attn.index"
+SCOPE_ATTN_SPARSE = "dnet.attn.sparse"
 DEVICE_SCOPES = (
     SCOPE_SAMPLE, SCOPE_LM_HEAD, SCOPE_MOE, SCOPE_ATTN,
     SCOPE_ATTN_WINDOW, SCOPE_ATTN_FULL, SCOPE_MOE_SHARED, SCOPE_ATTN_STATE,
-    SCOPE_ATTN_LATENT,
+    SCOPE_ATTN_LATENT, SCOPE_ATTN_INDEX, SCOPE_ATTN_SPARSE,
 )
 
 # dnet_kv_blocks_used / _free / dnet_kv_pool_blocks {kind=}: the paged pool
@@ -167,6 +174,16 @@ KV_KIND_STATE = "state"
 # by the program that carried them (a prefill chunk's real tokens; a decode
 # dispatch's lanes x steps)
 RETENTION_PHASES = ("prefill", "decode")
+
+# dnet_sparse_blocks_total{state=}: for each decode dispatch and sparse
+# layer, the blocks (of the model's `block_size` tokens) its active lanes
+# READ (`chosen`) against the blocks they HOLD (`resident`); 1 - chosen /
+# resident is the share of the cache a step leaves unread
+SPARSE_BLOCK_STATES = ("chosen", "resident")
+# dnet_sparse_tokens_total{mode=}: query positions that went through a
+# sparse layer on the served path, by the side of `dense_len` their context
+# lies on: `dense` attends everything before it, `sparse` the chosen blocks
+SPARSE_MODES = ("dense", "sparse")
 
 # dnet_flash_tiles_total{kind=, state=}: (q tile, kv tile) pairs of the
 # [T / bq, S / bk] grid a prefill chunk spans against its staged row, a

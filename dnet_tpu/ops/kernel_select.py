@@ -1,12 +1,14 @@
 """Which implementation each Pallas dispatcher picked, counted per process.
 
-Ten dispatchers choose between a Pallas kernel and a jnp path at trace
+Fifteen dispatchers choose between a Pallas kernel and a jnp path at trace
 time: causal prefill (`ops/flash_attention.py`), split-K decode
 (`ops/flash_decode.py`), ragged paged attend and its absorbed twin over
 latent entries (`ops/paged_attention.py`), the
 two hop-codec kernels (`compression/ops.py`), power retention's decode
-step and prefill chunk (`ops/retention.py`) and the gated delta rule's
-(`ops/gated_delta.py`).  The backend half of
+step and prefill chunk (`ops/retention.py`), the gated delta rule's (`ops/gated_delta.py`),
+lightning linear attention's (`ops/lightning.py`) and block-sparse
+attention's index, decode read and prefill (`ops/sparse_attention.py`).
+The backend half of
 that choice lives here, so that it is made one way: a TPU backend runs the
 Mosaic-compiled kernel and nothing else; DNET_FLASH_INTERPRET=1 selects
 interpret mode on a CPU backend (tier-1) and is an error on a TPU one.
@@ -25,7 +27,7 @@ import jax
 
 #: what a dispatcher can resolve to
 IMPLS = ("pallas", "interpret", "emulate", "dense")
-#: the ten dispatchers, by the name `/health` reports them under
+#: the dispatchers, by the name `/health` reports them under
 KERNELS = (
     "flash_prefill",
     "flash_decode",
@@ -37,6 +39,11 @@ KERNELS = (
     "retention_chunk",
     "gdn_step",
     "gdn_chunk",
+    "lightning_step",
+    "lightning_chunk",
+    "sparse_index",
+    "paged_attend_sparse",
+    "flash_prefill_sparse",
 )
 
 

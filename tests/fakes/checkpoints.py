@@ -590,3 +590,29 @@ def make_tiny_mistral4(model_dir: str | Path, seed: int = 2**31 + 41, **over) ->
     cfg = tiny_mistral4_config(**over)
     write_checkpoint(Path(model_dir), cfg, seed=seed, dtype="float32")
     return cfg
+
+
+def tiny_minicpm_sala_config(**over) -> dict:
+    """The benchmark configuration `minicpm-sala-8l` at its rehearsal size
+    (hidden 64, 4 query / 2 KV heads of 16, 4 lightning heads of 16, two
+    periods of [minicpm4, lightning-attn x 3]; blocks of 8 tokens chosen
+    6 at a time past 64 tokens of context): the HF keys alone."""
+    import json
+
+    root = Path(__file__).resolve().parents[2]
+    full = json.loads((root / "benchmarks/configs/minicpm-sala-8l.json").read_text())
+    cfg = {k: v for k, v in full.items()
+           if k not in ("assumed", "deployment", "serve", "check", "rehearse")}
+    cfg.update(full["rehearse"]["config"])
+    cfg.update(over)
+    return cfg
+
+
+def make_tiny_minicpm_sala(model_dir: str | Path, seed: int = 2**31 + 43, **over) -> dict:
+    """A seeded float32 minicpm_sala checkpoint, written as the benchmark
+    writes its own (tensor names from benchmarks/reference/minicpm_sala.py)."""
+    from benchmarks.harness.weights import write_checkpoint
+
+    cfg = tiny_minicpm_sala_config(**over)
+    write_checkpoint(Path(model_dir), cfg, seed=seed, dtype="float32")
+    return cfg
