@@ -580,6 +580,12 @@ def check_attribution_labels(errors: list) -> int:
         errors, text, "dnet_sched_drivers_turn_total", "outcome",
         DRIVERS_TURN_OUTCOMES, "obs.phases.DRIVERS_TURN_OUTCOMES",
     )
+    from dnet_tpu.obs.phases import DRIVER_ASK_ORDERS
+
+    n += _cross_check_labels(
+        errors, text, "dnet_api_driver_asks_total", "order",
+        DRIVER_ASK_ORDERS, "obs.phases.DRIVER_ASK_ORDERS",
+    )
     n += _cross_check_labels(
         errors, text, "dnet_jit_compiles_total", "fn",
         JIT_FNS, "obs.phases.JIT_FNS",

@@ -3,6 +3,24 @@
 Reference: src/dnet/api/inference.py:66-311 — template/encode, per-request
 nonce, per-token send/await/detokenize loop, EOS + stop-sequence + length
 stops, usage and profile metrics, and non-streaming aggregation.
+
+The order of a token's work (`InferenceManager._run`): DECIDE, ask, two
+hops, DELIVER.  A driver asks for its next token before it delivers the one
+it has.  What the serving side needs from a driver before it plans the next
+decode step is one bit (does the lane go on?), which the driver knows from
+the token id, the length and the deadline, a few tens of microseconds after
+it wakes; what the CLIENT needs (recorder, SLO tracker, detokenizer,
+logprob entry, chunk, SSE flush) is 0.3 ms a lane and none of the
+scheduler's business.  With the ask at the top of the next iteration, as it
+used to stand, every lane's delivery ran on the event loop between two decode
+steps and the chip waited for all of them (a tenth to a sixth of a slice on
+the two fastest decoders: PERF.md section 6, PR 51).  So the ask leaves at
+the end of DECIDE, the driver gives the loop its turn
+(`_behind_the_ticks_submit`), the tick loop plans and hands the step to the
+compute thread, and the deliveries run on the loop while that thread
+prepares, enqueues and reads.  The adapter protocol is unchanged
+(`send_tokens(step)` then `await_token(step)`): only WHEN the send leaves
+moved.
 """
 
 from __future__ import annotations
@@ -41,6 +59,7 @@ from dnet_tpu.core.types import (  # the two errors live there; re-exported
 )
 from dnet_tpu.obs import critical_path, get_recorder, get_slo_tracker, metric
 from dnet_tpu.obs.events import bind, log_event
+from dnet_tpu.obs.phases import DRIVER_ASK_AHEAD, DRIVER_ASK_AT_STEP
 from dnet_tpu.resilience.checkpoint import ResumableDecode
 from dnet_tpu.resilience.policy import is_retryable
 from dnet_tpu.utils.logger import get_logger
@@ -53,6 +72,9 @@ _REQUESTS = metric("dnet_requests_total")
 _REQUEST_ERRORS = metric("dnet_request_errors_total")
 _TOKENS_TOTAL = metric("dnet_tokens_generated_total")
 _CANCELS = metric("dnet_cancel_propagated_total")
+_ASKS = metric("dnet_api_driver_asks_total")
+_ASKS_AHEAD = _ASKS.labels(order=DRIVER_ASK_AHEAD)
+_ASKS_AT_STEP = _ASKS.labels(order=DRIVER_ASK_AT_STEP)
 
 
 class PromptTooLongError(InferenceError):
@@ -169,6 +191,54 @@ def _holdback_len(text: str, stop_seqs: list[str]) -> int:
     return hold
 
 
+def _scan_for_stop(text: str, stop_seqs: list[str]) -> tuple[str, str, bool]:
+    """Split the not-yet-emitted `text` of a request with stop sequences into
+    (what may be emitted now, what is held back, whether a stop matched).
+    A match discards itself and everything after it; without one, the
+    longest suffix that could still grow into a stop stays held."""
+    for s in stop_seqs:
+        idx = text.find(s)
+        if idx != -1:
+            return text[:idx], "", True
+    emit_upto = len(text) - _holdback_len(text, stop_seqs)
+    return text[:emit_upto], text[emit_upto:], False
+
+
+async def _behind_the_ticks_submit() -> None:
+    """Give the event loop its turn, twice, between a driver's ask for its
+    next token and the delivery of the one it has, so that the delivery
+    queues BEHIND the tick loop's submit of the next decode step
+    (sched/engine.py `_tick_loop`) and runs while the compute thread
+    prepares, enqueues and reads.
+
+    The two hops it stands behind, from the first ask of a turn to the
+    submit, where the tick loop is PARKED on `_kick.wait()` after a
+    step-only tick (`has_work` is False until a driver asks): (1) its
+    wake-up; (2) its own coalescing `await asyncio.sleep(0)`, after which
+    `_drivers_turn` finds every lane answered, plans and calls
+    `run_in_executor` without suspending again.  The ready queue, N drivers
+    woken by one tick (a = DECIDE and ask, y = after the first yield,
+    b = DELIVER):
+    `[d1a..dNa] -> [T1, d1y..dNy] -> [d1y..dNy, T2] -> [T2, d1b..dNb]`.
+
+    The loop's other state is ONE hop from its submit: the kick is already
+    set when the tick ends (a prompt waits; or the last turn waited in
+    `_drivers_turn`, whose way out leaves the kick set by the answer that
+    ended the wait), so `_kick.wait()` returns at once, the loop takes its
+    `sleep(0)` while `_apply`'s resolutions reach the drivers, and then
+    waits for their asks inside `_drivers_turn`; the last ask is its
+    wake-up.  There the second yield costs a pass of the queue.  Either
+    state keeps itself up in a closed loop.
+
+    One yield is not enough for the parked state (the order would be asks,
+    wake-up, ALL deliveries, plan).  Hang-free by construction: if the
+    loop's hops ever change, deliveries fall back to between the steps,
+    where they used to be, and tests/subsystems/test_ask_ahead.py says so
+    (it holds both states); nothing else breaks."""
+    await asyncio.sleep(0)
+    await asyncio.sleep(0)
+
+
 class InferenceManager:
     def __init__(
         self,
@@ -246,6 +316,18 @@ class InferenceManager:
             top_logprobs=top,
         )
 
+    def _degraded(self) -> bool:
+        return self.failure_monitor is not None and self.failure_monitor.degraded
+
+    def _bound_await(self, resume: ResumableDecode, deadline: Deadline) -> None:
+        """Re-bound the token await for the step about to be asked for: a
+        shard that hangs without dying must surface the 504 when the
+        deadline passes, not after the frozen request timeout (remaining()
+        shrinks every step)."""
+        resume.timeout_s = min(
+            self.request_timeout_s, max(deadline.remaining(), 0.001)
+        )
+
     def _deadline_for(self, req) -> Optional[Deadline]:
         from dnet_tpu.config import get_settings
 
@@ -298,6 +380,31 @@ class InferenceManager:
         deadline: Optional[Deadline] = None,
         admit_wait_ms: float = 0.0,
     ) -> AsyncIterator[ChatCompletionChunk]:
+        """One request's token loop.  Each token of step s is handled in
+        this order, and the order is the point (module docstring):
+
+        1. DECIDE what must be known before the lane may step again, and
+           nothing else: the error, the end-of-sequence ids, the resume
+           checkpoint, the length, the deadline and the ring's health, and,
+           ONLY where the request has stop sequences (read off the request,
+           no setting), the detokenizer and the stop search, because a stop
+           string is found in text;
+        2. if the lane goes on, ask for step s + 1 (`t_step` is stamped
+           here: the `decode_step` span and the SLO tracker measure ask to
+           token) and give the loop two hops (`_behind_the_ticks_submit`);
+           the next iteration's top does not send again.  An ask that
+           raises is not sent twice: what it raised is raised at that top,
+           inside the `try` that owns the resume path, after token s is
+           delivered, which is where and when a failed send always
+           surfaced;
+        3. DELIVER: recorder, SLO tracker, counters, the detokenizer where
+           DECIDE did not run it, the logprob entry, hold-back, the chunk's
+           `yield` (and behind it the HTTP layer's serialisation and flush).
+
+        A lane that stops at step s (eos, stop sequence, length, deadline)
+        asks for nothing.  A client that goes away between the ask and the
+        delivery closes this generator: `reset_cache` frees the lane and the
+        engine drops the step in flight as a surplus step."""
         rid = new_request_id()
         nonce = rid
         # request-identity binding (obs/events.py): every log record and
@@ -318,10 +425,7 @@ class InferenceManager:
         resume = None  # built once the wire session is prepared
         prompt_ids: list = []
         try:
-            if (
-                self.failure_monitor is not None
-                and self.failure_monitor.degraded
-            ):
+            if self._degraded():
                 raise ServiceDegradedError(
                     f"ring degraded: shard(s) "
                     f"{self.failure_monitor.down_shards()} down"
@@ -382,42 +486,42 @@ class InferenceManager:
                 timeout_s=self.request_timeout_s,
             )
             send_ids = list(prompt_ids)
+            asked = False  # DECIDE on the token before already sent this step's ask
+            ask_error: Optional[Exception] = None  # ... or tried, and this came of it
+            t_step = 0.0  # stamped where a step's ask leaves
             for step in range(max_new):
-                if deadline is not None:
-                    if deadline.expired:
-                        # between-step shed: the client's deadline passed,
-                        # so every further token is work nobody is waiting
-                        # for
-                        deadline_expired("api_step")
-                        raise DeadlineExceededError(
-                            f"request deadline expired after {generated} "
-                            f"token(s)"
-                        )
-                    # re-bound the token await per step: a shard that
-                    # hangs without dying must surface the 504 when the
-                    # deadline passes, not after the frozen request
-                    # timeout (remaining() shrinks every step)
-                    resume.timeout_s = min(
-                        self.request_timeout_s,
-                        max(deadline.remaining(), 0.001),
-                    )
-                t_step = time.perf_counter()
+                if not asked:
+                    if deadline is not None:
+                        if deadline.expired:
+                            # between-step shed: the client's deadline
+                            # passed, so every further token is work nobody
+                            # is waiting for
+                            deadline_expired("api_step")
+                            raise DeadlineExceededError(
+                                f"request deadline expired after "
+                                f"{generated} token(s)"
+                            )
+                        self._bound_await(resume, deadline)
+                    t_step = time.perf_counter()
                 try:
-                    # re-check per step: the monitor's one-shot fail_pending
-                    # only covers futures pending at the DOWN transition; a
-                    # request at a step boundary would otherwise hang the
-                    # full timeout
-                    if (
-                        self.failure_monitor is not None
-                        and self.failure_monitor.degraded
-                    ):
-                        raise ServiceDegradedError(
-                            f"ring degraded: shard(s) "
-                            f"{self.failure_monitor.down_shards()} down"
+                    if ask_error is not None:
+                        exc, ask_error = ask_error, None
+                        raise exc
+                    if not asked:
+                        # re-check per step: the monitor's one-shot
+                        # fail_pending only covers futures pending at the
+                        # DOWN transition; a request at a step boundary
+                        # would otherwise hang the full timeout
+                        if self._degraded():
+                            raise ServiceDegradedError(
+                                f"ring degraded: shard(s) "
+                                f"{self.failure_monitor.down_shards()} down"
+                            )
+                        await resume.send(
+                            send_ids, decoding, step, budget=max_new - step
                         )
-                    await resume.send(
-                        send_ids, decoding, step, budget=max_new - step
-                    )
+                        _ASKS_AT_STEP.inc()
+                    asked = False
                     result = await resume.await_token(step)
                     if result.error:
                         # typed: deadline / backpressure errors keep their
@@ -468,9 +572,61 @@ class InferenceManager:
                     )
                     if result is None:
                         raise
-                # one span per emitted token: send -> token resolved (grant /
+                # one span per emitted token: ask -> token resolved (grant /
                 # chunk-buffered steps resolve in ~0ms, visibly so)
-                step_ms = (time.perf_counter() - t_step) * 1000
+                t_token = time.perf_counter()
+                step_ms = (t_token - t_step) * 1000
+
+                # ---- DECIDE: does the lane step again?  Nothing else. ----
+                token = result.token_id
+                at_eos = token in eos
+                piece: Optional[str] = None  # the token's own text
+                delta = ""
+                stopped = False
+                if not at_eos:
+                    send_ids = [token]
+                    # checkpoint the accepted token: a later resume replays
+                    # prompt + generated so far (EOS never extends context
+                    # and never needs replaying)
+                    resume.record(token)
+                    if stop_seqs:
+                        # a stop string is found in TEXT, so for such a
+                        # request the detokenizer comes before the ask.
+                        # Never emit text at or beyond a match, and hold
+                        # back any suffix that could still become one.
+                        piece = detok.add(token)
+                        delta, pending, stopped = _scan_for_stop(
+                            pending + piece, stop_seqs
+                        )
+                if (
+                    not at_eos
+                    and not stopped
+                    and step + 1 < max_new
+                    # what stands at the loop's top: a lane that would be
+                    # shed or failed there is not asked for here, and the
+                    # top raises as it always did, after this delivery
+                    and not (deadline is not None and deadline.expired)
+                    and not self._degraded()
+                ):
+                    if deadline is not None:
+                        self._bound_await(resume, deadline)
+                    t_step = time.perf_counter()
+                    try:
+                        await resume.send(
+                            send_ids, decoding, step + 1,
+                            budget=max_new - (step + 1),
+                        )
+                    except Exception as exc:
+                        # nothing swallowed and nothing sent twice: it is
+                        # raised at the step's own top, inside the `try`
+                        # that owns the resume path, after this delivery
+                        ask_error = exc
+                    else:
+                        asked = True
+                        _ASKS_AHEAD.inc()
+                        await _behind_the_ticks_submit()
+
+                # ---- DELIVER: the books, the text, the chunk ----
                 recorder.span(rid, "decode_step", step_ms, step=step)
                 if step > 0:
                     # step 0 is the prefill pass — TTFT owns it; folding
@@ -478,7 +634,7 @@ class InferenceManager:
                     # as a decode-p95 SLO burn
                     slo.record_decode(step_ms)
                 if t_first is None:
-                    t_first = time.perf_counter()
+                    t_first = t_token
                     ttft_ms = (t_first - t_start) * 1000
                     _TTFT_MS.observe(ttft_ms)
                     slo.record_ttft(ttft_ms)
@@ -488,40 +644,17 @@ class InferenceManager:
                 generated += 1
                 _TOKENS_TOTAL.inc()
 
-                if result.token_id in eos:
+                if at_eos:
                     finish_reason = "stop"
                     break
 
-                delta = detok.add(result.token_id)
-                send_ids = [result.token_id]
-                # checkpoint the accepted token: a later resume replays
-                # prompt + generated so far (EOS breaks above — it never
-                # extends context and never needs replaying)
-                resume.record(result.token_id)
+                if piece is None:
+                    piece = delta = detok.add(token)
                 # one logprob entry per generated token, carrying the
                 # token's OWN text — holdback buffering must not smear one
                 # token's logprob across text accumulated from several
                 if req.logprobs_enabled:
-                    held_entries.append(self._logprob_entry(result, delta))
-
-                # Stop sequences: never emit text at or beyond a match, and
-                # hold back any suffix that could still become one.
-                stopped = False
-                if stop_seqs:
-                    pending += delta
-                    delta = ""
-                    for s in stop_seqs:
-                        idx = pending.find(s)
-                        if idx != -1:
-                            pending = pending[:idx]
-                            stopped = True
-                            break
-                    if stopped:
-                        delta, pending = pending, ""
-                    else:
-                        hold = _holdback_len(pending, stop_seqs)
-                        emit_upto = len(pending) - hold
-                        delta, pending = pending[:emit_upto], pending[emit_upto:]
+                    held_entries.append(self._logprob_entry(result, piece))
 
                 if delta or stopped:
                     logprobs = None

@@ -315,6 +315,17 @@ def _register_core(reg: MetricsRegistry) -> None:
     reg.counter(
         "dnet_tokens_generated_total", "Tokens emitted across all requests"
     )
+    from dnet_tpu.obs.phases import DRIVER_ASK_ORDERS
+
+    asks = reg.counter(
+        "dnet_api_driver_asks_total",
+        "send_tokens calls of the API driver, by when a step's ask left "
+        "(obs/phases.py DRIVER_ASK_ORDERS: ahead = before the delivery of "
+        "the token before it; api/inference.py _run)",
+        labelnames=("order",),
+    )
+    for order in DRIVER_ASK_ORDERS:
+        asks.labels(order=order)  # pre-touch: the lint checks these
     reg.counter(
         "dnet_prefix_refill_total",
         "Ring prefix-cache misses transparently re-sent as full prefills",
@@ -744,9 +755,9 @@ def _register_core(reg: MetricsRegistry) -> None:
     reg.histogram(
         "dnet_sched_answer_wait_ms",
         "A token's future resolved to the same request's next send_tokens, "
-        "both on the event loop: the driver's whole way back (recorder, "
-        "SLO tracker, detokenizer, chunk, SSE flush) as the scheduler "
-        "feels it, once per token asked for (ms)",
+        "both on the event loop: the driver's way back to its ask as the "
+        "scheduler feels it (the ask leaves before the token's delivery: "
+        "api/inference.py _run), once per token asked for (ms)",
         buckets=_TURN_MS_BUCKETS,
     )
     depth = reg.gauge(

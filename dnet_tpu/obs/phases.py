@@ -116,6 +116,18 @@ DRIVERS_TURN_OUTCOMES = (
     DRIVERS_TURN_ANSWERED, DRIVERS_TURN_TIMED_OUT, DRIVERS_TURN_NONE,
 )
 
+# dnet_api_driver_asks_total{order=}: when the API driver asked for a
+# step's token (api/inference.py _run): `ahead`, at the end of DECIDE on the
+# token before it, so before that token's delivery; `at_step`, at the top of
+# the step's own iteration (a request's first ask; a step whose ask DECIDE
+# held back because the ring was degraded, if it has recovered by then).
+# An ask ahead that raises is counted under neither and is not sent again:
+# what it raised surfaces at the step's top.  ahead / all is the share of
+# asks that kept a delivery off the path between two decode steps.
+DRIVER_ASK_AHEAD = "ahead"
+DRIVER_ASK_AT_STEP = "at_step"
+DRIVER_ASK_ORDERS = (DRIVER_ASK_AHEAD, DRIVER_ASK_AT_STEP)
+
 # jax.named_scope names inside the traced programs, beside each jitted
 # entry's own JIT_FNS name: metadata only (no instruction changes), so a
 # device trace can be read by layer where the profiler carries op names

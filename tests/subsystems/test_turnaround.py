@@ -389,17 +389,34 @@ def test_answer_wait_counts_one_a_decode_token(engine):
 
 
 def test_the_held_spans_land_in_a_profile_with_the_histograms_durations(
-    engine, tmp_path
+    engine, tmp_path, monkeypatch
 ):
     """`dnet.sched.turn` and `dnet.sched.drivers_turn` are held across
     awaits while another coroutine opens and closes spans on the same
     thread: what benchmarks/harness/xplane.py load() returns under `host`
     holds each of them once an observation, for as long as the histogram
-    saw, a turn around its `dnet.sched.apply` and its drivers' turn."""
+    saw, a turn around its `dnet.sched.apply` and its drivers' turn.
+
+    Held to what a loaded machine keeps.  A span stamps its profiler event
+    and its host clock in two calls each end, and the thread can lose the
+    processor between them: the event is then longer than the observation
+    by a scheduler's slice, on any one span.  So the two are paired by
+    order and count, every event must ENCLOSE its observation, and three
+    pairs of four must agree to a twentieth of the span; nothing is held
+    to a sum or to a fixed half millisecond."""
     import jax
 
     from benchmarks.harness import xplane
+    from dnet_tpu.obs.metrics import _HistogramChild
 
+    observed: dict = {}  # histogram child -> its observations, in order
+    observe_n = _HistogramChild.observe_n
+
+    def recording(self, v, n):
+        observed.setdefault(id(self), []).append(v)
+        observe_n(self, v, n)
+
+    monkeypatch.setattr(_HistogramChild, "observe_n", recording)
     inside = []
 
     async def beside():
@@ -423,6 +440,7 @@ def test_the_held_spans_land_in_a_profile_with_the_histograms_durations(
         finally:
             await adapter.shutdown()
         reset_obs()
+        observed.clear()
         opts = jax.profiler.ProfileOptions()  # as benchmarks/run.py takes its slice
         opts.python_tracer_level = 0
         opts.host_tracer_level = 2
@@ -441,21 +459,33 @@ def test_the_held_spans_land_in_a_profile_with_the_histograms_durations(
     spans = metric("dnet_span_ms")
     for name in (SPAN_SCHED_TURN, SPAN_SCHED_DRIVERS_TURN, "dnet.sched.apply"):
         child = spans.labels(span=name)
-        events = by_name.get(name, [])
-        assert len(events) == child.count > 0, (name, len(events), child.count)
-        prof_ms = sum(d for _, d in events) / 1e6
-        assert abs(prof_ms - child.sum) <= 0.5, (name, prof_ms, child.sum)
+        events = sorted(by_name.get(name, []))  # one thread, one at a time: by start
+        seen = observed.get(id(child), [])
+        assert len(events) == len(seen) == child.count > 0, (name, len(events), child.count)
+        close = 0
+        for (_, dur), ms in zip(events, seen):
+            prof_ms = dur / 1e6
+            assert prof_ms >= ms - 0.05, (name, prof_ms, ms)
+            close += prof_ms - ms <= max(0.1, 0.05 * ms)
+        assert 4 * close >= 3 * len(seen), (name, close, len(seen))
     assert len(inside) > 20 and len(by_name["dnet.api.sse_flush"]) == len(inside)
     turns = sorted(by_name[SPAN_SCHED_TURN])
-    # every apply lies in a turn that began less than 0.5 ms before it, and
-    # every drivers' turn begins where an apply ends and ends inside its turn
     applies = sorted(by_name["dnet.sched.apply"])
-    for (t0, td), (a0, ad) in zip(turns, applies):
-        assert t0 <= a0 <= t0 + 500_000 and a0 + ad <= t0 + td
-    ends = [a0 + ad for a0, ad in applies]
+    slack = 1_000  # ns: two stamps of one clock
+
+    def holds(t0, td, x0, xd):
+        return t0 <= x0 + slack and x0 + xd <= t0 + td + slack
+
+    # every turn holds ONE apply, and every drivers' turn lies in one turn,
+    # behind that turn's apply: by containment and order, not by distance
+    apply_end = {}
+    for t0, td in turns:
+        inside_turn = [(a0, ad) for a0, ad in applies if holds(t0, td, a0, ad)]
+        assert len(inside_turn) == 1, (t0, td, inside_turn)
+        apply_end[t0] = sum(inside_turn[0])
     for d0, dd in by_name[SPAN_SCHED_DRIVERS_TURN]:
-        assert min(abs(d0 - e) for e in ends) <= 500_000
-        assert any(t0 <= d0 and d0 + dd <= t0 + td for t0, td in turns)
+        around = [t0 for t0, td in turns if holds(t0, td, d0, dd)]
+        assert len(around) == 1 and apply_end[around[0]] <= d0 + slack, (d0, dd, around)
     # the other coroutine's spans opened and closed INSIDE the waits
     waits = by_name[SPAN_SCHED_DRIVERS_TURN]
     within = sum(
