@@ -138,13 +138,10 @@ class HostLayerStore:
         if self.weight_quant_bits:
             # quantize the RAW checkpoint values (before any lossy cast) so
             # fit and offload policies serve bit-identical quantized weights
-            from dnet_tpu.ops.quant import quantize_tree
-
-            mapped = quantize_tree(
+            mapped = self.model.quantize_layer(
                 mapped,
-                self.model.quant_keys,
+                self.weight_quant_bits,
                 scale_dtype=self.param_dtype,
-                bits=self.weight_quant_bits,
                 group_size=self.weight_quant_group,
             )
         mapped = self._cast(mapped)

@@ -351,6 +351,12 @@ class RingModel(abc.ABC):
             group_size=group_size,
         )
 
+    def quantize_layer(self, mapped, bits: int, scale_dtype=None, group_size: int = 0):
+        """Weight-only quantize ONE layer as `map_layer` gives it (the
+        streaming store's path, core/weights.py): a flat dict whatever the
+        model's stacked layout."""
+        return RingModel.quantize_params(self, mapped, bits, scale_dtype, group_size)
+
     def quantize_edge(self, edge: Dict[str, Any], bits: int, scale_dtype=None,
                       group_size: int = 0) -> Dict[str, Any]:
         """Quantize the LM projection among the edge params.

@@ -26,6 +26,17 @@ Routing, top-k and normalisation run over every routed expert; the layer
 returns its own experts' part plus the shared experts' term (ops/moe.py).
 Nothing stands in for the experts held elsewhere.
 
+A projection whose output is split by head (q, k, v) is STORED heads-first,
+`[L, heads, head_dim, D]`: HF's own `[out, in]` split by head, contracted
+over its last axis straight to `[B, T, heads, head_dim]`.  The v5e compiler
+keeps q head-major (the rope and the Mosaic call behind it want the heads
+apart) and so wants the weight with D minor: stored `[L, D, heads*head_dim]`
+every program slices the layer's matrix out of the stack and copies it into
+that layout before each use (wq: 128 MB a layer, PERF.md section 6, PR 52).
+The quantised form keeps `[L, D, out]` (ops/quant.py: groups along D, a
+scale an output row) and its contraction: dequantisation writes a temporary
+anyway.
+
 One `lax.scan` over the stacked layers with the layer's kind riding as
 data.  Over a slot-addressed cache both kinds read one flat
 [L, B, S, ...] cache (the window is the kernel's lower bound,
@@ -56,12 +67,14 @@ from dnet_tpu.obs.phases import (
 )
 from dnet_tpu.ops.attention import attend, sliding_window_mask
 from dnet_tpu.ops.norms import layer_norm
-from dnet_tpu.ops.quant import dq, lead_dim, out_dim
+from dnet_tpu.ops.quant import dq, is_quantized, lead_dim
 from dnet_tpu.ops.rope import apply_rope_interleaved, rope_frequencies
 
 # a layer's kind rides the scan as data: its index in obs/phases.py KV_KINDS,
 # the one encoding the pools, the gauges' labels and the kernels' names share
 KIND_FULL, KIND_WINDOW = (KV_KINDS.index(k) for k in (KV_KIND_FULL, KV_KIND_WINDOW))
+# the projections whose output is split by head: stored heads-first
+BY_HEAD = ("wq", "wk", "wv")
 
 
 class Cohere2MoeRingModel(RingModel):
@@ -153,15 +166,19 @@ class Cohere2MoeRingModel(RingModel):
         self._kind_index = jnp.asarray(within, dtype=jnp.int32)
 
     # ---- pure compute --------------------------------------------------
+    def _by_head(self, h, w):
+        """h [B, T, D] through a projection split by head -> [B, T, heads,
+        head_dim]: a float matrix is [heads, head_dim, D], a quantised one
+        [D, heads*head_dim]."""
+        if is_quantized(w):
+            B, T, _ = h.shape
+            return (h @ dq(w)).reshape(B, T, -1, self.config.head_dim)
+        return jnp.einsum("btd,hkd->bthk", h, w)
+
     def _attention(self, p, h, kvs, pos, kind, idx, mask, kv_commit, attend_fn):
-        cfg = self.config
         B, T, _ = h.shape
-        Hd = cfg.head_dim
-        H = out_dim(p["wq"]) // Hd
-        KVH = out_dim(p["wk"]) // Hd
-        q = (h @ dq(p["wq"])).reshape(B, T, H, Hd)
-        k = (h @ dq(p["wk"])).reshape(B, T, KVH, Hd)
-        v = (h @ dq(p["wv"])).reshape(B, T, KVH, Hd)
+        q, k, v = (self._by_head(h, p[name]) for name in BY_HEAD)
+        H, Hd = q.shape[2:]
         # window layers rotate, full layers carry no position at all
         positions = pos + jnp.arange(T)
         is_win = kind == KIND_WINDOW
@@ -357,15 +374,19 @@ class Cohere2MoeRingModel(RingModel):
         def t(name: str) -> np.ndarray:
             return np.ascontiguousarray(raw[name].T)  # HF [out,in] -> (in,out)
 
+        def by_head(name: str) -> np.ndarray:
+            w = raw[name]  # HF [heads*head_dim, D] as it lies, split by head
+            return w.reshape(-1, self.config.head_dim, w.shape[-1])
+
         def stack(fmt: str, ids) -> np.ndarray:
             return np.stack([t(fmt.format(e)) for e in ids])
 
         held = range(self.expert_offset, self.expert_offset + self.n_held)
         p = {
             "norm": raw["input_layernorm.weight"],
-            "wq": t("self_attn.q_proj.weight"),
-            "wk": t("self_attn.k_proj.weight"),
-            "wv": t("self_attn.v_proj.weight"),
+            "wq": by_head("self_attn.q_proj.weight"),
+            "wk": by_head("self_attn.k_proj.weight"),
+            "wv": by_head("self_attn.v_proj.weight"),
             "wo": t("self_attn.o_proj.weight"),
             "gate_w": t("mlp.gate.weight"),  # [D, routed experts]
             "e_gate": stack("mlp.experts.{}.gate_proj.weight", held),
@@ -385,3 +406,17 @@ class Cohere2MoeRingModel(RingModel):
                 [t(cat.format(j) + "down_proj.weight") for j in js], axis=0
             )
         return p
+
+    # ---- weight-only quantisation ----------------------------------------
+    def quantize_params(self, stacked, bits: int, scale_dtype=None, group_size: int = 0):
+        """The heads-first leaves quantise in ops/quant.py's form, [.., D,
+        out] with the groups along D: the numbers a [D, out] matrix gave."""
+
+        def rows_last(w):  # [.., heads, head_dim, D] -> a view [.., D, heads*head_dim]
+            w = np.asarray(w)
+            return np.swapaxes(w.reshape(*w.shape[:-3], -1, w.shape[-1]), -1, -2)
+
+        turned = {k: rows_last(v) if k in BY_HEAD else v for k, v in stacked.items()}
+        return super().quantize_params(turned, bits, scale_dtype, group_size)
+
+    quantize_layer = quantize_params  # one mapped layer is the same flat dict

@@ -13,6 +13,12 @@ proves the layout is accepted as it is, not that the programs are right:
 tests/test_paged_kv.py and tests/test_kv_kinds.py hold them to the dense
 path.
 
+The same reading for a WEIGHT's layout (PR 52): a projection split by head,
+stored `[L, D, heads*head_dim]`, is sliced whole out of its stack and copied
+into the layout the contraction wants (D minor, heads apart) in every layer
+of every program; stored `[L, heads, head_dim, D]` it is read where it lies.
+The mix cell's chunk and step programs hold no such slice and no such copy.
+
 ONE file, topology inside a fixture (on-chip-measurement guide, section 2):
 only the worker given this file loads the TPU library.
 """
@@ -393,3 +399,212 @@ def test_the_doc_cells_decode_step_reads_its_experts_through_the_grouped_kernel(
         .compile().as_text()
     )
     assert "%gmm" not in dense and re.search(rf"bf16\[(1,)?{E},({D},{F}|{F},{D})\]", dense)
+
+
+def materialised(text: str):
+    """(name, dims, op) of every instruction of a compiled module that
+    writes its result to memory: those of the entry, the loops' bodies and
+    the branches, not those inside a fusion's computation."""
+    fused = set(re.findall(r"fusion\([^\n]*?calls=%([\w.-]+)", text))
+    comp = None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%([\w.-]+) \(.*\{\s*$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = re.match(
+            r"^\s*(?:ROOT\s+)?%?([\w.-]+) = \w+\[([\d,]+)\][^\n]*? (\w[\w-]*)\(", line
+        )
+        if m and comp not in fused:
+            yield m.group(1), [int(d) for d in m.group(2).split(",")], m.group(3)
+
+
+def weight_relayouts(text: str, D: int, counts) -> list:
+    """The `copy` instructions and the slice fusions whose result is one
+    layer's projection matrix: one of `counts` elements with the hidden
+    width among its dimensions (a chunk's q, `[1, 256, 128, 128]`, has
+    `wk`'s count and is no weight)."""
+    return [
+        (name, dims) for name, dims, op in materialised(text)
+        if int(np.prod(dims)) in counts and D in dims
+        and (op == "copy" or "slice" in name or "slice" in op)
+    ]
+
+
+MIX = dict(L=4, D=4096, H=128, KVH=8, Hd=128, E=16, R=128, F=4096, V=32768, shared=4,
+           slots=16, bt=128, chunk=256, max_seq=16512, full_blocks=448, window_blocks=35)
+
+
+def _cohere2_moe_params(c: dict, dtype, heads_first: bool = True) -> dict:
+    """`models/cohere2_moe.py`'s window parameters as shapes, from a
+    geometry's numbers (held to the loader's own tree below)."""
+    L, D, H, KVH, Hd, E, F = (c[k] for k in ("L", "D", "H", "KVH", "Hd", "E", "F"))
+    Fs = c["shared"] * F
+
+    def by_head(n):
+        return (L, n, Hd, D) if heads_first else (L, D, n * Hd)
+
+    tree = {
+        "norm": (L, D), "wq": by_head(H), "wk": by_head(KVH), "wv": by_head(KVH),
+        "wo": (L, H * Hd, D), "gate_w": (L, D, c["R"]),
+        "e_gate": (L, E, D, F), "e_up": (L, E, D, F), "e_down": (L, E, F, D),
+        "s_gate": (L, D, Fs), "s_up": (L, D, Fs), "s_down": (L, Fs, D),
+    }
+    return {k: jax.ShapeDtypeStruct(s, dtype) for k, s in tree.items()}
+
+
+@pytest.fixture(scope="module")
+def mix_engine(tmp_path_factory):
+    """The mix cell's engine with its heads, experts, slots, blocks, window
+    and `max_seq`, and a hidden width, an expert width and a vocabulary a
+    CPU can hold (64, 32, 512): after one served prompt and one step, the
+    engine, the two programs' arguments and the narrow geometry."""
+    from benchmarks.harness import spec
+    from benchmarks.harness.weights import write_checkpoint
+
+    from dnet_tpu.config import reset_settings_cache
+    from dnet_tpu.core.batch import BatchedEngine
+    from dnet_tpu.core.types import DecodingParams
+
+    full = spec.load_json(spec.BENCH_DIR / "configs" / "command-a-plus-4l-ep8.json")
+    cfg = {k: v for k, v in full.items()
+           if k not in ("assumed", "deployment", "serve", "check", "rehearse")}
+    narrow = dict(MIX, D=64, F=32, V=512)
+    cfg.update(hidden_size=narrow["D"], intermediate_size=narrow["F"], vocab_size=narrow["V"])
+    assert (cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"],
+            cfg["num_experts"], cfg["num_experts_routed"], cfg["num_shared_experts"]) == tuple(
+        MIX[k] for k in ("H", "KVH", "Hd", "E", "R", "shared"))
+    model_dir = tmp_path_factory.mktemp("mix_geometry")
+    write_checkpoint(model_dir, cfg, seed=2**31 + 52, dtype="bfloat16")
+    env = pytest.MonkeyPatch()
+    serve = full["serve"]["env"]
+    for name in ("DNET_KV_BLOCK_TOKENS", "DNET_SCHED_TOKEN_BUDGET"):
+        env.setenv(name, serve[name])
+    env.setenv("DNET_KV_POOL_BLOCKS", "8")  # here; the cell's 448 on the described chip
+    reset_settings_cache()
+    eng = BatchedEngine(
+        model_dir, slots=MIX["slots"], max_seq=MIX["max_seq"], param_dtype="bfloat16",
+        kv_dtype="bfloat16", kv_paged=True,
+    )
+    try:
+        seen = {}
+
+        def spy(obj, name):
+            fn = getattr(obj, name)
+
+            def run(*args):
+                seen.setdefault(name, args)
+                return fn(*args)
+
+            setattr(obj, name, run)
+
+        spy(eng, "_ragged_step")
+        spy(eng.eng, "_forward")  # the chunk program: a prompt's rows against its staged row
+        dec = DecodingParams(temperature=0.0)
+        res = eng.prefill_and_sample("a", list(range(300, 320)), dec)
+        _, errs = eng.decode_batch({"a": (int(res.token[0]), dec)})
+        assert not errs and set(seen) == {"_ragged_step", "_forward"}
+        # the shapes' formula is the loader's tree
+        assert jax.tree.map(lambda a: a.shape, eng.eng.window_params) == jax.tree.map(
+            lambda a: a.shape, _cohere2_moe_params(narrow, BF)
+        )
+        yield eng, seen
+    finally:
+        eng.close()
+        env.undo()
+        reset_settings_cache()
+
+
+def _mix_programs(eng, seen, one_chip, monkeypatch, heads_first=True):
+    """The engine's chunk program and its 16-slot step as the chip compiles
+    them (the Mosaic kernels, the grouped experts), every argument a served
+    prompt gave them on the described chip and grown to the cell's widths."""
+    from dnet_tpu.ops import kernel_select, paged_attention
+
+    c = MIX
+    monkeypatch.setattr(kernel_select, "on_tpu", lambda: True)
+    monkeypatch.setattr(paged_attention, "paged_attend_impl", lambda: "pallas")
+    eng._build_ragged()
+    eng.eng._build_fns()
+
+    def a(shape, dtype=BF):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: a(np.shape(x), x.dtype), tree)
+
+    wp = on_chip(_cohere2_moe_params(c, BF, heads_first))
+    ep = {"embed": {"weight": a((c["V"], c["D"]))}, "final_norm": {"weight": a((c["D"],))}}
+    assert jax.tree.structure(ep) == jax.tree.structure(seen["_forward"][1])
+    chunk = [wp, ep, a((1, c["chunk"]), jnp.int32), *on_chip(seen["_forward"][3:])]
+    assert chunk[3]["k"].shape == (c["L"], 1, c["max_seq"], c["KVH"], c["Hd"])
+    step = [wp, ep, *on_chip(seen["_ragged_step"][2:])]
+    assert step[2].shape == (c["slots"], 1)
+    row = c["KVH"] * c["Hd"]
+    step[3] = {
+        KV_KIND_FULL: {n: a((1, c["full_blocks"], c["bt"], row)) for n in "kv"},
+        KV_KIND_WINDOW: {
+            n: a((3, c["slots"] * c["window_blocks"], c["bt"], row)) for n in "kv"
+        },
+    }
+    assert jax.tree.map(lambda x: x.shape[2:], step[3]) == jax.tree.map(
+        lambda x: x.shape[2:], seen["_ragged_step"][3])
+    assert seen["_ragged_step"][4][KV_KIND_WINDOW].shape == (c["slots"], c["window_blocks"])
+    step[4] = dict(step[4], **{KV_KIND_FULL: a((c["slots"], c["max_seq"] // c["bt"]), jnp.int32)})
+    step[9] = a((c["slots"], c["V"]), jnp.int32)  # the sampler's counts, a vocabulary wide
+    return {"chunk": (eng.eng._forward, chunk, ("flash_prefill", "flash_prefill_window")),
+            "step": (eng._ragged_step, step, ("paged_attend", "paged_attend_window", "gmm"))}
+
+
+def _compiled(program, args) -> str:
+    return program.trace(*args).lower(lowering_platforms=("tpu",)).compile().as_text()
+
+
+@pytest.mark.parametrize("which", ["chunk", "step"])
+def test_the_mix_cells_programs_read_a_projection_where_it_lies(
+    one_chip, no_cache, mix_engine, monkeypatch, which
+):
+    """The engine's OWN chunk program (256 tokens against the staged row of
+    16512) and its 16-slot step over both pools, at the mix cell's widths
+    (4 layers window x 3 + full, hidden 4096, 128 / 8 heads of 128, 16 held
+    experts of 128 routed, 128-token blocks): NO `copy` and no slice fusion
+    with the element count of a layer's `wq` (4096 x 16384) or of its `wk`
+    (4096 x 1024).  Stored `[L, D, heads*head_dim]` each layer of each
+    program made both, of each of the three (15 % of the cell's busy time:
+    ledger, PR 51, mix, `breakdown`)."""
+    eng, seen = mix_engine
+    program, args, kernels = _mix_programs(eng, seen, one_chip, monkeypatch)[which]
+    text = _compiled(program, args)
+    assert "tpu_custom_call" in text and all(f"%{k}" in text for k in kernels)
+    c = MIX
+    counts = {c["D"] * c["H"] * c["Hd"], c["D"] * c["KVH"] * c["Hd"]}
+    assert weight_relayouts(text, c["D"], counts) == []
+
+
+def test_the_reader_sees_the_relayouts_of_the_projection_layout_that_went(
+    one_chip, no_cache, mix_engine, monkeypatch
+):
+    """The assertion above is only as good as what it can see: the same
+    programs with the same contraction over `[L, D, heads*head_dim]` stacks
+    (the model's projection put back as it was) slice each of the three
+    matrices out of its stack and copy it, under the names the ledger's
+    `breakdown` of the mix cell carried."""
+    eng, seen = mix_engine
+    Hd = MIX["Hd"]
+
+    def as_it_was(h, w):
+        return (h @ w).reshape(*h.shape[:2], -1, Hd)
+
+    monkeypatch.setattr(eng.model, "_by_head", as_it_was)
+    programs = _mix_programs(eng, seen, one_chip, monkeypatch, heads_first=False)
+    c = MIX
+    wq, wk = c["D"] * c["H"] * c["Hd"], c["D"] * c["KVH"] * c["Hd"]
+    for which, pairs_of_wk in (("chunk", 2), ("step", 1)):
+        program, args, _ = programs[which]
+        found = weight_relayouts(_compiled(program, args), c["D"], {wq, wk})
+        big = [name for name, dims in found if int(np.prod(dims)) == wq]
+        small = [name for name, dims in found if int(np.prod(dims)) == wk]
+        assert len(big) == 2 and len(small) == 2 * pairs_of_wk, found
+        for names in (big, small):
+            assert sum(n.startswith("copy") for n in names) == len(names) // 2, found
+            assert sum("dynamic-slice_fusion" in n for n in names) == len(names) // 2, found
