@@ -183,6 +183,9 @@ _REQUIRED_FAMILIES = (
     "dnet_decode_lane_steps_total",
     "dnet_decode_tokens_total",
     "dnet_decode_buffer_dropped_total",
+    # the share of its held experts a decode step reads (core/batch.py;
+    # benchmarks/layer_metrics/moe_experts_visited_in_window.json)
+    "dnet_moe_experts_visited_total",
     "dnet_jit_compiles_total",
     "dnet_jit_compile_ms",
     "dnet_device_mem_bytes",

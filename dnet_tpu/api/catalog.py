@@ -67,6 +67,14 @@ model_catalog: List[CatalogEntry] = [
         notes="MoE 128x top-8 sigmoid + 4 shared, SWA 3:1, parallel block; "
         "language model only, expert share by config",
     ),
+    # Mellum: the Qwen3-MoE block with window and full layers mixed, each
+    # kind rotated by a RoPE table of its own (rope_parameters nested by
+    # layer type); the multi-token-prediction head is not served
+    CatalogEntry(
+        "JetBrains/Mellum2-12B-A2.5B-Instruct", "mellum", 12.2, 28,
+        notes="MoE 64x top-8, SWA 1024 3:1, a RoPE table a layer type "
+        "(YaRN x16 on the full kind); no MTP head",
+    ),
 ]
 
 

@@ -666,13 +666,25 @@ class BatchedEngine:
             # a model with an expert share says how many of each lane's
             # chosen experts it holds (dnet_moe_assignments_total)
             held = rows.pop("moe_held", None)  # [L, slots, 1]
+            # and, where it says so, WHICH of the held experts each lane
+            # chose (dnet_moe_experts_visited_total)
+            took = rows.pop("moe_chosen", None)  # [L, slots, 1, E] bool
             if held is None:
                 moe = jnp.zeros((2,), jnp.int32)
             else:
                 live = active.astype(jnp.int32)
                 mine = jnp.sum(held[..., 0] * live[None, :])
                 chosen = held.shape[0] * self.config.num_experts_per_tok * jnp.sum(live)
-                moe = jnp.stack([mine, chosen - mine]).astype(jnp.int32)
+                books = [mine, chosen - mine]
+                if took is not None:
+                    # a third entry for the model that reports it alone: the
+                    # others' step stays the program it was.  (Imported below
+                    # every kernel's call site on purpose: a line added above
+                    # one re-compiles it, PERF.md section 6, PR 30)
+                    from dnet_tpu.ops.moe import experts_visited
+
+                    books.append(experts_visited(took[:, :, 0], active))
+                moe = jnp.stack(books).astype(jnp.int32)
             x = model.normalize(ep, x[:, -1:])
             logits = model.lm_project(ep, x)[:, 0]  # [slots, V]
             res, counts, keys = vsample(logits, active, sp, keys, counts)
@@ -1214,9 +1226,11 @@ class BatchedEngine:
             tlps = np.asarray(src.top_logprobs)
             if self.kv_store is not None and self._moe_reported:
                 # summed on the device by the dispatch just read: no sync
-                mine, elsewhere = np.asarray(flight.moe)
+                mine, elsewhere, *visited = np.asarray(flight.moe)
                 _MOE_ASSIGNMENTS.labels(held="yes").inc(int(mine))
                 _MOE_ASSIGNMENTS.labels(held="no").inc(int(elsewhere))
+                if visited:  # the model says which experts its lanes chose
+                    metric("dnet_moe_experts_visited_total").inc(int(visited[0]))
         with span(SPAN_DECODE_UNPACK):
             now = time.time()
             out = flight.out

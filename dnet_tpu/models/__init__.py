@@ -63,6 +63,10 @@ def get_ring_model_cls(model_type: str) -> Type[RingModel]:
         from dnet_tpu.models import minicpm_sala  # noqa: F401
     except ImportError:
         pass
+    try:
+        from dnet_tpu.models import mellum  # noqa: F401
+    except ImportError:
+        pass
 
     for sub in _all_subclasses(RingModel):
         if getattr(sub, "model_type", None) == model_type:

@@ -57,6 +57,9 @@ class ModelConfig:
     # MoE (gpt-oss / mixtral style)
     num_local_experts: int = 0
     num_experts_per_tok: int = 0
+    # {layer type: (rope_theta, rope_scaling)} where `rope_parameters` is
+    # nested by layer type (mellum); None for the one flat group
+    rope_by_type: Optional[Dict[str, Tuple[float, Optional[dict]]]] = None
     # MLA (deepseek style) and other family-specific extras
     extra: dict = field(default_factory=dict)
 
@@ -67,9 +70,21 @@ class ModelConfig:
         # newer configs (mistral4) keep theta and the scaling in ONE group,
         # `rope_parameters`; it is read where a config has no `rope_scaling`
         rope = d.get("rope_parameters") or {}
+        # or one group a LAYER TYPE (mellum): each type in `layer_types`
+        # then has a theta and a scaling of its own (`rope_by_type`), and
+        # the flat fields hold the first type's
+        by_type = rope_parameters_by_type(rope, d.get("layer_types"))
+        if by_type:
+            rope = next(iter(by_type.values()))
         scaling = d.get("rope_scaling")
         if scaling is None and rope.get("rope_type", rope.get("type", "default")) != "default":
             scaling = rope
+        if any(t != "sparse" for t in d.get("mlp_layer_types") or ()):
+            raise NotImplementedError(
+                "mlp_layer_types with an entry other than 'sparse': "
+                f"{sorted(set(d['mlp_layer_types']))} (no published config has "
+                "a dense layer among them to hold one against)"
+            )
         return cls(
             model_type=d["model_type"],
             vocab_size=d["vocab_size"],
@@ -92,8 +107,34 @@ class ModelConfig:
             # mixtral/gpt_oss say num_local_experts; qwen3_moe says num_experts
             num_local_experts=d.get("num_local_experts", d.get("num_experts", 0)),
             num_experts_per_tok=d.get("num_experts_per_tok", 0),
+            rope_by_type={
+                t: (float(g.get("rope_theta", 10000.0)), _scaling_of(g))
+                for t, g in by_type.items()
+            } or None,
             extra=d,
         )
+
+
+def _scaling_of(group: dict) -> Optional[dict]:
+    """A rope group's scaling: the group itself, None for the default type."""
+    return None if group.get("rope_type", group.get("type", "default")) == "default" else group
+
+
+def rope_parameters_by_type(rope: dict, layer_types) -> Dict[str, dict]:
+    """`rope_parameters` NESTED BY LAYER TYPE -> {type: its group}, for the
+    types `layer_types` uses, in their order of first use; {} for a flat
+    group (or none).  A type the layers use and the dict lacks is an error:
+    read as a flat group it would lose theta and scaling without a word."""
+    if not rope or not all(isinstance(v, dict) for v in rope.values()):
+        return {}
+    used = list(dict.fromkeys(layer_types or rope))
+    missing = [t for t in used if t not in rope]
+    if missing:
+        raise ValueError(
+            f"rope_parameters is keyed by layer type {sorted(rope)} and has "
+            f"no entry for {missing}, which layer_types uses"
+        )
+    return {t: rope[t] for t in used}
 
 
 class RingModel(abc.ABC):
@@ -143,6 +184,10 @@ class RingModel(abc.ABC):
     # per-layer param names eligible for weight-only quantization (the big
     # matmuls; norms/biases/routers stay float).  Subclasses override.
     quant_keys: frozenset = frozenset(QUANTIZABLE)
+    # the model rotates each layer type by its own table
+    # (`ModelConfig.rope_by_type`); one that does not is refused a config
+    # whose types differ, where it would rotate them all by the first
+    rope_by_layer_type: bool = False
     # routed experts: None = the model has none; True = its experts have
     # the exact grouped closure beside the dense one (ops/moe.py:
     # swiglu_grouped_closure), False = dense closures only
@@ -154,6 +199,13 @@ class RingModel(abc.ABC):
 
     def __init__(self, config: ModelConfig, layers: Sequence[int]):
         self.config = config
+        tables = list((config.rope_by_type or {}).values())
+        if not self.rope_by_layer_type and any(t != tables[0] for t in tables):
+            raise NotImplementedError(
+                f"{self.model_type}: rope_parameters differ by layer type "
+                f"({sorted(config.rope_by_type)}) and this model rotates "
+                "every layer by one table"
+            )
         self.layers = sorted(set(int(x) for x in layers))
         self.abs_to_local = {a: i for i, a in enumerate(self.layers)}
         self.is_first = 0 in self.abs_to_local

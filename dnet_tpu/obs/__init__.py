@@ -286,6 +286,13 @@ def _register_core(reg: MetricsRegistry) -> None:
     )
     for held in MOE_HELD:
         moe_fam.labels(held=held)  # pre-touch: the lint checks these
+    reg.counter(
+        "dnet_moe_experts_visited_total",
+        "(layer, held expert) pairs that at least one active lane of a "
+        "batched decode dispatch chose: over the dispatches x the layers x "
+        "the held experts, the share of its experts a step reads (models "
+        "that do not report their lanes' choices leave this at 0)",
+    )
     rows_fam = reg.counter(
         "dnet_moe_expert_rows_total",
         "Rows (padding included) of the prefill chunks and decode steps "

@@ -506,7 +506,7 @@ def grouped_matmul(
         xs = jnp.pad(xs, ((0, pad), (0, 0)))
     out = gmm(
         xs, w, sizes, preferred_element_type=xs.dtype,
-        tiling=(tile, min(w.shape[1], GROUP_TILE_COLS), min(w.shape[2], GROUP_TILE_COLS)),
+        tiling=(tile, group_tile_cols(w.shape[1]), group_tile_cols(w.shape[2])),
         interpret=backend == "interpret",
     )
     return out[:m] if pad else out
@@ -570,3 +570,33 @@ def swiglu_grouped_closure(p, flat, top_idx, top_w, offset: int = 0):
         return jnp.einsum("nkd,nk->nd", y, top_w.astype(y.dtype))
 
     return grouped
+
+
+# Below the kernel's callers on purpose: a Mosaic kernel's serialized body
+# carries the lines of its call stack, and a line added above `grouped_matmul`
+# would re-compile every program that holds the kernel (PERF.md section 6,
+# PR 30).
+
+
+def group_tile_cols(width: int) -> int:
+    """The k or n tile for a side of `width` columns, from the shape alone:
+    the side itself where it fits GROUP_TILE_COLS, else the largest multiple
+    of 128 under the cap that DIVIDES it (2304 -> 768, where the cap itself
+    would run three steps for 2.25 tiles of work, the last one masked),
+    else the cap (megablox masks the remainder).  512, 768, 896 and 1024
+    are their own tile; 2048 and 4096 keep the cap."""
+    if width <= GROUP_TILE_COLS:
+        return width
+    for tile in range(GROUP_TILE_COLS, 0, -128):
+        if width % tile == 0:
+            return tile
+    return GROUP_TILE_COLS
+
+
+def experts_visited(chosen: jnp.ndarray, active: jnp.ndarray) -> jnp.ndarray:
+    """How many (layer, held expert) pairs some ACTIVE lane of a decode
+    step chose: what the step's grouped matmuls read of the experts
+    (dnet_moe_experts_visited_total).  chosen [L, lanes, E] bool, active
+    [lanes] bool -> int32 scalar."""
+    return jnp.sum(jnp.any(chosen & active[None, :, None], axis=1), dtype=jnp.int32)
+

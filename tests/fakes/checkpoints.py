@@ -616,3 +616,33 @@ def make_tiny_minicpm_sala(model_dir: str | Path, seed: int = 2**31 + 43, **over
     cfg = tiny_minicpm_sala_config(**over)
     write_checkpoint(Path(model_dir), cfg, seed=seed, dtype="float32")
     return cfg
+
+
+def rehearsal_config(name: str, **over) -> dict:
+    """The benchmark configuration `name` at its rehearsal size, the HF keys
+    alone: the file's top-level keys less the benchmark's own, its
+    `rehearse.config` over them, then `over`."""
+    import json
+
+    root = Path(__file__).resolve().parents[2]
+    full = json.loads((root / "benchmarks" / "configs" / f"{name}.json").read_text())
+    cfg = {k: v for k, v in full.items()
+           if k not in ("assumed", "deployment", "serve", "check", "rehearse")}
+    return {**cfg, **full["rehearse"]["config"], **over}
+
+
+def tiny_mellum_config(**over) -> dict:
+    """`mellum2-12b-a2.5b-8l` at its rehearsal size: hidden 64, 8 query / 2
+    KV heads of 16, 8 experts of 32 top-2, two periods of (sliding x 3,
+    full), window 8, YaRN x16 over an original length of 32 on the full kind."""
+    return rehearsal_config("mellum2-12b-a2.5b-8l", **over)
+
+
+def make_tiny_mellum(model_dir: str | Path, seed: int = 2**31 + 54, **over) -> dict:
+    """A seeded float32 mellum checkpoint, written as the benchmark writes
+    its own (tensor names from benchmarks/reference/mellum.py)."""
+    from benchmarks.harness.weights import write_checkpoint
+
+    cfg = tiny_mellum_config(**over)
+    write_checkpoint(Path(model_dir), cfg, seed=seed, dtype="float32")
+    return cfg
